@@ -91,10 +91,10 @@ def lint_passes():
 # ------------------------------------------------------------ jaxpr walk
 
 def _as_jaxprs(v):
-    import jax
-    if isinstance(v, jax.core.ClosedJaxpr):
+    from jax.extend import core as jex_core
+    if isinstance(v, jex_core.ClosedJaxpr):
         return [v.jaxpr]
-    if isinstance(v, jax.core.Jaxpr):
+    if isinstance(v, jex_core.Jaxpr):
         return [v]
     if isinstance(v, (list, tuple)):
         return [j for x in v for j in _as_jaxprs(x)]
@@ -114,15 +114,12 @@ def iter_eqns(jaxpr):
 def eqn_site(eqn):
     """``file:line (function)`` of the user frame that emitted the
     equation, via jax's source_info; "<unknown>" when unavailable."""
-    try:
-        from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
-            return (f"{frame.file_name}:{frame.start_line} "
-                    f"({frame.function_name})")
-    except Exception:
-        pass
-    return "<unknown>"
+    from jax._src import source_info_util
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
+        return "<unknown>"
+    return (f"{frame.file_name}:{frame.start_line} "
+            f"({frame.function_name})")
 
 
 def _resolve(target):
@@ -130,12 +127,12 @@ def _resolve(target):
     output), a raw Jaxpr, anything exposing ``.jaxpr`` (jax.stages
     Traced), a ServingEngine (delegates to ``engine.lint``'s
     resolution), or None (meta-only passes still run)."""
-    import jax
+    from jax.extend import core as jex_core
     if target is None:
         return None
-    if isinstance(target, jax.core.Jaxpr):
+    if isinstance(target, jex_core.Jaxpr):
         return target
-    if isinstance(target, jax.core.ClosedJaxpr):
+    if isinstance(target, jex_core.ClosedJaxpr):
         return target.jaxpr
     inner = getattr(target, "jaxpr", None)
     if inner is not None:

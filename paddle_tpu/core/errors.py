@@ -1,4 +1,4 @@
-"""Error-code taxonomy + enforce helpers.
+"""Error-code classes + enforce helpers.
 
 Reference parity: paddle/fluid/platform/enforce.h:427 (PADDLE_ENFORCE*
 macros), paddle/fluid/platform/errors.h + error_codes.proto (LEGACY,

@@ -23,11 +23,13 @@ _DEFAULTS = {
     # persistent XLA compilation cache directory ("" disables). Eager
     # dispatch compiles one executable per (op, shape); on TPU those
     # compiles dominate warmup (SURVEY §7 hard-part 1) — the disk cache
-    # amortizes them across processes/runs. Per-user path: cache entries
-    # are executed code, so a world-shared /tmp dir would let another
-    # local user poison them.
+    # amortizes them across processes/runs. One FIXED path inside the
+    # checkout: the path is part of jax's cache key, so a directory
+    # that moves never hits. $JAX_COMPILATION_CACHE_DIR, when set,
+    # places the cache from outside (see init_compilation_cache).
     "FLAGS_compilation_cache_dir": os.path.join(
-        os.path.expanduser("~"), ".cache", "paddle_tpu", "xla"),
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".jax_cache"),
     # only cache compiles slower than this (seconds)
     "FLAGS_compilation_cache_min_compile_secs": 0.3,
     # lazy micro-tracing eager executor (core/lazy.py): defer eager ops
@@ -40,24 +42,27 @@ _DEFAULTS = {
 
 def init_compilation_cache():
     """Apply FLAGS_compilation_cache_dir to jax (called at import and
-    whenever set_flags changes the cache flags)."""
+    whenever set_flags changes the cache flags).
+
+    Precedence: an explicit FLAGS_compilation_cache_dir (environment or
+    set_flags; "" disables) > $JAX_COMPILATION_CACHE_DIR, which jax
+    reads itself — then NO directory is set in code > the fixed
+    in-checkout default. An unusable directory raises."""
+    import jax
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs",
+        float(get_flag("FLAGS_compilation_cache_min_compile_secs")))
+    explicit = ("FLAGS_compilation_cache_dir" in _flags
+                or "FLAGS_compilation_cache_dir" in os.environ)
+    if not explicit and os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
     path = get_flag("FLAGS_compilation_cache_dir")
     if not path:
-        try:
-            import jax
-            jax.config.update("jax_compilation_cache_dir", None)
-        except Exception:
-            pass
+        jax.config.update("jax_compilation_cache_dir", None)
         return
-    try:
-        os.makedirs(path, exist_ok=True)
-        import jax
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs",
-            float(get_flag("FLAGS_compilation_cache_min_compile_secs")))
-    except Exception:  # unwritable dir/old jax: run without the cache
-        pass
+    os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", path)
+
 
 _flags = {}
 

@@ -15,9 +15,20 @@ import numpy as np
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-_SO = os.path.join(_ROOT, "runtime_cpp", "build", "libpaddle_tpu_runtime.so")
+_SRC = os.path.join(_ROOT, "runtime_cpp")
+_SO = os.path.join(_SRC, "build", "libpaddle_tpu_runtime.so")
 _lib = None
 _lock = threading.Lock()
+
+
+def _stale():
+    """The .so is git-ignored: what runs must be built from the
+    committed sources, so rebuild whenever one is newer than it."""
+    if not os.path.exists(_SO):
+        return True
+    built = os.path.getmtime(_SO)
+    return any(os.path.getmtime(os.path.join(_SRC, f)) > built
+               for f in ("runtime.cc", "Makefile"))
 
 
 def _load():
@@ -25,10 +36,9 @@ def _load():
     with _lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_SO):
+        if _stale():
             try:
-                subprocess.run(["make", "-C",
-                                os.path.join(_ROOT, "runtime_cpp")],
+                subprocess.run(["make", "-C", _SRC],
                                check=True, capture_output=True)
             except (subprocess.CalledProcessError, FileNotFoundError) as e:
                 raise RuntimeError(f"native runtime build failed: {e}")

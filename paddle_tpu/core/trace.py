@@ -38,6 +38,15 @@ def current_trace():
     return getattr(_state, "trace", None)
 
 
+def in_compiled_step():
+    """True while a compiled (to_static) step is being traced — the only
+    place mesh-wide sharding is applied: eager and record phases stay on
+    one device (no eager sub-group collectives, and a multi-device eager
+    program cannot hold a Mosaic kernel)."""
+    ctx = current_trace()
+    return ctx is not None and ctx.mode == "jit"
+
+
 def adopt(tensor):
     """Register a freshly constructed constant Tensor with the innermost
     active trace when its value is a tracer.

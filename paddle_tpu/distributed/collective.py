@@ -22,7 +22,6 @@ If the group spans a single device, collectives are identities, matching
 single-process paddle.
 """
 import jax
-from ..core.jax_compat import shard_map as _shard_map
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -139,7 +138,7 @@ def _eager_collective(x, group, per_shard_fn, out_spec_fn=None):
         return per_shard_fn(x, single=True)
     in_spec = P(axis)
     out_spec = out_spec_fn(axis) if out_spec_fn is not None else P(axis)
-    fn = _shard_map(lambda v: per_shard_fn(v, single=False),
+    fn = jax.shard_map(lambda v: per_shard_fn(v, single=False),
                        mesh=mesh, in_specs=(in_spec,), out_specs=out_spec)
     return fn(x)
 

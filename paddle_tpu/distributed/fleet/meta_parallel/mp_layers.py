@@ -75,8 +75,7 @@ def shard_constraint(t, spec, mesh=None):
     CPU backend deadlocks on those, and on TPU they would serialize);
     GSPMD materializes all sharding when the step compiles."""
     from ....core import trace as trace_mod
-    ctx = trace_mod.current_trace()
-    if ctx is None or ctx.mode != "jit":
+    if not trace_mod.in_compiled_step():
         return t
     mesh = mesh or topology.get_mesh()
     if mesh is None:

@@ -97,6 +97,15 @@ def _launch_collective(nproc, script, script_args, coordinator=None,
     their next collective waiting for it."""
     import subprocess
     import time
+    import jax
+    if nproc > 1 and jax.default_backend() == "tpu":
+        # the children get no per-process chip binding: each would
+        # claim every local chip and all but one would fail or hang
+        raise SystemExit(
+            f"launch: --nproc_per_node {nproc} is not supported on a "
+            f"TPU host: a chip belongs to one process. Run ONE process "
+            f"and drive all local chips through the mesh "
+            f"(fleet.init with hybrid_configs).")
     if coordinator is None:
         coordinator = f"127.0.0.1:{_free_port()}"
     base = dict(os.environ)

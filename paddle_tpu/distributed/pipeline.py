@@ -19,7 +19,6 @@ the analogue of the reference's per-microbatch scope recycling.
 import functools
 
 import jax
-from ..core.jax_compat import shard_map as _shard_map
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -84,7 +83,7 @@ def pipeline_apply(stage_fn, stacked_params, x_microbatches, mesh,
             jnp.where(idx == pp - 1, outs, jnp.zeros_like(outs)), axis_name)
         return outs
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis_name), stacked_params),
                   P()),
@@ -171,7 +170,7 @@ def pipeline_blocks_apply(block_fn, stacked_params, valid, h, microbatches,
             jnp.where(idx == pp - 1, outs, jnp.zeros_like(outs)), axis_name)
         return outs
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis_name), stacked_params),
                   P(axis_name), P()),

@@ -1378,14 +1378,13 @@ def _fluid_unsupported(name, why):
     def stub(*a, **k):
         from ..core.errors import UnimplementedError
         raise UnimplementedError(
-            f"fluid.layers.{name}: {why} (explicitly descoped — see "
-            "PARITY.md 'Known descopes')")
+            f"fluid.layers.{name}: {why} (explicitly descoped)")
     stub.__name__ = name
     return stub
 
 
-# CTR-pipeline / niche kernels intentionally not rebuilt (documented in
-# PARITY.md): each names its modern replacement or rationale.
+# CTR-pipeline / niche kernels intentionally not rebuilt: each names
+# its modern replacement or rationale.
 im2sequence = _fluid_unsupported(
     "im2sequence", "use unfold() (im2col) + sequence ops")
 row_conv = _fluid_unsupported(
@@ -1896,8 +1895,8 @@ def _descoped_construct(name, reason):
     def stub(*a, **k):
         from ..core.errors import UnimplementedError
         raise UnimplementedError(
-            f"fluid.layers.{name} is explicitly descoped on TPU "
-            f"(PARITY.md 'Known descopes'): {reason}")
+            f"fluid.layers.{name} is explicitly descoped on TPU: "
+            f"{reason}")
     stub.__name__ = name
     return stub
 

@@ -67,6 +67,15 @@ def _rebuild(struct, leaf_iter):
     return struct[1]
 
 
+def captured_arrays(compiled):
+    """(mutated, read-only) captured state of a compiled entry as jax
+    arrays, in the order its jitted callable takes them."""
+    mset = set(compiled["mut_cap_idx"])
+    captured = compiled["captured"]
+    return ([captured[i].value for i in compiled["mut_cap_idx"]],
+            [t.value for i, t in enumerate(captured) if i not in mset])
+
+
 class TracedFunction:
     def __init__(self, fn, input_spec=None, warmup=1, enable_ast=True):
         if enable_ast and not getattr(fn, "__wrapped_dy2static__", False):
@@ -217,10 +226,7 @@ class TracedFunction:
     # -- phase 3: run compiled --------------------------------------------
     def _run_compiled(self, entry, struct, leaves):
         c = entry["compiled"]
-        captured = c["captured"]
-        mset = set(c["mut_cap_idx"])
-        mut_caps = [captured[i].value for i in c["mut_cap_idx"]]
-        ro_caps = [t.value for i, t in enumerate(captured) if i not in mset]
+        mut_caps, ro_caps = captured_arrays(c)
         arg_arrays = [t.value for t in leaves]
         try:
             out_arrays, mut_arrays, grad_arrays = c["jitted"](
@@ -266,11 +272,7 @@ class TracedFunction:
                 continue
             arg_sds = [jax.ShapeDtypeStruct(shape, np.dtype(dtype))
                        for shape, dtype in avals]
-            mset = set(c["mut_cap_idx"])
-            mut_caps = [c["captured"][i].value for i in c["mut_cap_idx"]]
-            ro_caps = [t.value for i, t in enumerate(c["captured"])
-                       if i not in mset]
-            args = (arg_sds, mut_caps, ro_caps)
+            args = (arg_sds, *captured_arrays(c))
             closed = jax.make_jaxpr(c["fn"])(*args)
             findings.extend(lint_mod.lint_jaxpr(
                 closed, passes=passes,

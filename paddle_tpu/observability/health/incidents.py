@@ -4,8 +4,7 @@
 firing — last-K ledger rows, metrics snapshot, active request traces,
 host-span tail, watchdog report, the detector's verdict — to a
 directory with keep-last-N rotation, so the evidence of WHAT the
-engine was doing at the moment of anomaly survives the process (the
-flight-data-recorder answer to BENCH_r05's unattributable wedge).
+engine was doing at the moment of anomaly survives the process.
 
 ``HealthMonitor`` is the per-engine orchestrator: the engine feeds it
 one ledger row per step; it appends to the ledger, evaluates every

@@ -34,7 +34,7 @@ from .ledger import (  # noqa: F401
     compact, compare, config_digest, make_row, prune, read_rows,
 )
 from .roofline import (  # noqa: F401
-    PAGED_GATHER_FACTOR, REF_HBM_BPS, REF_PEAK_FLOPS,
+    PAGED_GATHER_FACTOR,
     decode_step_model, hbm_bps_for, kv_read_bytes_per_token,
     roofline_floor,
 )

@@ -17,8 +17,8 @@ The roofline fraction joins three facts the stack already collects:
 the measured per-dispatch wall (here), the program's
 ``cost_analysis`` flops/bytes (watchdog.executable_cost, bound via
 ``bind_cost`` at compile time), and the device's peak FLOP/s + HBM
-bandwidth (set once via ``set_device``; unknown devices fall back to
-the v5e reference constants with ``device_peak: false``). fraction =
+bandwidth (set once via ``set_device``; a device with no known peaks —
+the CPU — reports every fraction as None). fraction =
 roofline floor / measured per-dispatch wall — the go/no-go yardstick
 ROADMAP direction #2 judges the Pallas paged-attention kernel by.
 
@@ -30,8 +30,7 @@ per sync (~1-2us/step) — probe-measured in the bench artifact's
 """
 import threading
 
-from .roofline import (REF_HBM_BPS, REF_PEAK_FLOPS, decode_step_model,
-                       roofline_floor)
+from .roofline import decode_step_model, roofline_floor
 
 __all__ = ["ProgramPerf", "disabled_perf_report",
            "disabled_spec_report", "format_program_key", "PERF_KEYS",
@@ -133,8 +132,8 @@ class ProgramPerf:
         self._lock = threading.Lock()
         self._programs = {}      # AOT key tuple -> _Program
         self._device = None
-        self._peak_flops = REF_PEAK_FLOPS
-        self._hbm_bps = REF_HBM_BPS
+        self._peak_flops = None
+        self._hbm_bps = None
         self._decode_model = None
         if not self.enabled:
             return
@@ -159,13 +158,11 @@ class ProgramPerf:
     def set_device(self, platform, kind, peak_flops=None,
                    hbm_bps=None):
         """Price the roofline: the device's peak FLOP/s and HBM
-        bytes/sec. Unknown values fall back to the v5e reference
-        constants — the report carries ``device_peak`` / ``device_hbm``
-        flags so a reference-priced fraction is never mistaken for a
-        real-device one."""
-        self._peak_flops = float(peak_flops) if peak_flops \
-            else REF_PEAK_FLOPS
-        self._hbm_bps = float(hbm_bps) if hbm_bps else REF_HBM_BPS
+        bytes/sec. A peak that is not known stays None (``device_peak``
+        / ``device_hbm`` false in the report) and the fractions that
+        need it report None — never another chip's number."""
+        self._peak_flops = float(peak_flops) if peak_flops else None
+        self._hbm_bps = float(hbm_bps) if hbm_bps else None
         self._device = {
             "platform": str(platform),
             "kind": str(kind),

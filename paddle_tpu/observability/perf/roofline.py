@@ -43,14 +43,6 @@ three-way split (``paged=True`` means ``layout="paged_xla"``);
 """
 import os
 
-# reference chip when the real device is unknown (CPU smoke runs, new
-# TPU generations before the tables learn them): v5e bf16 peak and HBM
-# bandwidth — the same constants tools/gpt_roofline.py budgets with.
-# Fractions computed against the reference are a machinery exercise,
-# not an absolute claim; report()s flag device_peak=False for them.
-REF_PEAK_FLOPS = 197e12
-REF_HBM_BPS = 819e9
-
 # published per-chip HBM bandwidth (bytes/sec) by PJRT device_kind
 # prefix — the companion of the engine's _PEAK_FLOPS_BY_KIND table
 _HBM_BPS_BY_KIND = (
@@ -88,21 +80,32 @@ def resolve_layout(paged=False, layout=None):
     return layout
 
 
-def hbm_bps_for(device_kind):
-    """HBM bandwidth (bytes/sec) for a PJRT device_kind; the
-    PADDLE_TPU_HBM_BPS env var covers unknown kinds; None when
-    nothing is known (callers fall back to REF_HBM_BPS and flag it)."""
+def peak_for(device_kind, table, env_var):
+    """Look a PJRT device_kind up in a (prefix, peak) table; the env
+    var covers kinds the table does not know. A TPU that neither
+    names is an ERROR — it is never priced with another chip's peaks —
+    and any other device (CPU) has no peak: None, and every fraction
+    computed from it reports None."""
     kind = str(device_kind).lower()
-    for prefix, bw in _HBM_BPS_BY_KIND:
+    for prefix, peak in table:
         if kind.startswith(prefix):
-            return bw
-    env = os.environ.get("PADDLE_TPU_HBM_BPS")
+            return peak
+    env = os.environ.get(env_var)
     if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
+        return float(env)
+    if kind.startswith("tpu"):
+        raise ValueError(
+            f"unknown TPU device_kind {device_kind!r}: add it to the "
+            f"peak tables (serving/engine.py _PEAK_FLOPS_BY_KIND, "
+            f"observability/perf/roofline.py _HBM_BPS_BY_KIND) or set "
+            f"${env_var}")
     return None
+
+
+def hbm_bps_for(device_kind):
+    """HBM bandwidth (bytes/sec) for a PJRT device_kind (see
+    ``peak_for``; $PADDLE_TPU_HBM_BPS covers unknown kinds)."""
+    return peak_for(device_kind, _HBM_BPS_BY_KIND, "PADDLE_TPU_HBM_BPS")
 
 
 def roofline_floor(flops, bytes_accessed, peak_flops, hbm_bps):

@@ -18,7 +18,9 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..core import trace as trace_mod
 from ..core.dispatch import register_op
+from .fused_ce import _TP_MESHES, _register_mesh
 from .pallas_compat import trace_32bit as _trace_32bit
 
 # tests flip this to run the Pallas kernels in interpret mode on CPU
@@ -356,10 +358,35 @@ def _flash_bwd(scale, causal, res, g):
 _flash_attention_core.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _flash_over_mesh(q, k, v, scale, causal, mesh):
+    """The Pallas kernels inside a multi-device (GSPMD) program: Mosaic
+    kernels cannot be partitioned automatically, so the call is wrapped
+    in a shard_map — batch over the data-parallel axes, heads over 'mp'
+    (the Megatron split the QKV projection already produced), where
+    they divide; attention is independent per (batch, head), so no
+    collective is needed inside."""
+    from jax.sharding import PartitionSpec as P
+    b, h = q.shape[0], q.shape[1]
+    batch_axes = tuple(a for a in ("dp", "sharding")
+                       if int(mesh.shape.get(a, 1)) > 1)
+    n_b = math.prod(int(mesh.shape[a]) for a in batch_axes)
+    mp = int(mesh.shape.get("mp", 1))
+    spec = P(batch_axes if batch_axes and b % n_b == 0 else None,
+             "mp" if mp > 1 and h % mp == 0 else None, None, None)
+    return jax.shard_map(
+        lambda q_, k_, v_: _flash_attention_core(q_, k_, v_, scale,
+                                                 causal),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)(q, k, v)
+
+
 @register_op("flash_attention")
-def _flash_op(q, k, v, mask, *, scale, causal):
+def _flash_op(q, k, v, mask, *, scale, causal, mesh_id=None):
     if mask is not None:
         return _reference_attention(q, k, v, mask, scale, causal)
+    if mesh_id is not None and _use_pallas(q):
+        return _flash_over_mesh(q, k, v, scale, causal,
+                                _TP_MESHES[mesh_id])
     return _flash_attention_core(q, k, v, scale, causal)
 
 
@@ -370,8 +397,16 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     is accepted via transpose by callers). Dropout inside attention is not
     fused; applied to weights only in the fallback path when requested."""
     sc = scale if scale is not None else 1.0 / math.sqrt(query.shape[-1])
+    # inside a compiled step over a multi-device mesh the kernel has to
+    # be told the mesh (see _flash_over_mesh)
+    mesh_id = None
+    if trace_mod.in_compiled_step():
+        from ..distributed import topology
+        mesh = topology.get_mesh()
+        if mesh is not None and mesh.size > 1:
+            mesh_id = _register_mesh(mesh)
     return _flash_op(query, key, value, attn_mask, scale=float(sc),
-                     causal=bool(is_causal))
+                     causal=bool(is_causal), mesh_id=mesh_id)
 
 
 def cached_slot_attention(q, k_cache, v_cache, lengths):
@@ -400,8 +435,11 @@ def cached_slot_attention(q, k_cache, v_cache, lengths):
     kpos = jnp.arange(cache_len)[None, None, :]
     s = jnp.where(kpos < lengths[:, None, None], s,
                   jnp.float32(-1e30))
+    # f32 accumulation, output in the query's dtype (what the Pallas
+    # paged kernel returns too; a no-op for f32)
     return jnp.einsum("shk,shkd->shd", jax.nn.softmax(s, axis=-1),
-                      v_cache, preferred_element_type=jnp.float32)
+                      v_cache, preferred_element_type=jnp.float32
+                      ).astype(q.dtype)
 
 
 def cached_paged_attention(q, k_cache, v_cache, block_tables, lengths):
@@ -466,7 +504,8 @@ def cached_slot_block_attention(q, k_cache, v_cache, qpos):
     s = jnp.where(kpos <= qpos[:, None, :, None], s,
                   jnp.float32(-1e30))
     return jnp.einsum("shtk,shkd->shtd", jax.nn.softmax(s, axis=-1),
-                      v_cache, preferred_element_type=jnp.float32)
+                      v_cache, preferred_element_type=jnp.float32
+                      ).astype(q.dtype)
 
 
 def cached_paged_block_attention(q, k_cache, v_cache, block_tables,

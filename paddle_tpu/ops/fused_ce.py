@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 
 from ..core.dispatch import register_op
-from ..core.jax_compat import shard_map as _shard_map
 from .pallas_compat import trace_32bit as _trace_32bit
 
 _BLOCK_T = int(_os.environ.get("PADDLE_FUSED_CE_BLOCK_T", "256"))
@@ -421,7 +420,7 @@ def _tp_fwd_impl(x, w_vh, labels, mesh_id, ignore_index):
         loss = jnp.where(valid, lse_g - ll_g, 0.0)
         return loss, lse_g
 
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(x_spec, w_spec, t_spec),
         out_specs=(t_spec, t_spec), check_vma=False)(x, w_vh, labels)
 
@@ -462,7 +461,7 @@ def _tp_bwd_impl(x, w_vh, labels, lse_g, g, mesh_id, ignore_index):
             dw = jax.lax.psum(dw, "dp")
         return dx, dw
 
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, w_spec, t_spec, t_spec, t_spec),
         out_specs=(x_spec, w_spec), check_vma=False)(

@@ -82,13 +82,6 @@ def kernel_viable(num_heads, head_dim, block_size, dtype):
     return block_size % sub == 0 and head_dim % 8 == 0
 
 
-def use_paged_kernel(q, k_cache):
-    """Trace-time guard over the actual operands (programs.py calls
-    this on the traced q/k so a dtype surprise falls back cleanly)."""
-    _, nh, hd = q.shape
-    return kernel_viable(nh, hd, k_cache.shape[2], q.dtype)
-
-
 def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                          acc_ref, m_ref, l_ref, *, block_size,
                          max_blocks):
@@ -115,8 +108,10 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         v = v_ref[...]
         hd = q.shape[-1]
         # scores [nh, BS] in f32; same scale and mask value as the
-        # fallback so masked softmax terms agree exactly
-        s = jnp.sum(q[:, None, :].astype(jnp.float32)
+        # fallback so masked softmax terms agree exactly. Cast BEFORE
+        # the [:, None, :]: Mosaic has no bf16 [1,nh,hd]->[nh,1,hd]
+        # shape cast ("unsupported shape cast"), the f32 one it has
+        s = jnp.sum(q.astype(jnp.float32)[:, None, :]
                     * k.astype(jnp.float32), axis=-1)
         s = s / jnp.sqrt(jnp.float32(hd))
         kpos = bi * jnp.int32(block_size) + jax.lax.broadcasted_iota(
@@ -194,7 +189,7 @@ def _paged_decode_32(q, k_cache, v_cache, block_tables, lengths):
 def paged_decode_attention(q, k_cache, v_cache, block_tables, lengths):
     """Drop-in for ``ops.attention.cached_paged_attention`` (same
     signature, same semantics) reading K/V blocks in place. Callers
-    check ``use_paged_kernel`` first; ``cached_paged_attention`` is
+    check ``kernel_viable`` first; ``cached_paged_attention`` is
     the bit-exact-fallback parity oracle."""
     # x64 guard shared by every Pallas entry point (pallas_compat)
     return _trace_32bit(_paged_decode_32)(q, k_cache, v_cache,

@@ -11,16 +11,10 @@ import functools
 
 import jax
 
-try:  # the jax.enable_x64 alias was removed from newer jax releases
-    _enable_x64 = jax.enable_x64
-except AttributeError:
-    from jax.experimental import enable_x64 as _enable_x64
-
-
 def trace_32bit(fn):
     """Run `fn` (a pallas_call builder) with x64 disabled."""
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
-        with _enable_x64(False):
+        with jax.enable_x64(False):
             return fn(*args, **kwargs)
     return wrapper

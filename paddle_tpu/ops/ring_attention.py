@@ -20,7 +20,6 @@ import functools
 import math
 
 import jax
-from ..core.jax_compat import shard_map as _shard_map
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -102,7 +101,7 @@ def ring_attention(q, k, v, mesh, axis_name="sp", causal=True, scale=None):
                              sp=sp, scale=sc, causal=causal)
     bspec, hspec = _bh_specs(mesh, q, axis_name)
     spec = P(bspec, hspec, axis_name, None)
-    fn = _shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
     return fn(q, k, v)
 
@@ -143,6 +142,6 @@ def ulysses_attention(q, k, v, mesh, axis_name="sp", causal=True, scale=None):
                              scale=sc, causal=causal)
     bspec, hspec = _bh_specs(mesh, q, axis_name, heads_groups=sp)
     spec = P(bspec, hspec, axis_name, None)
-    fn = _shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
     return fn(q, k, v)

@@ -73,10 +73,10 @@ from .paged.pool import TRASH_BLOCK
 from .scheduler import QUEUED, RUNNING, Request, StepScheduler
 
 # published per-chip peak FLOP/s (bf16) by PJRT device_kind prefix —
-# the denominator of the estimated-MFU gauge. Unknown kinds (CPU, new
-# TPUs before this table learns them) fall back to the
-# PADDLE_TPU_PEAK_FLOPS env var or ServingConfig(peak_flops=...), else
-# the MFU gauge reads 0 (unknown, never a made-up number).
+# the denominator of the estimated-MFU gauge. ServingConfig(peak_flops=)
+# or the PADDLE_TPU_PEAK_FLOPS env var cover kinds the table does not
+# know; an unknown TPU kind is an error, and on the CPU the MFU gauge
+# reads 0 (unknown, never a made-up number).
 _PEAK_FLOPS_BY_KIND = (
     ("tpu v6", 918e12),
     ("tpu v5p", 459e12),
@@ -104,17 +104,9 @@ def _weak_method(method, default):
 
 
 def _peak_flops_for(device_kind):
-    kind = str(device_kind).lower()
-    for prefix, peak in _PEAK_FLOPS_BY_KIND:
-        if kind.startswith(prefix):
-            return peak
-    env = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    return None
+    from ..observability.perf.roofline import peak_for
+    return peak_for(device_kind, _PEAK_FLOPS_BY_KIND,
+                    "PADDLE_TPU_PEAK_FLOPS")
 
 # kc/vc/pos are donated into every serving executable; backends without
 # donation support (CPU) warn once per compiled program — expected, not
@@ -484,6 +476,11 @@ class ServingEngine:
             raise ValueError(
                 f"prefill_chunk {self.chunk_len} exceeds the per-slot "
                 f"capacity {cache_len}")
+        # the KV pool holds what the model computes: K/V come out of
+        # the qkv projection in the weights' dtype, so a bf16 model
+        # gets a bf16 pool (an f32 pool would only store the same
+        # values at twice the bytes)
+        kv_dtype = self.params["stacked"]["qkv_w"].dtype
         if self.paged:
             from .paged import PagedKVPool
 
@@ -492,7 +489,7 @@ class ServingEngine:
                     config.num_slots, cfg.num_layers, cfg.num_heads,
                     cache_len, cfg.hidden_size // cfg.num_heads,
                     block_size=config.block_size,
-                    num_blocks=config.num_blocks)
+                    num_blocks=config.num_blocks, dtype=kv_dtype)
 
             self._pool_factory = _pool_factory
             self.pool = _pool_factory()
@@ -502,9 +499,20 @@ class ServingEngine:
             # the one compiled decode program, so signatures, AOT keys
             # and the zero-steady-state-compile contract are unchanged
             from ..ops.paged_attention import kernel_viable
-            self.paged_attn = bool(config.paged_attn) and kernel_viable(
-                cfg.num_heads, cfg.hidden_size // cfg.num_heads,
-                self.pool.block_size, self.pool.kc.dtype)
+            import jax
+            shape = (cfg.num_heads, cfg.hidden_size // cfg.num_heads,
+                     self.pool.block_size, self.pool.kc.dtype)
+            self.paged_attn = bool(config.paged_attn) \
+                and kernel_viable(*shape)
+            if config.paged_attn and not self.paged_attn \
+                    and jax.default_backend() != "cpu":
+                # asked for by name on a backend that has Mosaic: a
+                # quiet drop to the gather path would hide the refusal
+                raise ValueError(
+                    f"paged_attn was requested but the Pallas decode "
+                    f"kernel is not viable for (heads, head_dim, block, "
+                    f"pool dtype) = {shape}: see "
+                    f"ops.paged_attention.kernel_viable")
             self._prefill_fn, self._decode_fn = \
                 model.build_paged_serving_fns(
                     config.num_slots, self.pool.block_size,
@@ -523,7 +531,8 @@ class ServingEngine:
             def _pool_factory():
                 return SlotKVPool(
                     config.num_slots, cfg.num_layers, cfg.num_heads,
-                    cache_len, cfg.hidden_size // cfg.num_heads)
+                    cache_len, cfg.hidden_size // cfg.num_heads,
+                    dtype=kv_dtype)
 
             self._pool_factory = _pool_factory
             self.pool = _pool_factory()
@@ -798,9 +807,9 @@ class ServingEngine:
             self.metrics.set_prefix_pool(self.pool.stats)
             self.metrics.cache.attach_pool(self.pool)
         if self._perf_on:
-            # price the per-program roofline (unknown devices fall
-            # back to the v5e reference constants, flagged
-            # device_peak/device_hbm=false in the report) and attach
+            # price the per-program roofline (the CPU has no peaks:
+            # device_peak/device_hbm=false and None fractions in the
+            # report; an unknown TPU kind raised above) and attach
             # the analytic decode-step HBM model: the fixed-shape
             # pooled decode reads the WHOLE cache_len layout every
             # step, so kv_len is the per-slot capacity, not the live

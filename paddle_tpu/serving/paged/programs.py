@@ -54,9 +54,9 @@ greedy path is the default and keeps the original signatures.
 Pallas paged kernel (ops.paged_attention) that reads K/V blocks in
 place via scalar-prefetched table indices instead of materializing
 the gathered view — a trace-time branch, so the program key, its
-signature and the zero-steady-state-compile contract are unchanged;
-the ``use_paged_kernel`` guard still falls back to the XLA gather on
-unsupported operands.
+signature and the zero-steady-state-compile contract are unchanged.
+The caller (the engine) resolves ``kernel_viable`` once at build time;
+there is no second, quiet fallback here.
 """
 
 
@@ -167,7 +167,7 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
             # block: advanced indexing [S],:,[S] scatters [S, nh, hd]
             kcl = kcl.at[bidx, :, off].set(k)
             vcl = vcl.at[bidx, :, off].set(v)
-            if attn_kernel and paged_attn_ops.use_paged_kernel(q, kcl):
+            if attn_kernel:
                 o = paged_attn_ops.paged_decode_attention(
                     q, kcl, vcl, tables, pos + 1)
             else:

@@ -13,7 +13,7 @@ Two flavors behind one surface (``begin`` / ``health`` / ``state`` /
     ``serve_metrics(post_routes=)``). The over-the-wire path the
     kill-a-replica drill SIGKILLs mid-request.
 
-Failure taxonomy — the distinction the circuit breaker feeds on:
+Failure classes — the distinction the circuit breaker feeds on:
 
   * :class:`TransportError` — the replica is unreachable or died
     mid-request (connection refused/reset, gateway killed, timeout).
@@ -350,7 +350,7 @@ class EngineGateway:
 
     def handle_prefill(self, body):
         """``POST /v1/prefill``: run hop 1 and answer the serialized
-        handoff. 503 on refusal so :class:`_HTTPCall`'s taxonomy maps
+        handoff. 503 on refusal so :class:`_HTTPCall`'s failure classes map
         it to TransportRefused (clean no, breaker untouched)."""
         prompt = body.get("prompt")
         if (not isinstance(prompt, list) or not prompt

@@ -3,7 +3,7 @@
 The reference ships text datasets (python/paddle/text/datasets/) and the
 ERNIE/GPT model definitions live in external repos; here the flagship
 transformer models are first-class since they anchor the perf baselines
-(BASELINE.md configs 3 and 5).
+(the BERT-base and GPT training configs).
 """
 from .models import (  # noqa: F401
     BertModel, BertForPretraining, GPTModel, GPTForCausalLM, gpt3_1p3b,

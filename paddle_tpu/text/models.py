@@ -1,7 +1,7 @@
 """BERT / GPT model families.
 
 Reference anchors: BERT-base pretraining and GPT-3 1.3B hybrid-parallel
-configs (BASELINE.md #3/#5; reference TP layers
+configs (reference TP layers
 fleet/meta_parallel/parallel_layers/mp_layers.py). Models are built from
 paddle_tpu.nn layers; when a hybrid mesh is active, linear/embedding
 layers use the tensor-parallel variants so GSPMD shards them over 'mp'.
@@ -9,6 +9,7 @@ layers use the tensor-parallel variants so GSPMD shards them over 'mp'.
 import math
 
 from .. import nn
+from ..core import trace as trace_mod
 from ..ops import creation, manipulation, math as math_ops, nn_ops
 from ..distributed import topology
 from ..distributed.fleet.meta_parallel.mp_layers import (
@@ -258,8 +259,10 @@ def _decode_forward_builder(num_heads, head_dim, hidden_size):
         kpos = jnp.arange(total)[None, None, None, :]
         qpos = pos + jnp.arange(t)[None, None, :, None]
         s = jnp.where(kpos <= qpos, s, jnp.float32(-1e30))
+        # f32 scores/softmax; the output returns to the residual
+        # stream's dtype (a no-op for f32, keeps a bf16 model bf16)
         o = jnp.einsum("bhts,bhsd->bhtd",
-                       jax.nn.softmax(s, axis=-1), vc)
+                       jax.nn.softmax(s, axis=-1), vc).astype(x.dtype)
         o = o.transpose(0, 2, 1, 3).reshape(bb, t, hidden_size)
         x = x + (o @ p["out_w"] + p["out_b"])
         h2 = ln(x, p["ln2_w"], p["ln2_b"])
@@ -302,12 +305,20 @@ class GPTForCausalLM(nn.Layer):
         mesh = topology.get_mesh()
         mesh_trivial = mesh is None or all(
             int(d) == 1 for d in mesh.shape.values())
+        # only the compiled step spans the mesh: a shard_map in an eager
+        # phase would turn the whole lazily-fused eager step into a
+        # multi-device program
+        in_step = trace_mod.in_compiled_step()
+        eager_mp = self.cfg.use_mp and mesh is not None and not in_step
         if labels is not None and self.cfg.tie_embeddings \
-                and not self.cfg.use_mp and mesh_trivial:
+                and ((not self.cfg.use_mp and mesh_trivial) or eager_mp):
             # fused linear+CE streams vocab tiles through VMEM: the
             # [tokens, vocab] logits tensor never exists in HBM in
             # either direction (ops/fused_ce.py; falls back to the
-            # composition below on CPU / unsupported shapes).
+            # composition below on CPU / unsupported shapes). Also the
+            # one-device head of a TP model's eager phases: unfused,
+            # they keep ~8 GiB of logits-sized intermediates live at
+            # GPT-124M x 8192 tokens.
             from ..ops.fused_ce import fused_linear_cross_entropy
             flat = manipulation.reshape(labels, (-1,))
             per_tok = fused_linear_cross_entropy(
@@ -319,7 +330,7 @@ class GPTForCausalLM(nn.Layer):
             valid = (flat != -100).astype("float32").sum()
             return per_tok.sum() / valid.clip(min=1.0)
         if labels is not None and self.cfg.tie_embeddings \
-                and self.cfg.use_mp and mesh is not None:
+                and self.cfg.use_mp and mesh is not None and in_step:
             # TP: the vocab-sharded fused kernel — each mp shard
             # streams its LOCAL vocab tile through VMEM, then
             # pmax/psum combine the per-shard logsumexp (the
@@ -678,7 +689,10 @@ class GPTForCausalLM(nn.Layer):
             return jax.random.categorical(key, lg).astype(jnp.int32)
 
         def decode(pr, ids, key, temp):
-            kc = jnp.zeros((L, b, nh, total, hd), jnp.float32)
+            # the cache holds K/V as the qkv projection computes them:
+            # in the weights' dtype (the serving engine's rule too)
+            kc = jnp.zeros((L, b, nh, total, hd),
+                           pr["stacked"]["qkv_w"].dtype)
             vc = jnp.zeros_like(kc)
             logits, kc, vc = forward_t(pr, ids, jnp.int32(0), kc, vc)
             key, sub = jax.random.split(key)
@@ -706,7 +720,8 @@ class GPTForCausalLM(nn.Layer):
             # (reference analogue: fluid beam_search op + gather_tree —
             # here the whole search is one scanned program; beams are a
             # batch*K batch dim, caches re-gathered by beam each step)
-            kc = jnp.zeros((L, b, nh, total, hd), jnp.float32)
+            kc = jnp.zeros((L, b, nh, total, hd),
+                           pr["stacked"]["qkv_w"].dtype)
             vc = jnp.zeros_like(kc)
             logits, kc, vc = forward_t(pr, ids, jnp.int32(0), kc, vc)
             lp0 = jax.nn.log_softmax(logits[:, -1])        # [b, V]
@@ -863,7 +878,8 @@ def bert_base(vocab_size=30522, max_seq_len=512, **kwargs):
 
 
 def gpt3_1p3b(vocab_size=50304, max_seq_len=1024, **kwargs):
-    """GPT-3 1.3B: 24 layers, hidden 2048, 16 heads (BASELINE config 5)."""
+    """GPT-3 1.3B: 24 layers, hidden 2048, 16 heads (the hybrid-parallel
+    GPT config)."""
     cfg = TransformerLMConfig(vocab_size=vocab_size, hidden_size=2048,
                               num_layers=24, num_heads=16,
                               max_seq_len=max_seq_len, **kwargs)

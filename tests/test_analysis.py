@@ -202,7 +202,7 @@ def test_created_ids_are_liveness_checked():
 # ---------------------------------------------------------------------------
 
 def test_f64_upcast_positive_and_negative():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         pos = lint_fn(lambda x: x.astype(jnp.float64) * 2.0,
                       jax.ShapeDtypeStruct((4,), jnp.float32),
                       passes=["f64-upcast"])
@@ -280,7 +280,7 @@ def test_host_callback_positive_and_negative():
 def test_lint_walks_nested_subjaxprs():
     """Findings inside cond branches / while bodies are reached (the
     pass walks every sub-jaxpr, not just the top level)."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         def f(x):
             return jax.lax.cond(x[0] > 0,
                                 lambda v: v.astype(jnp.float64).sum(),

@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.core.jax_compat import shard_map as _shard_map
 import paddle_tpu.nn as nn
 import paddle_tpu.nn.functional as F
 
@@ -408,13 +407,13 @@ def test_send_recv_spmd_edge():
         return out.value
 
     x = jnp.arange(8, dtype=jnp.float32).reshape(8, 1)
-    out = _shard_map(body, mesh=mesh, in_specs=(P("dp"),),
+    out = jax.shard_map(body, mesh=mesh, in_specs=(P("dp"),),
                         out_specs=P("dp"))(x)
     res = np.asarray(out).reshape(-1)
     assert res[3] == 1.0          # rank 3 received rank 1's value
     assert res[1] == 0.0          # non-destination ranks zeroed
     with pytest.raises(Exception):
-        _shard_map(
+        jax.shard_map(
             lambda v: collective.recv(
                 __import__("paddle_tpu").core.tensor.Tensor(v),
                 src=1, group=g).value,

@@ -268,18 +268,64 @@ def test_hooks_with_create_graph_raise():
         paddle.grad(z, x, create_graph=True)
 
 
-def test_set_flags_reapplies_compilation_cache():
+@pytest.fixture
+def cache_flags(monkeypatch):
+    """core.flags with no explicit FLAGS_compilation_cache_dir; the
+    flag and jax's setting are put back afterwards."""
+    import jax
+    from paddle_tpu.core import flags
+    key = "FLAGS_compilation_cache_dir"
+    was_dir = jax.config.jax_compilation_cache_dir
+    was_flag = flags._flags.pop(key, None)
+    monkeypatch.delenv(key, raising=False)
+    yield flags
+    flags._flags.pop(key, None)
+    if was_flag is not None:
+        flags._flags[key] = was_flag
+    jax.config.update("jax_compilation_cache_dir", was_dir)
+
+
+def test_set_flags_reapplies_compilation_cache(cache_flags):
     import jax
     import paddle_tpu as paddle
-    old = paddle.get_flags(["FLAGS_compilation_cache_dir"])[
-        "FLAGS_compilation_cache_dir"]
-    try:
-        paddle.set_flags({"FLAGS_compilation_cache_dir": ""})
-        assert jax.config.jax_compilation_cache_dir is None
-        paddle.set_flags({"FLAGS_compilation_cache_dir": "/tmp/ptpu_cache_t"})
-        assert jax.config.jax_compilation_cache_dir == "/tmp/ptpu_cache_t"
-    finally:
-        paddle.set_flags({"FLAGS_compilation_cache_dir": old})
+    paddle.set_flags({"FLAGS_compilation_cache_dir": ""})
+    assert jax.config.jax_compilation_cache_dir is None
+    paddle.set_flags({"FLAGS_compilation_cache_dir": "/tmp/ptpu_cache_t"})
+    assert jax.config.jax_compilation_cache_dir == "/tmp/ptpu_cache_t"
+
+
+def test_cache_dir_from_jax_env_is_not_overridden(cache_flags, monkeypatch):
+    """$JAX_COMPILATION_CACHE_DIR places the cache from outside: jax
+    reads it itself and the framework sets no directory in code."""
+    import jax
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")
+    jax.config.update("jax_compilation_cache_dir", "sentinel")
+    cache_flags.init_compilation_cache()
+    assert jax.config.jax_compilation_cache_dir == "sentinel"
+    # ... but the explicit user flag still wins over it
+    import paddle_tpu as paddle
+    paddle.set_flags({"FLAGS_compilation_cache_dir": ""})
+    assert jax.config.jax_compilation_cache_dir is None
+
+
+def test_cache_dir_default_is_fixed_path_in_checkout(cache_flags,
+                                                     monkeypatch):
+    import os
+    import jax
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    cache_flags.init_compilation_cache()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert jax.config.jax_compilation_cache_dir == os.path.join(
+        repo, ".jax_cache")
+
+
+def test_unusable_cache_dir_raises(cache_flags, tmp_path):
+    import paddle_tpu as paddle
+    blocker = tmp_path / "a_file"
+    blocker.write_text("")
+    with pytest.raises(OSError):
+        paddle.set_flags(
+            {"FLAGS_compilation_cache_dir": str(blocker / "sub")})
 
 
 def test_grad_failure_restores_accumulated_grads():

@@ -370,7 +370,7 @@ def test_elastic_kill_relaunch_resume(tmp_path):
         mgr.stop()
 
 
-def test_error_taxonomy():
+def test_error_classes():
     """Reference: platform/enforce.h:427 + error_codes.proto — typed
     error classes that also subclass the natural builtin so existing
     except-clauses keep working."""

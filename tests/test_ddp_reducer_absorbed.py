@@ -12,32 +12,16 @@ the reference's 25MB heuristic approximates."""
 import numpy as np
 import jax
 import jax.numpy as jnp
-import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import paddle_tpu as paddle
 import paddle_tpu.nn as nn
-
-# The combiner is an XLA backend pass: TPU/GPU pipelines run
-# all-reduce-combiner before codegen, the CPU pipeline (jaxlib 0.4.x)
-# does not — every per-parameter all-reduce survives to the optimized
-# HLO and the O(1)-collectives assertion below can't hold. The property
-# under test is real on the backends the Reducer absorption argument is
-# about; xfail (not skip) on CPU so a future jaxlib that combines on
-# CPU surfaces as XPASS.
-_cpu_no_combiner = pytest.mark.xfail(
-    jax.default_backend() == "cpu",
-    reason="XLA:CPU runs no all-reduce-combiner pass — per-param "
-           "all-reduces never fuse on this backend (TPU/GPU do)",
-    strict=True)
-
 
 def _mesh():
     return Mesh(np.array(jax.devices()), ("dp",))
 
 
 class TestReducerAbsorbed:
-    @_cpu_no_combiner
     def test_substrate_combines_grad_allreduces(self):
         """12 parameters' dp-grad reductions -> ONE all-reduce in the
         optimized HLO (XLA all-reduce combiner)."""
@@ -69,7 +53,6 @@ class TestReducerAbsorbed:
         assert n_ar <= 2, (
             f"{n_ar} all-reduces for 12 params — combiner not engaged")
 
-    @_cpu_no_combiner
     def test_paddle_dp_train_step_hlo(self):
         """The same property through the paddle surface: a DP train step
         (model + SGD via the op registry) compiles to O(1) fused grad

@@ -77,6 +77,17 @@ def _spawn(nproc, local_devices, mode="dp"):
     return outs
 
 
+def test_launcher_refuses_nproc_gt1_on_tpu_host(monkeypatch):
+    """On a TPU host the children get no per-chip binding — each would
+    claim every chip and all but one would fail or hang; the launcher
+    says so instead (one process drives all chips through the mesh)."""
+    import jax
+    from paddle_tpu.distributed import launch_mod
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(SystemExit, match="ONE process"):
+        launch_mod._launch_collective(2, _WORKER, [])
+
+
 def test_launcher_nproc_per_node_collective():
     """`launch_mod --nproc_per_node 2 worker.py` spawns the loopback
     multi-controller run (reference: fleet/launch.py collective mode)."""

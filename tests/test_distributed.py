@@ -8,7 +8,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 import paddle_tpu as paddle
-from paddle_tpu.core.jax_compat import shard_map as _shard_map
 import paddle_tpu.nn as nn
 from paddle_tpu.distributed import topology, fleet, collective
 from paddle_tpu.distributed.fleet import DistributedStrategy
@@ -52,7 +51,7 @@ def test_collectives_inside_shard_map(hybrid_mesh):
         return s
 
     x = jnp.arange(8.0)
-    out = jax.jit(_shard_map(body, mesh=mesh,
+    out = jax.jit(jax.shard_map(body, mesh=mesh,
                                 in_specs=P("dp"), out_specs=P("dp")))(x)
     # dp=2: halves summed pairwise across dp groups
     assert out.shape == (8,)
@@ -168,7 +167,7 @@ def test_spmd_collective_ops_via_shard_map(hybrid_mesh):
                     jnp.tile(x, (2,)), "mp", scatter_dimension=0, tiled=True))
 
     x = jnp.arange(16.0)
-    outs = jax.jit(_shard_map(
+    outs = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P("mp"),
         out_specs=(P("mp"), P(None, "mp"), P("mp"))))(x)
     assert all(np.isfinite(np.asarray(o)).all() for o in outs)
@@ -252,7 +251,7 @@ def test_collective_edge_semantics(hybrid_mesh):
     collective.all_reduce(t2, op=collective.ReduceOp.PROD, group=g)
     np.testing.assert_allclose(t2.numpy(), [[6.0], [6.0]])
     mesh = hybrid_mesh.mesh
-    out = jax.jit(_shard_map(
+    out = jax.jit(jax.shard_map(
         lambda x: collective._spmd_allreduce.fn(x, axis="dp", op="prod"),
         mesh=mesh, in_specs=P("dp"), out_specs=P("dp")))(
             jnp.asarray([2.0, 3.0]))

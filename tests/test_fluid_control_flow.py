@@ -141,11 +141,11 @@ def test_static_rnn_with_initial_memory(static_mode):
     np.testing.assert_allclose(res, np.stack(expect), rtol=1e-6)
 
 
-def test_descoped_constructs_point_to_parity(static_mode):
+def test_descoped_constructs_say_so(static_mode):
     from paddle_tpu.core.errors import UnimplementedError
     for ctor in (layers.Switch, layers.IfElse, layers.DynamicRNN,
                  layers.reorder_lod_tensor_by_rank):
-        with pytest.raises(UnimplementedError, match="PARITY.md"):
+        with pytest.raises(UnimplementedError, match="explicitly descoped"):
             ctor()
 
 

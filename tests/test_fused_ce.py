@@ -309,15 +309,16 @@ def test_tp_fused_pallas_interpret_parity(interpret_kernels):
                                rtol=3e-4, atol=3e-6)
 
 
-def test_gpt_mp_head_takes_fused_tp_path():
+def test_gpt_mp_head_takes_fused_tp_path(monkeypatch):
     """GPT with mp>1 routes through the vocab-sharded fused head (the
-    r4 verdict's Missing #5: exactly the large-vocab configs that need
-    TP lost the fused win), with loss parity vs the unfused TP
-    composition, and trains through it."""
+    large-vocab configs that need TP keep the fused win) INSIDE the
+    compiled step only: eager phases stay on one device (a shard_map
+    there would make the whole lazily-fused eager step a multi-device
+    program, which Mosaic kernels refuse on the chip). Loss parity vs
+    the unfused composition, and it trains through it."""
     from paddle_tpu.distributed import fleet, topology
     from paddle_tpu.distributed.fleet import DistributedStrategy
-    from paddle_tpu.ops import manipulation, nn_ops
-    from paddle_tpu.text import models as text_models
+    from paddle_tpu.ops import manipulation
     from paddle_tpu.text.models import (GPTForCausalLM,
                                         TransformerLMConfig)
 
@@ -331,8 +332,8 @@ def test_gpt_mp_head_takes_fused_tp_path():
                                   num_layers=2, num_heads=2,
                                   max_seq_len=16, dropout=0.0,
                                   use_mp=True)
-        model = GPTForCausalLM(cfg)
-        model = fleet.distributed_model(model)
+        inner = GPTForCausalLM(cfg)
+        model = fleet.distributed_model(inner)
         rs = np.random.RandomState(0)
         ids = paddle.to_tensor(rs.randint(0, 128, (4, 16))
                                .astype(np.int64))
@@ -341,26 +342,20 @@ def test_gpt_mp_head_takes_fused_tp_path():
 
         calls = []
         orig = fused_ce.fused_linear_cross_entropy_tp
+        monkeypatch.setattr(
+            fused_ce, "fused_linear_cross_entropy_tp",
+            lambda *a, **k: calls.append(1) or orig(*a, **k))
 
-        def spy(*a, **k):
-            calls.append(1)
-            return orig(*a, **k)
-
-        fused_ce.fused_linear_cross_entropy_tp = spy
-        try:
-            loss_fused = model(ids, labels=labels)
-        finally:
-            fused_ce.fused_linear_cross_entropy_tp = orig
-        assert calls, "mp GPT head did not take the fused TP path"
-
-        inner = model._layers              # unwrap TensorParallel
-        h = inner.gpt(ids)
-        logits = inner._head_loss(h)       # labels=None -> logits
-        loss_ref = nn_ops.cross_entropy(
-            manipulation.reshape(logits, (-1, 128)),
-            manipulation.reshape(labels, (-1,)))
-        np.testing.assert_allclose(float(loss_fused.numpy()),
-                                   float(loss_ref.numpy()), rtol=1e-5)
+        loss_eager = model(ids, labels=labels)
+        assert not calls, "the eager head ran the mesh-wide shard_map"
+        assert len(loss_eager.value.devices()) == 1
+        # the fused TP head computes the same loss as that composition
+        mesh = fleet.get_hybrid_communicate_group().mesh
+        flat = manipulation.reshape(labels, (-1,))
+        per_tok = orig(manipulation.reshape(inner.gpt(ids), (-1, 32)),
+                       inner.gpt.word_embeddings.weight, flat, mesh)
+        np.testing.assert_allclose(float(per_tok.mean().numpy()),
+                                   float(loss_eager.numpy()), rtol=1e-5)
 
         opt = fleet.distributed_optimizer(paddle.optimizer.AdamW(
             1e-2, parameters=model.parameters()))
@@ -373,8 +368,11 @@ def test_gpt_mp_head_takes_fused_tp_path():
             opt.clear_grad()
             return loss
 
-        losses = [float(train_step(ids, labels).numpy())
-                  for _ in range(4)]
+        losses = []
+        for i in range(4):
+            losses.append(float(train_step(ids, labels).numpy()))
+            assert bool(calls) == (i >= 2), \
+                "fused TP head must be taken by the compiled step only"
         assert np.isfinite(losses).all() and losses[-1] < losses[0]
     finally:
         topology._HYBRID = None

@@ -508,7 +508,11 @@ _TENANT_ENTRY_KEYS = {
 }
 
 
-def test_serving_snapshot_schema_contract():
+def test_serving_snapshot_schema_contract(monkeypatch):
+    # the CPU has no peaks of its own; state some so the roofline
+    # join (cost x measured wall x peaks) is exercised
+    monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "197e12")
+    monkeypatch.setenv("PADDLE_TPU_HBM_BPS", "819e9")
     m = _model()
     eng = ServingEngine(m, num_slots=2, bucket_min=8)
     _drive(eng, np.random.RandomState(1), [(4, 3), (9, 4), (6, 3)])

@@ -67,7 +67,7 @@ def test_kernel_matches_gather_oracle(interpret_kernel, S, nh, hd, BS,
     dt = jnp.dtype(dtype)
     q, kc, vc = (jnp.asarray(q, dt), jnp.asarray(kc, dt),
                  jnp.asarray(vc, dt))
-    assert pa.use_paged_kernel(q, kc)
+    assert pa.kernel_viable(nh, hd, BS, dt)
     ref = attn_ops.cached_paged_attention(q, kc, vc,
                                           jnp.asarray(tables),
                                           jnp.asarray(lens))
@@ -191,11 +191,16 @@ def _ref(m, prompt, n_new):
 
 @pytest.mark.parametrize("async_depth", [0, 1])
 def test_engine_kernel_greedy_parity_zero_compiles(interpret_kernel,
-                                                   async_depth):
+                                                   async_depth,
+                                                   monkeypatch):
     """Engine-level contract with the gate on (sync and async
     schedules): every stream bit-exact with generate(), zero
     steady-state compiles (watchdog raise-mode), and the perf report
     binds the paged_pallas layout + a decode roofline fraction."""
+    # the CPU has no peaks of its own; state some so the roofline
+    # join (cost x measured wall x peaks) is exercised
+    monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "197e12")
+    monkeypatch.setenv("PADDLE_TPU_HBM_BPS", "819e9")
     m = _tiny_model()
     eng = ServingEngine(m, num_slots=4, bucket_min=8, paged=True,
                         block_size=8, paged_attn=True,

@@ -103,10 +103,46 @@ def test_decode_step_model_accounting():
 
 def test_hbm_table_and_env_override(monkeypatch):
     assert hbm_bps_for("TPU v5e chip") == 819e9
+    assert hbm_bps_for("TPU v5 lite") == 819e9
     assert hbm_bps_for("TPU v4") == 1228e9
     assert hbm_bps_for("cpu") is None
     monkeypatch.setenv("PADDLE_TPU_HBM_BPS", "123e9")
     assert hbm_bps_for("cpu") == 123e9
+
+
+@pytest.mark.parametrize("lookup", ["hbm", "flops"])
+def test_unknown_tpu_kind_raises_never_priced_as_v5e(monkeypatch, lookup):
+    """A TPU the peak tables do not know is an error — not a v5e."""
+    from paddle_tpu.serving.engine import _peak_flops_for
+    fn, env = {"hbm": (hbm_bps_for, "PADDLE_TPU_HBM_BPS"),
+               "flops": (_peak_flops_for, "PADDLE_TPU_PEAK_FLOPS")}[lookup]
+    monkeypatch.delenv(env, raising=False)
+    with pytest.raises(ValueError, match="unknown TPU device_kind"):
+        fn("TPU v9 hyper")
+    monkeypatch.setenv(env, "1e12")      # the stated override covers it
+    assert fn("TPU v9 hyper") == 1e12
+
+
+def test_cpu_engine_reports_no_roofline_fraction(monkeypatch):
+    """No peaks are known for the CPU: every device-referenced fraction
+    is None (never computed against a reference chip), the measured
+    times still report."""
+    monkeypatch.delenv("PADDLE_TPU_PEAK_FLOPS", raising=False)
+    monkeypatch.delenv("PADDLE_TPU_HBM_BPS", raising=False)
+    eng = ServingEngine(_model(), num_slots=2, bucket_min=8)
+    eng.add_request(np.arange(1, 6, dtype=np.int64), max_new_tokens=4)
+    eng.run()
+    rep = eng.metrics.perf_report()
+    assert rep["device"]["device_peak"] is False
+    assert rep["device"]["device_hbm"] is False
+    assert rep["device"]["peak_flops"] is None
+    dec = rep["programs"]["decode"]
+    assert dec["avg_ms"] > 0
+    assert dec["roofline_fraction"] is None
+    assert dec["roofline_floor_ms"] is None
+    assert rep["decode_roofline"]["achieved_fraction"] is None
+    assert rep["decode_roofline"]["model"]["floor_s"] is None
+    eng.close()
 
 
 def test_gpt_roofline_cli_decode_mode():
@@ -159,7 +195,11 @@ def _drive(eng, rs, specs):
 
 
 @pytest.mark.parametrize("paged", [False, True])
-def test_program_attribution_sums_to_step_total(paged):
+def test_program_attribution_sums_to_step_total(paged, monkeypatch):
+    # the CPU has no peaks of its own; state some so the roofline
+    # join (cost x measured wall x peaks) is exercised
+    monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "197e12")
+    monkeypatch.setenv("PADDLE_TPU_HBM_BPS", "819e9")
     """Satellite acceptance: a two-bucket prefill + chunked + decode
     drain yields DISTINCT program keys whose summed measured time is
     tolerance-pinned against the serving/step span total, on both

@@ -4,7 +4,6 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.core.jax_compat import shard_map as _shard_map
 from paddle_tpu.distributed.fleet.dataset import InMemoryDataset, QueueDataset
 from paddle_tpu.distributed.fleet.distributed_embedding import (
     DistributedEmbedding, HostEmbedding, HostEmbeddingTable)
@@ -149,7 +148,7 @@ def test_c_embedding_manual_spmd_lookup():
         start = rank * (vocab // n)
         return c_embedding(ids_rep, w_local, "mp", start)
 
-    out = _shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P("mp", None), P()),
         out_specs=P())(jnp.asarray(w), jnp.asarray(ids))

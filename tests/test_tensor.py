@@ -137,3 +137,18 @@ def test_tensor_iteration_protocol():
         iter(s)
     with pytest.raises(TypeError):
         len(s)
+
+
+def test_set_device_tpu_without_a_tpu_is_an_error():
+    """Asking for the TPU by name never hands out a quiet CPU place;
+    the compat aliases ('gpu', ...) still map to whatever is there."""
+    import pytest
+    from paddle_tpu.core import device
+    before = device._current_place
+    try:
+        with pytest.raises(RuntimeError, match="no TPU present"):
+            paddle.set_device("tpu:0")
+        assert paddle.set_device("gpu:0").is_cpu_place()
+        assert paddle.set_device("cpu").is_cpu_place()
+    finally:
+        device._current_place = before
