@@ -658,11 +658,18 @@ DEFAULT_AUDIT_ALLOW = (
             ("serving/router/transport.py", r"self\._lock = threading\.RLock\(\)"),
             (
                 "serving/router/transport.py",
-                r"def submit\((.|\n){0,1200}?with self\._lock",
+                r"def submit\((.|\n){0,1400}?"
+                r"with self\._locked_for_submission\(\)",
             ),
             (
                 "serving/router/transport.py",
-                r"with self\._lock:\n(.|\n){0,200}?"
+                r"def _locked_for_submission\((.|\n){0,700}?"
+                r"self\._lock\.acquire\(\)\n(.|\n){0,200}?"
+                r"finally:\n\s+self\._lock\.release\(\)",
+            ),
+            (
+                "serving/router/transport.py",
+                r"with self\._lock:\n(.|\n){0,400}?"
                 r"worked = bool\(self\.engine\.step\(\)\)",
             ),
         ),

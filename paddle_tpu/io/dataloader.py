@@ -14,6 +14,7 @@ import threading
 
 import numpy as np
 
+from .. import profiler as _profiler
 from .dataset import IterableDataset
 from .sampler import BatchSampler, DistributedBatchSampler  # noqa: F401
 
@@ -35,6 +36,9 @@ def default_collate_fn(batch):
     if isinstance(sample, dict):
         return {k: default_collate_fn([s[k] for s in batch]) for k in sample}
     return np.asarray(batch)
+
+
+_EPOCH_END = object()
 
 
 class DataLoader:
@@ -107,10 +111,22 @@ class DataLoader:
             yield to_tensors(self.collate_fn(batch))
 
     def __iter__(self):
-        if self.num_workers == 0:
-            yield from self._make_batches()
-            return
-        yield from self._iter_multiprocess()
+        """Every batch's production, for as long as the consumer waits
+        for it, is the host span ``io/next`` (no workers: index +
+        collate + to-tensor; workers: the wait for the staged batch).
+        The probe that finds the epoch over leaves no span."""
+        batches = self._make_batches() if self.num_workers == 0 \
+            else self._iter_multiprocess()
+        try:
+            while True:
+                with _profiler.host_scope("io/next") as scope:
+                    batch = next(batches, _EPOCH_END)
+                    if batch is _EPOCH_END:
+                        scope.drop()
+                        return
+                yield batch
+        finally:
+            batches.close()
 
     def _convert_batch(self, batch, shm_holds):
         """Turn a decoded worker batch into consumer tensors and release

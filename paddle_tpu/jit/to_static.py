@@ -17,6 +17,12 @@ call 2: runs eagerly under a recording TraceContext that discovers which
 call 3+: executes the jit-compiled XLA program; mutated state buffers are
   donated, so parameter updates are in-place at the XLA level.
 
+Host spans (profiler.host_scope): the two set-up passes
+are ``jit/eager`` and ``jit/record``; every compiled call is ``jit/call``
+(gather the captured arrays, call, write the mutated state back) with
+``jit/enqueue`` inside it around the jitted callable alone, which on its
+first call per shape also traces and compiles.
+
 Python control flow is supported naturally when it doesn't depend on
 traced values (it is unrolled/baked like the reference's static backend);
 data-dependent branching inside a compiled step should use tensor ops
@@ -28,6 +34,7 @@ import numpy as np
 
 import jax
 
+from .. import profiler as _profiler
 from ..core import trace as trace_mod
 from ..core.tensor import Tensor
 
@@ -139,11 +146,15 @@ class TracedFunction:
             if donor is not None:
                 entry["compiled"] = donor
         if entry["compiled"] is not None:
-            return self._run_compiled(entry, struct, leaves)
+            with _profiler.host_scope("jit/call"):
+                return self._run_compiled(entry, struct, leaves)
         entry["calls"] += 1
         if entry["calls"] <= self._warmup:
-            return self._fn(*args, **kwargs)
-        return self._record_and_compile(entry, args, kwargs, struct, leaves)
+            with _profiler.host_scope("jit/eager"):
+                return self._fn(*args, **kwargs)
+        with _profiler.host_scope("jit/record"):
+            return self._record_and_compile(entry, args, kwargs, struct,
+                                            leaves)
 
     def _same_struct_compiled(self, sig, struct):
         _, _, inst = sig
@@ -229,8 +240,9 @@ class TracedFunction:
         mut_caps, ro_caps = captured_arrays(c)
         arg_arrays = [t.value for t in leaves]
         try:
-            out_arrays, mut_arrays, grad_arrays = c["jitted"](
-                arg_arrays, mut_caps, ro_caps)
+            with _profiler.host_scope("jit/enqueue"):
+                out_arrays, mut_arrays, grad_arrays = c["jitted"](
+                    arg_arrays, mut_caps, ro_caps)
         except jax.errors.UnexpectedTracerError as e:
             # structured replacement for jax's opaque leak error: a
             # captured input carried a dead sub-trace tracer into the

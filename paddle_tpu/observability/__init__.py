@@ -15,9 +15,11 @@ TPU-native generalization, built around ONE instrumentation point:
   3. the **dashboard** (registry.default_registry(): per-scope
      seconds + call counters, scrapeable as Prometheus text).
 
-The serving engine and the hapi training loop both instrument through
-it, so `serving/*`, `hapi/*` and `optimizer/*` scopes land in all
-three views. The third pillar, watchdog.CompileWatchdog, turns the
+``profiler.host_scope(name)`` is the same three sinks without the
+named_scope, for sections under which nothing is staged. The serving
+engine and its gateway, the DataLoader, to_static and the hapi training
+loop all instrument through the two, so `serving/*`, `io/*`, `jit/*`,
+`hapi/*` and `optimizer/*` scopes land in all three views. The third pillar, watchdog.CompileWatchdog, turns the
 serving engine's exact compile counter into an ATTRIBUTED invariant:
 every compile logs its key + abstract-shape signature + call-site,
 and any compile after ``declare_warmup_complete()`` is flagged (or

@@ -160,8 +160,7 @@ def _pallas_flash_fwd_32(q, k, v, scale, causal):
     kernel = functools.partial(_flash_fwd_kernel, scale=scale,
                                causal=causal, block_q=block_q,
                                block_k=block_k, nk=nk)
-    out, lse = pl.pallas_call(
-        kernel,
+    out, lse = pl.pallas_call(kernel, name="flash_fwd",
         grid=(b, h, nq, nk),
         in_specs=[
             pl.BlockSpec((None, None, block_q, d),
@@ -303,9 +302,10 @@ def _pallas_flash_bwd_32(q, k, v, out, lse, g, scale, causal):
 
     f32 = jnp.float32
     # dq: grid (b, h, nq, nk); dq block revisited across nk
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, scale=scale,
-                          causal=causal, block_q=block, block_k=block),
+    dq_kernel = functools.partial(_flash_bwd_dq_kernel, scale=scale,
+                                  causal=causal, block_q=block,
+                                  block_k=block)
+    dq = pl.pallas_call(dq_kernel, name="flash_bwd_dq",
         grid=(b, h, n, n),
         in_specs=[blk(2), blk(3), blk(3), blk(2), vec(2), vec(2)],
         out_specs=blk(2),
@@ -314,9 +314,10 @@ def _pallas_flash_bwd_32(q, k, v, out, lse, g, scale, causal):
     )(q, k, v, g, lse, delta)
 
     # dk/dv: grid (b, h, nk, nq); dk/dv blocks revisited across nq
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, scale=scale,
-                          causal=causal, block_q=block, block_k=block),
+    dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, scale=scale,
+                                   causal=causal, block_q=block,
+                                   block_k=block)
+    dk, dv = pl.pallas_call(dkv_kernel, name="flash_bwd_dkv",
         grid=(b, h, n, n),
         in_specs=[blk(3), blk(2), blk(2), blk(3), vec(3), vec(3)],
         out_specs=[blk(2), blk(2)],
@@ -467,10 +468,11 @@ def cached_paged_attention(q, k_cache, v_cache, block_tables, lengths):
     (ops.paged_attention, PADDLE_PAGED_ATTN) that reads the blocks in
     place instead."""
     S, nh, hd = q.shape
-    k = jnp.take(k_cache, block_tables, axis=0)  # [S, MB, nh, BS, hd]
-    v = jnp.take(v_cache, block_tables, axis=0)
-    k = k.transpose(0, 2, 1, 3, 4).reshape(S, nh, -1, hd)
-    v = v.transpose(0, 2, 1, 3, 4).reshape(S, nh, -1, hd)
+    with jax.named_scope("kv_gather"):
+        k = jnp.take(k_cache, block_tables, axis=0)  # [S, MB, nh, BS, hd]
+        v = jnp.take(v_cache, block_tables, axis=0)
+        k = k.transpose(0, 2, 1, 3, 4).reshape(S, nh, -1, hd)
+        v = v.transpose(0, 2, 1, 3, 4).reshape(S, nh, -1, hd)
     return cached_slot_attention(q, k, v, lengths)
 
 

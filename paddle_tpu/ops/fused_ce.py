@@ -140,8 +140,7 @@ def _pallas_fwd(x, w_vh, labels, ignore_index):
     lab2 = labels.astype(jnp.int32)[None, :]          # [1, T]
     kernel = functools.partial(_fwd_kernel, block_t=bt, block_v=bv,
                                nv=nv, ignore_index=ignore_index)
-    loss, lse = pl.pallas_call(
-        kernel,
+    loss, lse = pl.pallas_call(kernel, name="fused_ce_fwd",
         grid=(nt, nv),
         in_specs=[
             pl.BlockSpec((bt, h), lambda ti, vi: (ti, 0)),
@@ -222,9 +221,9 @@ def _pallas_bwd(x, w_vh, labels, lse, g, ignore_index):
     lse2 = lse[None, :]
     g2 = g.astype(jnp.float32)[None, :]
 
-    dx = pl.pallas_call(
-        functools.partial(_bwd_dx_kernel, block_t=bt, block_v=bv,
-                          ignore_index=ignore_index),
+    dx_kernel = functools.partial(_bwd_dx_kernel, block_t=bt, block_v=bv,
+                                  ignore_index=ignore_index)
+    dx = pl.pallas_call(dx_kernel, name="fused_ce_bwd_dx",
         grid=(nt, nv),
         in_specs=[
             pl.BlockSpec((bt, h), lambda ti, vi: (ti, 0)),
@@ -238,9 +237,9 @@ def _pallas_bwd(x, w_vh, labels, lse, g, ignore_index):
         interpret=_interpret(),
     )(x, w_vh, lab2, lse2, g2)
 
-    dw = pl.pallas_call(
-        functools.partial(_bwd_dw_kernel, block_t=bt, block_v=bv,
-                          ignore_index=ignore_index),
+    dw_kernel = functools.partial(_bwd_dw_kernel, block_t=bt, block_v=bv,
+                                  ignore_index=ignore_index)
+    dw = pl.pallas_call(dw_kernel, name="fused_ce_bwd_dw",
         grid=(nv, nt),
         in_specs=[
             pl.BlockSpec((bt, h), lambda vi, ti: (ti, 0)),

@@ -178,8 +178,7 @@ def _paged_decode_32(q, k_cache, v_cache, block_tables, lengths):
     )
     kernel = functools.partial(_paged_decode_kernel, block_size=BS,
                                max_blocks=MB)
-    return pl.pallas_call(
-        kernel,
+    return pl.pallas_call(kernel, name="paged_decode_attn",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, nh, hd), q.dtype),
         interpret=_interpret(),

@@ -13,7 +13,10 @@ above, the bounded host-span ring buffer (chrome://tracing dump), and
 the process metrics registry (per-scope seconds/calls, Prometheus
 text) — so a scope placed once in the serving engine or the hapi
 training loop shows up in the device timeline, the host timeline, and
-the dashboard.
+the dashboard. host_scope is the same three sinks for a section under
+which nothing is ever staged (the serving step loop, the DataLoader,
+a compiled to_static call): it leaves out the jax.named_scope push,
+which only names ops traced inside it.
 """
 import contextlib
 import time
@@ -57,6 +60,79 @@ class RecordEvent:
         self.__exit__()
 
 
+class host_scope:
+    """One host-only scope, three sinks: a TraceAnnotation on the
+    profiler's clock while it is open; at exit the span goes into the
+    host-span ring (``self.span`` is the HostSpan it left, so a caller
+    that learns a fact only afterwards can still attach ``args`` to it)
+    and seconds + a call accrue in the process registry. An optional
+    ``sink(name, dt)`` receives the same elapsed seconds — the hook
+    the serving metrics hang their per-engine accounting on.
+
+    ``drop()`` inside the scope leaves none of that behind (ring,
+    registry, sink): for a probe that turns out to have done nothing,
+    such as the ``next()`` that finds an epoch over. ``t0`` (a
+    perf_counter stamp) backdates the recorded span to a wait the
+    caller timed before it knew the scope would open; the annotation
+    cannot be backdated and opens on entry.
+
+    For code that dispatches already-compiled programs or does plain
+    host work. A section that may run under staging (jit tracing) wants
+    record_scope, which also names the ops traced inside it."""
+
+    __slots__ = ("name", "sink", "span", "_ann", "_t0")
+
+    def __init__(self, name, sink=None, t0=None):
+        self.name = name
+        self.sink = sink
+        self.span = None
+        self._t0 = t0
+
+    def __enter__(self):
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
+        if self._t0 is None:
+            self._t0 = time.perf_counter()
+        return self
+
+    def drop(self):
+        self.name = None
+
+    def __exit__(self, *exc):
+        t0 = self._t0
+        dt = time.perf_counter() - t0
+        self._ann.__exit__(*exc)
+        if self.name is not None:
+            self.span = record_span(self.name, t0, dt, self.sink)
+        return False
+
+
+def record_span(name, t0, dt, sink=None):
+    """The three sinks of host_scope for a section timed by hand
+    (``t0`` on perf_counter, ``dt`` seconds): ring, registry, ``sink``;
+    returns the HostSpan. No TraceAnnotation: one cannot be opened in
+    the past. For a wait that is only worth a span once it is over
+    (the gateway driver's, if the iteration then steps) or a span that
+    sums several pieces (the callbacks of one harvest)."""
+    span = _recorder.record(name, t0, dt)
+    kids = _span_kids.get(name)
+    if kids is None:
+        kids = _span_kids[name] = (_span_seconds.labels(name),
+                                   _span_calls.labels(name))
+    kids[0].inc(dt)
+    kids[1].inc()
+    if sink is not None:
+        sink(name, dt)
+    return span
+
+
+_recorder = _obs_tracing.default_recorder()
+# name -> (seconds child, calls child): .labels() takes the registry's
+# lock and rebuilds the label tuple on every call, and a scope name is
+# one of a few dozen literals
+_span_kids = {}
+
+
 @contextlib.contextmanager
 def record_scope(name, sink=None):
     """One scope, three sinks. Entering annotates the XLA trace
@@ -66,18 +142,10 @@ def record_scope(name, sink=None):
     timeline) and accrues seconds + a call count into the process
     metrics registry (observability.default_registry(), scrapeable as
     Prometheus text). An optional ``sink(name, dt)`` callback receives
-    the same elapsed seconds — the hook the serving metrics
-    (paddle_tpu.serving.metrics) hang their per-engine prefill/decode/
-    compile accounting on."""
-    t0 = time.perf_counter()
-    with RecordEvent(name):
+    the same elapsed seconds. host_scope plus the jax.named_scope that
+    names the ops staged inside the section."""
+    with host_scope(name, sink), jax.named_scope(name):
         yield
-    dt = time.perf_counter() - t0
-    _obs_tracing.default_recorder().record(name, t0, dt)
-    _span_seconds.labels(name).inc(dt)
-    _span_calls.labels(name).inc()
-    if sink is not None:
-        sink(name, dt)
 
 
 class ProfilerState:

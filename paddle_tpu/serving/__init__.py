@@ -35,15 +35,42 @@ math into a multi-tenant server:
     traffic), queue depth, slot occupancy, prefill-group histogram,
     KV-donation status, dispatch-vs-sync wall split and an exact
     compile counter. Every timed section uses the ONE-SCOPE-THREE-
-    SINKS discipline (paddle_tpu.profiler.record_scope): the same
-    ``serving/*`` scope is (1) annotated into the XLA trace for live
-    XPlane captures, (2) recorded into the bounded host-span ring
-    buffer — dump the engine-step anatomy (retirement → admission →
-    grouped prefill → decode dispatch → harvest) as a chrome://tracing
-    / Perfetto timeline via
+    SINKS discipline (paddle_tpu.profiler.host_scope, the span for
+    code under which nothing is staged): the same ``serving/*`` scope
+    is (1) annotated into the XLA trace for live XPlane captures, (2)
+    recorded into the bounded host-span ring buffer — dump the
+    engine-step anatomy as a chrome://tracing / Perfetto timeline via
     ``observability.default_recorder().dump_chrome_trace(path)`` —
     and (3) accrued into the registry for the snapshot()/Prometheus
-    numbers. Scrape with ``server = engine.serve_metrics()`` then
+    numbers. The spans, outermost first. On a caller's thread:
+    ``serving/submit_wait`` (EngineGateway.submit()/prefill() waiting
+    for the gateway's lock; ring args carry the rid; also the
+    histogram ``serving_submit_wait_seconds``). On the gateway's
+    driver thread: ``serving/drive`` (one iteration that stepped,
+    from before the lock is asked for until step() returned) holding
+    ``serving/drive_lock_wait``, ``serving/step`` and, after it,
+    ``serving/health_tick`` (step-ledger row + detectors; with
+    ``serving/health_audit`` inside it every ``health_audit_every``
+    steps). Inside ``serving/step``: ``serving/retirement`` →
+    ``serving/triage`` → ``serving/admit`` →
+    ``serving/prefill_dispatch`` / ``serving/chunk_dispatch`` →
+    ``serving/draft`` (speculative) → ``serving/decode_dispatch`` →
+    ``serving/harvest`` holding one ``serving/sync`` (the only
+    device→host wait) per harvested dispatch and at most one
+    ``serving/on_token`` (the time its callbacks took, added up);
+    ``serving/compile`` wherever a
+    program is first built; ``serving/kv_export``,
+    ``serving/kv_import`` and ``serving/supervisor_restart`` on their
+    own paths. ``serving/drive_lock_wait`` and ``serving/on_token``
+    are written from stamps once they are over (ring and registry,
+    no XPlane annotation); ``serving/drive``'s annotation opens when
+    the lock is held. Per request, beside the spans: ``Request.t_received``
+    (the gateway's entry, before its lock; TTFT, latency and the SLO
+    verdicts count from it), ``t_arrival`` (enqueued; queue wait and
+    deadlines count from it), ``t_admitted``,
+    ``t_prefill_dispatched`` with ``prefill_tokens_dispatched`` (the
+    padded tokens the device computed for it), ``t_first_token``,
+    ``t_done``. Scrape with ``server = engine.serve_metrics()`` then
     ``GET http://127.0.0.1:<port>/metrics`` (Prometheus text) or
     ``/metrics.json`` (the snapshot schema); the handle's ``close()``
     stops the server (idempotent; ``engine.close()`` closes every

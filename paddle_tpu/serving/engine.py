@@ -784,6 +784,9 @@ class ServingEngine:
         self._toks = jnp.zeros((config.num_slots,), jnp.int32)
         self._pos = jnp.zeros((config.num_slots,), jnp.int32)
         self._pending = []  # dispatched, not-yet-read device results
+        # first callback's start / summed callback seconds of the
+        # harvest in progress (its serving/on_token span)
+        self._on_token_t0, self._on_token_s = None, 0.0
         effective = jax.devices()[0].platform != "cpu"
         self._donate = (effective if config.donate_buffers is None
                         else bool(config.donate_buffers))
@@ -838,7 +841,8 @@ class ServingEngine:
     def add_request(self, prompt, max_new_tokens, eos_id=None,
                     on_token=None, temperature=0.0, top_k=0,
                     top_p=1.0, seed=None, deadline_ms=None,
-                    hold_kv=False, trace=None, tenant_id=None):
+                    hold_kv=False, trace=None, tenant_id=None,
+                    t_received=None):
         """Enqueue a prompt; returns the Request handle immediately.
         Tokens stream through on_token(request, token) as steps run
         (with async_depth=1 a token surfaces one engine step after the
@@ -876,7 +880,12 @@ class ServingEngine:
         trace-baggage entry (the router stamps it at admission, so a
         decode-tier import or failover replay keeps the original
         tenant), then to ``"default"``. The resolved id is written
-        back into the baggage so every downstream hop inherits it."""
+        back into the baggage so every downstream hop inherits it.
+
+        ``t_received`` (perf_counter) is when the caller's side took
+        the request over, where that was before this call: the gateway
+        stamps it before it waits for its lock. TTFT, latency and the
+        SLO verdicts count from it; None = now."""
         if self._draining or self._closed:
             raise RuntimeError(
                 "engine is draining/closed: no new requests (drain() "
@@ -894,7 +903,7 @@ class ServingEngine:
                       on_token=on_token, temperature=temperature,
                       top_k=top_k, top_p=top_p, seed=seed,
                       deadline_ms=deadline_ms, hold_kv=hold_kv,
-                      tenant_id=tenant_id)
+                      tenant_id=tenant_id, t_received=t_received)
         if ctx.baggage.get("tenant") != req.tenant_id:
             # write the resolved tenant back into the baggage (same
             # trace/span ids — this is annotation, not a new hop) so
@@ -1594,7 +1603,11 @@ class ServingEngine:
             # a user callback must never take down the step loop: a
             # raise is caught, counted, trace-attributed — and every
             # other slot keeps streaming (the token itself was already
-            # emitted and accounted above)
+            # emitted and accounted above). The callers' time adds up
+            # to the harvest's one serving/on_token span
+            t_cb = time.perf_counter()
+            if self._on_token_t0 is None:
+                self._on_token_t0 = t_cb
             try:
                 if self.chaos is not None:
                     self.chaos.maybe_raise("callback",
@@ -1603,6 +1616,7 @@ class ServingEngine:
             except Exception as e:  # noqa: BLE001 - isolation boundary
                 self.metrics.record_callback_error()
                 self.flight.callback_error(req, e)
+            self._on_token_s += time.perf_counter() - t_cb
         reason = self.scheduler.stop_reason(req, token)
         if reason is not None:
             self.scheduler.finish(req, self.pool)
@@ -1623,8 +1637,12 @@ class ServingEngine:
         values. np.asarray here is the engine's ONLY device->host
         sync; with async_depth=1 the current step's prefill/decode are
         already executing when it blocks, so stop checks, streaming
-        callbacks and retirement overlap device compute."""
+        callbacks and retirement overlap device compute. The time
+        spent in callbacks is charged to ONE ``serving/on_token`` span
+        per harvest (none when no token had a callback), so it reads
+        apart from the engine's own under ``serving/harvest``."""
         M = self.metrics
+        self._on_token_t0, self._on_token_s = None, 0.0
         for entry in pending:
             if self._perf_on:
                 t0 = time.perf_counter()
@@ -1695,6 +1713,12 @@ class ServingEngine:
                         continue
                     req.inflight -= 1
                     self._emit(req, int(vals[slot]))
+        if self._on_token_t0 is not None:
+            # the callers' share of this harvest, as one span: it
+            # starts with the first callback and lasts as long as all
+            # of them together
+            M.record_span("serving/on_token", self._on_token_t0,
+                          self._on_token_s)
 
     def _read_back(self, device_vals):
         """One device->host token read, with bounded retry for
@@ -1780,7 +1804,8 @@ class ServingEngine:
         appends one structured row to the step ledger and runs the
         online anomaly detectors over it — the ledger build happens
         AFTER the timed step, so the observatory's own bookkeeping
-        never pollutes the wall time it judges."""
+        never pollutes the wall time it judges; it has its own span,
+        ``serving/health_tick``, beside ``serving/step``."""
         if self.health is None:
             more = False
             with self.metrics.span("serving/step"):
@@ -1791,7 +1816,9 @@ class ServingEngine:
         t0 = time.perf_counter()
         with self.metrics.span("serving/step"):
             more = self._step_inner()
-        self._health_tick(time.perf_counter() - t0)
+        wall_s = time.perf_counter() - t0
+        with self.metrics.span("serving/health_tick"):
+            self._health_tick(wall_s)
         return more or self.scheduler.pending or bool(self._pending)
 
     def _step_inner(self):
@@ -2113,6 +2140,7 @@ class ServingEngine:
                 ex = self._compiled(("prefill", bucket, G),
                                     self._prefill_fn, args,
                                     donate=(5, 6, 7))
+                t_disp = time.perf_counter()
                 with M.span("serving/prefill_dispatch"):
                     for req, _slot in group:
                         self.flight.prefill_dispatched(req, bucket, G)
@@ -2133,6 +2161,7 @@ class ServingEngine:
             # counted twice
             for req, _slot in group:
                 M.record_admission(req)
+                self._stamp_prefill(req, t_disp, bucket)
             M.requests_admitted += G
             M.prefills += 1
             M.prefill_requests += G
@@ -2192,6 +2221,7 @@ class ServingEngine:
                 ex = self._compiled(("paged_prefill", bucket),
                                     self._prefill_fn, args,
                                     donate=(8, 9, 10))
+                t_disp = time.perf_counter()
                 with M.span("serving/prefill_dispatch"):
                     if start:
                         self.flight.prefix_hit(
@@ -2211,6 +2241,7 @@ class ServingEngine:
             pool.rebind(kc, vc)
             pool.commit_prefix(alloc.slot, ids)
             M.record_admission(req)
+            self._stamp_prefill(req, t_disp, bucket)
             M.requests_admitted += 1
             M.prefills += 1
             M.prefill_requests += 1
@@ -2224,6 +2255,15 @@ class ServingEngine:
                 self._pending.append(entry)
 
     # ---------------------------------------------- chunked prefill
+
+    @staticmethod
+    def _stamp_prefill(req, t_dispatched, padded_tokens):
+        """A prefill dispatch that stuck: when the request's first one
+        went out, and the padded tokens the device computes for it
+        (its bucket; the chunk width for every chunk)."""
+        if req.t_prefill_dispatched is None:
+            req.t_prefill_dispatched = t_dispatched
+        req.prefill_tokens_dispatched += int(padded_tokens)
 
     @staticmethod
     def _samp_scalars(req):
@@ -2292,6 +2332,7 @@ class ServingEngine:
                                            step=self._step_id + 1,
                                            chunk=plan.next)
                 ex = self._compiled(key, fn, args, donate=donate)
+                t_disp = time.perf_counter()
                 with M.span("serving/chunk_dispatch"):
                     if plan.next == 0 and plan.start0:
                         self.flight.prefix_hit(
@@ -2317,6 +2358,7 @@ class ServingEngine:
                 raise        # the retry re-plans from the queue)
             pool.rebind(kc, vc)
             M.record_prefill_chunk(clen)
+            self._stamp_prefill(req, t_disp, C)
             budget -= clen
             plan.advance()
             if final:

@@ -136,12 +136,18 @@ class ServingMetrics:
             "serving_span_seconds_total",
             "wall seconds accrued per engine scope",
             labelnames=("span",))
+        self._span_kids = {}    # span name -> its _c_span child
+        # "received" is where the caller handed the request over: the
+        # gateway's entry, before its lock (== arrival without one)
         self._h_ttft = r.histogram(
-            "serving_ttft_seconds", "arrival -> first token")
+            "serving_ttft_seconds", "received -> first token")
         self._h_latency = r.histogram(
-            "serving_request_latency_seconds", "arrival -> done")
+            "serving_request_latency_seconds", "received -> done")
         self._h_queue_wait = r.histogram(
             "serving_queue_wait_seconds", "arrival -> slot admission")
+        self._h_submit_wait = r.histogram(
+            "serving_submit_wait_seconds",
+            "gateway entry -> its lock taken (received -> arrival)")
         # prefix-cache economy (the paged pool moves these; the legacy
         # pool only accrues computed tokens): admissions that reused a
         # cached prefix vs not, tokens served FROM cache (never
@@ -346,14 +352,23 @@ class ServingMetrics:
         return list(self._res["request_latency"].samples())
 
     # ------------------------------------------------------- accounting
-    def span(self, name):
+    def span(self, name, t0=None):
         """Context manager: XPlane annotation + chrome host span +
-        registry accrual (via profiler.record_scope's three sinks) +
-        this engine's own span counter."""
-        return _profiler.record_scope(name, sink=self._accrue)
+        registry accrual (profiler.host_scope's three sinks: the step
+        loop only dispatches compiled programs, nothing is staged under
+        its spans) + this engine's own span counter."""
+        return _profiler.host_scope(name, sink=self._accrue, t0=t0)
+
+    def record_span(self, name, t0, dt):
+        """A span timed by hand, into the same sinks but the XPlane
+        annotation (profiler.record_span); returns the HostSpan."""
+        return _profiler.record_span(name, t0, dt, self._accrue)
 
     def _accrue(self, name, dt):
-        self._c_span.labels(name).inc(dt)
+        kid = self._span_kids.get(name)
+        if kid is None:
+            kid = self._span_kids[name] = self._c_span.labels(name)
+        kid.inc(dt)
         now = time.perf_counter()
         if self._t_first_work is None:
             self._t_first_work = now - dt
@@ -622,9 +637,13 @@ class ServingMetrics:
             getattr(request, "tenant_id", None), len(request.prompt),
             wait)
 
+    def record_submit_wait(self, seconds):
+        """The gateway's wait for its own lock, per submission."""
+        self._h_submit_wait.observe(seconds)
+
     def record_first_token(self, request):
         request.t_first_token = time.perf_counter()
-        ttft = request.t_first_token - request.t_arrival
+        ttft = request.t_first_token - request.t_received
         self._h_ttft.observe(ttft)
         self._res["ttft"].add(ttft)
         self.tenants.note_first_token(
@@ -635,11 +654,11 @@ class ServingMetrics:
         the violated dimensions (empty list = SLO attained) so the
         engine can stamp them onto the flight-recorder retirement."""
         self._c_completed.inc()
-        latency = request.t_done - request.t_arrival
+        latency = request.t_done - request.t_received
         self._h_latency.observe(latency)
         self._res["request_latency"].add(latency)
         ttft = (None if request.t_first_token is None
-                else request.t_first_token - request.t_arrival)
+                else request.t_first_token - request.t_received)
         violations = self.slo.observe_request(ttft, latency,
                                               len(request.generated))
         # the tenant ledger receives the engine's OWN verdict — never

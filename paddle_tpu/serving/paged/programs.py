@@ -101,25 +101,30 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
     def _prefill_core(params, tokens, tail_len, start, slot, final,
                       bt_row, toks, pos, kc, vc, samp):
         # tokens [1, B] right-padded tail; start = cached prefix length
-        kctx = gather_slot(kc, bt_row)
-        vctx = gather_slot(vc, bt_row)
+        with jax.named_scope("kv_gather"):
+            kctx = gather_slot(kc, bt_row)
+            vctx = gather_slot(vc, bt_row)
         logits, kctx, vctx = forward_t(params, tokens, start, kctx,
                                        vctx)
-        kc = scatter_slot(kc, bt_row, kctx)
-        vc = scatter_slot(vc, bt_row, vctx)
-        last = jnp.take(logits[0], tail_len - 1, axis=0)   # [vocab]
-        if samp is None:
-            first = jnp.argmax(last, -1).astype(jnp.int32)
-        else:
-            seed, temp, topk, topp = samp
-            first = head(last[None], seed[None],
-                         (start + tail_len - 1)[None], temp[None],
-                         topk[None], topp[None])[0]
-        toks = jnp.where(final > 0, toks.at[slot].set(first), toks)
-        # final: the next decode writes this slot at prompt_len;
-        # interior chunk: park at the row's last addressable position
-        pos = pos.at[slot].set(
-            jnp.where(final > 0, start + tail_len, jnp.int32(C - 1)))
+        with jax.named_scope("kv_write"):
+            kc = scatter_slot(kc, bt_row, kctx)
+            vc = scatter_slot(vc, bt_row, vctx)
+        with jax.named_scope("sample"):
+            last = jnp.take(logits[0], tail_len - 1, axis=0)   # [vocab]
+            if samp is None:
+                first = jnp.argmax(last, -1).astype(jnp.int32)
+            else:
+                seed, temp, topk, topp = samp
+                first = head(last[None], seed[None],
+                             (start + tail_len - 1)[None], temp[None],
+                             topk[None], topp[None])[0]
+            toks = jnp.where(final > 0, toks.at[slot].set(first), toks)
+            # final: the next decode writes this slot at prompt_len;
+            # interior chunk: park at the row's last addressable
+            # position
+            pos = pos.at[slot].set(
+                jnp.where(final > 0, start + tail_len,
+                          jnp.int32(C - 1)))
         return first[None], toks, pos, kc, vc
 
     if sampling:
@@ -138,8 +143,9 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
 
     def _decode_core(params, toks, pos, tables, kc, vc, samp):
         S = toks.shape[0]
-        x = params["wemb"][toks] + params["pemb"][
-            jnp.minimum(pos, params["pemb"].shape[0] - 1)]  # [S, h]
+        with jax.named_scope("embed"):
+            x = params["wemb"][toks] + params["pemb"][
+                jnp.minimum(pos, params["pemb"].shape[0] - 1)]  # [S, h]
         # clamp the WRITE position as a whole (column AND offset):
         # parked / released slots' positions keep incrementing past
         # the row, and clamping only the column would spray their
@@ -159,35 +165,42 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
         def body(carry, inp):
             x = carry
             p, kcl, vcl = inp
-            h_ = ln(x, p["ln1_w"], p["ln1_b"])
-            qkv = h_ @ p["qkv_w"] + p["qkv_b"]
-            qkv = qkv.reshape(S, 3, nh, hd).transpose(1, 0, 2, 3)
-            q, k, v = qkv[0], qkv[1], qkv[2]          # [S, nh, hd]
-            # per-slot row write into its current (privately-owned)
-            # block: advanced indexing [S],:,[S] scatters [S, nh, hd]
-            kcl = kcl.at[bidx, :, off].set(k)
-            vcl = vcl.at[bidx, :, off].set(v)
-            if attn_kernel:
-                o = paged_attn_ops.paged_decode_attention(
-                    q, kcl, vcl, tables, pos + 1)
-            else:
-                o = attn_ops.cached_paged_attention(
-                    q, kcl, vcl, tables, pos + 1)
-            o = o.reshape(S, hidden)                  # concat heads
-            x = x + (o @ p["out_w"] + p["out_b"])
-            h2 = ln(x, p["ln2_w"], p["ln2_b"])
-            m = jax.nn.gelu(h2 @ p["fc1_w"] + p["fc1_b"],
-                            approximate=True)
-            return x + (m @ p["fc2_w"] + p["fc2_b"]), (kcl, vcl)
+            with jax.named_scope("attn"):
+                h_ = ln(x, p["ln1_w"], p["ln1_b"])
+                qkv = h_ @ p["qkv_w"] + p["qkv_b"]
+                qkv = qkv.reshape(S, 3, nh, hd).transpose(1, 0, 2, 3)
+                q, k, v = qkv[0], qkv[1], qkv[2]          # [S, nh, hd]
+                # per-slot row write into its current (privately-
+                # owned) block: advanced indexing [S],:,[S] scatters
+                # [S, nh, hd]
+                with jax.named_scope("kv_write"):
+                    kcl = kcl.at[bidx, :, off].set(k)
+                    vcl = vcl.at[bidx, :, off].set(v)
+                if attn_kernel:
+                    o = paged_attn_ops.paged_decode_attention(
+                        q, kcl, vcl, tables, pos + 1)
+                else:
+                    # gathers the slots' blocks under "kv_gather"
+                    o = attn_ops.cached_paged_attention(
+                        q, kcl, vcl, tables, pos + 1)
+                o = o.reshape(S, hidden)                  # concat heads
+                x = x + (o @ p["out_w"] + p["out_b"])
+            with jax.named_scope("mlp"):
+                h2 = ln(x, p["ln2_w"], p["ln2_b"])
+                m = jax.nn.gelu(h2 @ p["fc1_w"] + p["fc1_b"],
+                                approximate=True)
+                return x + (m @ p["fc2_w"] + p["fc2_b"]), (kcl, vcl)
 
         x, (kc, vc) = lax.scan(body, x, (params["stacked"], kc, vc))
-        logits = ln(x, params["lnf_w"], params["lnf_b"]) \
-            @ params["head"]                          # [S, vocab]
-        if samp is None:
-            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-        else:
-            seeds, temps, topks, topps = samp
-            nxt = head(logits, seeds, pos, temps, topks, topps)
+        with jax.named_scope("lm_head"):
+            logits = ln(x, params["lnf_w"], params["lnf_b"]) \
+                @ params["head"]                          # [S, vocab]
+        with jax.named_scope("sample"):
+            if samp is None:
+                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+            else:
+                seeds, temps, topks, topps = samp
+                nxt = head(logits, seeds, pos, temps, topks, topps)
         return nxt, pos + jnp.int32(1), kc, vc
 
     if sampling:

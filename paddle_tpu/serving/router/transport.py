@@ -22,6 +22,7 @@ Failure classes — the distinction the circuit breaker feeds on:
     (draining/closed → HTTP 503). A clean verdict, NOT a failure:
     the router fails over without charging the breaker.
 """
+import contextlib
 import json
 import threading
 import time
@@ -104,14 +105,45 @@ class EngineGateway:
         return self._dead
 
     def _drive(self):
+        """Step the engine while it has work. An iteration that steps
+        is the span ``serving/drive``, from before the lock is asked
+        for until ``step()`` has returned (health tick and all, so
+        drive - step is the host work around a step and never sleep);
+        the driver's own wait for the lock is
+        ``serving/drive_lock_wait``. Both are written under the lock,
+        once the iteration is known to step: one that finds nothing
+        pending leaves no span, and between release and acquire, the
+        submitters' whole chance at the lock (PERF.md section 6), the
+        loop does what it always did plus one clock read. In the
+        device trace ``serving/drive`` opens once the lock is held."""
+        metrics = self.engine.metrics
         while not self._stop.is_set():
             worked = False
+            t0 = time.perf_counter()
             with self._lock:
                 if not self.engine._closed and self.engine.pending:
-                    worked = bool(self.engine.step())
+                    metrics.record_span("serving/drive_lock_wait", t0,
+                                        time.perf_counter() - t0)
+                    with metrics.span("serving/drive", t0=t0):
+                        worked = bool(self.engine.step())
             if not worked:
                 self._wake.wait(self._idle_sleep_s)
                 self._wake.clear()
+
+    @contextlib.contextmanager
+    def _locked_for_submission(self):
+        """The lock, taken on a caller's thread; the wait for it is the
+        span ``serving/submit_wait`` and one observation of
+        ``serving_submit_wait_seconds``. Yields the recorded span, to
+        which the caller attaches the ``rid`` it then obtains."""
+        metrics = self.engine.metrics
+        with metrics.span("serving/submit_wait") as wait:
+            self._lock.acquire()
+        try:
+            metrics.record_submit_wait(wait.span.dur)
+            yield wait.span
+        finally:
+            self._lock.release()
 
     # --------------------------------------------------- submission
     def submit(self, prompt, max_new_tokens, eos_id=None,
@@ -125,17 +157,20 @@ class EngineGateway:
         a request over a bad trace). ``tenant_id`` overrides the
         attribution id; None defers to the trace baggage (the routed
         case), then to ``"default"``."""
+        t_received = time.perf_counter()
         if self._dead:
             raise TransportError(
                 f"replica {self.replica_id} is dead")
-        with self._lock:
+        with self._locked_for_submission() as waited:
             try:
                 req = self.engine.add_request(
                     prompt, max_new_tokens, eos_id=eos_id,
                     deadline_ms=deadline_ms, on_token=on_token,
-                    trace=trace, tenant_id=tenant_id)
+                    trace=trace, tenant_id=tenant_id,
+                    t_received=t_received)
             except RuntimeError as e:   # draining/closed
                 raise TransportRefused(str(e)) from e
+            waited.args = {"rid": req.rid}
         self._wake.set()
         return req
 
@@ -187,16 +222,18 @@ class EngineGateway:
         ``trace`` propagates into the request AND (via export_kv)
         into the handoff payload, so the decode tier joins the same
         trace."""
+        t_received = time.perf_counter()
         if self._dead:
             raise TransportError(f"replica {self.replica_id} is dead")
-        with self._lock:
+        with self._locked_for_submission() as waited:
             try:
                 req = self.engine.add_request(
                     prompt, 1, deadline_ms=deadline_ms, hold_kv=True,
-                    trace=trace)
+                    trace=trace, t_received=t_received)
             except (RuntimeError, ValueError) as e:
                 # draining/closed, or no paged pool on this replica
                 raise TransportRefused(str(e)) from e
+            waited.args = {"rid": req.rid}
         self._wake.set()
         if timeout is None:
             timeout = self.generate_timeout_s

@@ -38,7 +38,7 @@ class Request:
     def __init__(self, prompt, max_new_tokens, eos_id=None,
                  on_token=None, temperature=0.0, top_k=0, top_p=1.0,
                  seed=None, deadline_ms=None, hold_kv=False,
-                 tenant_id=None):
+                 tenant_id=None, t_received=None):
         self.rid = next(_rid)
         self.prompt = np.asarray(prompt).reshape(-1).astype(np.int64)
         if self.prompt.size == 0:
@@ -84,11 +84,22 @@ class Request:
         # the request before admission (done with zero tokens)
         self.deprioritized = False
         self.shed_reason = None
-        # lifecycle timestamps (perf_counter clock): arrival ->
-        # admission (slot claimed) -> first token -> done. The deltas
-        # feed ServingMetrics' queue-wait / TTFT / latency histograms.
+        # lifecycle timestamps (perf_counter clock): received (the
+        # caller's hand-over: the gateway stamps it before it waits
+        # for its lock; == arrival for a direct add_request) ->
+        # arrival (enqueued) -> admission (slot claimed) -> prefill
+        # dispatched -> first token -> done. TTFT, latency and the SLO
+        # verdicts count from received, queue wait and deadlines from
+        # arrival.
         self.t_arrival = time.perf_counter()
+        self.t_received = self.t_arrival if t_received is None \
+            else float(t_received)
         self.t_admitted = None
+        # when its prefill (or first chunk) was dispatched, and the
+        # padded tokens the device computed for it: its bucket, or the
+        # chunk width times its chunks (0 until a dispatch stuck)
+        self.t_prefill_dispatched = None
+        self.prefill_tokens_dispatched = 0
         self.t_first_token = None
         self.t_done = None
         # distributed tracing: the propagated TraceContext (the engine
@@ -381,6 +392,8 @@ class StepScheduler:
                 req.slot = None
             req.state = QUEUED
             req.t_admitted = None
+            req.t_prefill_dispatched = None
+            req.prefill_tokens_dispatched = 0
             self.queue.appendleft(req)
             if self.flight is not None:
                 self.flight.admission_rolled_back(req)

@@ -8,6 +8,8 @@ layers use the tensor-parallel variants so GSPMD shards them over 'mp'.
 """
 import math
 
+import jax
+
 from .. import nn
 from ..core import trace as trace_mod
 from ..ops import creation, manipulation, math as math_ops, nn_ops
@@ -140,12 +142,19 @@ class Block(nn.Layer):
         self.mlp = MLP(cfg)
 
     def forward(self, x, attn_mask=None):
+        # the scopes name the staged ops in the device trace (each
+        # half with its norm and its residual add); eager, they do
+        # nothing
         if self.pre_norm:  # GPT style
-            x = math_ops.add(x, self.attn(self.ln1(x), attn_mask))
-            x = math_ops.add(x, self.mlp(self.ln2(x)))
+            with jax.named_scope("block/attn"):
+                x = math_ops.add(x, self.attn(self.ln1(x), attn_mask))
+            with jax.named_scope("block/mlp"):
+                x = math_ops.add(x, self.mlp(self.ln2(x)))
         else:  # BERT style post-norm
-            x = self.ln1(math_ops.add(x, self.attn(x, attn_mask)))
-            x = self.ln2(math_ops.add(x, self.mlp(x)))
+            with jax.named_scope("block/attn"):
+                x = self.ln1(math_ops.add(x, self.attn(x, attn_mask)))
+            with jax.named_scope("block/mlp"):
+                x = self.ln2(math_ops.add(x, self.mlp(x)))
         return x
 
 
@@ -180,13 +189,17 @@ class _TransformerCore(nn.Layer):
 
     def forward(self, input_ids, token_type_ids=None, attn_mask=None):
         s = input_ids.shape[1]
-        pos = creation.arange(0, s, dtype="int64")
-        x = self.word_embeddings(input_ids)
-        x = math_ops.add(x, self.position_embeddings(pos))
-        if self.token_type_embeddings is not None and token_type_ids is not None:
-            x = math_ops.add(x, self.token_type_embeddings(token_type_ids))
-        if self.cfg.dropout:
-            x = nn_ops.dropout(x, p=self.cfg.dropout, training=self.training)
+        with jax.named_scope("embed"):
+            pos = creation.arange(0, s, dtype="int64")
+            x = self.word_embeddings(input_ids)
+            x = math_ops.add(x, self.position_embeddings(pos))
+            if self.token_type_embeddings is not None \
+                    and token_type_ids is not None:
+                x = math_ops.add(
+                    x, self.token_type_embeddings(token_type_ids))
+            if self.cfg.dropout:
+                x = nn_ops.dropout(x, p=self.cfg.dropout,
+                                   training=self.training)
         if getattr(self.cfg, "use_sp", False) and _sp_active():
             # sequence-shard the activations: every elementwise op /
             # LayerNorm / MLP between attentions holds only S/sp of the
@@ -247,33 +260,37 @@ def _decode_forward_builder(num_heads, head_dim, hidden_size):
         # pos..pos+t (bb = batch OR batch*beams OR one pool slot)
         bb, t = x.shape[0], x.shape[1]
         total = kc.shape[2]
-        h_ = ln(x, p["ln1_w"], p["ln1_b"])
-        qkv = h_ @ p["qkv_w"] + p["qkv_b"]
-        qkv = qkv.reshape(bb, t, 3, nh, hd).transpose(2, 0, 3, 1, 4)
-        q, k, v = qkv[0], qkv[1], qkv[2]
-        z = jnp.int32(0)  # index dtypes must all match under x64
-        kc = lax.dynamic_update_slice(kc, k, (z, z, pos, z))
-        vc = lax.dynamic_update_slice(vc, v, (z, z, pos, z))
-        s = jnp.einsum("bhtd,bhsd->bhts", q, kc) / jnp.sqrt(
-            jnp.float32(hd))
-        kpos = jnp.arange(total)[None, None, None, :]
-        qpos = pos + jnp.arange(t)[None, None, :, None]
-        s = jnp.where(kpos <= qpos, s, jnp.float32(-1e30))
-        # f32 scores/softmax; the output returns to the residual
-        # stream's dtype (a no-op for f32, keeps a bf16 model bf16)
-        o = jnp.einsum("bhts,bhsd->bhtd",
-                       jax.nn.softmax(s, axis=-1), vc).astype(x.dtype)
-        o = o.transpose(0, 2, 1, 3).reshape(bb, t, hidden_size)
-        x = x + (o @ p["out_w"] + p["out_b"])
-        h2 = ln(x, p["ln2_w"], p["ln2_b"])
-        m = jax.nn.gelu(h2 @ p["fc1_w"] + p["fc1_b"],
-                        approximate=True)
-        return x + (m @ p["fc2_w"] + p["fc2_b"]), kc, vc
+        with jax.named_scope("attn"):
+            h_ = ln(x, p["ln1_w"], p["ln1_b"])
+            qkv = h_ @ p["qkv_w"] + p["qkv_b"]
+            qkv = qkv.reshape(bb, t, 3, nh, hd).transpose(2, 0, 3, 1, 4)
+            q, k, v = qkv[0], qkv[1], qkv[2]
+            z = jnp.int32(0)  # index dtypes must all match under x64
+            with jax.named_scope("kv_write"):
+                kc = lax.dynamic_update_slice(kc, k, (z, z, pos, z))
+                vc = lax.dynamic_update_slice(vc, v, (z, z, pos, z))
+            s = jnp.einsum("bhtd,bhsd->bhts", q, kc) / jnp.sqrt(
+                jnp.float32(hd))
+            kpos = jnp.arange(total)[None, None, None, :]
+            qpos = pos + jnp.arange(t)[None, None, :, None]
+            s = jnp.where(kpos <= qpos, s, jnp.float32(-1e30))
+            # f32 scores/softmax; the output returns to the residual
+            # stream's dtype (a no-op for f32, keeps a bf16 model bf16)
+            o = jnp.einsum("bhts,bhsd->bhtd",
+                           jax.nn.softmax(s, axis=-1), vc).astype(x.dtype)
+            o = o.transpose(0, 2, 1, 3).reshape(bb, t, hidden_size)
+            x = x + (o @ p["out_w"] + p["out_b"])
+        with jax.named_scope("mlp"):
+            h2 = ln(x, p["ln2_w"], p["ln2_b"])
+            m = jax.nn.gelu(h2 @ p["fc1_w"] + p["fc1_b"],
+                            approximate=True)
+            return x + (m @ p["fc2_w"] + p["fc2_b"]), kc, vc
 
     def forward_t(pr, tok, pos, kc, vc):
         # tok [bb, t] int32; kc/vc [L, bb, nh, total, hd]
         t = tok.shape[1]
-        x = pr["wemb"][tok] + pr["pemb"][pos + jnp.arange(t)]
+        with jax.named_scope("embed"):
+            x = pr["wemb"][tok] + pr["pemb"][pos + jnp.arange(t)]
 
         def body(carry, inp):
             x = carry
@@ -282,7 +299,8 @@ def _decode_forward_builder(num_heads, head_dim, hidden_size):
             return x, (kcl, vcl)
 
         x, (kc, vc) = lax.scan(body, x, (pr["stacked"], kc, vc))
-        logits = ln(x, pr["lnf_w"], pr["lnf_b"]) @ pr["head"]
+        with jax.named_scope("lm_head"):
+            logits = ln(x, pr["lnf_w"], pr["lnf_b"]) @ pr["head"]
         return logits, kc, vc
 
     return ln, forward_t
@@ -320,15 +338,18 @@ class GPTForCausalLM(nn.Layer):
             # they keep ~8 GiB of logits-sized intermediates live at
             # GPT-124M x 8192 tokens.
             from ..ops.fused_ce import fused_linear_cross_entropy
-            flat = manipulation.reshape(labels, (-1,))
-            per_tok = fused_linear_cross_entropy(
-                manipulation.reshape(h, (-1, self.cfg.hidden_size)),
-                self.gpt.word_embeddings.weight, flat)
-            # mean over NON-IGNORED tokens, matching cross_entropy's
-            # reduction='mean' (a plain mean would scale loss/grads by
-            # the valid fraction on padded batches)
-            valid = (flat != -100).astype("float32").sum()
-            return per_tok.sum() / valid.clip(min=1.0)
+            # head and loss are one kernel here: one scope, both names
+            with jax.named_scope("lm_head"), jax.named_scope("loss"):
+                flat = manipulation.reshape(labels, (-1,))
+                per_tok = fused_linear_cross_entropy(
+                    manipulation.reshape(h, (-1, self.cfg.hidden_size)),
+                    self.gpt.word_embeddings.weight, flat)
+                # mean over NON-IGNORED tokens, matching
+                # cross_entropy's reduction='mean' (a plain mean would
+                # scale loss/grads by the valid fraction on padded
+                # batches)
+                valid = (flat != -100).astype("float32").sum()
+                return per_tok.sum() / valid.clip(min=1.0)
         if labels is not None and self.cfg.tie_embeddings \
                 and self.cfg.use_mp and mesh is not None and in_step:
             # TP: the vocab-sharded fused kernel — each mp shard
@@ -344,22 +365,26 @@ class GPTForCausalLM(nn.Layer):
                 t *= int(d)
             if tp_fused_applicable(mesh, t, self.cfg.hidden_size,
                                    self.cfg.vocab_size):
-                flat = manipulation.reshape(labels, (-1,))
-                per_tok = fused_linear_cross_entropy_tp(
-                    manipulation.reshape(h, (-1, self.cfg.hidden_size)),
-                    self.gpt.word_embeddings.weight, flat, mesh)
-                valid = (flat != -100).astype("float32").sum()
-                return per_tok.sum() / valid.clip(min=1.0)
-        if self.cfg.tie_embeddings:
-            logits = math_ops.matmul(h, self.gpt.word_embeddings.weight,
-                                     transpose_y=True)
-        else:
-            logits = self.lm_head(h)
+                with jax.named_scope("lm_head"), jax.named_scope("loss"):
+                    flat = manipulation.reshape(labels, (-1,))
+                    per_tok = fused_linear_cross_entropy_tp(
+                        manipulation.reshape(
+                            h, (-1, self.cfg.hidden_size)),
+                        self.gpt.word_embeddings.weight, flat, mesh)
+                    valid = (flat != -100).astype("float32").sum()
+                    return per_tok.sum() / valid.clip(min=1.0)
+        with jax.named_scope("lm_head"):
+            if self.cfg.tie_embeddings:
+                logits = math_ops.matmul(
+                    h, self.gpt.word_embeddings.weight, transpose_y=True)
+            else:
+                logits = self.lm_head(h)
         if labels is None:
             return logits
-        loss = nn_ops.cross_entropy(
-            manipulation.reshape(logits, (-1, self.cfg.vocab_size)),
-            manipulation.reshape(labels, (-1,)))
+        with jax.named_scope("loss"):
+            loss = nn_ops.cross_entropy(
+                manipulation.reshape(logits, (-1, self.cfg.vocab_size)),
+                manipulation.reshape(labels, (-1,)))
         return loss
 
     def export_decode_params(self):
@@ -458,24 +483,27 @@ class GPTForCausalLM(nn.Layer):
         def _prefill_core(params, tokens, lengths, slots, toks, pos,
                           kc, vc, samp):
             # tokens [G, bucket]; lengths/slots [G]; toks/pos [S]
-            kcs = jnp.take(kc, slots, axis=1)   # [L, G, nh, C, hd]
-            vcs = jnp.take(vc, slots, axis=1)
+            with jax.named_scope("kv_gather"):
+                kcs = jnp.take(kc, slots, axis=1)   # [L, G, nh, C, hd]
+                vcs = jnp.take(vc, slots, axis=1)
             logits, kcs, vcs = forward_t(params, tokens, jnp.int32(0),
                                          kcs, vcs)
-            kc = kc.at[:, slots].set(kcs)
-            vc = vc.at[:, slots].set(vcs)
-            last = jnp.take_along_axis(
-                logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
-            if samp is None:
-                first = jnp.argmax(last, -1).astype(jnp.int32)  # [G]
-            else:
-                seeds, temps, topks, topps = samp
-                first = head(last, seeds, lengths - 1, temps, topks,
-                             topps)
-            toks = toks.at[slots].set(first)
-            # the next decode writes each group member at position
-            # lengths[g] (its first generated token's cache row)
-            pos = pos.at[slots].set(lengths)
+            with jax.named_scope("kv_write"):
+                kc = kc.at[:, slots].set(kcs)
+                vc = vc.at[:, slots].set(vcs)
+            with jax.named_scope("sample"):
+                last = jnp.take_along_axis(
+                    logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
+                if samp is None:
+                    first = jnp.argmax(last, -1).astype(jnp.int32)  # [G]
+                else:
+                    seeds, temps, topks, topps = samp
+                    first = head(last, seeds, lengths - 1, temps, topks,
+                                 topps)
+                toks = toks.at[slots].set(first)
+                # the next decode writes each group member at position
+                # lengths[g] (its first generated token's cache row)
+                pos = pos.at[slots].set(lengths)
             return first, toks, pos, kc, vc
 
         if sampling:
@@ -502,36 +530,42 @@ class GPTForCausalLM(nn.Layer):
             S = toks.shape[0]
             # parked / idle slots' positions keep incrementing past
             # the table; clamp so the (ignored) row reads in-bounds
-            x = params["wemb"][toks] + params["pemb"][
-                jnp.minimum(pos, params["pemb"].shape[0] - 1)]
+            with jax.named_scope("embed"):
+                x = params["wemb"][toks] + params["pemb"][
+                    jnp.minimum(pos, params["pemb"].shape[0] - 1)]
 
             def body(carry, inp):
                 x = carry
                 p, kcl, vcl = inp
-                h_ = ln(x, p["ln1_w"], p["ln1_b"])
-                qkv = h_ @ p["qkv_w"] + p["qkv_b"]
-                qkv = qkv.reshape(S, 3, nh, hd).transpose(1, 0, 2, 3)
-                q, k, v = qkv[0], qkv[1], qkv[2]      # [S, nh, hd]
-                kcl = write_slot(kcl, k, pos)
-                vcl = write_slot(vcl, v, pos)
-                o = attn_ops.cached_slot_attention(q, kcl, vcl,
-                                                   pos + 1)
-                o = o.reshape(S, hidden)              # concat heads
-                x = x + (o @ p["out_w"] + p["out_b"])
-                h2 = ln(x, p["ln2_w"], p["ln2_b"])
-                m = jax.nn.gelu(h2 @ p["fc1_w"] + p["fc1_b"],
-                                approximate=True)
-                return x + (m @ p["fc2_w"] + p["fc2_b"]), (kcl, vcl)
+                with jax.named_scope("attn"):
+                    h_ = ln(x, p["ln1_w"], p["ln1_b"])
+                    qkv = h_ @ p["qkv_w"] + p["qkv_b"]
+                    qkv = qkv.reshape(S, 3, nh, hd).transpose(1, 0, 2, 3)
+                    q, k, v = qkv[0], qkv[1], qkv[2]      # [S, nh, hd]
+                    with jax.named_scope("kv_write"):
+                        kcl = write_slot(kcl, k, pos)
+                        vcl = write_slot(vcl, v, pos)
+                    o = attn_ops.cached_slot_attention(q, kcl, vcl,
+                                                       pos + 1)
+                    o = o.reshape(S, hidden)              # concat heads
+                    x = x + (o @ p["out_w"] + p["out_b"])
+                with jax.named_scope("mlp"):
+                    h2 = ln(x, p["ln2_w"], p["ln2_b"])
+                    m = jax.nn.gelu(h2 @ p["fc1_w"] + p["fc1_b"],
+                                    approximate=True)
+                    return x + (m @ p["fc2_w"] + p["fc2_b"]), (kcl, vcl)
 
             x, (kc, vc) = lax.scan(body, x,
                                    (params["stacked"], kc, vc))
-            logits = ln(x, params["lnf_w"], params["lnf_b"]) \
-                @ params["head"]                      # [S, vocab]
-            if samp is None:
-                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            else:
-                seeds, temps, topks, topps = samp
-                nxt = head(logits, seeds, pos, temps, topks, topps)
+            with jax.named_scope("lm_head"):
+                logits = ln(x, params["lnf_w"], params["lnf_b"]) \
+                    @ params["head"]                      # [S, vocab]
+            with jax.named_scope("sample"):
+                if samp is None:
+                    nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+                else:
+                    seeds, temps, topks, topps = samp
+                    nxt = head(logits, seeds, pos, temps, topks, topps)
             return nxt, pos + jnp.int32(1), kc, vc
 
         if sampling:
