@@ -125,5 +125,6 @@ from .tracing import (  # noqa: F401
 )
 from .watchdog import (  # noqa: F401
     CompileAfterWarmupError, CompileWatchdog, abstract_signature,
-    device_memory_stats, executable_cost, watch_jax_lowering,
+    device_memory_stats, executable_cost, executable_memory,
+    watch_jax_lowering,
 )

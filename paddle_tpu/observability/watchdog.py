@@ -96,6 +96,20 @@ def executable_cost(compiled):
     return out or None
 
 
+def executable_memory(compiled):
+    """Best-effort ``compiled.memory_analysis()`` of one executable as
+    ``{"alias_bytes": ..., "temp_bytes": ...}`` (ints): the bytes of
+    donated arguments the program really updates in place, and the
+    temporaries it holds beside its arguments while it runs. None
+    where the backend's executable gives no memory analysis."""
+    try:
+        m = compiled.memory_analysis()
+        return {"alias_bytes": int(m.alias_size_in_bytes),
+                "temp_bytes": int(m.temp_size_in_bytes)}
+    except Exception:
+        return None
+
+
 def device_memory_stats(device=None):
     """Best-effort ``device.memory_stats()`` as a JSON-safe dict of
     numeric fields (PJRT reports e.g. bytes_in_use / bytes_limit /

@@ -20,7 +20,9 @@ ticks:
     the pooled kc/vc (and the position vector) donated, so on donating
     backends (TPU/GPU) the cache updates in place instead of
     double-buffering ~2x its footprint per call (CPU ignores donation;
-    ``metrics.kv_donation`` reports both facts);
+    ``metrics.kv_donation`` reports both facts, and beside them what
+    the compiled decode program really aliases and holds as
+    temporaries against the pool's bytes);
   * **one-step-deep async decode pipelining** — step N's token values
     are read back only AFTER step N+1's decode has been dispatched
     (tokens and write positions chain device-side through the
@@ -66,7 +68,7 @@ import numpy as np
 from ..analysis import threads as _lockpatrol
 from ..observability import (CompileWatchdog, FlightRecorder,
                              abstract_signature, device_memory_stats,
-                             executable_cost)
+                             executable_cost, executable_memory)
 from .kv_pool import SlotKVPool
 from .metrics import ServingMetrics
 from .paged.pool import TRASH_BLOCK
@@ -970,9 +972,20 @@ class ServingEngine:
             self.watchdog.annotate(
                 event["seq"], cost=cost,
                 memory=device_memory_stats(self._device))
-            if key == ("decode",) and cost:
-                self.metrics.set_decode_cost(
-                    cost.get("flops"), cost.get("bytes_accessed"))
+            if key == ("decode",):
+                if cost:
+                    self.metrics.set_decode_cost(
+                        cost.get("flops"), cost.get("bytes_accessed"))
+                mem = executable_memory(ex)
+                if mem:
+                    # is the KV pool held once or twice while decode
+                    # runs: aliased >= pool and temporaries well under
+                    # it mean the donated pool is updated in place
+                    self.metrics.kv_donation.update(
+                        decode_alias_bytes=mem["alias_bytes"],
+                        decode_temp_bytes=mem["temp_bytes"],
+                        pool_bytes=int(self.pool.kc.nbytes
+                                       + self.pool.vc.nbytes))
             if cost:
                 # the same cost_analysis prices this program's
                 # roofline floor in snapshot()["perf"] (no-op with

@@ -41,6 +41,27 @@ table instead of a slot-contiguous region:
       of cycling across block MB-1's offsets or gathering out of
       bounds.
 
+How the decode program carries the pool (ISSUE 26). The layer loop is
+a ``lax.scan`` over (stacked weights, layer index) with the pool in its
+CARRY, addressed flat as ``[L*NB, nh, BS, hd]`` (a reshape of the
+row-major pool: a bitcast); layer ``l`` reads and writes block ``b`` as
+flat row ``l*NB + b``, so attention gets the flat pool and
+``tables + l*NB`` (layer ``l``'s trash block is row ``l*NB + trash``).
+The pool as a scanned input and stacked output was sliced apart and
+restacked every step: five pool-sized passes, and a second pool in
+memory, because stacked outputs cannot alias scanned inputs. The write
+is a whole-block read-modify-write (gather the S current blocks, put
+the new row in, scatter the blocks back): a scatter whose window is
+every trailing dimension leaves the pool's layout row-major from
+parameter to result, so the donated buffer is updated in place; the row
+scatter ``at[fb, :, off]`` made XLA relayout the whole pool around the
+loop. Whole blocks are safe because a decode step's write blocks are
+private to their slot (the invariant ``pool.acquire`` asserts); the
+only duplicate targets are parked or released slots meeting in the
+trash block, where whichever wins is garbage behind the length mask.
+``tests/test_chip_compile.py`` holds the compiled program to this
+(aliased pool, temporaries under one pool half, no pool-shaped copy).
+
 Scatter/gather safety: table-row padding and released rows point at
 the reserved trash block, so pad-entry writes land in garbage, and the
 length mask keeps garbage reads at exactly-zero softmax weight — the
@@ -162,36 +183,54 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
         bidx = jnp.take_along_axis(tables, col[:, None], axis=1)[:, 0]
         off = wpos % jnp.int32(BS)
 
+        # the pool rides the layer loop as CARRIED state, flat: layer
+        # l's block b is row l*NB + b (module docstring)
+        NB = kc.shape[1]
+        kf = kc.reshape((L * NB,) + kc.shape[2:])
+        vf = vc.reshape((L * NB,) + vc.shape[2:])
+        row = (jnp.arange(BS, dtype=jnp.int32)[None, :]
+               == off[:, None])[:, None, :, None]      # [S, 1, BS, 1]
+
         def body(carry, inp):
-            x = carry
-            p, kcl, vcl = inp
+            x, kf, vf = carry
+            p, layer = inp
+            base = layer * jnp.int32(NB)
             with jax.named_scope("attn"):
                 h_ = ln(x, p["ln1_w"], p["ln1_b"])
                 qkv = h_ @ p["qkv_w"] + p["qkv_b"]
                 qkv = qkv.reshape(S, 3, nh, hd).transpose(1, 0, 2, 3)
                 q, k, v = qkv[0], qkv[1], qkv[2]          # [S, nh, hd]
-                # per-slot row write into its current (privately-
-                # owned) block: advanced indexing [S],:,[S] scatters
-                # [S, nh, hd]
+                # whole-block read-modify-write of each slot's current
+                # (privately-owned) block, so the carried pool is
+                # updated in place. The only duplicate fb are parked /
+                # released slots meeting in the trash block, where any
+                # winner is garbage behind the length mask.
                 with jax.named_scope("kv_write"):
-                    kcl = kcl.at[bidx, :, off].set(k)
-                    vcl = vcl.at[bidx, :, off].set(v)
+                    fb = base + bidx                      # [S]
+                    kf = kf.at[fb].set(jnp.where(
+                        row, k.astype(kf.dtype)[:, :, None], kf[fb]))
+                    vf = vf.at[fb].set(jnp.where(
+                        row, v.astype(vf.dtype)[:, :, None], vf[fb]))
+                ltab = tables + base     # layer l's trash: l*NB + trash
                 if attn_kernel:
                     o = paged_attn_ops.paged_decode_attention(
-                        q, kcl, vcl, tables, pos + 1)
+                        q, kf, vf, ltab, pos + 1)
                 else:
                     # gathers the slots' blocks under "kv_gather"
                     o = attn_ops.cached_paged_attention(
-                        q, kcl, vcl, tables, pos + 1)
+                        q, kf, vf, ltab, pos + 1)
                 o = o.reshape(S, hidden)                  # concat heads
                 x = x + (o @ p["out_w"] + p["out_b"])
             with jax.named_scope("mlp"):
                 h2 = ln(x, p["ln2_w"], p["ln2_b"])
                 m = jax.nn.gelu(h2 @ p["fc1_w"] + p["fc1_b"],
                                 approximate=True)
-                return x + (m @ p["fc2_w"] + p["fc2_b"]), (kcl, vcl)
+                return (x + (m @ p["fc2_w"] + p["fc2_b"]), kf, vf), None
 
-        x, (kc, vc) = lax.scan(body, x, (params["stacked"], kc, vc))
+        (x, kf, vf), _ = lax.scan(
+            body, (x, kf, vf),
+            (params["stacked"], jnp.arange(L, dtype=jnp.int32)))
+        kc, vc = kf.reshape(kc.shape), vf.reshape(vc.shape)
         with jax.named_scope("lm_head"):
             logits = ln(x, params["lnf_w"], params["lnf_b"]) \
                 @ params["head"]                          # [S, vocab]

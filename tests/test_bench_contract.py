@@ -90,7 +90,7 @@ def test_bench_serving_smoke_emits_contract_line_rc0(tmp_path):
         # KV-donation status, dispatch-vs-sync wall split — in the
         # engine snapshot AND the deep-queue scenario section
         snap = evidence["serving_metrics"]
-        assert set(snap["kv_donation"]) == {"enabled", "effective"}
+        assert set(snap["kv_donation"]) >= {"enabled", "effective"}
         assert snap["dispatch_s"] >= 0 and snap["sync_s"] >= 0
         assert snap["prefill_requests"] >= snap["prefills"] > 0
         # PR 3 observability sections: latency percentiles from the
@@ -513,7 +513,7 @@ def test_bench_serving_smoke_emits_contract_line_rc0(tmp_path):
         dq = evidence["deep_queue"]
         assert dq["group_sizes_used"] and \
             max(dq["group_sizes_used"]) > 1   # grouped prefill fired
-        assert set(dq["kv_donation"]) == {"enabled", "effective"}
+        assert set(dq["kv_donation"]) >= {"enabled", "effective"}
         assert dq["dispatch_s"] >= 0 and dq["sync_s"] >= 0
         assert dq["vs_pr1_engine"] > 0
         assert dq["steady_state_new_compiles"] == 0

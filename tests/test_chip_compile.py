@@ -122,6 +122,84 @@ def test_paged_decode_compiles(one_chip, dtype, monkeypatch):
     _compile(paged_attention.paged_decode_attention, q, kv, kv, bt, ln)
 
 
+def _paged_decode_program(one_chip, sampling, attn_kernel):
+    """(compiled paged_decode, pool shape), jitted with the engine's
+    donation (pos, kc, vc), at sizes where the pool dominates the
+    program: 8 layers x 257 blocks of [16 heads, 16 tokens, 128] bf16
+    (135 MB for K, as much for V), 4 slots, narrow MLP and vocab."""
+    from paddle_tpu.serving.paged.programs import build_paged_fns
+    from paddle_tpu.text.models import TransformerLMConfig
+    L, S, BS, MB, nh, hd = 8, 4, 16, 64, 16, 128
+    NB, hidden, ffn, vocab = S * MB + 1, nh * hd, 512, 512
+    cfg = TransformerLMConfig(vocab_size=vocab, hidden_size=hidden,
+                              num_layers=L, num_heads=nh,
+                              intermediate_size=ffn, max_seq_len=MB * BS,
+                              dropout=0.0)
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    stacked = {"ln1_w": (hidden,), "ln1_b": (hidden,),
+               "qkv_w": (hidden, 3 * hidden), "qkv_b": (3 * hidden,),
+               "out_w": (hidden, hidden), "out_b": (hidden,),
+               "ln2_w": (hidden,), "ln2_b": (hidden,),
+               "fc1_w": (hidden, ffn), "fc1_b": (ffn,),
+               "fc2_w": (ffn, hidden), "fc2_b": (hidden,)}
+    params = {"stacked": {k: sds((L,) + v) for k, v in stacked.items()},
+              "wemb": sds((vocab, hidden)), "pemb": sds((MB * BS, hidden)),
+              "lnf_w": sds((hidden,)), "lnf_b": sds((hidden,)),
+              "head": sds((hidden, vocab))}
+    pool = (L, NB, nh, BS, hd)
+    i32 = jnp.int32
+    args = [params, sds((S,), i32), sds((S,), i32), sds((S, MB), i32),
+            sds(pool), sds(pool)]
+    if sampling:   # seeds, temps, top-k, top-p (sched.sampling)
+        args += [sds((S,), jnp.uint32), sds((S,), jnp.float32),
+                 sds((S,), i32), sds((S,), jnp.float32)]
+    _, decode = build_paged_fns(cfg, S, BS, NB, MB, sampling=sampling,
+                                attn_kernel=attn_kernel)
+    return jax.jit(decode, donate_argnums=(2, 4, 5)).lower(
+        *args).compile(), pool
+
+
+@pytest.mark.parametrize("sampling", [False, True],
+                         ids=["greedy", "sampling"])
+def test_paged_decode_program_updates_pool_in_place(one_chip, sampling):
+    """The decode program carries the donated KV pool through its layer
+    loop in place: both pools aliased onto the results, temporaries
+    under ONE pool half (a second pool beside the first would be two),
+    and no copy / dynamic-slice / dynamic-update-slice left in the
+    optimized program with the pool's shape or one layer's."""
+    import re
+    compiled, (L, NB, nh, BS, hd) = _paged_decode_program(
+        one_chip, sampling, attn_kernel=False)
+    half = L * NB * nh * BS * hd * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * half
+    assert mem.temp_size_in_bytes < half, (mem.temp_size_in_bytes, half)
+    shapes = "|".join(f"{lead},{nh},{BS},{hd}" for lead in (
+        f"{L},{NB}", f"1,{NB}", f"{L * NB}", f"{NB}"))
+    inst = re.compile(
+        rf"%([\w.\-]+) = bf16\[(?:{shapes})\]\S* ([\w\-]+)\(")
+    found = [m.groups() for m in map(inst.search,
+                                     compiled.as_text().splitlines()) if m]
+    assert found   # the pool is in the program under these shapes
+    moving = ("copy", "dynamic-slice", "dynamic-update-slice")
+    bad = [(name, op) for name, op in found
+           if op in moving or any(w in name for w in moving)]
+    assert not bad, bad
+
+
+def test_paged_decode_program_kernel_aliases_pool(one_chip):
+    """attn_kernel=True (what no benchmark cell runs): the program
+    compiles with the Pallas kernel in it, reading the carried flat
+    pool, and aliases both pools."""
+    compiled, (L, NB, nh, BS, hd) = _paged_decode_program(
+        one_chip, sampling=False, attn_kernel=True)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        2 * 2 * L * NB * nh * BS * hd
+
+
 @pytest.mark.parametrize("vocab,kernel", [(50432, True), (VOCAB, False)])
 def test_tp_fused_ce_shard_map_compiles(topo, monkeypatch, vocab, kernel):
     """The vocab-sharded fused-CE head (fwd + bwd) on a dp2 x mp2 mesh

@@ -270,3 +270,102 @@ def test_cached_paged_attention_matches_slot_attention():
     ref = cached_slot_attention(q, jnp.asarray(kv_slot),
                                 jnp.asarray(vv_slot), lengths)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+def _parent_decode_step(params, toks, pos, tables, kc, vc, nh, BS):
+    """The decode step as the parent of ISSUE 26 formulated it, layer
+    by layer in plain jnp: each layer sliced out of the pool, a ROW
+    scatter into the slice, the pool put together again."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.attention import cached_paged_attention
+    from paddle_tpu.text.models import _decode_forward_builder
+    (S,), (L, _, _, _, hd) = toks.shape, kc.shape
+    ln, _ = _decode_forward_builder(nh, hd, nh * hd)
+    x = params["wemb"][toks] + params["pemb"][pos]
+    wpos = jnp.minimum(pos, tables.shape[1] * BS - 1)
+    bidx, off = tables[jnp.arange(S), wpos // BS], wpos % BS
+    for l in range(L):
+        p = {k: v[l] for k, v in params["stacked"].items()}
+        q, k, v = (ln(x, p["ln1_w"], p["ln1_b"]) @ p["qkv_w"]
+                   + p["qkv_b"]).reshape(S, 3, nh, hd).transpose(1, 0, 2, 3)
+        kc = kc.at[l, bidx, :, off].set(k)
+        vc = vc.at[l, bidx, :, off].set(v)
+        o = cached_paged_attention(q, kc[l], vc[l], tables, pos + 1)
+        x = x + (o.reshape(S, nh * hd) @ p["out_w"] + p["out_b"])
+        m = jax.nn.gelu(ln(x, p["ln2_w"], p["ln2_b"]) @ p["fc1_w"]
+                        + p["fc1_b"], approximate=True)
+        x = x + (m @ p["fc2_w"] + p["fc2_b"])
+    logits = ln(x, params["lnf_w"], params["lnf_b"]) @ params["head"]
+    return jnp.argmax(logits, -1).astype(jnp.int32), pos + 1, kc, vc
+
+
+@pytest.mark.parametrize("trash_writers", [1, 3], ids=["one", "meeting"])
+def test_paged_decode_writes_only_its_rows_in_every_layer(trash_writers):
+    """One paged_decode step on a pool of distinct values changes, in
+    EVERY layer l, exactly the rows (l, bidx[s], :, off[s]) and nothing
+    else (no layer bleeds into its neighbour's flat block range), and
+    tokens and pool are those of the parent's formulation. Slots: two
+    live ones at different positions behind a SHARED prefix block, a
+    fresh one, one parked past its row (pos >= MB*BS), and released
+    rows (all trash). With one trash writer every row is determined;
+    with several ("meeting": two released rows and a chunk-parked slot
+    whose last block is trash) the whole-block write lets any ONE of
+    them win the trash block, which stays garbage behind the length
+    mask: everything outside the trash block still matches."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving.paged.pool import TRASH_BLOCK as TR
+    from paddle_tpu.serving.paged.programs import build_paged_fns
+
+    m = _model(num_layers=3)
+    cfg, params = m.cfg, m.export_decode_params()
+    L, nh, BS, MB = cfg.num_layers, cfg.num_heads, 4, 4
+    hd, C = cfg.hidden_size // nh, MB * BS
+    tables = [[1, 2, TR, TR],      # live, pos 5: block 2, offset 1
+              [1, 3, 4, TR],       # shares prefix block 1; pos 10
+              [5, 6, 7, 8],        # parked past its row: (8, BS-1)
+              [TR, TR, TR, TR],    # released: trash, offset 3
+              [9, TR, TR, TR]]     # fresh: block 9, offset 0
+    pos = [5, 10, C + 3, 7, 0]
+    if trash_writers == 3:
+        tables += [[TR] * MB, [10, 11, TR, TR]]   # released; chunk-parked
+        pos += [2, C - 1]
+    S, NB = len(pos), 12
+    # slots whose token is read through a trash block that several wrote
+    live = np.setdiff1d(np.arange(S), [3, 5, 6] if trash_writers > 1 else [])
+    rs = np.random.RandomState(26)
+    kc0 = rs.randn(L, NB, nh, BS, hd).astype(np.float32)
+    vc0 = rs.randn(L, NB, nh, BS, hd).astype(np.float32)
+    toks = jnp.asarray(rs.randint(1, cfg.vocab_size, S).astype(np.int32))
+    pos, tables = jnp.asarray(pos, jnp.int32), jnp.asarray(tables, jnp.int32)
+
+    _, decode = build_paged_fns(cfg, S, BS, NB, MB)
+    nxt, pos1, kc1, vc1 = jax.jit(decode)(
+        params, toks, pos, tables, jnp.asarray(kc0), jnp.asarray(vc0))
+    r_nxt, r_pos1, r_kc, r_vc = jax.jit(
+        _parent_decode_step, static_argnums=(6, 7))(
+        params, toks, pos, tables, jnp.asarray(kc0), jnp.asarray(vc0),
+        nh, BS)
+
+    wpos = np.minimum(np.asarray(pos), C - 1)
+    bidx = np.asarray(tables)[np.arange(S), wpos // BS]
+    expect = np.zeros((L, NB, nh, BS), bool)
+    expect[:, bidx, :, wpos % BS] = True
+    keep = np.arange(NB) != TR if trash_writers > 1 else np.ones(NB, bool)
+    for new, old, ref in ((kc1, kc0, r_kc), (vc1, vc0, r_vc)):
+        new = np.asarray(new)
+        changed = (new != old).any(-1)
+        np.testing.assert_array_equal(changed[:, keep], expect[:, keep])
+        # untouched rows are the input's bits; written rows the parent's
+        np.testing.assert_array_equal(new[:, keep],
+                                      np.asarray(ref)[:, keep])
+    if trash_writers > 1:
+        # the trash block took whole blocks only: per layer at most
+        # one of its rows differs from the input
+        assert (changed[:, TR].any(1).sum(-1) <= 1).all()
+    np.testing.assert_array_equal(np.asarray(nxt)[live],
+                                  np.asarray(r_nxt)[live])
+    np.testing.assert_array_equal(np.asarray(pos1), np.asarray(r_pos1))

@@ -327,7 +327,15 @@ def test_snapshot_surfaces_pipeline_metrics():
     snap = eng.metrics.snapshot()
     assert snap["prefill_requests"] == 3
     assert sum(int(g) * c for g, c in snap["prefill_groups"].items()) == 3
-    assert set(snap["kv_donation"]) == {"enabled", "effective"}
+    kvd = snap["kv_donation"]
+    mem_keys = {"decode_alias_bytes", "decode_temp_bytes", "pool_bytes"}
+    # the decode executable's memory picture rides along wherever the
+    # backend's executable gives one (all three keys or none)
+    assert set(kvd) in ({"enabled", "effective"},
+                        {"enabled", "effective"} | mem_keys)
+    if "pool_bytes" in kvd:
+        assert kvd["pool_bytes"] == eng.pool.kc.nbytes + eng.pool.vc.nbytes
+        assert min(kvd["decode_temp_bytes"], kvd["decode_alias_bytes"]) >= 0
     assert snap["dispatch_s"] > 0 and snap["sync_s"] >= 0
     assert snap["speculative_masked"] >= 0
 
