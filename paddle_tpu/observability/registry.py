@@ -240,7 +240,11 @@ class _Family:
         with self._lock:
             child = self._children.get(values)
             if child is None:
-                cap = getattr(self._registry, "max_label_values", 0)
+                # a family of a known, bounded shape (one series an
+                # expert) may state its own cap
+                cap = getattr(self, "max_label_values", None)
+                if cap is None:
+                    cap = getattr(self._registry, "max_label_values", 0)
                 if cap and len(self._children) >= cap:
                     folded = True
                     values = tuple(OVERFLOW_LABEL for _ in values)
@@ -606,6 +610,24 @@ class MetricsRegistry:
         # the registry — overflow folds into OVERFLOW_LABEL and counts
         # in metrics_label_overflow_total{family}
         self.max_label_values = int(max_label_values)
+        self._collect_hooks = []
+
+    def add_collect_hook(self, fn):
+        """``fn()`` runs before every exposition (snapshot or scrape):
+        for values that live elsewhere (a device array) and are only
+        worth fetching when somebody looks. A failing hook counts as a
+        scrape error and the exposition goes on."""
+        with self._lock:
+            self._collect_hooks.append(fn)
+
+    def _collect(self):
+        with self._lock:
+            hooks = list(self._collect_hooks)
+        for fn in hooks:
+            try:
+                fn()
+            except Exception:  # noqa: BLE001 - never break a scrape
+                self.scrape_error(getattr(fn, "__name__", "collect_hook"))
 
     def _register(self, cls, name, help_text, labelnames, **kw):
         with self._lock:
@@ -667,6 +689,7 @@ class MetricsRegistry:
     def snapshot(self):
         """Stable, JSON-serializable view: family name -> {type, help,
         values} with label series keyed 'k=v,k=v' ('' for unlabeled)."""
+        self._collect()
         out = {}
         for fam in self.families():
             values = {}
@@ -692,6 +715,7 @@ class MetricsRegistry:
         """Prometheus text exposition format 0.0.4: HELP/TYPE lines,
         escaped label values, cumulative histogram buckets with the
         canonical _bucket/_sum/_count triple."""
+        self._collect()
         lines = []
         for fam in self.families():
             if fam.help:

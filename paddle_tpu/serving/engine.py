@@ -478,20 +478,37 @@ class ServingEngine:
             raise ValueError(
                 f"prefill_chunk {self.chunk_len} exceeds the per-slot "
                 f"capacity {cache_len}")
-        # the KV pool holds what the model computes: K/V come out of
-        # the qkv projection in the weights' dtype, so a bf16 model
-        # gets a bf16 pool (an f32 pool would only store the same
-        # values at twice the bytes)
-        kv_dtype = self.params["stacked"]["qkv_w"].dtype
+        # a model that has programs for only some of the engine's
+        # options refuses the others here, by name
+        check = getattr(model, "check_serving_config", None)
+        if check is not None:
+            check(config)
+        # the pool holds what the model's CACHE SPEC says a token owns
+        # (a GPT: K and V as the qkv projection computes them, in the
+        # weights' dtype, so a bf16 model gets a bf16 pool; latent
+        # attention: one latent and one rotary key, no head axis). A
+        # model without the method is a GPT.
+        if hasattr(model, "cache_spec"):
+            self.cache_spec = model.cache_spec()
+        else:
+            from .paged.cache_spec import kv_pair_spec
+            self.cache_spec = kv_pair_spec(
+                cfg.num_layers, cfg.num_heads,
+                cfg.hidden_size // cfg.num_heads,
+                self.params["stacked"]["qkv_w"].dtype)
+        kv_dtype = self.cache_spec.arrays[0].dtype
+        # the GPT's (k, v) pair: what kv_wire, the speculative verify
+        # programs and the analytic decode model are written for
+        self._kv_pair = [a.name for a in self.cache_spec.arrays] \
+            == ["k", "v"]
         if self.paged:
             from .paged import PagedKVPool
 
             def _pool_factory():
                 return PagedKVPool(
-                    config.num_slots, cfg.num_layers, cfg.num_heads,
-                    cache_len, cfg.hidden_size // cfg.num_heads,
+                    config.num_slots, max_len=cache_len,
                     block_size=config.block_size,
-                    num_blocks=config.num_blocks, dtype=kv_dtype)
+                    num_blocks=config.num_blocks, spec=self.cache_spec)
 
             self._pool_factory = _pool_factory
             self.pool = _pool_factory()
@@ -503,9 +520,9 @@ class ServingEngine:
             from ..ops.paged_attention import kernel_viable
             import jax
             shape = (cfg.num_heads, cfg.hidden_size // cfg.num_heads,
-                     self.pool.block_size, self.pool.kc.dtype)
+                     self.pool.block_size, kv_dtype)
             self.paged_attn = bool(config.paged_attn) \
-                and kernel_viable(*shape)
+                and self._kv_pair and kernel_viable(*shape)
             if config.paged_attn and not self.paged_attn \
                     and jax.default_backend() != "cpu":
                 # asked for by name on a backend that has Mosaic: a
@@ -785,6 +802,11 @@ class ServingEngine:
         # inputs never depend on step N's values reaching the host.
         self._toks = jnp.zeros((config.num_slots,), jnp.int32)
         self._pos = jnp.zeros((config.num_slots,), jnp.int32)
+        # what the decode program carries beside the cache (the cache
+        # spec's ``state``: a GPT has none): returned new each step and
+        # never donated, so whoever reads it holds a live array
+        self._state = tuple(jnp.zeros(shape, dt) for _, shape, dt
+                            in self.cache_spec.state)
         self._pending = []  # dispatched, not-yet-read device results
         # first callback's start / summed callback seconds of the
         # harvest in progress (its serving/on_token span)
@@ -811,7 +833,13 @@ class ServingEngine:
         if self.paged:
             self.metrics.set_prefix_pool(self.pool.stats)
             self.metrics.cache.attach_pool(self.pool)
-        if self._perf_on:
+        self.metrics.set_kv_bytes_per_token(
+            self.cache_spec.bytes_per_token)
+        moe = getattr(model, "moe_counter_layout", None)
+        if moe is not None:
+            self.metrics.set_moe_counters(
+                lambda: np.asarray(self._state[0]), **moe())
+        if self._perf_on and self._kv_pair:
             # price the per-program roofline (the CPU has no peaks:
             # device_peak/device_hbm=false and None fractions in the
             # report; an unknown TPU kind raised above) and attach
@@ -984,8 +1012,7 @@ class ServingEngine:
                     self.metrics.kv_donation.update(
                         decode_alias_bytes=mem["alias_bytes"],
                         decode_temp_bytes=mem["temp_bytes"],
-                        pool_bytes=int(self.pool.kc.nbytes
-                                       + self.pool.vc.nbytes))
+                        pool_bytes=int(self.pool.nbytes()))
             if cost:
                 # the same cost_analysis prices this program's
                 # roofline floor in snapshot()["perf"] (no-op with
@@ -1491,13 +1518,9 @@ class ServingEngine:
             fn = self._kv_import_fn
             donate = (0, 1) if self._donate else ()
         elif self.paged:
-            args = (self.params, self._toks, self._pos,
-                    self.pool.device_tables(), self.pool.kc,
-                    self.pool.vc)
-            if self.sampling:
-                args = args + self._sampler.device_arrays()
+            args, donate = self._decode_dispatch_args(self.pool)
             fn = self._decode_fn
-            donate = (2, 4, 5) if self._donate else ()
+            donate = donate if self._donate else ()
         else:
             args = (self.params, self._toks, self._pos, self.pool.kc,
                     self.pool.vc)
@@ -1762,9 +1785,11 @@ class ServingEngine:
         — one place, shared by the hot path and the warm-both-flavors
         discipline of the speculative schedule."""
         if self.paged:
+            # the cache spec's arrays, donated; then its state, not
             args = (self.params, self._toks, self._pos,
-                    pool.device_tables(), pool.kc, pool.vc)
-            donate = (2, 4, 5)
+                    pool.device_tables()) + tuple(pool.arrays)
+            donate = (2,) + tuple(range(4, len(args)))
+            args = args + self._state
         else:
             args = (self.params, self._toks, self._pos, pool.kc,
                     pool.vc)
@@ -1929,12 +1954,15 @@ class ServingEngine:
                                           donate=v_donate)
                 if use_spec:
                     with M.span("serving/decode_dispatch"):
-                        out, acc, nxt, self._pos, kc, vc = \
+                        out, acc, nxt, self._pos, *arrs = \
                             self._timed_call(key, ex_v, v_args)
                 else:
                     with M.span("serving/decode_dispatch"):
-                        nxt, self._pos, kc, vc = self._timed_call(
+                        nxt, self._pos, *rest = self._timed_call(
                             ("decode",), ex, args)
+                    # the cache arrays first, then the carried state
+                    n = len(rest) - len(self._state)
+                    arrs, self._state = rest[:n], tuple(rest[n:])
                 ok = True
             except BaseException as e:
                 # the dispatch never ran (chaos injects BEFORE the
@@ -1947,7 +1975,7 @@ class ServingEngine:
                 if not self._absorb_decode_failure(e):
                     raise
             if ok:
-                pool.rebind(kc, vc)
+                pool.rebind(*arrs)
                 self._toks = nxt
                 M.decode_steps += 1
                 self._decode_fail_streak = 0
@@ -2223,7 +2251,8 @@ class ServingEngine:
             args = (self.params, tokens, np.int32(tail),
                     np.int32(start), np.int32(alloc.slot),
                     np.int32(1), pool.table_row(alloc.slot),
-                    self._toks, self._pos, pool.kc, pool.vc)
+                    self._toks, self._pos) + tuple(pool.arrays)
+            donate = tuple(range(8, len(args)))
             if self.sampling:
                 args = args + self._samp_scalars(req)
             req.inflight += 1
@@ -2233,7 +2262,7 @@ class ServingEngine:
                                            step=self._step_id + 1)
                 ex = self._compiled(("paged_prefill", bucket),
                                     self._prefill_fn, args,
-                                    donate=(8, 9, 10))
+                                    donate=donate)
                 t_disp = time.perf_counter()
                 with M.span("serving/prefill_dispatch"):
                     if start:
@@ -2241,7 +2270,7 @@ class ServingEngine:
                             req, start, tail,
                             saved_ms=M.cache.estimate_saved_ms(start))
                     self.flight.prefill_dispatched(req, bucket, 1)
-                    first, self._toks, self._pos, kc, vc = \
+                    first, self._toks, self._pos, *arrs = \
                         self._timed_call(("paged_prefill", bucket),
                                          ex, args)
             except BaseException as e:
@@ -2251,7 +2280,7 @@ class ServingEngine:
                         e, "prefill", [(req, alloc.slot)]):
                     return   # rolled back; the retry runs next step
                 raise
-            pool.rebind(kc, vc)
+            pool.rebind(*arrs)
             pool.commit_prefix(alloc.slot, ids)
             M.record_admission(req)
             self._stamp_prefill(req, t_disp, bucket)
@@ -2325,9 +2354,9 @@ class ServingEngine:
                         np.int32(start), np.int32(plan.slot),
                         np.int32(1 if final else 0),
                         pool.table_row(plan.slot), self._toks,
-                        self._pos, pool.kc, pool.vc)
+                        self._pos) + tuple(pool.arrays)
                 key, fn, donate = ("paged_prefill", C), \
-                    self._prefill_fn, (8, 9, 10)
+                    self._prefill_fn, tuple(range(8, len(args)))
             else:
                 args = (self.params, tokens, np.int32(clen),
                         np.int32(start), np.int32(plan.slot),
@@ -2357,7 +2386,7 @@ class ServingEngine:
                                               clen, final)
                     if final:
                         self.flight.prefill_dispatched(req, C, 1)
-                    first, self._toks, self._pos, kc, vc = \
+                    first, self._toks, self._pos, *arrs = \
                         self._timed_call(key, ex, args)
             except BaseException as e:
                 if final:
@@ -2369,7 +2398,7 @@ class ServingEngine:
                         e, "chunk", [(req, plan.slot)]):
                     return   # rolled back (all chunk progress voided;
                 raise        # the retry re-plans from the queue)
-            pool.rebind(kc, vc)
+            pool.rebind(*arrs)
             M.record_prefill_chunk(clen)
             self._stamp_prefill(req, t_disp, C)
             budget -= clen

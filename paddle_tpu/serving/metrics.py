@@ -24,6 +24,8 @@ timeline — one scope, three sinks.
 """
 import time
 
+import numpy as np
+
 from .. import profiler as _profiler
 from ..observability import (CacheObservatory, MetricsRegistry,
                              ProgramPerf, Reservoir, SLOTracker,
@@ -290,6 +292,7 @@ class ServingMetrics:
             "queue_wait": Reservoir(self.RESERVOIR_SIZE),
         }
         self.kv_donation = {"enabled": False, "effective": False}
+        self._moe = None   # set_moe_counters
         self._t_first_work = None
         self._t_last_work = None
 
@@ -408,6 +411,67 @@ class ServingMetrics:
         computed (no cache to hit)."""
         if computed_tokens:
             self._c_prefill_tokens.inc(int(computed_tokens))
+
+    def set_kv_bytes_per_token(self, nbytes):
+        """Gauge ``serving_kv_bytes_per_token``: the useful bytes one
+        cached token takes over all layers, from the model's cache
+        spec."""
+        self.registry.gauge(
+            "serving_kv_bytes_per_token",
+            "bytes one cached token takes over all layers (cache spec)"
+        ).set(float(nbytes))
+
+    def set_moe_counters(self, read_fn, layers, first, count):
+        """Expert-routing counters that the decode program keeps ON THE
+        DEVICE (``CacheSpec.state``): ``read_fn()`` fetches the array
+        ``[len(layers), count + 2]`` (tokens per held expert, distinct
+        experts hit, steps) and is only called when somebody looks (a
+        snapshot or a scrape), never by the step loop."""
+        r = self.registry
+        tokens = r.counter(
+            "serving_moe_expert_tokens_total",
+            "decode tokens routed to each held expert",
+            labelnames=("layer", "expert"))
+        tokens.max_label_values = len(layers) * count + 1
+        hit = r.counter(
+            "serving_moe_experts_hit_total",
+            "distinct held experts hit, summed over decode steps",
+            labelnames=("layer",))
+        steps = r.counter(
+            "serving_moe_layer_steps_total",
+            "expert-layer executions of the decode program")
+        self._moe = {"read": read_fn, "layers": list(layers),
+                     "first": int(first), "count": int(count)}
+
+        def moe_collect():
+            arr = self.moe_counts()
+            for i, layer in enumerate(layers):
+                for e in range(count):
+                    tokens.labels(layer, first + e).set_to(
+                        float(arr[i, e]))
+                hit.labels(layer).set_to(float(arr[i, count]))
+            steps.set_to(float(arr[:, count + 1].sum()))
+        r.add_collect_hook(moe_collect)
+
+    def moe_counts(self):
+        """The device's routing counters as a host array (None for a
+        model without expert layers)."""
+        if self._moe is None:
+            return None
+        return np.asarray(self._moe["read"]())
+
+    def moe_report(self):
+        """The counters by layer and expert (None for a model without
+        expert layers)."""
+        if self._moe is None:
+            return None
+        arr = self.moe_counts()
+        m = self._moe
+        return {"layers": m["layers"], "first_expert": m["first"],
+                "held_experts": m["count"],
+                "expert_tokens": arr[:, :m["count"]].tolist(),
+                "experts_hit": arr[:, m["count"]].tolist(),
+                "layer_steps": arr[:, m["count"] + 1].tolist()}
 
     def set_prefix_pool(self, stats_fn):
         """Attach the paged pool's ``stats()`` as the pull source for
@@ -852,4 +916,6 @@ class ServingMetrics:
             "replica": self.identity_report(),
             "trace": self.trace_report(),
             "tenants": self.tenant_report(),
+            # only a model with expert layers adds its section
+            **({"moe": self.moe_report()} if self._moe else {}),
         }

@@ -1,0 +1,214 @@
+"""The engine's paged prefill and decode programs for a model whose
+cache is a LATENT a token (``text.deepseek_v3``): the same signatures,
+slot bookkeeping and sampling as ``programs.py`` has for the GPT, with
+the model's block IMPORTED, not written out again. What is here is only
+how a layer reaches the paged pool (``PagedAccess``) and what the
+engine's calling convention asks of a program.
+
+  ``paged_prefill(params, tokens [1, B], tail_len, start, slot, final,
+                  bt_row [MB], toks [S], pos [S], c, k_pe[, samp...])
+      -> (first [1], toks', pos', c, k_pe)``
+      One request's uncached tail in the EXPANDED attention form: every
+      layer gathers the slot's ``MB`` blocks into a position-ordered
+      view, puts the tail's latents in at ``start..`` and scatters the
+      blocks back whole. The head runs on the ONE row that is read
+      (``tail_len - 1``), never on the bucket.
+
+  ``paged_decode(params, toks [S], pos [S], tables [S, MB], c, k_pe,
+                 moe_counts[, samp...])
+      -> (next [S], pos + 1, c, k_pe, moe_counts')``
+      One token a slot in the ABSORBED form. The pool rides the layer
+      loops' carry flat (``[L*NB, BS, .]``), each slot's current block is
+      read, given its new row and written back whole (in place on a
+      donated pool: ISSUE 26's write), and attention reads the blocks in
+      place through ``tables + layer*NB``. ``moe_counts`` is the model's
+      carried state (``CacheSpec.state``): routing counters that stay on
+      the device, returned new each step and never donated, so that a
+      reader in another thread holds a live array whenever it looks.
+
+Parked and released slots behave as in ``programs.py``: write positions
+are clamped to the row's last entry, free rows point at the trash block,
+the length mask hides what they hold.
+"""
+
+
+class PagedAccess:
+    """A layer's way to the flat paged pool ``(c [L*NB, BS, rank], k_pe
+    [L*NB, dr, BS])`` (the rotary key's blocks are kept transposed:
+    ``ops.mla_attention``). Built per trace with the table it reads: one
+    row (``bt_row``, prefill) or all of them (``tables``, decode)."""
+
+    def __init__(self, num_blocks, block_size, blocks_per_slot,
+                 bt_row=None, tables=None, kernel=False):
+        self.NB, self.BS = int(num_blocks), int(block_size)
+        self.MB = int(blocks_per_slot)
+        self.bt_row, self.tables, self.kernel = bt_row, tables, kernel
+
+    def prefill(self, state, layer, start, c, k_pe):
+        import jax
+        import jax.numpy as jnp
+        C = self.MB * self.BS
+        rows = layer * jnp.int32(self.NB) + self.bt_row          # [MB]
+        at = start + jnp.arange(c.shape[1], dtype=jnp.int32)
+        out, views = [], []
+        for cache, new, flip in zip(state, (c, k_pe), (False, True)):
+            d = new.shape[-1]
+            with jax.named_scope("kv_gather"):
+                blocks = cache[rows]                 # [MB, BS, d] or flipped
+                if flip:
+                    blocks = blocks.transpose(0, 2, 1)
+                view = blocks.reshape(C, d)
+            # rows past the slot's capacity are dropped, not shifted
+            view = view.at[at].set(new[0].astype(cache.dtype),
+                                   mode="drop")
+            with jax.named_scope("kv_write"):
+                blocks = view.reshape(self.MB, self.BS, d)
+                if flip:
+                    blocks = blocks.transpose(0, 2, 1)
+                out.append(cache.at[rows].set(blocks))
+            views.append(view[None])
+        return tuple(out), tuple(views)
+
+    def decode(self, state, layer, pos, c, k_pe, q_lat, q_pe, scale):
+        import jax
+        import jax.numpy as jnp
+
+        from ...ops import mla_attention as mla_ops
+        BS, C = self.BS, self.MB * self.BS
+        base = layer * jnp.int32(self.NB)
+        # the WRITE position is clamped as a whole (programs.py)
+        wpos = jnp.minimum(pos, jnp.int32(C - 1))
+        bidx = jnp.take_along_axis(
+            self.tables, (wpos // jnp.int32(BS))[:, None], axis=1)[:, 0]
+        row = (jnp.arange(BS, dtype=jnp.int32)[None, :]
+               == (wpos % jnp.int32(BS))[:, None])           # [S, BS]
+        fb = base + bidx
+        with jax.named_scope("kv_write"):
+            cf, pf = state
+            cf = cf.at[fb].set(jnp.where(
+                row[:, :, None], c.astype(cf.dtype)[:, None, :], cf[fb]))
+            pf = pf.at[fb].set(jnp.where(
+                row[:, None, :], k_pe.astype(pf.dtype)[:, :, None],
+                pf[fb]))
+            state = (cf, pf)
+        fn = mla_ops.mla_paged_decode_attn if self.kernel \
+            else mla_ops.mla_paged_decode_attn_jnp
+        return state, fn(q_lat, q_pe, state[0], state[1],
+                         self.tables + base, pos + 1, scale)
+
+
+def decode_kernels(cfg, num_slots, block_size):
+    """Whether the decode program runs its two Pallas kernels: yes on
+    any backend that has Mosaic, and then a shape they cannot take is
+    refused here, by name; no on the CPU (the ``jnp`` formulations)."""
+    import jax
+
+    from ...ops import mla_attention as mla_ops
+    from ...ops import moe_experts as moe_ops
+    if jax.default_backend() == "cpu" and not (
+            mla_ops._FORCE_INTERPRET[0] or moe_ops._FORCE_INTERPRET[0]):
+        return False
+    if not mla_ops.kernel_viable(block_size, cfg.kv_lora_rank,
+                                 cfg.qk_rope_head_dim, cfg.cache_dtype):
+        raise ValueError(
+            f"mla_paged_decode_attn cannot take (block_size, rank, rope "
+            f"dim, cache dtype) = ({block_size}, {cfg.kv_lora_rank}, "
+            f"{cfg.qk_rope_head_dim}, {cfg.cache_dtype}): "
+            f"ops.mla_attention.kernel_viable")
+    if cfg.num_moe_layers and not moe_ops.kernel_viable(
+            num_slots, cfg.hidden_size, cfg.moe_intermediate_size,
+            cfg.dtype):
+        raise ValueError(
+            f"moe_experts_swiglu_decode cannot take (slots, hidden, "
+            f"expert width, dtype) = ({num_slots}, {cfg.hidden_size}, "
+            f"{cfg.moe_intermediate_size}, {cfg.dtype}): "
+            f"ops.moe_experts.kernel_viable")
+    return True
+
+
+def build_paged_latent_fns(cfg, num_slots, block_size, num_blocks,
+                           blocks_per_slot, sampling=False, kernels=None):
+    """(paged_prefill, paged_decode) for a ``DeepseekV3Config``. Pure
+    and shape-stable; ``kernels=None`` asks ``decode_kernels``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...text import deepseek_v3 as block
+    from ..sched.sampling import build_sampling_head
+
+    if kernels is None:
+        kernels = decode_kernels(cfg, num_slots, block_size)
+    head = build_sampling_head(cfg.vocab_size) if sampling else None
+    L = cfg.num_layers
+    NB, BS, MB = int(num_blocks), int(block_size), int(blocks_per_slot)
+    C = MB * BS
+
+    def flat(a):
+        return a.reshape((L * NB,) + a.shape[2:])
+
+    def _prefill_core(params, tokens, tail_len, start, slot, final,
+                      bt_row, toks, pos, c, k_pe, samp):
+        B = tokens.shape[1]
+        access = PagedAccess(NB, BS, MB, bt_row=bt_row)
+        with jax.named_scope("embed"):
+            x = params["wemb"][tokens]                       # [1, B, h]
+        positions = (start + jnp.arange(B, dtype=jnp.int32))[None]
+        x, (cf, pf), _ = block.run_layers(
+            cfg, params, x, positions, access, (flat(c), flat(k_pe)),
+            start, "prefill")
+        last = block.lm_head(cfg, params,
+                             jnp.take(x[0], tail_len - 1, axis=0))
+        with jax.named_scope("sample"):
+            if samp is None:
+                first = jnp.argmax(last, -1).astype(jnp.int32)
+            else:
+                seed, temp, topk, topp = samp
+                first = head(last[None], seed[None],
+                             (start + tail_len - 1)[None], temp[None],
+                             topk[None], topp[None])[0]
+            toks = jnp.where(final > 0, toks.at[slot].set(first), toks)
+            pos = pos.at[slot].set(
+                jnp.where(final > 0, start + tail_len, jnp.int32(C - 1)))
+        return first[None], toks, pos, cf.reshape(c.shape), \
+            pf.reshape(k_pe.shape)
+
+    def _decode_core(params, toks, pos, tables, c, k_pe, counts, samp):
+        access = PagedAccess(NB, BS, MB, tables=tables, kernel=kernels)
+        with jax.named_scope("embed"):
+            x = params["wemb"][toks]                         # [S, h]
+        x, (cf, pf), counts = block.run_layers(
+            cfg, params, x, pos, access, (flat(c), flat(k_pe)),
+            mode="decode", kernel=kernels, counts=counts)
+        logits = block.lm_head(cfg, params, x)
+        with jax.named_scope("sample"):
+            if samp is None:
+                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+            else:
+                seeds, temps, topks, topps = samp
+                nxt = head(logits, seeds, pos, temps, topks, topps)
+        return nxt, pos + jnp.int32(1), cf.reshape(c.shape), \
+            pf.reshape(k_pe.shape), counts
+
+    if sampling:
+        def paged_prefill(params, tokens, tail_len, start, slot, final,
+                          bt_row, toks, pos, c, k_pe, seed, temp, topk,
+                          topp):
+            return _prefill_core(params, tokens, tail_len, start, slot,
+                                 final, bt_row, toks, pos, c, k_pe,
+                                 (seed, temp, topk, topp))
+
+        def paged_decode(params, toks, pos, tables, c, k_pe, counts,
+                         seeds, temps, topks, topps):
+            return _decode_core(params, toks, pos, tables, c, k_pe,
+                                counts, (seeds, temps, topks, topps))
+    else:
+        def paged_prefill(params, tokens, tail_len, start, slot, final,
+                          bt_row, toks, pos, c, k_pe):
+            return _prefill_core(params, tokens, tail_len, start, slot,
+                                 final, bt_row, toks, pos, c, k_pe, None)
+
+        def paged_decode(params, toks, pos, tables, c, k_pe, counts):
+            return _decode_core(params, toks, pos, tables, c, k_pe,
+                                counts, None)
+
+    return paged_prefill, paged_decode

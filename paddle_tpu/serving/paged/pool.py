@@ -1,9 +1,11 @@
 """Paged KV pool: block-granular refcounted cache + fixed-shape block
 tables + the radix prefix index that makes blocks shareable.
 
-Physical layout: ONE pair of cache arrays
-``kc/vc [layers, num_blocks, heads, block_size, head_dim]`` and an
-int32 block table ``[num_slots, blocks_per_slot]`` mapping each slot's
+Physical layout: the arrays the model's CACHE SPEC names
+(``cache_spec.CacheSpec``), each ``[layers, num_blocks, *lead,
+block_size, *trail]`` (a GPT: the pair ``kc/vc [layers, num_blocks,
+heads, block_size, head_dim]``; latent attention: a latent and a rotary
+key a token, no head axis) and an int32 block table ``[num_slots, blocks_per_slot]`` mapping each slot's
 logical block i to a physical block. Both shapes are fixed at
 construction, so every AOT serving executable keeps one signature for
 the engine's lifetime — paging changes WHERE a slot's K/V lives, never
@@ -53,12 +55,15 @@ class PagedAllocation:
 class PagedKVPool:
     """Block allocator + slot table over the paged cache arrays."""
 
-    def __init__(self, num_slots, num_layers, num_heads, max_len,
-                 head_dim, block_size=16, num_blocks=None,
-                 dtype=None):
+    def __init__(self, num_slots, num_layers=None, num_heads=None,
+                 max_len=None, head_dim=None, block_size=16,
+                 num_blocks=None, dtype=None, spec=None):
         import jax.numpy as jnp
-        if dtype is None:
-            dtype = jnp.float32
+        if spec is None:
+            from .cache_spec import kv_pair_spec
+            spec = kv_pair_spec(num_layers, num_heads, head_dim,
+                                jnp.float32 if dtype is None else dtype)
+        self.spec = spec
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         if block_size < 1:
@@ -79,10 +84,9 @@ class PagedKVPool:
                 f"num_blocks {self.num_blocks} cannot back even one "
                 f"slot ({self.blocks_per_slot} blocks) plus the trash "
                 "block")
-        shape = (int(num_layers), self.num_blocks, int(num_heads),
-                 self.block_size, int(head_dim))
-        self.kc = jnp.zeros(shape, dtype)
-        self.vc = jnp.zeros(shape, dtype)
+        self.arrays = tuple(
+            jnp.zeros(spec.shape(a, self.num_blocks, self.block_size),
+                      a.dtype) for a in spec.arrays)
         self.index = RadixPrefixIndex(self.block_size)
         # block state: free heap (block 0 reserved as trash), refcounts
         # for allocated blocks, the evictable count (indexed & ref 0)
@@ -361,23 +365,38 @@ class PagedKVPool:
         # view of the live, in-place-mutated table
         return jnp.asarray(self.block_tables[slot].copy())
 
-    def rebind(self, kc, vc):
+    # the GPT's names for its pair (kv_wire, the speculative verify
+    # program and the tests read them)
+    @property
+    def kc(self):
+        return self.arrays[0]
+
+    @property
+    def vc(self):
+        return self.arrays[1]
+
+    def rebind(self, *arrays):
         """Same single-owner discipline as SlotKVPool.rebind: the
         compiled call's returned arrays become the live buffers; any
         shape/dtype drift is caught here, before a donating backend's
         next AOT call consumes a mismatched buffer."""
-        if kc.shape != self.kc.shape or vc.shape != self.vc.shape:
+        if len(arrays) != len(self.arrays):
             raise ValueError(
-                f"rebind shape drift: got {kc.shape}/{vc.shape}, pool "
-                f"owns {self.kc.shape}")
-        if kc.dtype != self.kc.dtype or vc.dtype != self.vc.dtype:
-            raise ValueError(
-                f"rebind dtype drift: got {kc.dtype}/{vc.dtype}, pool "
-                f"owns {self.kc.dtype}")
-        self.kc, self.vc = kc, vc
+                f"rebind: got {len(arrays)} arrays, the cache spec "
+                f"names {len(self.arrays)}")
+        for spec, new, old in zip(self.spec.arrays, arrays, self.arrays):
+            if new.shape != old.shape:
+                raise ValueError(
+                    f"rebind shape drift: got {new.shape}, pool owns "
+                    f"{old.shape} ({spec.name})")
+            if new.dtype != old.dtype:
+                raise ValueError(
+                    f"rebind dtype drift: got {new.dtype}, pool owns "
+                    f"{old.dtype} ({spec.name})")
+        self.arrays = tuple(arrays)
 
     def nbytes(self):
-        return int(self.kc.nbytes + self.vc.nbytes)
+        return int(sum(a.nbytes for a in self.arrays))
 
     # ------------------------------------------------------------ stats
     def stats(self):

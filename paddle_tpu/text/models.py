@@ -426,6 +426,18 @@ class GPTForCausalLM(nn.Layer):
                 "lnf_w": W(self.gpt.ln_f.weight),
                 "lnf_b": W(self.gpt.ln_f.bias), "head": head}
 
+    def cache_spec(self):
+        """What a token owns in a layer of the paged cache: K and V as
+        the qkv projection computes them, per head, in the weights'
+        dtype (``serving.paged.cache_spec``)."""
+        from ..core.lazy import concrete
+        from ..serving.paged.cache_spec import kv_pair_spec
+        cfg = self.cfg
+        return kv_pair_spec(
+            cfg.num_layers, cfg.num_heads,
+            cfg.hidden_size // cfg.num_heads,
+            concrete(self.gpt.blocks[0].attn.qkv.weight.value).dtype)
+
     def build_serving_fns(self, num_slots, cache_len, sampling=False):
         """Slot-indexed cache programs for the continuous-batching
         engine (paddle_tpu.serving), over a pooled cache

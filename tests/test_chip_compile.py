@@ -225,3 +225,113 @@ def test_tp_fused_ce_shard_map_compiles(topo, monkeypatch, vocab, kernel):
     text = jax.jit(loss_and_grads).lower(x, w, lab).compile().as_text()
     assert "all-reduce" in text
     assert ("tpu_custom_call" in text) == kernel
+
+
+# ------------------------------------------------------------------------
+# deepseek_v3 (latent attention + experts): kernels at the published
+# widths of kanana-2-30b-a3b, and the whole decode program at sizes where
+# the pool dominates
+
+def _sds(one_chip):
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dt, sharding=one_chip)
+    return sds
+
+
+def test_mla_paged_decode_attn_compiles(one_chip):
+    """32 heads over a latent of 512 + a rotary key of 64, blocks of 256
+    tokens, 32 slots of 64 blocks: Mosaic takes the kernel as the
+    benchmark's cell runs it."""
+    from paddle_tpu.ops import mla_attention
+    sds = _sds(one_chip)
+    S, nh, r, dr, BS, MB = 32, 32, 512, 64, 256, 64
+    NB = 6 * (S * MB + 1)
+    _compile(lambda *a: mla_attention.mla_paged_decode_attn(*a, 192 ** -0.5),
+             sds((S, nh, r)), sds((S, nh, dr)), sds((NB, BS, r)),
+             sds((NB, dr, BS)), sds((S, MB), jnp.int32),
+             sds((S,), jnp.int32))
+
+
+def test_moe_experts_swiglu_decode_compiles(one_chip):
+    """32 tokens through up to 128 experts of 2048 x 768, the matrices of
+    5 layers stacked flat, a layer's row offset traced."""
+    from paddle_tpu.ops import moe_experts
+    sds = _sds(one_chip)
+    T, h, f, E, Lm = 32, 2048, 768, 128, 5
+    _compile(moe_experts.moe_experts_swiglu_decode,
+             sds((T, h)), sds((Lm * E, h, f)), sds((Lm * E, h, f)),
+             sds((Lm * E, f, h)), sds((T, E), jnp.float32),
+             sds((), jnp.int32))
+
+
+def _latent_decode_program(one_chip, sampling):
+    """(compiled paged_decode, the two pool shapes) of a deepseek_v3
+    model, jitted with the engine's donation (pos and the cache spec's
+    arrays; the carried counters are not donated): published attention
+    widths, 4 layers x 1025 blocks of 256 tokens (1.07 GB of latent, 134
+    MB of rotary key: a smaller one XLA prefetches WHOLE into the 128
+    MiB of VMEM, which no real pool fits), 16 slots, few narrow experts,
+    small vocabulary."""
+    from paddle_tpu.serving.paged.latent_programs import \
+        build_paged_latent_fns
+    from paddle_tpu.text import deepseek_v3 as ds
+    S, BS, MB = 16, 256, 64
+    NB = S * MB + 1
+    cfg = ds.DeepseekV3Config(
+        vocab_size=1024, hidden_size=1024, num_hidden_layers=4,
+        num_attention_heads=16, intermediate_size=1024,
+        moe_intermediate_size=256, n_routed_experts=8,
+        n_shared_experts=1, num_experts_per_tok=2,
+        max_position_embeddings=MB * BS, dtype="bfloat16")
+    sds = _sds(one_chip)
+    params = {}
+    for path, (shape, _, dt) in ds.param_shapes(cfg).items():
+        node = params
+        for part in path[:-1]:
+            node = node.setdefault(part, {})
+        node[path[-1]] = sds(shape, jnp.dtype(dt))
+    spec = ds.latent_cache_spec(cfg)
+    pool = [spec.shape(a, NB, BS) for a in spec.arrays]
+    i32 = jnp.int32
+    args = [params, sds((S,), i32), sds((S,), i32), sds((S, MB), i32)] \
+        + [sds(p) for p in pool] \
+        + [sds(shape, dt) for _, shape, dt in spec.state]
+    if sampling:
+        args += [sds((S,), jnp.uint32), sds((S,), jnp.float32),
+                 sds((S,), i32), sds((S,), jnp.float32)]
+    _, decode = build_paged_latent_fns(cfg, S, BS, NB, MB,
+                                       sampling=sampling, kernels=True)
+    return jax.jit(decode, donate_argnums=(2, 4, 5)).lower(
+        *args).compile(), pool
+
+
+@pytest.mark.parametrize("sampling", [False, True],
+                         ids=["greedy", "sampling"])
+def test_latent_decode_program_updates_pool_in_place(one_chip, sampling):
+    """The deepseek_v3 decode program carries the donated latent pool
+    through both of its layer loops in place: both arrays aliased onto
+    the results, temporaries far under the pool, both kernels in the
+    program, and no copy / dynamic-slice / dynamic-update-slice of the
+    pool's shape (a rotary-key array with the key dim minor made XLA copy
+    the whole array in front of the kernel, every layer: PERF.md)."""
+    import re
+    compiled, pool = _latent_decode_program(one_chip, sampling)
+    nbytes = sum(2 * int(np.prod(p)) for p in pool)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes
+    assert mem.temp_size_in_bytes < nbytes // 8, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    assert "mla_paged_decode_attn" in text
+    assert "moe_experts_swiglu_decode" in text
+    shapes = "|".join(
+        ",".join(str(d) for d in lead + p[2:])
+        for p in pool for lead in ((p[0], p[1]), (1, p[1]),
+                                   (p[0] * p[1],), (p[1],)))
+    inst = re.compile(
+        rf"%([\w.\-]+) = bf16\[(?:{shapes})\]\S* ([\w\-]+)\(")
+    found = [m.groups() for m in map(inst.search, text.splitlines()) if m]
+    assert found   # the pool is in the program under these shapes
+    moving = ("copy", "dynamic-slice", "dynamic-update-slice")
+    bad = [(name, op) for name, op in found
+           if op in moving or any(w in name for w in moving)]
+    assert not bad, bad
