@@ -692,8 +692,11 @@ def _measure_decode_kernel(model, num_slots):
     probe of the paged decode program — the XLA gather composition vs
     the Pallas paged-attention kernel — on IDENTICAL greedy traffic.
 
-    Each arm builds its own paged engine (the gate is resolved at
-    build time; the AOT decode program embeds one path or the other),
+    Each arm builds its own paged engine (the engine chooses its decode
+    attention at build time from ``kernel_viable``; the AOT decode
+    program embeds one path or the other, and this probe steers that
+    guard per arm: refused for the XLA arm, interpret mode for the
+    Pallas arm on a CPU),
     drains the same request set twice (cold then warm; the warm drain
     is the measured one), and reports its decode ``avg_ms`` +
     per-program roofline fraction from the perf observatory.
@@ -718,10 +721,16 @@ def _measure_decode_kernel(model, num_slots):
                .astype(np.int64) for n, _ in specs]
     on_cpu = jax.default_backend() == "cpu"
 
-    def drive(gate):
-        eng = ServingEngine(model, num_slots=num_slots, bucket_min=8,
-                            paged=True, block_size=8, paged_attn=gate,
-                            watchdog_mode="raise")
+    def drive(kernel):
+        guard = paged_attn.kernel_viable
+        if not kernel:
+            paged_attn.kernel_viable = lambda *a: False
+        try:
+            eng = ServingEngine(model, num_slots=num_slots, bucket_min=8,
+                                paged=True, block_size=8,
+                                watchdog_mode="raise")
+        finally:
+            paged_attn.kernel_viable = guard
         wall = None
         for run in range(2):      # cold, then the measured warm drain
             t0 = _time.perf_counter()
@@ -2414,12 +2423,11 @@ def main():
     try:
         from paddle_tpu.observability.perf import (append_rows,
                                                    config_digest)
-        # the digest carries the decode-kernel gate + backend: a
-        # kernel-on run starts its own baseline series instead of
-        # cross-comparing against gather-path (or CPU-interpret) rows
+        # the digest carries the backend's decode-kernel mode: a
+        # real-kernel run starts its own baseline series instead of
+        # cross-comparing against CPU-interpret rows
         digest_cfg = dict(
             cfg,
-            paged_attn_gate=os.environ.get("PADDLE_PAGED_ATTN", "0"),
             # the spec env gate changes what the headline engine runs
             # (ServingEngine resolves it when speculative is unset),
             # so gated runs start their own baseline series

@@ -17,8 +17,9 @@ from --seed. Phases (one JSON line each, the verdict line LAST):
   2 serve   ServingEngine + paged KV pool behind EngineGateway.serve(),
             real POST /v1/generate requests over localhost; tokens ==
             model.generate(temperature=0), KV donation effective, zero
-            steady-state compiles; XLA-gather decode, then the Pallas
-            paged decode kernel on an f32 and on a bf16 pool
+            steady-state compiles; the engine's own choice of decode
+            attention: XLA gather at heads of 64, the Pallas paged
+            kernel at heads of 128 on an f32 and on a bf16 pool
 
 Any failed check raises: non-zero exit, no verdict line. With no TPU it
 stops in phase 0. ``--rehearse`` is the CPU rehearsal (tiny width,
@@ -287,11 +288,13 @@ def divergence_is_tie(paddle, model, prompt, got, ref):
 
 def serve_arm(paddle, name, model, waves, refs, kernel, rehearse):
     """One engine behind the HTTP gateway: a warm-up wave, then a
-    steady wave on fresh prompts of the same lengths."""
+    steady wave on fresh prompts of the same lengths. ``kernel`` is
+    what the engine must have chosen for this model's head width (the
+    Pallas paged decode kernel or the XLA gather): nobody sets it."""
     from paddle_tpu.serving import ServingEngine
     from paddle_tpu.serving.router.transport import EngineGateway
     eng = ServingEngine(model, num_slots=8, bucket_min=16, paged=True,
-                        block_size=16, paged_attn=kernel)
+                        block_size=16)
     gateway = EngineGateway(eng)
     handle = gateway.serve()
     try:
@@ -318,7 +321,7 @@ def serve_arm(paddle, name, model, waves, refs, kernel, rehearse):
                              f"beyond a rounding tie: {where}")
                 forks.append(where)
     require(steady == 0, f"{name}: {steady} steady-state compile(s)")
-    if not rehearse:    # (the CPU has no Mosaic: there the gate falls back)
+    if not rehearse:    # (the CPU has no Mosaic: there it is the gather)
         require(eng.paged_attn == bool(kernel),
                 f"{name}: engine.paged_attn is {eng.paged_attn}")
         require(snap["kv_donation"]["effective"],
@@ -345,16 +348,22 @@ def phase_serve(paddle, model, width, seq, seed, rehearse):
     waves = [([rs.randint(0, width["vocab_size"], (n,)).astype("int64")
                for n in lengths], new_tokens) for _ in range(2)]
 
-    def arms(model, tag, kernels):
+    def arm(model, tag, kernel):
         refs = [reference_tokens(model, p, k) for p, k in waves]
-        for kernel in kernels:
-            serve_arm(paddle, f"{tag}-{'pallas' if kernel else 'xla-gather'}",
-                      model, waves, refs, kernel, rehearse)
+        serve_arm(paddle, f"{tag}-{'pallas' if kernel else 'xla-gather'}",
+                  model, waves, refs, kernel, rehearse)
 
-    arms(model, "f32", [False, True])
+    # heads of 64 do not fill the kernel's lanes: the engine keeps the
+    # gather for the trained model, and takes the kernel for the same
+    # width cut into heads of 128
+    arm(model, "f32-hd64", False)
+    heads = max(1, width["hidden_size"] // 128)
+    wide = build_gpt(paddle, dict(width, num_heads=heads), seq, seed)
+    wide.eval()
+    arm(wide, "f32-hd128", True)
     # a bf16 model serves from a bf16 KV pool
-    paddle.amp.decorate(model, level="O2", dtype="bfloat16")
-    arms(model, "bf16", [True])
+    paddle.amp.decorate(wide, level="O2", dtype="bfloat16")
+    arm(wide, "bf16-hd128", True)
 
 
 # ---------------------------------------------------------------- --chips 4

@@ -27,14 +27,14 @@ Layout model (why paged costs more under plain XLA):
     XLA): the gather MATERIALIZES a contiguous copy before attention
     reads it — pool read + copy write + attention read, ~3x the
     contiguous traffic. That factor is exactly what the Pallas kernel
-    deletes by reading blocks in place, which is why the
-    achieved-fraction gauge exists: the kernel becomes default only
-    where measurements beat this model's floor;
-  * **paged_pallas** (ops.paged_attention, PADDLE_PAGED_ATTN): the
-    Pallas kernel streams blocks through VMEM straight from the pool
-    — gather factor 1.0, and no max-len over-read: its index-map
-    clamp stops the DMA at each slot's last LIVE block, so the read
-    length is the live ``kv_len`` (callers may pass
+    deletes by reading blocks in place; it is what the engine builds
+    only where ``ops.paged_attention.kernel_viable`` refuses the
+    kernel (the CPU, shapes that do not tile);
+  * **paged_pallas** (ops.paged_attention: the engine's choice
+    wherever ``kernel_viable`` says yes): the Pallas kernel copies
+    blocks into VMEM straight from the pool — gather factor 1.0, and
+    no max-len over-read: it walks each slot's LIVE blocks only, so
+    the read length is the live ``kv_len`` (callers may pass
     ``live_kv_len``), not the fixed cache capacity.
 
 The boolean ``paged=`` argument is kept for callers predating the

@@ -463,10 +463,11 @@ def cached_paged_attention(q, k_cache, v_cache, block_tables, lengths):
     row a padding entry gathered, get -1e30 before the f32 softmax and
     carry exactly-zero weight. For block tables describing the same
     live prefixes this computes bit-for-bit what the slot-contiguous
-    path computes; it is the XLA-composed gather baseline — and the
-    parity oracle / fallback — for the Pallas paged decode kernel
-    (ops.paged_attention, PADDLE_PAGED_ATTN) that reads the blocks in
-    place instead."""
+    path computes. It is the XLA-composed gather: what the decode
+    program runs where the Pallas paged decode kernel
+    (ops.paged_attention, which reads the live blocks in place) cannot
+    (the CPU, shapes ``kernel_viable`` refuses), and that kernel's
+    parity oracle. Its cost is the capacity's, whatever is live."""
     S, nh, hd = q.shape
     with jax.named_scope("kv_gather"):
         k = jnp.take(k_cache, block_tables, axis=0)  # [S, MB, nh, BS, hd]

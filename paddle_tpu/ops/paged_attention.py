@@ -4,42 +4,54 @@ Single-token decode attention computed DIRECTLY over the paged pool
 layout (vLLM's PagedAttention idea, SOSP'23, done TPU-natively): q
 ``[S, nh, hd]``, pooled ``k_cache``/``v_cache``
 ``[num_blocks, nh, BS, hd]``, fixed-shape ``block_tables [S, MB]``,
-per-slot ``lengths``. Each slot's physical blocks stream through VMEM
-one at a time under an online softmax — the ``[S, nh, MB*BS, hd]``
-gathered view the XLA composition (``ops.attention.
-cached_paged_attention``) materializes is never built, which deletes
-the ~3x gather traffic the roofline model prices as
-``PAGED_GATHER_FACTOR``.
+per-slot ``lengths``. The ``[S, nh, MB*BS, hd]`` gathered view the XLA
+composition (``ops.attention.cached_paged_attention``) materializes is
+never built, and the kernel's work follows each slot's LIVE length,
+not its capacity.
 
-How the table drives the DMA schedule: grid ``(S, MB)`` with
-``PrefetchScalarGridSpec(num_scalar_prefetch=2)`` — ``block_tables``
-and ``lengths`` arrive ahead of the kernel body as scalar-prefetch
-refs, and the K/V BlockSpec index maps read ``bt_ref[s, ...]`` to
-return PHYSICAL block ids, so Pallas' pipelining fetches exactly the
-blocks the table names. The index map clamps the logical block index
-to the slot's last LIVE block (``(lengths[s]-1) // BS``): grid steps
-beyond the live length re-present the previous block index, and
-Pallas elides the re-DMA for an unchanged block — the kernel never
-over-reads past a slot's live length, the fixed-shape over-read the
-roofline's ``paged_pallas`` layout (gather factor 1.0) models away.
+How the table drives the DMA schedule: grid ``(S,)``, one step a slot,
+with ``block_tables`` and ``lengths`` scalar-prefetched, both pools
+left in HBM (``memory_space=ANY``) and q and the output resident in
+VMEM for the whole call. A slot's live blocks are walked in
+CHUNKS of ``G`` blocks (``blocks_per_chunk``: from the shapes and a
+VMEM budget, not an option): one chunk is ``G`` key and ``G`` value
+block copies (``make_async_copy``, physical ids from the table) into
+one half of a double-buffered ``[2, nh, G*BS, hd]`` VMEM pair, side by
+side along the position axis, while the other half is computed. Only
+live blocks are copied: a slot with nothing live (length 0: a released
+slot) costs its grid step, a parked one (length 1) one block of DMA
+and one chunk of arithmetic, a full one ``MB / G`` chunks, and the next
+chunk (the next SLOT's first chunk after a slot's last) is always in
+flight behind the one being computed.
+
+A chunk's arithmetic is two batched MXU matmuls over the heads:
+scores ``q [nh, R, hd] x K [nh, T, hd]^T`` and ``p [nh, R, T] x
+V [nh, T, hd]``, with the one query row replicated to the operand
+tile's ``R`` rows (a lane reduce over ``hd`` on the VPU is what held the
+block-a-step kernel to a few percent of the bandwidth). Precision is
+the oracle's: products of pool-dtype values accumulate in f32, the
+softmax is f32, and its f32 weights are NOT rounded to the pool's
+dtype for the second matmul: with a 16-bit pool rows ``[0, R/2)`` carry
+the weights' upper half (``p`` rounded to the pool dtype) and rows
+``[R/2, R)`` the remainder, and the two partial products are added, so
+``p @ V`` is f32-grade at one pass over ``V``; an f32 pool multiplies
+at ``HIGHEST``.
 
 In-kernel masking mirrors the fallback exactly: key positions
 ``>= lengths[s]`` (trash-block padding rows, a recycled slot's stale
-rows, the tail of a partially-filled block) get ``-1e30`` before the
-f32 online softmax, so they carry exactly-zero weight. Scores and the
-output accumulator are f32 (the ``_dot_f32`` discipline); scores are
-computed as a VPU multiply-reduce over ``hd`` — per (slot, head) the
-contraction is ``[1, hd] x [hd, BS]``, far too skinny to feed the MXU,
-and the whole op is HBM-bound anyway.
+rows, the tail of a partially-filled block, the part of a chunk's
+buffer no copy refreshed) get ``-1e30`` before the f32 online softmax,
+so they carry exactly-zero weight. The value buffer is zeroed once a
+call, so what lies behind a zero weight is always finite.
 
-Gating follows the fused-CE playbook: ``PADDLE_PAGED_ATTN=1`` env
-opt-in (or the ``ServingConfig(paged_attn=...)`` knob), a
-``kernel_viable`` shape/dtype/backend guard, and interpret mode on CPU
-(tests flip ``_FORCE_INTERPRET``) so tier-1 exercises the real kernel
-while the XLA composition stays the default measured fallback.
+Where it runs: ``kernel_viable`` (backend has Mosaic, shapes tile) is
+the only gate; ``ServingEngine`` asks it once at build time and the
+GPT's paged decode program uses the kernel wherever it says yes. The
+CPU and refused shapes keep ``cached_paged_attention``, which is also
+the parity oracle; tests flip ``_FORCE_INTERPRET`` to run the real
+kernel in interpret mode on the CPU.
 """
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -48,20 +60,17 @@ from .pallas_compat import trace_32bit as _trace_32bit
 
 # tests flip this to run the kernel in interpret mode on CPU
 _FORCE_INTERPRET = [False]
+_NEG = -1e30
+# both pools' double-buffered chunks. At 16 heads x 128 in bf16 this is
+# G = 8 blocks of 16 = 128 positions a chunk: the smallest that runs a
+# full slot at the rate of larger ones (82 % of 819 GB/s; 65 % at G = 4)
+# and cheaper than G = 16 for a slot with one live block, whose chunk
+# is computed whole (2.3 us against 3.0) (my chip runs, PR 29)
+_CHUNK_VMEM_BYTES = 2 << 20
 
 
 def _interpret():
     return _FORCE_INTERPRET[0]
-
-
-def kernel_requested(override=None):
-    """The gate: ``ServingConfig(paged_attn=...)`` when set, else the
-    PADDLE_PAGED_ATTN env var. Default OFF — the XLA gather
-    composition stays the measured fallback until the kernel is
-    explicitly enabled (mirroring PADDLE_FUSED_CE)."""
-    if override is not None:
-        return bool(override)
-    return os.environ.get("PADDLE_PAGED_ATTN", "0") == "1"
 
 
 def kernel_viable(num_heads, head_dim, block_size, dtype):
@@ -76,69 +85,147 @@ def kernel_viable(num_heads, head_dim, block_size, dtype):
         return True   # interpret mode handles any shape
     if jax.default_backend() == "cpu":
         return False
-    # Mosaic wants the K/V block's sublane dim (BS) tiling-aligned;
-    # nh and hd ride in full so they only need the lane minimum
+    # a block lands in the chunk buffer at a multiple of BS along the
+    # sublane dim, so BS must be whole tiles, and Mosaic copies into
+    # such a slice only where hd fills the lanes; a chunk holds at least
+    # one block
     sub = 8 if dtype == jnp.dtype(jnp.float32) else 16
-    return block_size % sub == 0 and head_dim % 8 == 0
+    return (block_size % sub == 0 and head_dim % 128 == 0
+            and blocks_per_chunk(num_heads, head_dim, block_size, 1,
+                                 dtype) == 1)
 
 
-def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, block_size,
-                         max_blocks):
-    """Grid (S, MB), MB innermost: one slot's blocks arrive
-    sequentially, so the online-softmax state (acc, m, l) lives in
-    VMEM scratch across the inner steps and the o block is revisited
-    and written once at the last step — the flash-forward idiom, per
-    slot instead of per query-block."""
+def blocks_per_chunk(num_heads, head_dim, block_size, max_blocks, dtype):
+    """``G``: how many blocks one chunk holds. As many as keep the four
+    chunk buffers (K and V, two halves each) inside the budget, at most
+    a slot's capacity; 0 where not even one block fits."""
+    block = num_heads * block_size * head_dim * jnp.dtype(dtype).itemsize
+    return int(min(max_blocks, _CHUNK_VMEM_BYTES // (4 * block)))
+
+
+def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         kbuf, vbuf, q_rows, acc_ref, m_ref, l_ref, sem,
+                         half_ref, *, block_size, group):
+    """Grid (S,), sequential. ``kbuf``/``vbuf`` ``[2, nh, G*BS, hd]``
+    are the two halves of the chunk buffers, ``sem[0/1, half]`` the K/V
+    copies' semaphores, ``half_ref`` (SMEM) the half that holds this
+    slot's first chunk: the previous grid step started its copies."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    BS, G, MB = block_size, group, bt_ref.shape[1]
+    T = G * BS
     si = pl.program_id(0)
-    bi = pl.program_id(1)
+    num_slots = pl.num_programs(0)
+    nh, rows, hd = q_rows.shape
+    split = kbuf.dtype != jnp.float32   # 16-bit pool: p as upper + rest
 
-    @pl.when(bi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def live_blocks(s):
+        return jnp.clip((len_ref[s] + (BS - 1)) // BS, 0, MB)
+
+    def chunk_copies(s, c, half, go):
+        """``go`` (start or wait) the K and V copies of the live blocks
+        of slot s's chunk c, block g to positions [g*BS, (g+1)*BS)."""
+        def block(g, carry):
+            blk = bt_ref[s, c * G + g]
+            dst = pl.ds(pl.multiple_of(g * BS, BS), BS)
+            go(pltpu.make_async_copy(
+                k_hbm.at[blk], kbuf.at[half, :, dst, :], sem.at[0, half]))
+            go(pltpu.make_async_copy(
+                v_hbm.at[blk], vbuf.at[half, :, dst, :], sem.at[1, half]))
+            return carry
+        jax.lax.fori_loop(
+            0, jnp.clip(live_blocks(s) - c * G, 0, G), block, 0)
+
+    def start_chunk(s, c, half):
+        chunk_copies(s, c, half, lambda copy: copy.start())
+
+    def wait_chunk(s, c, half):
+        chunk_copies(s, c, half, lambda copy: copy.wait())
+
+    @pl.when(si == 0)
+    def _first():
+        # what no copy has written yet must be finite behind its zero
+        # weight; K's garbage is replaced by the mask itself
+        vbuf[...] = jnp.zeros_like(vbuf)
+        half_ref[0] = 0
+        start_chunk(0, 0, 0)
 
     length = len_ref[si]
+    chunks = (live_blocks(si) + (G - 1)) // G
+    half0 = half_ref[0]
+    has_next = si + 1 < num_slots
+    nxt = jnp.minimum(si + 1, num_slots - 1)
+    nt = (((2,), (2,)), ((0,), (0,)))      # [nh,R,hd] x [nh,T,hd]^T
+    nn = (((2,), (1,)), ((0,), (0,)))      # [nh,R,T]  x [nh,T,hd]
+    prec = None if split else jax.lax.Precision.HIGHEST
 
-    def _compute():
-        q = q_ref[...]   # [nh, hd]
-        k = k_ref[...]   # [nh, BS, hd]
-        v = v_ref[...]
-        hd = q.shape[-1]
-        # scores [nh, BS] in f32; same scale and mask value as the
-        # fallback so masked softmax terms agree exactly. Cast BEFORE
-        # the [:, None, :]: Mosaic has no bf16 [1,nh,hd]->[nh,1,hd]
-        # shape cast ("unsupported shape cast"), the f32 one it has
-        s = jnp.sum(q.astype(jnp.float32)[:, None, :]
-                    * k.astype(jnp.float32), axis=-1)
-        s = s / jnp.sqrt(jnp.float32(hd))
-        kpos = bi * jnp.int32(block_size) + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < length, s, jnp.float32(-1e30))
-        m_prev = m_ref[...]          # [nh, 1]
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    def chunk(c, carry):
+        half = (half0 + c) % 2
+
+        @pl.when(c + 1 < chunks)
+        def _():
+            start_chunk(si, c + 1, 1 - half)
+
+        @pl.when(jnp.logical_and(c + 1 == chunks, has_next))
+        def _():
+            start_chunk(nxt, 0, 1 - half)
+
+        wait_chunk(si, c, half)
+        s = jax.lax.dot_general(q_rows[...], kbuf[half], nt,
+                                precision=prec,
+                                preferred_element_type=jnp.float32)
+        s = s / jnp.sqrt(jnp.float32(hd))             # [nh, R, T]
+        kpos = c * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(kpos < length, s, jnp.float32(_NEG))
+        m_prev = m_ref[...]                           # [nh, R, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2,
+                                                  keepdims=True)
         m_ref[...] = m_new
-        pv = jnp.sum(p[:, :, None] * v.astype(jnp.float32), axis=1)
+        if split:
+            hi = p.astype(vbuf.dtype)
+            lo = (p - hi.astype(jnp.float32)).astype(vbuf.dtype)
+            upper = jax.lax.broadcasted_iota(
+                jnp.int32, p.shape, 1) < rows // 2
+            p = jnp.where(upper, hi, lo)
+        pv = jax.lax.dot_general(p, vbuf[half], nn, precision=prec,
+                                 preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * alpha + pv
+        return carry
 
-    # blocks entirely beyond the live length contribute zero weight:
-    # skip the math (their DMA is already elided by the index-map
-    # clamp re-presenting the previous block)
-    pl.when(bi * jnp.int32(block_size) < length)(_compute)
+    @pl.when(chunks > 0)
+    def _live():
+        # the one query row, replicated to the operand tile's rows: via
+        # f32 (Mosaic has no 16-bit [nh,hd] -> [nh,1,hd] shape cast) and
+        # through VMEM (its batched matmul cannot take the broadcast's
+        # replicated layout as an operand: apply-vector-layout aborts)
+        q_rows[...] = jnp.broadcast_to(
+            q_ref[si].astype(jnp.float32)[:, None, :],
+            (nh, rows, hd)).astype(q_rows.dtype)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        jax.lax.fori_loop(0, chunks, chunk, 0)
+        acc, l = acc_ref[...], l_ref[...]
+        if split:
+            acc = acc[:, :rows // 2] + acc[:, rows // 2:]
+        # l >= 1 (the max's own exp term); every row of acc is the same
+        o_ref[si] = (jnp.max(acc, axis=1)
+                     / jnp.max(l, axis=1)).astype(o_ref.dtype)
 
-    @pl.when(bi == max_blocks - 1)
-    def _store():
-        # l >= 1 whenever any block computed (the max's own exp term);
-        # the floor only guards a length<=0 slot, whose output is
-        # as-unused as the fallback's uniform-over-garbage row
-        l = jnp.maximum(l_ref[...], jnp.float32(1e-37))
-        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+    @pl.when(chunks == 0)
+    def _idle():
+        # nothing live (a released slot): no copy, no arithmetic, a
+        # finite row nobody reads; the hand-over still happens
+        o_ref[si] = jnp.zeros((nh, hd), o_ref.dtype)
+
+        @pl.when(has_next)
+        def _():
+            start_chunk(nxt, 0, half0)
+
+    half_ref[0] = (half0 + chunks) % 2
 
 
 def _paged_decode_32(q, k_cache, v_cache, block_tables, lengths):
@@ -147,49 +234,59 @@ def _paged_decode_32(q, k_cache, v_cache, block_tables, lengths):
     S, nh, hd = q.shape
     BS = k_cache.shape[2]
     MB = block_tables.shape[1]
+    dtype = k_cache.dtype
+    G = blocks_per_chunk(nh, hd, BS, MB, dtype)
+    # the operand tile's sublanes: 8 of f32, 16 of a 16-bit type (whose
+    # two halves carry the softmax weights' two parts)
+    rows = 8 if dtype == jnp.float32 else 16
     block_tables = block_tables.astype(jnp.int32)
     lengths = lengths.astype(jnp.int32)
 
-    def q_index(si, bi, bt_ref, len_ref):
-        return (si, 0, 0)
-
-    def kv_index(si, bi, bt_ref, len_ref):
-        # physical block id straight from the prefetched table; clamp
-        # to the slot's last live block so beyond-length grid steps
-        # repeat an index and their DMA is elided (no over-read)
-        last = jnp.minimum(jnp.maximum(len_ref[si] - 1, 0)
-                           // jnp.int32(BS), MB - 1)
-        return (bt_ref[si, jnp.minimum(bi, last)], 0, 0, 0)
+    def whole(si, bt_ref, len_ref):
+        # q and o stay in VMEM for the whole call (a block a grid step
+        # would put two small copies' latency into every step, which is
+        # most of a step that has little or nothing live)
+        return (0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, MB),
+        grid=(S,),
         in_specs=[
-            pl.BlockSpec((None, nh, hd), q_index),
-            pl.BlockSpec((None, nh, BS, hd), kv_index),
-            pl.BlockSpec((None, nh, BS, hd), kv_index),
+            pl.BlockSpec((S, nh, hd), whole),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((None, nh, hd), q_index),
+        out_specs=pl.BlockSpec((S, nh, hd), whole),
         scratch_shapes=[
-            pltpu.VMEM((nh, hd), jnp.float32),
-            pltpu.VMEM((nh, 1), jnp.float32),
-            pltpu.VMEM((nh, 1), jnp.float32),
+            pltpu.VMEM((2, nh, G * BS, hd), dtype),
+            pltpu.VMEM((2, nh, G * BS, hd), dtype),
+            pltpu.VMEM((nh, rows, hd), dtype),
+            pltpu.VMEM((nh, rows, hd), jnp.float32),
+            pltpu.VMEM((nh, rows, 1), jnp.float32),
+            pltpu.VMEM((nh, rows, 1), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     kernel = functools.partial(_paged_decode_kernel, block_size=BS,
-                               max_blocks=MB)
-    return pl.pallas_call(kernel, name="paged_decode_attn",
-        grid_spec=grid_spec,
+                               group=G)
+    return pl.pallas_call(
+        kernel, name="paged_decode_attn", grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, nh, hd), q.dtype),
+        # sequential: a slot's last chunk starts the next slot's first
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(block_tables, lengths, q, k_cache, v_cache)
 
 
 def paged_decode_attention(q, k_cache, v_cache, block_tables, lengths):
     """Drop-in for ``ops.attention.cached_paged_attention`` (same
-    signature, same semantics) reading K/V blocks in place. Callers
-    check ``kernel_viable`` first; ``cached_paged_attention`` is
-    the bit-exact-fallback parity oracle."""
+    signature, same numbers for every slot with something live) reading
+    the live K/V blocks in place; a slot of length 0 gets a row of
+    zeros where the oracle averages garbage, and nobody reads either.
+    Callers check ``kernel_viable`` first; ``cached_paged_attention`` is
+    the parity oracle."""
     # x64 guard shared by every Pallas entry point (pallas_compat)
     return _trace_32bit(_paged_decode_32)(q, k_cache, v_cache,
                                           block_tables, lengths)

@@ -165,7 +165,6 @@ class ServingConfig:
                  completed_keep=4096, trace_keep=256,
                  trace_decode_window=32, peak_flops=None,
                  paged=None, block_size=16, num_blocks=None,
-                 paged_attn=None,
                  prefill_chunk=None, prefill_token_budget=None,
                  policy=None, sampling=False, health=None,
                  health_audit_every=64, health_ledger_keep=512,
@@ -232,14 +231,6 @@ class ServingConfig:
         self.paged = bool(paged)
         self.block_size = int(block_size)
         self.num_blocks = num_blocks
-        # Pallas paged decode-attention kernel (ops.paged_attention):
-        # None = the PADDLE_PAGED_ATTN env gate (default off — the
-        # XLA gather composition stays the measured fallback, same
-        # playbook). Only meaningful with paged=True; the engine still
-        # applies the kernel_viable shape/dtype/backend guard, so the
-        # resolved path is exposed as engine.decode_layout.
-        from ..ops.paged_attention import kernel_requested
-        self.paged_attn = kernel_requested(paged_attn)
         # chunked prefill (serving.sched): prompts longer than
         # prefill_chunk split into fixed-width chunks interleaved with
         # decode steps under prefill_token_budget chunk tokens per
@@ -512,32 +503,32 @@ class ServingEngine:
 
             self._pool_factory = _pool_factory
             self.pool = _pool_factory()
-            # resolve the decode-attention path ONCE at build time:
-            # gate (config/env) AND the kernel_viable guard over the
-            # static shapes/dtype/backend — a trace-time branch inside
-            # the one compiled decode program, so signatures, AOT keys
-            # and the zero-steady-state-compile contract are unchanged
-            from ..ops.paged_attention import kernel_viable
-            import jax
-            shape = (cfg.num_heads, cfg.hidden_size // cfg.num_heads,
-                     self.pool.block_size, kv_dtype)
-            self.paged_attn = bool(config.paged_attn) \
-                and self._kv_pair and kernel_viable(*shape)
-            if config.paged_attn and not self.paged_attn \
-                    and jax.default_backend() != "cpu":
-                # asked for by name on a backend that has Mosaic: a
-                # quiet drop to the gather path would hide the refusal
-                raise ValueError(
-                    f"paged_attn was requested but the Pallas decode "
-                    f"kernel is not viable for (heads, head_dim, block, "
-                    f"pool dtype) = {shape}: see "
-                    f"ops.paged_attention.kernel_viable")
-            self._prefill_fn, self._decode_fn = \
-                model.build_paged_serving_fns(
-                    config.num_slots, self.pool.block_size,
-                    self.pool.num_blocks, self.pool.blocks_per_slot,
-                    sampling=self.sampling,
-                    attn_kernel=self.paged_attn)
+            # the decode-attention path, resolved ONCE at build time
+            # from what is observable and nothing else: a (k, v) pool
+            # whose shapes, dtype and backend the Pallas kernel takes
+            # (ops.paged_attention.kernel_viable) gets the kernel, the
+            # rest (the CPU, untileable shapes) the XLA gather. A
+            # trace-time branch inside the one compiled decode program,
+            # so signatures, AOT keys and the zero-steady-state-compile
+            # contract are the same on either path. A model with
+            # another cache (latent attention) brings its own programs
+            # and kernels and is never handed this choice.
+            sizes = (config.num_slots, self.pool.block_size,
+                     self.pool.num_blocks, self.pool.blocks_per_slot)
+            if self._kv_pair:
+                from ..ops.paged_attention import kernel_viable
+                self.paged_attn = bool(kernel_viable(
+                    cfg.num_heads, cfg.hidden_size // cfg.num_heads,
+                    self.pool.block_size, kv_dtype))
+                self._prefill_fn, self._decode_fn = \
+                    model.build_paged_serving_fns(
+                        *sizes, sampling=self.sampling,
+                        attn_kernel=self.paged_attn)
+            else:
+                self.paged_attn = False
+                self._prefill_fn, self._decode_fn = \
+                    model.build_paged_serving_fns(
+                        *sizes, sampling=self.sampling)
             self._chunk_fn = None   # chunks reuse the paged prefill
         else:
             self.paged_attn = False

@@ -71,13 +71,15 @@ same recycled-slot invariant the legacy pool pins, at block granularity.
 / top-k / top-p — serving.sched.sampling) through both programs; the
 greedy path is the default and keeps the original signatures.
 
-``attn_kernel=True`` swaps the decode program's attention for the
-Pallas paged kernel (ops.paged_attention) that reads K/V blocks in
-place via scalar-prefetched table indices instead of materializing
-the gathered view — a trace-time branch, so the program key, its
-signature and the zero-steady-state-compile contract are unchanged.
-The caller (the engine) resolves ``kernel_viable`` once at build time;
-there is no second, quiet fallback here.
+``attn_kernel`` is the decode program's attention: True = the Pallas
+paged kernel (ops.paged_attention) that reads each slot's LIVE K/V
+blocks in place from the carried flat pool, False = the XLA gather of
+every slot's whole table row (``cached_paged_attention``: the CPU's
+path, the path of shapes the kernel refuses, and the parity oracle). A
+trace-time branch, so the program key, its signature and the
+zero-steady-state-compile contract are the same on either. The engine
+chooses once at build time from ``kernel_viable``; nobody sets it by
+hand, and there is no second, quiet fallback here.
 """
 
 
@@ -94,6 +96,7 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
     from ...ops import paged_attention as paged_attn_ops
     from ...text.models import _decode_forward_builder
     from ..sched.sampling import build_sampling_head
+    from .pool import TRASH_BLOCK
 
     nh = cfg.num_heads
     hd = cfg.hidden_size // nh
@@ -190,6 +193,14 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
         vf = vc.reshape((L * NB,) + vc.shape[2:])
         row = (jnp.arange(BS, dtype=jnp.int32)[None, :]
                == off[:, None])[:, None, :, None]      # [S, 1, BS, 1]
+        # what attention may read of a slot: its positions so far, and
+        # never more than the blocks its table row holds. A released
+        # slot's position keeps counting while its row is all trash:
+        # nothing of it is live (length 0), and the kernel, whose work
+        # follows this length, passes it by instead of attending over a
+        # full row of trash
+        held = jnp.sum((tables != TRASH_BLOCK).astype(jnp.int32), axis=1)
+        lengths = jnp.minimum(pos + 1, held * jnp.int32(BS))
 
         def body(carry, inp):
             x, kf, vf = carry
@@ -214,11 +225,11 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
                 ltab = tables + base     # layer l's trash: l*NB + trash
                 if attn_kernel:
                     o = paged_attn_ops.paged_decode_attention(
-                        q, kf, vf, ltab, pos + 1)
+                        q, kf, vf, ltab, lengths)
                 else:
                     # gathers the slots' blocks under "kv_gather"
                     o = attn_ops.cached_paged_attention(
-                        q, kf, vf, ltab, pos + 1)
+                        q, kf, vf, ltab, lengths)
                 o = o.reshape(S, hidden)                  # concat heads
                 x = x + (o @ p["out_w"] + p["out_b"])
             with jax.named_scope("mlp"):

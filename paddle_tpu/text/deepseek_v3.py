@@ -464,7 +464,6 @@ class DeepseekV3ForCausalLM(nn.Layer):
         bad = [name for name, on in (
             ("paged=False", not config.paged),
             ("speculative", config.speculative),
-            ("paged_attn", config.paged_attn),
             (f"role={config.role!r}", config.role != "monolithic"),
         ) if on]
         if bad:
@@ -474,17 +473,12 @@ class DeepseekV3ForCausalLM(nn.Layer):
                 f"{', '.join(bad)}")
 
     def build_paged_serving_fns(self, num_slots, block_size, num_blocks,
-                                blocks_per_slot, sampling=False,
-                                attn_kernel=False):
+                                blocks_per_slot, sampling=False):
         """(paged_prefill, paged_decode) over the latent pool, with the
         engine's signatures (``serving/paged/latent_programs.py``). The
         decode program's kernels are not an option: on a backend that
         has Mosaic they are the only path and a shape they cannot take
         is refused here; the CPU runs the ``jnp`` formulations."""
-        if attn_kernel:
-            raise ValueError(
-                "paged_attn selects the GPT's paged decode kernel; this "
-                "model's decode kernels are chosen by the backend")
         from ..serving.paged.latent_programs import build_paged_latent_fns
         return build_paged_latent_fns(
             self.cfg, num_slots, block_size, num_blocks, blocks_per_slot,

@@ -612,9 +612,10 @@ class GPTForCausalLM(nn.Layer):
         prefix AND chunk variety costs zero compiles); the engine
         AOT-compiles them (decode once, prefill once per tail bucket).
         ``sampling=True`` appends per-slot sampling parameters to both
-        signatures (serving.sched.sampling); ``attn_kernel=True``
-        swaps the decode attention for the Pallas paged kernel
-        (ops.paged_attention) without changing either signature."""
+        signatures (serving.sched.sampling); ``attn_kernel`` is
+        the decode attention, the Pallas paged kernel
+        (ops.paged_attention) or the XLA gather: the engine's choice
+        from ``kernel_viable``, with the same signatures either way."""
         from ..serving.paged.programs import build_paged_fns
         return build_paged_fns(self.cfg, num_slots, block_size,
                                num_blocks, blocks_per_slot,

@@ -103,18 +103,30 @@ def test_fused_ce_bwd_compiles(one_chip):
         x, w, lab, lse, g, -100), x, w, lab, vec, vec)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-def test_paged_decode_compiles(one_chip, dtype, monkeypatch):
-    slots, block, blocks, per_slot = 8, 16, 256, 64
-    # kernel_viable asks the PROCESS's default backend (cpu here); the
-    # program is compiled for the described TPU, so answer for it
+# the 1.3B serving cell's attention: 16 heads x 128, blocks of 16, 64 a
+# slot (GPT-124M's heads of 64 do not fill the kernel's lanes: refused)
+PAGED_HEADS, PAGED_HEAD_DIM, PAGED_BLOCK, PAGED_PER_SLOT = 16, 128, 16, 64
+
+
+@pytest.fixture
+def mosaic_backend(monkeypatch):
+    """kernel_viable asks the PROCESS's default backend (cpu here); the
+    programs below are compiled for the described TPU, so answer for
+    it."""
     monkeypatch.setattr(paged_attention.jax, "default_backend",
                         lambda: "tpu")
-    assert paged_attention.kernel_viable(HEADS, HEAD_DIM, block, dtype)
-    q = jax.ShapeDtypeStruct((slots, HEADS, HEAD_DIM), dtype,
-                             sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((blocks, HEADS, block, HEAD_DIM), dtype,
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_paged_decode_compiles(one_chip, dtype, mosaic_backend):
+    slots, blocks = 24, 24 * 64 + 1
+    nh, hd, block, per_slot = (PAGED_HEADS, PAGED_HEAD_DIM, PAGED_BLOCK,
+                               PAGED_PER_SLOT)
+    assert paged_attention.kernel_viable(nh, hd, block, dtype)
+    assert not paged_attention.kernel_viable(HEADS, HEAD_DIM, block, dtype)
+    q = jax.ShapeDtypeStruct((slots, nh, hd), dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((blocks, nh, block, hd), dtype,
                               sharding=one_chip)
     bt = jax.ShapeDtypeStruct((slots, per_slot), jnp.int32,
                               sharding=one_chip)
@@ -122,14 +134,17 @@ def test_paged_decode_compiles(one_chip, dtype, monkeypatch):
     _compile(paged_attention.paged_decode_attention, q, kv, kv, bt, ln)
 
 
-def _paged_decode_program(one_chip, sampling, attn_kernel):
+def _paged_decode_program(one_chip, sampling, attn_kernel, slots=4):
     """(compiled paged_decode, pool shape), jitted with the engine's
     donation (pos, kc, vc), at sizes where the pool dominates the
-    program: 8 layers x 257 blocks of [16 heads, 16 tokens, 128] bf16
-    (135 MB for K, as much for V), 4 slots, narrow MLP and vocab."""
+    program: 8 layers of [16 heads, 16 tokens, 128] bf16 blocks, 64 a
+    slot plus the trash block (135 MB for K at 4 slots, as much for V),
+    narrow MLP and vocab. ``attn_kernel`` as the engine would choose it
+    (``kernel_viable``) or refused."""
     from paddle_tpu.serving.paged.programs import build_paged_fns
     from paddle_tpu.text.models import TransformerLMConfig
-    L, S, BS, MB, nh, hd = 8, 4, 16, 64, 16, 128
+    L, S, BS, MB, nh, hd = (8, slots, PAGED_BLOCK, PAGED_PER_SLOT,
+                            PAGED_HEADS, PAGED_HEAD_DIM)
     NB, hidden, ffn, vocab = S * MB + 1, nh * hd, 512, 512
     cfg = TransformerLMConfig(vocab_size=vocab, hidden_size=hidden,
                               num_layers=L, num_heads=nh,
@@ -161,27 +176,35 @@ def _paged_decode_program(one_chip, sampling, attn_kernel):
         *args).compile(), pool
 
 
+def _pool_shaped(compiled, shapes, dtype="bf16"):
+    """[(instruction name, opcode)] of the optimized program's
+    instructions whose result has one of ``shapes`` (comma-joined
+    dims)."""
+    import re
+    inst = re.compile(
+        rf"%([\w.\-]+) = {dtype}\[(?:{'|'.join(shapes)})\]\S* ([\w\-]+)\(")
+    return [m.groups() for m in map(inst.search,
+                                    compiled.as_text().splitlines()) if m]
+
+
 @pytest.mark.parametrize("sampling", [False, True],
                          ids=["greedy", "sampling"])
 def test_paged_decode_program_updates_pool_in_place(one_chip, sampling):
-    """The decode program carries the donated KV pool through its layer
+    """The GATHER decode program (the kernel refused: what the CPU and
+    untileable shapes run) carries the donated KV pool through its layer
     loop in place: both pools aliased onto the results, temporaries
     under ONE pool half (a second pool beside the first would be two),
     and no copy / dynamic-slice / dynamic-update-slice left in the
     optimized program with the pool's shape or one layer's."""
-    import re
     compiled, (L, NB, nh, BS, hd) = _paged_decode_program(
         one_chip, sampling, attn_kernel=False)
     half = L * NB * nh * BS * hd * 2
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * half
     assert mem.temp_size_in_bytes < half, (mem.temp_size_in_bytes, half)
-    shapes = "|".join(f"{lead},{nh},{BS},{hd}" for lead in (
-        f"{L},{NB}", f"1,{NB}", f"{L * NB}", f"{NB}"))
-    inst = re.compile(
-        rf"%([\w.\-]+) = bf16\[(?:{shapes})\]\S* ([\w\-]+)\(")
-    found = [m.groups() for m in map(inst.search,
-                                     compiled.as_text().splitlines()) if m]
+    found = _pool_shaped(compiled, [
+        f"{lead},{nh},{BS},{hd}" for lead in (
+            f"{L},{NB}", f"1,{NB}", f"{L * NB}", f"{NB}")])
     assert found   # the pool is in the program under these shapes
     moving = ("copy", "dynamic-slice", "dynamic-update-slice")
     bad = [(name, op) for name, op in found
@@ -189,15 +212,30 @@ def test_paged_decode_program_updates_pool_in_place(one_chip, sampling):
     assert not bad, bad
 
 
-def test_paged_decode_program_kernel_aliases_pool(one_chip):
-    """attn_kernel=True (what no benchmark cell runs): the program
-    compiles with the Pallas kernel in it, reading the carried flat
-    pool, and aliases both pools."""
+def test_default_gpt_decode_program_reads_live_blocks_in_place(
+        one_chip, mosaic_backend):
+    """The decode program the engine builds by default for the 1.3B
+    cell's shapes on a v5e (24 slots, 16 heads x 128, blocks of 16, 64 a
+    slot, bf16; ``attn_kernel`` is ``kernel_viable``'s answer, as in
+    ``ServingEngine``): ``paged_decode_attn`` is in it, both pools are
+    aliased, the temporaries are under 16 MB, and nothing of a gathered
+    view's shape is left: no ``[24,16,64,16,128]`` (every slot's keys
+    at capacity) and no ``[1536,16,16,128]`` (the gather of 24 x 64
+    blocks) in any type."""
+    chosen = paged_attention.kernel_viable(
+        PAGED_HEADS, PAGED_HEAD_DIM, PAGED_BLOCK, jnp.bfloat16)
+    assert chosen
     compiled, (L, NB, nh, BS, hd) = _paged_decode_program(
-        one_chip, sampling=False, attn_kernel=True)
-    assert "tpu_custom_call" in compiled.as_text()
-    assert compiled.memory_analysis().alias_size_in_bytes >= \
-        2 * 2 * L * NB * nh * BS * hd
+        one_chip, sampling=False, attn_kernel=chosen, slots=24)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_decode_attn" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * 2 * L * NB * nh * BS * hd
+    assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
+    gathered = [f"24,{nh},{PAGED_PER_SLOT},{BS},{hd}",
+                f"{24 * PAGED_PER_SLOT},{nh},{BS},{hd}"]
+    left = _pool_shaped(compiled, gathered, dtype=r"\w+")
+    assert not left, left
 
 
 @pytest.mark.parametrize("vocab,kernel", [(50432, True), (VOCAB, False)])

@@ -200,12 +200,30 @@ def test_sampling_program_runs_and_repeats(model_w):
 
 @pytest.mark.parametrize("option", [
     {"paged": False}, {"paged": True, "speculative": True},
-    {"paged": True, "paged_attn": True},
     {"paged": True, "role": "prefill"}],
-    ids=["contiguous", "speculative", "paged_attn", "role"])
+    ids=["contiguous", "speculative", "role"])
 def test_engine_refuses_an_option_without_a_program(model_w, option):
     with pytest.raises(ValueError, match="no program for"):
         ServingEngine(model_w[0], num_slots=2, **option)
+
+
+def test_engine_never_hands_the_latent_model_the_gpt_kernel(model_w):
+    """The engine chooses the GPT's paged decode kernel from
+    ``kernel_viable`` only where the cache is a (k, v) pair. Even where
+    that guard would say yes to anything (forced interpret), a latent
+    cache's programs are built without the choice: their builder has no
+    such parameter."""
+    import inspect
+    from paddle_tpu.ops import paged_attention as pa
+    assert "attn_kernel" not in inspect.signature(
+        model_w[0].build_paged_serving_fns).parameters
+    pa._FORCE_INTERPRET[0] = True
+    try:
+        eng = ServingEngine(model_w[0], num_slots=2, paged=True,
+                            block_size=8, max_len=64, buckets=[16])
+    finally:
+        pa._FORCE_INTERPRET[0] = False
+    assert eng.paged_attn is False and eng.decode_layout == "paged_xla"
 
 
 # --------------------------------------------------- hand calculations

@@ -1,11 +1,10 @@
 """Pallas paged decode-attention kernel (ops/paged_attention.py):
 interpret-mode parity vs the XLA gather oracle across block sizes /
-ragged lengths / trash rows / recycled slots / dtypes, the
-gate-and-guard resolution, the f32 score-accumulation precision fix,
-engine-level greedy parity + zero steady-state compiles with the
-kernel enabled, and the roofline layout binding."""
-import os
-
+ragged lengths around the chunk boundaries / parked slots / trash rows
+/ recycled slots / dtypes, the guard that chooses it, the f32
+score-accumulation precision fix, engine-level greedy parity + zero
+steady-state compiles on the observed choice, and the roofline layout
+binding."""
 import numpy as np
 import pytest
 
@@ -22,6 +21,17 @@ def interpret_kernel():
     pa._FORCE_INTERPRET[0] = True
     yield
     pa._FORCE_INTERPRET[0] = False
+
+
+@pytest.fixture
+def two_block_chunks(monkeypatch):
+    """Shrink the chunk budget so that the tiny shapes here walk several
+    chunks of G = 2 blocks a slot (the real budget would hold a whole
+    slot of them in one)."""
+    def budget(nh, hd, BS, itemsize):
+        monkeypatch.setattr(pa, "_CHUNK_VMEM_BYTES",
+                            2 * 4 * nh * BS * hd * itemsize)
+    return budget
 
 
 def _paged_case(seed, S, nh, hd, BS, MB, lengths=None, trash_fill=0.0):
@@ -46,6 +56,23 @@ def _paged_case(seed, S, nh, hd, BS, MB, lengths=None, trash_fill=0.0):
     return q, kc, vc, tables, lengths
 
 
+def _check_parity(q, kc, vc, tables, lens, dtype):
+    import jax.numpy as jnp
+    dt = jnp.dtype(dtype)
+    q, kc, vc = (jnp.asarray(q, dt), jnp.asarray(kc, dt),
+                 jnp.asarray(vc, dt))
+    ref = attn_ops.cached_paged_attention(q, kc, vc, jnp.asarray(tables),
+                                          jnp.asarray(lens))
+    out = pa.paged_decode_attention(q, kc, vc, jnp.asarray(tables),
+                                    jnp.asarray(lens))
+    assert out.shape == q.shape and out.dtype == q.dtype
+    ref32 = np.asarray(ref, np.float32)
+    out32 = np.asarray(out, np.float32)
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(out32, ref32, rtol=tol, atol=tol)
+    np.testing.assert_array_equal(out32.argmax(-1), ref32.argmax(-1))
+
+
 @pytest.mark.parametrize("S,nh,hd,BS,MB", [
     (4, 4, 8, 8, 4),     # the tier-1 engine shape
     (3, 2, 16, 4, 5),    # odd slot count, small blocks
@@ -60,25 +87,99 @@ def test_kernel_matches_gather_oracle(interpret_kernel, S, nh, hd, BS,
     tails included) and trash-padded tables, in f32 and bf16 —
     numerically tight, and bit-exact on the argmax (the greedy
     contract)."""
-    import jax.numpy as jnp
     lengths = [1, BS, BS + 1, MB * BS, max(1, MB * BS - 3)][:S]
-    q, kc, vc, tables, lens = _paged_case(7, S, nh, hd, BS, MB,
-                                          lengths=lengths)
-    dt = jnp.dtype(dtype)
+    assert pa.kernel_viable(nh, hd, BS, dtype)
+    _check_parity(*_paged_case(7, S, nh, hd, BS, MB, lengths=lengths),
+                  dtype)
+
+
+# (name, length as a function of BS, G, capacity)
+_CHUNK_EDGE_LENGTHS = [
+    ("one", lambda BS, G, C: 1),
+    ("one_block", lambda BS, G, C: BS),
+    ("chunk_minus_1", lambda BS, G, C: G * BS - 1),
+    ("chunk", lambda BS, G, C: G * BS),
+    ("chunk_plus_1", lambda BS, G, C: G * BS + 1),
+    ("capacity", lambda BS, G, C: C),
+]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_kernel_chunk_edges_in_one_batch(interpret_kernel,
+                                         two_block_chunks, dtype):
+    """Every length at which the chunk walk changes shape, side by side
+    in one batch (so each slot's last chunk hands the buffers on to a
+    neighbour of another length): 1, one block, one short of a chunk, a
+    chunk, one over, full capacity; G = 2 blocks a chunk, 3 chunks a
+    full slot."""
+    S, nh, hd, BS, MB = 6, 2, 16, 8, 6
+    two_block_chunks(nh, hd, BS, 4 if dtype == "float32" else 2)
+    G = pa.blocks_per_chunk(nh, hd, BS, MB, dtype)
+    assert G == 2
+    lengths = [f(BS, G, MB * BS) for _, f in _CHUNK_EDGE_LENGTHS]
+    _check_parity(*_paged_case(5, S, nh, hd, BS, MB, lengths=lengths),
+                  dtype)
+
+
+@pytest.mark.parametrize("name,length", _CHUNK_EDGE_LENGTHS,
+                         ids=[n for n, _ in _CHUNK_EDGE_LENGTHS])
+def test_kernel_whole_batch_at_one_chunk_edge(interpret_kernel,
+                                              two_block_chunks, name,
+                                              length):
+    """The same edges with every slot at the SAME length: the hand-over
+    between slots then always meets a chunk count of its own kind (all
+    one chunk, all three), bf16 pool."""
+    S, nh, hd, BS, MB = 3, 2, 16, 16, 6
+    two_block_chunks(nh, hd, BS, 2)
+    n = length(BS, pa.blocks_per_chunk(nh, hd, BS, MB, "bfloat16"),
+               MB * BS)
+    _check_parity(*_paged_case(13, S, nh, hd, BS, MB, lengths=[n] * S),
+                  "bfloat16")
+
+
+@pytest.mark.parametrize("order", ["parked_first", "full_first"])
+def test_kernel_parked_slot_beside_a_full_one(interpret_kernel,
+                                              two_block_chunks, order):
+    """A parked slot (length 1: one live block, the rest of its chunk
+    never copied) between full ones, in both orders: what the chunk
+    buffer still holds of the neighbour's keys and values carries
+    exactly zero weight."""
+    S, nh, hd, BS, MB = 4, 2, 16, 8, 6
+    two_block_chunks(nh, hd, BS, 2)
+    lengths = [1, MB * BS, 1, MB * BS]
+    if order == "full_first":
+        lengths = lengths[::-1]
+    _check_parity(*_paged_case(17, S, nh, hd, BS, MB, lengths=lengths),
+                  "bfloat16")
+
+
+@pytest.mark.parametrize("lengths", [
+    [0, 40, 0, 0, 48, 0], [33, 0, 0, 1, 0, 17], [0, 0, 0, 0, 0, 0]],
+    ids=["idle_first_and_last", "idle_runs_between", "all_idle"])
+def test_kernel_passes_by_slots_with_nothing_live(interpret_kernel,
+                                                  two_block_chunks,
+                                                  lengths):
+    """Length 0 (a released slot) is passed by: no copy, no arithmetic,
+    a finite row of zeros; the chunk buffers' hand-over from slot to
+    slot goes through any run of such slots, so the live slots around
+    them still match the oracle."""
+    import jax.numpy as jnp
+    S, nh, hd, BS, MB = 6, 2, 16, 8, 6
+    two_block_chunks(nh, hd, BS, 2)
+    q, kc, vc, tables, _ = _paged_case(19, S, nh, hd, BS, MB,
+                                       lengths=[MB * BS] * S)
+    lens = np.asarray(lengths, np.int32)
+    tables[lens == 0] = 0       # a released row is all trash
+    dt = jnp.bfloat16
     q, kc, vc = (jnp.asarray(q, dt), jnp.asarray(kc, dt),
                  jnp.asarray(vc, dt))
-    assert pa.kernel_viable(nh, hd, BS, dt)
-    ref = attn_ops.cached_paged_attention(q, kc, vc,
-                                          jnp.asarray(tables),
-                                          jnp.asarray(lens))
-    out = pa.paged_decode_attention(q, kc, vc, jnp.asarray(tables),
-                                    jnp.asarray(lens))
-    assert out.shape == (S, nh, hd) and out.dtype == q.dtype
-    ref32 = np.asarray(ref, np.float32)
-    out32 = np.asarray(out, np.float32)
-    tol = 1e-5 if dtype == "float32" else 2e-2
-    np.testing.assert_allclose(out32, ref32, rtol=tol, atol=tol)
-    np.testing.assert_array_equal(out32.argmax(-1), ref32.argmax(-1))
+    out = np.asarray(pa.paged_decode_attention(
+        q, kc, vc, jnp.asarray(tables), jnp.asarray(lens)), np.float32)
+    ref = np.asarray(attn_ops.cached_paged_attention(
+        q, kc, vc, jnp.asarray(tables), jnp.asarray(lens)), np.float32)
+    live = lens > 0
+    np.testing.assert_allclose(out[live], ref[live], rtol=2e-2, atol=2e-2)
+    np.testing.assert_array_equal(out[~live], 0.0)
 
 
 def test_kernel_ignores_trash_and_recycled_rows(interpret_kernel):
@@ -124,11 +225,13 @@ def test_kernel_ignores_trash_and_recycled_rows(interpret_kernel):
     assert np.isfinite(np.asarray(poisoned)).all()
 
 
-def test_guard_and_gate_resolution(monkeypatch):
-    """kernel_viable: CPU without forced interpret refuses (tier-1's
-    default measured path stays the XLA fallback); f64 refuses even
-    forced; the env gate defaults off and PADDLE_PAGED_ATTN=1 or the
-    config knob turns it on."""
+def test_guard_resolution(monkeypatch):
+    """kernel_viable is the only gate: the CPU without forced interpret
+    refuses (tier-1 runs the XLA gather); f64 refuses even forced; on a
+    backend that has Mosaic the shapes decide (whole-tile blocks, heads
+    that fill the lanes, one block inside the chunk budget). No option
+    and no environment name turns the kernel on or off."""
+    import inspect
     import jax
     assert jax.default_backend() == "cpu"
     assert not pa.kernel_viable(4, 8, 8, np.float32)
@@ -138,12 +241,21 @@ def test_guard_and_gate_resolution(monkeypatch):
         assert not pa.kernel_viable(4, 8, 8, np.float64)
     finally:
         pa._FORCE_INTERPRET[0] = False
-    monkeypatch.delenv("PADDLE_PAGED_ATTN", raising=False)
-    assert not pa.kernel_requested(None)
-    assert pa.kernel_requested(True)
-    monkeypatch.setenv("PADDLE_PAGED_ATTN", "1")
-    assert pa.kernel_requested(None)
-    assert not pa.kernel_requested(False)   # knob overrides env
+    monkeypatch.setattr(pa.jax, "default_backend", lambda: "tpu")
+    assert pa.kernel_viable(16, 128, 16, "bfloat16")   # the 1.3B cell
+    assert pa.kernel_viable(16, 128, 8, np.float32)
+    assert not pa.kernel_viable(16, 128, 8, "bfloat16")  # half a tile
+    assert not pa.kernel_viable(12, 64, 16, "bfloat16")  # half the lanes
+    assert not pa.kernel_viable(64, 256, 64, np.float32)  # over budget
+    assert pa.blocks_per_chunk(16, 128, 16, 64, "bfloat16") == 8
+    assert pa.blocks_per_chunk(16, 128, 16, 4, "bfloat16") == 4
+    assert not hasattr(pa, "kernel_requested")
+    assert "environ" not in inspect.getsource(pa)
+    from paddle_tpu.serving.engine import ServingConfig
+    assert "paged_attn" not in inspect.signature(
+        ServingConfig.__init__).parameters
+    with pytest.raises(TypeError):
+        ServingConfig(paged=True, paged_attn=True)
 
 
 def test_cached_attention_scores_accumulate_f32():
@@ -193,8 +305,9 @@ def _ref(m, prompt, n_new):
 def test_engine_kernel_greedy_parity_zero_compiles(interpret_kernel,
                                                    async_depth,
                                                    monkeypatch):
-    """Engine-level contract with the gate on (sync and async
-    schedules): every stream bit-exact with generate(), zero
+    """Engine-level contract on the engine's own choice (forced
+    interpret makes ``kernel_viable`` say yes on the CPU; sync and
+    async schedules): every stream bit-exact with generate(), zero
     steady-state compiles (watchdog raise-mode), and the perf report
     binds the paged_pallas layout + a decode roofline fraction."""
     # the CPU has no peaks of its own; state some so the roofline
@@ -203,8 +316,7 @@ def test_engine_kernel_greedy_parity_zero_compiles(interpret_kernel,
     monkeypatch.setenv("PADDLE_TPU_HBM_BPS", "819e9")
     m = _tiny_model()
     eng = ServingEngine(m, num_slots=4, bucket_min=8, paged=True,
-                        block_size=8, paged_attn=True,
-                        async_depth=async_depth,
+                        block_size=8, async_depth=async_depth,
                         watchdog_mode="raise")
     assert eng.paged_attn and eng.decode_layout == "paged_pallas"
     rs = np.random.RandomState(0)
@@ -235,25 +347,60 @@ def test_engine_kernel_greedy_parity_zero_compiles(interpret_kernel,
     assert state["decode_layout"] == "paged_pallas"
 
 
-def test_engine_gate_off_and_guard_fallback(monkeypatch):
-    """Default-off on CPU tier-1: without the gate the engine stays on
-    the XLA gather path; with the gate but no forced interpret the
-    kernel_viable guard refuses on CPU and the engine falls back —
-    layout honesty says paged_xla either way."""
-    monkeypatch.delenv("PADDLE_PAGED_ATTN", raising=False)
+def test_engine_keeps_gather_where_guard_refuses():
+    """On CPU tier-1 the guard refuses (no Mosaic), so a paged engine
+    is built on the XLA gather path and says so; the legacy pool is
+    contiguous. Layout honesty either way."""
     m = _tiny_model()
     eng = ServingEngine(m, num_slots=2, bucket_min=8, paged=True,
                         block_size=8)
     assert not eng.paged_attn
     assert eng.decode_layout == "paged_xla"
-    gated = ServingEngine(m, num_slots=2, bucket_min=8, paged=True,
-                          block_size=8, paged_attn=True)
-    assert not gated.paged_attn           # guard refused (CPU)
-    assert gated.decode_layout == "paged_xla"
+    assert eng.debug_state()["paged_attn"] is False
     legacy = ServingEngine(m, num_slots=2, bucket_min=8)
     assert legacy.decode_layout == "contiguous"
     model = legacy.metrics.perf_report()["decode_roofline"]["model"]
     assert model["layout"] == "contiguous"
+
+
+def test_released_slot_costs_the_kernel_nothing(interpret_kernel):
+    """A released slot's position keeps counting while its table row is
+    all trash. The decode program hands attention no more than the
+    blocks a row holds, so the kernel is asked for nothing of it (length
+    0) and not for a capacity of trash; live slots' lengths are their
+    positions, as before."""
+    import jax.numpy as jnp
+    from paddle_tpu.serving.paged.programs import build_paged_fns
+    m = _tiny_model()
+    S, BS, MB = 3, 8, 4
+    NB = S * MB + 1
+    seen = {}
+    real = pa.paged_decode_attention
+
+    def spy(q, kf, vf, tables, lengths):
+        seen["lengths"] = lengths
+        return real(q, kf, vf, tables, lengths)
+
+    pa.paged_decode_attention = spy
+    try:
+        _, decode = build_paged_fns(m.cfg, S, BS, NB, MB,
+                                    attn_kernel=True)
+        params = m.export_decode_params()
+        L, nh = m.cfg.num_layers, m.cfg.num_heads
+        hd = m.cfg.hidden_size // nh
+        pool = jnp.zeros((L, NB, nh, BS, hd), jnp.float32)
+        tables = np.zeros((S, MB), np.int32)
+        tables[0, :2] = [1, 2]          # live, 2 blocks held
+        tables[2, :4] = [3, 4, 5, 6]    # live, full row held
+        pos = jnp.asarray([9, 27, 31], jnp.int32)   # slot 1 released
+        import jax
+        with jax.disable_jit():
+            decode(params, jnp.zeros((S,), jnp.int32), pos,
+                   jnp.asarray(tables), pool, pool)
+    finally:
+        pa.paged_decode_attention = real
+    np.testing.assert_array_equal(np.asarray(seen["lengths"]),
+                                  [10, 0, 32])
 
 
 def test_roofline_paged_pallas_layout():
@@ -288,3 +435,59 @@ def test_roofline_paged_pallas_layout():
     assert pallas["bytes_total"] < cont["bytes_total"] \
         < xla["bytes_total"]
     assert pallas["floor_s"] < xla["floor_s"]
+
+
+def _reader_ctx(ops, decode_calls=100):
+    """What ``benchmarks/run.py`` hands a per-layer reader, cut to what
+    the two paged-attention readers take: a reduced trace, the client's
+    records, the traced window's bounds, model sizes and peaks."""
+    class Rec:
+        def __init__(self, prompt_len, stamps):
+            self.spec = {"prompt": [0] * prompt_len}
+            self.stamps = stamps
+    # two sequences: stamps[0] is prefill's token; the decode steps
+    # inside the traced window [10, 20) read p + j positions each
+    recs = [Rec(100, [9.0, 10.5, 11.5, 25.0]),     # 101 + 102
+            Rec(300, [9.5, 12.0])]                 # 301
+    return {
+        "trace": {"ops": ops, "programs": {"jit_paged_decode(1)": {
+            "seconds": 1.0, "calls": decode_calls, "durations_s": []}}},
+        "programs": {"decode": "jit_paged_decode"},
+        "trace_bounds": (10.0, 20.0),
+        "run": {"recs": recs},
+        "model": {"hidden_size": 2048, "num_hidden_layers": 24,
+                  "num_attention_heads": 16, "intermediate_size": 8192,
+                  "vocab_size": 50304, "max_position_embeddings": 1024},
+        "kv_bytes_per_value": 2,
+        "peaks": {"hbm_bytes_per_s": 819e9},
+    }
+
+
+def test_benchmark_readers_time_the_kernel_by_name():
+    """``paged_attn_dev_ms_per_step`` is the self time of the ops whose
+    instruction NAME holds ``paged_decode_attn`` per decode execution;
+    ``paged_attn_roofline`` the live K/V bytes of the traced steps at
+    the HBM bandwidth over that time. A program without the kernel (the
+    parent's gather, the CPU) reads None from both, not zero."""
+    from benchmarks.metrics import (paged_attn_dev_ms_per_step,
+                                    paged_attn_roofline)
+    ops = {
+        "%paged_decode_attn.8 = bf16[24,16,128] custom-call(...)":
+            {"seconds": 0.05, "calls": 2400},
+        "%fusion.1 = bf16[8] fusion(%paged_decode_attn.8)":
+            {"seconds": 9.0, "calls": 100},   # names it only as operand
+    }
+    ctx = _reader_ctx(ops)
+    ms = paged_attn_dev_ms_per_step.read(ctx)
+    assert ms == pytest.approx(0.5)
+    live = (101 + 102 + 301) / 100          # positions a decode step
+    nbytes = 2 * 24 * 2048 * live * 2
+    assert paged_attn_roofline.read(ctx) == pytest.approx(
+        100.0 * nbytes / 819e9 / 0.5e-3)
+    gather = _reader_ctx({"%copy.19 = f32[24,16,64,16,128] copy(...)":
+                          {"seconds": 6.9, "calls": 100}})
+    assert paged_attn_dev_ms_per_step.read(gather) is None
+    assert paged_attn_roofline.read(gather) is None
+    untraced = dict(ctx, trace=None)
+    assert paged_attn_dev_ms_per_step.read(untraced) is None
+    assert paged_attn_roofline.read(untraced) is None

@@ -275,7 +275,10 @@ def test_cached_paged_attention_matches_slot_attention():
 def _parent_decode_step(params, toks, pos, tables, kc, vc, nh, BS):
     """The decode step as the parent of ISSUE 26 formulated it, layer
     by layer in plain jnp: each layer sliced out of the pool, a ROW
-    scatter into the slice, the pool put together again."""
+    scatter into the slice, the pool put together again. Attended
+    lengths as the program hands them on since ISSUE 29: a slot's
+    positions so far, capped by the blocks its table row holds (a
+    released row, all trash, attends nothing)."""
     import jax
     import jax.numpy as jnp
 
@@ -286,13 +289,16 @@ def _parent_decode_step(params, toks, pos, tables, kc, vc, nh, BS):
     x = params["wemb"][toks] + params["pemb"][pos]
     wpos = jnp.minimum(pos, tables.shape[1] * BS - 1)
     bidx, off = tables[jnp.arange(S), wpos // BS], wpos % BS
+    from paddle_tpu.serving.paged.pool import TRASH_BLOCK
+    lengths = jnp.minimum(pos + 1,
+                          (tables != TRASH_BLOCK).sum(axis=1) * BS)
     for l in range(L):
         p = {k: v[l] for k, v in params["stacked"].items()}
         q, k, v = (ln(x, p["ln1_w"], p["ln1_b"]) @ p["qkv_w"]
                    + p["qkv_b"]).reshape(S, 3, nh, hd).transpose(1, 0, 2, 3)
         kc = kc.at[l, bidx, :, off].set(k)
         vc = vc.at[l, bidx, :, off].set(v)
-        o = cached_paged_attention(q, kc[l], vc[l], tables, pos + 1)
+        o = cached_paged_attention(q, kc[l], vc[l], tables, lengths)
         x = x + (o.reshape(S, nh * hd) @ p["out_w"] + p["out_b"])
         m = jax.nn.gelu(ln(x, p["ln2_w"], p["ln2_b"]) @ p["fc1_w"]
                         + p["fc1_b"], approximate=True)

@@ -65,13 +65,14 @@ def _workload(n_requests=16):
              int(rs.randint(3, 8))) for n in lengths]
 
 
-def _drain(model, specs, paged, chaos=None, chunk=None,
-           paged_attn=False, spec=False):
-    """One engine drain; returns (streams, engine, steps, fault_log)."""
+def _drain(model, specs, paged, chaos=None, chunk=None, spec=False):
+    """One engine drain; returns (streams, engine, steps, fault_log).
+    The paged engine's decode attention is its own choice (the Pallas
+    kernel wherever ``kernel_viable`` says yes: here, under forced
+    interpret)."""
     from paddle_tpu.serving import ServingEngine
     eng = ServingEngine(
-        model, num_slots=4, bucket_min=8, paged=paged,
-        paged_attn=paged_attn, speculative=spec,
+        model, num_slots=4, bucket_min=8, paged=paged, speculative=spec,
         prefill_chunk=chunk, chaos=chaos, max_dispatch_retries=3,
         supervisor_cooldown_s=0.0, health_audit_every=8)
     reqs = [eng.add_request(p, max_new_tokens=k,
@@ -88,7 +89,7 @@ def _drain(model, specs, paged, chaos=None, chunk=None,
 
 
 def _check_cell(site, seed, model, specs, reference, paged, chunk,
-                paged_attn=False, spec=False):
+                spec=False):
     """Run one (site, seed) cell twice; returns a result dict with
     ok=False and a reason on any contract break."""
     from paddle_tpu.serving.resilience import FaultPlan
@@ -98,12 +99,13 @@ def _check_cell(site, seed, model, specs, reference, paged, chunk,
     def plan():
         return FaultPlan(seed=seed, faults=faults)
 
-    out = {"site": site, "seed": seed, "paged": paged,
-           "paged_attn": paged_attn, "spec": spec, "ok": True}
+    out = {"site": site, "seed": seed, "paged": paged, "spec": spec,
+           "ok": True}
     streams, eng, steps, log = _drain(model, specs, paged,
                                       chaos=plan(), chunk=chunk,
-                                      paged_attn=paged_attn, spec=spec)
+                                      spec=spec)
     out["steps"] = steps
+    out["paged_attn"] = eng.paged_attn
     if streams is None:
         return dict(out, ok=False, reason=f"hang: > {_MAX_STEPS} steps")
     res = eng.metrics.snapshot()["resilience"]
@@ -136,8 +138,7 @@ def _check_cell(site, seed, model, specs, reference, paged, chunk,
                     reason=f"{incomplete}/{len(specs)} incomplete")
     # determinism: same seed => identical fault log and streams
     streams2, _, _, log2 = _drain(model, specs, paged, chaos=plan(),
-                                  chunk=chunk, paged_attn=paged_attn,
-                                  spec=spec)
+                                  chunk=chunk, spec=spec)
     if log2 != log:
         return dict(out, ok=False, reason="fault log not deterministic")
     if streams2 != streams:
@@ -301,20 +302,20 @@ def main(argv=None):
                 if not result["ok"]:
                     failures += 1
     # one decode-faulted cell per seed with the Pallas paged decode
-    # kernel gate on (interpret mode on CPU): retry/restart replay
-    # must stay bit-exact through the kernel path too, against a
-    # kernel-enabled unfaulted reference
+    # kernel as the engine's choice (forced interpret makes
+    # kernel_viable say yes on the CPU): retry/restart replay must stay
+    # bit-exact through the kernel path too, against an unfaulted
+    # reference on the same path
     from paddle_tpu.ops import paged_attention as paged_attn_mod
     paged_attn_mod._FORCE_INTERPRET[0] = True
     try:
-        reference, _, _, _ = _drain(model, specs, True, chunk=chunk,
-                                    paged_attn=True)
+        reference, eng, _, _ = _drain(model, specs, True, chunk=chunk)
         assert reference is not None, "pallas reference drain hung"
+        assert eng.decode_layout == "paged_pallas", eng.decode_layout
         for seed in seeds:
             cells += 1
             result = _patrolled(_check_cell, "decode_dispatch", seed,
-                                model, specs, reference, True, chunk,
-                                paged_attn=True)
+                                model, specs, reference, True, chunk)
             print(json.dumps(result), flush=True)
             if not result["ok"]:
                 failures += 1

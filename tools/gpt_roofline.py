@@ -16,7 +16,7 @@ bytes per token as a function of batch, context length, heads and
 layout (contiguous / paged_xla / paged_pallas), the parameter re-read
 every step pays, and the resulting per-step floor — printed for all
 THREE layouts so the XLA gather-materialization tax, and what the
-Pallas paged-attention kernel (PADDLE_PAGED_ATTN) buys back by
+Pallas paged-attention kernel (ops.paged_attention) buys back by
 deleting it, are numbers, not vibes.
 
 Usage: python tools/gpt_roofline.py [batch seq]           (train step)

@@ -14,8 +14,8 @@ Builds the three kinds of compiled programs this framework ships —
     would be noise, and the donation pass's size floor keeps it
     silent);
   * ``paged_decode_pallas`` — the paged engine again with the Pallas
-    paged decode-attention kernel enabled (``paged_attn=True``,
-    interpret mode forced so the kernel traces on this CPU lint run):
+    paged decode-attention kernel as its decode attention (interpret
+    mode forced, so ``kernel_viable`` chooses it on this CPU lint run):
     the decode jaxpr now embeds the ``pallas_call`` and the f64-upcast
     + donation passes must stay clean across its boundary (the kernel
     traces in 32-bit mode — pallas_compat — so an f64 leak here is a
@@ -125,7 +125,7 @@ def lint_paged_decode_pallas():
     paged_attn._FORCE_INTERPRET[0] = True
     try:
         engine = ServingEngine(model, num_slots=4, paged=True,
-                               block_size=8, paged_attn=True)
+                               block_size=8)
         rs = np.random.RandomState(0)
         for n in (5, 9):
             engine.add_request(rs.randint(0, 97, (n,)).astype(np.int64),
