@@ -711,7 +711,6 @@ DEFAULT_AUDIT_SOURCES = (
 
 DEFAULT_SNAPSHOT_SOURCES = (
     "serving/engine.py",
-    "serving/kv_pool.py",
     "serving/paged/pool.py",
     "serving/sched/sampling.py",
     "serving/kv_wire.py",

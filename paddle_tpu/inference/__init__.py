@@ -179,10 +179,10 @@ def create_serving_engine(model, **kwargs):
     analogue of create_predictor for autoregressive decode. Takes a
     live GPTForCausalLM (weights snapshotted now) and the
     paddle_tpu.serving knobs (num_slots, max_len, buckets, bucket_min,
-    prefill_group_sizes, async_depth, donate_buffers, eos_id); returns
+    block_size, async_depth, donate_buffers, eos_id); returns
     a paddle_tpu.serving.ServingEngine whose add_request/step/run loop
-    serves concurrent generations from a slot-pooled donated KV cache
-    with grouped bucketed prefill, one-step-deep async decode
+    serves concurrent generations from a paged, donated KV cache
+    with prefix-aware bucketed prefill, one-step-deep async decode
     pipelining and zero steady-state recompiles."""
     from ..serving import ServingEngine
     return ServingEngine(model, **kwargs)

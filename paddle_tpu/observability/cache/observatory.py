@@ -41,12 +41,10 @@ CACHE_KEYS = (
 # answer ROADMAP-#5's "what would a host-RAM spill tier buy"
 MRC_CAPACITY_FACTORS = (0.5, 1.0, 2.0, 4.0)
 
-_PREFILL_KINDS = ("prefill", "paged_prefill", "chunk_prefill")
-
 
 def disabled_cache_report():
     """The ``snapshot()["cache"]`` section of an engine without a
-    cache observatory (cache=False, or a legacy non-paged pool) —
+    cache observatory (cache=False) —
     same key set as a live report, so the snapshot schema contract
     holds either way."""
     return {"enabled": False, "accesses": 0, "hits": 0,

@@ -192,8 +192,12 @@ class StepTimeSpike(Detector):
 class QueueStall(Detector):
     """Queued work with zero progress for ``stall_steps`` consecutive
     steps. Progress = any admission, emitted token, prefill chunk, or
-    completion; a full-but-decoding engine is NOT stalled. Fires once
-    per stall episode (re-arms on the next progress)."""
+    completion; a full-but-decoding engine is NOT stalled. Neither is
+    a queue behind parked exports: the slots are held for a handoff
+    whose next move (``export_kv``) is the router's, and an idle
+    engine steps thousands of times while one HTTP round trip is in
+    flight — such steps are not counted. Fires once per stall episode
+    (re-arms on the next progress)."""
 
     def __init__(self, stall_steps=32):
         self.stall_steps = int(stall_steps)
@@ -204,6 +208,8 @@ class QueueStall(Detector):
         progress = (row["admitted"] or row["tokens"]
                     or row["prefill_chunks"] or row["completed"])
         if row["queue_depth"] > 0 and not progress:
+            if row.get("held_exports"):
+                return None
             self._streak += 1
             if self._streak >= self.stall_steps and not self._fired:
                 self._fired = True

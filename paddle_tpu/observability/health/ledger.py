@@ -27,6 +27,7 @@ LEDGER_ROW_KEYS = (
     "queue_depth",        # queued requests after the step
     "queue_age_s",        # how long the queue head has waited
     "occupied_slots",     # live slots after the step
+    "held_exports",       # of those, parked for export_kv (handoff)
     "chunked_inflight",   # chunk plans still mid-prefill
     "admitted",           # requests admitted this step
     "tokens",             # tokens emitted this step
@@ -40,7 +41,7 @@ LEDGER_ROW_KEYS = (
     "steady_compiles",    # of those, after declared warmup
     "slo_on",             # SLO targets configured (bool)
     "prefix_hit_rate",    # cumulative prefix-cache hit rate (None=n/a)
-    "pool_free_blocks",   # paged pool economy (None on legacy pool)
+    "pool_free_blocks",   # paged pool economy
     "pool_evictable_blocks",
     "pool_live_blocks",
     "conservation_ok",    # periodic audit verdict (None = not audited)

@@ -7,7 +7,7 @@ the wall go to, and how close to the hardware floor does it run") and
 across runs ("is that faster or slower than last time"):
 
   * **attribution.ProgramPerf** — every AOT executable dispatch
-    (prefill buckets, chunk program, pooled decode, per pool flavor)
+    (prefill buckets, chunks among them, and the pooled decode)
     records measured dispatch/sync wall seconds against its AOT-table
     key into registry histograms; ``snapshot()["perf"]`` and
     ``/debug/perf`` decompose a step into named programs;
@@ -17,7 +17,7 @@ across runs ("is that faster or slower than last time"):
     yields the ``serving_roofline_fraction{program}`` gauge — the
     go/no-go yardstick for ROADMAP direction #2's Pallas kernel;
   * **ledger** — the schema-versioned cross-run JSONL perf ledger
-    (``bench_artifacts/perf_ledger.jsonl``) and the robust
+    and the robust
     median+MAD comparison ``tools/perf_diff.py`` gates CI with.
 
 roofline.py and ledger.py are deliberately stdlib-only so the CLI

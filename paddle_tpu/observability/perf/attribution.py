@@ -4,13 +4,13 @@ roofline.
 
 The engine's span counters answer "how long did serving/step take";
 this module answers the next question — WHICH program: every AOT
-dispatch (bucketed/grouped prefill, the per-flavor chunk program, the
+dispatch (bucketed tail prefill, chunks included, and the
 pooled decode) and every harvest sync records its measured wall
 seconds against its AOT-table key, accumulated into per-program
 registry histograms::
 
     serving_program_dispatch_seconds{program="decode"}
-    serving_program_sync_seconds{program="prefill/b16/g4"}
+    serving_program_sync_seconds{program="paged_prefill/b16"}
     serving_roofline_fraction{program="decode"}
 
 The roofline fraction joins three facts the stack already collects:
@@ -25,8 +25,7 @@ ROADMAP direction #2 judges the Pallas paged-attention kernel by.
 ``report()`` is the ``snapshot()["perf"]`` / ``/debug/perf`` body;
 its key set is pinned by tests/test_observability.py. Hot-path cost
 is two perf_counter reads plus one histogram observe per dispatch and
-per sync (~1-2us/step) — probe-measured in the bench artifact's
-``perf.overhead`` section, same discipline as the PR-8 health tick.
+per sync, same discipline as the PR-8 health tick.
 """
 import threading
 
@@ -60,18 +59,13 @@ PERF_PROGRAM_KEYS = (
 
 def format_program_key(key):
     """Stable human-readable label for an engine AOT-table key:
-    ("decode",) -> "decode", ("prefill", 16, 4) -> "prefill/b16/g4",
-    ("paged_prefill", 32) -> "paged_prefill/b32",
-    ("chunk_prefill", 8) -> "chunk_prefill/c8"."""
+    ("decode",) -> "decode", ("paged_prefill", 32) ->
+    "paged_prefill/b32"."""
     if isinstance(key, str):
         return key
     kind, rest = key[0], key[1:]
-    if kind == "prefill" and len(rest) == 2:
-        return f"prefill/b{rest[0]}/g{rest[1]}"
     if kind == "paged_prefill" and len(rest) == 1:
         return f"paged_prefill/b{rest[0]}"
-    if kind == "chunk_prefill" and len(rest) == 1:
-        return f"chunk_prefill/c{rest[0]}"
     return "/".join(str(p) for p in key)
 
 
@@ -202,7 +196,7 @@ class ProgramPerf:
 
     def prefill_seconds(self):
         """Measured wall seconds accrued by the prefill-family
-        programs (bucketed/grouped, paged, chunked) — dispatch + sync.
+        programs (every bucket, chunks included) — dispatch + sync.
         The cache observatory divides this by prefill-computed tokens
         for its per-token savings attribution."""
         if not self.enabled:
@@ -212,7 +206,7 @@ class ProgramPerf:
         total = 0.0
         for key, prog in items:
             kind = key if isinstance(key, str) else key[0]
-            if kind in ("prefill", "paged_prefill", "chunk_prefill"):
+            if kind == "paged_prefill":
                 total += prog.h_dispatch.sum + prog.h_sync.sum
         return total
 
@@ -326,15 +320,14 @@ class ProgramPerf:
 
 
 def build_decode_model(batch, kv_len, num_layers, num_heads, head_dim,
-                       n_params, param_bytes, kv_bytes, paged,
-                       peak_flops, hbm_bps, layout=None):
+                       n_params, param_bytes, kv_bytes, layout,
+                       peak_flops, hbm_bps):
     """Thin convenience wrapper the engine uses (keeps its import
     surface to this package). ``layout`` names the attention path the
-    engine actually resolved ("contiguous" | "paged_xla" |
-    "paged_pallas") so serving_roofline_fraction prices the path that
-    is running; the bool ``paged`` alone means the XLA gather."""
+    engine actually resolved ("paged_xla" | "paged_pallas") so
+    serving_roofline_fraction prices the path that is running."""
     return decode_step_model(
         batch=batch, kv_len=kv_len, num_layers=num_layers,
         num_heads=num_heads, head_dim=head_dim, n_params=n_params,
-        param_bytes=param_bytes, kv_bytes=kv_bytes, paged=paged,
+        param_bytes=param_bytes, kv_bytes=kv_bytes,
         layout=layout, peak_flops=peak_flops, hbm_bps=hbm_bps)

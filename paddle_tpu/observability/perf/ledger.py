@@ -2,9 +2,8 @@
 (scenario, metric) per bench run, plus the robust comparison logic
 ``tools/perf_diff.py`` gates CI with.
 
-``bench_artifacts/`` holds a dozen serving artifacts no tool compares;
-this ledger is the durable, append-only record that makes performance
-a TRAJECTORY: every ``bench_serving.py`` run appends rows like::
+The ledger is the durable, append-only record that makes performance
+a TRAJECTORY: a bench run appends rows like::
 
     {"schema": "paddle_tpu.perf_ledger/v1", "timestamp": "...",
      "run_id": "serving_smoke_...json", "source": "live-smoke",
@@ -154,8 +153,7 @@ def compact(path, keep_last):
     ``keep_last`` rows per (scenario, metric, config_digest) series,
     preserving append order. The ledger grows one row per (scenario,
     metric) per bench run forever — compaction is the retention knob
-    (``bench_serving.py --ledger-keep N`` / $BENCH_LEDGER_KEEP,
-    default off). The rewrite is atomic (temp file + replace), so a
+    (default off). The rewrite is atomic (temp file + replace), so a
     crash mid-compaction never corrupts the ledger; junk lines and
     foreign schemas are dropped (they were already invisible to
     ``compare()``). Returns ``(kept, dropped)`` row counts."""

@@ -9,37 +9,30 @@ perf attribution (snapshot()["perf"], /debug/perf), the roofline CLI
 memory-bound long before it is FLOP-bound: every step re-reads the
 whole parameter set plus the K/V cache, so the HBM traffic term —
 KV-read bytes per token as a function of batch, sequence length,
-heads, and paged-vs-contiguous layout — is the yardstick any paged
-attention kernel gets judged by.
+heads, and the attention path over the paged pool — is the yardstick
+any paged attention kernel gets judged by.
 
 Deliberately dependency-free (stdlib only): tools/perf_diff.py and
 tools/gpt_roofline.py load this file directly via importlib without
 importing the paddle_tpu package (no jax at tool startup), and the
 engine imports it through paddle_tpu.observability.perf.
 
-Layout model (why paged costs more under plain XLA):
+Layout model (why the gather costs more under plain XLA):
 
-  * **contiguous** (SlotKVPool): attention reads the pooled
-    ``[slots, heads, cache_len, head_dim]`` K/V directly — one read of
-    the full fixed-shape cache per step (the max_len over-read is the
-    price of the zero-recompile fixed shape);
   * **paged_xla** (PagedKVPool behind a block table, composed in
-    XLA): the gather MATERIALIZES a contiguous copy before attention
-    reads it — pool read + copy write + attention read, ~3x the
-    contiguous traffic. That factor is exactly what the Pallas kernel
-    deletes by reading blocks in place; it is what the engine builds
-    only where ``ops.paged_attention.kernel_viable`` refuses the
-    kernel (the CPU, shapes that do not tile);
+    XLA): the gather MATERIALIZES a contiguous copy of every slot's
+    full capacity before attention reads it — pool read + copy write
+    + attention read, ~3x one direct read. That factor is exactly
+    what the Pallas kernel deletes by reading blocks in place; it is
+    what the engine builds only where
+    ``ops.paged_attention.kernel_viable`` refuses the kernel (the
+    CPU, shapes that do not tile);
   * **paged_pallas** (ops.paged_attention: the engine's choice
     wherever ``kernel_viable`` says yes): the Pallas kernel copies
     blocks into VMEM straight from the pool — gather factor 1.0, and
     no max-len over-read: it walks each slot's LIVE blocks only, so
     the read length is the live ``kv_len`` (callers may pass
     ``live_kv_len``), not the fixed cache capacity.
-
-The boolean ``paged=`` argument is kept for callers predating the
-three-way split (``paged=True`` means ``layout="paged_xla"``);
-``layout=`` wins when both are given.
 """
 import os
 
@@ -57,23 +50,21 @@ _HBM_BPS_BY_KIND = (
 
 # XLA-composed paged attention: gather reads the pool, writes a
 # contiguous copy, attention reads the copy back (vs one direct read
-# on the contiguous layout)
+# by the in-place kernel)
 PAGED_GATHER_FACTOR = 3.0
 
 # the decode K/V layouts the model prices; per-layout gather
 # materialization factor on the KV-read term
-LAYOUTS = ("contiguous", "paged_xla", "paged_pallas")
+LAYOUTS = ("paged_xla", "paged_pallas")
 _GATHER_FACTORS = {
-    "contiguous": 1.0,
     "paged_xla": PAGED_GATHER_FACTOR,
     "paged_pallas": 1.0,
 }
 
 
-def resolve_layout(paged=False, layout=None):
-    """Back-compat shim: the pre-kernel API was ``paged: bool``."""
-    if layout is None:
-        return "paged_xla" if paged else "contiguous"
+def resolve_layout(layout):
+    """``layout`` if the model prices it, else a ValueError naming
+    the ones it does."""
     if layout not in _GATHER_FACTORS:
         raise ValueError(f"unknown KV layout {layout!r}; "
                          f"expected one of {LAYOUTS}")
@@ -127,18 +118,18 @@ def roofline_floor(flops, bytes_accessed, peak_flops, hbm_bps):
 
 
 def kv_read_bytes_per_token(kv_len, num_layers, num_heads, head_dim,
-                            kv_bytes=2, paged=False, layout=None):
+                            kv_bytes=2, layout="paged_xla"):
     """HBM bytes attention reads to serve ONE decode token: K and V
     across every layer over ``kv_len`` positions, times the gather
     materialization factor on the XLA-composed paged layout (the
     Pallas in-place layout pays factor 1.0)."""
     base = 2.0 * num_layers * num_heads * head_dim * kv_len * kv_bytes
-    return base * _GATHER_FACTORS[resolve_layout(paged, layout)]
+    return base * _GATHER_FACTORS[resolve_layout(layout)]
 
 
 def decode_step_model(batch, kv_len, num_layers, num_heads, head_dim,
-                      n_params, param_bytes=2, kv_bytes=2, paged=False,
-                      layout=None, live_kv_len=None,
+                      n_params, param_bytes=2, kv_bytes=2,
+                      layout="paged_xla", live_kv_len=None,
                       peak_flops=None, hbm_bps=None):
     """Analytic cost of ONE pooled decode dispatch (``batch`` slots,
     one token each, attending over ``kv_len`` cached positions — the
@@ -147,15 +138,15 @@ def decode_step_model(batch, kv_len, num_layers, num_heads, head_dim,
 
     On the ``paged_pallas`` layout the kernel stops reading at each
     slot's live length, so the KV-read term uses ``live_kv_len`` when
-    given (the other layouts always read the fixed ``kv_len`` — the
-    over-read is part of their price).
+    given (the gather always reads the fixed ``kv_len`` — the
+    over-read is part of its price).
 
     Returns a JSON-safe dict: the traffic decomposition (KV read per
     token and total, KV append write, parameter read), matmul +
     attention FLOPs, arithmetic intensity, and — when peak_flops /
     hbm_bps are given — the roofline floor and its binding resource.
     """
-    layout = resolve_layout(paged, layout)
+    layout = resolve_layout(layout)
     hidden = num_heads * head_dim
     kv_len_read = kv_len
     if layout == "paged_pallas" and live_kv_len is not None:
@@ -181,9 +172,7 @@ def decode_step_model(batch, kv_len, num_layers, num_heads, head_dim,
         "num_heads": int(num_heads),
         "head_dim": int(head_dim),
         "n_params": int(n_params),
-        # "paged" keeps the pre-kernel bool meaning (is the POOL
-        # paged); "layout" names the attention path actually priced
-        "paged": layout != "contiguous",
+        # the attention path priced
         "layout": layout,
         "gather_factor": _GATHER_FACTORS[layout],
         "kv_len_read": int(kv_len_read),

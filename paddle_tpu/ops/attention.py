@@ -411,9 +411,11 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 
 
 def cached_slot_attention(q, k_cache, v_cache, lengths):
-    """Single-token decode attention over a slot-pooled static cache
-    with per-slot cache-length masking (the serving decode step,
-    text.models.GPTForCausalLM.build_serving_fns).
+    """Single-token decode attention over a slot-contiguous static
+    cache with per-slot cache-length masking: what
+    cached_paged_attention runs over its gathered view, and the
+    reference the paged gather and the Pallas kernel are tested
+    against.
 
     q [S, nh, hd] — one new-token query per slot;
     k_cache/v_cache [S, nh, C, hd] — each slot's full static cache;
@@ -478,9 +480,10 @@ def cached_paged_attention(q, k_cache, v_cache, block_tables, lengths):
 
 
 def cached_slot_block_attention(q, k_cache, v_cache, qpos):
-    """Multi-query decode attention over a slot-pooled static cache:
-    the t-token generalization of cached_slot_attention, used by the
-    speculative k-token verify program (serving.spec.programs) where
+    """Multi-query decode attention over a slot-contiguous static
+    cache: the t-token generalization of cached_slot_attention, what
+    cached_paged_block_attention runs over its gathered view for the
+    speculative k-token verify program (serving.spec.programs), where
     every slot scores t = k+1 candidate positions in one dispatch.
 
     q [S, nh, t, hd] — t new-token queries per slot (the slot's last

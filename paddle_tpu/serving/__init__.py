@@ -6,19 +6,21 @@ the slowest, and every new (batch, prompt_len, new_tokens) signature
 compiles a fresh XLA executable. This package turns the same decode
 math into a multi-tenant server:
 
-  * **slot-pooled static-shape KV cache** (kv_pool.SlotKVPool) — one
-    ``[layers, num_slots, heads, max_len, head_dim]`` pair; finished
-    sequences free their slot and waiting requests claim it mid-flight,
-    so the jitted decode step keeps ONE shape forever. The pooled
-    kc/vc (and the position vector) are DONATED into every serving
-    executable, so on TPU/GPU the cache updates in place instead of
-    double-buffering ~2x its footprint per call;
-  * **grouped bucketed prefill** — prompts pad to a small geometric
-    bucket set and same-bucket admissions batch into geometric group
-    sizes (1, 2, 4, ... capped at num_slots), so a deep queue prefills
-    in one ``[G, bucket]`` dispatch per group and prompt-length AND
-    queue-depth variety costs at most
-    ``len(buckets) * len(group_sizes)`` prefill compiles;
+  * **paged static-shape KV cache** (paged.PagedKVPool, the
+    engine's only cache) — one ``[layers, num_blocks, heads,
+    block_size, head_dim]`` pair behind a fixed-shape block table;
+    finished sequences free their slot and blocks and waiting
+    requests claim them mid-flight, so the jitted decode step keeps
+    ONE shape forever, and a radix index over frozen prompt blocks
+    turns a shared prompt prefix into a cache hit (see
+    ``serving.paged``). The pooled kc/vc (and the position vector)
+    are DONATED into every serving executable, so on TPU/GPU the
+    cache updates in place instead of double-buffering ~2x its
+    footprint per call;
+  * **bucketed tail prefill** — each admission prefills only the
+    uncached tail of its prompt, padded to a small geometric bucket
+    set, so prompt-length AND queue-depth variety costs at most
+    ``len(buckets)`` prefill compiles;
   * **one-step-deep async decode pipeline** — step N's tokens are read
     back only after step N+1's decode is dispatched (token/position
     state chains device-side), so host bookkeeping overlaps device
@@ -26,7 +28,7 @@ math into a multi-tenant server:
     masked at harvest, keeping exact greedy generate() parity
     (``async_depth=0`` restores the synchronous schedule);
   * **step scheduler** (scheduler.StepScheduler) — FIFO queue,
-    same-bucket group admission on free slots, per-slot EOS/max-token
+    prefix-aware admission on free slots, per-slot EOS/max-token
     stops, streaming token callbacks;
   * **metrics** (metrics.ServingMetrics) — a thin facade over a
     paddle_tpu.observability MetricsRegistry: tokens/sec, TTFT /
@@ -104,8 +106,9 @@ math into a multi-tenant server:
   * **scheduling subsystem** (serving.sched, PR 7 — all default-off):
     chunked prefill (``prefill_chunk=`` — long prompts prefill in
     fixed-width chunks co-scheduled with decode steps under a
-    per-step token budget; ONE compiled chunk program per pool
-    flavor, exact parity with whole-prompt prefill), SLO-feedback
+    per-step token budget; a chunk is a tail prefill at the
+    chunk-width bucket, exact parity with whole-prompt prefill),
+    SLO-feedback
     admission (``policy="slo_feedback"`` — sheds/defers queued
     requests whose TTFT SLO is already lost against live delivered
     latency; counted, SLO-judged, flight-evented), and per-slot
@@ -166,8 +169,8 @@ math into a multi-tenant server:
     an n-gram/prompt-lookup drafter over each slot's own context (no
     second model; bounded, incremental, radix-aware: shared prompts
     share draft statistics) proposes up to ``spec_k`` tokens per
-    slot, and ONE extra AOT program flavor per pool
-    (``spec_verify`` / ``paged_spec_verify``) verifies all k+1
+    slot, and ONE extra AOT program
+    (``paged_spec_verify``) verifies all k+1
     positions in a single fixed-shape dispatch — amortizing the
     HBM-bound parameter + KV read plain decode pays per token.
     Greedy streams stay bit-exact with ``generate()`` by construction
@@ -190,7 +193,7 @@ math into a multi-tenant server:
     blocks until the payload is handed off), ``import_kv(payload)``
     validates everything up front (corruption raises ``KVWireError``
     before the pool is touched) and binds the blocks via
-    ``SlotKVPool.rebind`` + block-table splice, resuming at the first
+    ``PagedKVPool.rebind`` + block-table splice, resuming at the first
     decode step with no prefill recompute;
     ``warmup_kv_handoff()`` pre-builds the import path so BOTH tiers
     keep the zero-compile steady state. Role is routing posture, not
@@ -203,8 +206,9 @@ math into a multi-tenant server:
   * zero-recompile steady state BY CONSTRUCTION — and ATTRIBUTED
     (engine.ServingEngine): all device work runs ahead-of-time
     compiled executables, the whole-lifetime compiled-program
-    inventory is bounded by ``len(buckets) * len(group_sizes) + 1``,
-    and every build is logged in a compile watchdog
+    inventory is bounded by ``len(buckets) + 1`` (+ the chunk-width
+    bucket when chunked, + verify when speculative, + the two wire
+    programs when warmed), and every build is logged in a compile watchdog
     (``engine.watchdog``) with its abstract-shape signature and
     dispatch call-site. After ``engine.declare_warmup()`` any further
     compile is flagged in ``watchdog.report()`` — or raised
@@ -216,7 +220,7 @@ Tuning knobs
 ------------
 ``num_slots``   decode batch width and cache pool size. Throughput
                 rises with concurrency until the pooled cache
-                (``SlotKVPool.nbytes()``) or the decode step's matmul
+                (``PagedKVPool.nbytes()``) or the decode step's matmul
                 width saturates the chip; 8-32 is a sensible range.
 ``max_len``     per-slot capacity (prompt + generated), default the
                 model's max_seq_len. The cache is num_slots*max_len
@@ -228,10 +232,10 @@ Tuning knobs
                 pad waste per prefill but more compiles; the doubling
                 set bounds pad waste at <2x and compiles at
                 O(log(max_len/bucket_min)).
-``prefill_group_sizes``
-                admission group sizes for grouped prefill, default
-                geometric ``[1, 2, 4, ..., <= num_slots]``. ``(1,)``
-                restores one-prefill-per-request.
+``block_size`` / ``num_blocks``
+                paging granularity (prefix sharing happens at block
+                multiples; default 16) and the physical pool size
+                (default: every slot fully backed + the trash block).
 ``async_depth`` 1 (default) = one-step-deep decode pipelining; 0 =
                 fully synchronous per-step host reads (can win on
                 churn-heavy tiny-model CPU workloads where every step
@@ -292,9 +296,9 @@ Tuning knobs
                 failed prefill/chunk/decode dispatches (and harvest
                 transfers) absorbed per request/step before the
                 request retires ``"error"`` (0 = default = the raise-
-                through prior behavior). Rollback is leak-free on
-                both pools; decode failures past the budget escalate
-                to the supervisor.
+                through prior behavior). Rollback is leak-free;
+                decode failures past the budget escalate to the
+                supervisor.
 ``retry_backoff_s``
                 base of the exponential admission backoff after an
                 absorbed dispatch failure (0 = retry next step).
@@ -350,13 +354,13 @@ Tuning knobs
 ``eos_id``      default stop token (per-request override on
                 add_request).
 
-Quick start: ``bench_serving.py --smoke``; correctness + throughput
-contracts live in tests/test_serving.py.
+Correctness contracts live in tests/test_serving.py and
+tests/test_paged_serving.py; the measured cells are
+``benchmarks/`` (``BENCHMARK.json``).
 """
 from .engine import (  # noqa: F401
-    ServingConfig, ServingEngine, default_buckets, default_group_sizes,
+    ServingConfig, ServingEngine, default_buckets,
 )
-from .kv_pool import SlotKVPool  # noqa: F401
 from .metrics import ServingMetrics  # noqa: F401
 from .paged import PagedKVPool, RadixPrefixIndex  # noqa: F401
 from .resilience import (  # noqa: F401

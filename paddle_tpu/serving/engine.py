@@ -1,8 +1,8 @@
 """Continuous-batching inference engine.
 
 One engine step = (dispatch of ONE pooled decode step) + (harvest of
-the PREVIOUS step's dispatched results) + (admission + grouped
-bucketed prefill of newly admitted requests). All device work goes
+the PREVIOUS step's dispatched results) + (admission + bucketed
+tail prefill of newly admitted requests). All device work goes
 through ahead-of-time compiled executables
 (jax.jit(...).lower(...).compile()), so steady state is zero-recompile
 BY CONSTRUCTION: an executable either exists in the table (cache hit,
@@ -10,12 +10,9 @@ no jit dispatch at all) or is built exactly once and counted in
 ``metrics.compiles`` — a shape drifting from its compiled signature is
 a hard error at the call, never a silent recompile.
 
-Three hot-path properties keep the device saturated between scheduler
+Two hot-path properties keep the device saturated between scheduler
 ticks:
 
-  * **grouped prefill** — same-bucket admissions prefill in one
-    ``[G, bucket]`` dispatch, G drawn from a small geometric group-size
-    set, so a deep queue costs one dispatch per group, not per request;
   * **donated KV buffers** — prefill/decode executables are built with
     the pooled kc/vc (and the position vector) donated, so on donating
     backends (TPU/GPU) the cache updates in place instead of
@@ -41,14 +38,15 @@ ticks:
 
 Compiled program inventory for a whole serving lifetime:
   * one decode step at the fixed pooled-cache shape,
-  * at most ``len(buckets) * len(group_sizes)`` prefill programs
-    (prompts pad up to a small geometric bucket set, admission groups
-    up to a small geometric size set), and
-  * with chunked prefill enabled (``prefill_chunk=``), ONE chunk
-    program per pool flavor (traced start/len/slot/final scalars —
-    the paged pool's chunks reuse its tail-prefill program outright),
-so prompt-length AND queue-depth variety is O(buckets x group_sizes)
-compiles — the generate() LRU problem this engine exists to delete.
+  * at most ``len(buckets)`` prefill programs (prompt tails pad up to
+    a small geometric bucket set; with chunked prefill enabled,
+    ``prefill_chunk=``, a chunk IS a tail prefill at the chunk-width
+    bucket, so chunking adds at most that one bucket),
+  * one verify program when speculative, and
+  * the two KV wire programs (export/import) once
+    ``warmup_kv_handoff`` has warmed them,
+so prompt-length AND queue-depth variety is O(buckets) compiles — the
+generate() LRU problem this engine exists to delete.
 
 Scheduling (serving.sched, all default-off): long prompts can prefill
 in fixed-width chunks interleaved with decode steps under a per-step
@@ -69,9 +67,8 @@ from ..analysis import threads as _lockpatrol
 from ..observability import (CompileWatchdog, FlightRecorder,
                              abstract_signature, device_memory_stats,
                              executable_cost, executable_memory)
-from .kv_pool import SlotKVPool
 from .metrics import ServingMetrics
-from .paged.pool import TRASH_BLOCK
+from .paged.pool import TRASH_BLOCK, PagedKVPool
 from .scheduler import QUEUED, RUNNING, Request, StepScheduler
 
 # published per-chip peak FLOP/s (bf16) by PJRT device_kind prefix —
@@ -117,6 +114,27 @@ warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable")
 
 
+def _kv_export_fn(kc, vc, idx):
+    """The ``("kv_export",)`` program: one slot's blocks as tiles."""
+    return kc[:, idx], vc[:, idx]
+
+
+def _kv_import_fn(kc, vc, idx, ktiles, vtiles, toks, pos, slot,
+                  first_tok, plen):
+    """The ``("kv_import",)`` program: scatter received tiles into
+    freshly bound blocks and splice the slot's token/position lanes."""
+    # unused idx lanes point at the trash block — the scatter
+    # scribbles garbage no reader sees, exactly the released-slot
+    # stale-write discipline
+    kc = kc.at[:, idx].set(ktiles)
+    vc = vc.at[:, idx].set(vtiles)
+    # toks/pos are RETURNED, not donated: a pending decode harvest
+    # still reads the pre-import token array
+    toks = toks.at[slot].set(first_tok)
+    pos = pos.at[slot].set(plen)
+    return toks, pos, kc, vc
+
+
 def default_buckets(cache_len, bucket_min=32):
     """Geometric prefill bucket set: bucket_min, 2x, 4x, ... capped at
     cache_len (the per-slot capacity) which is always included so any
@@ -132,34 +150,17 @@ def default_buckets(cache_len, bucket_min=32):
     return buckets
 
 
-def default_group_sizes(num_slots):
-    """Geometric prefill group-size set: 1, 2, 4, ... capped at
-    num_slots. Any admission burst splits into groups from this set
-    (largest first), so deep-queue admission costs O(log burst)
-    dispatches while the compile inventory stays
-    O(len(buckets) * len(group_sizes))."""
-    if num_slots < 1:
-        raise ValueError(f"num_slots must be >= 1, got {num_slots}")
-    sizes = []
-    g = 1
-    while g <= num_slots:
-        sizes.append(g)
-        g *= 2
-    return sizes
-
-
 class ServingConfig:
     """Knobs (see package docstring): num_slots sizes the decode batch
     and the pooled cache; max_len is the per-slot capacity (default:
     the model's max_seq_len); buckets/bucket_min shape the prefill
-    compile set; prefill_group_sizes the admission-group compile set
-    (default: geometric up to num_slots); async_depth selects the
+    compile set; async_depth selects the
     decode pipeline depth (1 = read step N's tokens after dispatching
     step N+1, 0 = synchronous); eos_id is the default stop token."""
 
     def __init__(self, num_slots=8, max_len=None, buckets=None,
-                 bucket_min=32, eos_id=None, prefill_group_sizes=None,
-                 async_depth=1, donate_buffers=None,
+                 bucket_min=32, eos_id=None, async_depth=1,
+                 donate_buffers=None,
                  watchdog_mode="flag", slo_ttft_ms=None,
                  slo_tpot_ms=None, slo_window_s=60.0,
                  completed_keep=4096, trace_keep=256,
@@ -184,7 +185,6 @@ class ServingConfig:
         self.buckets = buckets
         self.bucket_min = int(bucket_min)
         self.eos_id = eos_id
-        self.prefill_group_sizes = prefill_group_sizes
         self.async_depth = int(async_depth)
         if self.async_depth not in (0, 1):
             raise ValueError(
@@ -218,17 +218,19 @@ class ServingConfig:
         # device peak FLOP/s override for the estimated-MFU gauge
         # (default: a device_kind table, then $PADDLE_TPU_PEAK_FLOPS)
         self.peak_flops = peak_flops
-        # paged KV pool + radix prefix cache (serving.paged): None =
-        # the PADDLE_PAGED_KV env gate (default off — the legacy
-        # slot-contiguous pool stays the measured fallback, mirroring
-        # the PADDLE_FUSED_CE gating pattern); True/False forces.
+        # the paged KV pool + radix prefix cache (serving.paged) is the
+        # engine's only cache. `paged` is still accepted because the
+        # benchmark's configuration files pass `"paged": true` (ROADMAP
+        # D2b): None and True mean the same thing and nothing reads it.
+        if paged is not None and not paged:
+            raise ValueError(
+                "paged=False: the slot-contiguous KV pool was removed "
+                "(PR 30); the paged pool is the engine's only cache. "
+                "Drop the argument.")
         # block_size is the paging granularity (prefix sharing happens
         # at block multiples); num_blocks sizes the physical pool
-        # (default: every slot fully backed + the trash block, the
-        # legacy footprint — sharing stretches the same bytes further).
-        if paged is None:
-            paged = os.environ.get("PADDLE_PAGED_KV", "0") == "1"
-        self.paged = bool(paged)
+        # (default: every slot fully backed + the trash block —
+        # sharing stretches the same bytes further).
         self.block_size = int(block_size)
         self.num_blocks = num_blocks
         # chunked prefill (serving.sched): prompts longer than
@@ -237,7 +239,7 @@ class ServingConfig:
         # step (default: one chunk per step), so a long prompt never
         # monopolizes the step loop. None = off (whole-prompt prefill,
         # prior behavior); the PADDLE_PREFILL_CHUNK env var sets a
-        # default width, mirroring the PADDLE_PAGED_KV gating pattern.
+        # default width.
         if prefill_chunk is None:
             env = os.environ.get("PADDLE_PREFILL_CHUNK")
             if env:
@@ -324,18 +326,15 @@ class ServingConfig:
         # performance observatory (observability.perf): per-program
         # dispatch/sync attribution + roofline fractions, ON by
         # default (two perf_counter reads and one histogram observe
-        # per dispatch — probe-measured in the bench artifact);
-        # PADDLE_PERF=0 opts out, True/False forces.
+        # per dispatch); PADDLE_PERF=0 opts out, True/False forces.
         if perf is None:
             perf = os.environ.get("PADDLE_PERF", "1") != "0"
         self.perf = bool(perf)
         # cache observatory (observability.cache): reuse-distance/MRC
         # sampling, prefix heat, savings attribution and churn
         # telemetry over the paged pool, ON by default (a few dict/int
-        # ops per admission, probe-measured in the bench artifact's
-        # shared_prefix.cache.overhead section); PADDLE_CACHE_OBS=0
-        # opts out, True/False forces. Engines without a paged pool
-        # report the disabled shape regardless.
+        # ops per admission); PADDLE_CACHE_OBS=0 opts out, True/False
+        # forces.
         if cache_observatory is None:
             cache_observatory = os.environ.get(
                 "PADDLE_CACHE_OBS", "1") != "0"
@@ -351,9 +350,8 @@ class ServingConfig:
         self.replica_id = replica_id
         # self-drafting speculative decoding (serving.spec): None =
         # the PADDLE_SPEC_DECODE env gate (default off — plain
-        # one-token decode stays the measured fallback, same playbook
-        # as PADDLE_PAGED_KV). spec_k is the draft width: the verify
-        # program runs [slots, spec_k + 1] positions per dispatch and
+        # one-token decode stays the measured fallback). spec_k is
+        # the draft width: the verify program runs [slots, spec_k + 1] positions per dispatch and
         # emits 1..spec_k+1 tokens. spec_min_accept is the per-request
         # EWMA acceptance floor below which a request falls back to
         # plain decode (its slot stops drafting). Greedy-only: the
@@ -383,9 +381,7 @@ class ServingConfig:
         # "decode" replicas import streamed KV and own the decode
         # span. The role is ROUTING POSTURE, not capability — every
         # role keeps the full engine (failover replays a dead prefill
-        # tier's work on whoever survives), but prefill/decode roles
-        # require the paged pool (the refcounted block is the wire
-        # unit).
+        # tier's work on whoever survives).
         if role is None:
             role = os.environ.get("PADDLE_SERVING_ROLE") \
                 or "monolithic"
@@ -398,9 +394,8 @@ class ServingConfig:
         # distributed request tracing (observability.trace): per-hop
         # wall-anchored spans into a bounded ring served at
         # /debug/traces, ON by default (a handful of dict appends per
-        # request lifetime — probe-measured in the bench artifact);
-        # PADDLE_TRACE_SPANS=0 opts out, True/False forces. The
-        # disabled recorder keeps its full surface (scrapes answer,
+        # request lifetime); PADDLE_TRACE_SPANS=0 opts out,
+        # True/False forces. The disabled recorder keeps its full surface (scrapes answer,
         # snapshot shape identical). trace_span_keep bounds the ring.
         if trace_spans is None:
             trace_spans = os.environ.get(
@@ -450,18 +445,8 @@ class ServingEngine:
                                                     config.bucket_min)
         if max(buckets) > cache_len:
             raise ValueError("prefill buckets cannot exceed max_len")
-        sizes = (config.prefill_group_sizes
-                 or default_group_sizes(config.num_slots))
-        self.group_sizes = sorted(int(g) for g in sizes)
-        if self.group_sizes[0] != 1:
-            raise ValueError("prefill_group_sizes must include 1")
-        if self.group_sizes[-1] > config.num_slots:
-            raise ValueError(
-                f"prefill group size {self.group_sizes[-1]} exceeds "
-                f"num_slots {config.num_slots}")
         self.cache_len = cache_len
         self.params = model.export_decode_params()
-        self.paged = config.paged
         self.sampling = bool(config.sampling)
         self.chunk_len = config.prefill_chunk
         self.prefill_token_budget = config.prefill_token_budget
@@ -492,64 +477,38 @@ class ServingEngine:
         # programs and the analytic decode model are written for
         self._kv_pair = [a.name for a in self.cache_spec.arrays] \
             == ["k", "v"]
-        if self.paged:
-            from .paged import PagedKVPool
-
-            def _pool_factory():
-                return PagedKVPool(
-                    config.num_slots, max_len=cache_len,
-                    block_size=config.block_size,
-                    num_blocks=config.num_blocks, spec=self.cache_spec)
-
-            self._pool_factory = _pool_factory
-            self.pool = _pool_factory()
-            # the decode-attention path, resolved ONCE at build time
-            # from what is observable and nothing else: a (k, v) pool
-            # whose shapes, dtype and backend the Pallas kernel takes
-            # (ops.paged_attention.kernel_viable) gets the kernel, the
-            # rest (the CPU, untileable shapes) the XLA gather. A
-            # trace-time branch inside the one compiled decode program,
-            # so signatures, AOT keys and the zero-steady-state-compile
-            # contract are the same on either path. A model with
-            # another cache (latent attention) brings its own programs
-            # and kernels and is never handed this choice.
-            sizes = (config.num_slots, self.pool.block_size,
-                     self.pool.num_blocks, self.pool.blocks_per_slot)
-            if self._kv_pair:
-                from ..ops.paged_attention import kernel_viable
-                self.paged_attn = bool(kernel_viable(
-                    cfg.num_heads, cfg.hidden_size // cfg.num_heads,
-                    self.pool.block_size, kv_dtype))
-                self._prefill_fn, self._decode_fn = \
-                    model.build_paged_serving_fns(
-                        *sizes, sampling=self.sampling,
-                        attn_kernel=self.paged_attn)
-            else:
-                self.paged_attn = False
-                self._prefill_fn, self._decode_fn = \
-                    model.build_paged_serving_fns(
-                        *sizes, sampling=self.sampling)
-            self._chunk_fn = None   # chunks reuse the paged prefill
+        self.pool = self._new_pool()
+        # the decode-attention path, resolved ONCE at build time
+        # from what is observable and nothing else: a (k, v) pool
+        # whose shapes, dtype and backend the Pallas kernel takes
+        # (ops.paged_attention.kernel_viable) gets the kernel, the
+        # rest (the CPU, untileable shapes) the XLA gather. A
+        # trace-time branch inside the one compiled decode program,
+        # so signatures, AOT keys and the zero-steady-state-compile
+        # contract are the same on either path. A model with
+        # another cache (latent attention) brings its own programs
+        # and kernels and is never handed this choice.
+        sizes = (config.num_slots, self.pool.block_size,
+                 self.pool.num_blocks, self.pool.blocks_per_slot)
+        attn_kernel = False
+        if self._kv_pair:
+            from ..ops.paged_attention import kernel_viable
+            attn_kernel = bool(kernel_viable(
+                cfg.num_heads, cfg.hidden_size // cfg.num_heads,
+                self.pool.block_size, kv_dtype))
+            self._prefill_fn, self._decode_fn = \
+                model.build_paged_serving_fns(
+                    *sizes, sampling=self.sampling,
+                    attn_kernel=attn_kernel)
         else:
-            self.paged_attn = False
-            self._prefill_fn, self._decode_fn = model.build_serving_fns(
-                config.num_slots, cache_len, sampling=self.sampling)
-            self._chunk_fn = model.build_chunk_prefill_fn(
-                cache_len, sampling=self.sampling) \
-                if self.chunk_len is not None else None
-
-            def _pool_factory():
-                return SlotKVPool(
-                    config.num_slots, cfg.num_layers, cfg.num_heads,
-                    cache_len, cfg.hidden_size // cfg.num_heads,
-                    dtype=kv_dtype)
-
-            self._pool_factory = _pool_factory
-            self.pool = _pool_factory()
+            self._prefill_fn, self._decode_fn = \
+                model.build_paged_serving_fns(
+                    *sizes, sampling=self.sampling)
         # the attention path the decode program actually runs — what
-        # the roofline prices (observability.perf.roofline.LAYOUTS)
-        self.decode_layout = "paged_pallas" if self.paged_attn \
-            else ("paged_xla" if self.paged else "contiguous")
+        # the roofline prices (observability.perf.roofline.LAYOUTS);
+        # ``paged_attn`` reads it
+        self.decode_layout = "paged_pallas" if attn_kernel \
+            else "paged_xla"
         # disaggregated-serving role + KV wire programs (serving.
         # kv_wire): export gathers one slot's prompt blocks into
         # [layers, blocks_per_slot, ...] tiles (a bounded per-slot
@@ -560,34 +519,8 @@ class ServingEngine:
         # state stays zero-recompile across any number of handoffs.
         self.role = config.role
         self._held_exports = {}   # rid -> retired Request holding KV
-        if self.role != "monolithic" and not self.paged:
-            raise ValueError(
-                f"role={self.role!r} requires the paged pool "
-                f"(paged=True): the refcounted block is the KV wire "
-                f"unit")
-        if self.paged:
-            def _kv_export_fn(kc, vc, idx):
-                return kc[:, idx], vc[:, idx]
-
-            def _kv_import_fn(kc, vc, idx, ktiles, vtiles, toks, pos,
-                              slot, first_tok, plen):
-                # unused idx lanes point at the trash block — the
-                # scatter scribbles garbage no reader sees, exactly
-                # the released-slot stale-write discipline
-                kc = kc.at[:, idx].set(ktiles)
-                vc = vc.at[:, idx].set(vtiles)
-                # toks/pos are RETURNED, not donated: a pending decode
-                # harvest still reads the pre-import token array
-                toks = toks.at[slot].set(first_tok)
-                pos = pos.at[slot].set(plen)
-                return toks, pos, kc, vc
-
-            self._kv_export_fn = _kv_export_fn
-            self._kv_import_fn = _kv_import_fn
-        else:
-            self._kv_export_fn = self._kv_import_fn = None
         # speculative decoding (serving.spec): ONE extra verify program
-        # flavor per pool + the host-side drafter/acceptance gate. The
+        # + the host-side drafter/acceptance gate. The
         # plain decode program stays built either way — it is the
         # per-step fallback whenever no slot drafts, so BOTH programs
         # warm at the first decode-capable dispatch (zero steady-state
@@ -600,16 +533,11 @@ class ServingEngine:
                     f"spec_k + 1 ({self.spec_k + 1}) exceeds the "
                     f"per-slot cache capacity {cache_len}")
             from .spec import SpecDecoder
-            if self.paged:
-                self._verify_fn = model.build_paged_spec_verify_fn(
-                    config.num_slots, self.pool.block_size,
-                    self.pool.num_blocks, self.pool.blocks_per_slot,
-                    self.spec_k)
-                self._verify_key = ("paged_spec_verify",)
-            else:
-                self._verify_fn = model.build_spec_verify_fn(
-                    config.num_slots, cache_len, self.spec_k)
-                self._verify_key = ("spec_verify",)
+            self._verify_fn = model.build_paged_spec_verify_fn(
+                config.num_slots, self.pool.block_size,
+                self.pool.num_blocks, self.pool.blocks_per_slot,
+                self.spec_k)
+            self._verify_key = ("paged_spec_verify",)
             self._spec = SpecDecoder(config.num_slots, self.spec_k,
                                      config.spec_min_accept)
         else:
@@ -674,7 +602,7 @@ class ServingEngine:
             self._policy.name, self.chunk_len,
             self.prefill_token_budget)
         self.watchdog = CompileWatchdog(mode=config.watchdog_mode)
-        self._exec = {}  # (kind, bucket?, group?) -> XLA executable
+        self._exec = {}  # (kind, bucket?) -> XLA executable
         self._t_last_compile = float("-inf")  # SLO-feedback taint mark
         self._metric_servers = []
         # resilience: chaos harness + retry/quarantine/drain state
@@ -821,9 +749,8 @@ class ServingEngine:
         if device_memory_stats(dev) is not None:
             self.metrics.enable_device_memory(
                 lambda: device_memory_stats(dev))
-        if self.paged:
-            self.metrics.set_prefix_pool(self.pool.stats)
-            self.metrics.cache.attach_pool(self.pool)
+        self.metrics.set_prefix_pool(self.pool.stats)
+        self.metrics.cache.attach_pool(self.pool)
         self.metrics.set_kv_bytes_per_token(
             self.cache_spec.bytes_per_token)
         moe = getattr(model, "moe_counter_layout", None)
@@ -853,7 +780,7 @@ class ServingEngine:
                 n_params=n_params,
                 param_bytes=leaves[0].dtype.itemsize if leaves else 4,
                 kv_bytes=self.pool.kc.dtype.itemsize,
-                paged=self.paged, layout=self.decode_layout,
+                layout=self.decode_layout,
                 peak_flops=P.peak_flops,
                 hbm_bps=P.hbm_bps))
 
@@ -881,7 +808,7 @@ class ServingEngine:
         ``serving_requests_timed_out_total`` and SLO-judged as a
         violation. None (default) = no deadline.
 
-        ``hold_kv=True`` (paged pools only) parks the request's slot —
+        ``hold_kv=True`` parks the request's slot —
         blocks still live — when it retires instead of releasing it,
         so ``export_kv(rid)`` can serialize the prompt's KV blocks
         for a disaggregated handoff; the export (or abort/close)
@@ -911,10 +838,6 @@ class ServingEngine:
             raise RuntimeError(
                 "engine is draining/closed: no new requests (drain() "
                 "finishes already-submitted work, close() aborts it)")
-        if hold_kv and not self.paged:
-            raise ValueError(
-                "hold_kv requires the paged pool (paged=True): the "
-                "KV wire unit is the paged block")
         ctx = self._TraceContext.coerce(trace)
         if tenant_id is None:
             tenant_id = ctx.baggage.get("tenant")
@@ -948,6 +871,21 @@ class ServingEngine:
         return self.scheduler.pending or bool(self._pending)
 
     # ------------------------------------------------------- compilation
+
+    @property
+    def paged_attn(self):
+        """Whether the decode program's attention is the Pallas paged
+        kernel (``decode_layout`` says which path by name)."""
+        return self.decode_layout == "paged_pallas"
+
+    def _new_pool(self):
+        """A fresh pool of this engine's sizes (construction, and the
+        supervisor's restart)."""
+        config = self.config
+        return PagedKVPool(
+            config.num_slots, max_len=self.cache_len,
+            block_size=config.block_size,
+            num_blocks=config.num_blocks, spec=self.cache_spec)
 
     def _compiled(self, key, fn, args, donate=()):
         """AOT compile-once table. The ONLY place executables are
@@ -1106,9 +1044,6 @@ class ServingEngine:
         pool; everything after the single host read-back is pure numpy,
         so the transfer loop never traces. The slot is released even
         when serialization fails: a prefill tier never leaks blocks."""
-        if not self.paged:
-            raise RuntimeError(
-                "export_kv requires the paged pool (paged=True)")
         req = self._held_exports.pop(rid, None)
         if req is None:
             raise KeyError(
@@ -1131,7 +1066,7 @@ class ServingEngine:
                           TRASH_BLOCK, np.int32)
             idx[:n] = row
             args = (pool.kc, pool.vc, idx)
-            ex = self._compiled(("kv_export",), self._kv_export_fn,
+            ex = self._compiled(("kv_export",), _kv_export_fn,
                                 args)
             with self.metrics.span("serving/kv_export"):
                 k_dev, v_dev = self._timed_call(("kv_export",), ex,
@@ -1175,9 +1110,6 @@ class ServingEngine:
         the imported prompt's full blocks through the radix index, so
         later local admissions hit them and the fleet heat map sees
         this replica as the prefix's owner. Returns the live Request."""
-        if not self.paged:
-            raise RuntimeError(
-                "import_kv requires the paged pool (paged=True)")
         if self._draining or self._closed:
             raise RuntimeError(
                 "engine is draining/closed: no new requests (drain() "
@@ -1234,7 +1166,7 @@ class ServingEngine:
                 self._pos, np.int32(slot),
                 np.int32(handoff.first_token), np.int32(len(ids)))
         try:
-            ex = self._compiled(("kv_import",), self._kv_import_fn,
+            ex = self._compiled(("kv_import",), _kv_import_fn,
                                 args, donate=(0, 1))
             with self.metrics.span("serving/kv_import"):
                 toks, pos, kc, vc = self._timed_call(
@@ -1290,22 +1222,18 @@ class ServingEngine:
         and scribbles slot 0's toks/pos, both dead state on an idle
         engine; the donated kc/vc are rebound exactly like a real
         import."""
-        if not self.paged:
-            raise RuntimeError(
-                "warmup_kv_handoff requires the paged pool "
-                "(paged=True)")
         pool = self.pool
         layers, _, heads, bs, hd = pool.kc.shape
         bps = pool.blocks_per_slot
         idx = np.full((bps,), TRASH_BLOCK, np.int32)
         args = (pool.kc, pool.vc, idx)
-        ex = self._compiled(("kv_export",), self._kv_export_fn, args)
+        ex = self._compiled(("kv_export",), _kv_export_fn, args)
         k_dev, v_dev = ex(*args)
         np.asarray(k_dev), np.asarray(v_dev)
         tile = np.zeros((layers, bps, heads, bs, hd), pool.kc.dtype)
         args = (pool.kc, pool.vc, idx, tile, tile, self._toks,
                 self._pos, np.int32(0), np.int32(0), np.int32(0))
-        ex = self._compiled(("kv_import",), self._kv_import_fn, args,
+        ex = self._compiled(("kv_import",), _kv_import_fn, args,
                             donate=(0, 1))
         toks, pos, kc, vc = ex(*args)
         pool.rebind(kc, vc)
@@ -1422,8 +1350,7 @@ class ServingEngine:
             "kv_donation": dict(self.metrics.kv_donation),
             "flight": self.flight.state(),
             "slo": self.metrics.slo.report(),
-            "paged": self.paged,
-            "paged_attn": self.paged_attn,
+            "paged_attn": self.decode_layout == "paged_pallas",
             "role": self.role,
             "held_exports": len(self._held_exports),
             "decode_layout": self.decode_layout,
@@ -1446,12 +1373,12 @@ class ServingEngine:
         runs through the ``f64-upcast`` / ``host-callback`` / ``donation``
         passes, and the engine's compile watchdog feeds
         ``dynamic-shape-risk``. ``program`` picks the jaxpr:
-        "decode" (default), "chunk" (the chunked-prefill program —
-        legacy pool only; the paged flavor's chunks ARE its prefill
-        program), "spec_verify" (the speculative k-token verify
-        flavor of whichever pool this engine runs) or "kv_import"
-        (the disaggregation block-splice program — paged only). The donation metadata mirrors the real AOT build:
-        kc/vc/pos donated iff ``self._donate``
+        "decode" (default), "spec_verify" (the speculative k-token
+        verify program) or "kv_import" (the disaggregation
+        block-splice program). A chunk of a chunked prefill IS a
+        prefill dispatch, so there is no chunk program to lint. The
+        donation metadata mirrors the real AOT build: kc/vc/pos
+        donated iff ``self._donate``
         (``metrics.kv_donation["enabled"]``), aliasing iff the backend
         aliases donated buffers (``kv_donation["effective"]`` on) — so
         the ``donation`` pass cross-checks
@@ -1460,22 +1387,7 @@ class ServingEngine:
         exactly when the big cache buffers are donated."""
         import jax
         from ..analysis import lint as lint_mod
-        if program == "chunk":
-            if self._chunk_fn is None:
-                raise ValueError(
-                    "no chunk program on this engine (legacy pool + "
-                    "ServingConfig(prefill_chunk=...) builds one)")
-            C = self.chunk_len
-            args = (self.params, np.zeros((1, C), np.int32),
-                    np.int32(C), np.int32(0), np.int32(0),
-                    np.int32(1), self._toks, self._pos, self.pool.kc,
-                    self.pool.vc)
-            if self.sampling:
-                args = args + (np.int32(0), np.float32(0.0),
-                               np.int32(0), np.float32(1.0))
-            fn = self._chunk_fn
-            donate = (7, 8, 9) if self._donate else ()
-        elif program == "spec_verify":
+        if program == "spec_verify":
             if self._verify_fn is None:
                 raise ValueError(
                     "no verify program on this engine "
@@ -1483,21 +1395,11 @@ class ServingEngine:
             S = self.config.num_slots
             drafts = np.zeros((S, self.spec_k), np.int32)
             dlen = np.zeros((S,), np.int32)
-            if self.paged:
-                args = (self.params, self._toks, self._pos, drafts,
-                        dlen, self.pool.device_tables(), self.pool.kc,
-                        self.pool.vc)
-                donate = (2, 6, 7) if self._donate else ()
-            else:
-                args = (self.params, self._toks, self._pos, drafts,
-                        dlen, self.pool.kc, self.pool.vc)
-                donate = (2, 5, 6) if self._donate else ()
+            args, donate = self._verify_dispatch_args(self.pool,
+                                                      drafts, dlen)
+            donate = donate if self._donate else ()
             fn = self._verify_fn
         elif program == "kv_import":
-            if self._kv_import_fn is None:
-                raise ValueError(
-                    "no kv_import program on this engine (the paged "
-                    "pool builds one)")
             bps = self.pool.blocks_per_slot
             layers, _, heads, bs, hd = self.pool.kc.shape
             tile = np.zeros((layers, bps, heads, bs, hd),
@@ -1506,19 +1408,16 @@ class ServingEngine:
                     np.zeros((bps,), np.int32), tile, tile,
                     self._toks, self._pos, np.int32(0), np.int32(0),
                     np.int32(0))
-            fn = self._kv_import_fn
+            fn = _kv_import_fn
             donate = (0, 1) if self._donate else ()
-        elif self.paged:
+        elif program == "decode":
             args, donate = self._decode_dispatch_args(self.pool)
             fn = self._decode_fn
             donate = donate if self._donate else ()
         else:
-            args = (self.params, self._toks, self._pos, self.pool.kc,
-                    self.pool.vc)
-            if self.sampling:
-                args = args + self._sampler.device_arrays()
-            fn = self._decode_fn
-            donate = (2, 3, 4) if self._donate else ()
+            raise ValueError(
+                f"unknown program {program!r}: expected 'decode', "
+                f"'spec_verify' or 'kv_import'")
         closed = jax.make_jaxpr(fn)(*args)
         return lint_mod.lint_jaxpr(
             closed, passes=passes,
@@ -1659,7 +1558,7 @@ class ServingEngine:
 
     def _harvest(self, pending):
         """Read back dispatched results (at most one step's worth: the
-        prefill groups and the decode of the previous step, in
+        prefills and the decode of the previous step, in
         dispatch order) and run the host bookkeeping on the token
         values. np.asarray here is the engine's ONLY device->host
         sync; with async_depth=1 the current step's prefill/decode are
@@ -1775,16 +1674,11 @@ class ServingEngine:
         """(args, donate_argnums) for the plain pooled decode program
         — one place, shared by the hot path and the warm-both-flavors
         discipline of the speculative schedule."""
-        if self.paged:
-            # the cache spec's arrays, donated; then its state, not
-            args = (self.params, self._toks, self._pos,
-                    pool.device_tables()) + tuple(pool.arrays)
-            donate = (2,) + tuple(range(4, len(args)))
-            args = args + self._state
-        else:
-            args = (self.params, self._toks, self._pos, pool.kc,
-                    pool.vc)
-            donate = (2, 3, 4)
+        # the cache spec's arrays, donated; then its state, not
+        args = (self.params, self._toks, self._pos,
+                pool.device_tables()) + tuple(pool.arrays)
+        donate = (2,) + tuple(range(4, len(args)))
+        args = args + self._state
         if self.sampling:
             args = args + self._sampler.device_arrays()
         return args, donate
@@ -1794,15 +1688,9 @@ class ServingEngine:
         drafts/dlen are fixed-shape host arrays ([S, k] / [S]); the
         cache and pos donate exactly like plain decode (the two extra
         leading host inputs shift the argnums)."""
-        if self.paged:
-            args = (self.params, self._toks, self._pos, drafts, dlen,
-                    pool.device_tables(), pool.kc, pool.vc)
-            donate = (2, 6, 7)
-        else:
-            args = (self.params, self._toks, self._pos, drafts, dlen,
-                    pool.kc, pool.vc)
-            donate = (2, 5, 6)
-        return args, donate
+        args = (self.params, self._toks, self._pos, drafts, dlen,
+                pool.device_tables(), pool.kc, pool.vc)
+        return args, (2, 6, 7)
 
     def step(self):
         """One engine iteration of the pipelined hot path:
@@ -1811,7 +1699,7 @@ class ServingEngine:
            determined by in-flight tokens free NOW (predictable stops
            pay no retirement lag; EOS stops mask one speculative
            token);
-        2. admission + grouped prefill dispatch into free slots;
+        2. admission + tail prefill dispatch into free slots;
         3. dispatch ONE pooled decode advancing every token-wanting
            slot (freshly prefilled slots included — the device runs
            prefill then decode back to back);
@@ -1824,7 +1712,7 @@ class ServingEngine:
 
         Each phase runs in its own ``serving/*`` scope nested under
         ``serving/step``, so the step anatomy (retirement → admission
-        → grouped prefill → decode dispatch → harvest) is readable in
+        → prefill → decode dispatch → harvest) is readable in
         the chrome host timeline
         (observability.default_recorder().dump_chrome_trace()) as well
         as the XPlane capture and the span counters.
@@ -1888,10 +1776,7 @@ class ServingEngine:
         # (decode of already-running slots continues — backoff starves
         # nobody who already holds a slot)
         if time.perf_counter() >= self._retry_at:
-            if self.paged:
-                self._paged_prefills(sync)
-            else:
-                self._legacy_prefills(sync)
+            self._paged_prefills(sync)
             if self._chunk_q:
                 self._dispatch_chunks(sync)
 
@@ -2006,7 +1891,7 @@ class ServingEngine:
         self._step_id += 1
         step = self._step_id
         conservation_ok = conservation_error = None
-        if self.paged and step % self.config.health_audit_every == 0:
+        if step % self.config.health_audit_every == 0:
             with M.span("serving/health_audit"):
                 audit = self.pool.audit()
             conservation_ok = audit["ok"]
@@ -2043,10 +1928,8 @@ class ServingEngine:
                k[4]._value, k[5]._value, k[6]._value, k[7]._value,
                k[8]._value + k[9]._value + k[10]._value, k[11]._value,
                M.shed_count,
-               # cache-pressure facts (plain attr reads; 0 on legacy
-               # pools so the tuple shape is branch-free downstream)
-               pool.index.thrash_count if self.paged else 0,
-               pool.evictable_blocks if self.paged else 0)
+               # cache-pressure facts (plain attr reads)
+               pool.index.thrash_count, pool.evictable_blocks)
         prev = self._hprev
         self._hprev = cur
         if prev is None:
@@ -2071,6 +1954,7 @@ class ServingEngine:
             # wipes the pool out from under the export
             "occupied_slots": (len(self.scheduler.active)
                                + len(self._held_exports)),
+            "held_exports": len(self._held_exports),
             "chunked_inflight": len(self._chunk_q),
             "admitted": int(cur[1] - prev[1]),
             "tokens": int(cur[0] - prev[0]),
@@ -2088,20 +1972,15 @@ class ServingEngine:
             "slo_on": self._slo_on,
             "prefix_hit_rate": round(hits / (hits + misses), 4)
             if (hits + misses) else None,
-            "pool_free_blocks": self.pool.free_blocks
-            if self.paged else None,
-            "pool_evictable_blocks": self.pool.evictable_blocks
-            if self.paged else None,
-            "pool_live_blocks": self.pool.live_blocks
-            if self.paged else None,
+            "pool_free_blocks": pool.free_blocks,
+            "pool_evictable_blocks": pool.evictable_blocks,
+            "pool_live_blocks": pool.live_blocks,
             # per-step cache-pressure deltas (PR 13): thrash deltas
             # are clamped at 0 because a supervisor pool swap resets
             # the radix counter mid-stream; the evictable delta is
             # signed (pinning legitimately shrinks the supply)
-            "cache_thrash": max(0, int(cur[11] - prev[11]))
-            if self.paged else None,
-            "pool_evictable_delta": int(cur[12] - prev[12])
-            if self.paged else None,
+            "cache_thrash": max(0, int(cur[11] - prev[11])),
+            "pool_evictable_delta": int(cur[12] - prev[12]),
             "conservation_ok": conservation_ok,
             "conservation_error": conservation_error,
         })
@@ -2125,85 +2004,6 @@ class ServingEngine:
         for req, headroom in shed:
             M.record_shed(req.shed_reason, req.tenant_id)
             self.flight.shed(req, req.shed_reason, headroom)
-
-    def _legacy_prefills(self, sync):
-        """Admission + grouped bucketed prefill over the contiguous
-        slot pool. A dispatch failure (compile error, bad buffer)
-        rolls every not-yet-dispatched admission back to the queue and
-        releases its slot — acquire-to-dispatch is leak-free
-        (tests/test_serving.py::test_failed_prefill_dispatch...).
-        With chunked prefill enabled, prompts longer than the chunk
-        width claim their slot here but dispatch chunk by chunk in
-        ``_dispatch_chunks`` instead of joining a group."""
-        sch, pool, M = self.scheduler, self.pool, self.metrics
-        if self.chaos is not None \
-                and self.chaos.fires("block_exhaustion",
-                                     step=self._step_id + 1):
-            return          # simulated dry pool: admission waits
-        with M.span("serving/admit"):
-            groups, chunked = sch.admit_chunked(pool, self.group_sizes,
-                                                self.chunk_len)
-        self._register_chunked(chunked)
-
-        for gi, group in enumerate(groups):
-            G = len(group)
-            bucket = sch.bucket_for(len(group[0][0].prefill_ids))
-            tokens = np.zeros((G, bucket), np.int32)
-            lengths = np.zeros((G,), np.int32)
-            slots = np.zeros((G,), np.int32)
-            for g, (req, slot) in enumerate(group):
-                ids = req.prefill_ids   # prompt (+ replayed tokens)
-                n = len(ids)
-                tokens[g, :n] = ids
-                lengths[g] = n
-                slots[g] = slot
-                req.inflight += 1
-                if self._sampler is not None:
-                    self._sampler.set_slot(slot, req)
-            args = (self.params, tokens, lengths, slots, self._toks,
-                    self._pos, pool.kc, pool.vc)
-            if self.sampling:
-                from .sched import SlotSampler
-                args = args + SlotSampler.gather([r for r, _ in group])
-            try:
-                if self.chaos is not None:
-                    self.chaos.maybe_raise("prefill_dispatch",
-                                           step=self._step_id + 1)
-                ex = self._compiled(("prefill", bucket, G),
-                                    self._prefill_fn, args,
-                                    donate=(5, 6, 7))
-                t_disp = time.perf_counter()
-                with M.span("serving/prefill_dispatch"):
-                    for req, _slot in group:
-                        self.flight.prefill_dispatched(req, bucket, G)
-                    first, self._toks, self._pos, kc, vc = \
-                        self._timed_call(("prefill", bucket, G), ex,
-                                         args)
-            except BaseException as e:
-                for req, _slot in group:
-                    req.inflight -= 1
-                sch.rollback_admission(
-                    [r for g in groups[gi:] for r, _ in g], pool)
-                if self._absorb_dispatch_failure(e, "prefill", group):
-                    return   # rolled back; the retry runs next step
-                raise
-            pool.rebind(kc, vc)
-            # admission accounting lands only once the dispatch stuck:
-            # a rolled-back admission is re-counted on its retry, not
-            # counted twice
-            for req, _slot in group:
-                M.record_admission(req)
-                self._stamp_prefill(req, t_disp, bucket)
-            M.requests_admitted += G
-            M.prefills += 1
-            M.prefill_requests += G
-            M.record_prefill_group(G)
-            M.record_prefill_tokens(int(lengths.sum()))
-            entry = ("prefill", first, group, ("prefill", bucket, G))
-            if sync:
-                self._harvest([entry])
-            else:
-                self._pending.append(entry)
 
     def _paged_prefills(self, sync):
         """Prefix-aware admission + tail-only prefill over the paged
@@ -2232,7 +2032,7 @@ class ServingEngine:
                 # prefill itself runs chunk by chunk under the per-
                 # step budget (_dispatch_chunks); commit-to-index
                 # still waits for the FINAL chunk's dispatch success
-                self._register_chunked([(req, alloc.slot)], alloc)
+                self._register_chunked(req, alloc)
                 continue
             ids = req.prefill_ids   # prompt (+ replayed tokens)
             start = alloc.prefix_tokens
@@ -2307,21 +2107,17 @@ class ServingEngine:
         return (np.int32(seed), np.float32(temp), np.int32(topk),
                 np.float32(topp))
 
-    def _register_chunked(self, chunked, alloc=None):
-        """Queue freshly admitted long prompts for chunk-by-chunk
-        prefill and park their slots out of decode harvest."""
-        for req, slot in chunked:
-            if self._sampler is not None:
-                self._sampler.set_slot(slot, req)
-            start0 = alloc.prefix_tokens if alloc is not None else 0
-            self._chunk_q.append(self._ChunkPlan(
-                req, slot, start0, self.chunk_len, alloc=alloc))
-            self._prefilling.add(slot)
+    def _register_chunked(self, req, alloc):
+        """Queue a freshly admitted long prompt for chunk-by-chunk
+        prefill and park its slot out of decode harvest."""
+        self._chunk_q.append(self._ChunkPlan(
+            req, alloc.slot, alloc.prefix_tokens, self.chunk_len))
+        self._prefilling.add(alloc.slot)
 
     def _dispatch_chunks(self, sync):
         """Advance chunked prefills: dispatch chunks FIFO across the
         queued plans until the per-step token budget runs out. Every
-        dispatch is the ONE compiled chunk program per pool flavor
+        dispatch is the tail-prefill program at the chunk-width bucket
         (traced start/len/slot/final — any prompt-length mix, zero
         steady-state compiles). Interior chunks park the slot (no
         token emitted, decode ignores it); the FINAL chunk emits the
@@ -2340,21 +2136,13 @@ class ServingEngine:
                 break           # FIFO: never skip ahead past the head
             tokens = np.zeros((1, C), np.int32)
             tokens[0, :clen] = plan.ids[start:start + clen]
-            if self.paged:
-                args = (self.params, tokens, np.int32(clen),
-                        np.int32(start), np.int32(plan.slot),
-                        np.int32(1 if final else 0),
-                        pool.table_row(plan.slot), self._toks,
-                        self._pos) + tuple(pool.arrays)
-                key, fn, donate = ("paged_prefill", C), \
-                    self._prefill_fn, tuple(range(8, len(args)))
-            else:
-                args = (self.params, tokens, np.int32(clen),
-                        np.int32(start), np.int32(plan.slot),
-                        np.int32(1 if final else 0), self._toks,
-                        self._pos, pool.kc, pool.vc)
-                key, fn, donate = ("chunk_prefill", C), \
-                    self._chunk_fn, (7, 8, 9)
+            args = (self.params, tokens, np.int32(clen),
+                    np.int32(start), np.int32(plan.slot),
+                    np.int32(1 if final else 0),
+                    pool.table_row(plan.slot), self._toks,
+                    self._pos) + tuple(pool.arrays)
+            key, fn, donate = ("paged_prefill", C), \
+                self._prefill_fn, tuple(range(8, len(args)))
             if self.sampling:
                 args = args + self._samp_scalars(req)
             if final:
@@ -2397,10 +2185,8 @@ class ServingEngine:
             if final:
                 self._chunk_q.pop(0)
                 self._prefilling.discard(plan.slot)
-                if self.paged:
-                    pool.commit_prefix(plan.slot, plan.ids)
-                    M.record_prefix_reuse(plan.start0, 0,
-                                          req.tenant_id)
+                pool.commit_prefix(plan.slot, plan.ids)
+                M.record_prefix_reuse(plan.start0, 0, req.tenant_id)
                 M.record_admission(req)
                 M.requests_admitted += 1
                 M.prefill_requests += 1
@@ -2529,12 +2315,12 @@ class ServingEngine:
 
     def _supervisor_restart(self, reason):
         """In-process recovery (called ONLY by the supervisor): drop
-        every piece of suspect state — in-flight device results, both
-        pools' bookkeeping, the AOT executable table, per-slot failure
+        every piece of suspect state — in-flight device results, the
+        pool's bookkeeping, the AOT executable table, per-slot failure
         tallies — and re-queue every request still owed tokens for a
         re-prefill of its prompt + already-emitted tokens. Greedy
-        decoding makes the replay continuation bit-exact; on paged
-        pools the (rebuilt-empty) radix index re-warms as replays
+        decoding makes the replay continuation bit-exact; the
+        (rebuilt-empty) radix index re-warms as replays
         commit, so sibling requests sharing a prefix soften each
         other's recompute. Returns the re-queued requests; the whole
         recovery runs under a ``serving/supervisor_restart`` span and
@@ -2568,10 +2354,9 @@ class ServingEngine:
             for r in self._held_exports.values():
                 r.slot = None
             self._held_exports.clear()
-            self.pool = self._pool_factory()
-            if self.paged:
-                M.set_prefix_pool(self.pool.stats)
-                M.cache.attach_pool(self.pool)
+            self.pool = self._new_pool()
+            M.set_prefix_pool(self.pool.stats)
+            M.cache.attach_pool(self.pool)
             import jax.numpy as jnp
             self._toks = jnp.zeros((self.config.num_slots,), jnp.int32)
             self._pos = jnp.zeros((self.config.num_slots,), jnp.int32)

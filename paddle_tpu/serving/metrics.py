@@ -150,8 +150,7 @@ class ServingMetrics:
         self._h_submit_wait = r.histogram(
             "serving_submit_wait_seconds",
             "gateway entry -> its lock taken (received -> arrival)")
-        # prefix-cache economy (the paged pool moves these; the legacy
-        # pool only accrues computed tokens): admissions that reused a
+        # prefix-cache economy: admissions that reused a
         # cached prefix vs not, tokens served FROM cache (never
         # prefill-computed) vs tokens the prefill actually computed
         self._c_prefix_hits = r.counter(
@@ -475,7 +474,7 @@ class ServingMetrics:
 
     def set_prefix_pool(self, stats_fn):
         """Attach the paged pool's ``stats()`` as the pull source for
-        snapshot()["prefix_cache"]["pool"] (None on legacy engines)."""
+        snapshot()["prefix_cache"]["pool"]."""
         self._prefix_pool_stats = stats_fn
 
     def windowed_prefix_hit_rate(self):

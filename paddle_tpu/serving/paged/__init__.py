@@ -1,10 +1,10 @@
 """Paged KV pool with refcounted blocks + radix-tree shared-prefix
 reuse (the vLLM paging / SGLang radix-cache pattern, TPU-native).
 
-The legacy serving pool (kv_pool.SlotKVPool) gives every slot one
-contiguous ``max_len`` cache region, so two requests sharing a 500-token
-system prompt each prefill all 500 tokens. This package makes the KV
-cache BLOCK-granular and CONTENT-addressed so the shared span is
+A pool that gave every slot one contiguous ``max_len`` cache region
+would make two requests sharing a 500-token system prompt each prefill
+all 500 tokens. This package, the serving engine's only cache, makes
+the KV cache BLOCK-granular and CONTENT-addressed so the shared span is
 computed once and reused:
 
   * **paged cache** (pool.PagedKVPool) — ONE pair of arrays shaped
@@ -40,12 +40,6 @@ Safety invariants (tests/test_paged_kv.py pins them):
     the same admission, so an admission can never evict its own prefix;
   * eviction takes refcount-zero radix LEAVES only (lowest LRU tick
     first), so every cached prefix path stays contiguous from the root.
-
-Select with ``ServingConfig(paged=True)`` (or ``PADDLE_PAGED_KV=1``;
-mirrors the ``PADDLE_FUSED_CE`` gating pattern). The legacy
-slot-contiguous pool remains the default / measured fallback until the
-Pallas paged decode-attention kernel (ROADMAP direction #2) removes
-the gather materialization this XLA composition pays.
 """
 from .pool import PagedAllocation, PagedKVPool  # noqa: F401
 from .radix import RadixPrefixIndex  # noqa: F401

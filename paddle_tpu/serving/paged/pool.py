@@ -24,7 +24,7 @@ runs dry); unindexed blocks free immediately at ref 0. An admission
 pins its matched prefix (ref++) BEFORE allocating anything, so it can
 never evict blocks it is about to reuse.
 
-Host/device discipline mirrors SlotKVPool: the engine routes every
+Host/device discipline: the engine routes every
 executable's returned kc/vc through ``rebind`` (single owner of the
 live buffers under donation), while the block table is host-authored
 (numpy) and uploaded via ``device_tables()`` only when admission or
@@ -72,10 +72,10 @@ class PagedKVPool:
         self.block_size = int(block_size)
         self.max_len = int(max_len)
         self.blocks_per_slot = -(-self.max_len // self.block_size)
-        # default: the legacy pool's footprint (every slot fully backed)
-        # plus the trash block — sharing then stretches the same bytes
-        # further. Smaller num_blocks oversubscribes: admission waits
-        # when blocks run dry (acquire returns None), never corrupts.
+        # default: every slot fully backed, plus the trash block —
+        # sharing then stretches the same bytes further. Smaller
+        # num_blocks oversubscribes: admission waits when blocks run
+        # dry (acquire returns None), never corrupts.
         if num_blocks is None:
             num_blocks = self.num_slots * self.blocks_per_slot + 1
         self.num_blocks = int(num_blocks)
@@ -103,7 +103,7 @@ class PagedKVPool:
         # block alloc/free and once per successful admission. None
         # keeps every hot-path branch a single attribute test.
         self.observer = None
-        # slot state (mirrors SlotKVPool's deterministic allocator)
+        # slot state (deterministic allocator: lowest free index first)
         self._free_slots = list(range(self.num_slots))
         self._owner = {}
         self._quarantined = set()
@@ -133,9 +133,11 @@ class PagedKVPool:
         return sorted(self._quarantined)
 
     def quarantine(self, slot):
-        """Exclude a FREE slot from future admission (same contract
-        as SlotKVPool.quarantine; the slot's table row already points
-        at trash, so no blocks are pinned by a quarantined slot)."""
+        """Exclude a FREE slot from future admission (the engine's
+        repeated-same-slot-failure response; raises when the slot is
+        live — quarantine happens after rollback released it). The
+        slot's table row already points at trash, so no blocks are
+        pinned by a quarantined slot."""
         if slot in self._owner:
             raise ValueError(f"slot {slot} is live; release it first")
         if slot in self._quarantined:
@@ -376,10 +378,11 @@ class PagedKVPool:
         return self.arrays[1]
 
     def rebind(self, *arrays):
-        """Same single-owner discipline as SlotKVPool.rebind: the
-        compiled call's returned arrays become the live buffers; any
-        shape/dtype drift is caught here, before a donating backend's
-        next AOT call consumes a mismatched buffer."""
+        """Single-owner discipline: the compiled call's returned
+        arrays become the live buffers (with donation the previous
+        ones are already invalid); any shape/dtype drift is caught
+        here, before a donating backend's next AOT call consumes a
+        mismatched buffer."""
         if len(arrays) != len(self.arrays):
             raise ValueError(
                 f"rebind: got {len(arrays)} arrays, the cache spec "

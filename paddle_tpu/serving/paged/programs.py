@@ -1,6 +1,6 @@
 """AOT-compilable prefill/decode programs over the paged cache.
 
-Same decode math as ``text.models.build_serving_fns`` (both reuse
+Same decode math as ``GPTForCausalLM.generate()`` (both reuse
 ``_decode_forward_builder``; greedy parity with ``generate()`` is by
 construction), with the cache addressed through the fixed-shape block
 table instead of a slot-contiguous region:
@@ -65,7 +65,7 @@ trash block, where whichever wins is garbage behind the length mask.
 Scatter/gather safety: table-row padding and released rows point at
 the reserved trash block, so pad-entry writes land in garbage, and the
 length mask keeps garbage reads at exactly-zero softmax weight — the
-same recycled-slot invariant the legacy pool pins, at block granularity.
+same recycled-slot invariant as a contiguous cache, at block granularity.
 
 ``sampling=True`` threads per-slot sampling parameters (seeds / temps
 / top-k / top-p — serving.sched.sampling) through both programs; the

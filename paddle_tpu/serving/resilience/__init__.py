@@ -24,9 +24,7 @@ lives in the engine/scheduler/pools, keyed off ServingConfig knobs):
     with exact greedy replay; ``/debug/health`` reports ``degraded``
     until the replay drains, then ``healthy`` again.
 
-``tools/chaos_sweep.py`` runs the seeded fault matrix as a CI gate;
-the ``chaos`` bench scenario (bench_serving.py) measures hardened vs
-unhardened completion on the same fault schedule.
+``tools/chaos_sweep.py`` runs the seeded fault matrix as a CI gate.
 """
 from .chaos import (  # noqa: F401
     DEFAULT_RATES, FAULT_SITES, FaultInjector, FaultPlan, FaultSpec,

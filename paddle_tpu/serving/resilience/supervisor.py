@@ -6,10 +6,10 @@ by ACTING on the ones that mean "the step loop cannot make progress
 from here" (a wedged queue, a leaking pool, a dispatch that fails
 every retry). The supervisor's one move is an in-process restart —
 ``ServingEngine._supervisor_restart``: rebuild the AOT executable
-table, replace both pools with fresh ones, reset the device-side
+table, replace the pool with a fresh one, reset the device-side
 token/position state, and re-queue every in-flight request for
 re-prefill of its prompt PLUS the tokens it already emitted (greedy
-decoding makes the replay bit-exact; on paged pools the radix prefix
+decoding makes the replay bit-exact; the radix prefix
 cache softens the recompute when sibling requests shared a prefix).
 Nothing crosses a process boundary: slots, blocks, executables and
 queue state are all host objects the engine owns, so a restart is a

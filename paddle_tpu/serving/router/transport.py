@@ -217,7 +217,7 @@ class EngineGateway:
         (+ the first token) on this replica and serialize the blocks
         for the wire. Blocking; returns ``{rid, replica_id,
         first_token, handoff}``. TransportRefused when the engine
-        can't take it (draining / legacy pool / request expired before
+        can't take it (draining / request expired before
         export), TransportError when the gateway died mid-hop.
         ``trace`` propagates into the request AND (via export_kv)
         into the handoff payload, so the decode tier joins the same

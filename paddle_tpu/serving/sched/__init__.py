@@ -4,13 +4,14 @@ with decode, load-shedding admission, and per-slot sampling.
 Three pieces close the observability->control loop the PR-3/4 layers
 left open:
 
-  * **chunked prefill** (chunker / programs) — long prompts split into
+  * **chunked prefill** (chunker) — long prompts split into
     fixed-width chunks dispatched under a per-step token budget and
     interleaved with decode steps (Sarathi-Serve co-scheduling), so a
-    4k-token prompt never stalls the decoding slots; ``start`` /
-    ``chunk_len`` are traced scalars, so ANY prompt-length mix reuses
-    one compiled chunk program per pool flavor — the zero-recompile
-    invariant survives, watchdog-verified;
+    4k-token prompt never stalls the decoding slots; a chunk is a
+    tail prefill at the chunk-width bucket whose ``start`` /
+    ``tail_len`` are traced scalars, so ANY prompt-length mix reuses
+    the one compiled program — the zero-recompile invariant survives,
+    watchdog-verified;
   * **scheduling policy** (policy) — pluggable admission control:
     ``FIFOPolicy`` (the default, PR-1..6 behavior) or
     ``SLOFeedbackPolicy``, which reads each queued request's live TTFT
@@ -33,7 +34,6 @@ from .policy import (  # noqa: F401
     FIFOPolicy, SchedulingPolicy, SLOFeedbackPolicy, TriageDecision,
     resolve_policy,
 )
-from .programs import build_chunk_fns  # noqa: F401
 from .sampling import (  # noqa: F401
     SlotSampler, build_sampling_head, request_sampling_params,
 )

@@ -11,7 +11,7 @@ Zero-recompile invariant: every chunk dispatch is the SAME compiled
 program — a fixed ``[1, chunk]`` token window whose ``start`` /
 ``chunk_len`` are traced scalars (the PR-6 tail-only-prefill trick) —
 so prompt-length variety costs zero compiles and the whole chunked
-inventory is ONE program per pool flavor.
+inventory is ONE program (the tail prefill at the chunk-width bucket).
 
 The plan keeps every dispatch full-width, which is what makes the
 no-pad-row guarantee possible: interior chunks tile from the start,
@@ -32,15 +32,14 @@ class ChunkPlan:
     time so the chunk windows stay stable while the plan drains."""
 
     __slots__ = ("req", "slot", "ids", "starts", "next", "chunk",
-                 "start0", "alloc")
+                 "start0")
 
-    def __init__(self, req, slot, start0, chunk, alloc=None):
+    def __init__(self, req, slot, start0, chunk):
         self.req = req
         self.slot = slot
         self.ids = req.prefill_ids
         self.chunk = int(chunk)
-        self.start0 = int(start0)       # cached-prefix end (paged)
-        self.alloc = alloc              # PagedAllocation (paged pool)
+        self.start0 = int(start0)       # cached-prefix end
         self.starts = plan_chunks(self.start0, len(self.ids),
                                   self.chunk)
         self.next = 0                   # index of the next chunk

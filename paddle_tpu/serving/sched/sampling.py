@@ -120,13 +120,3 @@ class SlotSampler:
                          jnp.asarray(self.topps.copy()))
             self._dirty = False
         return self._dev
-
-    @staticmethod
-    def gather(requests):
-        """Per-dispatch ``[G]`` parameter arrays for a grouped prefill
-        (the group's members sample their FIRST token in-program)."""
-        rows = [request_sampling_params(r) for r in requests]
-        return (np.array([r[0] for r in rows], np.int32),
-                np.array([r[1] for r in rows], np.float32),
-                np.array([r[2] for r in rows], np.int32),
-                np.array([r[3] for r in rows], np.float32))

@@ -246,64 +246,6 @@ class StepScheduler:
             self.completed.append(req)
         return decision.shed, decision.deprioritized
 
-    def admit(self, pool, group_sizes=(1,)):
-        """Claim free slots for queued requests (FIFO) and return the
-        admissions as SAME-BUCKET prefill groups: a list of
-        [(request, slot), ...] lists, every member of a group sharing
-        one prefill bucket and group lengths drawn from ``group_sizes``
-        (largest fitting size first), so a deep queue costs one prefill
-        dispatch per group instead of one per request. Groups keep FIFO
-        order: buckets appear in first-arrival order, members in
-        arrival order within each bucket."""
-        return self.admit_chunked(pool, group_sizes, None)[0]
-
-    def admit_chunked(self, pool, group_sizes=(1,), chunk_len=None):
-        """``admit`` plus chunked-prefill routing: prompts LONGER than
-        ``chunk_len`` claim their slot like everyone else but return
-        as singleton ``(request, slot)`` chunked admissions instead of
-        joining a bucket group — the engine prefills them chunk by
-        chunk under its per-step token budget while the group members
-        dispatch whole. Returns ``(groups, chunked)``, both in FIFO
-        admission order; ``chunk_len=None`` (the default) routes
-        nothing and makes this exactly ``admit``."""
-        sizes = sorted(int(g) for g in group_sizes)
-        if not sizes or sizes[0] != 1:
-            raise ValueError(f"group_sizes must include 1, got "
-                             f"{group_sizes}")
-        by_bucket = {}
-        chunked = []
-        while self.queue and pool.free_count:
-            req = self.queue.popleft()
-            slot = pool.acquire(req.rid)
-            req.slot = slot
-            req.state = RUNNING
-            req.t_admitted = time.perf_counter()
-            self.active[slot] = req
-            # prefill_ids (not prompt): a restart-replayed request
-            # re-prefills its prompt PLUS already-emitted tokens
-            n_fill = len(req.prefill_ids)
-            if chunk_len is not None and n_fill > chunk_len:
-                chunked.append((req, slot))
-                if self.flight is not None:
-                    # chunked prefills dispatch at the chunk width
-                    self.flight.admitted(req, slot, int(chunk_len), 1)
-                continue
-            by_bucket.setdefault(self.bucket_for(n_fill),
-                                 []).append((req, slot))
-        groups = []
-        for bucket, members in by_bucket.items():
-            i = 0
-            while i < len(members):
-                take = max(g for g in sizes if g <= len(members) - i)
-                group = members[i:i + take]
-                groups.append(group)
-                if self.flight is not None:
-                    for req, slot in group:
-                        self.flight.admitted(req, slot, bucket,
-                                             len(group))
-                i += take
-        return groups, chunked
-
     def plan_prefix(self, prompt_len, cached_tokens, block_size,
                     slot_capacity):
         """How much of a cached prefix a paged admission actually uses:

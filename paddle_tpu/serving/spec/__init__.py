@@ -1,7 +1,7 @@
 """Self-drafting speculative decoding on the slot pool (ROADMAP open
 item #2): an n-gram/prompt-lookup drafter proposes up to k tokens per
 active slot from the slot's own context (no second model), and ONE
-extra AOT program flavor per pool verifies all k+1 positions in a
+extra AOT program verifies all k+1 positions in a
 single fixed-shape dispatch — amortizing the HBM-bound parameter + KV
 read that plain decode pays per token. Greedy streams stay bit-exact
 with generate() by construction (longest-accepted-prefix harvest over
@@ -14,6 +14,4 @@ this iteration (speculation x sampling is rejected at config time).
 """
 from .decoder import SpecDecoder  # noqa: F401
 from .drafter import NGramDrafter  # noqa: F401
-from .programs import (  # noqa: F401
-    build_paged_spec_verify_fn, build_spec_verify_fn,
-)
+from .programs import build_paged_spec_verify_fn  # noqa: F401

@@ -1,13 +1,9 @@
-"""AOT-compilable k-token verify programs (speculative decoding,
-Leviathan et al.): ONE more program flavor per pool that runs the
+"""AOT-compilable k-token verify program (speculative decoding,
+Leviathan et al.): ONE more program that runs the
 decode forward over ``[slots, k+1]`` positions in a single dispatch —
 the slot's last accepted token plus its k drafted continuations — so
 the HBM-bound parameter + KV read every decode dispatch pays is
 amortized over up to k+1 emitted tokens.
-
-  ``spec_verify(params, toks [S], pos [S], drafts [S, k], dlen [S],
-                kc, vc)``
-      -> (out [S, k+1], accepted [S], toks', pos', kc, vc)
 
   ``paged_spec_verify(params, toks [S], pos [S], drafts [S, k],
                       dlen [S], tables [S, MB], kc, vc)``
@@ -26,7 +22,7 @@ plain decode step, and the engine reads (out, accepted) back at
 harvest to emit 1..k+1 tokens.
 
 Greedy parity with generate() is by construction: query i attends
-(per-query causal mask, ops.attention.cached_slot_block_attention)
+(per-query causal mask, ops.attention.cached_paged_block_attention)
 over the live prefix plus candidates 0..i only, so its logits are
 conditioned purely on tokens that are accepted whenever position i's
 output is harvested. Rejected-tail K/V rows land in the cache but are
@@ -34,99 +30,20 @@ invisible and then legitimately overwritten: the next dispatch writes
 its rows before attending (the same recycled-slot/parked-row
 invariant the chunked-prefill program pins).
 
-Write discipline per pool:
-
-  * legacy — a windowed read-merge-write per slot: the t-row window
-    starting at ``min(pos, C - t)`` is read, rows whose global
-    position is a real candidate position (< C) take the new K/V,
-    rows below keep their current (historical) values, and positions
-    past the end are dropped — parked slots (pos >= C) write nothing
-    at all, strictly safer than plain decode's end-clamped write;
-  * paged — PR 7's whole-position ``wpos`` clamp per candidate row,
-    with rows past the slot's addressable range routed to the
-    reserved trash block (index 0), so a parked/overflowing slot's
-    stray rows land in garbage instead of cycling over live blocks.
+Write discipline: PR 7's whole-position ``wpos`` clamp per candidate
+row, with rows past the slot's addressable range routed to the
+reserved trash block (index 0), so a parked/overflowing slot's stray
+rows land in garbage instead of cycling over live blocks.
 """
-
-
-def build_spec_verify_fn(cfg, num_slots, cache_len, k):
-    """The legacy-pool verify program for a GPT decode config. Pure
-    and shape-stable; the engine AOT-compiles it ONCE (key
-    ``("spec_verify",)``) alongside the plain decode."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    from ...ops import attention as attn_ops
-    from ...text.models import _decode_forward_builder
-
-    nh = cfg.num_heads
-    hd = cfg.hidden_size // nh
-    hidden = cfg.hidden_size
-    ln, _ = _decode_forward_builder(nh, hd, hidden)
-    C = int(cache_len)
-    t = int(k) + 1
-    assert 1 <= t <= C, f"spec_k+1 ({t}) must fit the cache ({C})"
-
-    def write_slot_block(cache_l, new, pos):
-        # cache_l [S, nh, C, hd]; new [S, nh, t, hd]; pos [S]: each
-        # slot merges its t candidate rows into the window starting at
-        # min(pos, C - t) — rows below pos keep history, rows past
-        # C-1 are dropped (parked slots write nothing)
-        z = jnp.int32(0)
-
-        def one(c, n, p):
-            wstart = jnp.minimum(p, jnp.int32(C - t))
-            d = p - wstart                      # >= 0; >= t when parked
-            win = lax.dynamic_slice(c, (z, wstart, z), (nh, t, hd))
-            rows = jnp.arange(t)
-            shifted = jnp.take(n, jnp.maximum(rows - d, 0), axis=1)
-            merged = jnp.where((rows >= d)[None, :, None], shifted,
-                               win)
-            return lax.dynamic_update_slice(c, merged, (z, wstart, z))
-
-        return jax.vmap(one)(cache_l, new, pos)
-
-    def spec_verify(params, toks, pos, drafts, dlen, kc, vc):
-        S = toks.shape[0]
-        tok_blk = jnp.concatenate([toks[:, None], drafts], axis=1)
-        qpos = pos[:, None] + jnp.arange(t)[None, :]     # [S, t]
-        x = params["wemb"][tok_blk] + params["pemb"][
-            jnp.minimum(qpos, params["pemb"].shape[0] - 1)]
-
-        def body(carry, inp):
-            x = carry
-            p, kcl, vcl = inp
-            h_ = ln(x, p["ln1_w"], p["ln1_b"])
-            qkv = h_ @ p["qkv_w"] + p["qkv_b"]
-            qkv = qkv.reshape(S, t, 3, nh, hd).transpose(2, 0, 3, 1, 4)
-            q, k_, v = qkv[0], qkv[1], qkv[2]     # [S, nh, t, hd]
-            kcl = write_slot_block(kcl, k_, pos)
-            vcl = write_slot_block(vcl, v, pos)
-            o = attn_ops.cached_slot_block_attention(q, kcl, vcl,
-                                                     qpos)
-            o = o.transpose(0, 2, 1, 3).reshape(S, t, hidden)
-            x = x + (o @ p["out_w"] + p["out_b"])
-            h2 = ln(x, p["ln2_w"], p["ln2_b"])
-            m = jax.nn.gelu(h2 @ p["fc1_w"] + p["fc1_b"],
-                            approximate=True)
-            return x + (m @ p["fc2_w"] + p["fc2_b"]), (kcl, vcl)
-
-        x, (kc, vc) = lax.scan(body, x, (params["stacked"], kc, vc))
-        logits = ln(x, params["lnf_w"], params["lnf_b"]) \
-            @ params["head"]                       # [S, t, vocab]
-        out = jnp.argmax(logits, -1).astype(jnp.int32)   # [S, t]
-        return _accept(jnp, out, drafts, dlen, pos, kc, vc)
-
-    return spec_verify
 
 
 def build_paged_spec_verify_fn(cfg, num_slots, block_size, num_blocks,
                                blocks_per_slot, k):
-    """The paged-pool verify program (key ``("paged_spec_verify",)``):
-    same math, cache addressed through the fixed-shape block table
-    with candidate rows scattered straight into each slot's privately
-    owned blocks (decode positions are never inside shared-prefix
+    """The verify program for a GPT decode config (key
+    ``("paged_spec_verify",)``). Pure and shape-stable; the engine
+    AOT-compiles it ONCE alongside the plain decode. The cache is
+    addressed through the fixed-shape block table with candidate rows
+    scattered straight into each slot's privately owned blocks (decode positions are never inside shared-prefix
     blocks) and overflow rows trash-routed."""
     import jax
     import jax.numpy as jnp

@@ -462,15 +462,13 @@ class DeepseekV3ForCausalLM(nn.Layer):
         """Refuse, by name, an engine option this model has no program
         for (the engine calls this at construction)."""
         bad = [name for name, on in (
-            ("paged=False", not config.paged),
             ("speculative", config.speculative),
             (f"role={config.role!r}", config.role != "monolithic"),
         ) if on]
         if bad:
             raise ValueError(
-                f"DeepseekV3ForCausalLM is served on the paged path only "
-                f"(paged=True, greedy or sampling=True); no program for: "
-                f"{', '.join(bad)}")
+                f"DeepseekV3ForCausalLM is served greedy or with "
+                f"sampling=True; no program for: {', '.join(bad)}")
 
     def build_paged_serving_fns(self, num_slots, block_size, num_blocks,
                                 blocks_per_slot, sampling=False):
@@ -486,21 +484,11 @@ class DeepseekV3ForCausalLM(nn.Layer):
 
     def _no_program(self, what):
         raise NotImplementedError(
-            f"DeepseekV3ForCausalLM has no {what} program: only the "
-            f"paged path (ServingEngine(paged=True), greedy or "
-            f"sampling=True) is brought")
-
-    def build_serving_fns(self, *a, **k):
-        self._no_program("contiguous-cache (paged=False)")
-
-    def build_chunk_prefill_fn(self, *a, **k):
-        self._no_program("contiguous chunked-prefill")
-
-    def build_spec_verify_fn(self, *a, **k):
-        self._no_program("speculative verify")
+            f"DeepseekV3ForCausalLM has no {what} program: only "
+            f"prefill and decode (greedy or sampling=True) are brought")
 
     def build_paged_spec_verify_fn(self, *a, **k):
-        self._no_program("paged speculative verify")
+        self._no_program("speculative verify")
 
     # ------------------------------------------------------------ eager
     def forward(self, input_ids):

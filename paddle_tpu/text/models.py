@@ -438,167 +438,16 @@ class GPTForCausalLM(nn.Layer):
             cfg.hidden_size // cfg.num_heads,
             concrete(self.gpt.blocks[0].attn.qkv.weight.value).dtype)
 
-    def build_serving_fns(self, num_slots, cache_len, sampling=False):
-        """Slot-indexed cache programs for the continuous-batching
-        engine (paddle_tpu.serving), over a pooled cache
-        kc/vc [L, num_slots, nh, cache_len, hd]. Both programs thread
-        the engine's rolling device state (toks/pos [S]) through, so
-        consecutive steps chain entirely on device — the engine reads
-        token values back only AFTER dispatching the next step, and
-        the executables are built with kc/vc (and pos) donated so the
-        pooled cache updates in place on donating backends:
-
-          prefill(params, tokens [G, bucket], lengths [G], slots [G],
-                  toks [S], pos [S], kc, vc)
-              -> (first greedy tokens [G], toks', pos', kc, vc)
-              ONE dispatch prefills a whole same-bucket admission
-              group: the G claimed slot caches are gathered, the
-              shared forward_t runs batched over the group, and the
-              updated slices scatter back. The first tokens and next
-              write positions also scatter into toks/pos so the next
-              decode step consumes them with no host round-trip.
-              Prompts are right-padded to the bucket (causal masking
-              makes pad rows invisible to real rows, and decode's
-              length mask hides their stale K/V afterwards);
-
-          decode_step(params, toks [S], pos [S], kc, vc)
-              -> (next greedy tokens [S], pos + 1, kc, vc)
-              ONE fused program advancing every slot a token: per-slot
-              K/V writes at each slot's own position, attention under
-              the per-slot cache-length mask
-              (ops.attention.cached_slot_attention). Positions come
-              back incremented so decode chains into the next decode
-              device-side.
-
-        Both are pure and shape-stable; the engine AOT-compiles them
-        (decode once, prefill once per (bucket, group size)).
-
-        ``sampling=True`` threads per-slot sampling parameters
-        (serving.sched.sampling — seeds/temps/top-k/top-p arrays)
-        through both programs so temperature / top-k / top-p requests
-        share the one compiled dispatch with greedy ones; the default
-        keeps the original greedy-only signatures."""
-        import jax
-        import jax.numpy as jnp
-        from jax import lax
-
-        from ..ops import attention as attn_ops
-        from ..serving.sched.sampling import build_sampling_head
-
-        cfg = self.cfg
-        nh = cfg.num_heads
-        hd = cfg.hidden_size // nh
-        hidden = cfg.hidden_size
-        ln, forward_t = _decode_forward_builder(nh, hd, hidden)
-        head = build_sampling_head(cfg.vocab_size) if sampling else None
-
-        def _prefill_core(params, tokens, lengths, slots, toks, pos,
-                          kc, vc, samp):
-            # tokens [G, bucket]; lengths/slots [G]; toks/pos [S]
-            with jax.named_scope("kv_gather"):
-                kcs = jnp.take(kc, slots, axis=1)   # [L, G, nh, C, hd]
-                vcs = jnp.take(vc, slots, axis=1)
-            logits, kcs, vcs = forward_t(params, tokens, jnp.int32(0),
-                                         kcs, vcs)
-            with jax.named_scope("kv_write"):
-                kc = kc.at[:, slots].set(kcs)
-                vc = vc.at[:, slots].set(vcs)
-            with jax.named_scope("sample"):
-                last = jnp.take_along_axis(
-                    logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
-                if samp is None:
-                    first = jnp.argmax(last, -1).astype(jnp.int32)  # [G]
-                else:
-                    seeds, temps, topks, topps = samp
-                    first = head(last, seeds, lengths - 1, temps, topks,
-                                 topps)
-                toks = toks.at[slots].set(first)
-                # the next decode writes each group member at position
-                # lengths[g] (its first generated token's cache row)
-                pos = pos.at[slots].set(lengths)
-            return first, toks, pos, kc, vc
-
-        if sampling:
-            def prefill(params, tokens, lengths, slots, toks, pos, kc,
-                        vc, seeds, temps, topks, topps):
-                return _prefill_core(params, tokens, lengths, slots,
-                                     toks, pos, kc, vc,
-                                     (seeds, temps, topks, topps))
-        else:
-            def prefill(params, tokens, lengths, slots, toks, pos, kc,
-                        vc):
-                return _prefill_core(params, tokens, lengths, slots,
-                                     toks, pos, kc, vc, None)
-
-        def write_slot(cache_l, new, pos):
-            # cache_l [S, nh, C, hd], new [S, nh, hd]: each slot writes
-            # its own row at its own position
-            return jax.vmap(
-                lambda c, n, p: lax.dynamic_update_slice(
-                    c, n[:, None], (jnp.int32(0), p, jnp.int32(0))))(
-                    cache_l, new, pos)
-
-        def _decode_core(params, toks, pos, kc, vc, samp):
-            S = toks.shape[0]
-            # parked / idle slots' positions keep incrementing past
-            # the table; clamp so the (ignored) row reads in-bounds
-            with jax.named_scope("embed"):
-                x = params["wemb"][toks] + params["pemb"][
-                    jnp.minimum(pos, params["pemb"].shape[0] - 1)]
-
-            def body(carry, inp):
-                x = carry
-                p, kcl, vcl = inp
-                with jax.named_scope("attn"):
-                    h_ = ln(x, p["ln1_w"], p["ln1_b"])
-                    qkv = h_ @ p["qkv_w"] + p["qkv_b"]
-                    qkv = qkv.reshape(S, 3, nh, hd).transpose(1, 0, 2, 3)
-                    q, k, v = qkv[0], qkv[1], qkv[2]      # [S, nh, hd]
-                    with jax.named_scope("kv_write"):
-                        kcl = write_slot(kcl, k, pos)
-                        vcl = write_slot(vcl, v, pos)
-                    o = attn_ops.cached_slot_attention(q, kcl, vcl,
-                                                       pos + 1)
-                    o = o.reshape(S, hidden)              # concat heads
-                    x = x + (o @ p["out_w"] + p["out_b"])
-                with jax.named_scope("mlp"):
-                    h2 = ln(x, p["ln2_w"], p["ln2_b"])
-                    m = jax.nn.gelu(h2 @ p["fc1_w"] + p["fc1_b"],
-                                    approximate=True)
-                    return x + (m @ p["fc2_w"] + p["fc2_b"]), (kcl, vcl)
-
-            x, (kc, vc) = lax.scan(body, x,
-                                   (params["stacked"], kc, vc))
-            with jax.named_scope("lm_head"):
-                logits = ln(x, params["lnf_w"], params["lnf_b"]) \
-                    @ params["head"]                      # [S, vocab]
-            with jax.named_scope("sample"):
-                if samp is None:
-                    nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-                else:
-                    seeds, temps, topks, topps = samp
-                    nxt = head(logits, seeds, pos, temps, topks, topps)
-            return nxt, pos + jnp.int32(1), kc, vc
-
-        if sampling:
-            def decode_step(params, toks, pos, kc, vc, seeds, temps,
-                            topks, topps):
-                return _decode_core(params, toks, pos, kc, vc,
-                                    (seeds, temps, topks, topps))
-        else:
-            def decode_step(params, toks, pos, kc, vc):
-                return _decode_core(params, toks, pos, kc, vc, None)
-
-        return prefill, decode_step
-
     def build_paged_serving_fns(self, num_slots, block_size, num_blocks,
                                 blocks_per_slot, sampling=False,
                                 attn_kernel=False):
-        """Paged-cache analogues of build_serving_fns for the
-        block-granular KV pool (serving.paged): same decode math via
-        the shared _decode_forward_builder, cache addressed through a
-        fixed-shape block table so shared-prefix blocks are reused
-        instead of re-prefilled —
+        """The continuous-batching engine's prefill and decode
+        programs over the block-granular KV pool (serving.paged): the
+        decode math of generate() via the shared
+        _decode_forward_builder, cache addressed through a fixed-shape
+        block table so shared-prefix blocks are reused instead of
+        re-prefilled. Both thread the engine's rolling device state
+        (toks/pos [S]) through, so consecutive steps chain on device —
 
           paged_prefill(params, tokens [1, B], tail_len, start, slot,
                         final, bt_row [MB], toks [S], pos [S], kc, vc)
@@ -622,39 +471,22 @@ class GPTForCausalLM(nn.Layer):
                                sampling=sampling,
                                attn_kernel=attn_kernel)
 
-    def build_spec_verify_fn(self, num_slots, cache_len, spec_k):
-        """The speculative k-token verify program over the
-        slot-contiguous pool (serving.spec.programs): one fixed-shape
-        ``[S, k+1]``-position dispatch verifying each slot's k drafted
-        continuations against the model's own greedy choices —
-        longest-accepted-prefix on device, bit-exact with plain
-        decode by construction (ServingConfig(speculative=True))."""
-        from ..serving.spec.programs import build_spec_verify_fn
-        return build_spec_verify_fn(self.cfg, num_slots, cache_len,
-                                    spec_k)
-
     def build_paged_spec_verify_fn(self, num_slots, block_size,
                                    num_blocks, blocks_per_slot,
                                    spec_k):
-        """Paged-pool analogue of build_spec_verify_fn: candidate K/V
-        rows scatter straight into each slot's privately-owned blocks
-        under PR 7's whole-position clamp (overflow rows trash-routed),
-        attention through the gathered block-table view."""
+        """The speculative k-token verify program
+        (serving.spec.programs, ServingConfig(speculative=True)): one
+        fixed-shape ``[S, k+1]``-position dispatch verifying each
+        slot's k drafted continuations against the model's own greedy
+        choices — longest-accepted-prefix on device, bit-exact with
+        plain decode by construction. Candidate K/V rows scatter
+        straight into each slot's privately-owned blocks under PR 7's
+        whole-position clamp (overflow rows trash-routed), attention
+        through the gathered block-table view."""
         from ..serving.spec.programs import build_paged_spec_verify_fn
         return build_paged_spec_verify_fn(
             self.cfg, num_slots, block_size, num_blocks,
             blocks_per_slot, spec_k)
-
-    def build_chunk_prefill_fn(self, cache_len, sampling=False):
-        """The chunked-prefill program over the slot-contiguous pool
-        (serving.sched.programs.build_chunk_fns): one fixed-width
-        ``[1, chunk]`` dispatch per chunk with traced start / length /
-        slot / final scalars, so ANY prompt-length mix reuses one
-        compiled program per chunk width — the program that lets a
-        long prompt interleave with decode steps instead of stalling
-        them (ServingConfig(prefill_chunk=...))."""
-        from ..serving.sched.programs import build_chunk_fns
-        return build_chunk_fns(self.cfg, cache_len, sampling=sampling)
 
     _DECODE_CACHE_MAX = 16
 

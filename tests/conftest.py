@@ -28,6 +28,21 @@ def _seed_everything():
     yield
 
 
+@pytest.fixture(params=["gather", "kernel-interpret"])
+def decode_attention(request):
+    """Both decode attentions a ServingEngine can choose for a GPT:
+    the XLA gather (the CPU's own choice) and the Pallas paged kernel,
+    interpreted. The engine asks ``kernel_viable`` when it is built
+    and the kernel is lowered when the decode program first compiles,
+    so the switch holds for the whole test. ``engine.decode_layout``
+    says which one a test got."""
+    from paddle_tpu.ops import paged_attention as pa
+    pa._FORCE_INTERPRET[0] = request.param == "kernel-interpret"
+    yield {"gather": "paged_xla",
+           "kernel-interpret": "paged_pallas"}[request.param]
+    pa._FORCE_INTERPRET[0] = False
+
+
 def make_traced_train_step(net, opt, loss_fn):
     """jax-jittable closure running one REAL paddle train step (model +
     optimizer via the op registry) under a TraceContext — shared by the

@@ -40,31 +40,21 @@ def main():
     m = GPTForCausalLM(cfg)
     m.eval()
     # ROUTER_ROLE stamps this replica into a disaggregated tier
-    # (prefill|decode|monolithic). Non-monolithic roles require the
-    # paged pool (the KV wire unit is the paged block)
+    # (prefill|decode|monolithic)
     role = os.environ.get("ROUTER_ROLE", "monolithic")
-    paged = (role != "monolithic"
-             or os.environ.get("ROUTER_PAGED", "0") == "1")
     eng = ServingEngine(
-        m, num_slots=2, bucket_min=8, paged=paged, role=role,
+        m, num_slots=2, bucket_min=8, role=role,
         replica_id=os.environ.get("ROUTER_REPLICA_ID"),
         slo_ttft_ms=60000.0)
     gateway = EngineGateway(eng)
-    # warm the compile inventory BEFORE declaring ready — group-1 and
-    # group-2 prefill shapes plus decode, so the drill's steady-state
+    # warm the compile inventory BEFORE declaring ready — the
+    # drill's prefill bucket plus decode, so its steady-state
     # compile audit sees zero compiles under traffic
     rs = np.random.RandomState(0)
     solo = gateway.submit(rs.randint(0, 97, (5,)).astype(np.int64),
                           max_new_tokens=4)
     gateway.wait(solo, timeout=120.0)
-    with gateway._lock:   # both enqueued before the driver steps ->
-        # they admit as ONE group-2 prefill (the shape warmed here)
-        pair = [gateway.submit(
-            rs.randint(0, 97, (6,)).astype(np.int64),
-            max_new_tokens=4) for _ in range(2)]
-    for req in pair:
-        gateway.wait(req, timeout=120.0)
-    if eng.paged:
+    if role != "monolithic":
         # warm the KV export/import programs too: the disagg drill's
         # steady-state compile audit covers handoff traffic
         with gateway._lock:

@@ -349,9 +349,9 @@ def _engine(**kw):
 def _donation_findings(eng, backend_aliases, min_bytes=1 << 14):
     """engine.lint's exact donation feed, with the backend aliasing
     behavior overridden so CPU CI can exercise the aliasing branch."""
-    args = (eng.params, eng._toks, eng._pos, eng.pool.kc, eng.pool.vc)
+    args, donate = eng._decode_dispatch_args(eng.pool)
     closed = jax.make_jaxpr(eng._decode_fn)(*args)
-    donate = (2, 3, 4) if eng._donate else ()
+    donate = donate if eng._donate else ()
     return lint_jaxpr(
         closed, passes=["donation"],
         donated_invars=donated_invars_from_argnums(args, donate),
@@ -439,7 +439,7 @@ def test_lint_graft_self_lints_repo_clean():
     report = json.loads(res.stdout)
     assert report["ok"] is True
     assert report["counts"]["error"] == 0
-    assert set(report["targets"]) == {"serving_decode", "paged_decode",
+    assert set(report["targets"]) == {"serving_decode",
                                       "paged_decode_pallas",
                                       "chunked_prefill", "spec_verify",
                                       "kv_wire", "hapi_train_step",

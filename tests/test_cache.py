@@ -68,9 +68,7 @@ def test_sampled_rate_tracks_oracle_within_tolerance():
     traffic (hot stems / warm / cold tail — the shape the prefix
     cache sees, with enough distinct paths that the spatial sample is
     representative) it stays within a few points of the oracle at
-    every evaluated capacity. (The estimator's predicted hit rate is
-    also re-checked against LIVE traffic in the bench artifact, see
-    tests/test_bench_contract.py.)"""
+    every evaluated capacity."""
     def tiered(rs, n):
         out = []
         for _ in range(n):

@@ -208,17 +208,18 @@ def test_patrol_lint_pass_registered_and_inert():
 
 
 def test_patrol_real_drain_clean_and_overhead_bounded():
-    """The real engine drain produces zero patrol findings on both KV
-    pools, and the armed per-acquire cost — probe-measured inside the
+    """The real engine drain produces zero patrol findings at two
+    block sizes, and the armed per-acquire cost — probe-measured inside the
     armed window, times the drain's own acquire rate — stays under 2%
     of the measured step wall (the PR-8 health-tick contract style:
     micro-measured so CI wall noise can't flake it)."""
     m = _model()
     rs = np.random.RandomState(0)
     specs = [(5, 6), (9, 4), (12, 5)]
-    for paged in (False, True):
+    for block_size in (4, 16):
         with analysis.lock_patrol() as patrol:
-            eng = ServingEngine(m, num_slots=2, bucket_min=8, paged=paged)
+            eng = ServingEngine(m, num_slots=2, bucket_min=8,
+                                block_size=block_size)
             for n, k in specs:
                 eng.add_request(rs.randint(0, 97, (n,)).astype(np.int64),
                                 max_new_tokens=k)

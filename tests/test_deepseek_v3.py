@@ -153,7 +153,7 @@ def test_paged_prefill_and_decode_match_reference(model_w, chunk):
     is the reference's best at its position, by the logit gap that
     ``correct`` reads on the chip."""
     m, w = model_w
-    eng = ServingEngine(m, num_slots=3, paged=True, block_size=8,
+    eng = ServingEngine(m, num_slots=3, block_size=8,
                         max_len=64, buckets=[16, 32],
                         prefill_chunk=chunk)
     rng = np.random.default_rng(1)
@@ -188,7 +188,7 @@ def test_sampling_program_runs_and_repeats(model_w):
     m, _ = model_w
 
     def once():
-        eng = ServingEngine(m, num_slots=2, paged=True, block_size=8,
+        eng = ServingEngine(m, num_slots=2, block_size=8,
                             max_len=64, buckets=[16], sampling=True)
         r = eng.add_request(np.arange(7), max_new_tokens=6,
                             temperature=0.8, top_k=20, seed=5)
@@ -199,9 +199,8 @@ def test_sampling_program_runs_and_repeats(model_w):
 
 
 @pytest.mark.parametrize("option", [
-    {"paged": False}, {"paged": True, "speculative": True},
-    {"paged": True, "role": "prefill"}],
-    ids=["contiguous", "speculative", "role"])
+    {"speculative": True}, {"role": "prefill"}],
+    ids=["speculative", "role"])
 def test_engine_refuses_an_option_without_a_program(model_w, option):
     with pytest.raises(ValueError, match="no program for"):
         ServingEngine(model_w[0], num_slots=2, **option)
@@ -219,7 +218,7 @@ def test_engine_never_hands_the_latent_model_the_gpt_kernel(model_w):
         model_w[0].build_paged_serving_fns).parameters
     pa._FORCE_INTERPRET[0] = True
     try:
-        eng = ServingEngine(model_w[0], num_slots=2, paged=True,
+        eng = ServingEngine(model_w[0], num_slots=2,
                             block_size=8, max_len=64, buckets=[16])
     finally:
         pa._FORCE_INTERPRET[0] = False
@@ -352,7 +351,7 @@ def test_engine_with_kernels_in_interpret_mode(interpret, model_w):
         dict(HF, hidden_size=128, moe_intermediate_size=128,
              kv_lora_rank=128), initializer_range=0.2)
     m = ds.DeepseekV3ForCausalLM(cfg, seed=1)
-    eng = ServingEngine(m, num_slots=8, paged=True, block_size=8,
+    eng = ServingEngine(m, num_slots=8, block_size=8,
                         max_len=32, buckets=[16])
     p = np.arange(9) % 96
     (r,) = _drive(eng, [p], [5])
@@ -383,7 +382,7 @@ def test_pool_from_gpt_cache_spec_is_todays_pool():
                               num_heads=2, max_seq_len=32, dropout=0.0)
     gpt = GPTForCausalLM(cfg)
     gpt.eval()
-    eng = ServingEngine(gpt, num_slots=2, paged=True, block_size=8)
+    eng = ServingEngine(gpt, num_slots=2, block_size=8)
     assert [a.name for a in eng.cache_spec.arrays] == ["k", "v"]
     args, donate = eng._decode_dispatch_args(eng.pool)
     assert donate == (2, 4, 5) and len(args) == 6

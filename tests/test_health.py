@@ -53,7 +53,8 @@ def _row(step, **kw):
     base = {
         "step": int(step), "t": float(step), "wall_s": 0.01,
         "dispatch_s": 0.004, "sync_s": 0.003, "queue_depth": 0,
-        "queue_age_s": 0.0, "occupied_slots": 2, "chunked_inflight": 0,
+        "queue_age_s": 0.0, "occupied_slots": 2, "held_exports": 0,
+        "chunked_inflight": 0,
         "admitted": 0, "tokens": 2, "completed": 0,
         "goodput_tokens": 0, "prefill_tokens": 0, "prefill_chunks": 0,
         "shed": 0, "deprioritized": 0, "new_compiles": 0,
@@ -182,6 +183,46 @@ def test_queue_stall_fires_once_and_rearms_on_progress():
     assert _feed(det3, chunking) == []
 
 
+def test_queue_behind_parked_exports_is_not_a_stall():
+    """A prefill tier whose slots are all parked for export_kv waits
+    for the router, and steps idle meanwhile as fast as the host
+    allows: no verdict, no supervisor restart (which would wipe the
+    parked blocks), however many steps the round trip lasts. The
+    export frees the slot and the queue moves; a queue that then does
+    not move is still a stall."""
+    det = QueueStall(stall_steps=5)
+    parked = [_row(i + 1, queue_depth=1, tokens=0, occupied_slots=2,
+                   held_exports=2) for i in range(40)]
+    assert _feed(det, parked) == []
+    wedged = [_row(41 + i, queue_depth=1, tokens=0, occupied_slots=0)
+              for i in range(5)]
+    assert len(_feed(det, wedged)) == 1
+
+    m = _model()
+    eng = ServingEngine(m, num_slots=1, bucket_min=8, role="prefill",
+                        health_detectors={"queue_stall":
+                                          {"stall_steps": 4}})
+    rs = np.random.RandomState(2)
+    p1, p2 = (rs.randint(0, 97, (n,)).astype(np.int64) for n in (5, 6))
+    r1 = eng.add_request(p1, max_new_tokens=1, hold_kv=True)
+    r2 = eng.add_request(p2, max_new_tokens=1, hold_kv=True)
+    for _ in range(40):
+        eng.step()
+    assert r1.done and not r2.done and r2.slot is None
+    health = eng.metrics.snapshot()["health"]
+    assert health["anomalies_total"] == 0 and health["healthy"]
+    assert eng.supervisor.restarts == 0
+    assert eng.health.ledger.last()["held_exports"] == 1
+    payload = eng.export_kv(r1.rid)
+    assert payload["frames"]
+    for _ in range(6):
+        eng.step()
+    assert r2.done
+    eng.export_kv(r2.rid)
+    eng.pool.check_conservation()
+    eng.close()
+
+
 def test_goodput_collapse_fires_on_cliff_not_gradual_decline():
     def run(rates):
         det = GoodputCollapse(window=16, drop_frac=0.1,
@@ -236,8 +277,8 @@ def test_kv_block_leak_fires_on_audit_failure_and_idle_refs():
     assert len(fired) == 1                     # once per episode
     assert fired[0]["live_blocks"] == 3
 
-    # healthy: idle with everything free/evictable, and legacy pools
-    # (None fields) are inert
+    # healthy: idle with everything free/evictable, and rows
+    # without pool facts (None fields) are inert
     det3 = KVBlockLeak()
     ok = [_row(1, occupied_slots=0, tokens=0, pool_live_blocks=0,
                pool_free_blocks=8, pool_evictable_blocks=2),
@@ -376,7 +417,7 @@ def test_engine_forced_queue_stall_end_to_end(tmp_path):
     eng.add_request(np.arange(5, dtype=np.int64) % 97,
                     max_new_tokens=3)
     # induced fault: admission never admits, queue never drains
-    eng.scheduler.admit_chunked = lambda *a, **k: ([], [])
+    eng.scheduler.admit_paged = lambda *a, **k: None
     for _ in range(8):
         eng.step()
     # 1) the firing counter is in /metrics
@@ -451,13 +492,12 @@ def test_engine_induced_steady_compile_is_an_anomaly():
 
 
 def test_engine_clean_runs_fire_nothing():
-    """No false positives: plain, paged and chunked clean drains all
-    stay healthy with zero anomalies (the observatory is ON by
+    """No false positives: plain, small-block and chunked clean
+    drains all stay healthy with zero anomalies (the observatory is ON by
     default)."""
     m = _model()
     rs = np.random.RandomState(11)
-    for kw in ({}, {"paged": True, "block_size": 8,
-                    "health_audit_every": 2},
+    for kw in ({}, {"block_size": 8, "health_audit_every": 2},
                {"prefill_chunk": 8, "slo_ttft_ms": 5000.0}):
         eng = ServingEngine(m, num_slots=2, bucket_min=8, **kw)
         for wave in range(2):
@@ -476,7 +516,7 @@ def test_engine_health_audit_cadence_and_span():
     conservation audit; its cost is a visible serving/health_audit
     host span and its verdict lands on the audited rows."""
     m = _model()
-    eng = ServingEngine(m, num_slots=2, bucket_min=8, paged=True,
+    eng = ServingEngine(m, num_slots=2, bucket_min=8,
                         block_size=8, health_audit_every=2)
     rs = np.random.RandomState(4)
     for n, k in [(5, 4), (9, 4), (6, 3)]:

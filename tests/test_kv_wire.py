@@ -100,7 +100,7 @@ def _model(seed=11):
 def _engine(role="monolithic", **kw):
     kw.setdefault("num_slots", 4)
     kw.setdefault("bucket_min", 8)
-    return ServingEngine(_model(), paged=True, role=role, **kw)
+    return ServingEngine(_model(), role=role, **kw)
 
 
 def _pool_empty(eng):

@@ -490,7 +490,7 @@ _PERF_PROGRAM_KEYS = {
     "bound",
 }
 # the PR-13 cache observatory section: MRC + heat + savings + churn
-# (same key set whether the observatory has a paged pool or not)
+# (same key set whether the observatory is on or off)
 _CACHE_KEYS = {
     "enabled", "accesses", "hits", "hit_rate", "capacity_blocks",
     "sampled", "mrc", "heat", "savings", "churn",
@@ -590,14 +590,15 @@ def test_serving_snapshot_schema_contract(monkeypatch):
     assert rep["uptime_s"] > 0
     assert health["replica_id"] == rep["replica_id"]
     assert health["uptime_s"] > 0
-    # the PR-13 cache observatory section: a legacy (non-paged) pool
-    # has no block economy to observe -> the disabled shape, same keys
+    # the PR-13 cache observatory section: on by default, the three
+    # admissions counted even though no prompt here fills a block
     cache = snap["cache"]
     assert set(cache) == _CACHE_KEYS
-    assert cache["enabled"] is False and cache["mrc"] is None
-    # a paged engine reports live: schema, factor-stamped MRC, and
-    # cache_observatory=False degrades to the same disabled shape
-    eng_paged = ServingEngine(m, num_slots=2, bucket_min=8, paged=True,
+    assert cache["enabled"] is True and cache["capacity_blocks"] > 0
+    assert cache["hits"] == 0
+    # with blocks the prompts fill: factor-stamped MRC, and
+    # cache_observatory=False degrades to the disabled shape
+    eng_paged = ServingEngine(m, num_slots=2, bucket_min=8,
                               block_size=8)
     _drive(eng_paged, np.random.RandomState(1), [(9, 3), (9, 3)])
     live = eng_paged.metrics.snapshot()["cache"]
@@ -608,7 +609,7 @@ def test_serving_snapshot_schema_contract(monkeypatch):
     assert set(live["churn"]) == {"evictions", "thrash_reinserts",
                                   "block_lifetime_ms"}
     eng_nocache = ServingEngine(m, num_slots=2, bucket_min=8,
-                                paged=True, block_size=8,
+                                block_size=8,
                                 cache_observatory=False)
     _drive(eng_nocache, np.random.RandomState(1), [(9, 3)])
     off_cache = eng_nocache.metrics.snapshot()["cache"]
@@ -692,13 +693,13 @@ def test_engine_watchdog_zero_steady_state_and_induced_drift():
     _drive(eng, rs, wave)                  # steady state: same traffic
     rep = eng.watchdog.report()
     assert rep["warmed"] and rep["steady_state_compiles"] == 0
-    # induced drift: a prompt in a (bucket, group) never compiled
+    # induced drift: a prompt in a bucket never compiled
     _drive(eng, rs, [(20, 3)])
     rep = eng.watchdog.report()
     assert rep["steady_state_compiles"] == 1
     viol = rep["steady_state_events"][0]
     assert "engine.py" in viol["call_site"]        # attributed
-    assert viol["key"].startswith("('prefill'")
+    assert viol["key"] == "('paged_prefill', 32)"
     assert "#" in viol["signature"]                # shape digest present
     assert eng.metrics.compiles == warm + 1        # counter agrees
 
@@ -744,11 +745,11 @@ def test_engine_serve_metrics_http():
             f"http://127.0.0.1:{port}/debug/perf", timeout=10).read())
         assert perf["enabled"] is True
         assert "decode" in perf["programs"]
-        # /debug/cache: the cache observatory body (disabled shape on
-        # this legacy-pool engine, but the route and schema hold)
+        # /debug/cache: the cache observatory body, live
         cache = json.loads(urllib.request.urlopen(
             f"http://127.0.0.1:{port}/debug/cache", timeout=10).read())
-        assert cache["enabled"] is False and "churn" in cache
+        assert cache["enabled"] is True and "churn" in cache
+        assert cache["capacity_blocks"] == eng.pool.num_blocks - 1
     finally:
         server.shutdown()
 

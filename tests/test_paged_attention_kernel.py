@@ -255,7 +255,7 @@ def test_guard_resolution(monkeypatch):
     assert "paged_attn" not in inspect.signature(
         ServingConfig.__init__).parameters
     with pytest.raises(TypeError):
-        ServingConfig(paged=True, paged_attn=True)
+        ServingConfig(paged_attn=True)
 
 
 def test_cached_attention_scores_accumulate_f32():
@@ -315,7 +315,7 @@ def test_engine_kernel_greedy_parity_zero_compiles(interpret_kernel,
     monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "197e12")
     monkeypatch.setenv("PADDLE_TPU_HBM_BPS", "819e9")
     m = _tiny_model()
-    eng = ServingEngine(m, num_slots=4, bucket_min=8, paged=True,
+    eng = ServingEngine(m, num_slots=4, bucket_min=8,
                         block_size=8, async_depth=async_depth,
                         watchdog_mode="raise")
     assert eng.paged_attn and eng.decode_layout == "paged_pallas"
@@ -339,7 +339,6 @@ def test_engine_kernel_greedy_parity_zero_compiles(interpret_kernel,
     model = rep["decode_roofline"]["model"]
     assert model["layout"] == "paged_pallas"
     assert model["gather_factor"] == 1.0
-    assert model["paged"] is True
     assert rep["decode_roofline"]["achieved_fraction"] is not None
     assert rep["programs"]["decode"]["roofline_fraction"] is not None
     state = eng.debug_state()
@@ -348,20 +347,18 @@ def test_engine_kernel_greedy_parity_zero_compiles(interpret_kernel,
 
 
 def test_engine_keeps_gather_where_guard_refuses():
-    """On CPU tier-1 the guard refuses (no Mosaic), so a paged engine
-    is built on the XLA gather path and says so; the legacy pool is
-    contiguous. Layout honesty either way."""
+    """On CPU tier-1 the guard refuses (no Mosaic), so the engine
+    is built on the XLA gather path and says so, in its state and in
+    what its roofline prices."""
     m = _tiny_model()
-    eng = ServingEngine(m, num_slots=2, bucket_min=8, paged=True,
+    eng = ServingEngine(m, num_slots=2, bucket_min=8,
                         block_size=8)
     assert not eng.paged_attn
     assert eng.decode_layout == "paged_xla"
     assert eng.debug_state()["paged_attn"] is False
-    legacy = ServingEngine(m, num_slots=2, bucket_min=8)
-    assert legacy.decode_layout == "contiguous"
-    model = legacy.metrics.perf_report()["decode_roofline"]["model"]
-    assert model["layout"] == "contiguous"
-
+    model = eng.metrics.perf_report()["decode_roofline"]["model"]
+    assert model["layout"] == "paged_xla"
+    assert model["gather_factor"] == rf.PAGED_GATHER_FACTOR
 
 def test_released_slot_costs_the_kernel_nothing(interpret_kernel):
     """A released slot's position keeps counting while its table row is
@@ -406,33 +403,33 @@ def test_released_slot_costs_the_kernel_nothing(interpret_kernel):
 def test_roofline_paged_pallas_layout():
     """Roofline honesty: paged_pallas prices gather factor 1.0 and no
     max-len over-read (live_kv_len caps the read), paged_xla keeps
-    the 3x factor, and the bool ``paged=`` back-compat still maps to
-    paged_xla."""
-    base = rf.kv_read_bytes_per_token(1024, 12, 12, 64)
+    the 3x factor and is the default, and a layout the model does
+    not price (the slot pool's "contiguous" among them) is refused."""
+    base = 2 * 12 * 12 * 64 * 1024 * 2
     assert rf.kv_read_bytes_per_token(
         1024, 12, 12, 64, layout="paged_xla") == \
         rf.PAGED_GATHER_FACTOR * base
     assert rf.kv_read_bytes_per_token(
         1024, 12, 12, 64, layout="paged_pallas") == base
     assert rf.kv_read_bytes_per_token(
-        1024, 12, 12, 64, paged=True) == rf.PAGED_GATHER_FACTOR * base
-    with pytest.raises(ValueError):
-        rf.resolve_layout(layout="paged_mosaic")
+        1024, 12, 12, 64) == rf.PAGED_GATHER_FACTOR * base
+    for unknown in ("paged_mosaic", "contiguous", None):
+        with pytest.raises(ValueError, match="unknown KV layout"):
+            rf.resolve_layout(unknown)
     kw = dict(batch=8, kv_len=1024, num_layers=12, num_heads=12,
               head_dim=64, n_params=124e6, peak_flops=197e12,
               hbm_bps=819e9)
     xla = rf.decode_step_model(layout="paged_xla", **kw)
     pallas = rf.decode_step_model(layout="paged_pallas",
                                   live_kv_len=256, **kw)
-    cont = rf.decode_step_model(**kw)
-    assert xla["layout"] == "paged_xla" and xla["paged"] is True
+    whole = rf.decode_step_model(layout="paged_pallas", **kw)
+    assert xla == rf.decode_step_model(**kw)
+    assert xla["layout"] == "paged_xla"
     assert pallas["layout"] == "paged_pallas"
-    assert pallas["paged"] is True        # still a paged POOL
-    assert cont["paged"] is False
     assert pallas["gather_factor"] == 1.0
     assert pallas["kv_len_read"] == 256   # no max-len over-read
     assert xla["kv_len_read"] == 1024     # over-read is xla's price
-    assert pallas["bytes_total"] < cont["bytes_total"] \
+    assert pallas["bytes_total"] < whole["bytes_total"] \
         < xla["bytes_total"]
     assert pallas["floor_s"] < xla["floor_s"]
 

@@ -93,6 +93,62 @@ def test_pool_acquire_pins_prefix_and_allocates_tail():
     pool.check_conservation()
 
 
+def test_pool_slots_are_lowest_free_first():
+    """Slot determinism: whatever the release order, acquisition
+    always hands out the lowest free slot; a full pool refuses."""
+    pool = _pool()
+    p = np.arange(4)
+    slots = [pool.acquire(i, p + 10 * i, 8, 0).slot for i in range(4)]
+    assert slots == [0, 1, 2, 3]
+    assert pool.acquire(99, p, 8, 0) is None
+    for s in (3, 1, 2):
+        pool.release(s)
+    assert [pool.acquire(10 + i, p, 8, 0).slot for i in range(3)] \
+        == [1, 2, 3]
+    assert pool.reuse_count == 3
+    pool.check_conservation()
+
+
+def test_pool_slot_acquire_release_fuzz():
+    """Admit-when-full churn fuzz: across random acquire/release
+    traffic the free set and the owned set always partition the
+    slots, acquisition is always the minimum free slot, acquire on a
+    full pool is None, double-release raises, and a quarantined slot
+    is in neither set until it is handed back."""
+    pool = _pool()
+    rs = np.random.RandomState(9)
+    live = set()
+    for i in range(300):
+        if live and (pool.free_count == 0 or rs.rand() < 0.45):
+            slot = int(rs.choice(sorted(live)))
+            pool.release(slot)
+            live.discard(slot)
+            with pytest.raises(ValueError):
+                pool.release(slot)
+        else:
+            free_before = set(pool._free_slots)
+            alloc = pool.acquire(i, rs.randint(0, 50, 6), 10, 0)
+            assert alloc.slot == min(free_before)
+            assert pool.owner_of(alloc.slot) == i
+            live.add(alloc.slot)
+        free = set(pool._free_slots)
+        assert free | live == {0, 1, 2, 3} and not free & live
+        assert pool.free_count + len(live) == 4
+        assert pool.occupancy == len(live) / 4
+        if pool.free_count == 0:
+            assert pool.acquire(-1, np.arange(4), 8, 0) is None
+    assert pool.reuse_count >= 50
+    pool.check_conservation()
+    for slot in sorted(live):
+        pool.release(slot)
+    with pytest.raises(ValueError):       # live slots cannot be set aside
+        pool.quarantine(pool.acquire(0, np.arange(4), 8, 0).slot)
+    pool.quarantine(3)
+    assert pool.quarantined == [3] and 3 not in pool._free_slots
+    pool.unquarantine_all()
+    assert pool.quarantined == [] and pool.free_count == 3
+
+
 def test_pool_capacity_refusal_and_trash_reset():
     pool = _pool(num_slots=2, max_len=16, block_size=4, num_blocks=5)
     # 4 usable blocks (block 0 is trash): one 16-token request fills

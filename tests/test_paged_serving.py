@@ -1,9 +1,9 @@
-"""Paged serving engine (ServingConfig(paged=True)): exact greedy
+"""Serving engine over its paged pool: exact greedy
 parity with per-request generate() under shared-prefix traffic, tail-
 only prefill for cache hits (flight-recorder + counter evidence — the
 ISSUE 6 acceptance contract), zero steady-state recompiles with paging
 enabled (watchdog-verified), eviction under block pressure, and the
-leak-free dispatch-failure rollback on both pool flavors."""
+leak-free dispatch-failure rollback."""
 import numpy as np
 import pytest
 
@@ -35,7 +35,7 @@ def test_paged_matches_generate_shared_and_disjoint_prompts():
     arrivals, more requests than slots (slot AND block recycling) —
     every output exactly equals batch-1 generate()."""
     m = _model()
-    eng = ServingEngine(m, num_slots=3, bucket_min=8, paged=True,
+    eng = ServingEngine(m, num_slots=3, bucket_min=8,
                         block_size=4)
     rs = np.random.RandomState(0)
     stem = rs.randint(0, 97, (16,)).astype(np.int64)
@@ -63,7 +63,7 @@ def test_second_request_prefills_only_the_tail():
     via flight-recorder events AND the prefix_cache hit counters, with
     exact greedy parity against non-paged generate()."""
     m = _model()
-    eng = ServingEngine(m, num_slots=2, bucket_min=8, paged=True,
+    eng = ServingEngine(m, num_slots=2, bucket_min=8,
                         block_size=4)
     rs = np.random.RandomState(3)
     N = 24                                     # shared, block-aligned
@@ -106,7 +106,7 @@ def test_paged_zero_steady_state_recompiles():
     (watchdog-verified) and the whole inventory is bounded by
     len(buckets) + 1 — prefix-length variety is traced, not compiled."""
     m = _model()
-    eng = ServingEngine(m, num_slots=2, bucket_min=8, paged=True,
+    eng = ServingEngine(m, num_slots=2, bucket_min=8,
                         block_size=4, watchdog_mode="raise")
     rs = np.random.RandomState(2)
     stem = rs.randint(0, 97, (12,)).astype(np.int64)
@@ -133,7 +133,7 @@ def test_paged_parity_under_block_pressure_with_eviction():
     to generate() throughout."""
     m = _model()
     # 2 slots, 16 blocks of 4 = tight for 64-token slot capacity
-    eng = ServingEngine(m, num_slots=2, bucket_min=8, paged=True,
+    eng = ServingEngine(m, num_slots=2, bucket_min=8,
                         block_size=4, num_blocks=17, max_len=32)
     rs = np.random.RandomState(5)
     prompts = [rs.randint(0, 97, (n,)).astype(np.int64)
@@ -154,7 +154,7 @@ def test_paged_sync_mode_matches_pipelined():
                                .astype(np.int64)]) for k in (3, 6, 2)]
     outs = []
     for depth in (1, 0):
-        eng = ServingEngine(m, num_slots=2, bucket_min=8, paged=True,
+        eng = ServingEngine(m, num_slots=2, bucket_min=8,
                             block_size=4, async_depth=depth)
         rr = [eng.add_request(p, max_new_tokens=5) for p in prompts]
         eng.run()
@@ -182,21 +182,22 @@ def test_plan_prefix_respects_tail_and_capacity():
     assert start == 0 and bucket == 32
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_failed_prefill_dispatch_leaks_no_slot(paged):
+@pytest.mark.parametrize("block_size", [4, 16])
+def test_failed_prefill_dispatch_leaks_no_slot(block_size):
     """Satellite regression: a prefill dispatch failure between
-    acquire and admission completion must release the slot (and, for
-    the paged pool, every pinned/allocated block), requeue the request,
-    and leave the engine able to serve it once the fault clears."""
+    acquire and admission completion must release the slot and every
+    pinned/allocated block, requeue the request, and leave the engine
+    able to serve it once the fault clears. At blocks of 4 the prompts
+    hold full (committable) blocks, at 16 none."""
     m = _model()
-    eng = ServingEngine(m, num_slots=2, bucket_min=8, paged=paged,
-                        block_size=4)
+    eng = ServingEngine(m, num_slots=2, bucket_min=8,
+                        block_size=block_size)
     rs = np.random.RandomState(6)
     prompts = [rs.randint(0, 97, (n,)).astype(np.int64) for n in (5, 9)]
     orig = eng._compiled
 
     def failing(key, fn, args, donate=()):
-        if key[0] in ("prefill", "paged_prefill"):
+        if key[0] == "paged_prefill":
             raise RuntimeError("injected dispatch failure")
         return orig(key, fn, args, donate=donate)
 
@@ -211,9 +212,8 @@ def test_failed_prefill_dispatch_leaks_no_slot(paged):
     assert [r.rid for r in eng.scheduler.queue] == [r.rid for r in reqs]
     for r in reqs:
         assert r.state == QUEUED and r.slot is None and r.inflight == 0
-    if paged:
-        eng.pool.check_conservation()
-        assert eng.pool.live_blocks == 0
+    eng.pool.check_conservation()
+    assert eng.pool.live_blocks == 0
     # the rolled-back attempt never reached the admission counters
     assert eng.metrics.requests_admitted == 0
     # fault clears: the same engine drains the queue with full parity

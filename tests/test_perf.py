@@ -4,9 +4,8 @@ cross-run perf ledger, and the tools/perf_diff.py regression gate.
 
 Acceptance criteria pinned here: a two-bucket + chunked + decode
 drain attributes its measured time to distinct program keys whose sum
-is tolerance-pinned against the serving/step span total (on BOTH
-pools); a synthetic ledger with a planted 2x decode slowdown makes
-perf_diff exit 1 naming the (scenario, metric); a clean two-run
+is tolerance-pinned against the serving/step span total; a synthetic
+ledger with a planted 2x decode slowdown makes perf_diff exit 1 naming the (scenario, metric); a clean two-run
 ledger exits 0 (the tier-1 CI self-run, mirroring incident_report /
 chaos_sweep); a single-row ledger is a baseline, exit 0.
 """
@@ -59,16 +58,17 @@ def test_roofline_floor_bound_switch():
 
 
 def test_kv_read_bytes_scales_and_paged_gather_tax():
-    base = kv_read_bytes_per_token(128, 12, 12, 64, kv_bytes=2)
+    inplace = dict(kv_bytes=2, layout="paged_pallas")
+    base = kv_read_bytes_per_token(128, 12, 12, 64, **inplace)
     assert base == 2 * 12 * 12 * 64 * 128 * 2
     # linear in kv_len and heads
-    assert kv_read_bytes_per_token(256, 12, 12, 64, kv_bytes=2) \
+    assert kv_read_bytes_per_token(256, 12, 12, 64, **inplace) \
         == 2 * base
-    assert kv_read_bytes_per_token(128, 12, 24, 64, kv_bytes=2) \
+    assert kv_read_bytes_per_token(128, 12, 24, 64, **inplace) \
         == 2 * base
-    # the XLA-composed paged layout pays the gather materialization
-    paged = kv_read_bytes_per_token(128, 12, 12, 64, kv_bytes=2,
-                                    paged=True)
+    # the XLA-composed gather (the default layout: the one every
+    # backend can run) pays the gather materialization
+    paged = kv_read_bytes_per_token(128, 12, 12, 64, kv_bytes=2)
     assert paged == perf_mod.PAGED_GATHER_FACTOR * base
 
 
@@ -76,6 +76,7 @@ def test_decode_step_model_accounting():
     m = decode_step_model(batch=8, kv_len=1024, num_layers=12,
                           num_heads=12, head_dim=64, n_params=124e6,
                           param_bytes=2, kv_bytes=2,
+                          layout="paged_pallas",
                           peak_flops=197e12, hbm_bps=819e9)
     assert m["bytes_total"] == pytest.approx(
         m["kv_read_bytes"] + m["kv_write_bytes"]
@@ -89,7 +90,7 @@ def test_decode_step_model_accounting():
     paged = decode_step_model(batch=8, kv_len=1024, num_layers=12,
                               num_heads=12, head_dim=64,
                               n_params=124e6, param_bytes=2,
-                              kv_bytes=2, paged=True,
+                              kv_bytes=2, layout="paged_xla",
                               peak_flops=197e12, hbm_bps=819e9)
     assert paged["bytes_total"] > m["bytes_total"]
     assert paged["floor_s"] > m["floor_s"]
@@ -147,8 +148,9 @@ def test_cpu_engine_reports_no_roofline_fraction(monkeypatch):
 
 def test_gpt_roofline_cli_decode_mode():
     """tools/gpt_roofline.py --decode: the ROADMAP direction-#2
-    decode-step HBM model, contiguous vs paged, with the gather tax
-    as a number — and the train-step default output unchanged."""
+    decode-step HBM model, the XLA gather vs the in-place kernel,
+    with the gather tax as a number — and the train-step default
+    output unchanged."""
     res = subprocess.run(
         [sys.executable, os.path.join(_ROOT, "tools",
                                       "gpt_roofline.py"),
@@ -156,14 +158,15 @@ def test_gpt_roofline_cli_decode_mode():
         capture_output=True, text=True, timeout=60)
     assert res.returncode == 0, res.stderr
     out = json.loads(res.stdout.strip())
-    assert out["contiguous"]["bound"] == "hbm"
+    assert set(out) == {"config", "paged_xla", "paged_pallas",
+                        "pallas_vs_paged_xla_x"}
+    assert out["paged_xla"]["bound"] == "hbm"
+    # the Pallas paged-kernel column: gather tax gone, one direct
+    # read of the K/V; the modelled win is the whole tax
     assert out["paged_xla"]["kv_read_bytes_per_token"] \
-        > out["contiguous"]["kv_read_bytes_per_token"]
-    assert out["paged_gather_tax"] > 1.5
-    # the Pallas paged-kernel column: gather tax gone, reads priced
-    # identically to contiguous, the modelled win is the whole tax
+        == 3.0 * out["paged_pallas"]["kv_read_bytes_per_token"]
     assert out["paged_pallas"]["kv_read_bytes_per_token"] \
-        == out["contiguous"]["kv_read_bytes_per_token"]
+        == 2 * 12 * 12 * 64 * 1024 * 2
     assert out["paged_pallas"]["gather_factor"] == 1.0
     assert out["pallas_vs_paged_xla_x"] > 1.5
     res = subprocess.run(
@@ -180,11 +183,11 @@ def test_gpt_roofline_cli_decode_mode():
 
 def test_format_program_key():
     assert format_program_key(("decode",)) == "decode"
-    assert format_program_key(("prefill", 16, 4)) == "prefill/b16/g4"
     assert format_program_key(("paged_prefill", 32)) \
         == "paged_prefill/b32"
-    assert format_program_key(("chunk_prefill", 8)) \
-        == "chunk_prefill/c8"
+    assert format_program_key(("paged_spec_verify",)) \
+        == "paged_spec_verify"
+    assert format_program_key("decode") == "decode"
 
 
 def _drive(eng, rs, specs):
@@ -194,20 +197,18 @@ def _drive(eng, rs, specs):
     eng.run()
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_program_attribution_sums_to_step_total(paged, monkeypatch):
+def test_program_attribution_sums_to_step_total(monkeypatch):
     # the CPU has no peaks of its own; state some so the roofline
     # join (cost x measured wall x peaks) is exercised
     monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "197e12")
     monkeypatch.setenv("PADDLE_TPU_HBM_BPS", "819e9")
     """Satellite acceptance: a two-bucket prefill + chunked + decode
     drain yields DISTINCT program keys whose summed measured time is
-    tolerance-pinned against the serving/step span total, on both
-    pools. Measured over a WARM drain (deltas between reports), so
-    compile time never pollutes the comparison."""
+    tolerance-pinned against the serving/step span total. Measured
+    over a WARM drain (deltas between reports), so compile time never pollutes the comparison."""
     m = _model()
     eng = ServingEngine(m, num_slots=2, bucket_min=8,
-                        prefill_chunk=12, paged=paged)
+                        prefill_chunk=12)
     rs = np.random.RandomState(0)
     # buckets 8 (len 5/6) and 16 (len 9), plus a chunked prompt (20
     # > prefill_chunk) and enough decode to dominate
@@ -222,9 +223,7 @@ def test_program_attribution_sums_to_step_total(paged, monkeypatch):
 
     progs = r1["programs"]
     expect = {"decode", "paged_prefill/b8", "paged_prefill/b12",
-              "paged_prefill/b16"} if paged else \
-        {"decode", "prefill/b8/g1", "prefill/b16/g1",
-         "chunk_prefill/c12"}
+              "paged_prefill/b16"}
     assert expect <= set(progs), progs.keys()
     for entry in progs.values():
         assert entry["dispatches"] > 0 and entry["total_s"] > 0
@@ -250,11 +249,11 @@ def test_program_attribution_sums_to_step_total(paged, monkeypatch):
     # warm drain the dispatch+sync legs carry the device work, the
     # rest of the step is host bookkeeping
     assert attributed >= 0.2 * step_total
-    # the roofline join is live for decode on this pool flavor
+    # the roofline join is live for decode
     dec = progs["decode"]
     assert dec["roofline_fraction"] is not None
     assert dec["bound"] in ("hbm", "flops")
-    assert r1["decode_roofline"]["model"]["paged"] is paged
+    assert r1["decode_roofline"]["model"]["layout"] == "paged_xla"
     eng.close()
 
 
@@ -525,81 +524,3 @@ def test_perf_diff_prune_run_clears_planted_regression(tmp_path):
     res = _run_diff(path, "--prune-series", "perf/decode_avg_ms")
     assert res.returncode == 0
     assert "decode_avg_ms" not in res.stdout.split("pruned")[1]
-
-
-# ----------------------------------------------- bench harness pieces
-
-def test_bench_rotate_artifacts(tmp_path):
-    import bench_serving
-
-    d = str(tmp_path)
-    names = [f"serving_smoke_2026080{i}T000000Z.json"
-             for i in range(6)]
-    for n in names:
-        with open(os.path.join(d, n), "w") as fh:
-            fh.write("{}")
-    with open(os.path.join(d, "serving_20260801T000000Z.json"),
-              "w") as fh:
-        fh.write("{}")                     # full artifacts never rotate
-    removed = bench_serving._rotate_artifacts(d, keep=2)
-    assert removed == names[:4]            # oldest pruned, newest kept
-    left = sorted(os.listdir(d))
-    assert names[4] in left and names[5] in left
-    assert "serving_20260801T000000Z.json" in left
-    assert bench_serving._rotate_artifacts(d, keep=0) == []   # off
-
-
-def test_bench_ledger_rows_normalize_evidence():
-    import bench_serving
-
-    evidence = {
-        "timestamp": "2026-08-04T00:00:00Z",
-        "device": {"platform": "cpu"},
-        "tokens_per_sec": 1234.5,
-        "vs_sequential": 4.5,
-        "latency_percentiles": {"ttft": {"p50_ms": 12.0}},
-        "deep_queue": {"vs_pr1_engine": 1.4,
-                       "grouped_tokens_per_sec": 2000.0},
-        "overload": {"goodput_improvement": 4.2,
-                     "slo_feedback": {"goodput_tokens_per_sec": 99.0}},
-        "chaos": {"completion_rate": 1.0},
-        "perf": {"programs": {"decode": {"avg_ms": 0.3}},
-                 "decode_roofline": {"achieved_fraction": 0.4}},
-        # a cache-only shared_prefix section (PR 13): the cache rows
-        # normalize, the absent ttft_improvement is skipped, not faked
-        "shared_prefix": {"cache": {
-            "hit_rate": 0.91,
-            "savings": {"saved_ttft_ms": 88.5}}},
-        # an interpret-mode decode-kernel A/B (the CPU smoke runner):
-        # the ratio ledgers under its honest interp name, never as a
-        # "speedup" claim
-        "decode_kernel": {"interpret": True, "speedup_x": 0.5,
-                          "pallas": {"roofline_fraction": 0.001}},
-        # health section absent: skipped, not faked
-    }
-    rows = bench_serving._ledger_rows(evidence, "run.json",
-                                      "live-smoke", "digest0")
-    by_key = {(r["scenario"], r["metric"]): r for r in rows}
-    assert by_key[("headline", "tokens_per_sec")]["value"] == 1234.5
-    assert by_key[("perf", "decode_avg_ms")]["direction"] \
-        == "lower_better"
-    assert ("decode_kernel", "decode_kernel_speedup_x") not in by_key
-    assert by_key[("decode_kernel",
-                   "decode_kernel_interp_ratio_x")]["value"] == 0.5
-    # deterministic counter metrics carry the provenance marker and a
-    # tight threshold (zero timing noise — any movement is code)
-    hit = by_key[("shared_prefix", "cache_hit_rate")]
-    assert hit["measurement"] == "deterministic"
-    assert hit["rel_threshold"] == 0.05
-    assert "measurement" not in by_key[("headline",
-                                        "tokens_per_sec")]
-    assert by_key[("chaos", "completion_rate")]["rel_threshold"] == 0.1
-    assert by_key[("shared_prefix", "cache_hit_rate")]["value"] == 0.91
-    assert by_key[("shared_prefix", "cache_hit_rate")]["direction"] \
-        == "higher_better"
-    assert by_key[("shared_prefix", "cache_saved_ttft_ms")]["value"] \
-        == 88.5
-    assert ("shared_prefix", "ttft_improvement") not in by_key
-    assert ("health", "step_overhead_us") not in by_key
-    assert all(r["config_digest"] == "digest0"
-               and r["run_id"] == "run.json" for r in rows)

@@ -6,13 +6,13 @@ Acceptance criteria pinned here:
 
   * every fault-injection path is deterministic per seed — two chaos
     runs with the same FaultPlan produce identical fault logs AND
-    identical final token streams (both pools);
+    identical final token streams (both decode attentions);
   * a forced wedge (monkeypatched dispatch failure loop) triggers
     detector -> supervisor restart -> in-flight requests re-queued and
     completed with exact greedy parity vs an unfaulted run, with
     ``/debug/health`` reporting degraded during and healthy after;
   * rollback under injected failure at EVERY chunk boundary of a
-    chunked prefill conserves slots/blocks on both pools and the
+    chunked prefill conserves slots/blocks at two block sizes and the
     request completes on retry;
   * a poisoned ``on_token`` callback never kills the step loop;
   * ``close()`` with in-flight work retires it with an explicit
@@ -126,12 +126,13 @@ def test_injector_determinism_and_exact_scheduling():
     assert ei.value.site == "transfer"
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_chaos_runs_deterministic_and_greedy_exact(model, paged):
+def test_chaos_runs_deterministic_and_greedy_exact(model,
+                                                   decode_attention):
     """Acceptance: same FaultPlan seed => identical fault logs and
     identical final token streams — and the hardened engine's streams
     are bit-exact with an unfaulted run (retries/replay never corrupt
-    greedy decoding) with nothing leaked."""
+    greedy decoding) with nothing leaked, over the gather and over the
+    paged decode kernel."""
     prompts = _prompts(8)
     reference = _reference(model, prompts, 6)
 
@@ -141,8 +142,8 @@ def test_chaos_runs_deterministic_and_greedy_exact(model, paged):
             "transfer": 0.1, "callback": 0.3, "block_exhaustion": 0.1,
             "step_latency": {"rate": 0.05, "latency_s": 0.001}})
         eng = ServingEngine(model, num_slots=4, bucket_min=8,
-                            paged=paged, chaos=plan,
-                            max_dispatch_retries=3)
+                            chaos=plan, max_dispatch_retries=3)
+        assert eng.decode_layout == decode_attention
         reqs = [eng.add_request(p, max_new_tokens=6,
                                 on_token=lambda r, t: None)
                 for p in prompts]
@@ -155,9 +156,8 @@ def test_chaos_runs_deterministic_and_greedy_exact(model, paged):
     assert e1.chaos.total_fires > 0          # chaos actually ran
     assert s1 == s2 == reference
     assert e1.pool.free_count == 4           # no slot leaked
-    if paged:
-        e1.pool.check_conservation()
-        assert e1.pool.live_blocks == 0
+    e1.pool.check_conservation()
+    assert e1.pool.live_blocks == 0
     res = e1.metrics.snapshot()["resilience"]
     assert res["chaos"]["enabled"] is True
     assert res["chaos"]["plan"]["seed"] == 3
@@ -253,23 +253,24 @@ def test_quarantine_never_takes_the_last_slot(model):
 
 # ------------------------------------------- chunk-boundary rollback
 
-@pytest.mark.parametrize("paged", [False, True])
+@pytest.mark.parametrize("block_size", [4, 16])
 @pytest.mark.parametrize("boundary", [0, 1, 2, 3])
-def test_chunked_prefill_rollback_at_every_boundary(model, paged,
+def test_chunked_prefill_rollback_at_every_boundary(model, block_size,
                                                     boundary):
     """Inject a dispatch failure at EACH chunk boundary of a chunked
-    prefill (prompt of 26 tokens, chunk 8 -> 4 chunks), on both
-    pools: the rollback must conserve slots/blocks and the request
-    must complete bit-exact on retry."""
+    prefill (prompt of 26 tokens, chunk 8 -> 4 chunks), with a chunk
+    that spans two blocks and with two chunks to a block: the rollback
+    must conserve slots/blocks and the request must complete
+    bit-exact on retry."""
     rs = np.random.RandomState(31)
     prompt = rs.randint(0, _VOCAB, (26,)).astype(np.int64)
     ref_eng = ServingEngine(model, num_slots=2, bucket_min=8,
-                            prefill_chunk=8, paged=paged)
+                            prefill_chunk=8, block_size=block_size)
     ref = ref_eng.add_request(prompt, max_new_tokens=4)
     ref_eng.run()
     eng = ServingEngine(
-        model, num_slots=2, bucket_min=8, prefill_chunk=8, paged=paged,
-        max_dispatch_retries=3,
+        model, num_slots=2, bucket_min=8, prefill_chunk=8,
+        block_size=block_size, max_dispatch_retries=3,
         chaos=FaultPlan(seed=0, faults={"chunk_dispatch": {
             "rate": 1.0, "after": boundary, "max_fires": 1}}))
     req = eng.add_request(prompt, max_new_tokens=4)
@@ -280,9 +281,8 @@ def test_chunked_prefill_rollback_at_every_boundary(model, paged,
     assert res["dispatch_retries"] == 1
     assert eng.pool.free_count == 2          # slot conservation
     assert not eng._chunk_q and not eng._prefilling
-    if paged:
-        eng.pool.check_conservation()        # block conservation
-        assert eng.pool.live_blocks == 0
+    eng.pool.check_conservation()            # block conservation
+    assert eng.pool.live_blocks == 0
 
 
 # --------------------------------------------------------- deadlines
@@ -364,14 +364,14 @@ def test_poisoned_on_token_callback_does_not_kill_the_step_loop(model):
 
 # ------------------------------------------------------ drain / close
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_close_with_inflight_work_aborts_explicitly(model, paged):
+def test_close_with_inflight_work_aborts_explicitly(model,
+                                                    decode_attention):
     """Satellite pin: close() (and __exit__) with queued + running
     requests retires them with reason "aborted" — counted, flight-
     closed, slots/blocks conserved — instead of silent abandonment."""
     prompts = _prompts(6, seed=8)
-    with ServingEngine(model, num_slots=2, bucket_min=8,
-                       paged=paged) as eng:
+    with ServingEngine(model, num_slots=2, bucket_min=8) as eng:
+        assert eng.decode_layout == decode_attention
         reqs = [eng.add_request(p, max_new_tokens=30) for p in prompts]
         eng.step()
         eng.step()                            # some running, some queued
@@ -383,9 +383,8 @@ def test_close_with_inflight_work_aborts_explicitly(model, paged):
     res = eng.metrics.snapshot()["resilience"]
     assert res["requests_aborted"] == len(aborted)
     assert eng.pool.free_count == 2
-    if paged:
-        eng.pool.check_conservation()
-        assert eng.pool.live_blocks == 0
+    eng.pool.check_conservation()
+    assert eng.pool.live_blocks == 0
     with pytest.raises(RuntimeError):
         eng.add_request(prompts[0], max_new_tokens=2)
     eng.close()                               # idempotent
@@ -488,7 +487,7 @@ def test_supervisor_restart_replays_paged_pool_with_radix_rebuild(
     replay is greedy-exact."""
     prompts = _prompts(4, seed=14)
     reference = _reference(model, prompts, 6)
-    eng = ServingEngine(model, num_slots=4, bucket_min=8, paged=True,
+    eng = ServingEngine(model, num_slots=4, bucket_min=8,
                         max_dispatch_retries=1,
                         supervisor_cooldown_s=0.0)
     reqs = [eng.add_request(p, max_new_tokens=6) for p in prompts]
@@ -550,7 +549,7 @@ def test_incident_bundle_embeds_fault_plan_and_renders(model,
         health_detectors={"queue_stall": {"stall_steps": 3}},
         incident_dir=inc_dir)
     eng.add_request(_prompts(1)[0], max_new_tokens=3)
-    eng.scheduler.admit_chunked = lambda *a, **k: ([], [])  # wedge
+    eng.scheduler.admit_paged = lambda *a, **k: None   # wedge
     for _ in range(6):
         eng.step()
     files = [f for f in os.listdir(inc_dir)
@@ -587,13 +586,12 @@ def test_chaos_sweep_full_matrix_passes():
 
 
 def test_chaos_sweep_fast_gate():
-    """Tier-1 self-run: one seed across the reduced site matrix on the
-    paged pool — the leak/hang/parity/determinism gate the sweep
-    enforces, at smoke cost."""
+    """Tier-1 self-run: one seed across the reduced site matrix —
+    the leak/hang/parity/determinism gate the sweep enforces, at
+    smoke cost."""
     res = subprocess.run(
         [sys.executable, os.path.join(_ROOT, "tools",
-                                      "chaos_sweep.py"), "--fast",
-         "--paged", "1"],
+                                      "chaos_sweep.py"), "--fast"],
         capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, res.stdout[-2000:] + res.stderr[-500:]
     lines = [json.loads(ln) for ln in res.stdout.splitlines()

@@ -1,5 +1,5 @@
 """SLO-feedback scheduling subsystem (paddle_tpu.serving.sched):
-chunked prefill parity + compile-inventory guard on both KV pools,
+chunked prefill parity + compile-inventory guard at two block sizes,
 decode/prefill co-scheduling, per-slot sampling semantics, and the
 load-shedding admission policy (ISSUE 7 acceptance contracts)."""
 import time
@@ -37,14 +37,12 @@ def _prompts(rs, lengths):
 
 def _warm_inventory(eng, chunk, rs):
     """Deterministically cover the engine's whole compile inventory:
-    every (bucket, group size) the grouped path can hit (prompts <=
-    chunk stay grouped), the chunk program, and the decode step."""
-    short = min(min(eng.scheduler.buckets), chunk)
-    for g in eng.group_sizes:
-        for _ in range(g):
+    every bucket a whole (unchunked) tail can take (tails <= chunk),
+    the chunk-width prefill, and the decode step."""
+    for b in eng.scheduler.buckets:
+        if b <= chunk:
             eng.add_request(
-                rs.randint(0, 97, (short,)).astype(np.int64), 2)
-        eng.run()
+                rs.randint(0, 97, (b,)).astype(np.int64), 2)
     eng.add_request(
         rs.randint(0, 97, (chunk + 3,)).astype(np.int64), 2)
     eng.run()
@@ -79,14 +77,15 @@ def test_plan_chunks_rejects_short_tails():
 
 # -------------------------------------------- chunked prefill parity
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_chunked_prefill_exact_greedy_parity(paged):
+@pytest.mark.parametrize("block_size", [4, 16])
+def test_chunked_prefill_exact_greedy_parity(block_size):
     """ISSUE 7 acceptance: chunked and unchunked prefill produce
-    EXACTLY the same greedy tokens as batch-1 generate() on both KV
-    pools, across a mixed short/long staggered workload."""
+    EXACTLY the same greedy tokens as batch-1 generate(), across a
+    mixed short/long staggered workload — with a chunk that spans two
+    blocks (4) and with two chunks to a block (16)."""
     m = _model()
-    eng = ServingEngine(m, num_slots=3, bucket_min=8, paged=paged,
-                        block_size=4, prefill_chunk=8)
+    eng = ServingEngine(m, num_slots=3, bucket_min=8,
+                        block_size=block_size, prefill_chunk=8)
     rs = np.random.RandomState(0)
     specs = [(5, 6), (40, 5), (11, 4), (56, 7), (23, 5), (7, 6),
              (33, 4), (3, 8)]
@@ -104,8 +103,7 @@ def test_chunked_prefill_exact_greedy_parity(paged):
     assert sched["chunked_requests"] == sum(
         1 for n, _ in specs if n > 8)
     assert sched["prefill_chunks"] > sched["chunked_requests"]
-    if paged:
-        eng.pool.check_conservation()
+    eng.pool.check_conservation()
 
 
 def test_chunked_prefill_paged_shared_prefix_tail_only():
@@ -114,7 +112,7 @@ def test_chunked_prefill_paged_shared_prefix_tail_only():
     (prefix_hit + chunk starts begin at the cached span) with exact
     parity."""
     m = _model()
-    eng = ServingEngine(m, num_slots=2, bucket_min=8, paged=True,
+    eng = ServingEngine(m, num_slots=2, bucket_min=8,
                         block_size=4, prefill_chunk=8)
     rs = np.random.RandomState(3)
     stem = rs.randint(0, 97, (24,)).astype(np.int64)
@@ -164,26 +162,24 @@ def test_chunked_prefill_interleaves_with_decode():
     assert chunks[0]["t"] < t_retired < chunks[-1]["t"]
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_chunked_compile_inventory_guard(paged):
+@pytest.mark.parametrize("block_size", [4, 16])
+def test_chunked_compile_inventory_guard(block_size):
     """ISSUE 7 satellite: under chunked prefill the compile inventory
-    stays O(chunk_sizes x group_sizes) and ANY prompt-length mix after
+    stays within the buckets and ANY prompt-length mix after
     warmup triggers ZERO steady-state compiles — enforced by the
     watchdog's raise mode, so a silent recompile is a hard test
     failure, not a counter drift."""
     m = _model()
-    eng = ServingEngine(m, num_slots=4, bucket_min=8, paged=paged,
-                        block_size=4, prefill_chunk=8,
+    eng = ServingEngine(m, num_slots=4, bucket_min=8,
+                        block_size=block_size, prefill_chunk=8,
                         watchdog_mode="raise")
     rs = np.random.RandomState(11)
     _warm_inventory(eng, 8, rs)
     warm = eng.metrics.compiles
-    # grouped path only sees prompts <= chunk, so the bound collapses
-    # to (buckets <= chunk) x group_sizes + chunk program + decode
-    if paged:
-        assert warm <= len(eng.scheduler.buckets) + 1
-    else:
-        assert warm <= len(eng.group_sizes) + 1 + 1
+    # whole tails are <= chunk, so the inventory collapses to the
+    # buckets <= chunk (the chunk width is one of them) + decode
+    assert set(eng._exec) == {("paged_prefill", 8), ("decode",)}
+    assert warm == 2 <= len(eng.scheduler.buckets) + 1
     eng.declare_warmup()
     for n in rs.randint(1, 60, 50):
         eng.add_request(rs.randint(0, 97, (int(n),)).astype(np.int64),
@@ -227,8 +223,8 @@ def test_chunked_token_budget_paces_dispatches():
         a, eng2.scheduler.completed[-1].output_ids)
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_chunked_sync_mode_matches_pipelined(paged):
+@pytest.mark.parametrize("block_size", [4, 16])
+def test_chunked_sync_mode_matches_pipelined(block_size):
     """async_depth=0 + chunking: the synchronous schedule harvests
     each final chunk immediately — tokens identical to the pipelined
     default and to generate()."""
@@ -239,7 +235,7 @@ def test_chunked_sync_mode_matches_pipelined(paged):
     for depth in (1, 0):
         eng = ServingEngine(m, num_slots=2, bucket_min=8,
                             prefill_chunk=8, async_depth=depth,
-                            paged=paged, block_size=4)
+                            block_size=block_size)
         reqs = [eng.add_request(p, max_new_tokens=5) for p in prompts]
         eng.run()
         outs.append([r.output_ids.copy() for r in reqs])
@@ -248,23 +244,23 @@ def test_chunked_sync_mode_matches_pipelined(paged):
         np.testing.assert_array_equal(a, _ref(m, p, 5))
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_failed_chunk_dispatch_leaks_nothing(paged):
+@pytest.mark.parametrize("block_size", [4, 16])
+def test_failed_chunk_dispatch_leaks_nothing(block_size):
     """The PR-6 rollback discipline extends to chunked prefill: a
     dispatch failure MID-CHUNK-CHAIN (earlier chunks already wrote
-    K/V) releases the slot (and blocks), clears the chunk queue,
+    K/V) releases the slot and its blocks, clears the chunk queue,
     requeues the request uncounted, and a retry serves it with exact
     parity — recomputed from scratch, stale chunk rows masked."""
     m = _model()
     eng = ServingEngine(m, num_slots=2, bucket_min=8, prefill_chunk=8,
-                        paged=paged, block_size=4)
+                        block_size=block_size)
     rs = np.random.RandomState(19)
     prompt = rs.randint(0, 97, (44,)).astype(np.int64)   # 6 chunks
     orig = eng._compiled
     calls = {"n": 0}
 
     def failing(key, fn, args, donate=()):
-        if key[0] in ("chunk_prefill", "paged_prefill"):
+        if key[0] == "paged_prefill":
             calls["n"] += 1
             if calls["n"] == 3:        # third chunk dispatch fails
                 raise RuntimeError("injected chunk failure")
@@ -277,9 +273,8 @@ def test_failed_chunk_dispatch_leaks_nothing(paged):
     assert eng.pool.free_count == 2 and not eng.scheduler.active
     assert not eng._chunk_q and not eng._prefilling
     assert r.slot is None and r.inflight == 0
-    if paged:
-        eng.pool.check_conservation()
-        assert eng.pool.live_blocks == 0
+    eng.pool.check_conservation()
+    assert eng.pool.live_blocks == 0
     assert eng.metrics.requests_admitted == 0
     eng._compiled = orig
     eng.run()
@@ -334,8 +329,8 @@ def test_sampling_head_support_and_greedy_blend():
     assert draws(1.2, 0, 1.0, seed=1) != draws(1.2, 0, 1.0, seed=2)
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_sampled_and_greedy_slots_share_one_dispatch(paged):
+@pytest.mark.parametrize("block_size", [4, 16])
+def test_sampled_and_greedy_slots_share_one_dispatch(block_size):
     """Per-slot sampling: greedy requests stay BIT-EXACT with
     generate() while neighboring slots sample, sampled streams are
     reproducible per seed, and the whole mix adds no compiles beyond
@@ -346,7 +341,7 @@ def test_sampled_and_greedy_slots_share_one_dispatch(paged):
 
     def run_wave():
         eng = ServingEngine(m, num_slots=4, bucket_min=8,
-                            sampling=True, paged=paged, block_size=4)
+                            sampling=True, block_size=block_size)
         reqs = [
             eng.add_request(prompts[0], 6),
             eng.add_request(prompts[1], 6, temperature=0.8, top_k=12,

@@ -9,8 +9,9 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.serving import (ServingEngine, SlotKVPool, StepScheduler,
-                                default_buckets, default_group_sizes)
+from paddle_tpu.serving import (PagedKVPool, ServingConfig,
+                                ServingEngine, StepScheduler,
+                                default_buckets)
 from paddle_tpu.text.models import GPTForCausalLM, TransformerLMConfig
 
 
@@ -52,14 +53,6 @@ def test_default_buckets_edge_cases():
         default_buckets(64, 0)
 
 
-def test_default_group_sizes_geometric():
-    assert default_group_sizes(1) == [1]
-    assert default_group_sizes(6) == [1, 2, 4]   # capped at num_slots
-    assert default_group_sizes(8) == [1, 2, 4, 8]
-    with pytest.raises(ValueError):
-        default_group_sizes(0)
-
-
 def test_bucket_for_boundaries():
     """Prompt exactly at a bucket boundary stays in that bucket; one
     past it moves up; past the largest bucket raises."""
@@ -70,48 +63,6 @@ def test_bucket_for_boundaries():
     assert sch.bucket_for(32) == 32
     with pytest.raises(ValueError):
         sch.bucket_for(33)
-
-
-def test_pool_heap_is_lowest_slot_first():
-    """Free-list determinism: whatever the release order, acquisition
-    always hands out the lowest free slot."""
-    pool = SlotKVPool(4, 1, 1, 8, 4)
-    slots = [pool.acquire(i) for i in range(4)]
-    assert slots == [0, 1, 2, 3] and pool.acquire(99) is None
-    for s in (3, 1, 2):
-        pool.release(s)
-    assert [pool.acquire(10), pool.acquire(11), pool.acquire(12)] \
-        == [1, 2, 3]
-    assert pool.reuse_count == 3
-
-
-def test_pool_acquire_release_fuzz():
-    """Admit-when-full churn fuzz: across random acquire/release
-    traffic the free set and the owned set always partition the pool,
-    acquisition is always the minimum free slot, acquire on a full
-    pool is None, and double-release raises."""
-    pool = SlotKVPool(4, 1, 1, 8, 4)
-    rs = np.random.RandomState(9)
-    live = set()
-    for i in range(300):
-        if live and (pool.free_count == 0 or rs.rand() < 0.45):
-            slot = int(rs.choice(sorted(live)))
-            pool.release(slot)
-            live.discard(slot)
-            with pytest.raises(ValueError):
-                pool.release(slot)
-        else:
-            free_before = set(pool._free)
-            slot = pool.acquire(i)
-            assert slot == min(free_before)
-            assert pool.owner_of(slot) == i
-            live.add(slot)
-        assert set(pool._free) | live == {0, 1, 2, 3}
-        assert set(pool._free) & live == set()
-        assert pool.free_count + len(live) == 4
-        if pool.free_count == 0:
-            assert pool.acquire(-1) is None
-    assert pool.reuse_count >= 50
 
 
 def test_engine_matches_generate_staggered_mixed_lengths():
@@ -181,11 +132,11 @@ def test_eos_stops_slot_early_and_frees_it():
 
 
 def test_zero_steady_state_recompiles():
-    """After a warmup wave covers the workload's (bucket, group-size)
-    signatures, identical traffic adds ZERO compiles: all device work
-    is AOT executables at fixed shapes (metrics.compiles counts every
+    """After a warmup wave covers the workload's prefill buckets,
+    identical traffic adds ZERO compiles: all device work is AOT
+    executables at fixed shapes (metrics.compiles counts every
     executable ever built), and the whole inventory respects the hard
-    bound len(buckets) * len(group_sizes) + 1."""
+    bound len(buckets) + 1."""
     m = _model()
     eng = ServingEngine(m, num_slots=2, bucket_min=8)
     rs = np.random.RandomState(2)
@@ -194,24 +145,26 @@ def test_zero_steady_state_recompiles():
         eng.add_request(rs.randint(0, 97, (n,)).astype(np.int64), k)
     eng.run()
     warm = eng.metrics.compiles
-    # both admission bursts pair up: (8, G=2), (16, G=2) + 1 decode
+    # the tail buckets 8 and 16 + 1 decode
     assert warm == 3
-    assert warm <= len(eng.scheduler.buckets) * len(eng.group_sizes) + 1
+    assert set(eng._exec) == {("paged_prefill", 8),
+                              ("paged_prefill", 16), ("decode",)}
+    assert warm <= len(eng.scheduler.buckets) + 1
     # steady state: the same traffic pattern again — zero new compiles
     for n, k in wave:
         eng.add_request(rs.randint(0, 97, (n,)).astype(np.int64), k)
     eng.run()
     assert eng.metrics.compiles == warm, "steady-state recompiled"
-    # a NEW (bucket, group) signature is exactly one more compile
+    # a NEW bucket is exactly one more compile
     eng.add_request(rs.randint(0, 97, (20,)).astype(np.int64), 4)
     eng.run()
     assert eng.metrics.compiles == warm + 1
 
 
 def test_compile_inventory_bound_mixed_lengths():
-    """Tier-1 guard for the grouped-prefill compile inventory: a mixed
+    """Tier-1 guard for the compile inventory: a mixed
     prompt-length workload with arbitrary admission bursts never
-    builds more than len(buckets) * len(group_sizes) + 1 executables."""
+    builds more than len(buckets) + 1 executables."""
     m = _model()
     eng = ServingEngine(m, num_slots=4, bucket_min=8)
     rs = np.random.RandomState(11)
@@ -220,8 +173,65 @@ def test_compile_inventory_bound_mixed_lengths():
     for p, (_, k) in zip(_prompts(rs, [n for n, _ in specs]), specs):
         eng.add_request(p, max_new_tokens=k)
     eng.run()
-    bound = len(eng.scheduler.buckets) * len(eng.group_sizes) + 1
-    assert eng.metrics.compiles <= bound
+    assert eng.metrics.compiles <= len(eng.scheduler.buckets) + 1
+
+
+def test_compile_inventory_bound_chunked_speculative():
+    """The whole inventory of an engine with every program it can
+    have: len(buckets) prefills (the chunk width is one more bucket
+    when it is not one already) + decode + verify + the two wire
+    programs — and nothing else, whatever the traffic."""
+    m = _model()
+    eng = ServingEngine(m, num_slots=3, bucket_min=8, prefill_chunk=12,
+                        speculative=True, spec_k=3)
+    eng.warmup_kv_handoff()
+    rs = np.random.RandomState(15)
+    specs = [(int(n), int(k)) for n, k in zip(
+        rs.randint(2, 40, 12), rs.randint(2, 8, 12))]
+    prompts = _prompts(rs, [n for n, _ in specs])
+    reqs = [eng.add_request(p, max_new_tokens=k)
+            for p, (_, k) in zip(prompts, specs)]
+    eng.run()
+    for r, p, (_, k) in zip(reqs, prompts, specs):
+        np.testing.assert_array_equal(r.output_ids, _ref(m, p, k))
+    assert eng.metrics.scheduler_report()["chunked_requests"] > 0
+    kinds = {k[0] for k in eng._exec}
+    assert kinds == {"paged_prefill", "decode", "paged_spec_verify",
+                     "kv_export", "kv_import"}
+    widths = {k[1] for k in eng._exec if k[0] == "paged_prefill"}
+    assert widths <= set(eng.scheduler.buckets) | {12}
+    assert eng.metrics.compiles <= len(eng.scheduler.buckets) + 1 + 4
+
+
+def test_slot_pool_is_gone_and_says_so():
+    """paged=False no longer selects anything: the config refuses it,
+    for any model, and names the removal."""
+    with pytest.raises(ValueError, match="slot-contiguous KV pool was "
+                                         "removed"):
+        ServingConfig(paged=False)
+    with pytest.raises(ValueError, match="removed"):
+        ServingEngine(_model(), num_slots=2, paged=False)
+
+
+@pytest.mark.parametrize("kwargs", [{}, {"paged": None},
+                                    {"paged": True}],
+                         ids=["default", "none", "true"])
+def test_engine_without_options_is_paged(kwargs, monkeypatch):
+    """ServingEngine(model) builds the paged pool with its radix
+    index; paged=None and paged=True build the same thing, and the
+    environment name the slot pool's gate used to read is read by
+    nothing."""
+    monkeypatch.setenv("PADDLE_" + "PAGED_KV", "0")   # the old gate
+    m = _model()
+    eng = ServingEngine(m, **kwargs)
+    assert type(eng.pool) is PagedKVPool
+    assert eng.pool.index is not None
+    assert eng.decode_layout == "paged_xla"   # the CPU's path
+    assert not hasattr(eng.config, "paged")
+    p = np.arange(1, 20, dtype=np.int64)
+    r = eng.add_request(p, max_new_tokens=4)
+    eng.run()
+    np.testing.assert_array_equal(r.output_ids, _ref(m, p, 4))
 
 
 def test_run_returns_submission_order():
@@ -243,11 +253,10 @@ def test_run_returns_submission_order():
     assert eng.scheduler.completed != done
 
 
-def test_grouped_prefill_deep_queue_parity():
-    """Queue much deeper than the slot pool with same-bucket bursts:
-    multi-request prefill groups fire (one dispatch covers several
-    admissions) and every request still matches its own batch-1
-    generate() exactly."""
+def test_deep_queue_parity():
+    """Queue much deeper than the slot pool, prompts in mixed
+    buckets: every admission is accounted once and every request
+    still matches its own batch-1 generate() exactly."""
     m = _model()
     eng = ServingEngine(m, num_slots=4, bucket_min=8)
     rs = np.random.RandomState(8)
@@ -258,25 +267,23 @@ def test_grouped_prefill_deep_queue_parity():
             for p, (_, k) in zip(prompts, specs)]
     eng.run()
     hist = eng.metrics.prefill_group_hist
-    assert any(g > 1 for g in hist), f"no grouped prefill fired: {hist}"
     assert eng.metrics.prefill_requests == len(specs)
     assert sum(g * c for g, c in hist.items()) == len(specs)
-    assert eng.metrics.prefills < len(specs)  # fewer dispatches
+    assert eng.pool.reuse_count >= len(specs) - 4
     for r, p, (_, k) in zip(reqs, prompts, specs):
         np.testing.assert_array_equal(r.output_ids, _ref(m, p, k))
 
 
 def test_sync_mode_matches_pipelined_engine():
-    """async_depth=0 + singleton prefill (the PR-1 synchronous
-    schedule) and the pipelined grouped default produce identical
-    tokens — the overhaul changes the schedule, never the math."""
+    """async_depth=0 (the PR-1 synchronous schedule) and the
+    pipelined default produce identical tokens — the pipeline
+    changes the schedule, never the math."""
     m = _model()
     rs = np.random.RandomState(10)
     specs = [(3, 6), (11, 4), (7, 9), (20, 5), (5, 7), (13, 3)]
     prompts = _prompts(rs, [n for n, _ in specs])
     eng_a = ServingEngine(m, num_slots=3, bucket_min=8)
-    eng_b = ServingEngine(m, num_slots=3, bucket_min=8,
-                          prefill_group_sizes=(1,), async_depth=0)
+    eng_b = ServingEngine(m, num_slots=3, bucket_min=8, async_depth=0)
     ra = [eng_a.add_request(p, max_new_tokens=k)
           for p, (_, k) in zip(prompts, specs)]
     rb = [eng_b.add_request(p, max_new_tokens=k)
@@ -315,8 +322,7 @@ def test_forced_donation_parity_on_cpu():
 
 
 def test_snapshot_surfaces_pipeline_metrics():
-    """snapshot() carries the hot-path observability the bench artifact
-    asserts on: prefill group histogram, KV donation status, and the
+    """snapshot() carries the hot-path observability: prefill group histogram, KV donation status, and the
     dispatch-vs-sync wall split."""
     m = _model()
     eng = ServingEngine(m, num_slots=2, bucket_min=8)
@@ -421,10 +427,9 @@ def test_throughput_vs_sequential_generate():
 def test_serving_soak_slot_churn():
     """Soak (slow tier): 24 mixed requests through 4 slots in three
     arrival waves — full parity, heavy recycling, and the compile
-    inventory bound len(buckets) * len(group_sizes) + 1 holding across
-    the whole soak (admission-burst variety may touch new group sizes
-    per wave; the BOUND is the contract). A fourth wave repeating the
-    first three's arrival pattern must add zero compiles."""
+    inventory bound len(buckets) + 1 holding across the whole soak.
+    A fourth wave repeating the first three's arrival pattern must
+    add zero compiles."""
     m = _model(max_seq_len=64, num_layers=3)
     eng = ServingEngine(m, num_slots=4, bucket_min=8)
     rs = np.random.RandomState(6)
@@ -437,8 +442,7 @@ def test_serving_soak_slot_churn():
                                                    (wave + 1) * 8]:
             reqs.append(eng.add_request(p, max_new_tokens=k))
         eng.run()
-    bound = len(eng.scheduler.buckets) * len(eng.group_sizes) + 1
-    assert eng.metrics.compiles <= bound
+    assert eng.metrics.compiles <= len(eng.scheduler.buckets) + 1
     assert eng.pool.reuse_count >= 20
     for r, p, (_, k) in zip(reqs, prompts, specs):
         np.testing.assert_array_equal(r.output_ids, _ref(m, p, k))

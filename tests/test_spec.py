@@ -177,12 +177,12 @@ def test_spec_env_gate(monkeypatch):
 
 # ----------------------------------------------------- engine parity
 
-@pytest.mark.parametrize("paged", [False, True])
 @pytest.mark.parametrize("async_depth", [0, 1])
-def test_spec_parity_with_generate(paged, async_depth):
+def test_spec_parity_with_generate(decode_attention, async_depth):
     """THE contract: greedy streams with speculation ON are bit-exact
     with per-request generate() (and hence with speculation OFF) on
-    both pools and both schedules — with watchdog_mode="raise", so a
+    both schedules, whichever attention the plain decode program (the
+    step where nobody drafts) runs — with watchdog_mode="raise", so a
     single steady-state compile in the two-program schedule fails
     loudly, and a SECOND post-warmup wave proves it stays warm."""
     m = _model()
@@ -190,9 +190,10 @@ def test_spec_parity_with_generate(paged, async_depth):
     prompts = _prompts(rs, (5, 9, 13, 7, 21, 6))
     n_new = 24
     refs = [_ref(m, p, n_new) for p in prompts]
-    eng = ServingEngine(m, num_slots=4, bucket_min=8, paged=paged,
+    eng = ServingEngine(m, num_slots=4, bucket_min=8,
                         async_depth=async_depth, speculative=True,
                         spec_k=4, watchdog_mode="raise")
+    assert eng.decode_layout == decode_attention
     reqs = [eng.add_request(p, max_new_tokens=n_new) for p in prompts]
     eng.run()
     for r, ref in zip(reqs, refs):
@@ -233,8 +234,7 @@ class _OracleDrafter:
         return []
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_greedy_agreeing_drafts_totally_accepted(paged):
+def test_greedy_agreeing_drafts_totally_accepted(decode_attention):
     """Acceptance property: when every drafted token equals the
     model's greedy choice, the verify program accepts ALL of them —
     zero rejections, and each verify leg yields its full draft + the
@@ -244,9 +244,10 @@ def test_greedy_agreeing_drafts_totally_accepted(paged):
     prompts = _prompts(rs, (5, 9, 12))
     n_new = 12
     refs = [_ref(m, p, n_new) for p in prompts]
-    eng = ServingEngine(m, num_slots=4, bucket_min=8, paged=paged,
+    eng = ServingEngine(m, num_slots=4, bucket_min=8,
                         speculative=True, spec_k=4,
                         watchdog_mode="raise")
+    assert eng.decode_layout == decode_attention
     eng._spec.drafter = _OracleDrafter(4, refs)
     reqs = [eng.add_request(p, max_new_tokens=n_new) for p in prompts]
     eng.run()

@@ -116,10 +116,10 @@ def test_ledger_bounded_under_10k_tenant_flood():
         assert set(entry) == set(TENANT_ENTRY_KEYS)
 
 
-# ------------------------------------------- conservation, both pools
+# ------------------------------------- conservation, both SLO verdicts
 
-def test_conservation_legacy_pool_attained_path():
-    """Legacy (non-paged) pool, no SLO targets: every completion
+def test_conservation_attained_path():
+    """Default engine, no SLO targets: every completion
     attains, and every per-tenant sum matches the global counters."""
     eng = ServingEngine(_model(), num_slots=2, bucket_min=8)
     rs = np.random.RandomState(3)
@@ -145,11 +145,11 @@ def test_conservation_legacy_pool_attained_path():
         eng.close()
 
 
-def test_conservation_paged_pool_violation_path():
-    """Paged pool with an unmeetable TTFT target: every completion
+def test_conservation_violation_path():
+    """Small blocks and an unmeetable TTFT target: every completion
     violates, goodput is zero, and the sums still match exactly."""
     eng = ServingEngine(_model(), num_slots=2, bucket_min=8,
-                        paged=True, block_size=8,
+                        block_size=8,
                         slo_ttft_ms=0.000001)
     rs = np.random.RandomState(5)
     try:
@@ -231,7 +231,7 @@ def test_kv_handoff_carries_tenant_across_tiers():
     the prefill tier admitted — zero kv_wire format change."""
     def engine(role):
         return ServingEngine(_model(seed=11), num_slots=4,
-                             bucket_min=8, paged=True, role=role,
+                             bucket_min=8, role=role,
                              health=False)
 
     prompt = list(range(1, 20))

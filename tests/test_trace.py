@@ -302,7 +302,7 @@ def _drain(eng):
 
 def test_engine_records_prefill_spans_and_serves_debug_traces():
     eng = ServingEngine(_model(), config=ServingConfig(
-        num_slots=2, bucket_min=8, paged=True, health=False))
+        num_slots=2, bucket_min=8, health=False))
     try:
         req = eng.add_request(np.arange(1, 12, dtype=np.int64) % 97,
                               max_new_tokens=3)
@@ -357,7 +357,7 @@ def test_direct_add_request_mints_local_root():
     """An engine with no router above it serves a traceless
     add_request under a locally minted root — never an exception."""
     eng = ServingEngine(_model(), config=ServingConfig(
-        num_slots=2, bucket_min=8, paged=True, health=False))
+        num_slots=2, bucket_min=8, health=False))
     try:
         req = eng.add_request(np.arange(1, 10, dtype=np.int64) % 97,
                               max_new_tokens=2)
@@ -394,7 +394,7 @@ def test_corrupted_wire_trace_degrades_import_still_succeeds():
     a local root and the decode stream is unaffected."""
     def engine(role):
         return ServingEngine(_model(seed=11), num_slots=4,
-                             bucket_min=8, paged=True, role=role,
+                             bucket_min=8, role=role,
                              health=False)
 
     prompt = list(range(1, 20))
@@ -428,7 +428,7 @@ def test_clean_wire_trace_joins_decode_tier():
     ORIGINAL trace id carried inside the KV handoff payload."""
     def engine(role):
         return ServingEngine(_model(seed=11), num_slots=4,
-                             bucket_min=8, paged=True, role=role,
+                             bucket_min=8, role=role,
                              health=False)
 
     prompt = list(range(1, 20))
@@ -470,7 +470,7 @@ def test_live_disagg_trace_report_cli(tmp_path):
 
     def gw(rid, role):
         eng = ServingEngine(model, num_slots=2, bucket_min=8,
-                            paged=True, block_size=8, replica_id=rid,
+                            block_size=8, replica_id=rid,
                             role=role, health=False)
         g = EngineGateway(eng)
         warm = g.submit(np.asarray(prompt, dtype=np.int64),

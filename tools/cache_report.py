@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Cache observatory report: render a ``/debug/cache`` body (or a full
-``snapshot()`` / bench artifact containing one) as the operator-facing
+``snapshot()`` containing one) as the operator-facing
 cache story — measured hit rate, the miss-ratio curve ("what would
 0.5x/2x/4x capacity do"), the hot-prefix digest, savings attribution,
 eviction churn — and judge THRASH:
@@ -13,9 +13,8 @@ eviction churn — and judge THRASH:
   * 2 — input missing or not recognizable as a cache report.
 
 Input shapes accepted (auto-detected): the ``/debug/cache`` body
-itself, any dict with a ``"cache"`` section (``/debug/state``,
-``snapshot()``), or a bench artifact whose scenario section carries
-one (``shared_prefix.cache``). Reads a file path or stdin (``-``).
+itself, or any dict with a ``"cache"`` section (``/debug/state``,
+``snapshot()``). Reads a file path or stdin (``-``).
 
 Zero heavy imports (no jax, no paddle_tpu) — starts in milliseconds,
 usable against a live engine:
@@ -42,13 +41,6 @@ def find_cache_report(doc):
     cache = doc.get("cache")
     if isinstance(cache, dict) and "enabled" in cache:
         return cache
-    # bench artifact: {"scenarios": {"shared_prefix": {"cache": ...}}}
-    scenarios = doc.get("scenarios")
-    if isinstance(scenarios, dict):
-        for sec in scenarios.values():
-            found = find_cache_report(sec)
-            if found is not None:
-                return found
     return None
 
 

@@ -8,8 +8,8 @@ an "all" cell arming the default mix), then verifies the contract the
 resilience layer owes:
 
   * **no hang** — the drain finishes within a step budget;
-  * **no leak** — every slot free afterwards, and on the paged pool a
-    full ``check_conservation()`` audit passes;
+  * **no leak** — every slot free afterwards, and a full
+    ``check_conservation()`` audit of the pool's blocks passes;
   * **parity** — every completed request's token stream is bit-exact
     with the unfaulted reference drain (greedy replay correctness
     through rollback, retry and supervisor restart);
@@ -17,10 +17,10 @@ resilience layer owes:
     reproduce the identical fault log and streams.
 
 Output: one JSON line per cell plus a summary line; exit 1 on any
-failure (the CI gate). Tier-1 self-runs ``--fast`` (one seed, both
-pools) via tests/test_resilience.py; a nightly can widen ``--seeds``.
+failure (the CI gate). Tier-1 self-runs ``--fast`` (one seed) via
+tests/test_resilience.py; a nightly can widen ``--seeds``.
 
-Usage: python tools/chaos_sweep.py [--seeds N] [--fast] [--paged 0|1]
+Usage: python tools/chaos_sweep.py [--seeds N] [--fast]
 """
 import argparse
 import json
@@ -65,14 +65,14 @@ def _workload(n_requests=16):
              int(rs.randint(3, 8))) for n in lengths]
 
 
-def _drain(model, specs, paged, chaos=None, chunk=None, spec=False):
+def _drain(model, specs, chaos=None, chunk=None, spec=False):
     """One engine drain; returns (streams, engine, steps, fault_log).
-    The paged engine's decode attention is its own choice (the Pallas
+    The engine's decode attention is its own choice (the Pallas
     kernel wherever ``kernel_viable`` says yes: here, under forced
     interpret)."""
     from paddle_tpu.serving import ServingEngine
     eng = ServingEngine(
-        model, num_slots=4, bucket_min=8, paged=paged, speculative=spec,
+        model, num_slots=4, bucket_min=8, speculative=spec,
         prefill_chunk=chunk, chaos=chaos, max_dispatch_retries=3,
         supervisor_cooldown_s=0.0, health_audit_every=8)
     reqs = [eng.add_request(p, max_new_tokens=k,
@@ -88,7 +88,7 @@ def _drain(model, specs, paged, chaos=None, chunk=None, spec=False):
     return streams, eng, steps, log
 
 
-def _check_cell(site, seed, model, specs, reference, paged, chunk,
+def _check_cell(site, seed, model, specs, reference, chunk,
                 spec=False):
     """Run one (site, seed) cell twice; returns a result dict with
     ok=False and a reason on any contract break."""
@@ -99,11 +99,9 @@ def _check_cell(site, seed, model, specs, reference, paged, chunk,
     def plan():
         return FaultPlan(seed=seed, faults=faults)
 
-    out = {"site": site, "seed": seed, "paged": paged, "spec": spec,
-           "ok": True}
-    streams, eng, steps, log = _drain(model, specs, paged,
-                                      chaos=plan(), chunk=chunk,
-                                      spec=spec)
+    out = {"site": site, "seed": seed, "spec": spec, "ok": True}
+    streams, eng, steps, log = _drain(model, specs, chaos=plan(),
+                                      chunk=chunk, spec=spec)
     out["steps"] = steps
     out["paged_attn"] = eng.paged_attn
     if streams is None:
@@ -112,18 +110,16 @@ def _check_cell(site, seed, model, specs, reference, paged, chunk,
     out["faults"] = res["faults_injected"]
     out["retries"] = res["dispatch_retries"]
     out["restarts"] = res["supervisor_restarts"]
-    # leak checks: every slot free, paged block conservation intact
+    # leak checks: every slot free, block conservation intact
     if eng.pool.free_count + len(eng.pool.quarantined) \
             != eng.pool.num_slots:
         return dict(out, ok=False, reason="slot leak after drain")
-    if paged:
-        try:
-            eng.pool.check_conservation()
-        except AssertionError as e:
-            return dict(out, ok=False,
-                        reason=f"block conservation: {e}")
-        if eng.pool.live_blocks > 0:
-            return dict(out, ok=False, reason="live blocks at idle")
+    try:
+        eng.pool.check_conservation()
+    except AssertionError as e:
+        return dict(out, ok=False, reason=f"block conservation: {e}")
+    if eng.pool.live_blocks > 0:
+        return dict(out, ok=False, reason="live blocks at idle")
     # parity: completed requests match the unfaulted reference
     bad = [i for i, (got, want) in enumerate(zip(streams, reference))
            if got and got != want]
@@ -137,7 +133,7 @@ def _check_cell(site, seed, model, specs, reference, paged, chunk,
         return dict(out, ok=False,
                     reason=f"{incomplete}/{len(specs)} incomplete")
     # determinism: same seed => identical fault log and streams
-    streams2, _, _, log2 = _drain(model, specs, paged, chaos=plan(),
+    streams2, _, _, log2 = _drain(model, specs, chaos=plan(),
                                   chunk=chunk, spec=spec)
     if log2 != log:
         return dict(out, ok=False, reason="fault log not deterministic")
@@ -178,9 +174,9 @@ def _check_handoff_cell(seed, model, specs, reference):
 
     def run_once():
         pe = ServingEngine(model, num_slots=4, bucket_min=8,
-                           paged=True, role="prefill")
+                           role="prefill")
         de = ServingEngine(model, num_slots=4, bucket_min=8,
-                           paged=True, role="decode")
+                           role="decode")
         rs = np.random.RandomState(seed)
         streams, faults = [], 0
         try:
@@ -216,7 +212,7 @@ def _check_handoff_cell(seed, model, specs, reference):
             de.close()
         return streams, faults, None
 
-    out = {"site": "kv_handoff", "seed": seed, "paged": True, "ok": True}
+    out = {"site": "kv_handoff", "seed": seed, "ok": True}
     streams, faults, reason = run_once()
     out["faults"] = {"kv_wire_corruption": faults}
     if reason:
@@ -264,9 +260,6 @@ def main(argv=None):
     parser.add_argument("--seeds", type=int, default=3)
     parser.add_argument("--fast", action="store_true",
                         help="one seed, reduced site matrix (tier-1)")
-    parser.add_argument("--paged", type=int, choices=(0, 1),
-                        default=None,
-                        help="restrict to one pool flavor")
     args = parser.parse_args(argv)
 
     sites = ["prefill_dispatch", "decode_dispatch", "transfer",
@@ -275,7 +268,6 @@ def main(argv=None):
     if args.fast:
         sites = ["prefill_dispatch", "decode_dispatch", "chunk_dispatch",
                  "all"]
-    pools = [False, True] if args.paged is None else [bool(args.paged)]
 
     model = _build_model()
     specs = _workload(12 if args.fast else 16)
@@ -287,20 +279,16 @@ def main(argv=None):
 
     failures = 0
     cells = 0
-    for paged in pools:
-        reference, ref_eng, _, _ = _drain(model, specs, paged,
-                                          chunk=chunk)
-        assert reference is not None, "reference drain hung"
-        for seed in seeds:
-            for site in sites:
-                if site == "block_exhaustion" and not paged:
-                    continue   # legacy pool has no block economy
-                cells += 1
-                result = _patrolled(_check_cell, site, seed, model,
-                                    specs, reference, paged, chunk)
-                print(json.dumps(result), flush=True)
-                if not result["ok"]:
-                    failures += 1
+    reference, _, _, _ = _drain(model, specs, chunk=chunk)
+    assert reference is not None, "reference drain hung"
+    for seed in seeds:
+        for site in sites:
+            cells += 1
+            result = _patrolled(_check_cell, site, seed, model,
+                                specs, reference, chunk)
+            print(json.dumps(result), flush=True)
+            if not result["ok"]:
+                failures += 1
     # one decode-faulted cell per seed with the Pallas paged decode
     # kernel as the engine's choice (forced interpret makes
     # kernel_viable say yes on the CPU): retry/restart replay must stay
@@ -309,19 +297,19 @@ def main(argv=None):
     from paddle_tpu.ops import paged_attention as paged_attn_mod
     paged_attn_mod._FORCE_INTERPRET[0] = True
     try:
-        reference, eng, _, _ = _drain(model, specs, True, chunk=chunk)
-        assert reference is not None, "pallas reference drain hung"
+        pallas_ref, eng, _, _ = _drain(model, specs, chunk=chunk)
+        assert pallas_ref is not None, "pallas reference drain hung"
         assert eng.decode_layout == "paged_pallas", eng.decode_layout
         for seed in seeds:
             cells += 1
             result = _patrolled(_check_cell, "decode_dispatch", seed,
-                                model, specs, reference, True, chunk)
+                                model, specs, pallas_ref, chunk)
             print(json.dumps(result), flush=True)
             if not result["ok"]:
                 failures += 1
     finally:
         paged_attn_mod._FORCE_INTERPRET[0] = False
-    # speculation-enabled cells per seed, both pools: decode faults now
+    # speculation-enabled cells per seed: decode faults now
     # hit k-token verify dispatches too (same "decode_dispatch" site),
     # and retry / supervisor-restart replay must stay bit-exact against
     # a SPEC-ENABLED unfaulted reference (which itself is bit-exact
@@ -330,33 +318,28 @@ def main(argv=None):
     # generations so the n-gram drafter actually proposes and verify
     # dispatches really carry drafts when the faults land.
     spec_specs = [(p, k + 8) for p, k in specs]
-    for paged in pools:
-        reference, _, _, _ = _drain(model, spec_specs, paged,
-                                    chunk=chunk, spec=True)
-        assert reference is not None, "spec reference drain hung"
-        for seed in seeds:
-            cells += 1
-            result = _patrolled(_check_cell, "decode_dispatch", seed,
-                                model, spec_specs, reference, paged,
-                                chunk, spec=True)
-            print(json.dumps(result), flush=True)
-            if not result["ok"]:
-                failures += 1
-    # disaggregated KV-handoff cells (ISSUE 17), paged pool only (the
-    # wire unit IS the paged block): seeded in-flight corruption must
-    # surface as the typed wire error without poisoning the decode
-    # pool, clean retries stay bit-exact with a monolithic reference,
-    # and both tiers end block-clean
-    if True in pools:
-        reference, _, _, _ = _drain(model, specs, True, chunk=chunk)
-        assert reference is not None, "handoff reference drain hung"
-        for seed in seeds:
-            cells += 1
-            result = _patrolled(_check_handoff_cell, seed, model,
-                                specs, reference)
-            print(json.dumps(result), flush=True)
-            if not result["ok"]:
-                failures += 1
+    spec_ref, _, _, _ = _drain(model, spec_specs, chunk=chunk,
+                               spec=True)
+    assert spec_ref is not None, "spec reference drain hung"
+    for seed in seeds:
+        cells += 1
+        result = _patrolled(_check_cell, "decode_dispatch", seed,
+                            model, spec_specs, spec_ref, chunk,
+                            spec=True)
+        print(json.dumps(result), flush=True)
+        if not result["ok"]:
+            failures += 1
+    # disaggregated KV-handoff cells (ISSUE 17): seeded in-flight
+    # corruption must surface as the typed wire error without
+    # poisoning the decode pool, clean retries stay bit-exact with the
+    # monolithic reference, and both tiers end block-clean
+    for seed in seeds:
+        cells += 1
+        result = _patrolled(_check_handoff_cell, seed, model, specs,
+                            reference)
+        print(json.dumps(result), flush=True)
+        if not result["ok"]:
+            failures += 1
     print(json.dumps({"summary": True, "cells": cells,
                       "failures": failures}), flush=True)
     return 1 if failures else 0

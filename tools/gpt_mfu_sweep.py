@@ -6,7 +6,8 @@ flash-attention tile sizes (fwd and bwd independently), and the
 seq 2048/4096 extension points — each in a FRESH SUBPROCESS, one after
 another (the env knobs are read at import, and the chip belongs to one
 process at a time; this parent never touches jax). Every result line
-is appended to an artifact in bench_artifacts/.
+is appended to an artifact in chiprun_out/ (what a chip run brings
+back).
 
 Usage:  python tools/gpt_mfu_sweep.py [quick|full]
   quick: amp sweep + best-guess block sweep at seq 1024 (~6 configs)
@@ -19,7 +20,7 @@ import sys
 import time
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_ART = os.path.join(_ROOT, "bench_artifacts")
+_ART = os.path.join(_ROOT, "chiprun_out")
 
 
 def run_config(tag, batch, seq, env_extra, timeout=900):

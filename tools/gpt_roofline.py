@@ -13,9 +13,9 @@ direction #2's "roofline first" step, shared with the engine's
 snapshot()["perf"] via paddle_tpu/observability/perf/roofline.py,
 loaded directly by file so this tool never imports jax): KV-read
 bytes per token as a function of batch, context length, heads and
-layout (contiguous / paged_xla / paged_pallas), the parameter re-read
-every step pays, and the resulting per-step floor — printed for all
-THREE layouts so the XLA gather-materialization tax, and what the
+layout (paged_xla / paged_pallas), the parameter re-read
+every step pays, and the resulting per-step floor — printed for
+both layouts so the XLA gather-materialization tax, and what the
 Pallas paged-attention kernel (ops.paged_attention) buys back by
 deleting it, are numbers, not vibes.
 
@@ -98,9 +98,9 @@ def _load_roofline_module():
 
 def decode_budget(batch, ctx):
     """Decode-step HBM model for GPT-124M at (batch slots, ctx cached
-    positions), all three KV layouts — contiguous, XLA-composed paged
-    gather, and the in-place Pallas paged kernel — bf16 params/KV on
-    the v5e reference chip."""
+    positions), both attention paths over the paged pool — the
+    XLA-composed gather and the in-place Pallas kernel — bf16
+    params/KV on the v5e reference chip."""
     rf = _load_roofline_module()
     n_params = L * 12 * H * H + V * H + MAX_SEQ * H
     out = {"config": {"batch": batch, "ctx": ctx, "model": "gpt-124m",
@@ -122,10 +122,7 @@ def decode_budget(batch, ctx):
                 batch / m["floor_s"], 1),
             "bound": m["bound"],
         }
-    out["paged_gather_tax"] = round(
-        out["paged_xla"]["floor_us_per_step"]
-        / out["contiguous"]["floor_us_per_step"], 3)
-    # what the Pallas kernel buys back at the floor: the whole tax
+    # what the Pallas kernel buys back at the floor: the gather tax
     out["pallas_vs_paged_xla_x"] = round(
         out["paged_xla"]["floor_us_per_step"]
         / out["paged_pallas"]["floor_us_per_step"], 3)

@@ -4,16 +4,15 @@
 Builds the three kinds of compiled programs this framework ships —
 
   * ``serving_decode``   — a ServingEngine on a tiny GPT, drained once
-    and warm-declared, linted via ``engine.lint()`` (f64-upcast /
-    host-callback / donation over the decode jaxpr, dynamic-shape-risk
-    over the engine's compile watchdog);
-  * ``paged_decode``     — the same engine with the paged KV pool
-    (``paged=True``): the decode jaxpr now threads the int32 block
-    table, and the f64-upcast + donation passes must stay clean with
-    that argument (the table is small and host-authored — donating it
+    over shared-prefix prompts and warm-declared, linted via
+    ``engine.lint()`` (f64-upcast / host-callback / donation over the
+    decode jaxpr, dynamic-shape-risk over the engine's compile
+    watchdog). The decode jaxpr threads the int32 block table, and
+    the f64-upcast + donation passes must stay clean with that
+    argument (the table is small and host-authored — donating it
     would be noise, and the donation pass's size floor keeps it
     silent);
-  * ``paged_decode_pallas`` — the paged engine again with the Pallas
+  * ``paged_decode_pallas`` — the same engine with the Pallas
     paged decode-attention kernel as its decode attention (interpret
     mode forced, so ``kernel_viable`` chooses it on this CPU lint run):
     the decode jaxpr now embeds the ``pallas_call`` and the f64-upcast
@@ -21,18 +20,18 @@ Builds the three kinds of compiled programs this framework ships —
     traces in 32-bit mode — pallas_compat — so an f64 leak here is a
     real finding, not noise);
   * ``chunked_prefill``  — a chunked-prefill + per-slot-sampling
-    engine (``prefill_chunk=``, ``sampling=True``): the chunk program
-    (traced start/len/slot/final scalars + sampling params) and the
-    sampling decode linted via ``engine.lint(program="chunk")`` /
-    ``engine.lint()`` — both must stay f64/donation clean;
-  * ``spec_verify``      — speculative-decoding engines on BOTH pools
+    engine (``prefill_chunk=``, ``sampling=True``): the sampling
+    decode of an engine that really chunked, linted via
+    ``engine.lint()`` — it must stay f64/donation clean (a chunk is a
+    prefill dispatch; ``lint`` has no prefill target);
+  * ``spec_verify``      — a speculative-decoding engine
     (``speculative=True``): the k-token verify program
     (``engine.lint(program="spec_verify")``) and the plain decode it
     falls back to must all stay f64/donation clean — the verify
     flavor donates kc/vc/pos exactly like decode, shifted past the
     drafts/dlen host inputs;
   * ``kv_wire``          — a disaggregated KV handoff between a
-    prefill-role and a decode-role paged engine: the ``kv_import``
+    prefill-role and a decode-role engine: the ``kv_import``
     program is linted like any other jitted entry point, a SECOND
     handoff after ``declare_warmup`` must not compile (export/import
     are dispatch-only on the steady-state hot path), and the export
@@ -74,27 +73,7 @@ def lint_serving_decode():
                               num_heads=4, max_seq_len=64, dropout=0.0)
     model = GPTForCausalLM(cfg)
     model.eval()
-    engine = ServingEngine(model, num_slots=4)
-    rs = np.random.RandomState(0)
-    for n in (5, 9, 17):
-        engine.add_request(rs.randint(0, 97, (n,)).astype(np.int64),
-                           max_new_tokens=4)
-    engine.run()
-    engine.declare_warmup()
-    return engine.lint()
-
-
-def lint_paged_decode():
-    import paddle_tpu as paddle
-    from paddle_tpu.serving import ServingEngine
-    from paddle_tpu.text.models import GPTForCausalLM, TransformerLMConfig
-
-    paddle.seed(7)
-    cfg = TransformerLMConfig(vocab_size=97, hidden_size=32, num_layers=2,
-                              num_heads=4, max_seq_len=64, dropout=0.0)
-    model = GPTForCausalLM(cfg)
-    model.eval()
-    engine = ServingEngine(model, num_slots=4, paged=True, block_size=8)
+    engine = ServingEngine(model, num_slots=4, block_size=8)
     rs = np.random.RandomState(0)
     shared = rs.randint(0, 97, (16,)).astype(np.int64)
     for n in (5, 9):
@@ -105,7 +84,7 @@ def lint_paged_decode():
     engine.run()
     engine.declare_warmup()
     assert engine.metrics.snapshot()["prefix_cache"]["hits"] >= 1, \
-        "paged lint target never exercised the prefix cache"
+        "decode lint target never exercised the prefix cache"
     return engine.lint()
 
 
@@ -124,8 +103,7 @@ def lint_paged_decode_pallas():
     # this CPU run and the decode program embeds the real pallas_call
     paged_attn._FORCE_INTERPRET[0] = True
     try:
-        engine = ServingEngine(model, num_slots=4, paged=True,
-                               block_size=8)
+        engine = ServingEngine(model, num_slots=4, block_size=8)
         rs = np.random.RandomState(0)
         for n in (5, 9):
             engine.add_request(rs.randint(0, 97, (n,)).astype(np.int64),
@@ -162,9 +140,8 @@ def lint_chunked_prefill():
     sched = engine.metrics.snapshot()["scheduler"]
     assert sched["prefill_chunks"] >= 4, \
         "chunked-prefill lint target never actually chunked"
-    # the chunk program (traced start/len/slot/final + sampling args)
-    # AND the sampling decode must both stay f64/donation clean
-    return engine.lint(program="chunk") + engine.lint()
+    # the sampling decode must stay f64/donation clean
+    return engine.lint()
 
 
 def lint_spec_verify():
@@ -177,26 +154,23 @@ def lint_spec_verify():
                               num_heads=4, max_seq_len=64, dropout=0.0)
     model = GPTForCausalLM(cfg)
     model.eval()
-    findings = []
-    for paged in (False, True):
-        engine = ServingEngine(model, num_slots=4, paged=paged,
-                               block_size=8, speculative=True, spec_k=4)
-        rs = np.random.RandomState(0)
-        for n in (5, 9, 17):
-            # greedy tiny-model decoding locks into cycles within a
-            # few tokens — 16 new tokens reliably gives the n-gram
-            # drafter self-matches, so verify steps actually dispatch
-            engine.add_request(rs.randint(0, 97, (n,)).astype(np.int64),
-                               max_new_tokens=16)
-        engine.run()
-        engine.declare_warmup()
-        spec = engine.metrics.snapshot()["perf"]["spec"]
-        assert spec["verify_steps"] >= 1, \
-            "spec lint target never dispatched a verify step"
-        # the verify flavor AND the plain-decode fallback it shares the
-        # steady state with must both stay f64/donation clean
-        findings += engine.lint(program="spec_verify") + engine.lint()
-    return findings
+    engine = ServingEngine(model, num_slots=4, block_size=8,
+                           speculative=True, spec_k=4)
+    rs = np.random.RandomState(0)
+    for n in (5, 9, 17):
+        # greedy tiny-model decoding locks into cycles within a
+        # few tokens — 16 new tokens reliably gives the n-gram
+        # drafter self-matches, so verify steps actually dispatch
+        engine.add_request(rs.randint(0, 97, (n,)).astype(np.int64),
+                           max_new_tokens=16)
+    engine.run()
+    engine.declare_warmup()
+    spec = engine.metrics.snapshot()["perf"]["spec"]
+    assert spec["verify_steps"] >= 1, \
+        "spec lint target never dispatched a verify step"
+    # the verify program AND the plain-decode fallback it shares the
+    # steady state with must both stay f64/donation clean
+    return engine.lint(program="spec_verify") + engine.lint()
 
 
 def lint_kv_wire():
@@ -205,6 +179,7 @@ def lint_kv_wire():
     import paddle_tpu as paddle
     from paddle_tpu.analysis import Finding
     from paddle_tpu.serving import ServingEngine
+    from paddle_tpu.serving import engine as engine_mod
     from paddle_tpu.text.models import GPTForCausalLM, TransformerLMConfig
 
     def build(role):
@@ -215,7 +190,7 @@ def lint_kv_wire():
         model = GPTForCausalLM(cfg)
         model.eval()
         return ServingEngine(model, num_slots=4, bucket_min=8,
-                             paged=True, block_size=8, role=role)
+                             block_size=8, role=role)
 
     pe, de = build("prefill"), build("decode")
     rs = np.random.RandomState(0)
@@ -252,14 +227,14 @@ def lint_kv_wire():
     # output bytes against the pool it reads from
     pool = pe.pool
     idx = np.zeros((pool.blocks_per_slot,), np.int32)
-    out = jax.eval_shape(pe._kv_export_fn, pool.kc, pool.vc, idx)
+    out = jax.eval_shape(engine_mod._kv_export_fn, pool.kc, pool.vc, idx)
     out_bytes = sum(int(np.prod(o.shape)) * o.dtype.itemsize
                     for o in jax.tree_util.tree_leaves(out))
     pool_bytes = pool.kc.nbytes + pool.vc.nbytes
     if out_bytes * 2 > pool_bytes:
         findings.append(Finding(
             "kv_wire_transfer", "error",
-            "ServingEngine._kv_export_fn",
+            "serving.engine._kv_export_fn",
             f"export fetches {out_bytes} bytes against a "
             f"{pool_bytes}-byte pool — a per-slot slice should be a "
             f"small fraction; this is a device_get of the pool"))
@@ -329,7 +304,6 @@ def lint_concurrency():
 
 TARGETS = {
     "serving_decode": lint_serving_decode,
-    "paged_decode": lint_paged_decode,
     "paged_decode_pallas": lint_paged_decode_pallas,
     "chunked_prefill": lint_chunked_prefill,
     "spec_verify": lint_spec_verify,

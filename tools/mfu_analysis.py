@@ -8,7 +8,7 @@ which fraction of the step is MXU matmul work vs Pallas kernels vs
 data movement vs host gaps — i.e. where the non-MFU time actually goes.
 
 Usage: python tools/mfu_analysis.py [profile_dir] [n_steps]
-  profile_dir defaults to bench_artifacts/gpt_profile, n_steps 5.
+  profile_dir defaults to chiprun_out/gpt_profile, n_steps 5.
 """
 import glob
 import gzip
@@ -45,7 +45,7 @@ def load_events(profile_dir):
 
 def main():
     profile_dir = sys.argv[1] if len(sys.argv) > 1 else os.path.join(
-        _ROOT, "bench_artifacts", "gpt_profile")
+        _ROOT, "chiprun_out", "gpt_profile")
     n_steps = int(sys.argv[2]) if len(sys.argv) > 2 else 5
     evs = load_events(profile_dir)
 

@@ -2,9 +2,10 @@
 """Cross-run perf regression gate: compare the latest bench run
 against the perf ledger's baseline and exit nonzero on regression.
 
-``bench_serving.py`` appends one normalized row per (scenario, metric)
-to ``bench_artifacts/perf_ledger.jsonl`` on every run; this CLI reads
-the whole ledger, judges the LAST row of every (scenario, metric,
+A bench run appends one normalized row per (scenario, metric) to a
+perf ledger (``observability.perf.append_rows``; nothing in the repo
+writes one since the CPU serving bench was removed, ROADMAP D6); this
+CLI reads the whole ledger, judges the LAST row of every (scenario, metric,
 config_digest) group against the MEDIAN of its history with robust
 thresholds (relative delta gated by a MAD noise estimate — see
 paddle_tpu/observability/perf/ledger.py, loaded directly by file so
@@ -40,7 +41,7 @@ import os
 import sys
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_DEFAULT_LEDGER = os.path.join(_REPO, "bench_artifacts",
+_DEFAULT_LEDGER = os.path.join(_REPO, "chiprun_out",
                                "perf_ledger.jsonl")
 
 
@@ -89,7 +90,7 @@ def main(argv=None):
         description=__doc__.splitlines()[0])
     parser.add_argument("ledger", nargs="?", default=None,
                         help="perf ledger JSONL (default: "
-                             "bench_artifacts/perf_ledger.jsonl)")
+                             "chiprun_out/perf_ledger.jsonl)")
     parser.add_argument("--threshold", type=float, default=0.35,
                         help="default relative-worsening threshold "
                              "(rows may carry their own)")
@@ -120,7 +121,7 @@ def main(argv=None):
                   file=sys.stderr)
             return 2
         print(f"perf_diff: no ledger yet at {path} — nothing to "
-              f"judge (run bench_serving.py first)")
+              f"judge")
         return 0
 
     ledger = _load_ledger_module()

@@ -29,7 +29,7 @@ the lost/mismatched rids. One JSON line per wave on stdout, RESULT
 line last — the same scriptable-gate discipline as chaos_sweep.py.
 
 ``--kill prefill`` runs the DISAGGREGATED flavor: replica 0 comes up
-as the prefill tier, the rest as decode (paged pools + warmed KV
+as the prefill tier, the rest as decode (warmed KV
 export/import programs), wave 1 must complete through real handoffs
 (``disagg.handoffs > 0``), and wave 2 SIGKILLs the PREFILL replica
 mid-handoff — every request must still complete bit-exact via the
@@ -135,7 +135,7 @@ def _wait_inflight(urls, deadline_s=30.0):
     return None
 
 
-def _leak_audit(url, rid, paged, failures):
+def _leak_audit(url, rid, failures):
     st = _get(url, "/debug/state")
     if st.get("queue_depth", 0) != 0 \
             or st.get("slot_occupancy", 0) != 0 \
@@ -144,14 +144,13 @@ def _leak_audit(url, rid, paged, failures):
             f"leak on {rid}: queue_depth={st.get('queue_depth')} "
             f"slot_occupancy={st.get('slot_occupancy')} "
             f"held_exports={st.get('held_exports')}")
-    if paged:
-        pool = (st.get("prefix_cache") or {}).get("pool") or {}
-        # indexed prefix blocks are CACHE, not leaks — live counts
-        # only blocks some slot still references
-        if pool.get("live_blocks", 0) != 0:
-            failures.append(
-                f"leaked blocks on {rid}: "
-                f"live_blocks={pool.get('live_blocks')}")
+    pool = (st.get("prefix_cache") or {}).get("pool") or {}
+    # indexed prefix blocks are CACHE, not leaks — live counts
+    # only blocks some slot still references
+    if pool.get("live_blocks", 0) != 0:
+        failures.append(
+            f"leaked blocks on {rid}: "
+            f"live_blocks={pool.get('live_blocks')}")
 
 
 def run_drill(replicas=3, requests=12, max_new=16, seed=5,
@@ -215,7 +214,7 @@ def run_drill(replicas=3, requests=12, max_new=16, seed=5,
             # the prefill tier is about to die: audit it NOW (zero
             # leaked blocks, zero steady-state compiles under
             # handoff traffic)
-            _leak_audit(urls[0], rids[0], True, failures)
+            _leak_audit(urls[0], rids[0], failures)
             after0 = _compiles(urls[0])
             if after0 != compiles_w0[urls[0]]:
                 failures.append(
@@ -267,16 +266,21 @@ def run_drill(replicas=3, requests=12, max_new=16, seed=5,
         # journal through every dispatch attempt), annotated with a
         # router/failover span. The victim's ring died with it, so
         # assembly joins the router's recorder with the SURVIVORS'
-        # /debug/traces — the replayed attempt's replica-side spans
-        # must appear under the same id.
+        # /debug/traces — the spans of an attempt that moved TO a
+        # survivor must appear under the same id (an attempt that
+        # moved to the victim, after a seeded dispatch fault and
+        # before the kill landed, left its spans in the ring that
+        # died). A survivor that cannot be scraped is its own
+        # failure, not a forked trace.
         from paddle_tpu.observability.trace import TraceAssembler
         asm = TraceAssembler()
         asm.add_recorder(router.trace)
         for u in survivors:
             try:
-                asm.scrape(u, timeout=3.0)
-            except Exception:   # noqa: BLE001 - audit is best-effort
-                pass
+                asm.scrape(u, timeout=120.0)
+            except Exception as e:   # noqa: BLE001 - named below
+                failures.append(
+                    f"could not scrape {by_url[u]}/debug/traces: {e}")
         failed_over = [t for t in asm.assemble_all()
                        if any(s["name"] == "router/failover"
                               for s in t.spans)]
@@ -287,7 +291,10 @@ def run_drill(replicas=3, requests=12, max_new=16, seed=5,
                 f"assembled trace carries a router/failover span")
         survivor_rids = {by_url[u] for u in survivors}
         for t in failed_over:
-            if not ({s["replica"] for s in t.spans} & survivor_rids):
+            moved_to = {s.get("attrs", {}).get("to") for s in t.spans
+                        if s["name"] == "router/failover"}
+            if moved_to & survivor_rids and not (
+                    {s["replica"] for s in t.spans} & survivor_rids):
                 failures.append(
                     f"failed-over trace {t.trace_id} has no "
                     f"survivor-side spans under the original trace "
@@ -302,7 +309,7 @@ def run_drill(replicas=3, requests=12, max_new=16, seed=5,
             failures.append("failover wave accounting does not add up")
         # leak + steady-state-compile audit on the survivors
         for u in survivors:
-            _leak_audit(u, by_url[u], disagg, failures)
+            _leak_audit(u, by_url[u], failures)
             after = _compiles(u)
             if after != compiles_before[u]:
                 failures.append(
