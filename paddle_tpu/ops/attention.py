@@ -64,17 +64,94 @@ def _interpret():
     return _FORCE_INTERPRET[0]
 
 
+# ---- tiles ------------------------------------------------------------------
+# One (block x block) tile of the score square a grid step, computed
+# TRANSPOSED ([keys, queries]) a strip of queries at a time: a strip
+# meets every key it may see in one matmul. In that orientation the
+# softmax statistics are rows ((1, queries): what the tile arithmetic
+# broadcasts over sublanes for free, what the max and the sum reduce to
+# with VPU work alone, and what lse is stored as), so no tile relayouts
+# them; the scale rides on q where that is exact; and only the
+# (strip x strip) square on the diagonal ever sees a mask. Under causal
+# attention a diagonal tile computes its strips up to the diagonal only.
+#
+# Measured on a v5e at [24, 12, 1024, 64] bf16 causal (PERF.md, PR 31):
+# the tile is bound by neither the exponential, the mask nor the
+# reductions (taking each out moved the forward by 0-8 %) but by how
+# long a stream each MXU weight tile gets, so wide strips win although
+# they compute more of the square above the diagonal. A forward call
+# takes 0.30 ms less at strips of 512 than at 256 (0.17 less than at
+# 1024); a backward layer 0.31 ms less at 256 than at 512 (0.05 less
+# than at 128). In the training step a forward call takes 0.73 ms and
+# a backward layer 1.31 ms.
+
+_NEG = -1e30
+_FWD_STRIP = 512
+_BWD_STRIP = 256
+
+
+def _block(s, d, itemsize):
+    """Tile edge for sequences of s (a multiple of 128), head dimension
+    d and operands of itemsize bytes: the largest power of two that
+    divides s and keeps an operand block within 256 KB of VMEM (1024
+    at d = 64 in bf16: one grid step a head at sequences of 1024, K/V
+    streamed block by block beyond)."""
+    block = 1024
+    while s % block or block * d * itemsize > (256 << 10):
+        block //= 2
+    return block
+
+
+def _exact_scale(scale):
+    """True where multiplying by scale only moves the exponent (a power
+    of two, as 1/sqrt(64)): then it folds into q bit for bit."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _scores_t(q, k, scale, diagonal):
+    """[keys, queries] scores of one strip of queries against keys
+    [0, w), f32 accumulation. With `diagonal` the last len(q) keys are
+    the square on the diagonal, the only part where a key can lie after
+    its query. Also returns q as the MXU saw it (scaled where exact)."""
+    fold = _exact_scale(scale)
+    if fold:
+        q = q * scale
+    st = _dot_f32(k, q, ((1,), (1,)))
+    if not fold:
+        st = st * jnp.float32(scale)
+    if diagonal:
+        w, strip = st.shape
+        keep = (jax.lax.broadcasted_iota(jnp.int32, (strip, strip), 1)
+                >= jax.lax.broadcasted_iota(jnp.int32, (strip, strip), 0))
+        below, square = st[:w - strip], st[w - strip:]
+        square = jnp.where(keep, square, jnp.float32(_NEG))
+        st = square if w == strip else jnp.concatenate([below, square])
+    return st, q
+
+
+def _causal_walk(walk, causal, qi, ki):
+    """Run walk(diagonal) for the tile (qi, ki): tiles below the
+    diagonal whole and unmasked, tiles on it up to the diagonal, tiles
+    above it not at all."""
+    from jax.experimental import pallas as pl
+    if not causal:
+        walk(False)
+        return
+    pl.when(ki < qi)(lambda: walk(False))
+    pl.when(ki == qi)(lambda: walk(True))
+
+
 # ---- forward kernel --------------------------------------------------------
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *, scale, causal,
-                      block_q, block_k, nk):
+                      block, strip, nk):
     """Grid (b, h, nq, nk): K/V stream through VMEM one block at a
-    time, so VMEM use is O(block) — independent of seq length (a
-    full-seq-resident K/V caps out near seq 16k on the 16MB budget).
-    The online-softmax state (acc, m, l) lives in VMEM scratch, which
-    persists across the sequentially-executed inner ki grid steps; the
-    o/lse output blocks are revisited and written once at the last ki."""
+    time, so VMEM use is O(block) — independent of seq length. The
+    online-softmax state (acc [d, block], m and l [1, block]) lives in
+    VMEM scratch, which persists across the sequentially-executed inner
+    ki grid steps; the o/lse output blocks are revisited and written
+    once at the last ki."""
     from jax.experimental import pallas as pl
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -82,100 +159,76 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     @pl.when(ki == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def _compute():
-        q = q_ref[...]
-        k = k_ref[...]
-        v = v_ref[...]
-        # [block_q, block_k] = q @ k.T, f32 accumulation
-        s = _dot_f32(q, k, ((1,), (1,))) * jnp.float32(scale)
-        if causal:
-            q_pos = qi * jnp.int32(block_q) + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * jnp.int32(block_k) + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, jnp.float32(-1e30))
-        m_prev = m_ref[...][0]
-        l_prev = l_ref[...][0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = (alpha * l_prev + jnp.sum(p, axis=1))[None, :]
-        m_ref[...] = m_new[None, :]
-        pv = _dot_f32(p.astype(v.dtype), v, ((1,), (0,)))
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
+    def _walk(diagonal):
+        for j in range(block // strip):
+            cols = slice(j * strip, (j + 1) * strip)
+            w = (j + 1) * strip if diagonal else block
+            st, _ = _scores_t(q_ref[cols, :], k_ref[:w, :], scale, diagonal)
+            m_prev = m_ref[:, cols]
+            m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+            pt = jnp.exp(st - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[:, cols] = alpha * l_ref[:, cols] + jnp.sum(
+                pt, axis=0, keepdims=True)
+            m_ref[:, cols] = m_new
+            # [d, strip] = v.T @ p.T
+            pvt = _dot_f32(v_ref[:w, :], pt.astype(v_ref.dtype),
+                           ((0,), (0,)))
+            acc_ref[:, cols] = acc_ref[:, cols] * alpha + pvt
 
-    if causal:
-        # fully-future K blocks contribute nothing: skip their matmuls
-        pl.when(ki * block_k <= qi * block_q + block_q - 1)(_compute)
-    else:
-        _compute()
+    _causal_walk(_walk, causal, qi, ki)
 
     @pl.when(ki == nk - 1)
     def _store():
-        l = l_ref[...][0]
-        o_ref[...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[...] = m_ref[...] + jnp.log(l)[None, :]
+        l = l_ref[...]
+        o_ref[...] = (acc_ref[...] / l).T.astype(o_ref.dtype)
+        lse_ref[...] = m_ref[...] + jnp.log(l)
 
 
 def _pallas_flash_fwd(q, k, v, scale, causal):
-    # x64 guard shared by every Pallas entry point (pallas_compat)
-    return _trace_32bit(_pallas_flash_fwd_32)(q, k, v, scale, causal)
+    return _pallas_flash_fwd_32(q, k, v, scale, causal, _interpret())
 
 
-import os as _os
-
-# Block sizes: 128-row blocks leave the MXU underfed (64-deep contractions
-# on 128x128 tiles) and pay per-grid-cell DMA/semaphore overhead; 512
-# amortizes both while staying well inside the 16MB VMEM budget at
-# d=64..256. Measured on v5e at [8,12,1024,64] bf16 causal: grad
-# 7.4ms (block 128) -> 4.7ms (block 512), 1.9x faster than
-# jax.experimental.pallas.ops.tpu.flash_attention on the same shape.
-_BLOCK_Q = int(_os.environ.get("PADDLE_FLASH_BLOCK_Q", "512"))
-_BLOCK_K = int(_os.environ.get("PADDLE_FLASH_BLOCK_K", "512"))
-_BLOCK_BWD = int(_os.environ.get("PADDLE_FLASH_BLOCK_BWD", "512"))
-
-
-def _block_for(s, want):
-    """Largest power-of-two block <= want that divides s (s is a
-    multiple of 128 per the _use_pallas gate, so the halving loop
-    terminates by 128; non-power-of-two env overrides are rounded down
-    so it cannot degenerate below that)."""
-    want = max(128, 1 << (max(want, 1).bit_length() - 1))
-    blk = min(want, s)
-    while s % blk:
-        blk //= 2
-    return blk
-
-
-def _pallas_flash_fwd_32(q, k, v, scale, causal):
+# jitted, so that a step which calls the kernel once a layer traces and
+# lowers it once, not once a call site (the strips are unrolled: left
+# to each of a 12-layer step's 36 sites, a warm set-up took 9 s longer,
+# PERF.md, PR 31); identical calls are then also identical to XLA, which
+# merges the forward that a tape replays with the first. x64 guard
+# shared by every Pallas entry point (pallas_compat)
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+@_trace_32bit
+def _pallas_flash_fwd_32(q, k, v, scale, causal, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     b, h, s, d = q.shape
-    block_q = _block_for(s, _BLOCK_Q)
-    block_k = _block_for(s, _BLOCK_K)
-    nq, nk = s // block_q, s // block_k
+    block = _block(s, d, q.dtype.itemsize)
+    n = s // block
     kernel = functools.partial(_flash_fwd_kernel, scale=scale,
-                               causal=causal, block_q=block_q,
-                               block_k=block_k, nk=nk)
+                               causal=causal, block=block,
+                               strip=min(_FWD_STRIP, block), nk=n)
+
+    def kv_map(bi, hi, qi, ki):
+        # a tile above the diagonal computes nothing: name the block
+        # already in VMEM, so the skipped step fetches nothing either
+        return (bi, hi, jnp.minimum(ki, qi) if causal else ki, 0)
+
     out, lse = pl.pallas_call(kernel, name="flash_fwd",
-        grid=(b, h, nq, nk),
+        grid=(b, h, n, n),
         in_specs=[
-            pl.BlockSpec((None, None, block_q, d),
+            pl.BlockSpec((None, None, block, d),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((None, None, block_k, d),
-                         lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((None, None, block_k, d),
-                         lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+            pl.BlockSpec((None, None, block, d), kv_map),
+            pl.BlockSpec((None, None, block, d), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((None, None, block_q, d),
+            pl.BlockSpec((None, None, block, d),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             # mosaic needs the last two block dims ~(8,128)-aligned or
-            # full; a [b,h,1,s] layout makes the lse block (1, block_q)
-            pl.BlockSpec((None, None, 1, block_q),
+            # full; a [b,h,1,s] layout makes the lse block (1, block)
+            pl.BlockSpec((None, None, 1, block),
                          lambda bi, hi, qi, ki: (bi, hi, 0, qi)),
         ],
         out_shape=[
@@ -183,149 +236,137 @@ def _pallas_flash_fwd_32(q, k, v, scale, causal):
             jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((1, block_q), jnp.float32),
-            pltpu.VMEM((1, block_q), jnp.float32),
+            pltpu.VMEM((d, block), jnp.float32),
+            pltpu.VMEM((1, block), jnp.float32),
+            pltpu.VMEM((1, block), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret,
     )(q, k, v)
     return out, lse
 
 
-# ---- backward kernels (flash-attention-2 style, O(seq) memory) -------------
-# 4D grid (b, h, outer, inner): the inner loop is a GRID dimension, so
-# only block-sized tiles live in VMEM at a time (full-seq tiles blew the
-# 16MB scoped-vmem budget at seq 16k); the output block is revisited
-# across inner steps and accumulated (TPU grids execute sequentially).
+# ---- backward kernel (flash-attention-2 style, O(seq) memory) --------------
+# One body. With the scores transposed lse and delta broadcast from the
+# rows they are stored as, and dK, dV and dQ.T are all plain matmuls of
+# the tile as it stands. Where one block holds the sequence it is one
+# kernel and s, p, dp are computed once for all three gradients;
+# beyond, dQ needs the key loop innermost and dK/dV the query loop, so
+# the body runs twice (grid (b, h, nq, nk) for dQ, (b, h, nk, nq) for
+# dK/dV), each time with block-sized VMEM. Gradients accumulate in f32
+# scratch and leave once, in the inputs' dtype, at the last inner step.
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, *, scale, causal, block_q, block_k):
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      *refs, scale, causal, block, strip, n, want_dq,
+                      want_dkv):
     from jax.experimental import pallas as pl
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    outs, accs = refs[:len(refs) // 2], refs[len(refs) // 2:]
+    # dQ alone walks keys innermost; with dK/dV the queries are inner
+    outer, inner = pl.program_id(2), pl.program_id(3)
+    qi, ki = (inner, outer) if want_dkv else (outer, inner)
+    dqt_acc = accs[0] if want_dq else None     # [d, block]
+    dk_acc, dv_acc = accs[-2:] if want_dkv else (None, None)
 
-    @pl.when(ki == 0)
+    @pl.when(inner == 0)
     def _init():
-        dq_ref[...] = jnp.zeros_like(dq_ref)
+        for acc in accs:
+            acc[...] = jnp.zeros_like(acc)
 
-    def _compute():
-        q = q_ref[...]
-        do = do_ref[...]
-        lse = lse_ref[...][0]
-        delta = delta_ref[...][0]
-        k = k_ref[...]
-        v = v_ref[...]
-        s = _dot_f32(q, k, ((1,), (1,))) * jnp.float32(scale)
-        if causal:
-            q_pos = qi * jnp.int32(block_q) + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * jnp.int32(block_k) + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, jnp.float32(-1e30))
-        p = jnp.exp(s - lse[:, None])
-        dp = _dot_f32(do, v, ((1,), (1,)))
-        ds = p * (dp - delta[:, None])
-        dq_ref[...] += _dot_f32(ds.astype(k.dtype), k,
-                                ((1,), (0,))) * jnp.float32(scale)
+    def _walk(diagonal):
+        for j in range(block // strip):
+            cols = slice(j * strip, (j + 1) * strip)
+            w = (j + 1) * strip if diagonal else block
+            do = do_ref[cols, :]
+            k = k_ref[:w, :]
+            st, qs = _scores_t(q_ref[cols, :], k, scale, diagonal)
+            pt = jnp.exp(st - lse_ref[:, cols])
+            dpt = _dot_f32(v_ref[:w, :], do, ((1,), (1,)))
+            dst = (pt * (dpt - delta_ref[:, cols])).astype(k.dtype)
+            if want_dkv:
+                dv_acc[:w, :] += _dot_f32(pt.astype(do.dtype), do,
+                                          ((1,), (0,)))
+                dk_acc[:w, :] += _dot_f32(dst, qs, ((1,), (0,)))
+            if want_dq:
+                # [d, strip] = k.T @ ds.T
+                dqt_acc[:, cols] += _dot_f32(k, dst, ((0,), (0,)))
 
-    if causal:
-        pl.when(qi >= ki)(_compute)  # fully-future blocks contribute 0
-    else:
-        _compute()
+    _causal_walk(_walk, causal, qi, ki)
 
-
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, scale, causal, block_q,
-                          block_k):
-    from jax.experimental import pallas as pl
-    ki = pl.program_id(2)
-    qi = pl.program_id(3)
-
-    @pl.when(qi == 0)
-    def _init():
-        dk_ref[...] = jnp.zeros_like(dk_ref)
-        dv_ref[...] = jnp.zeros_like(dv_ref)
-
-    def _compute():
-        k = k_ref[...]
-        v = v_ref[...]
-        q = q_ref[...]
-        do = do_ref[...]
-        lse = lse_ref[...][0]
-        delta = delta_ref[...][0]
-        s = _dot_f32(q, k, ((1,), (1,))) * jnp.float32(scale)
-        if causal:
-            q_pos = qi * jnp.int32(block_q) + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * jnp.int32(block_k) + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, jnp.float32(-1e30))
-        p = jnp.exp(s - lse[:, None])
-        # p.T @ do and ds.T @ q, contracting over the block_q axis
-        dv_ref[...] += _dot_f32(p.astype(do.dtype), do, ((0,), (0,)))
-        dp = _dot_f32(do, v, ((1,), (1,)))
-        ds = p * (dp - delta[:, None])
-        dk_ref[...] += _dot_f32(ds.astype(q.dtype), q,
-                                ((0,), (0,))) * jnp.float32(scale)
-
-    if causal:
-        pl.when(qi >= ki)(_compute)
-    else:
-        _compute()
+    @pl.when(inner == n - 1)
+    def _store():
+        # s = scale * q.k: dQ takes the factor here; dK got it with
+        # the folded q, or takes it here too
+        grads = []
+        if want_dq:
+            grads.append((dqt_acc[...] * jnp.float32(scale)).T)
+        if want_dkv:
+            dk = dk_acc[...]
+            grads += [dk if _exact_scale(scale)
+                      else dk * jnp.float32(scale), dv_acc[...]]
+        for out, g in zip(outs, grads):
+            out[...] = g.astype(out.dtype)
 
 
 def _pallas_flash_bwd(q, k, v, out, lse, g, scale, causal):
-    return _trace_32bit(_pallas_flash_bwd_32)(q, k, v, out, lse, g,
-                                              scale, causal)
+    return _pallas_flash_bwd_32(q, k, v, out, lse, g, scale, causal,
+                                _interpret())
 
 
-def _pallas_flash_bwd_32(q, k, v, out, lse, g, scale, causal):
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+@_trace_32bit
+def _pallas_flash_bwd_32(q, k, v, out, lse, g, scale, causal, interpret):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
     b, h, s, d = q.shape
-    block = _block_for(s, _BLOCK_BWD)
+    block = _block(s, d, q.dtype.itemsize)
     n = s // block
     # delta = rowsum(dO * O): O(s d) precompute outside the kernels
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, :, None, :]  # [b, h, 1, s]
 
-    def blk(which):  # index by grid dim 2 or 3
-        return pl.BlockSpec(
-            (None, None, block, d),
-            (lambda bi, hi, i, j: (bi, hi, i, 0)) if which == 2
-            else (lambda bi, hi, i, j: (bi, hi, j, 0)))
+    def call(name, want_dq, want_dkv):
+        # grid (b, h, i, j), j innermost: (i, j) = (query, key) tile
+        # for dQ alone, (key, query) with dK/dV. A tile above the
+        # diagonal computes nothing and names the block already in VMEM
+        if want_dkv:
+            k_at = lambda i, j: i
+            q_at = (lambda i, j: jnp.maximum(j, i)) if causal else (
+                lambda i, j: j)
+        else:
+            q_at = lambda i, j: i
+            k_at = (lambda i, j: jnp.minimum(j, i)) if causal else (
+                lambda i, j: j)
 
-    def vec(which):
-        return pl.BlockSpec(
-            (None, None, 1, block),
-            (lambda bi, hi, i, j: (bi, hi, 0, i)) if which == 2
-            else (lambda bi, hi, i, j: (bi, hi, 0, j)))
+        def blk(at):
+            return pl.BlockSpec((None, None, block, d),
+                                lambda bi, hi, i, j: (bi, hi, at(i, j), 0))
 
-    f32 = jnp.float32
-    # dq: grid (b, h, nq, nk); dq block revisited across nk
-    dq_kernel = functools.partial(_flash_bwd_dq_kernel, scale=scale,
-                                  causal=causal, block_q=block,
-                                  block_k=block)
-    dq = pl.pallas_call(dq_kernel, name="flash_bwd_dq",
-        grid=(b, h, n, n),
-        in_specs=[blk(2), blk(3), blk(3), blk(2), vec(2), vec(2)],
-        out_specs=blk(2),
-        out_shape=jax.ShapeDtypeStruct(q.shape, f32),
-        interpret=_interpret(),
-    )(q, k, v, g, lse, delta)
+        row = pl.BlockSpec((None, None, 1, block),
+                           lambda bi, hi, i, j: (bi, hi, 0, q_at(i, j)))
+        # outputs by the OUTER tile (with dK/dV, dQ only where n == 1)
+        grads = [x for x, want in ((q, want_dq), (k, want_dkv),
+                                   (v, want_dkv)) if want]
+        kernel = functools.partial(
+            _flash_bwd_kernel, scale=scale, causal=causal, block=block,
+            strip=min(_BWD_STRIP, block), n=n, want_dq=want_dq,
+            want_dkv=want_dkv)
+        return pl.pallas_call(kernel, name=name,
+            grid=(b, h, n, n),
+            in_specs=[blk(q_at), blk(k_at), blk(k_at), blk(q_at), row, row],
+            out_specs=[blk(lambda i, j: i) for _ in grads],
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                       for x in grads],
+            # dQ.T, then dK and dV
+            scratch_shapes=[pltpu.VMEM(
+                (d, block) if x is q else (block, d), jnp.float32)
+                for x in grads],
+            interpret=interpret,
+        )(q, k, v, g, lse, delta)
 
-    # dk/dv: grid (b, h, nk, nq); dk/dv blocks revisited across nq
-    dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, scale=scale,
-                                   causal=causal, block_q=block,
-                                   block_k=block)
-    dk, dv = pl.pallas_call(dkv_kernel, name="flash_bwd_dkv",
-        grid=(b, h, n, n),
-        in_specs=[blk(3), blk(2), blk(2), blk(3), vec(3), vec(3)],
-        out_specs=[blk(2), blk(2)],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, f32),
-                   jax.ShapeDtypeStruct(v.shape, f32)],
-        interpret=_interpret(),
-    )(q, k, v, g, lse, delta)
-    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype))
+    if n == 1:
+        return tuple(call("flash_bwd_dqkv", True, True))
+    (dq,) = call("flash_bwd_dq", True, False)
+    dk, dv = call("flash_bwd_dkv", False, True)
+    return dq, dk, dv
 
 
 # ---- custom-vjp wrapper ----------------------------------------------------

@@ -13,6 +13,9 @@ import time: only one process may hold libtpu, and every xdist worker
 imports every test file. Keep all such tests in THIS file so they land
 on one worker (``--dist loadfile``).
 """
+import re
+from unittest import mock
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -65,20 +68,85 @@ def _qkv(one_chip, batch, seq, dtype=jnp.bfloat16):
                                 sharding=one_chip)
 
 
-@pytest.mark.parametrize("batch,seq", [(8, 1024), (1, 8192)])
+def _instruction_names(text):
+    """Names of the optimized program's instructions: a Pallas kernel's
+    ``name=`` becomes its custom call's (``%flash_fwd = ...``), which
+    is what the benchmark's per-kernel readers match in the trace."""
+    return re.findall(r"%([\w.-]+) = ", text)
+
+
+# (24, 1024) is the training cell's own shape: one tile a head, one
+# backward kernel; (1, 8192) streams K/V tile by tile through the grid
+# and takes the backward's two kernels
+FLASH_SHAPES = [(8, 1024), (24, 1024), (1, 8192)]
+
+
+@pytest.mark.parametrize("batch,seq", FLASH_SHAPES)
 def test_flash_fwd_compiles(one_chip, batch, seq):
     q = _qkv(one_chip, batch, seq)
-    _compile(lambda q, k, v: attention._pallas_flash_fwd(
+    text = _compile(lambda q, k, v: attention._pallas_flash_fwd(
         q, k, v, HEAD_DIM ** -0.5, True), q, q, q)
+    # benchmarks/metrics/flash_fwd_dev_ms_per_step.py reads this name
+    assert any("flash_fwd" in n for n in _instruction_names(text))
 
 
-@pytest.mark.parametrize("batch,seq", [(8, 1024), (1, 8192)])
+@pytest.mark.parametrize("batch,seq", FLASH_SHAPES)
 def test_flash_bwd_compiles(one_chip, batch, seq):
     q = _qkv(one_chip, batch, seq)
     lse = jax.ShapeDtypeStruct((batch, HEADS, 1, seq), jnp.float32,
                                sharding=one_chip)
-    _compile(lambda q, k, v, o, lse, g: attention._pallas_flash_bwd(
+    text = _compile(lambda q, k, v, o, lse, g: attention._pallas_flash_bwd(
         q, k, v, o, lse, g, HEAD_DIM ** -0.5, True), q, q, q, q, lse, q)
+    # ... and flash_bwd_dev_ms_per_step.py this prefix, on every kernel
+    # of the backward
+    kernels = [n for n in _instruction_names(text) if "flash" in n]
+    assert kernels and all("flash_bwd_" in n for n in kernels), kernels
+    # gradients leave the kernels in the inputs' dtype: no f32 copy of
+    # them, and no fusion that casts one
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    assert calls and not any(
+        f"f32[{batch},{HEADS},{seq},{HEAD_DIM}]" in l for l in calls)
+
+
+def test_replayed_flash_fwd_merges_with_the_first(one_chip):
+    """The tape's backward runs jax.vjp of an op's forward again
+    (core/engine.py), so a step holds every layer's forward twice. The
+    kernels' wrappers are jitted: the two calls are then one computation
+    on the same operands to XLA, which keeps one."""
+    q = _qkv(one_chip, 24, 1024)
+
+    def taped(q, k, v, g):
+        core = lambda *a: attention._flash_attention_core(
+            *a, HEAD_DIM ** -0.5, True)
+        out = core(q, k, v)                       # the forward pass
+        _, vjp = jax.vjp(core, q, k, v)           # the tape's replay
+        return out, vjp(g)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = _compile(taped, q, q, q, q)
+    kernels = [n for line in text.splitlines() if "tpu_custom_call" in line
+               for n in _instruction_names(line)]
+    assert sorted(n.split(".")[0] for n in kernels) == [
+        "flash_bwd_dqkv", "flash_fwd"], kernels
+
+
+# what else runs the kernels (nn.functional.scaled_dot_product_attention
+# for every model): wider heads, f32 operands (no scale folded at 128:
+# 1/sqrt(128) is no power of two), no causal mask
+@pytest.mark.parametrize("d,dtype,causal", [
+    (128, jnp.bfloat16, True), (256, jnp.bfloat16, True),
+    (64, jnp.float32, True), (128, jnp.float32, False),
+    (64, jnp.bfloat16, False)],
+    ids=["d128", "d256", "f32", "f32-d128-full", "full"])
+def test_flash_compiles_at_other_shapes(one_chip, d, dtype, causal):
+    batch, seq = 2, 2048
+    q = jax.ShapeDtypeStruct((batch, HEADS, seq, d), dtype,
+                             sharding=one_chip)
+
+    def both(q, k, v, g):
+        o, lse = attention._pallas_flash_fwd(q, k, v, d ** -0.5, causal)
+        return attention._pallas_flash_bwd(q, k, v, o, lse, g, d ** -0.5,
+                                           causal)
+    _compile(both, q, q, q, q)
 
 
 def _ce_args(sharding_x, sharding_w, sharding_t, vocab=VOCAB):

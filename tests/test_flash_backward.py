@@ -43,46 +43,74 @@ def test_flash_fwd_matches_reference(causal):
                                rtol=1e-4, atol=1e-4)
 
 
+# (384, 64): the preferred tile does not divide the sequence (tiles of
+# 128, two backward kernels); (1024, 64): the training cell's own shape
+# (one tile a head, walked in strips up to the diagonal, one backward
+# kernel); (512, 128): a head that fills the lanes, and a scale that
+# does not fold into q; (2048, 64): two tiles a side, each of several
+# strips (the clamped index maps and the diagonal walk together)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("seq,d", [(256, 64), (384, 64), (1024, 64),
+                                   (512, 128), (2048, 64)])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_bwd_matches_reference(causal):
-    q, k, v = _qkv(256)
-    scale = 1.0 / np.sqrt(q.shape[-1])
+def test_flash_matches_reference_in_f32(causal, seq, d, dtype):
+    """Output and dq / dk / dv of the kernels against the dense
+    composition computed in f32 from the same (rounded) inputs, as a
+    share of each array's largest entry: f32 inputs to rounding, bf16
+    inputs to the rounding of p and ds that the MXU operands carry."""
+    q, k, v = (x.astype(dtype) for x in _qkv(seq, d=d, seed=seq + d))
+    w = jnp.asarray(np.random.RandomState(1).randn(*q.shape)
+                    .astype("float32"))
+    scale = 1.0 / np.sqrt(d)
+    f32 = lambda x: x.astype(jnp.float32)
 
-    def f_flash(q_, k_, v_):
-        return jnp.sum(attn._flash_attention_core(q_, k_, v_, scale,
-                                                  causal) ** 2)
+    def flash(q_, k_, v_):
+        return f32(attn._flash_attention_core(q_, k_, v_, scale, causal))
 
-    def f_ref(q_, k_, v_):
-        return jnp.sum(attn._reference_attention(q_, k_, v_, None, scale,
-                                                 causal) ** 2)
+    def dense(q_, k_, v_):
+        return attn._reference_attention(f32(q_), f32(k_), f32(v_), None,
+                                         scale, causal)
 
-    g1 = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b, name in zip(g1, g2, "qkv"):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=5e-3, atol=5e-4,
-            err_msg=f"d{name} mismatch")
+    got = (flash(q, k, v),) + jax.grad(
+        lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    want = (dense(q, k, v),) + jax.grad(
+        lambda *a: jnp.sum(dense(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    limit = 2e-5 if dtype == jnp.float32 else 2e-2
+    for a, b, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        assert a.dtype == (jnp.float32 if name == "out" else dtype)
+        gap = float(jnp.max(jnp.abs(f32(a) - f32(b)))
+                    / jnp.max(jnp.abs(f32(b))))
+        assert gap < limit, (name, gap)
 
 
-def test_flash_bwd_multiblock_seq():
-    # seq > block (128): exercises the fori_loop block iteration and the
-    # causal first-block skip in the dkv kernel
-    q, k, v = _qkv(384, seed=3)
-    scale = 0.125
+@pytest.mark.parametrize("s,d,itemsize,block", [
+    (1024, 64, 2, 1024),    # the training cell: one tile a head
+    (8192, 64, 2, 1024),    # K/V streamed tile by tile
+    (384, 64, 2, 128),      # 256 and up do not divide it
+    (1024, 64, 4, 1024), (1024, 128, 4, 512),
+    (2048, 256, 2, 512), (2048, 256, 4, 256)])
+def test_tile_follows_the_shape(s, d, itemsize, block):
+    assert attn._block(s, d, itemsize) == block
+    assert block * d * itemsize <= 256 << 10 and s % block == 0
 
-    def f_flash(q_, k_, v_):
-        return jnp.sum(attn._flash_attention_core(q_, k_, v_, scale,
-                                                  True) * 0.01) ** 2
 
-    def f_ref(q_, k_, v_):
-        return jnp.sum(attn._reference_attention(q_, k_, v_, None, scale,
-                                                 True) * 0.01) ** 2
+@pytest.mark.parametrize("d,exact", [(64, True), (256, True),
+                                     (128, False), (96, False)])
+def test_scale_folds_into_q_only_where_exact(d, exact):
+    assert attn._exact_scale(d ** -0.5) == exact
 
-    g1 = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g1, g2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=5e-3, atol=1e-5)
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_only_causal_kernels_build_a_mask(causal):
+    # bidirectional attention keeps no mask at all: no iota, no select
+    # in the forward or the backward kernel
+    q = jnp.ones((1, 1, 256, 64), jnp.bfloat16)
+
+    def loss(q_, k_, v_):
+        return attn._flash_attention_core(q_, k_, v_, 0.125, causal).sum()
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, q, q))
+    assert ("iota" in text and "select_n" in text) == causal
 
 
 def test_flash_bwd_inside_train_step():

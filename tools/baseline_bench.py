@@ -165,8 +165,7 @@ def bench_gpt(batch=8, seq=1024, steps=20, amp_level=None):
 
     Knobs (also see tools/gpt_mfu_sweep.py): batch/seq from argv,
     GPT_AMP_LEVEL=O1|O2 (O2 = pure-bf16 compute, fp32 master weights in
-    the optimizer — halves the cast traffic), PADDLE_FLASH_BLOCK_* for
-    the attention kernel tile sweep."""
+    the optimizer — halves the cast traffic)."""
     import paddle_tpu as paddle
     from paddle_tpu.text.models import TransformerLMConfig, GPTForCausalLM
 

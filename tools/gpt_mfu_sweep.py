@@ -2,16 +2,17 @@
 
 Runs tools/baseline_bench.py's GPT config across the tuning axes that
 matter on one chip — AMP level (O1 per-op autocast vs O2 pure-bf16),
-flash-attention tile sizes (fwd and bwd independently), and the
-seq 2048/4096 extension points — each in a FRESH SUBPROCESS, one after
+the fused head, and the seq 2048/4096 extension points (the flash
+kernels pick their own tiles from the shape: ops/attention._block) —
+each in a FRESH SUBPROCESS, one after
 another (the env knobs are read at import, and the chip belongs to one
 process at a time; this parent never touches jax). Every result line
 is appended to an artifact in chiprun_out/ (what a chip run brings
 back).
 
 Usage:  python tools/gpt_mfu_sweep.py [quick|full]
-  quick: amp sweep + best-guess block sweep at seq 1024 (~6 configs)
-  full:  + seq 2048/4096 points and the full block grid
+  quick: amp and head sweep at seq 1024
+  full:  + seq 2048/4096 points
 """
 import json
 import os
@@ -113,19 +114,6 @@ def main():
          {"GPT_AMP_LEVEL": "O2",
           "PADDLE_FUSED_CE_DISABLE": "1",
           "GPT_PROFILE_DIR": os.path.join(_ART, "gpt_profile")}),
-        # attention-axis configs run UNFUSED (nf): the 2026-08-02 window
-        # showed the fused head costs ~46 ms/step, which would drown the
-        # flash-tile deltas these configs exist to measure
-        ("O2_nf_blk256_bwd", 8, 1024, {"GPT_AMP_LEVEL": "O2",
-                                       "PADDLE_FUSED_CE_DISABLE": "1",
-                                       "PADDLE_FLASH_BLOCK_BWD": "256"}),
-        ("O2_nf_blk1024", 8, 1024, {"GPT_AMP_LEVEL": "O2",
-                                    "PADDLE_FUSED_CE_DISABLE": "1",
-                                    "PADDLE_FLASH_BLOCK_Q": "1024",
-                                    "PADDLE_FLASH_BLOCK_K": "1024"}),
-        ("O2_nf_blk1024_bwd", 8, 1024, {"GPT_AMP_LEVEL": "O2",
-                                        "PADDLE_FUSED_CE_DISABLE": "1",
-                                        "PADDLE_FLASH_BLOCK_BWD": "1024"}),
         # LAST in the quick list (the longest compile); unfused so the
         # batch-scaling axis is clean of the head question
         ("O2_nf_batch16", 16, 1024, {"GPT_AMP_LEVEL": "O2",
@@ -133,9 +121,6 @@ def main():
     ]
     if mode == "full":
         configs += [
-            ("O1_nf_blk256_bwd", 8, 1024, {"GPT_AMP_LEVEL": "O1",
-                                           "PADDLE_FUSED_CE_DISABLE": "1",
-                                           "PADDLE_FLASH_BLOCK_BWD": "256"}),
             ("O2_nf_seq2048", 4, 2048, {"GPT_AMP_LEVEL": "O2",
                                         "PADDLE_FUSED_CE_DISABLE": "1"}),
             ("O2_nf_seq4096", 2, 4096, {"GPT_AMP_LEVEL": "O2",
