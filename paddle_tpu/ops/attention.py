@@ -459,7 +459,9 @@ def cached_slot_attention(q, k_cache, v_cache, lengths):
     against.
 
     q [S, nh, hd] — one new-token query per slot;
-    k_cache/v_cache [S, nh, C, hd] — each slot's full static cache;
+    k_cache/v_cache [S, nkv, C, hd] — each slot's full static cache
+    (grouped queries: ``nkv`` divides ``nh``, query head ``h`` reads KV
+    head ``h // (nh // nkv)``; a GPT has ``nkv == nh``);
     lengths [S] int — live prefix length per slot (prompt + generated
     so far, INCLUDING the row just written for this step).
 
@@ -471,6 +473,20 @@ def cached_slot_attention(q, k_cache, v_cache, lengths):
     vectorized over slots."""
     hd = q.shape[-1]
     cache_len = k_cache.shape[2]
+    nh, nkv = q.shape[1], k_cache.shape[1]
+    if nh != nkv:
+        # the queries of a group as a batch over their one KV head
+        S = q.shape[0]
+        qg = q.reshape(S, nkv, nh // nkv, hd)
+        s = jnp.einsum("sngd,snkd->sngk", qg, k_cache,
+                       preferred_element_type=jnp.float32) / jnp.sqrt(
+            jnp.float32(hd))
+        kpos = jnp.arange(cache_len)[None, None, None, :]
+        s = jnp.where(kpos < lengths[:, None, None, None], s,
+                      jnp.float32(-1e30))
+        return jnp.einsum("sngk,snkd->sngd", jax.nn.softmax(s, axis=-1),
+                          v_cache, preferred_element_type=jnp.float32
+                          ).astype(q.dtype).reshape(S, nh, hd)
     # f32 score accumulation (the _dot_f32 discipline): bf16 caches
     # keep full MXU rate but never sum scores in bf16; a no-op for f32
     s = jnp.einsum("shd,shkd->shk", q, k_cache,
@@ -486,14 +502,51 @@ def cached_slot_attention(q, k_cache, v_cache, lengths):
                       ).astype(q.dtype)
 
 
+def grouped_causal_attention(q, k_view, v_view, q_pos, q_block=128):
+    """Causal attention of ONE sequence's run of queries over a
+    position-ordered view of its cache, with grouped queries (a paged
+    PREFILL: the run's own keys and values are already in the view).
+
+    q ``[T, nh, hd]`` at absolute positions ``q_pos [T]``; k_view,
+    v_view ``[nkv, C, hd]`` (view index == position; ``nkv`` divides
+    ``nh``, query head ``h`` reads KV head ``h // (nh // nkv)``). Key
+    ``s`` is seen by query ``t`` when ``s <= q_pos[t]``. Scores and
+    softmax in f32, scale ``hd ** -0.5``; computed ``q_block`` query
+    rows at a time so that the ``[nh, rows, C]`` scores stay a temporary
+    of that size. Returns ``[T, nh, hd]`` in q's dtype."""
+    T, nh, hd = q.shape
+    nkv, C = k_view.shape[:2]
+    kpos = jnp.arange(C, dtype=jnp.int32)
+
+    def rows(qb, pos):
+        qg = qb.reshape(qb.shape[0], nkv, nh // nkv, hd)
+        s = jnp.einsum("tngd,ncd->ngtc", qg, k_view,
+                       preferred_element_type=jnp.float32) / jnp.sqrt(
+            jnp.float32(hd))
+        s = jnp.where(kpos[None, None, None, :] <= pos[None, None, :, None],
+                      s, jnp.float32(-1e30))
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("ngtc,ncd->tngd", p.astype(v_view.dtype), v_view,
+                       preferred_element_type=jnp.float32)
+        return o.astype(q.dtype).reshape(qb.shape[0], nh, hd)
+
+    qb = min(int(q_block), T)
+    if T % qb or T == qb:
+        return rows(q, q_pos)
+    out = jax.lax.map(lambda a: rows(*a),
+                      (q.reshape(T // qb, qb, nh, hd),
+                       q_pos.reshape(T // qb, qb)))
+    return out.reshape(T, nh, hd)
+
+
 def cached_paged_attention(q, k_cache, v_cache, block_tables, lengths):
     """Single-token decode attention over a PAGED cache addressed
     through a fixed-shape block table (the serving paged decode step,
     serving.paged.programs.build_paged_fns).
 
     q [S, nh, hd] — one new-token query per slot;
-    k_cache/v_cache [num_blocks, nh, block_size, hd] — one layer's
-    pooled block arrays;
+    k_cache/v_cache [num_blocks, nkv, block_size, hd] — one layer's
+    pooled block arrays (``nkv`` divides ``nh``: grouped queries);
     block_tables [S, max_blocks] int — each slot's logical->physical
     block row (padding/released entries point at the trash block);
     lengths [S] int — live prefix length per slot, INCLUDING the row
@@ -511,7 +564,8 @@ def cached_paged_attention(q, k_cache, v_cache, block_tables, lengths):
     (ops.paged_attention, which reads the live blocks in place) cannot
     (the CPU, shapes ``kernel_viable`` refuses), and that kernel's
     parity oracle. Its cost is the capacity's, whatever is live."""
-    S, nh, hd = q.shape
+    S, _, hd = q.shape
+    nh = k_cache.shape[1]
     with jax.named_scope("kv_gather"):
         k = jnp.take(k_cache, block_tables, axis=0)  # [S, MB, nh, BS, hd]
         v = jnp.take(v_cache, block_tables, axis=0)

@@ -2,9 +2,10 @@
 
 Single-token decode attention computed DIRECTLY over the paged pool
 layout (vLLM's PagedAttention idea, SOSP'23, done TPU-natively): q
-``[S, nh, hd]``, pooled ``k_cache``/``v_cache``
-``[num_blocks, nh, BS, hd]``, fixed-shape ``block_tables [S, MB]``,
-per-slot ``lengths``. The ``[S, nh, MB*BS, hd]`` gathered view the XLA
+``[S, nq, hd]``, pooled ``k_cache``/``v_cache``
+``[num_blocks, nh, BS, hd]`` (``nh`` KV heads; ``nq = nh`` for a GPT,
+a multiple of it for grouped queries), fixed-shape ``block_tables [S,
+MB]``, per-slot ``lengths``. The ``[S, nh, MB*BS, hd]`` gathered view the XLA
 composition (``ops.attention.cached_paged_attention``) materializes is
 never built, and the kernel's work follows each slot's LIVE length,
 not its capacity.
@@ -26,9 +27,11 @@ flight behind the one being computed.
 
 A chunk's arithmetic is two batched MXU matmuls over the heads:
 scores ``q [nh, R, hd] x K [nh, T, hd]^T`` and ``p [nh, R, T] x
-V [nh, T, hd]``, with the one query row replicated to the operand
-tile's ``R`` rows (a lane reduce over ``hd`` on the VPU is what held the
-block-a-step kernel to a few percent of the bandwidth). Precision is
+V [nh, T, hd]``. The operand tile's ``R`` rows are the query heads of
+the KV head's GROUP: one row replicated where ``nq = nh`` (a lane
+reduce over ``hd`` on the VPU is what held the block-a-step kernel to a
+few percent of the bandwidth), the group's ``nq / nh`` heads, padded to
+whole sublane tiles, where queries are grouped (``_group_rows``). Precision is
 the oracle's: products of pool-dtype values accumulate in f32, the
 softmax is f32, and its f32 weights are NOT rounded to the pool's
 dtype for the second matmul: with a 16-bit pool rows ``[0, R/2)`` carry
@@ -105,7 +108,7 @@ def blocks_per_chunk(num_heads, head_dim, block_size, max_blocks, dtype):
 
 def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                          kbuf, vbuf, q_rows, acc_ref, m_ref, l_ref, sem,
-                         half_ref, *, block_size, group):
+                         half_ref, *, block_size, group, q_group=1):
     """Grid (S,), sequential. ``kbuf``/``vbuf`` ``[2, nh, G*BS, hd]``
     are the two halves of the chunk buffers, ``sem[0/1, half]`` the K/V
     copies' semaphores, ``half_ref`` (SMEM) the half that holds this
@@ -197,13 +200,18 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     @pl.when(chunks > 0)
     def _live():
-        # the one query row, replicated to the operand tile's rows: via
-        # f32 (Mosaic has no 16-bit [nh,hd] -> [nh,1,hd] shape cast) and
-        # through VMEM (its batched matmul cannot take the broadcast's
-        # replicated layout as an operand: apply-vector-layout aborts)
-        q_rows[...] = jnp.broadcast_to(
-            q_ref[si].astype(jnp.float32)[:, None, :],
-            (nh, rows, hd)).astype(q_rows.dtype)
+        if q_group > 1:
+            # the group's query heads, laid out by the wrapper
+            q_rows[...] = q_ref[si]
+        else:
+            # the one query row, replicated to the operand tile's rows:
+            # via f32 (Mosaic has no 16-bit [nh,hd] -> [nh,1,hd] shape
+            # cast) and through VMEM (its batched matmul cannot take the
+            # broadcast's replicated layout as an operand:
+            # apply-vector-layout aborts)
+            q_rows[...] = jnp.broadcast_to(
+                q_ref[si].astype(jnp.float32)[:, None, :],
+                (nh, rows, hd)).astype(q_rows.dtype)
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -211,15 +219,19 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         acc, l = acc_ref[...], l_ref[...]
         if split:
             acc = acc[:, :rows // 2] + acc[:, rows // 2:]
-        # l >= 1 (the max's own exp term); every row of acc is the same
-        o_ref[si] = (jnp.max(acc, axis=1)
-                     / jnp.max(l, axis=1)).astype(o_ref.dtype)
+        if q_group > 1:
+            # l >= 1 (the max's own exp term); a row a query head
+            o_ref[si] = (acc / l[:, :acc.shape[1]]).astype(o_ref.dtype)
+        else:
+            # every row of acc is the same
+            o_ref[si] = (jnp.max(acc, axis=1)
+                         / jnp.max(l, axis=1)).astype(o_ref.dtype)
 
     @pl.when(chunks == 0)
     def _idle():
         # nothing live (a released slot): no copy, no arithmetic, a
         # finite row nobody reads; the hand-over still happens
-        o_ref[si] = jnp.zeros((nh, hd), o_ref.dtype)
+        o_ref[si] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
 
         @pl.when(has_next)
         def _():
@@ -228,35 +240,53 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     half_ref[0] = (half0 + chunks) % 2
 
 
+def _group_rows(q_group, dtype):
+    """(rows of the operand tile, rows that are distinct query heads):
+    the group padded to whole 8-row tiles, twice over for a 16-bit pool
+    (whose two halves carry the softmax weights' two parts)."""
+    heads = -(-q_group // 8) * 8
+    return (heads if dtype == jnp.float32 else 2 * heads), heads
+
+
 def _paged_decode_32(q, k_cache, v_cache, block_tables, lengths):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    S, nh, hd = q.shape
-    BS = k_cache.shape[2]
+    S, nq, hd = q.shape
+    nh, BS = k_cache.shape[1:3]
     MB = block_tables.shape[1]
     dtype = k_cache.dtype
+    q_group = nq // nh
     G = blocks_per_chunk(nh, hd, BS, MB, dtype)
     # the operand tile's sublanes: 8 of f32, 16 of a 16-bit type (whose
     # two halves carry the softmax weights' two parts)
-    rows = 8 if dtype == jnp.float32 else 16
+    rows, heads = _group_rows(q_group, dtype)
     block_tables = block_tables.astype(jnp.int32)
     lengths = lengths.astype(jnp.int32)
+    if q_group > 1:
+        # [S, nh, rows, hd]: a KV head's query heads as the tile's rows
+        q = q.reshape(S, nh, q_group, hd)
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, heads - q_group), (0, 0)))
+        if rows > heads:
+            q = jnp.concatenate([q, q], axis=2)
+        o_shape = (S, nh, heads, hd)
+    else:
+        o_shape = (S, nh, hd)
 
     def whole(si, bt_ref, len_ref):
         # q and o stay in VMEM for the whole call (a block a grid step
         # would put two small copies' latency into every step, which is
         # most of a step that has little or nothing live)
-        return (0, 0, 0)
+        return (0,) * len(o_shape)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(S,),
         in_specs=[
-            pl.BlockSpec((S, nh, hd), whole),
+            pl.BlockSpec(q.shape, whole),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((S, nh, hd), whole),
+        out_specs=pl.BlockSpec(o_shape, whole),
         scratch_shapes=[
             pltpu.VMEM((2, nh, G * BS, hd), dtype),
             pltpu.VMEM((2, nh, G * BS, hd), dtype),
@@ -269,15 +299,18 @@ def _paged_decode_32(q, k_cache, v_cache, block_tables, lengths):
         ],
     )
     kernel = functools.partial(_paged_decode_kernel, block_size=BS,
-                               group=G)
-    return pl.pallas_call(
+                               group=G, q_group=q_group)
+    o = pl.pallas_call(
         kernel, name="paged_decode_attn", grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, nh, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(o_shape, q.dtype),
         # sequential: a slot's last chunk starts the next slot's first
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(block_tables, lengths, q, k_cache, v_cache)
+    if q_group > 1:
+        o = o[:, :, :q_group].reshape(S, nq, hd)
+    return o
 
 
 def paged_decode_attention(q, k_cache, v_cache, block_tables, lengths):

@@ -239,7 +239,10 @@ Tuning knobs
 ``async_depth`` 1 (default) = one-step-deep decode pipelining; 0 =
                 fully synchronous per-step host reads (can win on
                 churn-heavy tiny-model CPU workloads where every step
-                prefills).
+                prefills); k > 1 = up to k steps' results unread, so
+                the device holds k steps of queued work while the
+                host is away (tokens surface k steps late; refused
+                with ``speculative``).
 ``donate_buffers``
                 None (default) = donate kc/vc/pos where the backend
                 aliases donated buffers (TPU/GPU); True/False forces.

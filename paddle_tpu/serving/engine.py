@@ -34,7 +34,12 @@ ticks:
     ``async_depth=0`` restores the fully synchronous schedule — on
     CPU's serial device queue it can win on churn-heavy tiny-model
     workloads (every step prefilling), while the pipeline pays off
-    when decode dominates the step.
+    when decode dominates the step. ``async_depth=k`` keeps up to k
+    steps' results unread: the device then holds k steps of queued
+    work, so a host that is away for less than that (a collector's
+    pause, a frozen machine) stalls nothing; a token surfaces k steps
+    after its dispatch and an EOS stop masks k tokens. Speculative
+    decoding drafts from harvested tokens and is refused beyond 1.
 
 Compiled program inventory for a whole serving lifetime:
   * one decode step at the fixed pooled-cache shape,
@@ -56,6 +61,7 @@ TTFT target is already unrecoverable (goodput under overload), and
 per-slot sampling threads temperature/top-k/top-p through the one
 compiled decode.
 """
+import collections
 import os
 import time
 import warnings
@@ -156,7 +162,8 @@ class ServingConfig:
     the model's max_seq_len); buckets/bucket_min shape the prefill
     compile set; async_depth selects the
     decode pipeline depth (1 = read step N's tokens after dispatching
-    step N+1, 0 = synchronous); eos_id is the default stop token."""
+    step N+1, k = after dispatching step N+k, 0 = synchronous);
+    eos_id is the default stop token."""
 
     def __init__(self, num_slots=8, max_len=None, buckets=None,
                  bucket_min=32, eos_id=None, async_depth=1,
@@ -186,10 +193,11 @@ class ServingConfig:
         self.bucket_min = int(bucket_min)
         self.eos_id = eos_id
         self.async_depth = int(async_depth)
-        if self.async_depth not in (0, 1):
+        if self.async_depth < 0:
             raise ValueError(
-                f"async_depth must be 0 (synchronous) or 1 (one-step-"
-                f"deep pipeline), got {async_depth}")
+                f"async_depth must be 0 (synchronous) or the number "
+                f"of steps kept in flight (1 = one-step-deep "
+                f"pipeline), got {async_depth}")
         # None = auto: donate kc/vc/pos where the backend aliases
         # donated buffers (TPU/GPU). On CPU donation never aliases but
         # JAX still enforces the input invalidation AND charges ~40us
@@ -374,6 +382,12 @@ class ServingConfig:
                 "speculative decoding is greedy-only (draft acceptance "
                 "compares against argmax); drop sampling=True or "
                 "speculative=True")
+        if self.speculative and self.async_depth > 1:
+            raise ValueError(
+                "speculative decoding drafts from each request's last "
+                "HARVESTED token, so it keeps at most one step in "
+                f"flight; drop speculative=True or async_depth="
+                f"{self.async_depth}")
         # replica role in a disaggregated fleet (None = env override):
         # "monolithic" (default) serves prefill+decode like every
         # prior PR; "prefill" replicas compute KV for admitted
@@ -726,7 +740,10 @@ class ServingEngine:
         # never donated, so whoever reads it holds a live array
         self._state = tuple(jnp.zeros(shape, dt) for _, shape, dt
                             in self.cache_spec.state)
-        self._pending = []  # dispatched, not-yet-read device results
+        # dispatched, not-yet-read device results, oldest first, and
+        # how many of them each step still in flight dispatched
+        self._pending = []
+        self._pending_steps = collections.deque()
         # first callback's start / summed callback seconds of the
         # harvest in progress (its serving/on_token span)
         self._on_token_t0, self._on_token_s = None, 0.0
@@ -753,6 +770,8 @@ class ServingEngine:
         self.metrics.cache.attach_pool(self.pool)
         self.metrics.set_kv_bytes_per_token(
             self.cache_spec.bytes_per_token)
+        self.metrics.set_state_bytes_per_slot(
+            self.cache_spec.bytes_per_slot)
         moe = getattr(model, "moe_counter_layout", None)
         if moe is not None:
             self.metrics.set_moe_counters(
@@ -838,6 +857,8 @@ class ServingEngine:
             raise RuntimeError(
                 "engine is draining/closed: no new requests (drain() "
                 "finishes already-submitted work, close() aborts it)")
+        if hold_kv:
+            self._require_kv_pair("hold_kv")
         ctx = self._TraceContext.coerce(trace)
         if tenant_id is None:
             tenant_id = ctx.baggage.get("tenant")
@@ -1035,6 +1056,15 @@ class ServingEngine:
 
     # ------------------------------------------- disaggregated handoff
 
+    def _require_kv_pair(self, what):
+        """``serving.kv_wire`` carries a GPT's (k, v) pair: a model
+        with another cache is refused the hand-off by name."""
+        if not self._kv_pair:
+            raise NotImplementedError(
+                f"{what}: the KV wire carries a (k, v) pair a token; "
+                f"this model's cache spec names "
+                f"{[a.name for a in self.cache_spec.arrays]}")
+
     def export_kv(self, rid):
         """Serialize a retired ``hold_kv`` request's prompt KV blocks
         into a wire payload (see serving.kv_wire) and release its
@@ -1044,6 +1074,7 @@ class ServingEngine:
         pool; everything after the single host read-back is pure numpy,
         so the transfer loop never traces. The slot is released even
         when serialization fails: a prefill tier never leaks blocks."""
+        self._require_kv_pair("export_kv")
         req = self._held_exports.pop(rid, None)
         if req is None:
             raise KeyError(
@@ -1110,6 +1141,7 @@ class ServingEngine:
         the imported prompt's full blocks through the radix index, so
         later local admissions hit them and the fleet heat map sees
         this replica as the prefix's owner. Returns the live Request."""
+        self._require_kv_pair("import_kv")
         if self._draining or self._closed:
             raise RuntimeError(
                 "engine is draining/closed: no new requests (drain() "
@@ -1297,6 +1329,7 @@ class ServingEngine:
                 if r.state == RUNNING:   # prereleased finals included
                     owed.setdefault(r.rid, r)
         self._pending = []
+        self._pending_steps.clear()
         self._chunk_q = []
         self._prefilling.clear()
         # parked exports are already DONE — just give their blocks back
@@ -1740,20 +1773,22 @@ class ServingEngine:
 
     def _step_inner(self):
         sch, pool, M = self.scheduler, self.pool, self.metrics
-        sync = self.config.async_depth == 0
-        prev, self._pending = self._pending, []
+        depth = self.config.async_depth
+        sync = depth == 0
         epoch = self._restart_epoch
 
-        if self._spec is not None and prev:
+        if self._spec is not None and self._pending:
             # speculative schedule: drafts extend the request's last
             # HARVESTED token, so the previous step's in-flight results
             # are consumed BEFORE proposing. The verify dispatch still
             # overlaps all of this step's host bookkeeping — the
             # pipeline depth is unchanged, only the harvest moves from
             # the tail of the step to its head.
+            prev, self._pending = self._pending, []
+            self._pending_steps.clear()
             with M.span("serving/harvest"):
                 self._harvest(prev)
-            prev = []
+        held = len(self._pending)
 
         if self.chaos is not None \
                 and self.chaos.fires("step_latency",
@@ -1868,12 +1903,24 @@ class ServingEngine:
                     self._pending.append(entry)
 
         if epoch == self._restart_epoch:
+            # at most `depth` steps' results stay in flight (this
+            # step's among them), so the device holds that many steps
+            # of queued work while the host is away; a step that
+            # dispatched nothing holds nothing back
+            steps = self._pending_steps
+            steps.append(len(self._pending) - held)
+            keep = depth if steps[-1] else 0
+            while len(steps) > keep:
+                steps.popleft()
+            n = len(self._pending) - sum(steps)
+            prev = self._pending[:n]
+            del self._pending[:n]
             with M.span("serving/harvest"):
                 self._harvest(prev)
-        # else: a supervisor restart happened this step — `prev`
-        # belongs to the pre-restart schedule; its requests were
-        # re-queued with inflight reset, and greedy replay regenerates
-        # every unread token bit-exactly
+        # else: a supervisor restart happened this step — everything
+        # in flight belonged to the pre-restart schedule and went with
+        # it; its requests were re-queued with inflight reset, and
+        # greedy replay regenerates every unread token bit-exactly
 
         M.queue_depth = len(sch.queue)
         M.slot_occupancy = self.pool.occupancy
@@ -2110,8 +2157,10 @@ class ServingEngine:
     def _register_chunked(self, req, alloc):
         """Queue a freshly admitted long prompt for chunk-by-chunk
         prefill and park its slot out of decode harvest."""
+        # a slot with recurrent state cannot recompute rows it passed
         self._chunk_q.append(self._ChunkPlan(
-            req, alloc.slot, alloc.prefix_tokens, self.chunk_len))
+            req, alloc.slot, alloc.prefix_tokens, self.chunk_len,
+            tile=bool(self.cache_spec.slot_arrays)))
         self._prefilling.add(alloc.slot)
 
     def _dispatch_chunks(self, sync):
@@ -2345,6 +2394,7 @@ class ServingEngine:
             # tokens they carry were never surfaced, and the greedy
             # replay regenerates them bit-exactly from clean state
             self._pending = []
+            self._pending_steps.clear()
             self._chunk_q = []
             self._prefilling.clear()
             sch.active.clear()

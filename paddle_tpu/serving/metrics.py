@@ -420,6 +420,16 @@ class ServingMetrics:
             "bytes one cached token takes over all layers (cache spec)"
         ).set(float(nbytes))
 
+    def set_state_bytes_per_slot(self, nbytes):
+        """Gauge ``serving_state_bytes_per_slot``: the useful bytes of
+        per-slot state (recurrent layers) one slot takes over all
+        layers, from the model's cache spec; 0 for a model that keeps
+        none."""
+        self.registry.gauge(
+            "serving_state_bytes_per_slot",
+            "bytes of per-slot state one slot takes over all layers "
+            "(cache spec)").set(float(nbytes))
+
     def set_moe_counters(self, read_fn, layers, first, count):
         """Expert-routing counters that the decode program keeps ON THE
         DEVICE (``CacheSpec.state``): ``read_fn()`` fetches the array

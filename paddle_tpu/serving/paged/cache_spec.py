@@ -1,46 +1,106 @@
-"""What a model keeps in the paged cache: the arrays a token owns in
-one layer, stated by the model (``model.cache_spec()``) and allocated by
-``PagedKVPool``. The engine's donation, ``rebind``, ``nbytes`` and the
+"""What a model keeps in the paged cache, stated by the model
+(``model.cache_spec()``) and allocated by ``PagedKVPool``: the arrays a
+TOKEN owns in one layer (keys and values, a latent) and the arrays a
+SLOT owns (recurrent state: constant size whatever the sequence's
+length). The engine's donation, ``rebind``, ``nbytes`` and the
 ``kv_donation`` gauge walk this instead of naming a K and a V array.
 """
 import collections
 
 import numpy as np
 
-CacheArray = collections.namedtuple("CacheArray", "name lead trail dtype")
+CacheArray = collections.namedtuple(
+    "CacheArray", "name lead trail dtype layers per",
+    defaults=(None, "token"))
 
 
 class CacheSpec:
-    """For each array its name, the axes before the block's token axis
-    (``lead``: a head axis, or none), the axes after it (``trail``) and
-    the dtype; the pool's array is ``[layers, blocks, *lead, block_size,
-    *trail]``. ``state`` names the small arrays the DECODE program
-    carries beside the cache, ``(name, shape, dtype)``: it takes them
-    after the cache arrays and returns them new each step; they are
-    never donated, so a reader in another thread holds a live array
-    whenever it looks."""
+    """``arrays``: per-token arrays first, per-slot ones after them, in
+    the order the programs take them.
 
-    def __init__(self, num_layers, arrays, state=()):
+    A per-token array has a name, the axes before the block's token axis
+    (``lead``: a head axis, or none), the axes after it (``trail``) and a
+    dtype; the pool's array is ``[layers, blocks, *lead, block_size,
+    *trail]``. Its ``layers`` is ``num_layers`` unless the entry gives
+    its own as a fifth field (a model in which only some layers attend).
+
+    A per-slot array (``slot``: ``(name, layers, shape, dtype)``) is
+    ``[layers, slots, *shape]``: what the layers of ONE kind keep of a
+    sequence between steps. It is donated with the rest, carried and
+    written in place by decode, written by prefill at the end of its
+    run, and a prefill that starts a sequence (``start == 0``) takes
+    zeros for it whatever the slot held before. Blocks of a model with
+    such state are NOT shareable between requests (``shareable``): a
+    cached prefix without the state at its boundary is a wrong answer.
+
+    ``state`` names the small arrays the DECODE program carries beside
+    the cache, ``(name, shape, dtype)``: it takes them after the cache
+    arrays and returns them new each step; they are never donated, so a
+    reader in another thread holds a live array whenever it looks."""
+
+    def __init__(self, num_layers, arrays, state=(), slot=()):
         import jax.numpy as jnp
         self.num_layers = int(num_layers)
-        self.arrays = tuple(
-            CacheArray(str(n), tuple(int(d) for d in le),
-                       tuple(int(d) for d in tr), jnp.dtype(dt))
-            for n, le, tr, dt in arrays)
+        token = tuple(
+            CacheArray(str(a[0]), tuple(int(d) for d in a[1]),
+                       tuple(int(d) for d in a[2]), jnp.dtype(a[3]),
+                       int(a[4]) if len(a) > 4 else self.num_layers,
+                       "token")
+            for a in arrays)
+        per_slot = tuple(
+            CacheArray(str(n), tuple(int(d) for d in sh), (),
+                       jnp.dtype(dt), int(layers), "slot")
+            for n, layers, sh, dt in slot)
+        self.arrays = token + per_slot
         self.state = tuple((str(n), tuple(int(d) for d in sh),
                             jnp.dtype(dt)) for n, sh, dt in state)
+        self.num_slots = None
+
+    def with_slots(self, num_slots):
+        """The same spec knowing how many slots there are (the pool's
+        own copy does; ``shape`` of a per-slot array needs it)."""
+        import copy
+        out = copy.copy(self)
+        out.num_slots = int(num_slots)
+        return out
 
     def shape(self, a, num_blocks, block_size):
-        return (self.num_layers, int(num_blocks)) + a.lead \
+        if a.per == "slot":
+            if self.num_slots is None:
+                raise ValueError(f"{a.name} is per-slot state: its shape "
+                                 f"needs the number of slots "
+                                 f"(with_slots)")
+            return (a.layers, self.num_slots) + a.lead
+        return (a.layers, int(num_blocks)) + a.lead \
             + (int(block_size),) + a.trail
+
+    @property
+    def token_arrays(self):
+        return tuple(a for a in self.arrays if a.per == "token")
+
+    @property
+    def slot_arrays(self):
+        return tuple(a for a in self.arrays if a.per == "slot")
+
+    @property
+    def shareable(self):
+        """Whether a cached block means the same to every request that
+        reaches it: not where a slot carries state beside its blocks."""
+        return not self.slot_arrays
 
     @property
     def bytes_per_token(self):
         """Useful bytes one cached token takes over all layers (what a
         device layout pads on top is not in it)."""
-        return self.num_layers * sum(
-            int(np.prod(a.lead + a.trail, dtype=np.int64))
-            * a.dtype.itemsize for a in self.arrays)
+        return sum(a.layers * int(np.prod(a.lead + a.trail, dtype=np.int64))
+                   * a.dtype.itemsize for a in self.token_arrays)
+
+    @property
+    def bytes_per_slot(self):
+        """Useful bytes of per-slot state one slot takes over all layers,
+        whatever its sequence's length."""
+        return sum(a.layers * int(np.prod(a.lead, dtype=np.int64))
+                   * a.dtype.itemsize for a in self.slot_arrays)
 
 
 def kv_pair_spec(num_layers, num_heads, head_dim, dtype):

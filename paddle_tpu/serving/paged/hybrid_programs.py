@@ -1,0 +1,287 @@
+"""The engine's paged prefill and decode programs for a model that
+keeps TWO kinds of state side by side (``text.nemotron_h``): keys and
+values a token owns, in the paged pool behind the block tables, and a
+convolution window and a recurrent state a SLOT owns, in per-slot arrays
+(``cache_spec``: per-slot leaves). The same signatures, slot bookkeeping
+and sampling as ``programs.py`` has for the GPT, with the model's block
+IMPORTED, not written out again. What is here is only how a layer
+reaches its cache (``PagedAccess``) and what the engine's calling
+convention asks of a program.
+
+  ``paged_prefill(params, tokens [1, B], tail_len, start, slot, final,
+                  bt_row [MB], toks [S], pos [S], k, v, conv, ssm
+                  [, samp...])
+      -> (first [1], toks', pos', k, v, conv, ssm)``
+      One request's run of ``tail_len`` tokens from position ``start``.
+      An attention layer gathers the slot's ``MB`` blocks into a
+      position-ordered view, puts the run's keys and values in and
+      scatters the blocks back whole. A state-space layer starts from
+      the slot's window and state when ``start > 0`` (the next chunk of
+      a chunked prefill) and from ZEROS when ``start == 0``, whatever
+      the slot's last owner left; it writes the window and the state as
+      they are after row ``tail_len - 1``: rows of the bucket past the
+      run change neither (their step size is set to zero). A model with
+      such state shares no prefix (``CacheSpec.shareable``), so
+      ``start`` is only ever a chunk boundary of the request's own.
+
+  ``paged_decode(params, toks [S], pos [S], tables [S, MB], k, v, conv,
+                 ssm, moe_counts[, samp...])
+      -> (next [S], pos + 1, k, v, conv, ssm, moe_counts')``
+      One token a slot. Keys and values ride the layer loop flat
+      (``[La*NB, nkv, BS, hd]``), each slot's current block is read,
+      given its new row and written back whole, and attention reads the
+      LIVE blocks in place through ``tables + layer*NB``. The recurrent
+      state rides it flat too (``[Lm*S, ...]``) and is updated in place
+      by ``ops.ssm``'s kernel, a layer's rows a call.
+
+Parked and released slots: write positions are clamped to the row's
+last entry, free rows point at the trash block, the length mask hides
+what they hold (``programs.py``). A slot parked between the chunks of
+its prefill (``pos == C - 1``; a live sequence never feeds a token
+there) keeps its window and its state through the decode steps in
+between: its step size is set to zero.
+"""
+
+
+class PagedAccess:
+    """A layer's way to keys and values ``k, v [La*NB, nkv, BS, hd]``
+    and to slot state. DECODE (built with all table rows) carries every
+    slot's: ``conv [Lm, S, .]``, ``ssm [Lm*S, ...]``. PREFILL (built
+    with one table row) carries ONE slot's, ``conv [Lm, .]``, ``ssm
+    [Lm, ...]``, cut out before the layer loop and put back after it:
+    with the whole state in the loop's carry XLA gave all 1.6 GB of it a
+    layout that suits the chunked scan's small transposes and copied it
+    in and out of every prefill (AOT, PR 35)."""
+
+    def __init__(self, cfg, num_slots, num_blocks, block_size,
+                 blocks_per_slot, bt_row=None, tables=None):
+        self.cfg, self.S = cfg, int(num_slots)
+        self.NB, self.BS = int(num_blocks), int(block_size)
+        self.MB = int(blocks_per_slot)
+        self.bt_row, self.tables = bt_row, tables
+
+    # ---------------------------------------------------------- prefill
+    def attn_prefill(self, state, li, start, k, v):
+        import jax
+        import jax.numpy as jnp
+        kf, vf, conv, ssm = state
+        nkv, hd = k.shape[2:]
+        C = self.MB * self.BS
+        rows = li * jnp.int32(self.NB) + self.bt_row             # [MB]
+        at = start + jnp.arange(k.shape[1], dtype=jnp.int32)
+        out, views = [], []
+        for cache, new in ((kf, k), (vf, v)):
+            with jax.named_scope("kv_gather"):
+                view = cache[rows].transpose(1, 0, 2, 3).reshape(
+                    nkv, C, hd)
+            # rows past the slot's capacity are dropped, not shifted
+            view = view.at[:, at].set(
+                new[0].transpose(1, 0, 2).astype(cache.dtype), mode="drop")
+            with jax.named_scope("kv_write"):
+                out.append(cache.at[rows].set(
+                    view.reshape(nkv, self.MB, self.BS, hd)
+                    .transpose(1, 0, 2, 3)))
+            views.append(view[None])
+        return (out[0], out[1], conv, ssm), tuple(views)
+
+    def ssm_init(self, state, mi, start, b):
+        import jax.numpy as jnp
+        _, _, conv, ssm = state
+        fresh = start == 0
+        return (jnp.where(fresh, jnp.zeros_like(conv[mi]), conv[mi])[None],
+                jnp.where(fresh, jnp.zeros_like(ssm[mi]), ssm[mi])[None])
+
+    def ssm_commit(self, state, mi, window, S):
+        import jax
+        kf, vf, conv, ssm = state
+        with jax.named_scope("state_write"):
+            conv = jax.lax.dynamic_update_index_in_dim(
+                conv, window[0].astype(conv.dtype), mi, axis=0)
+            ssm = jax.lax.dynamic_update_index_in_dim(
+                ssm, S[0].astype(ssm.dtype), mi, axis=0)
+        return kf, vf, conv, ssm
+
+    # ----------------------------------------------------------- decode
+    def attn_decode(self, state, li, pos, q, k, v, kernel):
+        import jax
+        import jax.numpy as jnp
+
+        from ...ops import attention as attn_ops
+        from ...ops import paged_attention as paged_ops
+        from .pool import TRASH_BLOCK
+        kf, vf, conv, ssm = state
+        BS, C = self.BS, self.MB * self.BS
+        base = li * jnp.int32(self.NB)
+        # the WRITE position is clamped as a whole (programs.py)
+        wpos = jnp.minimum(pos, jnp.int32(C - 1))
+        bidx = jnp.take_along_axis(
+            self.tables, (wpos // jnp.int32(BS))[:, None], axis=1)[:, 0]
+        row = (jnp.arange(BS, dtype=jnp.int32)[None, :]
+               == (wpos % jnp.int32(BS))[:, None])[:, None, :, None]
+        fb = base + bidx
+        with jax.named_scope("kv_write"):
+            kf = kf.at[fb].set(jnp.where(
+                row, k.astype(kf.dtype)[:, :, None], kf[fb]))
+            vf = vf.at[fb].set(jnp.where(
+                row, v.astype(vf.dtype)[:, :, None], vf[fb]))
+        # what attention may read of a slot: its positions so far, never
+        # more than the blocks its row holds (a released slot: nothing)
+        held = jnp.sum((self.tables != TRASH_BLOCK).astype(jnp.int32),
+                       axis=1)
+        lengths = jnp.minimum(pos + 1, held * jnp.int32(BS))
+        fn = paged_ops.paged_decode_attention if kernel \
+            else attn_ops.cached_paged_attention
+        return (kf, vf, conv, ssm), fn(q, kf, vf, self.tables + base,
+                                       lengths)
+
+    def ssm_decode(self, state, mi, pos, u, dt, A, conv_w, conv_b, kernel):
+        import jax.numpy as jnp
+
+        from ...ops import ssm as ssm_ops
+        from ...text.nemotron_h import split_channels
+        kf, vf, conv, ssm = state
+        active = pos < jnp.int32(self.MB * self.BS - 1)
+        conv, ssm, xs, y = ssm_ops.ssm_decode_step(
+            conv, ssm, mi, u, dt, A,
+            lambda act: split_channels(self.cfg, act), conv_w, conv_b,
+            self.S, active, kernel)
+        return (kf, vf, conv, ssm), xs, y
+
+
+def decode_kernels(cfg, num_slots, block_size):
+    """Whether the decode program runs its three Pallas kernels: yes on
+    any backend that has Mosaic, and then a shape they cannot take is
+    refused here, by name; no on the CPU (the ``jnp`` formulations)."""
+    import jax
+
+    from ...ops import moe_experts as moe_ops
+    from ...ops import paged_attention as paged_ops
+    from ...ops import ssm as ssm_ops
+    if jax.default_backend() == "cpu" and not (
+            moe_ops._FORCE_INTERPRET[0] or paged_ops._FORCE_INTERPRET[0]
+            or ssm_ops._FORCE_INTERPRET[0]):
+        return False
+    if cfg.count("*") and not paged_ops.kernel_viable(
+            cfg.num_kv_heads, cfg.head_dim, block_size, cfg.cache_dtype):
+        raise ValueError(
+            f"paged_decode_attn cannot take (kv heads, head dim, "
+            f"block_size, cache dtype) = ({cfg.num_kv_heads}, "
+            f"{cfg.head_dim}, {block_size}, {cfg.cache_dtype}): "
+            f"ops.paged_attention.kernel_viable")
+    if cfg.count("M") and not ssm_ops.kernel_viable(
+            cfg.mamba_heads, cfg.mamba_head_dim, cfg.state_size,
+            cfg.n_groups):
+        raise ValueError(
+            f"ssm_decode_step cannot take (heads, head dim, state size, "
+            f"groups) = ({cfg.mamba_heads}, {cfg.mamba_head_dim}, "
+            f"{cfg.state_size}, {cfg.n_groups}): ops.ssm.kernel_viable")
+    if cfg.count("E") and not moe_ops.kernel_viable(
+            num_slots, cfg.hidden_size, cfg.moe_intermediate_size,
+            cfg.dtype, gated=False):
+        raise ValueError(
+            f"moe_experts_relu2_decode cannot take (slots, hidden, "
+            f"expert width, dtype) = ({num_slots}, {cfg.hidden_size}, "
+            f"{cfg.moe_intermediate_size}, {cfg.dtype}): "
+            f"ops.moe_experts.kernel_viable")
+    return True
+
+
+def build_paged_hybrid_fns(cfg, num_slots, block_size, num_blocks,
+                           blocks_per_slot, sampling=False, kernels=None):
+    """(paged_prefill, paged_decode) for a ``NemotronHConfig``. Pure and
+    shape-stable; ``kernels=None`` asks ``decode_kernels``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...text import nemotron_h as block
+    from ..sched.sampling import build_sampling_head
+
+    if kernels is None:
+        kernels = decode_kernels(cfg, num_slots, block_size)
+    head = build_sampling_head(cfg.vocab_size) if sampling else None
+    S = int(num_slots)
+    NB, BS, MB = int(num_blocks), int(block_size), int(blocks_per_slot)
+    C = MB * BS
+
+    def flat(a):
+        return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
+
+    def _prefill_core(params, tokens, tail_len, start, slot, final,
+                      bt_row, toks, pos, k, v, conv, ssm, samp):
+        B = tokens.shape[1]
+        access = PagedAccess(cfg, S, NB, BS, MB, bt_row=bt_row)
+        with jax.named_scope("embed"):
+            x = params["wemb"][tokens]                       # [1, B, h]
+        positions = (start + jnp.arange(B, dtype=jnp.int32))[None]
+        mine = (jax.lax.dynamic_index_in_dim(conv, slot, 1, False),
+                jax.lax.dynamic_index_in_dim(ssm, slot, 1, False))
+        x, (kf, vf, conv_s, ssm_s), _ = block.run_layers(
+            cfg, params, x, positions, access, (flat(k), flat(v)) + mine,
+            start, "prefill", length=tail_len)
+        with jax.named_scope("state_write"):
+            conv = jax.lax.dynamic_update_index_in_dim(conv, conv_s, slot,
+                                                       axis=1)
+            ssm = jax.lax.dynamic_update_index_in_dim(ssm, ssm_s, slot,
+                                                      axis=1)
+        # ONE row through the head, as a [1, h] matmul: as a vector the
+        # product is elementwise and XLA upcasts the whole head to f32
+        last = block.lm_head(cfg, params, jax.lax.dynamic_slice_in_dim(
+            x[0], tail_len - 1, 1, axis=0))[0]
+        with jax.named_scope("sample"):
+            if samp is None:
+                first = jnp.argmax(last, -1).astype(jnp.int32)
+            else:
+                seed, temp, topk, topp = samp
+                first = head(last[None], seed[None],
+                             (start + tail_len - 1)[None], temp[None],
+                             topk[None], topp[None])[0]
+            toks = jnp.where(final > 0, toks.at[slot].set(first), toks)
+            pos = pos.at[slot].set(
+                jnp.where(final > 0, start + tail_len, jnp.int32(C - 1)))
+        return first[None], toks, pos, kf.reshape(k.shape), \
+            vf.reshape(v.shape), conv, ssm
+
+    def _decode_core(params, toks, pos, tables, k, v, conv, ssm, counts,
+                     samp):
+        access = PagedAccess(cfg, S, NB, BS, MB, tables=tables)
+        with jax.named_scope("embed"):
+            x = params["wemb"][toks]                         # [S, h]
+        x, (kf, vf, conv, sf), counts = block.run_layers(
+            cfg, params, x, pos, access,
+            (flat(k), flat(v), conv, flat(ssm)), mode="decode",
+            kernel=kernels, counts=counts)
+        logits = block.lm_head(cfg, params, x)
+        with jax.named_scope("sample"):
+            if samp is None:
+                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+            else:
+                seeds, temps, topks, topps = samp
+                nxt = head(logits, seeds, pos, temps, topks, topps)
+        return nxt, pos + jnp.int32(1), kf.reshape(k.shape), \
+            vf.reshape(v.shape), conv, sf.reshape(ssm.shape), counts
+
+    if sampling:
+        def paged_prefill(params, tokens, tail_len, start, slot, final,
+                          bt_row, toks, pos, k, v, conv, ssm, seed, temp,
+                          topk, topp):
+            return _prefill_core(params, tokens, tail_len, start, slot,
+                                 final, bt_row, toks, pos, k, v, conv, ssm,
+                                 (seed, temp, topk, topp))
+
+        def paged_decode(params, toks, pos, tables, k, v, conv, ssm,
+                         counts, seeds, temps, topks, topps):
+            return _decode_core(params, toks, pos, tables, k, v, conv,
+                                ssm, counts, (seeds, temps, topks, topps))
+    else:
+        def paged_prefill(params, tokens, tail_len, start, slot, final,
+                          bt_row, toks, pos, k, v, conv, ssm):
+            return _prefill_core(params, tokens, tail_len, start, slot,
+                                 final, bt_row, toks, pos, k, v, conv, ssm,
+                                 None)
+
+        def paged_decode(params, toks, pos, tables, k, v, conv, ssm,
+                         counts):
+            return _decode_core(params, toks, pos, tables, k, v, conv,
+                                ssm, counts, None)
+
+    return paged_prefill, paged_decode

@@ -5,8 +5,11 @@ Physical layout: the arrays the model's CACHE SPEC names
 (``cache_spec.CacheSpec``), each ``[layers, num_blocks, *lead,
 block_size, *trail]`` (a GPT: the pair ``kc/vc [layers, num_blocks,
 heads, block_size, head_dim]``; latent attention: a latent and a rotary
-key a token, no head axis) and an int32 block table ``[num_slots, blocks_per_slot]`` mapping each slot's
-logical block i to a physical block. Both shapes are fixed at
+key a token, no head axis), after them the spec's PER-SLOT arrays
+``[layers, num_slots, *shape]`` (recurrent state: no block, no table;
+such a pool shares no prefix), and an int32 block table ``[num_slots,
+blocks_per_slot]`` mapping each slot's logical block i to a physical
+block. Both shapes are fixed at
 construction, so every AOT serving executable keeps one signature for
 the engine's lifetime — paging changes WHERE a slot's K/V lives, never
 the compiled program's shape.
@@ -63,12 +66,12 @@ class PagedKVPool:
             from .cache_spec import kv_pair_spec
             spec = kv_pair_spec(num_layers, num_heads, head_dim,
                                 jnp.float32 if dtype is None else dtype)
-        self.spec = spec
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         self.num_slots = int(num_slots)
+        self.spec = spec = spec.with_slots(self.num_slots)
         self.block_size = int(block_size)
         self.max_len = int(max_len)
         self.blocks_per_slot = -(-self.max_len // self.block_size)
@@ -217,7 +220,10 @@ class PagedKVPool:
 
     def match_prefix(self, prompt):
         """Longest cached prefix of ``prompt`` in TOKENS (always a
-        block multiple). Touches the matched path's LRU ticks."""
+        block multiple). Touches the matched path's LRU ticks. Nothing
+        where the spec's blocks are not shareable (per-slot state)."""
+        if not self.spec.shareable:
+            return 0
         return len(self.index.match(prompt)) * self.block_size
 
     def acquire(self, owner, prompt, total_tokens, prefix_tokens):
@@ -324,9 +330,12 @@ class PagedKVPool:
         is a prompt token are shareable — the partial last block (and
         every decode block after it) takes decode writes and stays
         private. Call after the prefill dispatch succeeded; an
-        admission rolled back before commit leaves the index untouched."""
+        admission rolled back before commit leaves the index untouched.
+        Commits nothing where the spec's blocks are not shareable."""
         if slot not in self._owner:
             raise ValueError(f"slot {slot} is not live")
+        if not self.spec.shareable:
+            return []
         n_full = len(prompt) // self.block_size
         blocks = self._slot_blocks[slot][:n_full]
         return self.index.insert(prompt, blocks)

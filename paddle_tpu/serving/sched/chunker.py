@@ -21,6 +21,11 @@ K/V rows recompute to identical values because each row is a function
 of the rows below it only), but no dispatch ever writes a K/V row at
 a position >= n, so no clamp-shift or pad-row hazard exists at any
 prompt length.
+
+A model with RECURRENT state a slot (``CacheSpec.slot_arrays``) cannot
+recompute rows its state has passed: its plan TILES, the final chunk
+starting where the one before it ended and running short
+(``tail_len < chunk``; its program passes the bucket's other rows by).
 """
 
 
@@ -34,14 +39,14 @@ class ChunkPlan:
     __slots__ = ("req", "slot", "ids", "starts", "next", "chunk",
                  "start0")
 
-    def __init__(self, req, slot, start0, chunk):
+    def __init__(self, req, slot, start0, chunk, tile=False):
         self.req = req
         self.slot = slot
         self.ids = req.prefill_ids
         self.chunk = int(chunk)
         self.start0 = int(start0)       # cached-prefix end
         self.starts = plan_chunks(self.start0, len(self.ids),
-                                  self.chunk)
+                                  self.chunk, tile)
         self.next = 0                   # index of the next chunk
 
     @property
@@ -62,19 +67,22 @@ class ChunkPlan:
         self.next += 1
 
 
-def plan_chunks(start0, prompt_len, chunk):
+def plan_chunks(start0, prompt_len, chunk, tile=False):
     """Chunk start offsets covering ``[start0, prompt_len)`` with
     full-width ``chunk`` dispatches: interior chunks tile from
     ``start0``; the final chunk is end-aligned at ``prompt_len -
     chunk`` so its last row is the prompt's last token (the one whose
     logits produce the first generated token) and NO dispatch writes a
-    K/V position >= prompt_len. Requires ``prompt_len - start0 >
-    chunk`` (shorter tails take the ordinary unchunked prefill)."""
+    K/V position >= prompt_len. With ``tile`` the final chunk starts
+    where the one before it ended instead (no row is computed twice:
+    recurrent state). Requires ``prompt_len - start0 > chunk`` (shorter
+    tails take the ordinary unchunked prefill)."""
     tail = prompt_len - start0
     if tail <= chunk:
         raise ValueError(
             f"tail {tail} does not need chunking at chunk={chunk}")
     m = -(-tail // chunk)               # ceil
     starts = [start0 + i * chunk for i in range(m - 1)]
-    starts.append(prompt_len - chunk)
+    starts.append(start0 + (m - 1) * chunk if tile
+                  else prompt_len - chunk)
     return starts

@@ -34,14 +34,13 @@ tensor or expert parallel sharding over a mesh (``held`` only says which
 experts THIS program holds), query compression (``q_lora_rank``), rope
 scaling, expert groups (``n_group > 1``).
 """
-import collections
-
 import jax
 import jax.numpy as jnp
 
-from .. import nn
 from ..ops import mla_attention as mla_ops
 from ..ops import moe_experts as moe_ops
+from .stacked_lm import (  # noqa: F401 - parts of this block
+    StackedCausalLM, count_routing, greedy_or_sampled, lm_head, rms_norm)
 
 
 class DeepseekV3Config:
@@ -125,13 +124,6 @@ class DeepseekV3Config:
 
 
 # ------------------------------------------------------------ the block
-def rms_norm(x, w, eps):
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
-                           + jnp.float32(eps))
-    return (y * w.astype(jnp.float32)).astype(x.dtype)
-
-
 def rope_interleaved(x, pos, theta):
     """Rotary positions in the checkpoint's interleaved layout: lanes
     ``(2i, 2i+1)`` are one pair, turned by ``pos * theta**(-2i/d)``.
@@ -243,10 +235,7 @@ def moe_mlp(cfg, p, experts, x, layer_m, mode, kernel, counts):
         -1, x.shape[-1])
     y, tokens = expert_layer(cfg, p, experts, xn, layer_m, mode, kernel)
     if counts is not None and mode == "decode":
-        row = jnp.concatenate([
-            tokens, jnp.sum(tokens > 0, dtype=jnp.int32)[None],
-            jnp.ones((1,), jnp.int32)])
-        counts = counts.at[layer_m].add(row)
+        counts = count_routing(counts, layer_m, tokens)
     return x + y.astype(x.dtype).reshape(lead + (x.shape[-1],)), counts
 
 
@@ -289,13 +278,6 @@ def run_layers(cfg, params, x, positions, access, state, start=0,
                                        dtype=jnp.int32)))
     x, state, counts = carry
     return x, state, (counts if have_counts else None)
-
-
-def lm_head(cfg, params, x):
-    """Final norm + head over x ``[..., h]``: logits in f32."""
-    with jax.named_scope("lm_head"):
-        return jnp.dot(rms_norm(x, params["norm_f"], cfg.rms_norm_eps),
-                       params["head"], preferred_element_type=jnp.float32)
 
 
 # ------------------------------------------------------- cache accesses
@@ -390,64 +372,15 @@ def param_shapes(cfg):
     return out
 
 
-def _init_leaf(key, shape, kind, dtype, std):
-    if kind == "g":
-        return jnp.ones(shape, dtype)
-    if kind == "z":
-        return jnp.zeros(shape, dtype)
-    return (jax.random.normal(key, shape, jnp.float32)
-            * jnp.float32(std)).astype(dtype)
-
-
-_init_leaf_jit = jax.jit(_init_leaf, static_argnums=(1, 2, 3, 4))
-
-
-class DeepseekV3ForCausalLM(nn.Layer):
+class DeepseekV3ForCausalLM(StackedCausalLM):
     """Causal LM of the family, for serving. Parameters are held
     STACKED per group, in ``cfg.dtype``, exactly as the compiled
-    programs take them: ``export_decode_params()`` hands out the same
-    arrays (no second copy), and ``weights=`` adopts a ready tree of
-    arrays without initialising anything."""
+    programs take them (``stacked_lm.StackedCausalLM``)."""
 
     def __init__(self, cfg, weights=None, seed=0):
-        super().__init__()
-        self.cfg = cfg
-        self._paths = {}
-        key = jax.random.PRNGKey(int(seed))
-        for i, (path, (shape, kind, dt)) in enumerate(
-                sorted(param_shapes(cfg).items())):
-            if weights is not None:
-                a = weights
-                for part in path:
-                    a = a[part]
-                if tuple(a.shape) != shape or jnp.dtype(a.dtype) != \
-                        jnp.dtype(dt):
-                    raise ValueError(
-                        f"{'.'.join(path)}: got {a.dtype}{a.shape}, "
-                        f"the config says {dt}{shape}")
-            else:
-                a = _init_leaf_jit(jax.random.fold_in(key, i), shape,
-                                   kind, dt, cfg.initializer_range)
-            name = "_".join(path)
-            from ..core.tensor import Parameter
-            self.add_parameter(name, Parameter(a, name=name,
-                                               trainable=False))
-            self._paths[path] = name
-        self._decode_cache = collections.OrderedDict()
+        super().__init__(cfg, param_shapes(cfg), weights, seed)
 
     # -------------------------------------------------- what serving takes
-    def export_decode_params(self):
-        """The parameter tree of the compiled programs, BY REFERENCE:
-        the arrays the model holds, as of this call."""
-        from ..core.lazy import concrete
-        tree = {}
-        for path, name in self._paths.items():
-            node = tree
-            for part in path[:-1]:
-                node = node.setdefault(part, {})
-            node[path[-1]] = concrete(self._parameters[name].value)
-        return tree
-
     def cache_spec(self):
         return latent_cache_spec(self.cfg)
 
@@ -457,18 +390,6 @@ class DeepseekV3ForCausalLM(nn.Layer):
         cfg = self.cfg
         return {"layers": list(range(cfg.first_k_dense, cfg.num_layers)),
                 "first": cfg.held[0], "count": cfg.held[1]}
-
-    def check_serving_config(self, config):
-        """Refuse, by name, an engine option this model has no program
-        for (the engine calls this at construction)."""
-        bad = [name for name, on in (
-            ("speculative", config.speculative),
-            (f"role={config.role!r}", config.role != "monolithic"),
-        ) if on]
-        if bad:
-            raise ValueError(
-                f"DeepseekV3ForCausalLM is served greedy or with "
-                f"sampling=True; no program for: {', '.join(bad)}")
 
     def build_paged_serving_fns(self, num_slots, block_size, num_blocks,
                                 blocks_per_slot, sampling=False):
@@ -482,23 +403,13 @@ class DeepseekV3ForCausalLM(nn.Layer):
             self.cfg, num_slots, block_size, num_blocks, blocks_per_slot,
             sampling=sampling)
 
-    def _no_program(self, what):
-        raise NotImplementedError(
-            f"DeepseekV3ForCausalLM has no {what} program: only "
-            f"prefill and decode (greedy or sampling=True) are brought")
-
-    def build_paged_spec_verify_fn(self, *a, **k):
-        self._no_program("speculative verify")
-
     # ------------------------------------------------------------ eager
     def forward(self, input_ids):
         """Logits ``[b, T, vocab]`` (f32) of whole sequences, through
         the same block as the serving programs, expanded attention
         form, no cache. Inference only: nothing is taped."""
-        from ..core.lazy import concrete
         from ..core.tensor import Tensor
-        ids = jnp.asarray(concrete(getattr(input_ids, "value", input_ids)),
-                          jnp.int32)
+        ids = self._ids(input_ids)
         fn = self._jitted(("forward",) + ids.shape, self._forward_fn)
         return Tensor(fn(self.export_decode_params(), ids))
 
@@ -510,28 +421,15 @@ class DeepseekV3ForCausalLM(nn.Layer):
                              SeqAccess(), (), 0, "prefill")
         return lm_head(cfg, params, x)
 
-    def _jitted(self, key, fn):
-        cache = self._decode_cache
-        got = cache.get(key)
-        if got is None:
-            got = cache[key] = jax.jit(fn)
-            while len(cache) > 8:
-                cache.popitem(last=False)
-        else:
-            cache.move_to_end(key)
-        return got
-
     def generate(self, input_ids, max_new_tokens=32, temperature=0.0,
                  top_k=0, seed=0):
         """Prefill (expanded form) + one decode step a token (absorbed
         form) over a contiguous latent cache, as one jitted program.
         Greedy when ``temperature <= 0`` or ``top_k == 1``, else
         temperature sampling over the ``top_k`` logits (0 = all)."""
-        from ..core.lazy import concrete
         from ..core.tensor import Tensor
         cfg = self.cfg
-        ids = jnp.asarray(concrete(getattr(input_ids, "value", input_ids)),
-                          jnp.int32)
+        ids = self._ids(input_ids)
         b, s0 = ids.shape
         n_new = int(max_new_tokens)
         if s0 + n_new > cfg.max_seq_len:
@@ -543,15 +441,7 @@ class DeepseekV3ForCausalLM(nn.Layer):
         kk = min(int(top_k), cfg.vocab_size)
         total = s0 + n_new
         access = ContigAccess()
-
-        def pick(logits, key, temp):
-            if greedy:
-                return jnp.argmax(logits, -1).astype(jnp.int32)
-            lg = logits / temp
-            if kk > 0:
-                kth = jax.lax.top_k(lg, kk)[0][:, -1:]
-                lg = jnp.where(lg < kth, jnp.float32(-1e30), lg)
-            return jax.random.categorical(key, lg).astype(jnp.int32)
+        pick = greedy_or_sampled(greedy, kk)
 
         def decode(params, ids, key, temp):
             cdt = jnp.dtype(cfg.cache_dtype)

@@ -441,3 +441,156 @@ def test_latent_decode_program_updates_pool_in_place(one_chip, sampling):
     bad = [(name, op) for name, op in found
            if op in moving or any(w in name for w in moving)]
     assert not bad, bad
+
+
+# ------------------------------------------------ nemotron_h (PR 35)
+# NVIDIA-Nemotron-3-Nano-30B-A3B's widths, cut in depth and slots only
+NEMOTRON = dict(
+    vocab_size=131072, hidden_size=2688, num_attention_heads=32,
+    num_key_value_heads=2, head_dim=128, mamba_num_heads=64,
+    mamba_head_dim=64, ssm_state_size=128, n_groups=8, conv_kernel=4,
+    chunk_size=128, moe_intermediate_size=1856,
+    moe_shared_expert_intermediate_size=3712, n_routed_experts=8,
+    router_experts=16, num_experts_per_tok=6, routed_scaling_factor=2.5,
+    max_position_embeddings=4096)
+
+
+def test_ssm_decode_step_compiles(one_chip):
+    """The state-space decode kernel at the published widths, 128 slots,
+    layer 3 of 6: the packed float32 state aliased in and out."""
+    from paddle_tpu.ops import ssm
+    S, H, P, G, N, Lm = 128, 64, 64, 8, 128, 6
+    assert ssm.kernel_viable(H, P, N, G)
+    f32 = jnp.float32
+
+    def sds(shape, dt=f32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    state = sds((Lm * S,) + ssm.packed_shape(H, P, N, G))
+    compiled = jax.jit(
+        lambda st, xs, dt, A, B, C: ssm.ssm_state_step(
+            st, jnp.int32(3), xs, dt, A, B, C, S),
+        donate_argnums=(0,)).lower(
+        state, sds((S, H, P), jnp.bfloat16), sds((S, H)), sds((H,)),
+        sds((S, G, N), jnp.bfloat16), sds((S, G, N), jnp.bfloat16)
+    ).compile()
+    assert "ssm_decode_step" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= Lm * S * H * P * N * 4
+    assert mem.temp_size_in_bytes < 64 << 20
+
+
+def test_relu2_decode_compiles_at_a_width_off_the_lane_tile(one_chip):
+    """relu-squared experts of width 1856 = 4 x 464 (no multiple of
+    128) stored ``[f, h]``: the width rides the sublanes in tiles of 464
+    rows."""
+    from paddle_tpu.ops import moe_experts as moe
+    T, h, f, E = 128, 2688, 1856, 16
+    assert moe.kernel_viable(T, h, f, jnp.bfloat16, gated=False)
+    assert not moe.kernel_viable(T, h, f, jnp.bfloat16)
+    assert moe._f_rows(f, h, 2) == 464
+    bf = jnp.bfloat16
+
+    def sds(shape, dt=bf):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    text = _compile(
+        lambda x, up, down, cw: moe.moe_experts_relu2_decode(
+            x, up, down, cw, jnp.int32(8)),
+        sds((T, h)), sds((E, f, h)), sds((E, f, h)),
+        sds((T, 8), jnp.float32))
+    assert "moe_experts_relu2_decode" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_grouped_query_paged_decode_compiles(one_chip, dtype,
+                                             mosaic_backend):
+    """32 query heads over 2 KV heads of 128 in blocks of 256: the 16
+    query heads of a group are the rows of the MXU tile."""
+    slots, per_slot, nkv, nq, hd, block = 128, 48, 2, 32, 128, 256
+    assert paged_attention.kernel_viable(nkv, hd, block, dtype)
+
+    def sds(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    _compile(paged_attention.paged_decode_attention, sds((slots, nq, hd)),
+             sds((257, nkv, block, hd)), sds((257, nkv, block, hd)),
+             sds((slots, per_slot), jnp.int32), sds((slots,), jnp.int32))
+
+
+def _hybrid_programs(one_chip, pattern, slots, max_len):
+    from paddle_tpu.serving.paged.hybrid_programs import \
+        build_paged_hybrid_fns
+    from paddle_tpu.text import nemotron_h as nh
+    cfg = nh.NemotronHConfig.from_hf(
+        dict(NEMOTRON, hybrid_override_pattern=pattern), dtype="bfloat16")
+    BS = 256
+    MB = max_len // BS
+    NB = slots * MB + 1
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        prefill, decode = build_paged_hybrid_fns(cfg, slots, BS, NB, MB)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dt),
+                                    sharding=one_chip)
+    params = {}
+    for path, (shape, _, dt) in nh.param_shapes(cfg).items():
+        node = params
+        for part in path[:-1]:
+            node = node.setdefault(part, {})
+        node[path[-1]] = sds(shape, dt)
+    spec = nh.hybrid_cache_spec(cfg).with_slots(slots)
+    pool = [sds(spec.shape(a, NB, BS), a.dtype) for a in spec.arrays]
+    state = [sds(shape, dt) for _, shape, dt in spec.state]
+    i32 = jnp.int32
+    toks, pos = sds((slots,), i32), sds((slots,), i32)
+    nbytes = [int(np.prod(p.shape)) * p.dtype.itemsize for p in pool]
+    return prefill, decode, params, pool, state, toks, pos, MB, nbytes
+
+
+def test_hybrid_decode_program_updates_both_kinds_of_state_in_place(
+        one_chip):
+    """The nemotron_h decode program (one layer of each kind in a
+    repeated run, published widths, 16 slots) carries keys, values,
+    convolution windows and recurrent state through its layer loop in
+    place: all four aliased onto the results, temporaries far under
+    them, all three kernels in the program."""
+    _, decode, params, pool, state, toks, pos, MB, nbytes = \
+        _hybrid_programs(one_chip, "ME*ME*", 16, 1024)
+    n = len(pool)
+    tables = jax.ShapeDtypeStruct((16, MB), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        decode, donate_argnums=(2,) + tuple(range(4, 4 + n))).lower(
+        params, toks, pos, tables, *pool, *state).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(nbytes)
+    assert mem.temp_size_in_bytes < min(nbytes[0], nbytes[3]) // 4, \
+        mem.temp_size_in_bytes
+    text = compiled.as_text()
+    for kernel in ("ssm_decode_step", "moe_experts_relu2_decode",
+                   "paged_decode_attn"):
+        assert kernel in text
+
+
+def test_hybrid_prefill_program_copies_no_slot_state(one_chip):
+    """The prefill program cuts ONE slot's state out before its layer
+    loop and puts it back after: no copy of the whole recurrent state
+    (with the state in the loop's carry XLA relaid all of it for the
+    chunked scan's small transposes: PERF.md, PR 35)."""
+    prefill, _, params, pool, _, toks, pos, MB, nbytes = \
+        _hybrid_programs(one_chip, "ME*ME*", 16, 1024)
+    n = len(pool)
+    i32 = jnp.int32
+    scalar = jax.ShapeDtypeStruct((), i32, sharding=one_chip)
+    compiled = jax.jit(
+        prefill, donate_argnums=tuple(range(8, 9 + n))).lower(
+        params, jax.ShapeDtypeStruct((1, 512), i32, sharding=one_chip),
+        scalar, scalar, scalar, scalar,
+        jax.ShapeDtypeStruct((MB,), i32, sharding=one_chip), toks, pos,
+        *pool).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(nbytes)
+    state = pool[3].shape
+    shapes = "|".join(",".join(str(d) for d in s) for s in (
+        state, (state[0] * state[1],) + state[2:]))
+    moved = re.findall(rf"= f32\[(?:{shapes})\]\S* copy\(",
+                       compiled.as_text())
+    assert not moved, moved

@@ -296,6 +296,70 @@ def test_sync_mode_matches_pipelined_engine():
     assert eng_b.metrics.speculative_masked == 0
 
 
+@pytest.mark.parametrize("chunk", [None, 8], ids=["whole", "chunked"])
+@pytest.mark.parametrize("depth", [2, 4, 12])
+def test_deep_pipeline_matches_generate(depth, chunk):
+    """``async_depth=k``: up to k steps' results stay unread while the
+    device works through them (a host that is away for less than k
+    steps stalls nothing). More requests than slots, uneven lengths, so
+    slots are prereleased, reused and prefilled while older steps that
+    still name them are in flight: every request's tokens equal
+    generate()'s, nothing is left unread, and the pipeline did reach
+    its depth."""
+    m = _model()
+    rs = np.random.RandomState(21)
+    specs = [(3, 14), (11, 4), (7, 19), (20, 5), (5, 17), (13, 3),
+             (30, 9), (4, 1)]
+    prompts = _prompts(rs, [n for n, _ in specs])
+    eng = ServingEngine(m, num_slots=3, bucket_min=8, block_size=4,
+                        async_depth=depth, prefill_chunk=chunk)
+    reqs = [eng.add_request(p, max_new_tokens=k)
+            for p, (_, k) in zip(prompts, specs)]
+    deepest = 0
+    while eng.step():
+        deepest = max(deepest, len(eng._pending_steps))
+        assert len(eng._pending_steps) <= depth
+        assert sum(eng._pending_steps) == len(eng._pending)
+    assert deepest == depth
+    assert not eng._pending and not eng._pending_steps
+    for r, p, (_, k) in zip(reqs, prompts, specs):
+        np.testing.assert_array_equal(r.output_ids, _ref(m, p, k))
+    assert eng.pool.reuse_count >= len(specs) - 3
+    eng.pool.check_conservation()
+
+
+@pytest.mark.parametrize("depth", [1, 3, 8])
+def test_deep_pipeline_masks_every_step_past_an_eos(depth):
+    """An EOS is known only when its token is read, ``depth`` steps
+    after its dispatch: the steps dispatched meanwhile computed a token
+    for the stopped request each, and every one of them is masked. The
+    slot's next request starts clean."""
+    m = _model()
+    rs = np.random.RandomState(4)
+    p1, p2, p3 = _prompts(rs, [5, 8, 6])
+    want = _ref(m, p1, 3)
+    eos = int(want[-1])                # greedy's third token
+    assert eos not in want[len(p1):-1]
+    eng = ServingEngine(m, num_slots=2, bucket_min=8,
+                        async_depth=depth)
+    r1 = eng.add_request(p1, max_new_tokens=40, eos_id=eos)
+    r2 = eng.add_request(p2, max_new_tokens=30)
+    r3 = eng.add_request(p3, max_new_tokens=5)
+    eng.run()
+    np.testing.assert_array_equal(r1.output_ids, want)
+    np.testing.assert_array_equal(r2.output_ids, _ref(m, p2, 30))
+    np.testing.assert_array_equal(r3.output_ids, _ref(m, p3, 5))
+    assert eng.metrics.speculative_masked == depth
+
+
+def test_async_depth_refusals():
+    with pytest.raises(ValueError, match="async_depth"):
+        ServingConfig(async_depth=-1)
+    with pytest.raises(ValueError, match="HARVESTED"):
+        ServingConfig(async_depth=2, speculative=True)
+    assert ServingConfig(async_depth=1, speculative=True).speculative
+
+
 def test_forced_donation_parity_on_cpu():
     """donate_buffers=True: JAX enforces donation semantics (the input
     buffers are invalidated after the call) even on backends that

@@ -47,6 +47,7 @@ import os
 import signal
 import subprocess
 import sys
+import tempfile
 import threading
 import time
 import urllib.request
@@ -65,9 +66,15 @@ def _spawn(idx, role=None):
     env.setdefault("ROUTER_PORT", "0")
     if role is not None:
         env["ROUTER_ROLE"] = role
+    # stderr goes to a file, not a pipe: nobody reads it while the
+    # worker lives, and a pipe that fills (a runtime that warns once
+    # per program it loads from the compile cache fills 64 KB before
+    # the ready line) blocks the worker for good
+    err = tempfile.TemporaryFile(mode="w+")
     proc = subprocess.Popen(
         [sys.executable, _WORKER], env=env, stdout=subprocess.PIPE,
-        stderr=subprocess.PIPE, text=True)
+        stderr=err, text=True)
+    proc.err_file = err
     return proc
 
 
@@ -83,7 +90,9 @@ def _ready(proc, timeout=180.0):
     line = box.get("line")
     if not line:
         proc.kill()
-        err = proc.stderr.read()[-2000:] if proc.stderr else ""
+        proc.wait()
+        proc.err_file.seek(0)
+        err = proc.err_file.read()[-2000:]
         raise RuntimeError(
             f"replica worker never became ready:\n{err}")
     return json.loads(line)
