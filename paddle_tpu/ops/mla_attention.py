@@ -1,6 +1,6 @@
 """Multi-head latent attention (DeepSeek-V2/V3): the plain ``jnp``
 formulations of both of its forms and the Pallas TPU kernel for the
-absorbed form over the paged latent cache.
+absorbed form computed DIRECTLY over the paged latent cache.
 
 A token's cache entry is ONE latent ``c`` (``kv_lora_rank`` values,
 RMS-normed) and ONE rotary key ``k_pe`` (``qk_rope_head_dim`` values)
@@ -22,10 +22,43 @@ layer of every step: AOT, PR 28), and its scores are a plain ``[nh, dr]
 x [dr, BS]`` matmul.
 
 ``mla_paged_decode_attn`` is the absorbed form's middle (scores,
-softmax, ``o_lat``) for every head of a slot over the slot's blocks,
-read in place through scalar-prefetched table rows under the length
-mask; ``mla_decode_attn_jnp`` is the same arithmetic in ``jnp`` over a
-gathered view (the CPU path and the interpret-mode oracle).
+softmax, ``o_lat``) for every head of a slot over the slot's LIVE
+blocks, read in place; ``mla_decode_attn_jnp`` is the same arithmetic in
+``jnp`` over a gathered view (the CPU path and the interpret-mode
+oracle).
+
+How the table drives the DMA schedule (after ``ops/paged_attention.py``):
+grid ``(S,)``, one step a slot, with ``tables`` and ``lengths``
+scalar-prefetched, both pools left in HBM (``memory_space=ANY``) and
+``q_lat``, ``q_pe`` and the output resident in VMEM for the whole call.
+A slot's live blocks, ``ceil(length / BS)`` clipped to ``MB``, are
+walked in CHUNKS of ``G`` blocks (``blocks_per_chunk``: from the shapes
+and a VMEM budget, not an option): one chunk is ``G`` latent and ``G``
+rotary-key block copies (``make_async_copy``, physical ids from the
+table) into one half of a double-buffered pair, the latent's blocks one
+under the other in ``[2, G*BS, rank]``, the rotary key's as the planes
+of ``[2, G, dr, BS]``, while the other half is computed. Only live
+blocks are copied: a slot with nothing live (length 0: a released slot)
+costs its grid step, a parked one (length 1) one block of DMA and one
+chunk of arithmetic, a full one ``MB / G`` chunks, and the next chunk
+(the next SLOT's first chunk after a slot's last) is always in flight
+behind the one being computed. On the chip the copies are the bound and
+the arithmetic hides behind them (``_CHUNK_VMEM_BYTES``).
+
+A chunk's arithmetic covers its ``T = G*BS`` positions at once:
+scores ``q_lat [nh, rank] x c [T, rank]^T`` + ``q_pe [nh, dr]
+x k_pe [dr, BS]`` a block, side by side on the lanes; the f32 online
+softmax; ``p`` rounded once to the cache's dtype; ``p [nh, T] x c [T,
+rank]`` accumulated in f32. The heads are the rows of every matmul and
+the latent is the MXU's stationary operand in both (streaming the latent
+past a stationary ``q_lat`` read a third slower on the chip, PR 36).
+
+In-kernel masking mirrors the oracle exactly: positions ``>=
+lengths[s]`` (the tail of a partially-filled block, the part of a
+chunk's buffer no copy refreshed, a chunk's reach past the slot's
+capacity) get ``-1e30`` before the softmax, so they carry exactly-zero
+weight. The latent buffer, which is the value operand too, is zeroed
+once a call, so what lies behind a zero weight is always finite.
 """
 import functools
 
@@ -39,15 +72,44 @@ _FORCE_INTERPRET = [False]
 _NEG = -1e30
 
 
+# both chunk buffers' two halves. At rank 512 + a rotary key of 64 in
+# bf16, blocks of 256 (288 KB a block), this is G = 4 blocks = 1,024
+# positions a chunk: the smallest that runs a full slot at the rate of
+# larger ones (90.5 % of 819 GB/s at G = 4, 90.8 at 8, 90.5 at 16; 72.9
+# at G = 2 and 51.6 at G = 1, where a short chunk's arithmetic outlasts
+# its copies) and cheaper than G = 8 for a slot with one live block,
+# whose chunk is computed whole (1.6 us against 2.2) (kernel timed in a
+# chain of 120 calls, my chip runs, PR 36)
+_CHUNK_VMEM_BYTES = 5 << 19
+
+
 def kernel_viable(block_size, rank, rope_dim, dtype):
     """Static facts Mosaic needs of the cache blocks: the token axis is
-    the sublane dim (a multiple of 16 for 2-byte types, 8 for f32) and
-    the latent rides the lanes whole (a multiple of 128)."""
+    the sublane dim (a multiple of 16 for 2-byte types, 8 for f32: a
+    block lands in the chunk buffer at a multiple of it), the latent
+    rides the lanes whole (a multiple of 128), and a chunk holds at
+    least one block."""
     dtype = jnp.dtype(dtype)
     if dtype not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
         return False
     sub = 8 if dtype == jnp.dtype(jnp.float32) else 16
-    return block_size % sub == 0 and rank % 128 == 0 and rope_dim % 8 == 0
+    return (block_size % sub == 0 and rank % 128 == 0
+            and rope_dim % 8 == 0
+            and blocks_per_chunk(block_size, rank, rope_dim, 1,
+                                 dtype) == 1)
+
+
+def blocks_per_chunk(block_size, rank, rope_dim, max_blocks, dtype):
+    """``G``: how many blocks one chunk holds. As many as keep the chunk
+    buffers (latent and rotary key, two halves each) inside the budget,
+    at most a slot's capacity; 1 where blocks cannot sit side by side on
+    the lanes of the scores (``block_size`` not whole lane tiles); 0
+    where not even one block fits."""
+    block = block_size * (rank + rope_dim) * jnp.dtype(dtype).itemsize
+    fit = _CHUNK_VMEM_BYTES // (2 * block)
+    if block_size % 128:
+        fit = min(fit, 1)
+    return int(min(max_blocks, fit))
 
 
 # ------------------------------------------------------------ jnp forms
@@ -131,36 +193,92 @@ def mla_paged_decode_attn_jnp(q_lat, q_pe, c_cache, pe_cache, tables,
 
 
 # --------------------------------------------------------------- kernel
-def _mla_decode_kernel(bt_ref, len_ref, ql_ref, qp_ref, c_ref, pe_ref,
-                       o_ref, acc_ref, m_ref, l_ref, *, block_size,
-                       max_blocks, scale):
-    """Grid (S, MB), MB innermost: one slot's blocks arrive in order,
-    the online-softmax state lives in VMEM scratch across them and the
-    output block is written once at the last step. All heads of the
-    slot share each block: scores ``[nh, BS]`` and ``p @ c`` are two
-    MXU matmuls over the latent, one small one over the rotary key."""
+def _mla_decode_kernel(bt_ref, len_ref, ql_ref, qp_ref, c_hbm, pe_hbm,
+                       o_ref, cbuf, pebuf, acc_ref, m_ref, l_ref, sem,
+                       half_ref, *, block_size, group, scale):
+    """Grid (S,), sequential: one step a slot. The table row of the
+    slot names the blocks to copy, ``lengths`` how many of them are
+    live; ``cbuf [2, G*BS, rank]`` / ``pebuf [2, G, dr, BS]`` are the
+    two halves of the chunk buffers (``G`` blocks a chunk: the 4 that
+    the cell's 288 KB blocks give ran a full slot at 90.5 % of the
+    chip's bandwidth, ``_CHUNK_VMEM_BYTES``), ``sem[0/1, half]`` the
+    latent / rotary-key copies' semaphores, ``half_ref`` (SMEM) the half
+    that holds this slot's first chunk: the previous grid step started
+    its copies. All heads of the slot share each chunk: scores ``[nh,
+    T]`` and ``p @ c`` are two MXU matmuls over the latent, ``G`` small
+    ones over the rotary key; the online-softmax state lives in VMEM
+    scratch across the chunks and the slot's output row is written
+    once."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    BS, G, MB = block_size, group, bt_ref.shape[1]
+    T = G * BS
     si = pl.program_id(0)
-    bi = pl.program_id(1)
+    num_slots = pl.num_programs(0)
 
-    @pl.when(bi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def live_blocks(s):
+        return jnp.clip((len_ref[s] + (BS - 1)) // BS, 0, MB)
 
-    length = len_ref[si]
+    def chunk_copies(s, c, half, go):
+        """``go`` (start or wait) the two copies of each live block of
+        slot s's chunk c: the latent to rows [g*BS, (g+1)*BS), the
+        rotary key to plane g."""
+        def block(g, carry):
+            blk = bt_ref[s, c * G + g]
+            rows = pl.ds(pl.multiple_of(g * BS, BS), BS)
+            go(pltpu.make_async_copy(
+                c_hbm.at[blk], cbuf.at[half, rows, :], sem.at[0, half]))
+            go(pltpu.make_async_copy(
+                pe_hbm.at[blk], pebuf.at[half, g], sem.at[1, half]))
+            return carry
+        jax.lax.fori_loop(
+            0, jnp.clip(live_blocks(s) - c * G, 0, G), block, 0)
 
-    def _compute():
-        c = c_ref[...]                                   # [BS, rank]
-        nt = (((1,), (1,)), ((), ()))                    # a @ b^T
-        s = jax.lax.dot_general(ql_ref[...], c, nt,
+    def start_chunk(s, c, half):
+        chunk_copies(s, c, half, lambda copy: copy.start())
+
+    def wait_chunk(s, c, half):
+        chunk_copies(s, c, half, lambda copy: copy.wait())
+
+    @pl.when(si == 0)
+    def _first():
+        # what no copy has written yet must be finite behind its zero
+        # weight (the latent is the value operand too); the rotary
+        # key's garbage is replaced by the mask itself
+        cbuf[...] = jnp.zeros_like(cbuf)
+        half_ref[0] = 0
+        start_chunk(0, 0, 0)
+
+    # a chunk may reach past the slot's capacity: nothing is live there
+    length = jnp.minimum(len_ref[si], MB * BS)
+    chunks = (live_blocks(si) + (G - 1)) // G
+    half0 = half_ref[0]
+    has_next = si + 1 < num_slots
+    nxt = jnp.minimum(si + 1, num_slots - 1)
+    nt = (((1,), (1,)), ((), ()))                        # a @ b^T
+
+    def chunk(c, carry):
+        half = (half0 + c) % 2
+
+        @pl.when(c + 1 < chunks)
+        def _():
+            start_chunk(si, c + 1, 1 - half)
+
+        @pl.when(jnp.logical_and(c + 1 == chunks, has_next))
+        def _():
+            start_chunk(nxt, 0, 1 - half)
+
+        wait_chunk(si, c, half)
+        lat = cbuf[half]                                 # [T, rank]
+        qp = qp_ref[si]
+        s = jax.lax.dot_general(ql_ref[si], lat, nt,
                                 preferred_element_type=jnp.float32)
-        s = s + jnp.dot(qp_ref[...], pe_ref[...],        # [dr, BS]
-                        preferred_element_type=jnp.float32)
-        s = s * jnp.float32(scale)                       # [nh, BS]
-        kpos = bi * jnp.int32(block_size) + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
+        s = s + jnp.concatenate(
+            [jnp.dot(qp, pebuf[half, g],                 # [dr, BS]
+                     preferred_element_type=jnp.float32)
+             for g in range(G)], axis=1)
+        s = s * jnp.float32(scale)                       # [nh, T]
+        kpos = c * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(kpos < length, s, jnp.float32(_NEG))
         m_prev = m_ref[...]                              # [nh, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -169,18 +287,31 @@ def _mla_decode_kernel(bt_ref, len_ref, ql_ref, qp_ref, c_ref, pe_ref,
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1,
                                                   keepdims=True)
         m_ref[...] = m_new
-        pv = jnp.dot(p.astype(c.dtype), c,
+        pv = jnp.dot(p.astype(lat.dtype), lat,
                      preferred_element_type=jnp.float32)  # [nh, rank]
         acc_ref[...] = acc_ref[...] * alpha + pv
+        return carry
 
-    # blocks wholly beyond the live length weigh nothing: no math (the
-    # index map re-presents the last live block, so no DMA either)
-    pl.when(bi * jnp.int32(block_size) < length)(_compute)
+    @pl.when(chunks > 0)
+    def _live():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        jax.lax.fori_loop(0, chunks, chunk, 0)
+        # l >= 1 (the max's own exp term)
+        o_ref[si] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
-    @pl.when(bi == max_blocks - 1)
-    def _store():
-        l = jnp.maximum(l_ref[...], jnp.float32(1e-37))
-        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+    @pl.when(chunks == 0)
+    def _idle():
+        # nothing live (a released slot): no copy, no arithmetic, a
+        # finite row nobody reads; the hand-over still happens
+        o_ref[si] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+        @pl.when(has_next)
+        def _():
+            start_chunk(nxt, 0, half0)
+
+    half_ref[0] = (half0 + chunks) % 2
 
 
 def _mla_paged_decode_32(q_lat, q_pe, c_cache, pe_cache, tables, lengths,
@@ -191,43 +322,45 @@ def _mla_paged_decode_32(q_lat, q_pe, c_cache, pe_cache, tables, lengths,
     dr = q_pe.shape[-1]
     BS = c_cache.shape[1]
     MB = tables.shape[1]
+    dtype = c_cache.dtype
+    G = blocks_per_chunk(BS, rank, dr, MB, dtype)
     tables = tables.astype(jnp.int32)
     lengths = lengths.astype(jnp.int32)
 
-    def q_index(si, bi, bt_ref, len_ref):
-        return (si, 0, 0)
-
-    def kv_index(si, bi, bt_ref, len_ref):
-        # physical block from the prefetched table row, clamped to the
-        # slot's last live block: steps beyond it repeat an index and
-        # their DMA is elided
-        last = jnp.minimum(jnp.maximum(len_ref[si] - 1, 0)
-                           // jnp.int32(BS), MB - 1)
-        return (bt_ref[si, jnp.minimum(bi, last)], 0, 0)
+    def whole(shape):
+        # q and o stay in VMEM for the whole call (a block a grid step
+        # would put three small copies' latency into every step)
+        return pl.BlockSpec(shape, lambda si, bt_ref, len_ref:
+                            (0,) * len(shape))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, MB),
+        grid=(S,),
         in_specs=[
-            pl.BlockSpec((None, nh, rank), q_index),
-            pl.BlockSpec((None, nh, dr), q_index),
-            pl.BlockSpec((None, BS, rank), kv_index),
-            pl.BlockSpec((None, dr, BS), kv_index),
+            whole(q_lat.shape),
+            whole(q_pe.shape),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((None, nh, rank), q_index),
+        out_specs=whole((S, nh, rank)),
         scratch_shapes=[
+            pltpu.VMEM((2, G * BS, rank), dtype),
+            pltpu.VMEM((2, G, dr, BS), dtype),
             pltpu.VMEM((nh, rank), jnp.float32),
             pltpu.VMEM((nh, 1), jnp.float32),
             pltpu.VMEM((nh, 1), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
-    kernel = functools.partial(_mla_decode_kernel, block_size=BS,
-                               max_blocks=MB, scale=float(scale))
+    kernel = functools.partial(_mla_decode_kernel, block_size=BS, group=G,
+                               scale=float(scale))
     return pl.pallas_call(
         kernel, name="mla_paged_decode_attn", grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, nh, rank), jnp.float32),
+        # sequential: a slot's last chunk starts the next slot's first
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=_FORCE_INTERPRET[0],
     )(tables, lengths, q_lat, q_pe, c_cache, pe_cache)
 

@@ -344,14 +344,21 @@ def _sds(one_chip):
     return sds
 
 
-def test_mla_paged_decode_attn_compiles(one_chip):
+@pytest.mark.parametrize("nh,r,BS,G", [(32, 512, 256, 4),
+                                       (16, 256, 128, 16)],
+                         ids=["kanana2", "h16-r256-bs128"])
+def test_mla_paged_decode_attn_compiles(one_chip, nh, r, BS, G):
     """32 heads over a latent of 512 + a rotary key of 64, blocks of 256
     tokens, 32 slots of 64 blocks: Mosaic takes the kernel as the
-    benchmark's cell runs it."""
+    benchmark's cell runs it, at the blocks a chunk its shapes give;
+    and at another head count, rank and block size, whose chunk holds
+    another number of blocks."""
     from paddle_tpu.ops import mla_attention
     sds = _sds(one_chip)
-    S, nh, r, dr, BS, MB = 32, 32, 512, 64, 256, 64
+    S, dr, MB = 32, 64, 64
     NB = 6 * (S * MB + 1)
+    assert mla_attention.kernel_viable(BS, r, dr, jnp.bfloat16)
+    assert mla_attention.blocks_per_chunk(BS, r, dr, MB, jnp.bfloat16) == G
     _compile(lambda *a: mla_attention.mla_paged_decode_attn(*a, 192 ** -0.5),
              sds((S, nh, r)), sds((S, nh, dr)), sds((NB, BS, r)),
              sds((NB, dr, BS)), sds((S, MB), jnp.int32),

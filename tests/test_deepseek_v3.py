@@ -297,25 +297,77 @@ def interpret(monkeypatch):
     monkeypatch.setattr(moe, "_FORCE_INTERPRET", [True])
 
 
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
-                                       (jnp.bfloat16, 2e-2)],
-                         ids=["f32", "bf16"])
-def test_mla_decode_kernel_matches_formulation(interpret, dtype, tol):
-    rng = np.random.default_rng(0)
-    S, nh, r, dr, BS, MB, NB = 3, 4, 128, 16, 16, 4, 20
+# (block size, blocks a slot, blocks a chunk, per-slot lengths). The
+# first is PR 28's case (blocks of 16 cannot sit side by side on the
+# lanes: one block a chunk). The others walk chunks of G = 2 blocks of
+# 128 over slots of 5 (capacity 640, chunks of 256, an odd count of
+# chunks so that the buffers' halves swap from slot to slot): a released
+# slot (0) between live ones and at either end (the hand-over of the
+# prefetch), 1, a chunk's last position (256, 512), its first position
+# + 1 (258, 514), a ragged last block (300, 130), full capacity and one
+# past it (``pos + 1`` at the clamp in ``LatentAccess.decode``)
+_MLA_WALKS = {
+    "blocks16": (16, 4, 1, [5, 33, 64]),
+    "chunks": (128, 5, 2, [300, 0, 1, 256, 258, 640, 641, 0, 0, 514]),
+    "edges": (128, 5, 2, [0, 640, 0, 512, 130, 1, 0, 257, 641, 0]),
+}
+
+
+def _mla_case(monkeypatch, walk, dtype, rng):
+    BS, MB, G, lengths = _MLA_WALKS[walk]
+    S, nh, r, dr = len(lengths), 4, 128, 16
+    NB = S * MB + 2       # the last two are in no table
+    if G > 1:
+        # the chunk budget of a test-sized block: room for G blocks
+        monkeypatch.setattr(mla, "_CHUNK_VMEM_BYTES", 2 * G * BS * (r + dr)
+                            * jnp.dtype(dtype).itemsize)
+    assert mla.blocks_per_chunk(BS, r, dr, MB, dtype) == G
+    assert G == 1 or MB > 2 * G
     q_lat = jnp.asarray(rng.normal(size=(S, nh, r)), dtype)
     q_pe = jnp.asarray(rng.normal(size=(S, nh, dr)), dtype)
     c = jnp.asarray(rng.normal(size=(NB, BS, r)), dtype)
     pe = jnp.asarray(rng.normal(size=(NB, dr, BS)), dtype)
-    tables = jnp.asarray(rng.permutation(NB)[:S * MB].reshape(S, MB),
+    tables = jnp.asarray(rng.permutation(NB - 2)[:S * MB].reshape(S, MB),
                          jnp.int32)
-    lengths = jnp.asarray([5, 33, 64], jnp.int32)   # part, mid, full
+    return q_lat, q_pe, c, pe, tables, jnp.asarray(lengths, jnp.int32)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("walk", list(_MLA_WALKS))
+def test_mla_decode_kernel_matches_formulation(interpret, monkeypatch,
+                                               walk, dtype, tol):
+    args = _mla_case(monkeypatch, walk, dtype, np.random.default_rng(0))
+    want = mla.mla_paged_decode_attn_jnp(*args, 0.1)
+    got = mla.mla_paged_decode_attn(*args, 0.1)
+    assert got.dtype == jnp.float32
+    # a slot with nothing live: the oracle averages what it gathered,
+    # the kernel writes zeros, nobody reads either
+    live = np.asarray(args[-1]) > 0
+    assert np.abs(np.asarray(got) - np.asarray(want))[live].max() < tol
+    assert np.isfinite(np.asarray(got)).all()
+
+
+@pytest.mark.parametrize("walk", ["blocks16", "chunks"])
+def test_mla_decode_kernel_never_reads_a_dead_block(interpret, monkeypatch,
+                                                    walk):
+    """Table entries past a slot's live blocks point at a block of
+    ``nan`` latent and ``inf`` rotary key (in the pool: the trash block,
+    or a released slot's stale row): neither copied nor computed."""
+    q_lat, q_pe, c, pe, tables, lengths = _mla_case(
+        monkeypatch, walk, jnp.float32, np.random.default_rng(1))
+    BS, MB = c.shape[1], tables.shape[1]
+    bad = c.shape[0] - 1
+    dead = np.arange(MB)[None, :] * BS >= np.asarray(lengths)[:, None]
     want = mla.mla_paged_decode_attn_jnp(q_lat, q_pe, c, pe, tables,
                                          lengths, 0.1)
-    got = mla.mla_paged_decode_attn(q_lat, q_pe, c, pe, tables, lengths,
-                                    0.1)
-    assert got.dtype == jnp.float32
-    assert np.abs(np.asarray(got) - np.asarray(want)).max() < tol
+    got = mla.mla_paged_decode_attn(
+        q_lat, q_pe, c.at[bad].set(jnp.nan), pe.at[bad].set(jnp.inf),
+        jnp.where(dead, bad, tables), lengths, 0.1)
+    live = np.asarray(lengths) > 0
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.abs(np.asarray(got) - np.asarray(want))[live].max() < 2e-6
 
 
 @pytest.mark.parametrize("layer_m", [0, 1])
