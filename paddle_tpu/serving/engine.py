@@ -455,19 +455,9 @@ class ServingEngine:
             raise ValueError(
                 f"max_len {cache_len} exceeds the model's position "
                 f"table max_seq_len {cfg.max_seq_len}")
-        buckets = config.buckets or default_buckets(cache_len,
-                                                    config.bucket_min)
-        if max(buckets) > cache_len:
-            raise ValueError("prefill buckets cannot exceed max_len")
         self.cache_len = cache_len
         self.params = model.export_decode_params()
         self.sampling = bool(config.sampling)
-        self.chunk_len = config.prefill_chunk
-        self.prefill_token_budget = config.prefill_token_budget
-        if self.chunk_len is not None and self.chunk_len > cache_len:
-            raise ValueError(
-                f"prefill_chunk {self.chunk_len} exceeds the per-slot "
-                f"capacity {cache_len}")
         # a model that has programs for only some of the engine's
         # options refuses the others here, by name
         check = getattr(model, "check_serving_config", None)
@@ -487,10 +477,44 @@ class ServingEngine:
                 cfg.hidden_size // cfg.num_heads,
                 self.params["stacked"]["qkv_w"].dtype)
         kv_dtype = self.cache_spec.arrays[0].dtype
-        # the GPT's (k, v) pair: what kv_wire, the speculative verify
-        # programs and the analytic decode model are written for
+        # entries that are not positions (CacheSpec.window): a prompt
+        # is prefilled a WINDOW at a time (a run that fills its window
+        # compacts it), so the chunk is the window and no bucket is
+        # wider; the step loop keeps every live slot's position (from
+        # its own counts, never read back) to hand out and take back
+        # blocks and to dispatch a finished window's compaction
+        self._window = self.cache_spec.window
+        self._hpos = {}   # slot -> the position its next step writes
+        widest = cache_len
+        self.chunk_len = config.prefill_chunk
+        self.prefill_token_budget = config.prefill_token_budget
+        if self._window is not None:
+            W = self._window[0]
+            if self.chunk_len not in (None, W):
+                raise ValueError(
+                    f"prefill_chunk {self.chunk_len}: this model's "
+                    f"cache is compacted a window of {W} positions at "
+                    f"a time and its prompts are prefilled by windows")
+            widest = min(W, cache_len)
+            self.chunk_len = W
+            self.prefill_token_budget = config.prefill_token_budget or W
+        buckets = config.buckets or default_buckets(widest,
+                                                    config.bucket_min)
+        if max(buckets) > widest:
+            raise ValueError(
+                "prefill buckets cannot exceed max_len" if widest
+                == cache_len else f"prefill buckets cannot exceed the "
+                f"cache window ({widest} positions)")
+        if self.chunk_len is not None and self.chunk_len > cache_len \
+                and self._window is None:
+            raise ValueError(
+                f"prefill_chunk {self.chunk_len} exceeds the per-slot "
+                f"capacity {cache_len}")
+        # the GPT's (k, v) pair, one entry a position: what kv_wire,
+        # the speculative verify programs and the analytic decode model
+        # are written for
         self._kv_pair = [a.name for a in self.cache_spec.arrays] \
-            == ["k", "v"]
+            == ["k", "v"] and self._window is None
         self.pool = self._new_pool()
         # the decode-attention path, resolved ONCE at build time
         # from what is observable and nothing else: a (k, v) pool
@@ -518,6 +542,8 @@ class ServingEngine:
             self._prefill_fn, self._decode_fn = \
                 model.build_paged_serving_fns(
                     *sizes, sampling=self.sampling)
+        self._compact_fn = model.build_paged_compact_fn(*sizes) \
+            if self._window is not None else None
         # the attention path the decode program actually runs — what
         # the roofline prices (observability.perf.roofline.LAYOUTS);
         # ``paged_attn`` reads it
@@ -571,7 +597,9 @@ class ServingEngine:
             decode_window=config.trace_decode_window)
         self.scheduler = StepScheduler(
             buckets, cache_len, completed_keep=config.completed_keep,
-            flight=self.flight, policy=self._policy)
+            flight=self.flight, policy=self._policy,
+            chunked_beyond=None if self._window is None
+            else self.chunk_len)
         self.metrics = ServingMetrics(
             slo_ttft_ms=config.slo_ttft_ms,
             slo_tpot_ms=config.slo_tpot_ms,
@@ -772,6 +800,8 @@ class ServingEngine:
             self.cache_spec.bytes_per_token)
         self.metrics.set_state_bytes_per_slot(
             self.cache_spec.bytes_per_slot)
+        if self._window is not None:
+            self.metrics.enable_entry_cache()
         moe = getattr(model, "moe_counter_layout", None)
         if moe is not None:
             self.metrics.set_moe_counters(
@@ -1843,6 +1873,8 @@ class ServingEngine:
                     # the decode/queue -> decode/first_step boundary
                     # for an imported request's trace
                     req.t_decode0 = t_dec
+            if self._window is not None:
+                self._grow_for_decode(snapshot)
             args, donate = self._decode_dispatch_args(pool)
             if spec is not None:
                 v_args, v_donate = self._verify_dispatch_args(
@@ -1855,6 +1887,14 @@ class ServingEngine:
                                            step=self._step_id + 1)
                 ex = self._compiled(("decode",), self._decode_fn, args,
                                     donate=donate)
+                if self._window is not None \
+                        and ("compact",) not in self._exec:
+                    # warm with decode: a window's first end must not
+                    # compile in steady state
+                    c_args, c_donate = self._compact_args(
+                        next(iter(snapshot)), 0)
+                    self._compiled(("compact",), self._compact_fn,
+                                   c_args, donate=c_donate)
                 if spec is not None:
                     # BOTH flavors warm up-front regardless of which
                     # one this step needs: a later acceptance-collapse
@@ -1888,6 +1928,8 @@ class ServingEngine:
             if ok:
                 pool.rebind(*arrs)
                 self._toks = nxt
+                if self._window is not None:
+                    self._after_decode(snapshot)
                 M.decode_steps += 1
                 self._decode_fail_streak = 0
                 if use_spec:
@@ -2086,6 +2128,8 @@ class ServingEngine:
             tail = len(ids) - start
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :tail] = ids[start:]
+            if self._window is not None:
+                pool.grow(alloc.slot, self.cache_spec.entries(len(ids)))
             args = (self.params, tokens, np.int32(tail),
                     np.int32(start), np.int32(alloc.slot),
                     np.int32(1), pool.table_row(alloc.slot),
@@ -2120,6 +2164,8 @@ class ServingEngine:
                 raise
             pool.rebind(*arrs)
             pool.commit_prefix(alloc.slot, ids)
+            if self._window is not None:
+                self._prefilled(alloc.slot, start, tail)
             M.record_admission(req)
             self._stamp_prefill(req, t_disp, bucket)
             M.requests_admitted += 1
@@ -2160,7 +2206,8 @@ class ServingEngine:
         # a slot with recurrent state cannot recompute rows it passed
         self._chunk_q.append(self._ChunkPlan(
             req, alloc.slot, alloc.prefix_tokens, self.chunk_len,
-            tile=bool(self.cache_spec.slot_arrays)))
+            tile=bool(self.cache_spec.slot_arrays)
+            or self._window is not None))
         self._prefilling.add(alloc.slot)
 
     def _dispatch_chunks(self, sync):
@@ -2185,6 +2232,9 @@ class ServingEngine:
                 break           # FIFO: never skip ahead past the head
             tokens = np.zeros((1, C), np.int32)
             tokens[0, :clen] = plan.ids[start:start + clen]
+            if self._window is not None:
+                pool.grow(plan.slot,
+                          self.cache_spec.entries(start + clen))
             args = (self.params, tokens, np.int32(clen),
                     np.int32(start), np.int32(plan.slot),
                     np.int32(1 if final else 0),
@@ -2227,6 +2277,8 @@ class ServingEngine:
                     return   # rolled back (all chunk progress voided;
                 raise        # the retry re-plans from the queue)
             pool.rebind(*arrs)
+            if self._window is not None:
+                self._prefilled(plan.slot, start, clen, final)
             M.record_prefill_chunk(clen)
             self._stamp_prefill(req, t_disp, C)
             budget -= clen
@@ -2245,6 +2297,54 @@ class ServingEngine:
                     self._harvest([entry])
                 else:
                     self._pending.append(entry)
+
+    # ------------------------------- entries that are not positions
+
+    def _prefilled(self, slot, start, length, final=True):
+        """A prefill run of ``length`` positions from window boundary
+        ``start`` went out for ``slot``: a run that filled its window
+        left it compacted; the final run sets where decode goes on."""
+        if length == self._window[0]:
+            self.metrics.record_compaction(0)
+        if final:
+            self._hpos[slot] = start + length
+
+    def _grow_for_decode(self, snapshot):
+        """Before a decode dispatch: every slot it advances has the
+        block its next entry falls in."""
+        pool, entries = self.pool, self.cache_spec.entries
+        for slot in snapshot:
+            pool.grow(slot, entries(self._hpos[slot]) + 1)
+
+    def _compact_args(self, slot, window):
+        pool = self.pool
+        args = (self.params, np.int32(window), pool.table_row(slot)) \
+            + tuple(pool.arrays)
+        return args, tuple(range(3, len(args)))
+
+    def _after_decode(self, snapshot):
+        """After a decode dispatch: every slot it advanced is one
+        position on. One whose window that step FILLED is compacted now,
+        before the next step goes out (``paged_compact``, one dispatch a
+        slot, nothing read back), and the blocks behind its summaries go
+        back to the pool. The gauges count what the live slots hold."""
+        pool, spec, M = self.pool, self.cache_spec, self.metrics
+        W = self._window[0]
+        hpos = self._hpos
+        entries = positions = 0
+        for slot in snapshot:
+            t = hpos[slot] = hpos[slot] + 1
+            if t % W == 0:
+                args, donate = self._compact_args(slot, t // W - 1)
+                ex = self._compiled(("compact",), self._compact_fn, args,
+                                    donate=donate)
+                with M.span("serving/compact_dispatch"):
+                    arrs = self._timed_call(("compact",), ex, args)
+                pool.rebind(*arrs)
+                M.record_compaction(pool.shrink(slot, spec.entries(t)))
+            positions += t
+            entries += spec.entries(t)
+        M.set_cache_live(entries, positions)
 
     # ------------------------------------------------------ resilience
 
@@ -2397,6 +2497,7 @@ class ServingEngine:
             self._pending_steps.clear()
             self._chunk_q = []
             self._prefilling.clear()
+            self._hpos.clear()
             sch.active.clear()
             # parked exports die with the pool: their blocks live in
             # the arrays being replaced, so there is nothing to stream

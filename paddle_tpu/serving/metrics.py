@@ -292,6 +292,7 @@ class ServingMetrics:
         }
         self.kv_donation = {"enabled": False, "effective": False}
         self._moe = None   # set_moe_counters
+        self._g_cache_entries = None   # enable_entry_cache
         self._t_first_work = None
         self._t_last_work = None
 
@@ -429,6 +430,49 @@ class ServingMetrics:
             "serving_state_bytes_per_slot",
             "bytes of per-slot state one slot takes over all layers "
             "(cache spec)").set(float(nbytes))
+
+    def enable_entry_cache(self):
+        """A cache whose ENTRIES are not positions (``CacheSpec.window``:
+        a finished window is compacted, ``serving_kv_bytes_per_token``
+        is then the bytes of one entry): what the live slots hold, from
+        the step loop's own counts, and how often a window was compacted
+        and what that gave back."""
+        r = self.registry
+        self._g_cache_entries = r.gauge(
+            "serving_cache_entries_live",
+            "cache entries the decoding slots hold (summaries of "
+            "finished windows + positions of the current one)")
+        self._g_cache_positions = r.gauge(
+            "serving_cache_positions_live",
+            "positions the decoding slots have reached (what a cache "
+            "of one entry a position would hold)")
+        self._c_compactions = r.counter(
+            "serving_cache_compactions_total",
+            "windows compacted into their summaries (by the compaction "
+            "program after a decode step, or by the prefill run that "
+            "filled them)")
+        self._c_blocks_released = r.counter(
+            "serving_cache_blocks_released_total",
+            "blocks a live slot gave back to the pool at a compaction")
+
+    def set_cache_live(self, entries, positions):
+        self._g_cache_entries.set(float(entries))
+        self._g_cache_positions.set(float(positions))
+
+    def record_compaction(self, blocks_released):
+        self._c_compactions.inc()
+        if blocks_released:
+            self._c_blocks_released.inc(int(blocks_released))
+
+    def entry_cache_report(self):
+        """The four numbers above, or None for a model whose entries
+        are positions."""
+        if self._g_cache_entries is None:
+            return None
+        return {"entries_live": int(self._g_cache_entries.value),
+                "positions_live": int(self._g_cache_positions.value),
+                "compactions": int(self._c_compactions.value),
+                "blocks_released": int(self._c_blocks_released.value)}
 
     def set_moe_counters(self, read_fn, layers, first, count):
         """Expert-routing counters that the decode program keeps ON THE
@@ -927,4 +971,7 @@ class ServingMetrics:
             "tenants": self.tenant_report(),
             # only a model with expert layers adds its section
             **({"moe": self.moe_report()} if self._moe else {}),
+            # only a model whose cache entries are not positions
+            **({"cache_entries": self.entry_cache_report()}
+               if self._g_cache_entries is not None else {}),
         }

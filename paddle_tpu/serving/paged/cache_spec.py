@@ -36,9 +36,21 @@ class CacheSpec:
     ``state`` names the small arrays the DECODE program carries beside
     the cache, ``(name, shape, dtype)``: it takes them after the cache
     arrays and returns them new each step; they are never donated, so a
-    reader in another thread holds a live array whenever it looks."""
+    reader in another thread holds a live array whenever it looks.
 
-    def __init__(self, num_layers, arrays, state=(), slot=()):
+    ``window`` (``(W, C)``) says that a cache ENTRY is not a position:
+    a slot keeps one entry a position of its current window of ``W``
+    positions and, of every window that is over, one entry a chunk of
+    ``C`` positions (``text.evabyte``: a pooled key and value). The
+    window's ``W`` entries become ``W / C`` when it ends, in place, so a
+    slot's cache length is ``entries(positions)``, its capacity is
+    counted in entries, a block is REWRITTEN while its slot lives (not
+    shareable) and the pool hands a slot its blocks as it grows and
+    takes them back when a window is compacted (``PagedKVPool.grow``,
+    ``.shrink``)."""
+
+    def __init__(self, num_layers, arrays, state=(), slot=(),
+                 window=None):
         import jax.numpy as jnp
         self.num_layers = int(num_layers)
         token = tuple(
@@ -55,6 +67,33 @@ class CacheSpec:
         self.state = tuple((str(n), tuple(int(d) for d in sh),
                             jnp.dtype(dt)) for n, sh, dt in state)
         self.num_slots = None
+        self.window = None
+        if window is not None:
+            W, C = (int(d) for d in window)
+            if W < 1 or C < 1 or W % C:
+                raise ValueError(f"window {window!r}: the chunk must "
+                                 f"divide the window")
+            self.window = (W, C)
+
+    def entries(self, positions):
+        """Cache entries a slot holds once ``positions`` positions are
+        in it: also the entry that position ``positions`` is written
+        at. A window that is over counts ``W / C``."""
+        if self.window is None:
+            return int(positions)
+        W, C = self.window
+        return (positions // W) * (W // C) + positions % W
+
+    def capacity(self, positions):
+        """The most entries a slot holds at any moment while it is
+        filled to ``positions`` positions: the last window's raw
+        entries, or the window before it just before it is compacted."""
+        if self.window is None or positions <= self.window[0]:
+            return int(positions)
+        W, C = self.window
+        last = (positions - 1) // W
+        return max((last - 1) * (W // C) + W,
+                   self.entries(positions - 1) + 1)
 
     def with_slots(self, num_slots):
         """The same spec knowing how many slots there are (the pool's
@@ -85,13 +124,15 @@ class CacheSpec:
     @property
     def shareable(self):
         """Whether a cached block means the same to every request that
-        reaches it: not where a slot carries state beside its blocks."""
-        return not self.slot_arrays
+        reaches it: not where a slot carries state beside its blocks,
+        nor where a block is rewritten in place (``window``)."""
+        return not self.slot_arrays and self.window is None
 
     @property
     def bytes_per_token(self):
         """Useful bytes one cached token takes over all layers (what a
-        device layout pads on top is not in it)."""
+        device layout pads on top is not in it); with ``window`` set,
+        the bytes of one ENTRY."""
         return sum(a.layers * int(np.prod(a.lead + a.trail, dtype=np.int64))
                    * a.dtype.itemsize for a in self.token_arrays)
 

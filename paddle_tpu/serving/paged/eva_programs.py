@@ -1,0 +1,282 @@
+"""The engine's paged programs for a model whose cache ENTRIES are not
+positions (``text.evabyte``: ``CacheSpec.window``): the same signatures,
+slot bookkeeping and sampling as ``programs.py`` has for the GPT, with
+the model's block IMPORTED, not written out again, and a third program
+that the other models have no use for.
+
+A slot's table row addresses its entries: ``S = W / C`` summaries for
+every window that is over, then the raw keys and values of the current
+window; position ``t`` is written at entry ``S (t // W) + t % W``.
+
+  ``paged_prefill(params, tokens [1, B], tail_len, start, slot, final,
+                  bt_row [MB], toks [S], pos [S], k, v[, samp...])
+      -> (first [1], toks', pos', k, v)``
+      One request's run of ``tail_len <= B <= W`` positions from window
+      boundary ``start`` (the engine tiles a prompt by windows). The
+      run attends its own rows and the summaries before it, read from
+      the slot's first blocks; a run that FILLS its window (``tail_len
+      == W``) leaves the window's ``S`` summaries, any other its raw
+      rows. No host read decides which: both are computed, one is kept.
+
+  ``paged_decode(params, toks [S], pos [S], tables [S, MB], k, v
+                 [, samp...]) -> (next [S], pos + 1, k, v)``
+      One position a slot: the pool rides the layer loop flat (``[L*NB,
+      H, BS, d]``), each slot's current block is read, given its new
+      row and written back whole, and attention is ordinary attention
+      over the slot's entries so far (``ops.paged_attention``, the
+      GPT's kernel, with ``lengths`` = entries).
+
+  ``paged_compact(params, window, bt_row [MB], k, v) -> (k, v)``
+      One slot's finished window ``window``: its ``W`` raw entries
+      read, its ``S`` pooled pairs written over the first of them, in
+      every layer, in place. The step loop dispatches it between the
+      decode step that wrote the window's last position and the next
+      one; which slot and when it knows from its own counts. A program
+      of its own and not a branch of ``paged_decode``: the host has to
+      know every slot's position anyway (it hands out and takes back the
+      blocks), a parked or released slot needs no guard, the decode
+      program that runs 2,047 steps of 2,048 stays free of a 33 MB
+      gather under a condition, and the trace shows its time by name.
+
+Parked and released slots: a slot parked between the chunks of its
+prefill sits at the model's last position, whose entry (clamped to the
+row's last) no live sequence uses; free rows point at the trash block;
+``lengths`` never exceeds what the row's blocks hold.
+"""
+
+
+class PagedAccess:
+    """A layer's way to the flat entry pool ``k, v [L*NB, H, BS, d]``.
+    Built per trace with the table it reads: one row (``bt_row``,
+    prefill) or all of them (``tables``, decode)."""
+
+    def __init__(self, cfg, num_blocks, block_size, blocks_per_slot,
+                 bt_row=None, tables=None):
+        self.cfg = cfg
+        self.NB, self.BS = int(num_blocks), int(block_size)
+        self.MB = int(blocks_per_slot)
+        self.bt_row, self.tables = bt_row, tables
+
+    # ---------------------------------------------------------- prefill
+    def summaries(self, state, layer, start):
+        """The slot's leading entries as far as summaries can reach,
+        ``S (start // W)`` of them live."""
+        import jax
+        import jax.numpy as jnp
+        cfg = self.cfg
+        S = cfg.summaries_per_window
+        most = min(self.MB * self.BS,
+                   S * ((cfg.max_seq_len - 1) // cfg.window_size))
+        rows = layer * jnp.int32(self.NB) \
+            + self.bt_row[:-(-most // self.BS)]
+        out = []
+        with jax.named_scope("kv_gather"):
+            for cache in state:
+                H, d = cache.shape[1], cache.shape[3]
+                out.append(cache[rows].transpose(1, 0, 2, 3).reshape(
+                    H, -1, d)[None, :, :most])
+        return out[0], out[1], (start // cfg.window_size) * S
+
+    def store(self, state, layer, start, k, v, kbar, vbar, length):
+        import jax
+        import jax.numpy as jnp
+        cfg = self.cfg
+        W, S = cfg.window_size, cfg.summaries_per_window
+        B, H, d = k.shape[1:]
+        C = self.MB * self.BS
+        rows = layer * jnp.int32(self.NB) + self.bt_row          # [MB]
+        at = (start // W) * S + jnp.arange(B, dtype=jnp.int32)
+        out = []
+        for cache, raw, bar in zip(state, (k, v), (kbar, vbar)):
+            new = raw[0]                                      # [B, H, d]
+            if B == W:
+                # a run that fills its window leaves its summaries
+                new = new.at[:S].set(jnp.where(
+                    length == W, bar[0].transpose(1, 0, 2), new[:S]))
+            with jax.named_scope("kv_gather"):
+                view = cache[rows].transpose(1, 0, 2, 3).reshape(H, C, d)
+            # rows past the slot's capacity are dropped, not shifted
+            view = view.at[:, at].set(
+                new.transpose(1, 0, 2).astype(cache.dtype), mode="drop")
+            with jax.named_scope("kv_write"):
+                out.append(cache.at[rows].set(
+                    view.reshape(H, self.MB, self.BS, d)
+                    .transpose(1, 0, 2, 3)))
+        return tuple(out)
+
+    # ----------------------------------------------------------- decode
+    def decode(self, state, layer, pos, q, k, v, kernel):
+        import jax
+        import jax.numpy as jnp
+
+        from ...ops import attention as attn_ops
+        from ...ops import paged_attention as paged_ops
+        from .pool import TRASH_BLOCK
+        cfg = self.cfg
+        kf, vf = state
+        BS, C = self.BS, self.MB * self.BS
+        base = layer * jnp.int32(self.NB)
+        # a position past the model's last (a parked slot, counting on)
+        # stays there; its entry is clamped as a whole (programs.py)
+        entry = jnp.minimum(cfg.entries(jnp.minimum(
+            pos, jnp.int32(cfg.max_seq_len - 1))), jnp.int32(C - 1))
+        bidx = jnp.take_along_axis(
+            self.tables, (entry // jnp.int32(BS))[:, None], axis=1)[:, 0]
+        row = (jnp.arange(BS, dtype=jnp.int32)[None, :]
+               == (entry % jnp.int32(BS))[:, None])[:, None, :, None]
+        fb = base + bidx
+        with jax.named_scope("kv_write"):
+            kf = kf.at[fb].set(jnp.where(
+                row, k.astype(kf.dtype)[:, :, None], kf[fb]))
+            vf = vf.at[fb].set(jnp.where(
+                row, v.astype(vf.dtype)[:, :, None], vf[fb]))
+        # what attention may read of a slot: its ENTRIES so far, never
+        # more than the blocks its row holds (a released slot: nothing)
+        held = jnp.sum((self.tables != TRASH_BLOCK).astype(jnp.int32),
+                       axis=1)
+        lengths = jnp.minimum(entry + 1, held * jnp.int32(BS))
+        fn = paged_ops.paged_decode_attention if kernel \
+            else attn_ops.cached_paged_attention
+        return (kf, vf), fn(q, kf, vf, self.tables + base, lengths)
+
+
+def decode_kernel(cfg, block_size):
+    """Whether the decode program's attention is the Pallas paged
+    kernel: yes on any backend that has Mosaic, and then a shape it
+    cannot take is refused here, by name; no on the CPU (the gather)."""
+    import jax
+
+    from ...ops import paged_attention as paged_ops
+    if jax.default_backend() == "cpu" \
+            and not paged_ops._FORCE_INTERPRET[0]:
+        return False
+    if not paged_ops.kernel_viable(cfg.num_heads, cfg.head_dim,
+                                   block_size, cfg.cache_dtype):
+        raise ValueError(
+            f"paged_decode_attn cannot take (heads, head dim, "
+            f"block_size, cache dtype) = ({cfg.num_heads}, "
+            f"{cfg.head_dim}, {block_size}, {cfg.cache_dtype}): "
+            f"ops.paged_attention.kernel_viable")
+    return True
+
+
+def build_paged_eva_fns(cfg, num_slots, block_size, num_blocks,
+                        blocks_per_slot, sampling=False, kernel=None):
+    """(paged_prefill, paged_decode, paged_compact) for an
+    ``EvaByteConfig``. Pure and shape-stable; ``kernel=None`` asks
+    ``decode_kernel``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...ops import eva as eva_ops
+    from ...text import evabyte as block
+    from ..sched.sampling import build_sampling_head
+
+    NB, BS, MB = int(num_blocks), int(block_size), int(blocks_per_slot)
+    W, S = cfg.window_size, cfg.summaries_per_window
+    if S % BS or W % BS:
+        raise ValueError(
+            f"block_size {BS} must divide a window's {S} summaries "
+            f"(window_size {W} / chunk_size {cfg.chunk_size}): a "
+            f"compacted window ends on a block boundary")
+    if kernel is None:
+        kernel = decode_kernel(cfg, block_size)
+    head = build_sampling_head(cfg.vocab_size) if sampling else None
+    L, H, d = cfg.num_layers, cfg.num_heads, cfg.head_dim
+    park = jnp.int32(cfg.max_seq_len - 1)
+
+    def flat(a):
+        return a.reshape((L * NB,) + a.shape[2:])
+
+    def _prefill_core(params, tokens, tail_len, start, slot, final,
+                      bt_row, toks, pos, k, v, samp):
+        B = tokens.shape[1]
+        if B > W:
+            raise ValueError(f"a prefill run of {B} positions is wider "
+                             f"than the window ({W}): prompts are "
+                             f"prefilled by windows")
+        access = PagedAccess(cfg, NB, BS, MB, bt_row=bt_row)
+        x = block.embed(cfg, params, tokens)                 # [1, B, h]
+        positions = (start + jnp.arange(B, dtype=jnp.int32))[None]
+        x, (kf, vf) = block.run_layers(
+            cfg, params, x, positions, access, (flat(k), flat(v)), start,
+            "prefill", length=tail_len)
+        # ONE row through the head, as a [1, h] matmul
+        last = block.lm_head(cfg, params, jax.lax.dynamic_slice_in_dim(
+            x[0], tail_len - 1, 1, axis=0))[0]
+        with jax.named_scope("sample"):
+            if samp is None:
+                first = jnp.argmax(last, -1).astype(jnp.int32)
+            else:
+                seed, temp, topk, topp = samp
+                first = head(last[None], seed[None],
+                             (start + tail_len - 1)[None], temp[None],
+                             topk[None], topp[None])[0]
+            toks = jnp.where(final > 0, toks.at[slot].set(first), toks)
+            pos = pos.at[slot].set(
+                jnp.where(final > 0, start + tail_len, park))
+        return first[None], toks, pos, kf.reshape(k.shape), \
+            vf.reshape(v.shape)
+
+    def _decode_core(params, toks, pos, tables, k, v, samp):
+        access = PagedAccess(cfg, NB, BS, MB, tables=tables)
+        x = block.embed(cfg, params, toks)                   # [S, h]
+        x, (kf, vf) = block.run_layers(
+            cfg, params, x, pos, access, (flat(k), flat(v)),
+            mode="decode", kernel=kernel)
+        logits = block.lm_head(cfg, params, x)
+        with jax.named_scope("sample"):
+            if samp is None:
+                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+            else:
+                seeds, temps, topks, topps = samp
+                nxt = head(logits, seeds, pos, temps, topks, topps)
+        return nxt, pos + jnp.int32(1), kf.reshape(k.shape), \
+            vf.reshape(v.shape)
+
+    def paged_compact(params, window, bt_row, k, v):
+        nw, ns = W // BS, S // BS
+        blocks = jax.lax.dynamic_slice_in_dim(bt_row, window * ns, nw)
+
+        def layer(carry, inp):
+            mu, phi, li = inp
+            rows = li * jnp.int32(NB) + blocks                   # [nw]
+            with jax.named_scope("kv_gather"):
+                kw, vw = (c[rows].transpose(1, 0, 2, 3).reshape(H, W, d)
+                          for c in carry)
+            bars = eva_ops.window_compact(kw, vw, mu, phi,
+                                          cfg.chunk_size)     # [H, S, d]
+            with jax.named_scope("kv_write"):
+                return tuple(
+                    c.at[rows[:ns]].set(
+                        bar.reshape(H, ns, BS, d).transpose(1, 0, 2, 3))
+                    for c, bar in zip(carry, bars)), None
+
+        (kf, vf), _ = jax.lax.scan(
+            layer, (flat(k), flat(v)),
+            (params["layers"]["mu"], params["layers"]["phi"],
+             jnp.arange(L, dtype=jnp.int32)))
+        return kf.reshape(k.shape), vf.reshape(v.shape)
+
+    if sampling:
+        def paged_prefill(params, tokens, tail_len, start, slot, final,
+                          bt_row, toks, pos, k, v, seed, temp, topk,
+                          topp):
+            return _prefill_core(params, tokens, tail_len, start, slot,
+                                 final, bt_row, toks, pos, k, v,
+                                 (seed, temp, topk, topp))
+
+        def paged_decode(params, toks, pos, tables, k, v, seeds, temps,
+                         topks, topps):
+            return _decode_core(params, toks, pos, tables, k, v,
+                                (seeds, temps, topks, topps))
+    else:
+        def paged_prefill(params, tokens, tail_len, start, slot, final,
+                          bt_row, toks, pos, k, v):
+            return _prefill_core(params, tokens, tail_len, start, slot,
+                                 final, bt_row, toks, pos, k, v, None)
+
+        def paged_decode(params, toks, pos, tables, k, v):
+            return _decode_core(params, toks, pos, tables, k, v, None)
+
+    return paged_prefill, paged_decode, paged_compact

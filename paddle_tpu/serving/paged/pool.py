@@ -27,6 +27,15 @@ runs dry); unindexed blocks free immediately at ref 0. An admission
 pins its matched prefix (ref++) BEFORE allocating anything, so it can
 never evict blocks it is about to reuse.
 
+Entries that are not positions (``CacheSpec.window``): the table row
+addresses a slot's ENTRIES and ``blocks_per_slot`` covers the most a
+slot of ``max_len`` positions ever holds. ``acquire`` then RESERVES the
+blocks the request can come to need (a count: admission waits while the
+reservations of the live slots would exceed the pool) and allocates
+none; the engine asks for blocks as the slot's entries grow (``grow``)
+and gives back those behind a compacted window (``shrink``) while the
+slot lives. A grow inside a reservation cannot find the pool dry.
+
 Host/device discipline: the engine routes every
 executable's returned kc/vc through ``rebind`` (single owner of the
 live buffers under donation), while the block table is host-authored
@@ -74,7 +83,10 @@ class PagedKVPool:
         self.spec = spec = spec.with_slots(self.num_slots)
         self.block_size = int(block_size)
         self.max_len = int(max_len)
-        self.blocks_per_slot = -(-self.max_len // self.block_size)
+        # a table row addresses ENTRIES: positions, unless the spec's
+        # windows are compacted
+        self.blocks_per_slot = -(-spec.capacity(self.max_len)
+                                 // self.block_size)
         # default: every slot fully backed, plus the trash block —
         # sharing then stretches the same bytes further. Smaller
         # num_blocks oversubscribes: admission waits when blocks run
@@ -111,6 +123,8 @@ class PagedKVPool:
         self._owner = {}
         self._quarantined = set()
         self._slot_blocks = {}
+        # blocks each live slot may come to hold (CacheSpec.window)
+        self._reserved = {}
         self.reuse_count = 0
         self._ever_used = set()
         self.block_tables = np.full(
@@ -244,11 +258,13 @@ class PagedKVPool:
             raise ValueError(
                 f"prefix_tokens {prefix_tokens} is not block-aligned "
                 f"(block_size {bs})")
-        n_total = -(-int(total_tokens) // bs)
+        n_total = -(-self.spec.capacity(int(total_tokens)) // bs)
         if n_total > self.blocks_per_slot:
             raise ValueError(
                 f"{total_tokens} tokens need {n_total} blocks; a slot "
                 f"row holds {self.blocks_per_slot}")
+        if self.spec.window is not None:
+            return self._acquire_reserved(owner, n_total)
         n_prefix = prefix_tokens // bs
         n_new = n_total - n_prefix
         # the row's LAST block must be freshly allocated (private):
@@ -324,6 +340,59 @@ class PagedKVPool:
         return PagedAllocation(slot, prefix_tokens, prefix_blocks,
                                new_blocks)
 
+    def _acquire_reserved(self, owner, n_blocks):
+        """``acquire`` where entries are not positions: the slot and a
+        RESERVATION of the blocks it can come to hold; its row starts
+        empty and ``grow`` fills it. None while the live reservations
+        leave too little (retirement frees them)."""
+        if sum(self._reserved.values()) + n_blocks > self.num_blocks - 1:
+            return None
+        slot = heapq.heappop(self._free_slots)
+        self._owner[slot] = owner
+        if slot in self._ever_used:
+            self.reuse_count += 1
+        self._ever_used.add(slot)
+        self._reserved[slot] = n_blocks
+        self._slot_blocks[slot] = []
+        self.block_tables[slot, :] = TRASH_BLOCK
+        self._dirty = True
+        return PagedAllocation(slot, 0, [], [])
+
+    def grow(self, slot, entries):
+        """Back the first ``entries`` entries of a live slot's row with
+        blocks (those it lacks, from the free list) before a dispatch
+        writes them. Inside the slot's reservation, so never dry."""
+        row = self._slot_blocks[slot]
+        need = -(-int(entries) // self.block_size)
+        if need <= len(row):
+            return
+        if need > self._reserved[slot]:
+            raise ValueError(
+                f"slot {slot}: {entries} entries need {need} blocks, "
+                f"{self._reserved[slot]} were reserved at admission")
+        while len(row) < need:
+            b = self._alloc_block()
+            assert b is not None, "reserved blocks ran dry"
+            self.block_tables[slot, len(row)] = b
+            row.append(b)
+        self._dirty = True
+
+    def shrink(self, slot, entries):
+        """Give back the blocks behind a live slot's first ``entries``
+        entries (a window was compacted: what lay there is summarised
+        in the entries that stay). Their table entries point at trash
+        from the next upload on. Returns how many went back."""
+        row = self._slot_blocks[slot]
+        keep = -(-int(entries) // self.block_size)
+        gone = row[keep:]
+        if gone:
+            del row[keep:]
+            for b in gone:
+                self._deref(b)
+            self.block_tables[slot, keep:] = TRASH_BLOCK
+            self._dirty = True
+        return len(gone)
+
     def commit_prefix(self, slot, prompt):
         """Index the slot's FULL prompt blocks in the radix tree so
         later admissions can hit them. Only blocks every row of which
@@ -350,6 +419,7 @@ class PagedKVPool:
         del self._owner[slot]
         for b in self._slot_blocks.pop(slot):
             self._deref(b)
+        self._reserved.pop(slot, None)
         heapq.heappush(self._free_slots, slot)
         self.block_tables[slot, :] = TRASH_BLOCK
         self._dirty = True
@@ -421,6 +491,7 @@ class PagedKVPool:
             "free_blocks": len(self._free_blocks),
             "live_blocks": self.live_blocks,
             "evictable_blocks": self._evictable,
+            "reserved_blocks": sum(self._reserved.values()),
             "indexed_blocks": len(self.index),
             "radix_depth": self.index.stats()["depth"],
             "evictions": self.evictions,
@@ -457,4 +528,13 @@ class PagedKVPool:
             assert r >= 0, (b, r)
             if r == 0:
                 assert b in self.index  # unindexed ref-0 blocks free
+        # reservations (CacheSpec.window): never beyond the pool, each
+        # slot inside its own, its table row its blocks and then trash
+        assert sum(self._reserved.values()) <= self.num_blocks - 1
+        for slot, n in self._reserved.items():
+            row = self._slot_blocks[slot]
+            assert len(row) <= n, (slot, len(row), n)
+            assert list(self.block_tables[slot, :len(row)]) == row
+            assert (self.block_tables[slot, len(row):]
+                    == TRASH_BLOCK).all(), slot
         return True

@@ -174,8 +174,11 @@ class StepScheduler:
     """
 
     def __init__(self, buckets, cache_len, completed_keep=4096,
-                 flight=None, policy=None):
+                 flight=None, policy=None, chunked_beyond=None):
         self.buckets = sorted(int(b) for b in buckets)
+        # prompts longer than this are ALWAYS prefilled in chunks of it
+        # (a cache compacted a window at a time): they need no bucket
+        self.chunked_beyond = chunked_beyond
         self.cache_len = int(cache_len)
         if not self.buckets:
             raise ValueError("need at least one prefill bucket")
@@ -204,7 +207,8 @@ class StepScheduler:
 
     def submit(self, request):
         n = len(request.prompt)
-        self.bucket_for(n)  # raises on oversized prompts
+        if self.chunked_beyond is None or n <= self.chunked_beyond:
+            self.bucket_for(n)  # raises on oversized prompts
         if n + request.max_new_tokens > self.cache_len:
             raise ValueError(
                 f"prompt {n} + max_new_tokens {request.max_new_tokens} "

@@ -601,3 +601,94 @@ def test_hybrid_prefill_program_copies_no_slot_state(one_chip):
     moved = re.findall(rf"= f32\[(?:{shapes})\]\S* copy\(",
                        compiled.as_text())
     assert not moved, moved
+
+
+# ----------------------------- evabyte: cache entries that are not positions
+EVABYTE = dict(vocab_size=320, hidden_size=4096, num_hidden_layers=2,
+               num_attention_heads=32, num_key_value_heads=32,
+               intermediate_size=11008, window_size=2048, chunk_size=16,
+               num_pred_heads=8, max_position_embeddings=32768,
+               rope_theta=100000, init_std=0.01275)
+
+
+def _eva_programs(one_chip, slots):
+    """The three programs at the published widths (2 layers), blocks of
+    64 entries, ``slots`` slots of 3,968 entries."""
+    from paddle_tpu.serving.paged.eva_programs import build_paged_eva_fns
+    from paddle_tpu.text import evabyte as eb
+    cfg = eb.EvaByteConfig.from_hf(EVABYTE, dtype="bfloat16")
+    spec = eb.eva_cache_spec(cfg)
+    BS = 64
+    MB = -(-spec.capacity(cfg.max_seq_len) // BS)
+    NB = slots * MB + 1
+    assert MB == 62
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        fns = build_paged_eva_fns(cfg, slots, BS, NB, MB)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dt),
+                                    sharding=one_chip)
+    params = {}
+    for path, (shape, _, dt) in eb.param_shapes(cfg).items():
+        node = params
+        for part in path[:-1]:
+            node = node.setdefault(part, {})
+        node[path[-1]] = sds(shape, dt)
+    pool = [sds(spec.shape(a, NB, BS), a.dtype) for a in spec.arrays]
+    nbytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in pool)
+    i32 = jnp.int32
+    return fns, params, pool, sds((slots,), i32), sds((slots,), i32), \
+        sds((slots, MB), i32), sds((MB,), i32), sds((), i32), nbytes
+
+
+def test_paged_decode_kernel_compiles_at_32_heads(one_chip, mosaic_backend):
+    """The GPT's kernel, unedited, at 32 heads of 128 in bf16: a block
+    of 64 entries is the largest its chunk buffers hold."""
+    dtype = jnp.bfloat16
+    assert paged_attention.kernel_viable(32, 128, 64, dtype)
+    assert not paged_attention.kernel_viable(32, 128, 128, dtype)
+
+    def sds(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    text = _compile(paged_attention.paged_decode_attention,
+                    sds((20, 32, 128)), sds((1241, 32, 64, 128)),
+                    sds((1241, 32, 64, 128)), sds((20, 62), jnp.int32),
+                    sds((20,), jnp.int32))
+    assert "paged_decode_attn" in text
+
+
+def test_eva_decode_program_aliases_the_whole_pool(one_chip):
+    """The evabyte decode program carries the entry pool through its
+    layer loop in place: all of it aliased onto the results, temporaries
+    in MB, the shared kernel in the program."""
+    (_, decode, _), params, pool, toks, pos, tables, _, _, nbytes = \
+        _eva_programs(one_chip, 8)
+    compiled = jax.jit(decode, donate_argnums=(2, 4, 5)).lower(
+        params, toks, pos, tables, *pool).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes
+    assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
+    assert "paged_decode_attn" in compiled.as_text()
+
+
+def test_eva_compact_and_prefill_programs_update_the_pool_in_place(
+        one_chip):
+    """``paged_compact`` reads one window's raw entries and writes its
+    summaries over the first of them: the pool aliased whole,
+    temporaries a few windows' worth; the window-sized prefill aliases
+    it too."""
+    (prefill, _, compact), params, pool, toks, pos, _, row, scalar, \
+        nbytes = _eva_programs(one_chip, 8)
+    compiled = jax.jit(compact, donate_argnums=(3, 4)).lower(
+        params, scalar, row, *pool).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes
+    window = 2048 * 32 * 128 * 2          # one window's keys, one layer
+    assert mem.temp_size_in_bytes < 16 * window, mem.temp_size_in_bytes
+    compiled = jax.jit(prefill, donate_argnums=(9, 10)).lower(
+        params, jax.ShapeDtypeStruct((1, 2048), jnp.int32,
+                                     sharding=one_chip),
+        scalar, scalar, scalar, scalar, row, toks, pos, *pool).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes
+    assert mem.temp_size_in_bytes < 1 << 30, mem.temp_size_in_bytes
