@@ -574,6 +574,90 @@ def cached_paged_attention(q, k_cache, v_cache, block_tables, lengths):
     return cached_slot_attention(q, k, v, lengths)
 
 
+def causal_attention_lse(q, k, v):
+    """Causal attention of a run of queries over the run's OWN keys and
+    values, with each row's log-sum-exp: ``(out [b, h, s, d]`` in q's
+    dtype, ``lse [b, h, 1, s]`` f32``)``, scale ``d ** -0.5``. The flash
+    forward kernel where it takes the shape (``_use_pallas``: on a TPU
+    sequences from 256 in steps of 128, interpreted on request), else
+    ``jnp`` with the ``[h, s, s]`` scores as a temporary (short and odd
+    runs; the CPU). f32 scores, statistics and accumulation on either;
+    the kernel rounds ``p`` to the value dtype for the MXU, the ``jnp``
+    form keeps it f32 as ``forward_t`` does."""
+    scale = float(q.shape[-1]) ** -0.5
+    if _use_pallas(q):
+        return _pallas_flash_fwd(q, k, v, scale, True)
+    s = q.shape[2]
+    st = jnp.einsum("bhsd,bhtd->bhst", q, k,
+                    preferred_element_type=jnp.float32) * jnp.float32(scale)
+    st = jnp.where(jnp.tril(jnp.ones((s, s), bool)), st, jnp.float32(_NEG))
+    m = jnp.max(st, axis=-1, keepdims=True)
+    p = jnp.exp(st - m)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum("bhst,bhtd->bhsd", p, v,
+                     preferred_element_type=jnp.float32) / l
+    return out.astype(q.dtype), jnp.swapaxes(m + jnp.log(l), 2, 3)
+
+
+# positions of cached prefix a step of paged_prefill_attention's walk
+# reads: [h, run, 256] f32 scores are the walk's largest temporary
+_PREFIX_WALK = 256
+
+
+def paged_prefill_attention(q, k, v, k_cache, v_cache, bt_row, start):
+    """Attention of ONE sequence's run of queries at positions
+    ``start..start + T`` (a paged PREFILL: an uncached tail, or a chunk
+    of one) whose keys and values below ``start`` are in the paged cache
+    and whose own are in hand.
+
+    q, k, v ``[nh, T, hd]``; k_cache, v_cache ``[num_blocks, nh,
+    block_size, hd]`` (one layer's pool, or the flat pool with
+    ``bt_row`` offset to the layer); bt_row ``[MB]`` the slot's table
+    row; start a traced scalar. Two parts, merged by their softmax
+    statistics: the run over itself, causal (``causal_attention_lse``:
+    no key of the cache, no score tensor beyond the run's own square),
+    and a walk over the cached prefix ``_PREFIX_WALK`` positions at a
+    time that reads the blocks below ``start`` and no others, so a run
+    without a prefix pays nothing for one and its cost follows
+    ``start``, never the slot's capacity. A position ``>= start`` that
+    the walk's last step gathers (the rest of a block, trash behind a
+    padded table) gets ``-1e30`` before the f32 softmax: exactly zero
+    weight. Returns ``[nh, T, hd]`` in q's dtype."""
+    nh, T, hd = q.shape
+    BS, MB = k_cache.shape[2], bt_row.shape[0]
+    out, lse = causal_attention_lse(q[None], k[None], v[None])
+    per = max(1, min(MB, _PREFIX_WALK // BS))      # blocks a step
+    width = per * BS
+    scale = jnp.float32(float(hd) ** -0.5)
+
+    def step(i, carry):
+        m, l, acc = carry
+        col = i * per + jnp.arange(per, dtype=jnp.int32)
+        rows = bt_row[jnp.minimum(col, MB - 1)]
+        with jax.named_scope("kv_gather"):
+            kb = k_cache[rows].transpose(1, 0, 2, 3).reshape(nh, width, hd)
+            vb = v_cache[rows].transpose(1, 0, 2, 3).reshape(nh, width, hd)
+        st = jnp.einsum("htd,hsd->hts", q, kb.astype(q.dtype),
+                        preferred_element_type=jnp.float32) * scale
+        kpos = i * width + jnp.arange(width, dtype=jnp.int32)
+        st = jnp.where(kpos < start, st, jnp.float32(_NEG))
+        m_new = jnp.maximum(m, jnp.max(st, axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(st - m_new[..., None])
+        l = alpha * l + jnp.sum(p, axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "hts,hsd->htd", p.astype(vb.dtype), vb,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    # the run's own part as the walk's first state: m = lse, l = 1
+    _, l, acc = jax.lax.fori_loop(
+        0, (start + width - 1) // width, step,
+        (lse[0, :, 0], jnp.ones((nh, T), jnp.float32),
+         out[0].astype(jnp.float32)))
+    return (acc / l[..., None]).astype(q.dtype)
+
+
 def cached_slot_block_attention(q, k_cache, v_cache, qpos):
     """Multi-query decode attention over a slot-contiguous static
     cache: the t-token generalization of cached_slot_attention, what

@@ -2170,6 +2170,7 @@ class ServingEngine:
             self._stamp_prefill(req, t_disp, bucket)
             M.requests_admitted += 1
             M.prefills += 1
+            M.record_prefill_arm(start)
             M.prefill_requests += 1
             M.record_prefill_group(1)
             M.record_prefix_reuse(start, tail, req.tenant_id)
@@ -2280,6 +2281,7 @@ class ServingEngine:
             if self._window is not None:
                 self._prefilled(plan.slot, start, clen, final)
             M.record_prefill_chunk(clen)
+            M.record_prefill_arm(start)
             self._stamp_prefill(req, t_disp, C)
             budget -= clen
             plan.advance()

@@ -205,6 +205,18 @@ class ServingMetrics:
         self._c_chunked_reqs = r.counter(
             "serving_chunked_requests_total",
             "requests whose prefill ran chunk-by-chunk")
+        # which arm of the paged prefill program a dispatch ran: the
+        # run over its own keys alone (start 0), or also the walk over
+        # the cached prefix below it (a radix hit, a later chunk)
+        self._c_prefill_arm = r.counter(
+            "serving_prefill_arm_dispatches_total",
+            "prefill and chunk dispatches by what they attended: "
+            "'own' keys only, or a cached 'prefix' as well",
+            labelnames=("arm",))
+        self._c_prefix_read = r.counter(
+            "serving_prefill_prefix_tokens_read_total",
+            "cached positions below their start that prefill and "
+            "chunk dispatches attended")
         self._g_policy = r.gauge(
             "serving_scheduler_policy",
             "active scheduling policy (the labeled policy reads 1)",
@@ -665,6 +677,16 @@ class ServingMetrics:
     def record_chunked_request(self):
         self._c_chunked_reqs.inc()
 
+    def record_prefill_arm(self, start):
+        """One prefill or chunk dispatch that stuck, by the cached
+        positions below its run (``start``)."""
+        self._c_prefill_arm.labels("prefix" if start else "own").inc()
+        if start:
+            self._c_prefix_read.inc(int(start))
+
+    def _prefill_arm(self, arm):
+        return int(self._c_prefill_arm.labels(arm).value)
+
     def scheduler_report(self):
         """The ``snapshot()["scheduler"]`` section: policy identity,
         chunking configuration, and the shed / deferred / chunk
@@ -947,6 +969,10 @@ class ServingMetrics:
             "slot_occupancy": round(self.slot_occupancy, 4),
             "prefills": self.prefills,
             "prefill_requests": self.prefill_requests,
+            # prefill + chunk dispatches by the program's arm
+            "prefills_without_prefix": self._prefill_arm("own"),
+            "prefills_with_prefix": self._prefill_arm("prefix"),
+            "prefill_prefix_tokens_read": int(self._c_prefix_read.value),
             "prefill_groups": {str(k): v for k, v in
                                sorted(self.prefill_group_hist.items())},
             "decode_steps": self.decode_steps,

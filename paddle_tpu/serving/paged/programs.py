@@ -1,29 +1,44 @@
 """AOT-compilable prefill/decode programs over the paged cache.
 
-Same decode math as ``GPTForCausalLM.generate()`` (both reuse
-``_decode_forward_builder``; greedy parity with ``generate()`` is by
-construction), with the cache addressed through the fixed-shape block
-table instead of a slot-contiguous region:
+Same decode math as ``GPTForCausalLM.generate()`` (its ``forward_t``,
+from ``_decode_forward_builder``, is the parity oracle the tests hold
+both programs to), with the cache addressed through the fixed-shape
+block table instead of a slot-contiguous region:
 
   ``paged_prefill(params, tokens [1, B], tail_len, start, slot, final,
                   bt_row [MB], toks [S], pos [S], kc, vc[, samp...])``
       One request's UNCACHED TAIL (or, under chunked prefill, one
-      CHUNK of it) prefills in one dispatch: the slot's MB blocks
-      gather into a position-ordered contiguous view
-      ``[L, 1, nh, MB*BS, hd]`` (view index == cache position, so the
-      shared forward_t attends over the cached prefix below ``start``
-      exactly as if this slot had prefilled it itself), the tail's K/V
-      writes land at ``start..start+B``, and the view scatters back
-      block-by-block. ``start``, ``tail_len`` and ``final`` are TRACED
-      scalars: every (prefix length, tail length, chunk index) triple
-      reuses the one compiled program per tail bucket B — prefix AND
-      chunk variety cost zero compiles. Only a ``final != 0`` dispatch
-      emits the first token and sets ``pos[slot] = start + tail_len``;
-      interior chunk dispatches PARK the slot at the row's last
-      addressable position instead (``MB*BS - 1`` — trash-backed or
-      legitimately overwritten before its length mask exposes it), so
-      the decode steps interleaving between chunks never write inside
-      prompt rows earlier chunks filled.
+      CHUNK of it) prefills in one dispatch, and its work follows the
+      run, never the slot's capacity (ISSUE 38). A layer computes the
+      bucket's q, k, v; attends the run over its OWN keys causally
+      (``ops.attention.paged_prefill_attention``: the flash forward
+      kernel where it takes the bucket, no score tensor in HBM) and,
+      only where ``start > 0`` (a radix prefix hit, a later chunk),
+      walks the cached blocks below ``start`` through ``bt_row`` and
+      merges the two by their softmax statistics; then writes the
+      run's k and v into the ``B / BS + 1`` table entries from
+      ``start // BS`` on by whole-block read-modify-write into the
+      carried flat pool (as decode does; one entry more than the run
+      holds because an end-aligned final chunk starts inside a block).
+      Only the run's REAL positions ``start .. start + tail_len``
+      change: rows below ``start`` in the first block, the bucket's
+      padding rows and every block the row's padding entries name
+      (trash, as a column past the row is) are written back as they
+      were read, so a shared (pinned) prefix block, which is always
+      whole and below ``start``, is never touched. The head runs over
+      the ONE row whose logits are used. ``start``, ``tail_len`` and
+      ``final`` are TRACED scalars: every (prefix length, tail length,
+      chunk index) triple reuses the one compiled program per tail
+      bucket B — prefix AND chunk variety cost zero compiles. Only a
+      ``final != 0`` dispatch emits the first token and sets
+      ``pos[slot] = start + tail_len``; interior chunk dispatches PARK
+      the slot at the row's last addressable position instead
+      (``MB*BS - 1`` — trash-backed or legitimately overwritten before
+      its length mask exposes it), so the decode steps interleaving
+      between chunks never write inside prompt rows earlier chunks
+      filled. ``tests/test_chip_compile.py`` holds the compiled
+      program to this at the 1.3B cell's four buckets (aliased pool,
+      no view of a slot at capacity, no scores over it).
 
   ``paged_decode(params, toks [S], pos [S], tables [S, MB], kc, vc
                  [, samp...])``
@@ -79,7 +94,12 @@ path, the path of shapes the kernel refuses, and the parity oracle). A
 trace-time branch, so the program key, its signature and the
 zero-steady-state-compile contract are the same on either. The engine
 chooses once at build time from ``kernel_viable``; nobody sets it by
-hand, and there is no second, quiet fallback here.
+hand, and there is no second, quiet fallback here. The prefill
+program's kernel is chosen the same way, once, when a bucket's program
+is traced, from the bucket's shape and the backend
+(``ops.attention.causal_attention_lse``: the flash forward kernel from
+256 positions on in steps of 128, ``jnp`` for the bucket of 128, odd
+chunk widths and the CPU).
 """
 
 
@@ -101,40 +121,91 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
     nh = cfg.num_heads
     hd = cfg.hidden_size // nh
     hidden = cfg.hidden_size
-    ln, forward_t = _decode_forward_builder(nh, hd, hidden)
+    ln, _ = _decode_forward_builder(nh, hd, hidden)
     head = build_sampling_head(cfg.vocab_size) if sampling else None
     L = cfg.num_layers
     BS = int(block_size)
     MB = int(blocks_per_slot)
-    C = MB * BS   # one slot's gathered contiguous context length
+    C = MB * BS   # positions a slot's table row addresses
 
-    def gather_slot(cache, bt_row):
-        # [L, NB, nh, BS, hd] + row [MB] -> [L, 1, nh, MB*BS, hd],
-        # position-ordered: view index bi*BS+off IS the cache position
-        g = jnp.take(cache, bt_row, axis=1)          # [L, MB, nh, BS, hd]
-        g = g.transpose(0, 2, 1, 3, 4).reshape(L, nh, C, hd)
-        return g[:, None]
-
-    def scatter_slot(cache, bt_row, view):
-        # inverse of gather_slot; pad entries of bt_row all point at
-        # the trash block (duplicate scatter indices land in garbage)
-        blocks = view[:, 0].reshape(L, nh, MB, BS, hd) \
-            .transpose(0, 2, 1, 3, 4)                # [L, MB, nh, BS, hd]
-        return cache.at[:, bt_row].set(blocks)
+    def mlp(x, p):
+        with jax.named_scope("mlp"):
+            h2 = ln(x, p["ln2_w"], p["ln2_b"])
+            m = jax.nn.gelu(h2 @ p["fc1_w"] + p["fc1_b"], approximate=True)
+            return x + (m @ p["fc2_w"] + p["fc2_b"])
 
     def _prefill_core(params, tokens, tail_len, start, slot, final,
                       bt_row, toks, pos, kc, vc, samp):
         # tokens [1, B] right-padded tail; start = cached prefix length
-        with jax.named_scope("kv_gather"):
-            kctx = gather_slot(kc, bt_row)
-            vctx = gather_slot(vc, bt_row)
-        logits, kctx, vctx = forward_t(params, tokens, start, kctx,
-                                       vctx)
-        with jax.named_scope("kv_write"):
-            kc = scatter_slot(kc, bt_row, kctx)
-            vc = scatter_slot(vc, bt_row, vctx)
+        B = tokens.shape[1]
+        NB = kc.shape[1]
+        with jax.named_scope("embed"):
+            at = start + jnp.arange(B, dtype=jnp.int32)
+            x = params["wemb"][tokens[0]] + params["pemb"][
+                jnp.minimum(at, params["pemb"].shape[0] - 1)]   # [B, h]
+        # where the run's keys and values go: the table entries from
+        # start // BS on, one more than the run's own blocks because a
+        # chunk may start inside a block. A column past the row goes to
+        # the trash block like the row's own padding
+        nW = (B + 2 * BS - 2) // BS
+        off = start % jnp.int32(BS)
+        wcol = start // jnp.int32(BS) + jnp.arange(nW, dtype=jnp.int32)
+        wblk = jnp.where(wcol < MB, bt_row[jnp.minimum(wcol, MB - 1)],
+                         jnp.int32(TRASH_BLOCK))
+        # of those blocks' rows only the run's REAL positions change:
+        # what lies below start (another chunk's, never a shared block:
+        # a prefix is whole blocks) and the bucket's padding keep what
+        # the pool holds, so trash is written back as it was read
+        wrow = jnp.arange(nW * BS, dtype=jnp.int32)
+        mine = ((wrow >= off) & (wrow < off + tail_len)).reshape(
+            nW, 1, BS, 1)
+
+        def blocks(new):
+            # [nh, B, hd] -> [nW, nh, BS, hd], row t at flat row off + t
+            buf = lax.dynamic_update_slice(
+                jnp.zeros((nW * BS, nh, hd), new.dtype),
+                new.transpose(1, 0, 2), (off, jnp.int32(0), jnp.int32(0)))
+            return buf.reshape(nW, BS, nh, hd).transpose(0, 2, 1, 3)
+
+        # the pool rides the layer loop as CARRIED state, flat, as in
+        # the decode program: layer l's block b is row l*NB + b
+        kf = kc.reshape((L * NB,) + kc.shape[2:])
+        vf = vc.reshape((L * NB,) + vc.shape[2:])
+
+        def body(carry, inp):
+            x, kf, vf = carry
+            p, layer = inp
+            base = layer * jnp.int32(NB)
+            with jax.named_scope("attn"):
+                h_ = ln(x, p["ln1_w"], p["ln1_b"])
+                qkv = (h_ @ p["qkv_w"] + p["qkv_b"]).reshape(
+                    B, 3, nh, hd).transpose(1, 2, 0, 3)   # [3, nh, B, hd]
+                # attention sees keys and values as the cache holds them
+                q, k, v = qkv[0], qkv[1].astype(kf.dtype), \
+                    qkv[2].astype(vf.dtype)
+                o = attn_ops.paged_prefill_attention(
+                    q, k, v, kf, vf, base + bt_row, start)
+                with jax.named_scope("kv_write"):
+                    rows = base + wblk
+                    kf = kf.at[rows].set(jnp.where(
+                        mine, blocks(k), kf[rows]))
+                    vf = vf.at[rows].set(jnp.where(
+                        mine, blocks(v), vf[rows]))
+                o = o.transpose(1, 0, 2).reshape(B, hidden)
+                x = x + (o @ p["out_w"] + p["out_b"])
+            return (mlp(x, p), kf, vf), None
+
+        (x, kf, vf), _ = lax.scan(
+            body, (x, kf, vf),
+            (params["stacked"], jnp.arange(L, dtype=jnp.int32)))
+        kc, vc = kf.reshape(kc.shape), vf.reshape(vc.shape)
+        with jax.named_scope("lm_head"):
+            # ONE row through the head, as a [1, h] matmul (as a vector
+            # the product is elementwise and the head is upcast whole)
+            row = lax.dynamic_slice_in_dim(x, tail_len - 1, 1, axis=0)
+            last = (ln(row, params["lnf_w"], params["lnf_b"])
+                    @ params["head"])[0]                       # [vocab]
         with jax.named_scope("sample"):
-            last = jnp.take(logits[0], tail_len - 1, axis=0)   # [vocab]
             if samp is None:
                 first = jnp.argmax(last, -1).astype(jnp.int32)
             else:
@@ -232,11 +303,7 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
                         q, kf, vf, ltab, lengths)
                 o = o.reshape(S, hidden)                  # concat heads
                 x = x + (o @ p["out_w"] + p["out_b"])
-            with jax.named_scope("mlp"):
-                h2 = ln(x, p["ln2_w"], p["ln2_b"])
-                m = jax.nn.gelu(h2 @ p["fc1_w"] + p["fc1_b"],
-                                approximate=True)
-                return (x + (m @ p["fc2_w"] + p["fc2_b"]), kf, vf), None
+            return (mlp(x, p), kf, vf), None
 
         (x, kf, vf), _ = lax.scan(
             body, (x, kf, vf),

@@ -443,11 +443,12 @@ class GPTForCausalLM(nn.Layer):
                                 attn_kernel=False):
         """The continuous-batching engine's prefill and decode
         programs over the block-granular KV pool (serving.paged): the
-        decode math of generate() via the shared
-        _decode_forward_builder, cache addressed through a fixed-shape
-        block table so shared-prefix blocks are reused instead of
-        re-prefilled. Both thread the engine's rolling device state
-        (toks/pos [S]) through, so consecutive steps chain on device —
+        decode math of generate() (``forward_t`` of
+        _decode_forward_builder is their parity oracle), cache
+        addressed through a fixed-shape block table so shared-prefix
+        blocks are reused instead of re-prefilled. Both thread the
+        engine's rolling device state (toks/pos [S]) through, so
+        consecutive steps chain on device —
 
           paged_prefill(params, tokens [1, B], tail_len, start, slot,
                         final, bt_row [MB], toks [S], pos [S], kc, vc)

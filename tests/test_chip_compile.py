@@ -202,18 +202,13 @@ def test_paged_decode_compiles(one_chip, dtype, mosaic_backend):
     _compile(paged_attention.paged_decode_attention, q, kv, kv, bt, ln)
 
 
-def _paged_decode_program(one_chip, sampling, attn_kernel, slots=4):
-    """(compiled paged_decode, pool shape), jitted with the engine's
-    donation (pos, kc, vc), at sizes where the pool dominates the
-    program: 8 layers of [16 heads, 16 tokens, 128] bf16 blocks, 64 a
-    slot plus the trash block (135 MB for K at 4 slots, as much for V),
-    narrow MLP and vocab. ``attn_kernel`` as the engine would choose it
-    (``kernel_viable``) or refused."""
-    from paddle_tpu.serving.paged.programs import build_paged_fns
+def _paged_gpt(one_chip, slots, L=8, ffn=512, vocab=512):
+    """(config, parameter shapes, pool shape, shape maker) of a GPT of
+    16 heads x 128 over blocks of 16, 64 a slot plus the trash block."""
     from paddle_tpu.text.models import TransformerLMConfig
-    L, S, BS, MB, nh, hd = (8, slots, PAGED_BLOCK, PAGED_PER_SLOT,
-                            PAGED_HEADS, PAGED_HEAD_DIM)
-    NB, hidden, ffn, vocab = S * MB + 1, nh * hd, 512, 512
+    S, BS, MB, nh, hd = (slots, PAGED_BLOCK, PAGED_PER_SLOT, PAGED_HEADS,
+                         PAGED_HEAD_DIM)
+    NB, hidden = S * MB + 1, nh * hd
     cfg = TransformerLMConfig(vocab_size=vocab, hidden_size=hidden,
                               num_layers=L, num_heads=nh,
                               intermediate_size=ffn, max_seq_len=MB * BS,
@@ -231,7 +226,19 @@ def _paged_decode_program(one_chip, sampling, attn_kernel, slots=4):
               "wemb": sds((vocab, hidden)), "pemb": sds((MB * BS, hidden)),
               "lnf_w": sds((hidden,)), "lnf_b": sds((hidden,)),
               "head": sds((hidden, vocab))}
-    pool = (L, NB, nh, BS, hd)
+    return cfg, params, (L, NB, nh, BS, hd), sds
+
+
+def _paged_decode_program(one_chip, sampling, attn_kernel, slots=4):
+    """(compiled paged_decode, pool shape), jitted with the engine's
+    donation (pos, kc, vc), at sizes where the pool dominates the
+    program: 8 layers of [16 heads, 16 tokens, 128] bf16 blocks, 64 a
+    slot plus the trash block (135 MB for K at 4 slots, as much for V),
+    narrow MLP and vocab. ``attn_kernel`` as the engine would choose it
+    (``kernel_viable``) or refused."""
+    from paddle_tpu.serving.paged.programs import build_paged_fns
+    cfg, params, pool, sds = _paged_gpt(one_chip, slots)
+    S, MB, NB, BS = slots, PAGED_PER_SLOT, pool[1], PAGED_BLOCK
     i32 = jnp.int32
     args = [params, sds((S,), i32), sds((S,), i32), sds((S, MB), i32),
             sds(pool), sds(pool)]
@@ -255,6 +262,20 @@ def _pool_shaped(compiled, shapes, dtype="bf16"):
                                     compiled.as_text().splitlines()) if m]
 
 
+def _assert_pool_stays_put(compiled, pool):
+    """No copy, dynamic-slice or dynamic-update-slice is left in the
+    optimized program with the pool's shape or one layer's."""
+    L, NB, nh, BS, hd = pool
+    found = _pool_shaped(compiled, [
+        f"{lead},{nh},{BS},{hd}" for lead in (
+            f"{L},{NB}", f"1,{NB}", f"{L * NB}", f"{NB}")])
+    assert found   # the pool is in the program under these shapes
+    moving = ("copy", "dynamic-slice", "dynamic-update-slice")
+    bad = [(name, op) for name, op in found
+           if op in moving or any(w in name for w in moving)]
+    assert not bad, bad
+
+
 @pytest.mark.parametrize("sampling", [False, True],
                          ids=["greedy", "sampling"])
 def test_paged_decode_program_updates_pool_in_place(one_chip, sampling):
@@ -270,14 +291,7 @@ def test_paged_decode_program_updates_pool_in_place(one_chip, sampling):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * half
     assert mem.temp_size_in_bytes < half, (mem.temp_size_in_bytes, half)
-    found = _pool_shaped(compiled, [
-        f"{lead},{nh},{BS},{hd}" for lead in (
-            f"{L},{NB}", f"1,{NB}", f"{L * NB}", f"{NB}")])
-    assert found   # the pool is in the program under these shapes
-    moving = ("copy", "dynamic-slice", "dynamic-update-slice")
-    bad = [(name, op) for name, op in found
-           if op in moving or any(w in name for w in moving)]
-    assert not bad, bad
+    _assert_pool_stays_put(compiled, (L, NB, nh, BS, hd))
 
 
 def test_default_gpt_decode_program_reads_live_blocks_in_place(
@@ -304,6 +318,47 @@ def test_default_gpt_decode_program_reads_live_blocks_in_place(
                 f"{24 * PAGED_PER_SLOT},{nh},{BS},{hd}"]
     left = _pool_shaped(compiled, gathered, dtype=r"\w+")
     assert not left, left
+
+
+@pytest.mark.parametrize("bucket", [128, 256, 512, 1024])
+def test_paged_prefill_program_updates_pool_in_place(one_chip, bucket,
+                                                     mosaic_backend):
+    """The prefill program of the 1.3B cell (24 layers of 2048 / 8192,
+    vocabulary 50304, 24 slots, 16 heads x 128, blocks of 16, 64 a
+    slot, bf16) at each of its buckets, jitted with the engine's
+    donation (pos, kc, vc): both pools aliased onto the results; no
+    copy, dynamic-slice or dynamic-update-slice of the pool's shape or
+    a layer's; nothing left of a slot's view at capacity
+    (``[24,1,16,1024,128]``, 0.40 GB of temporaries until PR 38) nor of
+    scores over it (``[16,bucket,1024]``); temporaries under 16 MB; the
+    flash kernel in it from 256 on, the bucket of 128 in plain XLA."""
+    from paddle_tpu.serving.paged.programs import build_paged_fns
+    S, MB, BS = 24, PAGED_PER_SLOT, PAGED_BLOCK
+    cfg, params, pool, sds = _paged_gpt(one_chip, S, L=24, ffn=8192,
+                                        vocab=50304)
+    L, NB, nh, _, hd = pool
+    prefill, _ = build_paged_fns(cfg, S, BS, NB, MB, attn_kernel=True)
+    i32 = jnp.int32
+    compiled = jax.jit(prefill, donate_argnums=(8, 9, 10)).lower(
+        params, sds((1, bucket), i32), sds((), i32), sds((), i32),
+        sds((), i32), sds((), i32), sds((MB,), i32), sds((S,), i32),
+        sds((S,), i32), sds(pool), sds(pool)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * 2 * L * NB * nh * BS * hd
+    assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
+    _assert_pool_stays_put(compiled, pool)
+    C = MB * BS
+    view = [f"{L},1,{nh},{C},{hd}", f"{L},{nh},{C},{hd}"]
+    # (at the bucket of 1024 a layer's view would look like q, and the
+    # scores over it like the run's own square, which the kernel keeps)
+    if bucket < C:
+        view += [f"{lead}{nh},{C},{hd}" for lead in ("1,", "")]
+        view += [f"{lead}{nh},{bucket},{C}" for lead in ("1,", "")]
+    left = _pool_shaped(compiled, view, dtype=r"\w+")
+    assert not left, left
+    text = compiled.as_text()
+    assert ("flash_fwd" in text) == (bucket >= 256)
+    assert ("tpu_custom_call" in text) == (bucket >= 256)
 
 
 @pytest.mark.parametrize("vocab,kernel", [(50432, True), (VOCAB, False)])

@@ -438,6 +438,8 @@ def test_watch_jax_lowering_records_generic_compiles():
 _SNAPSHOT_KEYS = {
     "tokens_generated", "tokens_per_sec", "ttft_avg_ms", "queue_depth",
     "slot_occupancy", "prefills", "prefill_requests", "prefill_groups",
+    "prefills_without_prefix", "prefills_with_prefix",
+    "prefill_prefix_tokens_read",
     "decode_steps", "speculative_masked", "kv_donation", "compiles",
     "requests_admitted", "requests_completed", "dispatch_s", "sync_s",
     "span_s", "latency_percentiles", "slo", "prefix_cache",

@@ -375,3 +375,141 @@ def test_paged_decode_writes_only_its_rows_in_every_layer(trash_writers):
     np.testing.assert_array_equal(np.asarray(nxt)[live],
                                   np.asarray(r_nxt)[live])
     np.testing.assert_array_equal(np.asarray(pos1), np.asarray(r_pos1))
+
+
+# ------------------------------------------------ the prefill program
+# paged_prefill against forward_t over a contiguous cache (ISSUE 38):
+# 36 blocks of 16 a slot; buckets 32 (the jnp form), 128 and 256 (the
+# flash kernel, interpreted); the cached prefix a radix hit of 3 blocks,
+# or the first of two chunks: the second END-ALIGNED where the prompt
+# is no whole number of chunks (its start falls inside a block and its
+# first 11 rows recompute the first chunk's last), else tiled and short
+_PF_BS, _PF_MB, _PF_NB = 16, 36, 48
+_PF_ROW = [int(b) for b in
+           np.random.RandomState(38).permutation(np.arange(1, 48))[:36]]
+
+
+def _prefill_model(dtype):
+    paddle.seed(38)
+    cfg = TransformerLMConfig(vocab_size=211, hidden_size=128,
+                              num_layers=3, num_heads=2,
+                              max_seq_len=_PF_MB * _PF_BS, dropout=0.0)
+    m = GPTForCausalLM(cfg)
+    m.eval()
+    import jax
+    import jax.numpy as jnp
+    params = jax.tree_util.tree_map(
+        lambda a: jnp.asarray(a, dtype), m.export_decode_params())
+    return cfg, params
+
+
+def _to_view(pool, row):
+    """[L, NB, nh, BS, hd] through a table row -> [L, nh, C, hd]."""
+    g = np.asarray(pool)[:, row]                 # [L, MB, nh, BS, hd]
+    L, MB, nh, BS, hd = g.shape
+    return g.transpose(0, 2, 1, 3, 4).reshape(L, nh, MB * BS, hd)
+
+
+@pytest.fixture
+def flash_interpreted():
+    from paddle_tpu.ops import attention
+    attention._FORCE_INTERPRET[0] = True
+    yield
+    attention._FORCE_INTERPRET[0] = False
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("full", [True, False], ids=["full", "padded"])
+@pytest.mark.parametrize("prefix", ["none", "radix", "chunked"])
+@pytest.mark.parametrize("bucket", [32, 128, 256])
+def test_paged_prefill_matches_forward_t(bucket, prefix, full, dtype,
+                                         flash_interpreted):
+    """Every cache position the run owns, in every layer, is what
+    ``forward_t`` writes into a contiguous cache, and the token it
+    emits is one whose reference logit is the reference's best (to the
+    dtype's rounding); every other element of the pool is bit for bit
+    what it was: the shared prefix blocks, the slot's blocks past the
+    run, the bucket's padding rows, other slots' blocks and the trash
+    block that the row's padding entries name."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving.paged.pool import TRASH_BLOCK as TR
+    from paddle_tpu.serving.paged.programs import build_paged_fns
+    from paddle_tpu.text.models import _decode_forward_builder
+
+    cfg, params = _prefill_model(dtype)
+    L, nh, BS, MB, NB = cfg.num_layers, cfg.num_heads, _PF_BS, _PF_MB, _PF_NB
+    hd, C = cfg.hidden_size // nh, MB * BS
+    _, forward_t = _decode_forward_builder(nh, hd, cfg.hidden_size)
+    prefill, _ = build_paged_fns(cfg, 4, BS, NB, MB)
+    prefill = jax.jit(prefill)
+    forward_t = jax.jit(forward_t)
+    from paddle_tpu.ops import attention
+    assert attention._use_pallas(
+        jnp.zeros((1, nh, bucket, hd), dtype)) == (bucket >= 128)
+    tail = bucket if full else bucket - 11
+    # (start, length, final) of each dispatch; the LAST one is judged
+    runs = {"none": [(0, tail, 1)],
+            "radix": [(3 * BS, tail, 1)],
+            "chunked": [(0, bucket, 0), (bucket - 11, bucket, 1) if full
+                        else (bucket, tail, 1)]}[prefix]
+    n = runs[-1][0] + runs[-1][1]
+    assert n <= C
+    rs = np.random.RandomState(bucket + len(prefix) + full)
+    ids = rs.randint(1, cfg.vocab_size, n).astype(np.int32)
+    # the slot's row: blocks for the prompt and 5 tokens more, then trash
+    held = -(-(n + 5) // BS)
+    row = np.asarray(_PF_ROW[:held] + [TR] * (MB - held), np.int32)
+    kc = rs.randn(L, NB, nh, BS, hd).astype(np.float32)
+    vc = rs.randn(L, NB, nh, BS, hd).astype(np.float32)
+    kc, vc = jnp.asarray(kc, dtype), jnp.asarray(vc, dtype)
+    # the reference's contiguous cache starts as the slot's view of the
+    # pool, so that rows nobody writes compare equal as well
+    rk = jnp.asarray(_to_view(kc.astype(jnp.float32), row), dtype)[:, None]
+    rv = jnp.asarray(_to_view(vc.astype(jnp.float32), row), dtype)[:, None]
+    if prefix == "radix":
+        # the shared prefix: computed by the reference, put into the
+        # pool's blocks as another request's prefill left them
+        _, rk, rv = forward_t(params, jnp.asarray(ids[None, :3 * BS]),
+                              jnp.int32(0), rk, rv)
+
+        def shared(cache, ref):
+            cache = np.array(cache.astype(jnp.float32))
+            cache[:, row[:3]] = np.asarray(ref.astype(jnp.float32))[
+                :, 0, :, :3 * BS].reshape(L, nh, 3, BS, hd).transpose(
+                0, 2, 1, 3, 4)
+            return jnp.asarray(cache, dtype)
+        kc, vc = shared(kc, rk), shared(vc, rv)
+    toks, pos = jnp.zeros(4, jnp.int32), jnp.zeros(4, jnp.int32)
+    for start, length, final in runs:
+        tokens = np.zeros((1, bucket), np.int32)
+        tokens[0, :length] = ids[start:start + length]
+        before = (np.asarray(kc.astype(jnp.float32)),
+                  np.asarray(vc.astype(jnp.float32)))
+        first, toks, pos, kc, vc = prefill(
+            params, jnp.asarray(tokens), jnp.int32(length),
+            jnp.int32(start), jnp.int32(2), jnp.int32(final),
+            jnp.asarray(row), toks, pos, kc, vc)
+        logits, rk, rv = forward_t(params, jnp.asarray(tokens),
+                                   jnp.int32(start), rk, rv)
+    tol = 2e-5 if dtype == "float32" else 4e-2
+    owned = np.zeros(C, bool)
+    owned[start:start + length] = True
+    for new, old, ref in ((kc, before[0], rk), (vc, before[1], rv)):
+        new = np.asarray(new.astype(jnp.float32))
+        ref = np.asarray(ref.astype(jnp.float32))[:, 0]
+        np.testing.assert_allclose(_to_view(new, row)[:, :, owned],
+                                   ref[:, :, owned], rtol=tol, atol=tol)
+        # nothing else moved: compare the pool with the run's own
+        # positions put back as they were
+        back = _to_view(new, row)
+        back[:, :, owned] = _to_view(old, row)[:, :, owned]
+        restored = new.copy()
+        restored[:, row[:held]] = back.reshape(
+            L, nh, MB, BS, hd).transpose(0, 2, 1, 3, 4)[:, :held]
+        np.testing.assert_array_equal(restored, old)
+    last = np.asarray(logits.astype(jnp.float32))[0, length - 1]
+    assert last.max() - last[int(first[0])] <= tol
+    assert int(toks[2]) == int(first[0])
+    assert int(pos[2]) == n

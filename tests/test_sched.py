@@ -599,3 +599,34 @@ def test_debug_state_carries_scheduler_section():
     assert sched["prefill_chunk"] == 8
     assert "chunked_inflight" in sched
     eng.run()
+
+
+def test_prefill_arm_counters_follow_the_dispatches():
+    """The paged prefill program's two arms, counted a dispatch: an
+    unshared prompt attends its own keys only; a radix hit also reads
+    its cached prefix; a chunked prompt's first chunk is the first arm,
+    every later one the second, reading what lies below its start (the
+    end-aligned final chunk included)."""
+    m = _model()
+    eng = ServingEngine(m, num_slots=2, bucket_min=8, block_size=4,
+                        prefill_chunk=16)
+    rs = np.random.RandomState(38)
+    stem = rs.randint(0, 97, (12,)).astype(np.int64)
+    unshared = np.concatenate([stem, rs.randint(0, 97, (3,))])   # 15
+    hit = np.concatenate([stem, rs.randint(0, 97, (5,))])        # 12 + 5
+    chunked = rs.randint(0, 97, (41,)).astype(np.int64)  # 0, 16, 25
+    reqs = []
+    for p in (unshared, hit, chunked):
+        reqs.append(eng.add_request(p, max_new_tokens=3))
+        eng.run()
+    for r, p in zip(reqs, (unshared, hit, chunked)):
+        np.testing.assert_array_equal(r.output_ids, _ref(m, p, 3))
+    snap = eng.metrics.snapshot()
+    assert snap["prefills"] == 2
+    assert snap["scheduler"]["prefill_chunks"] == 3
+    assert snap["prefills_without_prefix"] == 2      # unshared, chunk 0
+    assert snap["prefills_with_prefix"] == 3         # hit, chunks 1 and 2
+    assert snap["prefill_prefix_tokens_read"] == 12 + 16 + 25
+    text = eng.metrics.prometheus_text()
+    assert 'serving_prefill_arm_dispatches_total{arm="prefix"} 3' in text
+    assert 'serving_prefill_prefix_tokens_read_total 53' in text
