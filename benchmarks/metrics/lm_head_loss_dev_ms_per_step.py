@@ -1,0 +1,8 @@
+"""Device time of the head and the loss (scopes ``lm_head``, ``loss``),
+forward and ``bwd/`` alike, per traced train step: op self times joined
+to the program's table of scopes (``_scopes.py``)."""
+from benchmarks.metrics import _scopes
+
+
+def read(ctx):
+    return _scopes.train_group_ms(ctx, "lm_head_loss")

@@ -1,0 +1,8 @@
+"""Device time of the optimizer (scope ``optimizer/step``),
+forward and ``bwd/`` alike, per traced train step: op self times joined
+to the program's table of scopes (``_scopes.py``)."""
+from benchmarks.metrics import _scopes
+
+
+def read(ctx):
+    return _scopes.train_group_ms(ctx, "optimizer")

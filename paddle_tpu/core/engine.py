@@ -19,13 +19,15 @@ import jax
 import jax.numpy as jnp
 
 from . import lazy as lazy_mod
+from . import trace as trace_mod
 
 _ones_cache = {}  # backward seed cotangents, keyed by (shape, dtype)
 
 
 class GradNode:
     __slots__ = ("op", "key", "closure", "arrays", "input_tensors",
-                 "out_avals", "out_refs", "pending", "released", "multi_out")
+                 "out_avals", "out_refs", "pending", "released", "multi_out",
+                 "scope")
 
     def __init__(self, op, key, closure, arrays, input_tensors, out_avals):
         self.op = op
@@ -40,6 +42,14 @@ class GradNode:
         self.pending = None  # cotangent slots during a backward run
         self.released = False
         self.multi_out = False
+        # the device_scope names open where the node was recorded, kept
+        # only while a step is staged (mode "jit"): its backward then
+        # runs under "bwd" + these, so a backward op's op_name reads
+        # .../bwd/block/mlp/... Eager nodes keep None (their backward
+        # is a cached jit shared across call sites).
+        ctx = trace_mod.current_trace()
+        self.scope = _current_scopes() \
+            if ctx is not None and ctx.mode == "jit" else None
 
     def parents(self):
         seen = []
@@ -49,6 +59,31 @@ class GradNode:
                 if node is not self:
                     seen.append(node)
         return seen
+
+
+def _current_scopes():
+    from ..profiler import current_scopes
+    return current_scopes()
+
+
+class _backward_scope:
+    """``jax.named_scope("bwd")`` + the forward's scopes (``scope``, a
+    node's tuple) around one node's backward: its vjp and the
+    accumulation of what it gives."""
+
+    __slots__ = ("_stack",)
+
+    def __init__(self, scope):
+        self._stack = [jax.named_scope(n) for n in ("bwd",) + scope]
+
+    def __enter__(self):
+        for ns in self._stack:
+            ns.__enter__()
+
+    def __exit__(self, *exc):
+        for ns in reversed(self._stack):
+            ns.__exit__(*exc)
+        return False
 
 
 def register_tensor_hook(tensor, hook):
@@ -137,6 +172,41 @@ def _accumulate_into_leaf(tensor, grad_array, create_graph=False):
     else:
         # keep the same Tensor object so traced steps functionalize correctly
         tensor._grad.value = lazy_mod.add(tensor._grad.value, grad_array)
+
+
+def _node_backward(node, cts, create_graph):
+    """One node's vjp on its cotangents, and what it gives handed to
+    the node's inputs."""
+    if create_graph:
+        in_grads = _vjp_apply(node, cts)
+    else:
+        in_grads = None
+        # only standard deferrable ops: custom op stand-ins (e.g.
+        # _SparseLookupOp) override vjp_fn with semantics autodiff
+        # of the closure would not reproduce (IndexedSlices grads)
+        if node.closure is not None and getattr(node.op, "defer", False) \
+                and lazy_mod.enabled():
+            # lazy micro-tracing: the vjp becomes a deferred node so
+            # the whole backward fuses into the step's micro-graph
+            try:
+                in_grads = lazy_mod.dispatch_vjp(node, cts)
+            except lazy_mod.Fallback:
+                in_grads = None
+        if in_grads is None:
+            if lazy_mod.ever_enabled():
+                cts_c = [
+                    _zero_ct(*node.out_avals[i]) if c is None
+                    else lazy_mod.concrete(c)
+                    for i, c in enumerate(cts)]
+            else:
+                cts_c = cts
+            ct_arg = tuple(cts_c) if node.multi_out else cts_c[0]
+            bwd = node.op.vjp_fn(node.key, node.closure)
+            arrays = node.arrays
+            if arrays is not None and lazy_mod.ever_enabled():
+                arrays = [lazy_mod.concrete(a) for a in arrays]
+            in_grads = bwd(arrays, ct_arg)
+    _distribute(node, in_grads, create_graph)
 
 
 def run_backward(loss, grad_tensor=None, retain_graph=False,
@@ -236,36 +306,11 @@ def run_backward(loss, grad_tensor=None, retain_graph=False,
             raise RuntimeError(
                 "trying to backward through a released graph; pass "
                 "retain_graph=True to backward()")
-        if create_graph:
-            in_grads = _vjp_apply(node, cts)
+        if node.scope is None:
+            _node_backward(node, cts, create_graph)
         else:
-            in_grads = None
-            # only standard deferrable ops: custom op stand-ins (e.g.
-            # _SparseLookupOp) override vjp_fn with semantics autodiff
-            # of the closure would not reproduce (IndexedSlices grads)
-            if node.closure is not None and getattr(node.op, "defer", False) \
-                    and lazy_mod.enabled():
-                # lazy micro-tracing: the vjp becomes a deferred node so
-                # the whole backward fuses into the step's micro-graph
-                try:
-                    in_grads = lazy_mod.dispatch_vjp(node, cts)
-                except lazy_mod.Fallback:
-                    in_grads = None
-            if in_grads is None:
-                if lazy_mod.ever_enabled():
-                    cts_c = [
-                        _zero_ct(*node.out_avals[i]) if c is None
-                        else lazy_mod.concrete(c)
-                        for i, c in enumerate(cts)]
-                else:
-                    cts_c = cts
-                ct_arg = tuple(cts_c) if node.multi_out else cts_c[0]
-                bwd = node.op.vjp_fn(node.key, node.closure)
-                arrays = node.arrays
-                if arrays is not None and lazy_mod.ever_enabled():
-                    arrays = [lazy_mod.concrete(a) for a in arrays]
-                in_grads = bwd(arrays, ct_arg)
-        _distribute(node, in_grads, create_graph)
+            with _backward_scope(node.scope):
+                _node_backward(node, cts, create_graph)
         if not retain_graph:
             node.released = True
             node.arrays = None

@@ -29,6 +29,7 @@ data-dependent branching inside a compiled step should use tensor ops
 (where/cond) — same constraint the reference's static graph has.
 """
 import functools
+import weakref
 
 import numpy as np
 
@@ -37,6 +38,7 @@ import jax
 from .. import profiler as _profiler
 from ..core import trace as trace_mod
 from ..core.tensor import Tensor
+from ..observability import watchdog as _watchdog
 
 
 def _flatten(obj, leaves):
@@ -72,6 +74,26 @@ def _rebuild(struct, leaf_iter):
     if kind == "D":
         return {k: _rebuild(s, leaf_iter) for k, s in struct[1]}
     return struct[1]
+
+
+def _abstract(a):
+    """What lowering needs to know of one argument, pinning nothing: a
+    ShapeDtypeStruct, with the sharding where the array is committed to
+    one (as jit itself resolves it, so that lowering on these finds
+    what the call on the arrays lowered)."""
+    sharding = a.sharding if getattr(a, "committed", False) else None
+    return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding,
+                                weak_type=getattr(a, "weak_type", False))
+
+
+def _text_producer(lowered):
+    """The compiled text of a ``Lowered``, when called. All the closure
+    pins is the lowering: no Tensor, no array, not the recorded
+    function (jax keeps the loaded executable with the lowering, so the
+    entry that owns the step has the record resolved when it goes)."""
+    def text():
+        return lowered.compile().as_text()
+    return text
 
 
 def captured_arrays(compiled):
@@ -239,6 +261,10 @@ class TracedFunction:
         c = entry["compiled"]
         mut_caps, ro_caps = captured_arrays(c)
         arg_arrays = [t.value for t in leaves]
+        # the first compiled call of a signature: its shapes, taken
+        # before the call donates what it mutates
+        sds = None if "program" in entry else jax.tree_util.tree_map(
+            _abstract, (arg_arrays, mut_caps, ro_caps))
         try:
             with _profiler.host_scope("jit/enqueue"):
                 out_arrays, mut_arrays, grad_arrays = c["jitted"](
@@ -257,12 +283,39 @@ class TracedFunction:
                 "paddle_tpu.analysis.birth_tracking() to attribute "
                 "the birth op/trace and escape site.\n\nOriginal "
                 f"error: {e}") from e
+        if sds is not None:
+            self._note_program(entry, c, sds)
         for t, v in zip(c["mutated"], mut_arrays):
             t._value = v
         for t, g in zip(c["grad_owners"], grad_arrays):
             t._grad = Tensor(g, stop_gradient=True)
         out_tensors = iter([Tensor(a) for a in out_arrays])
         return _rebuild(c["out_struct"], out_tensors)
+
+    def _note_program(self, entry, c, sds):
+        """After the first compiled call of a signature: hand the
+        compile watchdog's table of programs
+        (``watchdog.program_scopes``) a way to the text of the program
+        that call ran. ``c["jitted"].lower`` on the call's own shapes
+        finds what the call just traced and lowered (jax's caches: no
+        second trace), and ``compile()`` on it the executable the step
+        runs; ``as_text()`` and the parse wait until the table is asked
+        for, or until this entry's compiled function is dropped,
+        whichever is first, never in a step. The table holds the
+        ``Lowered`` until then: no Tensor of the model."""
+        name = getattr(self, "__name__", "fn")
+        try:
+            lowered = c["jitted"].lower(*sds)
+        except Exception:  # noqa: BLE001 - an observer never raises
+            entry["program"] = None
+            return
+        entry["program"] = _watchdog.note_program(
+            ("to_static", name), producer=_text_producer(lowered),
+            signature=_watchdog.abstract_signature(sds),
+            owner=_watchdog.new_owner())
+        finalizer = weakref.finalize(
+            c["fn"], _watchdog.resolve_program, entry["program"])
+        finalizer.atexit = False
 
     def concrete_program(self):
         return self._entries

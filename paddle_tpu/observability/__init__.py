@@ -86,6 +86,20 @@ whose counters sum and fixed-bucket histograms merge bucket-wise
 load_skew), and a FleetServer exposing ``/fleet/health`` /
 ``/fleet/state`` / ``/fleet/metrics`` — the surface the ROADMAP
 direction-#2 router consumes.
+
+PR 39 adds device time in the program's own layer names. The models name
+their parts with ``profiler.device_scope`` (a ``jax.named_scope`` whose
+stack the tape keeps, so a compiled step's backward ops read
+``bwd/block/mlp``), and ``watchdog.program_scopes()`` keeps, for every
+program the engine's AOT table or a ``to_static`` step built, which
+scope each instruction belongs to (text and parsed pairs only: no
+Tensor, no buffer, and no executable once its engine or step is gone).
+An operator gets device time by layer from a capture in three steps:
+``GET /debug/programs`` on the engine's metrics server shows each
+program's signature, cost, memory and instructions per scope; a cell
+run through ``benchmarks/tools/scope_report.py --workload <cell>`` keeps
+the trace with its table beside it; the same tool on those two files
+prints device self time by program and scope, no chip needed.
 """
 from .cache import (  # noqa: F401
     CACHE_KEYS, CacheObservatory, ReuseDistanceSampler,
@@ -126,5 +140,5 @@ from .tracing import (  # noqa: F401
 from .watchdog import (  # noqa: F401
     CompileAfterWarmupError, CompileWatchdog, abstract_signature,
     device_memory_stats, executable_cost, executable_memory,
-    watch_jax_lowering,
+    program_scopes, watch_jax_lowering,
 )

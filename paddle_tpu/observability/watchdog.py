@@ -32,7 +32,9 @@ serving engine and bench artifacts rely on.
 """
 import contextlib
 import hashlib
+import itertools
 import os
+import re
 import threading
 import traceback
 
@@ -134,6 +136,266 @@ def device_memory_stats(device=None):
     return out
 
 
+# ------------------------------------------------- the programs' table
+# What each compiled program's instructions belong to: the
+# ``op_name`` metadata (jit(...)/.../mla/out/dot_general: the
+# ``profiler.device_scope`` names open where the op was staged) of every
+# instruction the device runs as an op of its own, keyed by what a
+# profiler trace's op event can be matched on. Process-wide, filled
+# where programs are built (ServingEngine._compiled hands over the
+# executable's text; a to_static step hands over a producer of it, run
+# when the table is first asked for or when the step is dropped),
+# parsed on the first request, and readable after the engine or step
+# that built a program is gone: it holds text and parsed pairs, never
+# a Tensor or a buffer, and an executable no longer than its owner
+# does. The newest ``_PROGRAMS_MAX`` records are kept.
+_programs_lock = threading.Lock()    # the dict
+_resolve_lock = threading.RLock()    # one parse (or lowering) at a time
+_programs = {}   # table key -> record, see note_program
+_PROGRAMS_MAX = 256
+_owner_ids = itertools.count(1)
+
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?(%?[^\s=]+) = (.*?) ([a-z][a-z0-9-]*)\(")
+_LAYOUT = re.compile(r"\{[^{}]*\}")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_COMPUTATION = re.compile(r"^(ENTRY )?(%?[^\s(]+) \(.*\{\s*$")
+_MODULE = re.compile(r"^HloModule ([^\s,]+)")
+# computations an instruction runs as ops of their own (a fusion's
+# ``calls`` and a reducer's ``to_apply`` are inside ONE op)
+_CALLED = re.compile(
+    r"(?:body|condition|true_computation|false_computation)=(%?[\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_CALLS = re.compile(r"(?:calls|to_apply)=(%?[\w.\-]+)")
+# components of an op_name that are jax's own, not a scope's
+_TRANSFORMS = frozenset((
+    "jit", "pjit", "jvp", "transpose", "vmap", "pmap", "shard_map",
+    "remat", "checkpoint", "custom_jvp", "custom_vjp", "custom_vjp_call",
+    "custom_jvp_call", "while", "body", "cond", "closed_call",
+    "core_call"))
+_TOKEN = re.compile(r"[^/()]+\(?")
+# instructions that never run as an op of their own in a trace
+_NOT_AN_OP = frozenset(("parameter", "get-tuple-element", "tuple",
+                        "bitcast", "constant"))
+
+
+def instruction_key(text):
+    """``%name = shape opcode`` of an HLO instruction: of a line of
+    ``compiled.as_text()`` or of a trace's op event (whose name is the
+    whole instruction), layouts dropped, so that the two meet. None for
+    a line that is no instruction."""
+    m = _INSTRUCTION.match(text)
+    if m is None:
+        return None
+    name = m.group(1)
+    if not name.startswith("%"):
+        name = "%" + name
+    return f"{name} = {_LAYOUT.sub('', m.group(2))} {m.group(3)}"
+
+
+def scope_path(op_name):
+    """The scope names of an ``op_name`` as a tuple of components
+    (``.../bwd/block/mlp/transpose(jvp())/dot_general`` ->
+    ``("bwd", "block", "mlp")``): jax's own wrappers, a jit's function
+    name and the primitive at the end are left out."""
+    out, skip = [], False
+    tokens = _TOKEN.findall(op_name)
+    for i, tok in enumerate(tokens):
+        head = tok.endswith("(")
+        word = tok.rstrip("(")
+        if skip:            # the function name of a jit(...)
+            skip = False
+            if not head:
+                continue
+        if head:
+            skip = word in ("jit", "pjit")
+            if word in _TRANSFORMS:
+                continue
+        if word in _TRANSFORMS or i == len(tokens) - 1:
+            continue
+        out.append(word)
+    return tuple(out)
+
+
+def parse_program_text(text):
+    """``(module name, {instruction_key: op_name})`` of one compiled
+    program's HLO text: every instruction of the entry computation and
+    of the computations it runs as ops of their own (``while`` bodies
+    and conditions, branches, calls), ``""`` where an instruction has
+    no metadata. A fusion carries its root's ``op_name``."""
+    module, comps, entry, cur = None, {}, None, None
+    for line in text.splitlines():
+        if cur is None:
+            m = _COMPUTATION.match(line)
+            if m:
+                cur = m.group(2)
+                comps[cur] = []
+                if m.group(1):
+                    entry = cur
+            elif module is None:
+                mm = _MODULE.match(line)
+                if mm:
+                    module = mm.group(1)
+        elif line.startswith("}"):
+            cur = None
+        else:
+            comps[cur].append(line)
+    out, seen, todo = {}, set(), [entry] if entry else []
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            m = _INSTRUCTION.match(line)
+            if m is None:
+                continue
+            meta = _OP_NAME.search(line)
+            out[instruction_key(line)] = meta.group(1) if meta else ""
+            opcode = m.group(3)
+            if opcode == "fusion":
+                continue
+            todo.extend(_CALLED.findall(line))
+            for group in _BRANCHES.findall(line):
+                todo.extend(x.strip() for x in group.split(","))
+            if opcode in ("call", "async-start"):
+                todo.extend(_CALLS.findall(line))
+    return module, out
+
+
+def new_owner():
+    """A name no other builder of programs in this process has: an
+    engine's compile watchdog and a to_static step's entry each take
+    one, so two of them never share a record of the table."""
+    return next(_owner_ids)
+
+
+def note_program(key, text=None, producer=None, signature="", owner=None):
+    """Keep what the program compiled under ``key`` is made of: its
+    HLO ``text`` (``compiled.as_text()``), or a ``producer`` that gives
+    that text when the table is first asked for (and is dropped then).
+    ``owner`` (``new_owner()``) tells one builder's programs from
+    another's of the same key; a later program of the same owner and
+    key takes the earlier one's place. Returns the table's key."""
+    name = key if isinstance(key, str) else repr(key)
+    tkey = name if owner is None else f"{name}#{owner}"
+    rec = {"key": name, "owner": owner, "module": None,
+           "signature": signature, "text": text, "producer": producer,
+           "instructions": None, "error": None}
+    with _programs_lock:
+        _programs.pop(tkey, None)        # a replacement is the newest
+        _programs[tkey] = rec
+        for old in list(_programs)[:-_PROGRAMS_MAX]:
+            del _programs[old]
+    return tkey
+
+
+def _resolved(rec):
+    """Parse a record's text (produce it first where it is a
+    producer's); afterwards it holds the pairs alone."""
+    if rec["instructions"] is not None or rec["error"] is not None:
+        return rec
+    text, producer = rec["text"], rec["producer"]
+    if text is None and producer is None:
+        return rec           # being resolved further up this thread
+    rec["text"] = rec["producer"] = None
+    try:
+        if text is None:
+            text = producer()
+        rec["module"], rec["instructions"] = parse_program_text(text)
+    except Exception as e:  # noqa: BLE001 - an observer never raises
+        rec["instructions"] = {}
+        rec["error"] = f"{type(e).__name__}: {e}"[:400]
+    return rec
+
+
+def resolve_program(tkey):
+    """Produce and parse the record ``tkey`` now, where it still waits
+    for the first request: what a to_static step's entry does when it
+    is dropped, so that the record stops holding its producer (and
+    with it the step's executable)."""
+    with _programs_lock:
+        rec = _programs.get(tkey)
+    if rec is not None:
+        with _resolve_lock:
+            _resolved(rec)
+
+
+def program_scopes():
+    """``{table key: {"key": <program key>, "owner": ..., "module":
+    <HLO module name, the program's name in a trace>, "signature": ...,
+    "instructions": {instruction_key: op_name}}}`` for every program
+    this process has built through an engine's AOT table or a to_static
+    step (the table key is the program key, ``#owner`` appended). The
+    first call parses (and asks a to_static step's producer for its
+    text); a record that could not be read has no instructions and
+    says why under ``"error"``."""
+    with _programs_lock:
+        recs = list(_programs.items())
+    out = {}
+    for tkey, rec in recs:
+        # not under the dict's lock: a producer may take seconds, and
+        # an engine that compiles meanwhile must not wait
+        with _resolve_lock:
+            rec = _resolved(rec)
+        out[tkey] = {k: rec[k] for k in (
+            "key", "owner", "module", "signature", "instructions",
+            "error")}
+    return out
+
+
+def ambiguous_instructions(table):
+    """The instruction keys that two programs of ``table`` (a
+    ``program_scopes()``, or the part of it that ran) hold under
+    DIFFERENT scopes: a trace's op event of that key cannot be given to
+    either, so a reader counts it as unscoped."""
+    first, out = {}, set()
+    for rec in table.values():
+        for ikey, op_name in rec["instructions"].items():
+            path = scope_path(op_name)
+            if first.setdefault(ikey, path) != path:
+                out.add(ikey)
+    return out
+
+
+def programs_report(events=None, owner=None):
+    """The operator's view (``GET /debug/programs``): per program (of
+    the builder ``owner`` with its compile ``events``: an engine's own;
+    else all) its module name, signature, and the count of instructions
+    per scope (``"/"``-joined ``scope_path``; ``"(none)"`` for an
+    instruction with no scope; parameters, tuples, bitcasts and
+    constants, which never run as an op, left out), joined to the
+    watchdog's compile ``events`` of the same key (cost, memory). No
+    seconds: device time by scope needs a capture, see
+    ``benchmarks/tools/scope_report.py``.
+    """
+    table = program_scopes()
+    if events is not None:
+        table = {k: rec for k, rec in table.items()
+                 if rec["owner"] == owner}
+    amb = ambiguous_instructions(table)
+    by_key = {e["key"]: e for e in events or ()}   # the newest build
+    programs = {}
+    for tkey, rec in table.items():
+        counts = {}
+        for ikey, op_name in rec["instructions"].items():
+            if ikey.rsplit(" ", 1)[-1] in _NOT_AN_OP:
+                continue
+            name = "/".join(scope_path(op_name)) or "(none)"
+            if ikey in amb:
+                name = "(ambiguous)"
+            counts[name] = counts.get(name, 0) + 1
+        e = by_key.get(rec["key"], {})
+        programs[rec["key"] if events is not None else tkey] = {
+            "module": rec["module"], "signature": rec["signature"],
+            "error": rec["error"],
+            "instructions": sum(counts.values()),
+            "instructions_by_scope": dict(sorted(counts.items())),
+            "cost": e.get("cost"), "memory": e.get("memory"),
+            "call_site": e.get("call_site")}
+    return {"programs": programs}
+
+
 def _call_site(skip=0):
     """Innermost stack frame outside this module, after skipping
     ``skip`` additional frames (the engine skips its own _compiled
@@ -162,6 +424,7 @@ class CompileWatchdog:
             raise ValueError(f"mode must be 'flag' or 'raise', got "
                              f"{mode!r}")
         self.mode = mode
+        self.id = new_owner()   # its programs' owner in the table
         self._lock = threading.Lock()
         self._events = []
         self._warmed = False

@@ -18,6 +18,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..profiler import device_scope
 from ..core import trace as trace_mod
 from ..core.dispatch import register_op
 from .fused_ce import _TP_MESHES, _register_mesh
@@ -566,7 +567,7 @@ def cached_paged_attention(q, k_cache, v_cache, block_tables, lengths):
     parity oracle. Its cost is the capacity's, whatever is live."""
     S, _, hd = q.shape
     nh = k_cache.shape[1]
-    with jax.named_scope("kv_gather"):
+    with device_scope("kv_gather"):
         k = jnp.take(k_cache, block_tables, axis=0)  # [S, MB, nh, BS, hd]
         v = jnp.take(v_cache, block_tables, axis=0)
         k = k.transpose(0, 2, 1, 3, 4).reshape(S, nh, -1, hd)
@@ -634,7 +635,7 @@ def paged_prefill_attention(q, k, v, k_cache, v_cache, bt_row, start):
         m, l, acc = carry
         col = i * per + jnp.arange(per, dtype=jnp.int32)
         rows = bt_row[jnp.minimum(col, MB - 1)]
-        with jax.named_scope("kv_gather"):
+        with device_scope("kv_gather"):
             kb = k_cache[rows].transpose(1, 0, 2, 3).reshape(nh, width, hd)
             vb = v_cache[rows].transpose(1, 0, 2, 3).reshape(nh, width, hd)
         st = jnp.einsum("htd,hsd->hts", q, kb.astype(q.dtype),

@@ -22,6 +22,8 @@ once a window of 2,048 steps.
 import jax
 import jax.numpy as jnp
 
+from ..profiler import device_scope
+
 _NEG = -1e30
 
 
@@ -46,7 +48,7 @@ def window_compact(k, v, mu, phi, chunk):
     of ``chunk``), mu, phi ``[H, d]`` -> (kbar, vbar) ``[..., H,
     n / chunk, d]`` in the inputs' dtype. Scores, softmax and both sums
     in float32 on the vector unit (no matmul rounds a weight)."""
-    with jax.named_scope("eva/compact"):
+    with device_scope("eva/compact"):
         *lead, H, n, d = k.shape
         shape = tuple(lead) + (H, n // chunk, chunk, d)
         kc = k.astype(jnp.float32).reshape(shape)

@@ -65,6 +65,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..profiler import device_scope
 from .pallas_compat import trace_32bit as _trace_32bit
 
 # tests flip this to run the kernel in interpret mode on CPU
@@ -186,7 +187,7 @@ def gather_paged(cache, tables, token_axis_last=False):
 def mla_paged_decode_attn_jnp(q_lat, q_pe, c_cache, pe_cache, tables,
                               lengths, scale):
     """The kernel's signature in ``jnp``: gathers every slot's blocks."""
-    with jax.named_scope("kv_gather"):
+    with device_scope("kv_gather"):
         c = gather_paged(c_cache, tables)
         k_pe = gather_paged(pe_cache, tables, token_axis_last=True)
     return mla_decode_attn_jnp(q_lat, q_pe, c, k_pe, lengths, scale)

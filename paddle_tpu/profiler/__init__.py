@@ -19,6 +19,7 @@ a compiled to_static call): it leaves out the jax.named_scope push,
 which only names ops traced inside it.
 """
 import contextlib
+import threading
 import time
 
 import jax
@@ -46,7 +47,7 @@ class RecordEvent:
     def __enter__(self):
         self._cm = contextlib.ExitStack()
         self._cm.enter_context(jax.profiler.TraceAnnotation(self.name))
-        self._cm.enter_context(jax.named_scope(self.name))
+        self._cm.enter_context(device_scope(self.name))
         return self
 
     def __exit__(self, *exc):
@@ -133,6 +134,43 @@ _recorder = _obs_tracing.default_recorder()
 _span_kids = {}
 
 
+_device_scopes = threading.local()
+
+
+def current_scopes():
+    """The names of the ``device_scope``s open on this thread, outermost
+    first, as one tuple (every push makes a new one, so whatever is
+    recorded inside one scope shares it). The tape keeps it on a
+    ``GradNode`` recorded while a step is staged and runs the node's
+    backward under ``bwd`` + these names."""
+    return getattr(_device_scopes, "stack", ())
+
+
+class device_scope:
+    """``jax.named_scope(name)`` that also keeps ``name`` on the
+    framework's own stack (``current_scopes``): the one way a model or
+    a serving program names the layer its staged ops belong to. Names
+    only what is traced inside it (``op_name`` metadata of the compiled
+    program, read back through ``observability.watchdog.
+    program_scopes``); no span, no clock, nothing at run time."""
+
+    __slots__ = ("name", "_prev", "_ns")
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self._prev = current_scopes()
+        _device_scopes.stack = self._prev + (self.name,)
+        self._ns = jax.named_scope(self.name)
+        self._ns.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        _device_scopes.stack = self._prev
+        return self._ns.__exit__(*exc)
+
+
 @contextlib.contextmanager
 def record_scope(name, sink=None):
     """One scope, three sinks. Entering annotates the XLA trace
@@ -142,9 +180,9 @@ def record_scope(name, sink=None):
     timeline) and accrues seconds + a call count into the process
     metrics registry (observability.default_registry(), scrapeable as
     Prometheus text). An optional ``sink(name, dt)`` callback receives
-    the same elapsed seconds. host_scope plus the jax.named_scope that
+    the same elapsed seconds. host_scope plus the device_scope that
     names the ops staged inside the section."""
-    with host_scope(name, sink), jax.named_scope(name):
+    with host_scope(name, sink), device_scope(name):
         yield
 
 

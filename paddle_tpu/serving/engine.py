@@ -73,6 +73,7 @@ from ..analysis import threads as _lockpatrol
 from ..observability import (CompileWatchdog, FlightRecorder,
                              abstract_signature, device_memory_stats,
                              executable_cost, executable_memory)
+from ..observability.watchdog import note_program, programs_report
 from .metrics import ServingMetrics
 from .paged.pool import TRASH_BLOCK, PagedKVPool
 from .scheduler import QUEUED, RUNNING, Request, StepScheduler
@@ -977,6 +978,14 @@ class ServingEngine:
             # at build time (both best-effort None on non-reporting
             # backends — CPU has no memory_stats)
             cost = executable_cost(ex)
+            # what the program's instructions belong to (op_name: the
+            # device_scope names), for the process-wide table the
+            # readers of a trace join op times to: the text alone, the
+            # parse waits for the first request, and the table never
+            # holds the executable
+            note_program(key, text=ex.as_text(),
+                         signature=event["signature"],
+                         owner=self.watchdog.id)
             self.watchdog.annotate(
                 event["seq"], cost=cost,
                 memory=device_memory_stats(self._device))
@@ -999,6 +1008,14 @@ class ServingEngine:
                 # perf off)
                 self.metrics.perf.bind_cost(key, cost)
         return ex
+
+    def programs_report(self):
+        """``GET /debug/programs``: what each program this engine built
+        is made of (``observability.watchdog.programs_report`` joined
+        to this engine's compile records). Seconds-free; device time by
+        scope needs a capture (``benchmarks/tools/scope_report.py``)."""
+        return programs_report(self.watchdog.events(),
+                               owner=self.watchdog.id)
 
     def _timed_call(self, key, ex, args):
         """Dispatch one compiled executable, attributing its measured
@@ -1040,7 +1057,10 @@ class ServingEngine:
         (this replica's distributed-trace span ring — the surface
         tools/trace_report.py assembles fleet-wide), /debug/state (live
         engine state), /debug/perf (per-program attribution +
-        roofline fractions), /debug/cache (MRC, prefix heat, savings
+        roofline fractions), /debug/programs (per compiled program its
+        signature, cost, memory and the count of its instructions per
+        device_scope: which layer an op of a capture belongs to),
+        /debug/cache (MRC, prefix heat, savings
         attribution, churn), /debug/tenants (the per-tenant
         attribution ledger) and — with the health observatory on —
         /debug/health ({healthy, detectors, last_incident}: the
@@ -1062,6 +1082,7 @@ class ServingEngine:
             "/debug/requests": _debug_requests,
             "/debug/state": self.debug_state,
             "/debug/perf": self.metrics.perf_report,
+            "/debug/programs": self.programs_report,
             "/debug/cache": self.metrics.cache_report,
             "/debug/traces": self.trace.debug_traces,
             "/debug/tenants": self.metrics.tenant_report,

@@ -43,6 +43,7 @@ prefill sits at the model's last position, whose entry (clamped to the
 row's last) no live sequence uses; free rows point at the trash block;
 ``lengths`` never exceeds what the row's blocks hold.
 """
+from ...profiler import device_scope
 
 
 class PagedAccess:
@@ -70,7 +71,7 @@ class PagedAccess:
         rows = layer * jnp.int32(self.NB) \
             + self.bt_row[:-(-most // self.BS)]
         out = []
-        with jax.named_scope("kv_gather"):
+        with device_scope("kv_gather"):
             for cache in state:
                 H, d = cache.shape[1], cache.shape[3]
                 out.append(cache[rows].transpose(1, 0, 2, 3).reshape(
@@ -93,12 +94,12 @@ class PagedAccess:
                 # a run that fills its window leaves its summaries
                 new = new.at[:S].set(jnp.where(
                     length == W, bar[0].transpose(1, 0, 2), new[:S]))
-            with jax.named_scope("kv_gather"):
+            with device_scope("kv_gather"):
                 view = cache[rows].transpose(1, 0, 2, 3).reshape(H, C, d)
             # rows past the slot's capacity are dropped, not shifted
             view = view.at[:, at].set(
                 new.transpose(1, 0, 2).astype(cache.dtype), mode="drop")
-            with jax.named_scope("kv_write"):
+            with device_scope("kv_write"):
                 out.append(cache.at[rows].set(
                     view.reshape(H, self.MB, self.BS, d)
                     .transpose(1, 0, 2, 3)))
@@ -125,7 +126,7 @@ class PagedAccess:
         row = (jnp.arange(BS, dtype=jnp.int32)[None, :]
                == (entry % jnp.int32(BS))[:, None])[:, None, :, None]
         fb = base + bidx
-        with jax.named_scope("kv_write"):
+        with device_scope("kv_write"):
             kf = kf.at[fb].set(jnp.where(
                 row, k.astype(kf.dtype)[:, :, None], kf[fb]))
             vf = vf.at[fb].set(jnp.where(
@@ -204,7 +205,7 @@ def build_paged_eva_fns(cfg, num_slots, block_size, num_blocks,
         # ONE row through the head, as a [1, h] matmul
         last = block.lm_head(cfg, params, jax.lax.dynamic_slice_in_dim(
             x[0], tail_len - 1, 1, axis=0))[0]
-        with jax.named_scope("sample"):
+        with device_scope("sample"):
             if samp is None:
                 first = jnp.argmax(last, -1).astype(jnp.int32)
             else:
@@ -225,7 +226,7 @@ def build_paged_eva_fns(cfg, num_slots, block_size, num_blocks,
             cfg, params, x, pos, access, (flat(k), flat(v)),
             mode="decode", kernel=kernel)
         logits = block.lm_head(cfg, params, x)
-        with jax.named_scope("sample"):
+        with device_scope("sample"):
             if samp is None:
                 nxt = jnp.argmax(logits, -1).astype(jnp.int32)
             else:
@@ -241,12 +242,12 @@ def build_paged_eva_fns(cfg, num_slots, block_size, num_blocks,
         def layer(carry, inp):
             mu, phi, li = inp
             rows = li * jnp.int32(NB) + blocks                   # [nw]
-            with jax.named_scope("kv_gather"):
+            with device_scope("kv_gather"):
                 kw, vw = (c[rows].transpose(1, 0, 2, 3).reshape(H, W, d)
                           for c in carry)
             bars = eva_ops.window_compact(kw, vw, mu, phi,
                                           cfg.chunk_size)     # [H, S, d]
-            with jax.named_scope("kv_write"):
+            with device_scope("kv_write"):
                 return tuple(
                     c.at[rows[:ns]].set(
                         bar.reshape(H, ns, BS, d).transpose(1, 0, 2, 3))

@@ -41,6 +41,7 @@ its prefill (``pos == C - 1``; a live sequence never feeds a token
 there) keeps its window and its state through the decode steps in
 between: its step size is set to zero.
 """
+from ...profiler import device_scope
 
 
 class PagedAccess:
@@ -71,13 +72,13 @@ class PagedAccess:
         at = start + jnp.arange(k.shape[1], dtype=jnp.int32)
         out, views = [], []
         for cache, new in ((kf, k), (vf, v)):
-            with jax.named_scope("kv_gather"):
+            with device_scope("kv_gather"):
                 view = cache[rows].transpose(1, 0, 2, 3).reshape(
                     nkv, C, hd)
             # rows past the slot's capacity are dropped, not shifted
             view = view.at[:, at].set(
                 new[0].transpose(1, 0, 2).astype(cache.dtype), mode="drop")
-            with jax.named_scope("kv_write"):
+            with device_scope("kv_write"):
                 out.append(cache.at[rows].set(
                     view.reshape(nkv, self.MB, self.BS, hd)
                     .transpose(1, 0, 2, 3)))
@@ -94,7 +95,7 @@ class PagedAccess:
     def ssm_commit(self, state, mi, window, S):
         import jax
         kf, vf, conv, ssm = state
-        with jax.named_scope("state_write"):
+        with device_scope("state_write"):
             conv = jax.lax.dynamic_update_index_in_dim(
                 conv, window[0].astype(conv.dtype), mi, axis=0)
             ssm = jax.lax.dynamic_update_index_in_dim(
@@ -119,7 +120,7 @@ class PagedAccess:
         row = (jnp.arange(BS, dtype=jnp.int32)[None, :]
                == (wpos % jnp.int32(BS))[:, None])[:, None, :, None]
         fb = base + bidx
-        with jax.named_scope("kv_write"):
+        with device_scope("kv_write"):
             kf = kf.at[fb].set(jnp.where(
                 row, k.astype(kf.dtype)[:, :, None], kf[fb]))
             vf = vf.at[fb].set(jnp.where(
@@ -210,7 +211,7 @@ def build_paged_hybrid_fns(cfg, num_slots, block_size, num_blocks,
                       bt_row, toks, pos, k, v, conv, ssm, samp):
         B = tokens.shape[1]
         access = PagedAccess(cfg, S, NB, BS, MB, bt_row=bt_row)
-        with jax.named_scope("embed"):
+        with device_scope("embed"):
             x = params["wemb"][tokens]                       # [1, B, h]
         positions = (start + jnp.arange(B, dtype=jnp.int32))[None]
         mine = (jax.lax.dynamic_index_in_dim(conv, slot, 1, False),
@@ -218,7 +219,7 @@ def build_paged_hybrid_fns(cfg, num_slots, block_size, num_blocks,
         x, (kf, vf, conv_s, ssm_s), _ = block.run_layers(
             cfg, params, x, positions, access, (flat(k), flat(v)) + mine,
             start, "prefill", length=tail_len)
-        with jax.named_scope("state_write"):
+        with device_scope("state_write"):
             conv = jax.lax.dynamic_update_index_in_dim(conv, conv_s, slot,
                                                        axis=1)
             ssm = jax.lax.dynamic_update_index_in_dim(ssm, ssm_s, slot,
@@ -227,7 +228,7 @@ def build_paged_hybrid_fns(cfg, num_slots, block_size, num_blocks,
         # product is elementwise and XLA upcasts the whole head to f32
         last = block.lm_head(cfg, params, jax.lax.dynamic_slice_in_dim(
             x[0], tail_len - 1, 1, axis=0))[0]
-        with jax.named_scope("sample"):
+        with device_scope("sample"):
             if samp is None:
                 first = jnp.argmax(last, -1).astype(jnp.int32)
             else:
@@ -244,14 +245,14 @@ def build_paged_hybrid_fns(cfg, num_slots, block_size, num_blocks,
     def _decode_core(params, toks, pos, tables, k, v, conv, ssm, counts,
                      samp):
         access = PagedAccess(cfg, S, NB, BS, MB, tables=tables)
-        with jax.named_scope("embed"):
+        with device_scope("embed"):
             x = params["wemb"][toks]                         # [S, h]
         x, (kf, vf, conv, sf), counts = block.run_layers(
             cfg, params, x, pos, access,
             (flat(k), flat(v), conv, flat(ssm)), mode="decode",
             kernel=kernels, counts=counts)
         logits = block.lm_head(cfg, params, x)
-        with jax.named_scope("sample"):
+        with device_scope("sample"):
             if samp is None:
                 nxt = jnp.argmax(logits, -1).astype(jnp.int32)
             else:

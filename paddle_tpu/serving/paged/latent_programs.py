@@ -30,6 +30,7 @@ Parked and released slots behave as in ``programs.py``: write positions
 are clamped to the row's last entry, free rows point at the trash block,
 the length mask hides what they hold.
 """
+from ...profiler import device_scope
 
 
 class PagedAccess:
@@ -53,7 +54,7 @@ class PagedAccess:
         out, views = [], []
         for cache, new, flip in zip(state, (c, k_pe), (False, True)):
             d = new.shape[-1]
-            with jax.named_scope("kv_gather"):
+            with device_scope("kv_gather"):
                 blocks = cache[rows]                 # [MB, BS, d] or flipped
                 if flip:
                     blocks = blocks.transpose(0, 2, 1)
@@ -61,7 +62,7 @@ class PagedAccess:
             # rows past the slot's capacity are dropped, not shifted
             view = view.at[at].set(new[0].astype(cache.dtype),
                                    mode="drop")
-            with jax.named_scope("kv_write"):
+            with device_scope("kv_write"):
                 blocks = view.reshape(self.MB, self.BS, d)
                 if flip:
                     blocks = blocks.transpose(0, 2, 1)
@@ -83,7 +84,7 @@ class PagedAccess:
         row = (jnp.arange(BS, dtype=jnp.int32)[None, :]
                == (wpos % jnp.int32(BS))[:, None])           # [S, BS]
         fb = base + bidx
-        with jax.named_scope("kv_write"):
+        with device_scope("kv_write"):
             cf, pf = state
             cf = cf.at[fb].set(jnp.where(
                 row[:, :, None], c.astype(cf.dtype)[:, None, :], cf[fb]))
@@ -150,7 +151,7 @@ def build_paged_latent_fns(cfg, num_slots, block_size, num_blocks,
                       bt_row, toks, pos, c, k_pe, samp):
         B = tokens.shape[1]
         access = PagedAccess(NB, BS, MB, bt_row=bt_row)
-        with jax.named_scope("embed"):
+        with device_scope("embed"):
             x = params["wemb"][tokens]                       # [1, B, h]
         positions = (start + jnp.arange(B, dtype=jnp.int32))[None]
         x, (cf, pf), _ = block.run_layers(
@@ -158,7 +159,7 @@ def build_paged_latent_fns(cfg, num_slots, block_size, num_blocks,
             start, "prefill")
         last = block.lm_head(cfg, params,
                              jnp.take(x[0], tail_len - 1, axis=0))
-        with jax.named_scope("sample"):
+        with device_scope("sample"):
             if samp is None:
                 first = jnp.argmax(last, -1).astype(jnp.int32)
             else:
@@ -174,13 +175,13 @@ def build_paged_latent_fns(cfg, num_slots, block_size, num_blocks,
 
     def _decode_core(params, toks, pos, tables, c, k_pe, counts, samp):
         access = PagedAccess(NB, BS, MB, tables=tables, kernel=kernels)
-        with jax.named_scope("embed"):
+        with device_scope("embed"):
             x = params["wemb"][toks]                         # [S, h]
         x, (cf, pf), counts = block.run_layers(
             cfg, params, x, pos, access, (flat(c), flat(k_pe)),
             mode="decode", kernel=kernels, counts=counts)
         logits = block.lm_head(cfg, params, x)
-        with jax.named_scope("sample"):
+        with device_scope("sample"):
             if samp is None:
                 nxt = jnp.argmax(logits, -1).astype(jnp.int32)
             else:

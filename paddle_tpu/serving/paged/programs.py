@@ -101,6 +101,7 @@ is traced, from the bucket's shape and the backend
 256 positions on in steps of 128, ``jnp`` for the bucket of 128, odd
 chunk widths and the CPU).
 """
+from ...profiler import device_scope
 
 
 def build_paged_fns(cfg, num_slots, block_size, num_blocks,
@@ -129,7 +130,7 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
     C = MB * BS   # positions a slot's table row addresses
 
     def mlp(x, p):
-        with jax.named_scope("mlp"):
+        with device_scope("mlp"):
             h2 = ln(x, p["ln2_w"], p["ln2_b"])
             m = jax.nn.gelu(h2 @ p["fc1_w"] + p["fc1_b"], approximate=True)
             return x + (m @ p["fc2_w"] + p["fc2_b"])
@@ -139,7 +140,7 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
         # tokens [1, B] right-padded tail; start = cached prefix length
         B = tokens.shape[1]
         NB = kc.shape[1]
-        with jax.named_scope("embed"):
+        with device_scope("embed"):
             at = start + jnp.arange(B, dtype=jnp.int32)
             x = params["wemb"][tokens[0]] + params["pemb"][
                 jnp.minimum(at, params["pemb"].shape[0] - 1)]   # [B, h]
@@ -176,7 +177,7 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
             x, kf, vf = carry
             p, layer = inp
             base = layer * jnp.int32(NB)
-            with jax.named_scope("attn"):
+            with device_scope("attn"):
                 h_ = ln(x, p["ln1_w"], p["ln1_b"])
                 qkv = (h_ @ p["qkv_w"] + p["qkv_b"]).reshape(
                     B, 3, nh, hd).transpose(1, 2, 0, 3)   # [3, nh, B, hd]
@@ -185,7 +186,7 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
                     qkv[2].astype(vf.dtype)
                 o = attn_ops.paged_prefill_attention(
                     q, k, v, kf, vf, base + bt_row, start)
-                with jax.named_scope("kv_write"):
+                with device_scope("kv_write"):
                     rows = base + wblk
                     kf = kf.at[rows].set(jnp.where(
                         mine, blocks(k), kf[rows]))
@@ -199,13 +200,13 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
             body, (x, kf, vf),
             (params["stacked"], jnp.arange(L, dtype=jnp.int32)))
         kc, vc = kf.reshape(kc.shape), vf.reshape(vc.shape)
-        with jax.named_scope("lm_head"):
+        with device_scope("lm_head"):
             # ONE row through the head, as a [1, h] matmul (as a vector
             # the product is elementwise and the head is upcast whole)
             row = lax.dynamic_slice_in_dim(x, tail_len - 1, 1, axis=0)
             last = (ln(row, params["lnf_w"], params["lnf_b"])
                     @ params["head"])[0]                       # [vocab]
-        with jax.named_scope("sample"):
+        with device_scope("sample"):
             if samp is None:
                 first = jnp.argmax(last, -1).astype(jnp.int32)
             else:
@@ -238,7 +239,7 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
 
     def _decode_core(params, toks, pos, tables, kc, vc, samp):
         S = toks.shape[0]
-        with jax.named_scope("embed"):
+        with device_scope("embed"):
             x = params["wemb"][toks] + params["pemb"][
                 jnp.minimum(pos, params["pemb"].shape[0] - 1)]  # [S, h]
         # clamp the WRITE position as a whole (column AND offset):
@@ -277,7 +278,7 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
             x, kf, vf = carry
             p, layer = inp
             base = layer * jnp.int32(NB)
-            with jax.named_scope("attn"):
+            with device_scope("attn"):
                 h_ = ln(x, p["ln1_w"], p["ln1_b"])
                 qkv = h_ @ p["qkv_w"] + p["qkv_b"]
                 qkv = qkv.reshape(S, 3, nh, hd).transpose(1, 0, 2, 3)
@@ -287,7 +288,7 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
                 # updated in place. The only duplicate fb are parked /
                 # released slots meeting in the trash block, where any
                 # winner is garbage behind the length mask.
-                with jax.named_scope("kv_write"):
+                with device_scope("kv_write"):
                     fb = base + bidx                      # [S]
                     kf = kf.at[fb].set(jnp.where(
                         row, k.astype(kf.dtype)[:, :, None], kf[fb]))
@@ -309,10 +310,10 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
             body, (x, kf, vf),
             (params["stacked"], jnp.arange(L, dtype=jnp.int32)))
         kc, vc = kf.reshape(kc.shape), vf.reshape(vc.shape)
-        with jax.named_scope("lm_head"):
+        with device_scope("lm_head"):
             logits = ln(x, params["lnf_w"], params["lnf_b"]) \
                 @ params["head"]                          # [S, vocab]
-        with jax.named_scope("sample"):
+        with device_scope("sample"):
             if samp is None:
                 nxt = jnp.argmax(logits, -1).astype(jnp.int32)
             else:

@@ -37,6 +37,7 @@ scaling, expert groups (``n_group > 1``).
 import jax
 import jax.numpy as jnp
 
+from ..profiler import device_scope
 from ..ops import mla_attention as mla_ops
 from ..ops import moe_experts as moe_ops
 from .stacked_lm import (  # noqa: F401 - parts of this block
@@ -150,7 +151,7 @@ def attention(cfg, p, x, positions, access, state, layer, start, mode):
     r, dv = cfg.kv_lora_rank, cfg.v_head_dim
     lead = x.shape[:-1]
     cdt = jnp.dtype(cfg.cache_dtype)
-    with jax.named_scope("mla/q_absorb"):
+    with device_scope("mla/q_absorb"):
         xn = rms_norm(x, p["norm1"], cfg.rms_norm_eps)
         q = jnp.dot(xn, p["wq"]).reshape(lead + (nh, dn + dr))
         q_nope = q[..., :dn]
@@ -164,7 +165,7 @@ def attention(cfg, p, x, positions, access, state, layer, start, mode):
         if mode == "decode":
             q_lat = jnp.einsum("shd,rhd->shr", q_nope,
                                p["wkvb"][..., :dn]).astype(cdt)
-    with jax.named_scope("mla/attn"):
+    with device_scope("mla/attn"):
         if mode == "decode":
             state, o_lat = access.decode(state, layer, positions, c, k_pe,
                                          q_lat, q_pe.astype(cdt),
@@ -176,7 +177,7 @@ def attention(cfg, p, x, positions, access, state, layer, start, mode):
                     qn, qp, cc, pp, p["wkvb"].astype(cc.dtype), ps,
                     cfg.attn_scale))(
                 q_nope.astype(cdt), q_pe.astype(cdt), cv, pv, positions)
-    with jax.named_scope("mla/out"):
+    with device_scope("mla/out"):
         if mode == "decode":
             o = jnp.einsum("shr,rhd->shd", o_lat.astype(x.dtype),
                            p["wkvb"][..., dn:])
@@ -186,7 +187,7 @@ def attention(cfg, p, x, positions, access, state, layer, start, mode):
 
 
 def dense_mlp(cfg, p, x):
-    with jax.named_scope("mlp"):
+    with device_scope("mlp"):
         xn = rms_norm(x, p["norm2"], cfg.rms_norm_eps)
         y = moe_ops.swiglu(xn, p["mlp_gate"], p["mlp_up"], p["mlp_down"])
         return x + y.astype(x.dtype)
@@ -203,13 +204,13 @@ def expert_layer(cfg, p, experts, xn, layer_m, mode, kernel=False,
     Returns (y ``[T, h]`` f32, tokens per held expert ``[count]``)."""
     first, count = held if held is not None else cfg.held
     base = jnp.asarray(layer_m, jnp.int32) * jnp.int32(count)
-    with jax.named_scope("moe/router"):
+    with device_scope("moe/router"):
         idx, w = moe_ops.route_sigmoid(
             xn, p["router_w"], p["router_b"], cfg.num_experts_per_tok,
             cfg.norm_topk_prob, cfg.routed_scaling_factor,
             jnp.dtype(cfg.router_dtype))
         tokens = moe_ops.expert_counts(idx, first, count)
-    with jax.named_scope("moe/experts"):
+    with device_scope("moe/experts"):
         wg, wu, wd = experts["gate"], experts["up"], experts["down"]
         if mode == "decode":
             cw = moe_ops.combine_matrix(idx, w, first, count)
@@ -220,7 +221,7 @@ def expert_layer(cfg, p, experts, xn, layer_m, mode, kernel=False,
             y = moe_ops.moe_experts_grouped(xn, wg, wu, wd, idx, w,
                                             first, count, base)
     if with_shared:
-        with jax.named_scope("moe/shared"):
+        with device_scope("moe/shared"):
             y = y + moe_ops.swiglu(xn, p["sh_gate"], p["sh_up"],
                                    p["sh_down"])
     return y, tokens

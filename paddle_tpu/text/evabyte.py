@@ -43,6 +43,7 @@ embeddings, grouped key-value heads.
 import jax
 import jax.numpy as jnp
 
+from ..profiler import device_scope
 from ..ops import eva as eva_ops
 from ..ops import moe_experts as moe_ops
 from .stacked_lm import StackedCausalLM, greedy_or_sampled, rms_norm
@@ -166,7 +167,7 @@ def attention(cfg, p, x, positions, access, state, layer, start, mode,
     H, d = cfg.num_heads, cfg.head_dim
     lead = x.shape[:-1]
     cdt = jnp.dtype(cfg.cache_dtype)
-    with jax.named_scope("eva/qkv"):
+    with device_scope("eva/qkv"):
         h = norm(cfg, x, p["norm1"])
         # three matmuls, not one over a fused matrix: XLA brings each
         # layer's [h, h] matrix into VMEM at the HBM bandwidth and
@@ -180,7 +181,7 @@ def attention(cfg, p, x, positions, access, state, layer, start, mode,
         k = eva_ops.rope_half(k, positions[..., None],
                               cfg.rope_theta).astype(cdt)
         v = v.astype(cdt)
-    with jax.named_scope("eva/attn"):
+    with device_scope("eva/attn"):
         if mode == "decode":
             state, o = access.decode(state, layer, positions, q, k, v,
                                      kernel)
@@ -190,14 +191,14 @@ def attention(cfg, p, x, positions, access, state, layer, start, mode,
                 cfg, q, k, v, p["mu"], p["phi"], ks0, vs0, n0)
             state = access.store(state, layer, start, k, v, kbar, vbar,
                                  length)
-    with jax.named_scope("eva/out"):
+    with device_scope("eva/out"):
         y = jnp.dot(o.astype(h.dtype).reshape(lead + (H * d,)), p["wo"],
                     preferred_element_type=jnp.float32)
     return x + y.astype(x.dtype), state
 
 
 def mlp(cfg, p, x):
-    with jax.named_scope("mlp"):
+    with device_scope("mlp"):
         y = moe_ops.swiglu(norm(cfg, x, p["norm2"]), p["gate"], p["up"],
                            p["down"])
         return x + y.astype(x.dtype)
@@ -221,7 +222,7 @@ def run_layers(cfg, params, x, positions, access, state, start=0,
 
 
 def embed(cfg, params, ids):
-    with jax.named_scope("embed"):
+    with device_scope("embed"):
         x = params["wemb"][ids]
         return x.astype(jnp.float32) if cfg.fp32_skip_add else x
 
@@ -231,7 +232,7 @@ def lm_head(cfg, params, x, heads=1):
     h]``: logits ``[..., heads * vocab]`` in float32 (``fp32_logits``:
     operands too). Head ``i`` is columns ``[i V, (i + 1) V)``; head 0 is
     the next byte, what serving samples."""
-    with jax.named_scope("lm_head"):
+    with device_scope("lm_head"):
         xn = norm(cfg, x, params["norm_f"])
         w = params["head"][:, :heads * cfg.vocab_size]
         if cfg.fp32_logits:

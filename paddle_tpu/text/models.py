@@ -10,6 +10,7 @@ import math
 
 import jax
 
+from ..profiler import device_scope
 from .. import nn
 from ..core import trace as trace_mod
 from ..ops import creation, manipulation, math as math_ops, nn_ops
@@ -146,14 +147,14 @@ class Block(nn.Layer):
         # half with its norm and its residual add); eager, they do
         # nothing
         if self.pre_norm:  # GPT style
-            with jax.named_scope("block/attn"):
+            with device_scope("block/attn"):
                 x = math_ops.add(x, self.attn(self.ln1(x), attn_mask))
-            with jax.named_scope("block/mlp"):
+            with device_scope("block/mlp"):
                 x = math_ops.add(x, self.mlp(self.ln2(x)))
         else:  # BERT style post-norm
-            with jax.named_scope("block/attn"):
+            with device_scope("block/attn"):
                 x = self.ln1(math_ops.add(x, self.attn(x, attn_mask)))
-            with jax.named_scope("block/mlp"):
+            with device_scope("block/mlp"):
                 x = self.ln2(math_ops.add(x, self.mlp(x)))
         return x
 
@@ -189,7 +190,7 @@ class _TransformerCore(nn.Layer):
 
     def forward(self, input_ids, token_type_ids=None, attn_mask=None):
         s = input_ids.shape[1]
-        with jax.named_scope("embed"):
+        with device_scope("embed"):
             pos = creation.arange(0, s, dtype="int64")
             x = self.word_embeddings(input_ids)
             x = math_ops.add(x, self.position_embeddings(pos))
@@ -260,13 +261,13 @@ def _decode_forward_builder(num_heads, head_dim, hidden_size):
         # pos..pos+t (bb = batch OR batch*beams OR one pool slot)
         bb, t = x.shape[0], x.shape[1]
         total = kc.shape[2]
-        with jax.named_scope("attn"):
+        with device_scope("attn"):
             h_ = ln(x, p["ln1_w"], p["ln1_b"])
             qkv = h_ @ p["qkv_w"] + p["qkv_b"]
             qkv = qkv.reshape(bb, t, 3, nh, hd).transpose(2, 0, 3, 1, 4)
             q, k, v = qkv[0], qkv[1], qkv[2]
             z = jnp.int32(0)  # index dtypes must all match under x64
-            with jax.named_scope("kv_write"):
+            with device_scope("kv_write"):
                 kc = lax.dynamic_update_slice(kc, k, (z, z, pos, z))
                 vc = lax.dynamic_update_slice(vc, v, (z, z, pos, z))
             s = jnp.einsum("bhtd,bhsd->bhts", q, kc) / jnp.sqrt(
@@ -280,7 +281,7 @@ def _decode_forward_builder(num_heads, head_dim, hidden_size):
                            jax.nn.softmax(s, axis=-1), vc).astype(x.dtype)
             o = o.transpose(0, 2, 1, 3).reshape(bb, t, hidden_size)
             x = x + (o @ p["out_w"] + p["out_b"])
-        with jax.named_scope("mlp"):
+        with device_scope("mlp"):
             h2 = ln(x, p["ln2_w"], p["ln2_b"])
             m = jax.nn.gelu(h2 @ p["fc1_w"] + p["fc1_b"],
                             approximate=True)
@@ -289,7 +290,7 @@ def _decode_forward_builder(num_heads, head_dim, hidden_size):
     def forward_t(pr, tok, pos, kc, vc):
         # tok [bb, t] int32; kc/vc [L, bb, nh, total, hd]
         t = tok.shape[1]
-        with jax.named_scope("embed"):
+        with device_scope("embed"):
             x = pr["wemb"][tok] + pr["pemb"][pos + jnp.arange(t)]
 
         def body(carry, inp):
@@ -299,7 +300,7 @@ def _decode_forward_builder(num_heads, head_dim, hidden_size):
             return x, (kcl, vcl)
 
         x, (kc, vc) = lax.scan(body, x, (pr["stacked"], kc, vc))
-        with jax.named_scope("lm_head"):
+        with device_scope("lm_head"):
             logits = ln(x, pr["lnf_w"], pr["lnf_b"]) @ pr["head"]
         return logits, kc, vc
 
@@ -339,7 +340,7 @@ class GPTForCausalLM(nn.Layer):
             # GPT-124M x 8192 tokens.
             from ..ops.fused_ce import fused_linear_cross_entropy
             # head and loss are one kernel here: one scope, both names
-            with jax.named_scope("lm_head"), jax.named_scope("loss"):
+            with device_scope("lm_head"), device_scope("loss"):
                 flat = manipulation.reshape(labels, (-1,))
                 per_tok = fused_linear_cross_entropy(
                     manipulation.reshape(h, (-1, self.cfg.hidden_size)),
@@ -365,7 +366,7 @@ class GPTForCausalLM(nn.Layer):
                 t *= int(d)
             if tp_fused_applicable(mesh, t, self.cfg.hidden_size,
                                    self.cfg.vocab_size):
-                with jax.named_scope("lm_head"), jax.named_scope("loss"):
+                with device_scope("lm_head"), device_scope("loss"):
                     flat = manipulation.reshape(labels, (-1,))
                     per_tok = fused_linear_cross_entropy_tp(
                         manipulation.reshape(
@@ -373,7 +374,7 @@ class GPTForCausalLM(nn.Layer):
                         self.gpt.word_embeddings.weight, flat, mesh)
                     valid = (flat != -100).astype("float32").sum()
                     return per_tok.sum() / valid.clip(min=1.0)
-        with jax.named_scope("lm_head"):
+        with device_scope("lm_head"):
             if self.cfg.tie_embeddings:
                 logits = math_ops.matmul(
                     h, self.gpt.word_embeddings.weight, transpose_y=True)
@@ -381,7 +382,7 @@ class GPTForCausalLM(nn.Layer):
                 logits = self.lm_head(h)
         if labels is None:
             return logits
-        with jax.named_scope("loss"):
+        with device_scope("loss"):
             loss = nn_ops.cross_entropy(
                 manipulation.reshape(logits, (-1, self.cfg.vocab_size)),
                 manipulation.reshape(labels, (-1,)))

@@ -62,6 +62,7 @@ import re
 import jax
 import jax.numpy as jnp
 
+from ..profiler import device_scope
 from ..ops import attention as attn_ops
 from ..ops import moe_experts as moe_ops
 from ..ops import ssm as ssm_ops
@@ -227,7 +228,7 @@ def mamba_mixer(cfg, p, x, positions, access, state, mi, start, mode,
     ``[S, h]``."""
     d, H = cfg.d_inner, cfg.mamba_heads
     f32 = jnp.float32
-    with jax.named_scope("ssm/in_proj"):
+    with device_scope("ssm/in_proj"):
         xn = rms_norm(x, p["norm"], cfg.rms_norm_eps)
         zxd = jnp.dot(xn, p["in_proj"])
         z = zxd[..., :d]
@@ -236,19 +237,19 @@ def mamba_mixer(cfg, p, x, positions, access, state, mi, start, mode,
                              + p["dt_bias"].astype(f32))
         A = -jnp.exp(p["A_log"].astype(f32))
     if mode == "decode":
-        with jax.named_scope("ssm/scan"):
+        with device_scope("ssm/scan"):
             state, xs, y = access.ssm_decode(
                 state, mi, positions, u, dt, A, p["conv_w"], p["conv_b"],
                 kernel)
     else:
         T = x.shape[1]
-        with jax.named_scope("ssm/conv"):
+        with device_scope("ssm/conv"):
             window, S0 = access.ssm_init(state, mi, start, x.shape[0])
             act, window = jax.vmap(
                 lambda uu, ww: ssm_ops.conv_prefill(
                     uu, ww, p["conv_w"], p["conv_b"], length))(u, window)
             xs, B, C = split_channels(cfg, act)
-        with jax.named_scope("ssm/scan"):
+        with device_scope("ssm/scan"):
             # rows past the run leave the state as it was
             dt = jnp.where((jnp.arange(T) < length)[None, :, None], dt,
                            f32(0))
@@ -256,7 +257,7 @@ def mamba_mixer(cfg, p, x, positions, access, state, mi, start, mode,
                 lambda a, b, c, e, s: ssm_ops.ssd_prefill(
                     a, b, A, c, e, s, cfg.chunk_size))(xs, dt, B, C, S0)
             state = access.ssm_commit(state, mi, window, S)
-    with jax.named_scope("ssm/out"):
+    with device_scope("ssm/out"):
         y = y + p["D"].astype(f32)[:, None] * xs.astype(f32)
         y = y.reshape(x.shape[:-1] + (d,))
         y = group_rms_norm(y * jax.nn.silu(z.astype(f32)), p["gnorm"],
@@ -272,12 +273,12 @@ def attention(cfg, p, x, positions, access, state, li, start, mode,
     nq, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     lead = x.shape[:-1]
     cdt = jnp.dtype(cfg.cache_dtype)
-    with jax.named_scope("attn/qkv"):
+    with device_scope("attn/qkv"):
         xn = rms_norm(x, p["norm"], cfg.rms_norm_eps)
         q = jnp.dot(xn, p["wq"]).reshape(lead + (nq, hd)).astype(cdt)
         k = jnp.dot(xn, p["wk"]).reshape(lead + (nkv, hd)).astype(cdt)
         v = jnp.dot(xn, p["wv"]).reshape(lead + (nkv, hd)).astype(cdt)
-    with jax.named_scope("attn/paged"):
+    with device_scope("attn/paged"):
         if mode == "decode":
             state, o = access.attn_decode(state, li, positions, q, k, v,
                                           kernel)
@@ -285,7 +286,7 @@ def attention(cfg, p, x, positions, access, state, li, start, mode,
             state, (kv_, vv_) = access.attn_prefill(state, li, start, k, v)
             o = jax.vmap(attn_ops.grouped_causal_attention)(
                 q, kv_, vv_, positions)
-    with jax.named_scope("attn/out"):
+    with device_scope("attn/out"):
         return x + jnp.dot(o.astype(x.dtype).reshape(lead + (nq * hd,)),
                            p["wo"]), state
 
@@ -301,13 +302,13 @@ def expert_layer(cfg, p, experts, xn, ei, mode, kernel=False,
     ``[T, h]`` f32, tokens per held expert ``[count]``)."""
     first, count = held if held is not None else cfg.held
     base = jnp.asarray(ei, jnp.int32) * jnp.int32(count)
-    with jax.named_scope("moe/router"):
+    with device_scope("moe/router"):
         idx, w = moe_ops.route_sigmoid(
             xn, p["router_w"], p["router_b"], cfg.num_experts_per_tok,
             cfg.norm_topk_prob, cfg.routed_scaling_factor,
             jnp.dtype(cfg.router_dtype))
         tokens = moe_ops.expert_counts(idx, first, count)
-    with jax.named_scope("moe/experts"):
+    with device_scope("moe/experts"):
         up, down = experts["up_t"], experts["down"]
         if mode == "decode":
             cw = moe_ops.combine_matrix(idx, w, first, count)
@@ -318,7 +319,7 @@ def expert_layer(cfg, p, experts, xn, ei, mode, kernel=False,
             y = moe_ops.moe_experts_grouped_relu2(xn, up, down, idx, w,
                                                   first, count, base)
     if with_shared:
-        with jax.named_scope("moe/shared"):
+        with device_scope("moe/shared"):
             y = y + moe_ops.relu2_mlp(xn, p["sh_up_t"], p["sh_down"])
     return y, tokens
 

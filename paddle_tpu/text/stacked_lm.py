@@ -10,6 +10,7 @@ import collections
 import jax
 import jax.numpy as jnp
 
+from ..profiler import device_scope
 from .. import nn
 
 
@@ -22,7 +23,7 @@ def rms_norm(x, w, eps):
 
 def lm_head(cfg, params, x):
     """Final norm + head over x ``[..., h]``: logits in f32."""
-    with jax.named_scope("lm_head"):
+    with device_scope("lm_head"):
         return jnp.dot(rms_norm(x, params["norm_f"], cfg.rms_norm_eps),
                        params["head"], preferred_element_type=jnp.float32)
 
