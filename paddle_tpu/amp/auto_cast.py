@@ -23,6 +23,7 @@ _state = threading.local()
 WHITE_LIST = {
     "matmul", "matmul_v2", "mul", "conv2d", "conv3d", "conv2d_transpose",
     "einsum", "bmm", "addmm", "attention", "flash_attention",
+    "flash_attention_qkv",
     # the fused linear op IS a matmul (reference white list has mul/fc);
     # without it every nn.Linear ran fp32 under O1
     "linear",

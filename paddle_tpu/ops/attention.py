@@ -8,12 +8,12 @@ analogue: paddle/fluid/operators/math/bert_encoder_functor.cu and the
 fused multihead-matmul passes — here it's fused kernels instead of
 fusion passes. Falls back to the XLA softmax(QK^T)V composition for
 small shapes or on CPU where Pallas TPU kernels are unavailable
-(interpret mode exercises the kernels in CPU tests).
-
-Layout: [batch, num_heads, seq, head_dim].
+(interpret mode exercises the kernels in CPU tests). Layouts: [batch,
+heads, seq, head_dim], or a fused projection's [batch, seq, 3 * hidden].
 """
 import functools
 import math
+import types
 
 import jax
 import jax.numpy as jnp
@@ -256,7 +256,7 @@ def _pallas_flash_fwd_32(q, k, v, scale, causal, interpret):
 # dK/dV), each time with block-sized VMEM. Gradients accumulate in f32
 # scratch and leave once, in the inputs' dtype, at the last inner step.
 
-def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                       *refs, scale, causal, block, strip, n, want_dq,
                       want_dkv):
     from jax.experimental import pallas as pl
@@ -278,10 +278,14 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             w = (j + 1) * strip if diagonal else block
             do = do_ref[cols, :]
             k = k_ref[:w, :]
+            # delta = rowsum(dO * O), a row like lse
+            delta = jnp.sum((do.astype(jnp.float32)
+                             * o_ref[cols, :].astype(jnp.float32)).T,
+                            axis=0, keepdims=True)
             st, qs = _scores_t(q_ref[cols, :], k, scale, diagonal)
             pt = jnp.exp(st - lse_ref[:, cols])
             dpt = _dot_f32(v_ref[:w, :], do, ((1,), (1,)))
-            dst = (pt * (dpt - delta_ref[:, cols])).astype(k.dtype)
+            dst = (pt * (dpt - delta)).astype(k.dtype)
             if want_dkv:
                 dv_acc[:w, :] += _dot_f32(pt.astype(do.dtype), do,
                                           ((1,), (0,)))
@@ -307,6 +311,18 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             out[...] = g.astype(out.dtype)
 
 
+def _tile_maps(causal, keys_outer):
+    """(q_at, k_at): the query and the key tile of grid step (.., i, j),
+    j innermost: (i, j) = (query, key) tile (the forward, dQ alone) or,
+    with `keys_outer`, (key, query) (dK/dV). A tile above the diagonal
+    computes nothing and names the block already in VMEM."""
+    if keys_outer:
+        return ((lambda i, j: jnp.maximum(j, i)) if causal else (
+            lambda i, j: j)), (lambda i, j: i)
+    return (lambda i, j: i), (
+        (lambda i, j: jnp.minimum(j, i)) if causal else (lambda i, j: j))
+
+
 def _pallas_flash_bwd(q, k, v, out, lse, g, scale, causal):
     return _pallas_flash_bwd_32(q, k, v, out, lse, g, scale, causal,
                                 _interpret())
@@ -320,22 +336,9 @@ def _pallas_flash_bwd_32(q, k, v, out, lse, g, scale, causal, interpret):
     b, h, s, d = q.shape
     block = _block(s, d, q.dtype.itemsize)
     n = s // block
-    # delta = rowsum(dO * O): O(s d) precompute outside the kernels
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)[:, :, None, :]  # [b, h, 1, s]
 
     def call(name, want_dq, want_dkv):
-        # grid (b, h, i, j), j innermost: (i, j) = (query, key) tile
-        # for dQ alone, (key, query) with dK/dV. A tile above the
-        # diagonal computes nothing and names the block already in VMEM
-        if want_dkv:
-            k_at = lambda i, j: i
-            q_at = (lambda i, j: jnp.maximum(j, i)) if causal else (
-                lambda i, j: j)
-        else:
-            q_at = lambda i, j: i
-            k_at = (lambda i, j: jnp.minimum(j, i)) if causal else (
-                lambda i, j: j)
+        q_at, k_at = _tile_maps(causal, want_dkv)
 
         def blk(at):
             return pl.BlockSpec((None, None, block, d),
@@ -352,7 +355,8 @@ def _pallas_flash_bwd_32(q, k, v, out, lse, g, scale, causal, interpret):
             want_dkv=want_dkv)
         return pl.pallas_call(kernel, name=name,
             grid=(b, h, n, n),
-            in_specs=[blk(q_at), blk(k_at), blk(k_at), blk(q_at), row, row],
+            in_specs=[blk(q_at), blk(k_at), blk(k_at), blk(q_at), blk(q_at),
+                      row],
             out_specs=[blk(lambda i, j: i) for _ in grads],
             out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
                        for x in grads],
@@ -361,7 +365,7 @@ def _pallas_flash_bwd_32(q, k, v, out, lse, g, scale, causal, interpret):
                 (d, block) if x is q else (block, d), jnp.float32)
                 for x in grads],
             interpret=interpret,
-        )(q, k, v, g, lse, delta)
+        )(q, k, v, g, out, lse)
 
     if n == 1:
         return tuple(call("flash_bwd_dqkv", True, True))
@@ -423,6 +427,260 @@ def _flash_over_mesh(q, k, v, scale, causal, mesh):
         check_vma=False)(q, k, v)
 
 
+# ---- packed entry: q, k, v where the qkv projection left them --------------
+# The same two bodies over another operand layout. `qkv` is the fused
+# projection's [b, s, 3 * heads * d] output (column = (part, head, i)),
+# passed three times: a grid step reads a [block, 128] column block of
+# each part by its index map (two heads where d is 64, one head of 128
+# or 256), writes o and the gradients as column blocks of [b, s,
+# heads * d], and runs the body once a head on views of the block's
+# lanes. No [b, heads, s, d] array exists on either side of the kernels.
+
+def _heads_a_step(d):
+    return max(1, 128 // d)
+
+
+class _Lanes:
+    """Columns [lo, lo + d) of a block's ref as a ref of their own, for
+    the loads and stores the kernel bodies make (all of them take the
+    columns whole). Mosaic slices a memref by whole tiles of 128 lanes
+    only; a load or a store may start at any."""
+
+    def __init__(self, ref, lo, d):
+        self.ref, self.lanes = ref, slice(lo, lo + d)
+        self.shape, self.dtype = (ref.shape[0], d), ref.dtype
+
+    def _rows(self, idx):
+        return slice(None) if idx is Ellipsis else idx[0]
+
+    def __getitem__(self, idx):
+        return self.ref[self._rows(idx), self.lanes]
+
+    def __setitem__(self, idx, value):
+        self.ref[self._rows(idx), self.lanes] = value
+
+
+def _head_views(body, g, d, kinds):
+    """`body` once for each of the `g` heads that share a grid step, on
+    views of its refs. `kinds`, a letter a ref: `c` the head's `d`
+    columns of a [rows, g * d] block, `s` its own [g, ...] rows or
+    scratch."""
+    def kernel(*refs):
+        for u in range(g):
+            body(*[_Lanes(r, u * d, d) if kind == "c" else r.at[u]
+                   for r, kind in zip(refs, kinds)])
+    return kernel
+
+
+def _packed_specs(heads, d, block):
+    """BlockSpec makers for the packed layout, grid (b, heads // g, i,
+    j): `cols(part, at)` the column block of q (part 0), k (1) or v (2)
+    in [b, s, 3 * heads * d], and of o or a gradient (part 0) in [b, s,
+    heads * d], at the row tile `at(i, j)`; `rows(at)` the heads' rows
+    of lse [b, heads, 1, s]."""
+    from jax.experimental import pallas as pl
+    g = _heads_a_step(d)
+
+    def cols(part, at):
+        return pl.BlockSpec(
+            (None, block, g * d),
+            lambda bi, hi, i, j: (bi, at(i, j), part * (heads // g) + hi))
+
+    def rows(at):
+        return pl.BlockSpec((None, g, 1, block),
+                            lambda bi, hi, i, j: (bi, hi, 0, at(i, j)))
+    return cols, rows
+
+
+def _pallas_flash_qkv_fwd(qkv, heads, scale, causal):
+    return _pallas_flash_qkv_fwd_32(qkv, heads, scale, causal, _interpret())
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+@_trace_32bit
+def _pallas_flash_qkv_fwd_32(qkv, heads, scale, causal, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    b, s, width = qkv.shape
+    d = width // (3 * heads)
+    g = _heads_a_step(d)
+    block = _block(s, g * d, qkv.dtype.itemsize)
+    n = s // block
+    cols, rows = _packed_specs(heads, d, block)
+    kernel = _head_views(functools.partial(
+        _flash_fwd_kernel, scale=scale, causal=causal, block=block,
+        strip=min(_FWD_STRIP, block), nk=n), g, d, "ccccssss")
+    q_at, k_at = _tile_maps(causal, False)
+    return pl.pallas_call(kernel, name="flash_fwd",
+        grid=(b, heads // g, n, n),
+        in_specs=[cols(0, q_at), cols(1, k_at), cols(2, k_at)],
+        out_specs=[cols(0, q_at), rows(q_at)],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, s, heads * d), qkv.dtype),
+            jax.ShapeDtypeStruct((b, heads, 1, s), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((g, d, block), jnp.float32),
+            pltpu.VMEM((g, 1, block), jnp.float32),
+            pltpu.VMEM((g, 1, block), jnp.float32),
+        ],
+        interpret=interpret,
+    )(qkv, qkv, qkv)
+
+
+def _write_dqkv(body, parts, block, width, n, *refs):
+    """The backward `body` with its gradients written where the qkv
+    projection's backward reads them: dqkv [b, s, 3 * heads * d] stays
+    in HBM, a grid step's gradient blocks (`parts` of dq 0, dk 1, dv 2)
+    are staged in VMEM and copied from there into their column blocks,
+    so no pass over dqkv assembles it. Two stages a part, taken in
+    turn: a store's copies run under the next store's arithmetic and
+    are waited for where their stage is written again, and at the
+    call's last step. refs: the body's inputs, an aliased dqkv that is
+    not read (where another call wrote the other parts), dqkv, the
+    stages [2, block, width], the body's accumulators, the copies'
+    semaphores [2, parts]."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    k = len(parts)
+    (*ins, dqkv), stages, accs, sem = (
+        refs[:-2 * k - 1], refs[-2 * k - 1:-k - 1], refs[-k - 1:-1], refs[-1])
+    bi, hi, outer, inner = (pl.program_id(i) for i in range(4))
+    cols = pl.num_programs(1)
+    store = (bi * cols + hi) * n + outer     # one a (bi, hi, outer)
+    last = pl.num_programs(0) * cols * n - 1
+    half = store % 2
+    stores = inner == n - 1
+
+    def copies(half, go):
+        rows = pl.ds(pl.multiple_of(outer * block, block), block)
+        for i, (part, stage) in enumerate(zip(parts, stages)):
+            go(pltpu.make_async_copy(stage.at[half], dqkv.at[bi, rows, pl.ds(
+                pl.multiple_of((part * cols + hi) * width, width), width)],
+                sem.at[half, i]))
+
+    wait = lambda copy: copy.wait()   # by its size: the place is any
+
+    @pl.when(jnp.logical_and(stores, store >= 2))
+    def _drain():
+        copies(half, wait)
+
+    body(*ins[:6], *[stage.at[half] for stage in stages], *accs)
+
+    @pl.when(stores)
+    def _send():
+        copies(half, lambda copy: copy.start())
+
+    @pl.when(jnp.logical_and(stores, store == last))
+    def _finish():
+        copies(half, wait)
+        pl.when(last > 0)(lambda: copies(1 - half, wait))
+
+
+def _pallas_flash_qkv_bwd(qkv, out, lse, do, heads, scale, causal):
+    return _pallas_flash_qkv_bwd_32(qkv, out, lse, do, heads, scale, causal,
+                                    _interpret())
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+@_trace_32bit
+def _pallas_flash_qkv_bwd_32(qkv, out, lse, do, heads, scale, causal,
+                             interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    b, s, width = qkv.shape
+    d = width // (3 * heads)
+    g = _heads_a_step(d)
+    block = _block(s, g * d, qkv.dtype.itemsize)
+    n = s // block
+    cols, rows = _packed_specs(heads, d, block)
+
+    def call(name, parts, dqkv=None):
+        want_dq, want_dkv = 0 in parts, 1 in parts
+        q_at, k_at = _tile_maps(causal, want_dkv)
+        body = _head_views(functools.partial(
+            _flash_bwd_kernel, scale=scale, causal=causal, block=block,
+            strip=min(_BWD_STRIP, block), n=n, want_dq=want_dq,
+            want_dkv=want_dkv), g, d, "cccccs" + "c" * len(parts)
+            + "".join("s" if part == 0 else "c" for part in parts))
+        given = [] if dqkv is None else [dqkv]
+        return pl.pallas_call(
+            functools.partial(_write_dqkv, body, parts, block, g * d, n),
+            name=name,
+            grid=(b, heads // g, n, n),
+            in_specs=[cols(0, q_at), cols(1, k_at), cols(2, k_at),
+                      cols(0, q_at), cols(0, q_at), rows(q_at)]
+            + [pl.BlockSpec(memory_space=pl.ANY) for _ in given],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            out_shape=jax.ShapeDtypeStruct(qkv.shape, qkv.dtype),
+            # the thirds of dqkv that another call wrote stay
+            input_output_aliases={6: 0} if given else {},
+            # a gradient's block staged in its dtype, twice; then the
+            # f32 accumulators: dQ.T a head, dK and dV side by side
+            scratch_shapes=[pltpu.VMEM((2, block, g * d), qkv.dtype)
+                            for _ in parts]
+            + [pltpu.VMEM((g, d, block) if part == 0 else (block, g * d),
+                          jnp.float32) for part in parts]
+            + [pltpu.SemaphoreType.DMA((2, len(parts)))],
+            interpret=interpret,
+        )(qkv, qkv, qkv, do, out, lse, *given)
+
+    if n == 1:
+        return call("flash_bwd_dqkv", (0, 1, 2))
+    return call("flash_bwd_dkv", (1, 2), call("flash_bwd_dq", (0,)))
+
+
+def packed_qkv_viable(shape, dtype, heads):
+    """True where the packed kernels take a qkv of `shape` [b, s, 3 *
+    heads * d]: what `_use_pallas` takes as [b, heads, s, d], with
+    heads that fill whole blocks of 128 lanes."""
+    b, s, width = shape
+    d = width // (3 * heads)
+    return (width == 3 * heads * d and heads % _heads_a_step(d) == 0
+            and _use_pallas(types.SimpleNamespace(shape=(b, heads, s, d),
+                                                  dtype=dtype)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def _flash_qkv_core(qkv, heads, scale, causal):
+    return _pallas_flash_qkv_fwd(qkv, heads, scale, causal)[0]
+
+
+def _flash_qkv_fwd(qkv, heads, scale, causal):
+    out, lse = _pallas_flash_qkv_fwd(qkv, heads, scale, causal)
+    return out, (qkv, out, lse)
+
+
+def _flash_qkv_bwd(heads, scale, causal, res, do):
+    return (_pallas_flash_qkv_bwd(*res, do, heads, scale, causal),)
+
+
+_flash_qkv_core.defvjp(_flash_qkv_fwd, _flash_qkv_bwd)
+
+
+@register_op("flash_attention_qkv")
+def _flash_qkv_op(qkv, *, heads, causal):
+    b, s, width = qkv.shape
+    d = width // (3 * heads)
+    scale = 1.0 / math.sqrt(d)
+    if packed_qkv_viable(qkv.shape, qkv.dtype, heads):
+        return _flash_qkv_core(qkv, heads, scale, causal)
+    q, k, v = jnp.moveaxis(qkv.reshape(b, s, 3, heads, d), (2, 3), (0, 2))
+    o = _flash_attention_core(q, k, v, scale, causal)
+    return jnp.swapaxes(o, 1, 2).reshape(b, s, heads * d)
+
+
+def flash_attention_qkv(qkv, heads, causal=False):
+    """Attention of a fused projection's output where it lies: `qkv`
+    [batch, seq, 3 * heads * head_dim] with columns ordered (q | k | v,
+    head, head_dim) -> [batch, seq, heads * head_dim], scale
+    head_dim ** -0.5, no mask. The flash kernels read q, k, v from
+    `qkv` by their index maps and hand the output, and in the backward
+    the gradient of `qkv`, back in these layouts (`packed_qkv_viable`);
+    other shapes split the heads and take `_flash_attention_core`."""
+    return _flash_qkv_op(qkv, heads=int(heads), causal=bool(causal))
+
+
 @register_op("flash_attention")
 def _flash_op(q, k, v, mask, *, scale, causal, mesh_id=None):
     if mask is not None:
@@ -436,9 +694,14 @@ def _flash_op(q, k, v, mask, *, scale, causal, mesh_id=None):
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
                                  training=True, scale=None, name=None):
-    """Inputs [batch, heads, seq, head_dim] (or [b, s, h, d] paddle-style
-    is accepted via transpose by callers). Dropout inside attention is not
-    fused; applied to weights only in the fallback path when requested."""
+    """query, key, value [batch, heads, seq, head_dim] -> the same
+    layout; this entry takes no other (a caller that holds [batch, seq,
+    heads, head_dim] transposes first; one that holds a fused
+    projection's [batch, seq, 3 * heads * head_dim] and no mask calls
+    `flash_attention_qkv`, which reads it where it lies). With
+    `attn_mask` (added to the scores) the dense composition runs, else
+    the flash kernels where `_use_pallas` takes the shape. `dropout_p`
+    is accepted and not applied: no path fuses dropout into attention."""
     sc = scale if scale is not None else 1.0 / math.sqrt(query.shape[-1])
     # inside a compiled step over a multi-device mesh the kernel has to
     # be told the mesh (see _flash_over_mesh)

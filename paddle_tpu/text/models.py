@@ -82,8 +82,19 @@ class SelfAttention(nn.Layer):
             self.out = nn.Linear(h, h)
 
     def forward(self, x, attn_mask=None):
+        from ..ops import attention as attn_ops
         b, s, h = x.shape
         qkv = self.qkv(x)
+        mesh = topology.get_mesh()
+        if attn_mask is None and (mesh is None or mesh.size == 1) \
+                and attn_ops.packed_qkv_viable(qkv.shape, qkv.dtype,
+                                               self.num_heads):
+            # the kernels read q, k, v from the projection's output
+            # where it lies and write o as the output projection reads
+            # it: no head split, forward or backward
+            o = attn_ops.flash_attention_qkv(qkv, self.num_heads,
+                                             causal=self.causal)
+            return self._project_out(o)
         qkv = manipulation.reshape(qkv, (b, s, 3, self.num_heads,
                                          self.head_dim))
         qkv = manipulation.transpose(qkv, (2, 0, 3, 1, 4))
@@ -98,11 +109,12 @@ class SelfAttention(nn.Layer):
                      else ulysses_attention)
             o = sp_fn(q, k, v, causal=self.causal)
         else:
-            from ..ops import attention as attn_ops
             o = attn_ops.scaled_dot_product_attention(
                 q, k, v, attn_mask=attn_mask, is_causal=self.causal)
         o = manipulation.transpose(o, (0, 2, 1, 3))
-        o = manipulation.reshape(o, (b, s, h))
+        return self._project_out(manipulation.reshape(o, (b, s, h)))
+
+    def _project_out(self, o):
         o = self.out(o)
         if self.dropout:
             o = nn_ops.dropout(o, p=self.dropout, training=self.training)

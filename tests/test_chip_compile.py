@@ -149,6 +149,95 @@ def test_flash_compiles_at_other_shapes(one_chip, d, dtype, causal):
     _compile(both, q, q, q, q)
 
 
+# the packed entry (q, k, v read from the qkv projection's output where
+# it lies): the training cell's own shape, two heads of 64 a grid step,
+# and the 1.3B GPT's heads of 128, one a step
+PACKED_SHAPES = [(24, 1024, 12, 64), (8, 1024, 16, 128)]
+
+
+def _packed(one_chip, batch, seq, heads, d):
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    return (sds((batch, seq, 3 * heads * d)), sds((batch, seq, heads * d)),
+            sds((batch, heads, 1, seq), jnp.float32))
+
+
+@pytest.mark.parametrize("batch,seq,heads,d", PACKED_SHAPES)
+def test_packed_flash_fwd_compiles(one_chip, batch, seq, heads, d):
+    qkv, _, _ = _packed(one_chip, batch, seq, heads, d)
+    text = _compile(lambda x: attention._pallas_flash_qkv_fwd(
+        x, heads, d ** -0.5, True), qkv)
+    assert any("flash_fwd" in n for n in _instruction_names(text))
+
+
+@pytest.mark.parametrize("batch,seq,heads,d", PACKED_SHAPES)
+def test_packed_flash_bwd_compiles(one_chip, batch, seq, heads, d):
+    qkv, o, lse = _packed(one_chip, batch, seq, heads, d)
+    text = _compile(lambda x, o, lse, g: attention._pallas_flash_qkv_bwd(
+        x, o, lse, g, heads, d ** -0.5, True), qkv, o, lse, o)
+    kernels = [n for n in _instruction_names(text) if "flash" in n]
+    assert kernels and all("flash_bwd_" in n for n in kernels), kernels
+    # the kernel writes dqkv itself: nothing else is in the program
+    assert not re.search(r" (fusion|copy|transpose|concatenate)\(", text)
+
+
+def test_packed_layer_holds_no_head_split(one_chip):
+    """qkv projection -> packed attention -> output projection, forward
+    and vjp, at the training cell's shape: between the matmuls and the
+    kernels no copy or transpose, no [.., heads, seq, d] or [.., 3,
+    heads, d] array, no f32 array of an operand's size."""
+    batch, seq = 24, 1024
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                              sharding=one_chip)
+
+    def layer(x, wq, bq, wo, bo):
+        o = attention._flash_qkv_op.fn(x @ wq + bq, heads=HEADS,
+                                       causal=True)
+        return o @ wo + bo
+
+    def step(g, *args):
+        out, vjp = jax.vjp(layer, *args)
+        return out, vjp(g)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = _compile(step, sds(batch, seq, HIDDEN), sds(batch, seq, HIDDEN),
+                        sds(HIDDEN, 3 * HIDDEN), sds(3 * HIDDEN),
+                        sds(HIDDEN, HIDDEN), sds(HIDDEN))
+    entry = text[text.index("ENTRY"):]
+    for line in entry.splitlines():
+        shapes = re.findall(r"(\w+)\[([\d,]+)\]", line.split(" = ")[-1]
+                            .split("(")[0])
+        for dt, shape in shapes:
+            dims = shape.split(",")
+            assert dims[-2:] != [str(HEADS), str(HEAD_DIM)] \
+                and dims[-3:-1] != [str(HEADS), str(seq)], line
+            assert not (dt == "f32" and len(dims) >= 3
+                        and dims[:2] == [str(batch), str(seq)]), line
+        assert not re.search(r" (copy|transpose)\(", line) \
+            or "copy-" in line, line
+    kernels = [n for line in entry.splitlines() if "tpu_custom_call" in line
+               for n in _instruction_names(line)]
+    assert sorted(n.split(".")[0] for n in kernels) == [
+        "flash_bwd_dqkv", "flash_fwd"], kernels
+
+
+def test_replayed_packed_flash_fwd_merges_with_the_first(one_chip):
+    """As above for the packed entry: the tape's replayed forward is the
+    first forward to XLA, one `flash_fwd` a layer."""
+    qkv, o, _ = _packed(one_chip, 24, 1024, HEADS, HEAD_DIM)
+
+    def taped(x, g):
+        core = lambda x_: attention._flash_qkv_core(
+            x_, HEADS, HEAD_DIM ** -0.5, True)
+        out = core(x)
+        _, vjp = jax.vjp(core, x)
+        return out, vjp(g)
+    text = _compile(taped, qkv, o)
+    kernels = [n for line in text.splitlines() if "tpu_custom_call" in line
+               for n in _instruction_names(line)]
+    assert sorted(n.split(".")[0] for n in kernels) == [
+        "flash_bwd_dqkv", "flash_fwd"], kernels
+
+
 def _ce_args(sharding_x, sharding_w, sharding_t, vocab=VOCAB):
     x = jax.ShapeDtypeStruct((TOKENS, HIDDEN), jnp.bfloat16,
                              sharding=sharding_x)

@@ -84,6 +84,120 @@ def test_flash_matches_reference_in_f32(causal, seq, d, dtype):
         assert gap < limit, (name, gap)
 
 
+def _split_heads(qkv, heads):
+    b, s, width = qkv.shape
+    return jnp.moveaxis(qkv.reshape(b, s, 3, heads, width // (3 * heads)),
+                        (2, 3), (0, 2))
+
+
+# (12, 64): the training cell's heads, two a grid step side by side on
+# the lanes; (16, 128): the 1.3B GPT's, one a step; (2, 64) at 1024:
+# in f32 two tiles a side, so two backward kernels write one dqkv
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("heads,d,s", [(12, 64, 256), (16, 128, 256),
+                                       (2, 64, 1024)])
+@pytest.mark.parametrize("causal", [False, True])
+def test_packed_flash_matches_reference_in_f32(causal, heads, d, s, dtype):
+    """The packed entry (q, k, v read from the projection's [b, s, 3 *
+    heads * d] where it lies, o and dqkv written in that layout)
+    against the dense composition in f32 from the same inputs: the
+    output and every third of dqkv, under the limits of the
+    [b, h, s, d] entry above."""
+    b, hidden = 2, heads * d
+    rs = np.random.RandomState(heads + d)
+    qkv = jnp.asarray(rs.randn(b, s, 3 * hidden).astype("float32")
+                      * 0.3).astype(dtype)
+    w = jnp.asarray(rs.randn(b, s, hidden).astype("float32"))
+    f32 = lambda x: x.astype(jnp.float32)
+    assert attn.packed_qkv_viable(qkv.shape, qkv.dtype, heads)
+
+    def packed(x):
+        return f32(attn._flash_qkv_core(x, heads, d ** -0.5, causal))
+
+    def dense(x):
+        o = attn._reference_attention(*_split_heads(f32(x), heads), None,
+                                      d ** -0.5, causal)
+        return jnp.swapaxes(o, 1, 2).reshape(b, s, hidden)
+
+    got = (packed(qkv), jax.grad(lambda x: jnp.sum(packed(x) * w))(qkv))
+    want = (dense(qkv), jax.grad(lambda x: jnp.sum(dense(x) * w))(qkv))
+    assert got[1].dtype == dtype and got[1].shape == qkv.shape
+    parts = [("out", got[0], want[0])] + [
+        (name, got[1][..., i * hidden:(i + 1) * hidden],
+         want[1][..., i * hidden:(i + 1) * hidden])
+        for i, name in enumerate(("dq", "dk", "dv"))]
+    limit = 2e-5 if dtype == jnp.float32 else 2e-2
+    for name, a, b_ in parts:
+        gap = float(jnp.max(jnp.abs(f32(a) - f32(b_)))
+                    / jnp.max(jnp.abs(f32(b_))))
+        assert gap < limit, (name, gap)
+
+
+def _attention_layer(heads, hidden, causal=True):
+    import paddle_tpu as paddle
+    from paddle_tpu.text.models import SelfAttention, TransformerLMConfig
+    paddle.seed(0)
+    return SelfAttention(TransformerLMConfig(
+        hidden_size=hidden, num_heads=heads, dropout=0.0), causal)
+
+
+def _loss_and_grads(layer, x, mask=None):
+    """The loss and the gradient of the input and of every parameter,
+    through the tape."""
+    import paddle_tpu as paddle
+    x = paddle.to_tensor(x, stop_gradient=False)
+    loss = (layer(x, mask) ** 2).mean()
+    loss.backward()
+    grads = [x.grad.numpy()] + [p.grad.numpy() for p in layer.parameters()]
+    for p in layer.parameters():
+        p.clear_grad()
+    return float(loss.numpy()), grads
+
+
+@pytest.mark.parametrize("heads,hidden", [(12, 768), (2, 256)],
+                         ids=["12x64", "2x128"])
+def test_self_attention_packed_matches_head_split(heads, hidden,
+                                                  monkeypatch):
+    """SelfAttention end to end through the packed entry against
+    today's head-split path (the same kernels over [b, h, s, d]): the
+    same loss and the same gradient of the input and of every
+    parameter."""
+    layer = _attention_layer(heads, hidden)
+    x = np.random.RandomState(1).randn(2, 256, hidden).astype("float32")
+    calls = []
+    real = attn.flash_attention_qkv
+    monkeypatch.setattr(attn, "flash_attention_qkv",
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    loss, grads = _loss_and_grads(layer, x)
+    assert calls
+    monkeypatch.setattr(attn, "packed_qkv_viable", lambda *a: False)
+    calls.clear()
+    loss_split, grads_split = _loss_and_grads(layer, x)
+    assert not calls
+    np.testing.assert_allclose(loss, loss_split, rtol=2e-4)
+    for a, b_ in zip(grads, grads_split):
+        np.testing.assert_allclose(a, b_, rtol=5e-3,
+                                   atol=5e-4 * np.abs(b_).max())
+
+
+@pytest.mark.parametrize("case", ["mask", "seq200", "13heads"])
+def test_self_attention_keeps_the_head_split_where_packed_cannot(
+        case, monkeypatch):
+    """A masked call, a sequence the kernels refuse and an odd number
+    of heads of 64 (two share a block's lanes) take today's path."""
+    import paddle_tpu as paddle
+    heads, seq = (13, 256) if case == "13heads" else (4, 256)
+    seq = 200 if case == "seq200" else seq
+    layer = _attention_layer(heads, heads * 64, causal=False)
+    monkeypatch.setattr(attn, "flash_attention_qkv", lambda *a, **k: 1 / 0)
+    x = np.random.RandomState(2).randn(1, seq, heads * 64).astype("float32")
+    mask = paddle.to_tensor(np.zeros((1, 1, seq, seq), "float32")) \
+        if case == "mask" else None
+    loss, grads = _loss_and_grads(layer, x, mask)
+    assert np.isfinite(loss) and all(np.isfinite(g).all() for g in grads)
+
+
 @pytest.mark.parametrize("s,d,itemsize,block", [
     (1024, 64, 2, 1024),    # the training cell: one tile a head
     (8192, 64, 2, 1024),    # K/V streamed tile by tile
