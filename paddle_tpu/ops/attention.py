@@ -748,9 +748,11 @@ def cached_slot_attention(q, k_cache, v_cache, lengths):
         kpos = jnp.arange(cache_len)[None, None, None, :]
         s = jnp.where(kpos < lengths[:, None, None, None], s,
                       jnp.float32(-1e30))
+        # a value may be narrower than its key
         return jnp.einsum("sngk,snkd->sngd", jax.nn.softmax(s, axis=-1),
                           v_cache, preferred_element_type=jnp.float32
-                          ).astype(q.dtype).reshape(S, nh, hd)
+                          ).astype(q.dtype).reshape(S, nh,
+                                                    v_cache.shape[-1])
     # f32 score accumulation (the _dot_f32 discipline): bf16 caches
     # keep full MXU rate but never sum scores in bf16; a no-op for f32
     s = jnp.einsum("shd,shkd->shk", q, k_cache,
@@ -766,33 +768,60 @@ def cached_slot_attention(q, k_cache, v_cache, lengths):
                       ).astype(q.dtype)
 
 
-def grouped_causal_attention(q, k_view, v_view, q_pos, q_block=128):
+def softmax_with_sink(s, sink):
+    """Softmax of f32 scores ``s`` over the last axis with one more
+    column of logit ``sink`` (broadcastable to ``s[..., :1]``; None:
+    none) that carries no value: the weights of the REAL columns, which
+    then sum to less than 1."""
+    if sink is None:
+        return jax.nn.softmax(s, axis=-1)
+    b = sink.astype(jnp.float32)
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), b)
+    e = jnp.exp(s - m)
+    return e / (jnp.exp(b - m) + jnp.sum(e, axis=-1, keepdims=True))
+
+
+def grouped_causal_attention(q, k_view, v_view, q_pos, q_block=128,
+                             k_pos=None, window=None, sink=None):
     """Causal attention of ONE sequence's run of queries over a
     position-ordered view of its cache, with grouped queries (a paged
     PREFILL: the run's own keys and values are already in the view).
 
-    q ``[T, nh, hd]`` at absolute positions ``q_pos [T]``; k_view,
-    v_view ``[nkv, C, hd]`` (view index == position; ``nkv`` divides
-    ``nh``, query head ``h`` reads KV head ``h // (nh // nkv)``). Key
-    ``s`` is seen by query ``t`` when ``s <= q_pos[t]``. Scores and
-    softmax in f32, scale ``hd ** -0.5``; computed ``q_block`` query
-    rows at a time so that the ``[nh, rows, C]`` scores stay a temporary
-    of that size. Returns ``[T, nh, hd]`` in q's dtype."""
+    q ``[T, nh, hd]`` at absolute positions ``q_pos [T]``; k_view
+    ``[nkv, C, hd]``, v_view ``[nkv, C, dv]`` (a value may be narrower
+    than its key; ``nkv`` divides ``nh``, query head ``h`` reads KV
+    head ``h // (nh // nkv)``). Key ``c`` is at position ``k_pos[c]``
+    (default: its index; a key that holds nothing is given a negative
+    one) and is seen by query ``t`` when ``0 <= k_pos[c] <= q_pos[t]``
+    and, with ``window``, ``k_pos[c] > q_pos[t] - window``. ``sink
+    [nh]`` (f32 logits) joins each head's softmax as one more column
+    that carries no value. Scores and softmax in f32, scale ``hd **
+    -0.5``; computed ``q_block`` query rows at a time so that the
+    ``[nh, rows, C]`` scores stay a temporary of that size. Returns
+    ``[T, nh, dv]`` in q's dtype."""
     T, nh, hd = q.shape
     nkv, C = k_view.shape[:2]
-    kpos = jnp.arange(C, dtype=jnp.int32)
+    dv = v_view.shape[-1]
+    kpos = jnp.arange(C, dtype=jnp.int32) if k_pos is None else k_pos
+    if sink is not None:
+        sink = sink.reshape(nkv, nh // nkv, 1, 1)
 
     def rows(qb, pos):
         qg = qb.reshape(qb.shape[0], nkv, nh // nkv, hd)
         s = jnp.einsum("tngd,ncd->ngtc", qg, k_view,
                        preferred_element_type=jnp.float32) / jnp.sqrt(
             jnp.float32(hd))
-        s = jnp.where(kpos[None, None, None, :] <= pos[None, None, :, None],
-                      s, jnp.float32(-1e30))
-        p = jax.nn.softmax(s, axis=-1)
+        kp, qp = kpos[None, None, None, :], pos[None, None, :, None]
+        seen = kp <= qp
+        if k_pos is not None:
+            seen = jnp.logical_and(seen, kp >= 0)
+        if window is not None:
+            seen = jnp.logical_and(seen, kp > qp - jnp.int32(window))
+        s = jnp.where(seen, s, jnp.float32(-1e30))
+        p = softmax_with_sink(s, sink)
         o = jnp.einsum("ngtc,ncd->tngd", p.astype(v_view.dtype), v_view,
                        preferred_element_type=jnp.float32)
-        return o.astype(q.dtype).reshape(qb.shape[0], nh, hd)
+        return o.astype(q.dtype).reshape(qb.shape[0], nh, dv)
 
     qb = min(int(q_block), T)
     if T % qb or T == qb:
@@ -800,10 +829,11 @@ def grouped_causal_attention(q, k_view, v_view, q_pos, q_block=128):
     out = jax.lax.map(lambda a: rows(*a),
                       (q.reshape(T // qb, qb, nh, hd),
                        q_pos.reshape(T // qb, qb)))
-    return out.reshape(T, nh, hd)
+    return out.reshape(T, nh, dv)
 
 
-def cached_paged_attention(q, k_cache, v_cache, block_tables, lengths):
+def cached_paged_attention(q, k_cache, v_cache, block_tables, lengths,
+                           q_rot=None, k_rot=None):
     """Single-token decode attention over a PAGED cache addressed
     through a fixed-shape block table (the serving paged decode step,
     serving.paged.programs.build_paged_fns).
@@ -827,14 +857,25 @@ def cached_paged_attention(q, k_cache, v_cache, block_tables, lengths):
     program runs where the Pallas paged decode kernel
     (ops.paged_attention, which reads the live blocks in place) cannot
     (the CPU, shapes ``kernel_viable`` refuses), and that kernel's
-    parity oracle. Its cost is the capacity's, whatever is live."""
+    parity oracle. Its cost is the capacity's, whatever is live.
+
+    ``q_rot [S, nh, d2]``, ``k_rot [num_blocks, nkv, d2, BS]``: the
+    second part of a key wider than its value, its pool stored
+    transposed (``ops.paged_attention``); the two parts are put side by
+    side and the scores scaled by the whole width."""
     S, _, hd = q.shape
     nh = k_cache.shape[1]
     with device_scope("kv_gather"):
         k = jnp.take(k_cache, block_tables, axis=0)  # [S, MB, nh, BS, hd]
         v = jnp.take(v_cache, block_tables, axis=0)
         k = k.transpose(0, 2, 1, 3, 4).reshape(S, nh, -1, hd)
-        v = v.transpose(0, 2, 1, 3, 4).reshape(S, nh, -1, hd)
+        v = v.transpose(0, 2, 1, 3, 4).reshape(S, nh, -1, v.shape[-1])
+        if k_rot is not None:
+            k2 = jnp.take(k_rot, block_tables, axis=0)  # [S,MB,nh,d2,BS]
+            k2 = k2.transpose(0, 2, 1, 4, 3).reshape(S, nh, -1,
+                                                     k_rot.shape[2])
+            k = jnp.concatenate([k, k2], axis=-1)
+            q = jnp.concatenate([q, q_rot], axis=-1)
     return cached_slot_attention(q, k, v, lengths)
 
 

@@ -47,6 +47,18 @@ buffer no copy refreshed) get ``-1e30`` before the f32 online softmax,
 so they carry exactly-zero weight. The value buffer is zeroed once a
 call, so what lies behind a zero weight is always finite.
 
+A key may be WIDER than its value and come in two parts
+(``paged_decode_attention(..., q_rot=, k_rot=)``: ``text.mimo_v2``, keys
+of 192 beside values of 128): the part of the value's width in
+``k_cache`` as above, the other lanes in a second pool stored
+TRANSPOSED, ``[num_blocks, nh, d2, BS]`` (a width that is no multiple of
+the 128 lanes rides the sublanes and the block's positions fill the
+lanes: nothing is padded in HBM). Its blocks are copied as the planes
+of a third chunk buffer ``[2, G, nh, d2, BS]`` and add ``q_rot x
+k_rot`` to the scores, which are scaled by the whole key's width. The
+same body: without the second part no ref, copy or product of it is
+traced, and a call's jaxpr is what it was before there was one.
+
 Where it runs: ``kernel_viable`` (backend has Mosaic, shapes tile) is
 the only gate; ``ServingEngine`` asks it once at build time and the
 GPT's paged decode program uses the kernel wherever it says yes. The
@@ -76,10 +88,12 @@ def _interpret():
     return _FORCE_INTERPRET[0]
 
 
-def kernel_viable(num_heads, head_dim, block_size, dtype):
+def kernel_viable(num_heads, head_dim, block_size, dtype, rot_dim=0):
     """Shape/dtype/backend guard (the ``_use_pallas`` discipline).
     Static facts only, so the engine can resolve the active decode
-    layout once at build time and bind it to the roofline."""
+    layout once at build time and bind it to the roofline. ``rot_dim``:
+    lanes of the key beyond ``head_dim``, kept in a transposed pool of
+    their own (module docstring)."""
     dtype = jnp.dtype(dtype)
     if dtype not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16),
                      jnp.dtype(jnp.float16)):
@@ -93,33 +107,50 @@ def kernel_viable(num_heads, head_dim, block_size, dtype):
     # such a slice only where hd fills the lanes; a chunk holds at least
     # one block
     sub = 8 if dtype == jnp.dtype(jnp.float32) else 16
+    # the transposed part: its width on the sublanes, a block's
+    # positions whole lane tiles (the planes sit side by side on the
+    # lanes of the scores)
+    if rot_dim and (rot_dim % sub or block_size % 128):
+        return False
     return (block_size % sub == 0 and head_dim % 128 == 0
             and blocks_per_chunk(num_heads, head_dim, block_size, 1,
-                                 dtype) == 1)
+                                 dtype, rot_dim) == 1)
 
 
-def blocks_per_chunk(num_heads, head_dim, block_size, max_blocks, dtype):
-    """``G``: how many blocks one chunk holds. As many as keep the four
-    chunk buffers (K and V, two halves each) inside the budget, at most
-    a slot's capacity; 0 where not even one block fits."""
-    block = num_heads * block_size * head_dim * jnp.dtype(dtype).itemsize
-    return int(min(max_blocks, _CHUNK_VMEM_BYTES // (4 * block)))
+def blocks_per_chunk(num_heads, head_dim, block_size, max_blocks, dtype,
+                     rot_dim=0):
+    """``G``: how many blocks one chunk holds. As many as keep the
+    chunk buffers (K and V, and a key's second part, two halves each)
+    inside the budget, at most a slot's capacity; 0 where not even one
+    block fits."""
+    row = 2 * head_dim + rot_dim
+    block = num_heads * block_size * row * jnp.dtype(dtype).itemsize
+    return int(min(max_blocks, _CHUNK_VMEM_BYTES // (2 * block)))
 
 
-def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         kbuf, vbuf, q_rows, acc_ref, m_ref, l_ref, sem,
-                         half_ref, *, block_size, group, q_group=1):
+def _paged_decode_kernel(bt_ref, len_ref, *refs, block_size, group,
+                         q_group=1, rot=False):
     """Grid (S,), sequential. ``kbuf``/``vbuf`` ``[2, nh, G*BS, hd]``
     are the two halves of the chunk buffers, ``sem[0/1, half]`` the K/V
     copies' semaphores, ``half_ref`` (SMEM) the half that holds this
-    slot's first chunk: the previous grid step started its copies."""
+    slot's first chunk: the previous grid step started its copies.
+    With ``rot`` the key's second part rides along: ``q2_ref`` (the
+    queries' lanes for it, laid out like ``q_ref``), its pool ``k2_hbm``
+    and ``k2buf [2, G, nh, d2, BS]``, copies on ``sem[2, half]``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    if rot:
+        (q_ref, k_hbm, v_hbm, q2_ref, k2_hbm, o_ref, kbuf, vbuf, q_rows,
+         acc_ref, m_ref, l_ref, sem, half_ref, k2buf) = refs
+    else:
+        (q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, q_rows, acc_ref, m_ref,
+         l_ref, sem, half_ref) = refs
     BS, G, MB = block_size, group, bt_ref.shape[1]
     T = G * BS
     si = pl.program_id(0)
     num_slots = pl.num_programs(0)
     nh, rows, hd = q_rows.shape
+    width = hd + (k2buf.shape[3] if rot else 0)   # the whole key's
     split = kbuf.dtype != jnp.float32   # 16-bit pool: p as upper + rest
 
     def live_blocks(s):
@@ -135,6 +166,9 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                 k_hbm.at[blk], kbuf.at[half, :, dst, :], sem.at[0, half]))
             go(pltpu.make_async_copy(
                 v_hbm.at[blk], vbuf.at[half, :, dst, :], sem.at[1, half]))
+            if rot:
+                go(pltpu.make_async_copy(
+                    k2_hbm.at[blk], k2buf.at[half, g], sem.at[2, half]))
             return carry
         jax.lax.fori_loop(
             0, jnp.clip(live_blocks(s) - c * G, 0, G), block, 0)
@@ -177,7 +211,14 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         s = jax.lax.dot_general(q_rows[...], kbuf[half], nt,
                                 precision=prec,
                                 preferred_element_type=jnp.float32)
-        s = s / jnp.sqrt(jnp.float32(hd))             # [nh, R, T]
+        if rot:
+            q2 = q2_ref[si]                           # [nh, R, d2]
+            s = s + jnp.concatenate(
+                [jax.lax.dot_general(
+                    q2, k2buf[half, g], nn, precision=prec,
+                    preferred_element_type=jnp.float32)
+                 for g in range(G)], axis=2)
+        s = s / jnp.sqrt(jnp.float32(width))          # [nh, R, T]
         kpos = c * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(kpos < length, s, jnp.float32(_NEG))
         m_prev = m_ref[...]                           # [nh, R, 1]
@@ -248,7 +289,8 @@ def _group_rows(q_group, dtype):
     return (heads if dtype == jnp.float32 else 2 * heads), heads
 
 
-def _paged_decode_32(q, k_cache, v_cache, block_tables, lengths):
+def _paged_decode_32(q, k_cache, v_cache, block_tables, lengths,
+                     q_rot=None, k_rot=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     S, nq, hd = q.shape
@@ -256,18 +298,25 @@ def _paged_decode_32(q, k_cache, v_cache, block_tables, lengths):
     MB = block_tables.shape[1]
     dtype = k_cache.dtype
     q_group = nq // nh
-    G = blocks_per_chunk(nh, hd, BS, MB, dtype)
+    rot = k_rot is not None
+    d2 = k_rot.shape[2] if rot else 0
+    if rot and q_group == 1:
+        raise NotImplementedError(
+            "a key in two parts is brought for grouped queries only")
+    G = blocks_per_chunk(nh, hd, BS, MB, dtype, d2)
     # the operand tile's sublanes: 8 of f32, 16 of a 16-bit type (whose
     # two halves carry the softmax weights' two parts)
     rows, heads = _group_rows(q_group, dtype)
     block_tables = block_tables.astype(jnp.int32)
     lengths = lengths.astype(jnp.int32)
-    if q_group > 1:
-        # [S, nh, rows, hd]: a KV head's query heads as the tile's rows
-        q = q.reshape(S, nh, q_group, hd)
+    def group_tile(q):
+        # [S, nh, rows, .]: a KV head's query heads as the tile's rows
+        q = q.reshape(S, nh, q_group, q.shape[-1])
         q = jnp.pad(q, ((0, 0), (0, 0), (0, heads - q_group), (0, 0)))
-        if rows > heads:
-            q = jnp.concatenate([q, q], axis=2)
+        return jnp.concatenate([q, q], axis=2) if rows > heads else q
+
+    if q_group > 1:
+        q = group_tile(q)
         o_shape = (S, nh, heads, hd)
     else:
         o_shape = (S, nh, hd)
@@ -278,28 +327,38 @@ def _paged_decode_32(q, k_cache, v_cache, block_tables, lengths):
         # most of a step that has little or nothing live)
         return (0,) * len(o_shape)
 
+    operands = [q, k_cache, v_cache]
+    in_specs = [
+        pl.BlockSpec(q.shape, whole),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    scratch = [
+        pltpu.VMEM((2, nh, G * BS, hd), dtype),
+        pltpu.VMEM((2, nh, G * BS, hd), dtype),
+        pltpu.VMEM((nh, rows, hd), dtype),
+        pltpu.VMEM((nh, rows, hd), jnp.float32),
+        pltpu.VMEM((nh, rows, 1), jnp.float32),
+        pltpu.VMEM((nh, rows, 1), jnp.float32),
+        pltpu.SemaphoreType.DMA((3 if rot else 2, 2)),
+        pltpu.SMEM((1,), jnp.int32),
+    ]
+    kernel = functools.partial(_paged_decode_kernel, block_size=BS,
+                               group=G, q_group=q_group)
+    if rot:
+        q2 = group_tile(q_rot)
+        operands += [q2, k_rot]
+        in_specs += [pl.BlockSpec(q2.shape, whole),
+                     pl.BlockSpec(memory_space=pl.ANY)]
+        scratch.append(pltpu.VMEM((2, G, nh, d2, BS), dtype))
+        kernel = functools.partial(kernel, rot=True)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(S,),
-        in_specs=[
-            pl.BlockSpec(q.shape, whole),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec(o_shape, whole),
-        scratch_shapes=[
-            pltpu.VMEM((2, nh, G * BS, hd), dtype),
-            pltpu.VMEM((2, nh, G * BS, hd), dtype),
-            pltpu.VMEM((nh, rows, hd), dtype),
-            pltpu.VMEM((nh, rows, hd), jnp.float32),
-            pltpu.VMEM((nh, rows, 1), jnp.float32),
-            pltpu.VMEM((nh, rows, 1), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SMEM((1,), jnp.int32),
-        ],
+        scratch_shapes=scratch,
     )
-    kernel = functools.partial(_paged_decode_kernel, block_size=BS,
-                               group=G, q_group=q_group)
     o = pl.pallas_call(
         kernel, name="paged_decode_attn", grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(o_shape, q.dtype),
@@ -307,19 +366,23 @@ def _paged_decode_32(q, k_cache, v_cache, block_tables, lengths):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
-    )(block_tables, lengths, q, k_cache, v_cache)
+    )(block_tables, lengths, *operands)
     if q_group > 1:
         o = o[:, :, :q_group].reshape(S, nq, hd)
     return o
 
 
-def paged_decode_attention(q, k_cache, v_cache, block_tables, lengths):
+def paged_decode_attention(q, k_cache, v_cache, block_tables, lengths,
+                           q_rot=None, k_rot=None):
     """Drop-in for ``ops.attention.cached_paged_attention`` (same
     signature, same numbers for every slot with something live) reading
     the live K/V blocks in place; a slot of length 0 gets a row of
     zeros where the oracle averages garbage, and nobody reads either.
+    ``q_rot [S, nq, d2]``, ``k_rot [num_blocks, nh, d2, BS]``: the
+    second part of a key wider than its value (module docstring).
     Callers check ``kernel_viable`` first; ``cached_paged_attention`` is
     the parity oracle."""
     # x64 guard shared by every Pallas entry point (pallas_compat)
+    second = () if k_rot is None else (q_rot, k_rot)
     return _trace_32bit(_paged_decode_32)(q, k_cache, v_cache,
-                                          block_tables, lengths)
+                                          block_tables, lengths, *second)

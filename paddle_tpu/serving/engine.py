@@ -485,6 +485,11 @@ class ServingEngine:
         # its own counts, never read back) to hand out and take back
         # blocks and to dispatch a finished window's compaction
         self._window = self.cache_spec.window
+        # rings beside the blocks (CacheSpec.ring): the same count of
+        # positions feeds the gauges of what the rings save
+        self._ring = self.cache_spec.ring
+        self._counts_positions = self._window is not None \
+            or self._ring is not None
         self._hpos = {}   # slot -> the position its next step writes
         widest = cache_len
         self.chunk_len = config.prefill_chunk
@@ -599,8 +604,9 @@ class ServingEngine:
         self.scheduler = StepScheduler(
             buckets, cache_len, completed_keep=config.completed_keep,
             flight=self.flight, policy=self._policy,
-            chunked_beyond=None if self._window is None
-            else self.chunk_len)
+            # a prompt beyond the chunk is prefilled by chunks of the
+            # chunk's bucket and needs no bucket of its own
+            chunked_beyond=self.chunk_len)
         self.metrics = ServingMetrics(
             slo_ttft_ms=config.slo_ttft_ms,
             slo_tpot_ms=config.slo_tpot_ms,
@@ -803,6 +809,11 @@ class ServingEngine:
             self.cache_spec.bytes_per_slot)
         if self._window is not None:
             self.metrics.enable_entry_cache()
+        if self._ring is not None:
+            self.metrics.enable_ring_cache(
+                self.cache_spec.bytes_per_token,
+                self.cache_spec.bytes_per_slot,
+                self.cache_spec.dense_bytes_per_token)
         moe = getattr(model, "moe_counter_layout", None)
         if moe is not None:
             self.metrics.set_moe_counters(
@@ -1951,6 +1962,8 @@ class ServingEngine:
                 self._toks = nxt
                 if self._window is not None:
                     self._after_decode(snapshot)
+                elif self._ring is not None:
+                    self._count_ring_cache(snapshot)
                 M.decode_steps += 1
                 self._decode_fail_streak = 0
                 if use_spec:
@@ -2185,7 +2198,7 @@ class ServingEngine:
                 raise
             pool.rebind(*arrs)
             pool.commit_prefix(alloc.slot, ids)
-            if self._window is not None:
+            if self._counts_positions:
                 self._prefilled(alloc.slot, start, tail)
             M.record_admission(req)
             self._stamp_prefill(req, t_disp, bucket)
@@ -2299,7 +2312,7 @@ class ServingEngine:
                     return   # rolled back (all chunk progress voided;
                 raise        # the retry re-plans from the queue)
             pool.rebind(*arrs)
-            if self._window is not None:
+            if self._counts_positions:
                 self._prefilled(plan.slot, start, clen, final)
             M.record_prefill_chunk(clen)
             M.record_prefill_arm(start)
@@ -2327,7 +2340,7 @@ class ServingEngine:
         """A prefill run of ``length`` positions from window boundary
         ``start`` went out for ``slot``: a run that filled its window
         left it compacted; the final run sets where decode goes on."""
-        if length == self._window[0]:
+        if self._window is not None and length == self._window[0]:
             self.metrics.record_compaction(0)
         if final:
             self._hpos[slot] = start + length
@@ -2368,6 +2381,17 @@ class ServingEngine:
             positions += t
             entries += spec.entries(t)
         M.set_cache_live(entries, positions)
+
+    def _count_ring_cache(self, snapshot):
+        """After a decode dispatch of a model with rings beside its
+        blocks: every slot it advanced is one position on; the gauges
+        count what those positions hold and what they would."""
+        hpos = self._hpos
+        positions = 0
+        for slot in snapshot:
+            t = hpos[slot] = hpos[slot] + 1
+            positions += t
+        self.metrics.set_ring_cache_live(positions, len(snapshot))
 
     # ------------------------------------------------------ resilience
 

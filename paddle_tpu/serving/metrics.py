@@ -305,6 +305,7 @@ class ServingMetrics:
         self.kv_donation = {"enabled": False, "effective": False}
         self._moe = None   # set_moe_counters
         self._g_cache_entries = None   # enable_entry_cache
+        self._g_cache_live_bytes = None    # enable_ring_cache
         self._t_first_work = None
         self._t_last_work = None
 
@@ -485,6 +486,38 @@ class ServingMetrics:
                 "positions_live": int(self._g_cache_positions.value),
                 "compactions": int(self._c_compactions.value),
                 "blocks_released": int(self._c_blocks_released.value)}
+
+    def enable_ring_cache(self, bytes_per_token, bytes_per_slot,
+                          dense_bytes_per_token):
+        """A cache in which some layers keep a RING a slot and not an
+        entry a position (``CacheSpec.ring``): what the decoding slots'
+        positions hold, and what the same positions would hold if every
+        layer kept each of them, from the step loop's own counts."""
+        r = self.registry
+        self._ring_bytes = (int(bytes_per_token), int(bytes_per_slot),
+                            int(dense_bytes_per_token))
+        self._g_cache_live_bytes = r.gauge(
+            "serving_cache_live_bytes",
+            "bytes the decoding slots hold: their positions in the "
+            "layers that keep every one + their rings in the others")
+        self._g_cache_dense_bytes = r.gauge(
+            "serving_cache_full_equiv_bytes",
+            "bytes the decoding slots' positions would hold if every "
+            "layer kept each of them")
+
+    def set_ring_cache_live(self, positions, slots):
+        per_token, per_slot, dense = self._ring_bytes
+        self._g_cache_live_bytes.set(
+            float(positions * per_token + slots * per_slot))
+        self._g_cache_dense_bytes.set(float(positions * dense))
+
+    def ring_cache_report(self):
+        """The two gauges above, or None for a model without rings."""
+        if self._g_cache_live_bytes is None:
+            return None
+        return {"cache_live_bytes": int(self._g_cache_live_bytes.value),
+                "cache_full_equiv_bytes":
+                    int(self._g_cache_dense_bytes.value)}
 
     def set_moe_counters(self, read_fn, layers, first, count):
         """Expert-routing counters that the decode program keeps ON THE
@@ -1000,4 +1033,6 @@ class ServingMetrics:
             # only a model whose cache entries are not positions
             **({"cache_entries": self.entry_cache_report()}
                if self._g_cache_entries is not None else {}),
+            **({"cache_rings": self.ring_cache_report()}
+               if self._g_cache_live_bytes is not None else {}),
         }

@@ -47,10 +47,17 @@ class CacheSpec:
     counted in entries, a block is REWRITTEN while its slot lives (not
     shareable) and the pool hands a slot its blocks as it grows and
     takes them back when a window is compacted (``PagedKVPool.grow``,
-    ``.shrink``)."""
+    ``.shrink``).
+
+    ``ring`` (``W``) says that the per-slot arrays are RINGS of ``W``
+    entries a layer: layers that see the last ``W`` positions only keep
+    position ``t`` at entry ``t % W`` and nothing else, beside the
+    layers whose token arrays keep every position. Nothing is
+    allocated differently for it; it tells the step loop to count what
+    the rings save (``dense_bytes_per_token``)."""
 
     def __init__(self, num_layers, arrays, state=(), slot=(),
-                 window=None):
+                 window=None, ring=None):
         import jax.numpy as jnp
         self.num_layers = int(num_layers)
         token = tuple(
@@ -67,6 +74,10 @@ class CacheSpec:
         self.state = tuple((str(n), tuple(int(d) for d in sh),
                             jnp.dtype(dt)) for n, sh, dt in state)
         self.num_slots = None
+        self.ring = None if ring is None else int(ring)
+        if self.ring is not None and (self.ring < 1 or not per_slot):
+            raise ValueError(f"ring {ring!r}: needs per-slot arrays of "
+                             f"that many entries")
         self.window = None
         if window is not None:
             W, C = (int(d) for d in window)
@@ -142,6 +153,16 @@ class CacheSpec:
         whatever its sequence's length."""
         return sum(a.layers * int(np.prod(a.lead, dtype=np.int64))
                    * a.dtype.itemsize for a in self.slot_arrays)
+
+    @property
+    def dense_bytes_per_token(self):
+        """Bytes a position would take over all layers if the layers
+        that keep a ring kept every position instead (a ring entry's
+        bytes a layer, as the token arrays' are); None without
+        ``ring``."""
+        if self.ring is None:
+            return None
+        return self.bytes_per_token + self.bytes_per_slot // self.ring
 
 
 def kv_pair_spec(num_layers, num_heads, head_dim, dtype):

@@ -176,8 +176,10 @@ class StepScheduler:
     def __init__(self, buckets, cache_len, completed_keep=4096,
                  flight=None, policy=None, chunked_beyond=None):
         self.buckets = sorted(int(b) for b in buckets)
-        # prompts longer than this are ALWAYS prefilled in chunks of it
-        # (a cache compacted a window at a time): they need no bucket
+        # an uncached tail longer than this is prefilled in chunks of it
+        # (the engine's chunk; the window of a cache compacted a window
+        # at a time): where the chunk itself fits a bucket, a prompt
+        # beyond it needs none of its own
         self.chunked_beyond = chunked_beyond
         self.cache_len = int(cache_len)
         if not self.buckets:
@@ -207,7 +209,8 @@ class StepScheduler:
 
     def submit(self, request):
         n = len(request.prompt)
-        if self.chunked_beyond is None or n <= self.chunked_beyond:
+        if self.chunked_beyond is None or n <= self.chunked_beyond \
+                or self.chunked_beyond > self.buckets[-1]:
             self.bucket_for(n)  # raises on oversized prompts
         if n + request.max_new_tokens > self.cache_len:
             raise ValueError(

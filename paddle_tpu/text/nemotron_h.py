@@ -57,8 +57,6 @@ Not brought by this module: training, sharding over a mesh, expert
 groups (``n_group > 1``), projection biases, a sliding window,
 speculative decoding, a disaggregated role, KV hand-off.
 """
-import re
-
 import jax
 import jax.numpy as jnp
 
@@ -67,27 +65,10 @@ from ..ops import attention as attn_ops
 from ..ops import moe_experts as moe_ops
 from ..ops import ssm as ssm_ops
 from .stacked_lm import (  # noqa: F401 - parts of this block
-    StackedCausalLM, count_routing, greedy_or_sampled, lm_head, rms_norm)
+    StackedCausalLM, count_routing, greedy_or_sampled, layer_plan, lm_head,
+    rms_norm, take_layer as _take)
 
 KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
-
-
-def layer_plan(pattern):
-    """The pattern as runs ``[(unit, repeats), ...]``: at each position
-    the repeated unit (up to 4 letters) that covers most layers, or the
-    single layer."""
-    plan, i = [], 0
-    while i < len(pattern):
-        best = (pattern[i], 1)
-        for u in range(1, 5):
-            unit = pattern[i:i + u]
-            reps = len(re.match(f"(?:{re.escape(unit)})*",
-                                pattern[i:]).group(0)) // u
-            if reps > 1 and u * reps > len(best[0]) * best[1]:
-                best = (unit, reps)
-        plan.append(best)
-        i += len(best[0]) * best[1]
-    return plan
 
 
 class NemotronHConfig:
@@ -192,13 +173,6 @@ class NemotronHConfig:
 
 
 # ------------------------------------------------------------ the block
-def _take(tree, i):
-    """Layer ``i`` of a kind's stacked weights."""
-    return jax.tree_util.tree_map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
-        tree)
-
-
 def group_rms_norm(x, w, groups, eps):
     """RMS norm over each of ``groups`` equal runs of the last axis."""
     xf = x.astype(jnp.float32)

@@ -1,11 +1,12 @@
 """What the causal LMs that are built FOR SERVING share
-(``text.deepseek_v3``, ``text.nemotron_h``): parameters held stacked per
+(``text.deepseek_v3``, ``text.nemotron_h``, ``text.mimo_v2``): parameters held stacked per
 group in the serving dtype, exactly as the compiled programs take them,
 a ready tree of arrays adopted without a copy, a small cache of jitted
 eager programs, and the refusal by name of engine options the model has
 no program for.
 """
 import collections
+import re
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +37,32 @@ def count_routing(counts, layer, tokens):
         tokens, jnp.sum(tokens > 0, dtype=jnp.int32)[None],
         jnp.ones((1,), jnp.int32)])
     return counts.at[layer].add(row)
+
+
+def layer_plan(pattern):
+    """A pattern of one letter a layer as runs ``[(unit, repeats),
+    ...]``: at each position the repeated unit (up to 4 letters) that
+    covers most layers, or the single layer. A run is one ``lax.scan``
+    of a model's layer loop."""
+    plan, i = [], 0
+    while i < len(pattern):
+        best = (pattern[i], 1)
+        for u in range(1, 5):
+            unit = pattern[i:i + u]
+            reps = len(re.match(f"(?:{re.escape(unit)})*",
+                                pattern[i:]).group(0)) // u
+            if reps > 1 and u * reps > len(best[0]) * best[1]:
+                best = (unit, reps)
+        plan.append(best)
+        i += len(best[0]) * best[1]
+    return plan
+
+
+def take_layer(tree, i):
+    """Layer ``i`` of a kind's stacked weights."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+        tree)
 
 
 def _init_leaf(key, shape, kind, dtype, std):

@@ -836,3 +836,115 @@ def test_eva_compact_and_prefill_programs_update_the_pool_in_place(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= nbytes
     assert mem.temp_size_in_bytes < 1 << 30, mem.temp_size_in_bytes
+
+
+# -------------- mimo_v2: blocks in the full layers, rings in the window ones
+def _mixed_programs(one_chip):
+    """Both programs of the ``mimo_v2_flash_pp8ep16`` cell at its
+    configuration's widths and its sizes (48 slots x 28,672 positions,
+    blocks of 256), as ``tools/aot_compile_arch.py`` builds them."""
+    import json
+    import os
+    from paddle_tpu.serving.paged.mixed_programs import \
+        build_paged_mixed_fns
+    from paddle_tpu.text import mimo_v2
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "mimo_v2_flash_pp8ep16.json")) as f:
+        config = json.load(f)
+    sz = config["sizing"]
+    cfg = mimo_v2.MimoV2Config.from_hf(config, dtype="bfloat16")
+    S, BS = sz["num_slots"], sz["block_size"]
+    MB = sz["max_len"] // BS
+    NB = S * MB + 1
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        prefill, decode = build_paged_mixed_fns(cfg, S, BS, NB, MB)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dt),
+                                    sharding=one_chip)
+    params = {}
+    for path, (shape, _, dt) in mimo_v2.param_shapes(cfg).items():
+        node = params
+        for part in path[:-1]:
+            node = node.setdefault(part, {})
+        node[path[-1]] = sds(shape, dt)
+    spec = mimo_v2.mixed_cache_spec(cfg).with_slots(S)
+    pool = [sds(spec.shape(a, NB, BS), a.dtype) for a in spec.arrays]
+    state = [sds(shape, dt) for _, shape, dt in spec.state]
+    toks, pos = sds((S,), jnp.int32), sds((S,), jnp.int32)
+    nbytes = [int(np.prod(p.shape)) * p.dtype.itemsize for p in pool]
+    return (prefill, decode, params, pool, state, toks, pos, sz, NB, MB,
+            nbytes, sds)
+
+
+def test_two_part_key_paged_decode_compiles(one_chip, mosaic_backend):
+    """The kernel alone at the full layers' shape: 4 KV heads x 16 query
+    heads, keys of 128 + 64 transposed, values of 128, blocks of 256."""
+    S, nkv, BS, MB = 48, 4, 256, 112
+    assert paged_attention.kernel_viable(nkv, 128, BS, jnp.bfloat16, 64)
+    assert paged_attention.blocks_per_chunk(nkv, 128, BS, MB,
+                                            jnp.bfloat16, 64) == 1
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    NB = S * MB + 1
+    _compile(paged_attention.paged_decode_attention,
+             sds((S, 64, 128)), sds((NB, nkv, BS, 128)),
+             sds((NB, nkv, BS, 128)), sds((S, MB), jnp.int32),
+             sds((S,), jnp.int32), sds((S, 64, 64)),
+             sds((NB, nkv, 64, BS)))
+
+
+def test_mixed_decode_program_keeps_rings_and_no_copy_of_the_pool(
+        one_chip):
+    """The decode program of the cell: blocks and rings aliased onto the
+    results and carried through the layer loop in place, both kernels in
+    it; NOTHING of a window layer grows with ``max_len`` (no array with
+    the window layers' 8 KV heads has a block or position axis), and no
+    copy, slice or update of a pool-shaped array is left."""
+    (_, decode, params, pool, state, toks, pos, sz, NB, MB, nbytes,
+     sds) = _mixed_programs(one_chip)
+    n = len(pool)
+    compiled = jax.jit(
+        decode, donate_argnums=(2,) + tuple(range(4, 4 + n))).lower(
+        params, toks, pos, sds((sz["num_slots"], MB), jnp.int32), *pool,
+        *state).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(nbytes)
+    assert mem.temp_size_in_bytes < 256 << 20, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    for kernel in ("paged_decode_attn", "moe_experts_swiglu_decode"):
+        assert kernel in text
+    # every array that has an axis of the pool's (blocks, all layers'
+    # blocks, positions) is one of the full layers' three: 4 KV heads
+    grows = re.findall(
+        rf"= \w+\[([\d,]*\b(?:{NB}|{2 * NB}|{sz['max_len']})\b[\d,]*)\]",
+        text)
+    assert grows
+    full = {f"{lead},4,256,128" for lead in (f"2,{NB}", f"{2 * NB}")} \
+        | {f"{lead},4,64,256" for lead in (f"2,{NB}", f"{2 * NB}")}
+    assert set(grows) <= full, set(grows) - full
+    # the rings are there, at their size, whatever max_len is
+    assert "bf16[4,48,8,192,128]" in text and "bf16[4,48,8,128,128]" in text
+    moving = ("copy", "dynamic-slice", "dynamic-update-slice")
+    bad = [(name, op) for name, op in _pool_shaped(compiled, sorted(full))
+           if op in moving or any(w in name for w in moving)]
+    assert not bad, bad
+
+
+def test_mixed_prefill_program_updates_the_pool_in_place(one_chip):
+    """The one prefill bucket of the cell: blocks and rings aliased,
+    temporaries (the walk's scores over one block of keys) under a GB."""
+    (prefill, _, params, pool, _, toks, pos, sz, _, MB, nbytes,
+     sds) = _mixed_programs(one_chip)
+    n = len(pool)
+    scalar = sds((), jnp.int32)
+    (bucket,) = sz["buckets"]
+    compiled = jax.jit(
+        prefill, donate_argnums=tuple(range(8, 9 + n))).lower(
+        params, sds((1, bucket), jnp.int32), scalar, scalar, scalar,
+        scalar, sds((MB,), jnp.int32), toks, pos, *pool).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(nbytes)
+    assert mem.temp_size_in_bytes < 1 << 30, mem.temp_size_in_bytes
