@@ -59,14 +59,47 @@ k_rot`` to the scores, which are scaled by the whole key's width. The
 same body: without the second part no ref, copy or product of it is
 traced, and a call's jaxpr is what it was before there was one.
 
+How the step's new entry is placed (``new=``, ``write_pos=``: what
+the decode programs call, ``paged_write_attention``). A slot's new
+entry sits at position ``lengths[s] - 1``, in its last live block,
+which the slot's LAST chunk brings into ``kbuf`` / ``vbuf`` (/
+``k2buf``) anyway. After that chunk's copies are waited for, the kernel
+selects the entry into the buffer (an ``iota ==`` select over the one
+tile that holds its row, in f32, exact for a 16-bit pool), runs the
+chunk's arithmetic on the buffer as for any other chunk, and copies the
+tile back to HBM; the pools are the call's aliased outputs
+(``input_output_aliases``), so a donated pool carried through a layer
+loop is updated in place and no gather, scatter or second read of the
+block is left in front of the kernel (PR 43: the ``jnp`` block
+read-modify-write moved 30-500 times the bytes that change, at 30-45 %
+of the bandwidth). A TILE and not a row: ``BS`` rides the sublanes, two
+rows of a 16-bit pool share a 32-bit word, and Mosaic copies whole
+tiles, so the write-back is the ``[nh, 16, hd]`` group of rows around
+the entry (8 rows of an f32 pool) and, for the transposed second part,
+where positions ride the lanes, the 128 lanes around it; its 64
+rotated values are turned from lanes onto sublanes by a ``diag(entry) x
+onehot`` matmul a head, one exact term a sum. The write-back is started
+before the chunk's arithmetic and waited for after it, before its half
+of the double buffer can be a copy's target again and before the grid
+step ends; the next slot's prefetched first chunk never holds the
+block, because a slot's last block is private (``pool.acquire`` asserts
+it). Only a LIVE entry is written (``write_pos[s] == lengths[s] - 1``,
+``live_write_pos``): a parked or released slot, whose stray row the
+``jnp`` write pins to the last entry of its table row or drops in the
+trash block, writes nothing, and a slot with nothing live copies
+nothing in either direction. Without ``new`` no ref, copy or select of
+the write is traced and the call is what it was.
+
 Where it runs: ``kernel_viable`` (backend has Mosaic, shapes tile) is
 the only gate; ``ServingEngine`` asks it once at build time and the
-GPT's paged decode program uses the kernel wherever it says yes. The
-CPU and refused shapes keep ``cached_paged_attention``, which is also
-the parity oracle; tests flip ``_FORCE_INTERPRET`` to run the real
-kernel in interpret mode on the CPU.
+paged decode programs use the kernel wherever it says yes. The
+CPU and refused shapes keep ``write_block_rows`` in front of
+``cached_paged_attention``, which is also the parity oracle; tests
+flip ``_FORCE_INTERPRET`` to run the real kernel in interpret mode on
+the CPU.
 """
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -129,22 +162,37 @@ def blocks_per_chunk(num_heads, head_dim, block_size, max_blocks, dtype,
 
 
 def _paged_decode_kernel(bt_ref, len_ref, *refs, block_size, group,
-                         q_group=1, rot=False):
+                         q_group=1, rot=False, write=False):
     """Grid (S,), sequential. ``kbuf``/``vbuf`` ``[2, nh, G*BS, hd]``
     are the two halves of the chunk buffers, ``sem[0/1, half]`` the K/V
     copies' semaphores, ``half_ref`` (SMEM) the half that holds this
     slot's first chunk: the previous grid step started its copies.
     With ``rot`` the key's second part rides along: ``q2_ref`` (the
     queries' lanes for it, laid out like ``q_ref``), its pool ``k2_hbm``
-    and ``k2buf [2, G, nh, d2, BS]``, copies on ``sem[2, half]``."""
+    and ``k2buf [2, G, nh, d2, BS]``, copies on ``sem[2, half]``.
+    With ``write`` the step's new entry is placed (module docstring):
+    ``wpos_ref`` (a third prefetched scalar a slot), ``nk_ref`` /
+    ``nv_ref`` ``[S, nh, hd]`` (/ ``nk2_ref [S, nh, d2]``) resident like
+    ``q_ref``, the pools the call's aliased OUTPUTS (read and written
+    through the one ref), the write-backs on ``wsem``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    if rot:
-        (q_ref, k_hbm, v_hbm, q2_ref, k2_hbm, o_ref, kbuf, vbuf, q_rows,
-         acc_ref, m_ref, l_ref, sem, half_ref, k2buf) = refs
-    else:
-        (q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, q_rows, acc_ref, m_ref,
-         l_ref, sem, half_ref) = refs
+    it = iter(refs)
+    wpos_ref = next(it) if write else None
+    q_ref, k_hbm, v_hbm = next(it), next(it), next(it)
+    q2_ref, k2_hbm = (next(it), next(it)) if rot else (None, None)
+    nk_ref, nv_ref = (next(it), next(it)) if write else (None, None)
+    nk2_ref = next(it) if write and rot else None
+    o_ref = next(it)
+    if write:
+        # the aliased outputs: the same HBM as the inputs, read and
+        # written through the one ref
+        k_hbm, v_hbm = next(it), next(it)
+        k2_hbm = next(it) if rot else None
+    kbuf, vbuf, q_rows, acc_ref, m_ref, l_ref, sem, half_ref = (
+        next(it) for _ in range(8))
+    k2buf = next(it) if rot else None
+    wsem = next(it) if write else None
     BS, G, MB = block_size, group, bt_ref.shape[1]
     T = G * BS
     si = pl.program_id(0)
@@ -179,6 +227,67 @@ def _paged_decode_kernel(bt_ref, len_ref, *refs, block_size, group,
     def wait_chunk(s, c, half):
         chunk_copies(s, c, half, lambda copy: copy.wait())
 
+    # the step's new entry (``write``): position length - 1, which the
+    # slot's LAST chunk holds at buffer row ``length - 1 - c * T``.
+    # Rows of a K/V tile, lanes of a transposed tile (whole ones on a
+    # chip: ``kernel_viable``; interpret mode takes smaller blocks)
+    sub = math.gcd(BS, 8 if kbuf.dtype == jnp.float32 else 16)
+    lane = math.gcd(BS, 128)
+
+    def entry_copies(c, half, go):
+        """``go`` (start or wait) the copies back to HBM of the tiles
+        of chunk c's buffers that hold this slot's new entry: the
+        ``sub`` rows around it of K and V, the 128 lanes around it of
+        the transposed part."""
+        p = len_ref[si] - 1
+        blk = bt_ref[si, p // BS]
+        src = pl.ds(pl.multiple_of((p - c * T) // sub * sub, sub), sub)
+        dst = pl.ds(pl.multiple_of(p % BS // sub * sub, sub), sub)
+        go(pltpu.make_async_copy(
+            kbuf.at[half, :, src, :], k_hbm.at[blk, :, dst, :], wsem.at[0]))
+        go(pltpu.make_async_copy(
+            vbuf.at[half, :, src, :], v_hbm.at[blk, :, dst, :], wsem.at[1]))
+        if rot:
+            lanes = pl.ds(pl.multiple_of(p % BS // lane * lane, lane), lane)
+            go(pltpu.make_async_copy(
+                k2buf.at[half, (p - c * T) // BS, :, :, lanes],
+                k2_hbm.at[blk, :, :, lanes], wsem.at[2]))
+
+    def place_entry(c, half):
+        """Select the new entry into chunk c's buffers (an ``iota ==``
+        select over the one tile that holds its row, via f32: exact for
+        a 16-bit pool) and start the tiles' way back."""
+        f32 = jnp.float32
+        r = len_ref[si] - 1 - c * T
+        at = pl.ds(pl.multiple_of(r // sub * sub, sub), sub)
+        hot = jax.lax.broadcasted_iota(
+            jnp.int32, (nh, sub, hd), 1) == r % sub
+        for buf, new_ref in ((kbuf, nk_ref), (vbuf, nv_ref)):
+            new = jnp.broadcast_to(
+                new_ref[si].astype(f32)[:, None, :], (nh, sub, hd))
+            buf[half, :, at, :] = jnp.where(
+                hot, new, buf[half, :, at, :].astype(f32)).astype(buf.dtype)
+        if rot:
+            # positions ride the lanes here and the entry's d2 values
+            # must ride the sublanes: diag(entry) x (ones on the
+            # entry's lane), one exact term a sum, is the column. A
+            # head at a time: plain 2-D tiles
+            g, d2 = r // BS, k2buf.shape[3]
+            eye = jax.lax.broadcasted_iota(jnp.int32, (d2, d2), 0) \
+                == jax.lax.broadcasted_iota(jnp.int32, (d2, d2), 1)
+            here = jax.lax.broadcasted_iota(
+                jnp.int32, (d2, BS), 1) == r % BS
+            ones = jnp.where(here, f32(1), f32(0)).astype(k2buf.dtype)
+            for n in range(nh):
+                diag = jnp.where(eye, jnp.broadcast_to(
+                    nk2_ref[si, n:n + 1, :], (d2, d2)), f32(0))
+                col = jnp.dot(diag.astype(k2buf.dtype), ones,
+                              precision=prec, preferred_element_type=f32)
+                k2buf[half, g, n] = jnp.where(
+                    here, col, k2buf[half, g, n].astype(f32)).astype(
+                        k2buf.dtype)
+        entry_copies(c, half, lambda copy: copy.start())
+
     @pl.when(si == 0)
     def _first():
         # what no copy has written yet must be finite behind its zero
@@ -195,6 +304,9 @@ def _paged_decode_kernel(bt_ref, len_ref, *refs, block_size, group,
     nt = (((2,), (2,)), ((0,), (0,)))      # [nh,R,hd] x [nh,T,hd]^T
     nn = (((2,), (1,)), ((0,), (0,)))      # [nh,R,T]  x [nh,T,hd]
     prec = None if split else jax.lax.Precision.HIGHEST
+    # only a LIVE entry is written: a parked or released slot's write
+    # position is not its last live one (module docstring)
+    placed = write and wpos_ref[si] == length - 1
 
     def chunk(c, carry):
         half = (half0 + c) % 2
@@ -208,6 +320,10 @@ def _paged_decode_kernel(bt_ref, len_ref, *refs, block_size, group,
             start_chunk(nxt, 0, 1 - half)
 
         wait_chunk(si, c, half)
+        if write:
+            @pl.when(jnp.logical_and(c + 1 == chunks, placed))
+            def _():
+                place_entry(c, half)
         s = jax.lax.dot_general(q_rows[...], kbuf[half], nt,
                                 precision=prec,
                                 preferred_element_type=jnp.float32)
@@ -257,6 +373,13 @@ def _paged_decode_kernel(bt_ref, len_ref, *refs, block_size, group,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
         jax.lax.fori_loop(0, chunks, chunk, 0)
+        if write:
+            # the tiles are in HBM before this half is a copy's target
+            # again, and before the grid step ends
+            @pl.when(placed)
+            def _():
+                entry_copies(chunks - 1, (half0 + chunks - 1) % 2,
+                             lambda copy: copy.wait())
         acc, l = acc_ref[...], l_ref[...]
         if split:
             acc = acc[:, :rows // 2] + acc[:, rows // 2:]
@@ -290,7 +413,7 @@ def _group_rows(q_group, dtype):
 
 
 def _paged_decode_32(q, k_cache, v_cache, block_tables, lengths,
-                     q_rot=None, k_rot=None):
+                     q_rot=None, k_rot=None, new=None, write_pos=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     S, nq, hd = q.shape
@@ -299,6 +422,7 @@ def _paged_decode_32(q, k_cache, v_cache, block_tables, lengths,
     dtype = k_cache.dtype
     q_group = nq // nh
     rot = k_rot is not None
+    write = new is not None
     d2 = k_rot.shape[2] if rot else 0
     if rot and q_group == 1:
         raise NotImplementedError(
@@ -307,8 +431,10 @@ def _paged_decode_32(q, k_cache, v_cache, block_tables, lengths,
     # the operand tile's sublanes: 8 of f32, 16 of a 16-bit type (whose
     # two halves carry the softmax weights' two parts)
     rows, heads = _group_rows(q_group, dtype)
-    block_tables = block_tables.astype(jnp.int32)
-    lengths = lengths.astype(jnp.int32)
+    scalars = [block_tables.astype(jnp.int32), lengths.astype(jnp.int32)]
+    if write:
+        scalars.append(write_pos.astype(jnp.int32))
+
     def group_tile(q):
         # [S, nh, rows, .]: a KV head's query heads as the tile's rows
         q = q.reshape(S, nh, q_group, q.shape[-1])
@@ -321,18 +447,17 @@ def _paged_decode_32(q, k_cache, v_cache, block_tables, lengths,
     else:
         o_shape = (S, nh, hd)
 
-    def whole(si, bt_ref, len_ref):
-        # q and o stay in VMEM for the whole call (a block a grid step
-        # would put two small copies' latency into every step, which is
-        # most of a step that has little or nothing live)
-        return (0,) * len(o_shape)
+    def resident(shape):
+        # q, o and the new entry stay in VMEM for the whole call (a
+        # block a grid step would put two small copies' latency into
+        # every step, which is most of a step that has little or
+        # nothing live)
+        return pl.BlockSpec(shape, lambda si, *scalar_refs:
+                            (0,) * len(shape))
 
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     operands = [q, k_cache, v_cache]
-    in_specs = [
-        pl.BlockSpec(q.shape, whole),
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
+    in_specs = [resident(q.shape), in_hbm, in_hbm]
     scratch = [
         pltpu.VMEM((2, nh, G * BS, hd), dtype),
         pltpu.VMEM((2, nh, G * BS, hd), dtype),
@@ -344,45 +469,145 @@ def _paged_decode_32(q, k_cache, v_cache, block_tables, lengths,
         pltpu.SMEM((1,), jnp.int32),
     ]
     kernel = functools.partial(_paged_decode_kernel, block_size=BS,
-                               group=G, q_group=q_group)
+                               group=G, q_group=q_group, rot=rot,
+                               write=write)
     if rot:
         q2 = group_tile(q_rot)
         operands += [q2, k_rot]
-        in_specs += [pl.BlockSpec(q2.shape, whole),
-                     pl.BlockSpec(memory_space=pl.ANY)]
+        in_specs += [resident(q2.shape), in_hbm]
         scratch.append(pltpu.VMEM((2, G, nh, d2, BS), dtype))
-        kernel = functools.partial(kernel, rot=True)
+    out_shape = [jax.ShapeDtypeStruct(o_shape, q.dtype)]
+    out_specs = [resident(o_shape)]
+    aliases = {}
+    if write:
+        # the pools come back, updated in place: operands 1, 2 (and 4)
+        # after the prefetched scalars, each aliased onto a result
+        for at in (1, 2, 4)[:2 + rot]:
+            aliases[len(scalars) + at] = len(out_shape)
+            out_shape.append(jax.ShapeDtypeStruct(
+                operands[at].shape, operands[at].dtype))
+            out_specs.append(in_hbm)
+        # the second part's entry in f32 (exact): the kernel reads it a
+        # row at a time, which a packed 16-bit tile does not give
+        new = list(new[:2]) + [a.astype(jnp.float32) for a in new[2:]]
+        operands += new
+        in_specs += [resident(a.shape) for a in new]
+        scratch.append(pltpu.SemaphoreType.DMA((2 + rot,)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(S,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(o_shape, whole),
+        out_specs=out_specs,
         scratch_shapes=scratch,
     )
-    o = pl.pallas_call(
+    o, *pools = pl.pallas_call(
         kernel, name="paged_decode_attn", grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(o_shape, q.dtype),
+        out_shape=out_shape, input_output_aliases=aliases,
         # sequential: a slot's last chunk starts the next slot's first
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
-    )(block_tables, lengths, *operands)
+    )(*scalars, *operands)
     if q_group > 1:
         o = o[:, :, :q_group].reshape(S, nq, hd)
-    return o
+    return (o, tuple(pools)) if write else o
 
 
 def paged_decode_attention(q, k_cache, v_cache, block_tables, lengths,
-                           q_rot=None, k_rot=None):
+                           q_rot=None, k_rot=None, new=None,
+                           write_pos=None):
     """Drop-in for ``ops.attention.cached_paged_attention`` (same
     signature, same numbers for every slot with something live) reading
     the live K/V blocks in place; a slot of length 0 gets a row of
     zeros where the oracle averages garbage, and nobody reads either.
     ``q_rot [S, nq, d2]``, ``k_rot [num_blocks, nh, d2, BS]``: the
     second part of a key wider than its value (module docstring).
+
+    With ``new`` the kernel places the step's new entry itself before
+    it attends (module docstring): ``new = (new_k, new_v)`` ``[S, nh,
+    hd]`` in the pools' dtype (and ``new_k_rot [S, nh, d2]`` third for a
+    key in two parts), ``write_pos [S]`` the position each slot's entry
+    goes to. It is written where that is the slot's last live position
+    (``lengths[s] - 1``: ``lengths`` counts it) and nowhere otherwise.
+    Returns ``(o, pools)``, the pools in the order given (``k_cache``,
+    ``v_cache``, then ``k_rot``), updated in place where the caller
+    donates them.
+
     Callers check ``kernel_viable`` first; ``cached_paged_attention`` is
     the parity oracle."""
     # x64 guard shared by every Pallas entry point (pallas_compat)
     second = () if k_rot is None else (q_rot, k_rot)
-    return _trace_32bit(_paged_decode_32)(q, k_cache, v_cache,
-                                          block_tables, lengths, *second)
+    return _trace_32bit(_paged_decode_32)(
+        q, k_cache, v_cache, block_tables, lengths, *second, new=new,
+        write_pos=write_pos)
+
+
+def write_block_rows(pool, new, blocks, offsets, axis=2):
+    """Put ``new[s]`` at row ``offsets[s]`` (along ``axis``, the block's
+    positions) of block ``blocks[s]`` of ``pool`` by whole-block
+    read-modify-write: gather the S blocks, select the row in, scatter
+    the blocks back. A scatter whose window is every trailing dimension
+    leaves the pool's layout row-major from parameter to result, so a
+    donated pool carried through a layer loop is updated in place (the
+    row scatter ``at[blocks, :, offsets]`` made XLA relayout the whole
+    pool around the loop: ISSUE 26). Safe because a decode step's write
+    blocks are private to their slots; the only duplicates are parked
+    or released slots meeting in the trash block, where any winner is
+    garbage behind the length mask."""
+    hot = jnp.arange(pool.shape[axis], dtype=jnp.int32) \
+        == offsets[:, None]                                   # [S, BS]
+    hot = jnp.expand_dims(hot, [a for a in (1, 2, 3) if a != axis])
+    return pool.at[blocks].set(jnp.where(
+        hot, jnp.expand_dims(new.astype(pool.dtype), axis), pool[blocks]))
+
+
+def live_write_pos(pos, lengths):
+    """Where each slot's new entry goes: ``pos[s]`` where that is the
+    slot's last live position (``lengths[s] - 1``: ``lengths`` counts
+    the entry), -1 where it is not. A parked slot (its position past
+    what its row holds) and a released one (nothing live) write
+    nothing anybody reads."""
+    return jnp.where(pos + 1 == lengths.astype(jnp.int32), pos,
+                     jnp.int32(-1)).astype(jnp.int32)
+
+
+def paged_write_attention(q, new, pools, block_tables, write_pos, lengths,
+                          kernel, q_rot=None):
+    """A decode step's cache write and attention, what every paged
+    decode program does with its pools: the new entry of each slot
+    (``new = (k, v)`` ``[S, nh, hd]``, and ``k_rot [S, nh, d2]`` third
+    for a key in two parts) goes to position ``write_pos[s]``
+    (``live_write_pos``) of the slot's table row in ``pools =
+    (k_cache, v_cache[, k_rot])``, then q attends over the ``lengths``
+    live positions. Returns ``(o, pools)``.
+
+    ``kernel`` (the engine's choice, ``kernel_viable``): the Pallas
+    kernel, which places the entry itself and writes nothing for -1.
+    Otherwise the ``jnp`` block write, then ``cached_paged_attention``,
+    the parity oracle. There every slot writes, and -1 goes to the
+    row's last entry AS A WHOLE (column MB-1 AND offset BS-1): private
+    or trash, and behind the length mask either way. A position counting
+    on past the row, clamped by its column alone, would spray a parked
+    slot's stray entry over block MB-1 as ``pos % BS`` cycles."""
+    from ..profiler import device_scope
+    from . import attention as attn_ops
+    second = () if q_rot is None else (q_rot, pools[2])
+    if kernel:
+        return paged_decode_attention(
+            q, pools[0], pools[1], block_tables, lengths, *second,
+            new=new, write_pos=write_pos)
+    BS = pools[0].shape[2]
+    with device_scope("kv_write"):
+        wpos = jnp.where(write_pos < 0,
+                         jnp.int32(block_tables.shape[1] * BS - 1),
+                         write_pos)
+        blocks = jnp.take_along_axis(
+            block_tables, (wpos // jnp.int32(BS))[:, None], axis=1)[:, 0]
+        off = wpos % jnp.int32(BS)
+        # the transposed part's positions are its last axis
+        pools = tuple(write_block_rows(pool, entry, blocks, off,
+                                       axis=3 if i == 2 else 2)
+                      for i, (pool, entry) in enumerate(zip(pools, new)))
+    second = () if q_rot is None else (q_rot, pools[2])
+    return attn_ops.cached_paged_attention(
+        q, pools[0], pools[1], block_tables, lengths, *second), pools

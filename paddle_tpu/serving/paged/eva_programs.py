@@ -21,10 +21,12 @@ window; position ``t`` is written at entry ``S (t // W) + t % W``.
   ``paged_decode(params, toks [S], pos [S], tables [S, MB], k, v
                  [, samp...]) -> (next [S], pos + 1, k, v)``
       One position a slot: the pool rides the layer loop flat (``[L*NB,
-      H, BS, d]``), each slot's current block is read, given its new
-      row and written back whole, and attention is ordinary attention
-      over the slot's entries so far (``ops.paged_attention``, the
-      GPT's kernel, with ``lengths`` = entries).
+      H, BS, d]``), each slot's new entry is placed and attention is
+      ordinary attention over the slot's entries so far
+      (``ops.paged_attention.paged_write_attention``, what the GPT's
+      program calls, with ``lengths`` = entries: on a chip the kernel
+      places the entry itself, elsewhere the slot's current block is
+      read, given its new row and written back whole).
 
   ``paged_compact(params, window, bt_row [MB], k, v) -> (k, v)``
       One slot's finished window ``window``: its ``W`` raw entries
@@ -40,8 +42,9 @@ window; position ``t`` is written at entry ``S (t // W) + t % W``.
 
 Parked and released slots: a slot parked between the chunks of its
 prefill sits at the model's last position, whose entry (clamped to the
-row's last) no live sequence uses; free rows point at the trash block;
-``lengths`` never exceeds what the row's blocks hold.
+row's last) no live sequence uses and is written nowhere a length mask
+shows (``ops.paged_attention.live_write_pos``); free rows point at the
+trash block; ``lengths`` never exceeds what the row's blocks hold.
 """
 from ...profiler import device_scope
 
@@ -110,35 +113,27 @@ class PagedAccess:
         import jax
         import jax.numpy as jnp
 
-        from ...ops import attention as attn_ops
         from ...ops import paged_attention as paged_ops
         from .pool import TRASH_BLOCK
         cfg = self.cfg
         kf, vf = state
         BS, C = self.BS, self.MB * self.BS
-        base = layer * jnp.int32(self.NB)
+        tables = self.tables + layer * jnp.int32(self.NB)
         # a position past the model's last (a parked slot, counting on)
-        # stays there; its entry is clamped as a whole (programs.py)
+        # stays there, and so does its entry
         entry = jnp.minimum(cfg.entries(jnp.minimum(
             pos, jnp.int32(cfg.max_seq_len - 1))), jnp.int32(C - 1))
-        bidx = jnp.take_along_axis(
-            self.tables, (entry // jnp.int32(BS))[:, None], axis=1)[:, 0]
-        row = (jnp.arange(BS, dtype=jnp.int32)[None, :]
-               == (entry % jnp.int32(BS))[:, None])[:, None, :, None]
-        fb = base + bidx
-        with device_scope("kv_write"):
-            kf = kf.at[fb].set(jnp.where(
-                row, k.astype(kf.dtype)[:, :, None], kf[fb]))
-            vf = vf.at[fb].set(jnp.where(
-                row, v.astype(vf.dtype)[:, :, None], vf[fb]))
         # what attention may read of a slot: its ENTRIES so far, never
         # more than the blocks its row holds (a released slot: nothing)
         held = jnp.sum((self.tables != TRASH_BLOCK).astype(jnp.int32),
                        axis=1)
         lengths = jnp.minimum(entry + 1, held * jnp.int32(BS))
-        fn = paged_ops.paged_decode_attention if kernel \
-            else attn_ops.cached_paged_attention
-        return (kf, vf), fn(q, kf, vf, self.tables + base, lengths)
+        with device_scope("kv_write"):
+            new = (k.astype(kf.dtype), v.astype(vf.dtype))
+            wpos = paged_ops.live_write_pos(entry, lengths)
+        o, pools = paged_ops.paged_write_attention(
+            q, new, (kf, vf), tables, wpos, lengths, kernel)
+        return pools, o
 
 
 def decode_kernel(cfg, block_size):

@@ -28,18 +28,21 @@ convention asks of a program.
                  ssm, moe_counts[, samp...])
       -> (next [S], pos + 1, k, v, conv, ssm, moe_counts')``
       One token a slot. Keys and values ride the layer loop flat
-      (``[La*NB, nkv, BS, hd]``), each slot's current block is read,
-      given its new row and written back whole, and attention reads the
-      LIVE blocks in place through ``tables + layer*NB``. The recurrent
+      (``[La*NB, nkv, BS, hd]``); the new row is placed and attention
+      reads the LIVE blocks in place through ``tables + layer*NB``
+      (``ops.paged_attention.paged_write_attention``: the kernel places
+      the row itself, the ``jnp`` path writes the slot's current block
+      back whole in front of the gather). The recurrent
       state rides it flat too (``[Lm*S, ...]``) and is updated in place
       by ``ops.ssm``'s kernel, a layer's rows a call.
 
-Parked and released slots: write positions are clamped to the row's
-last entry, free rows point at the trash block, the length mask hides
-what they hold (``programs.py``). A slot parked between the chunks of
-its prefill (``pos == C - 1``; a live sequence never feeds a token
-there) keeps its window and its state through the decode steps in
-between: its step size is set to zero.
+Parked and released slots: their entries are nobody's
+(``ops.paged_attention.live_write_pos``: the kernel writes none, the
+``jnp`` path pins them to the row's last entry), free rows point at the
+trash block, the length mask hides what they hold (``programs.py``). A
+slot parked between the chunks of its prefill (``pos == C - 1``; a live
+sequence never feeds a token there) keeps its window and its state
+through the decode steps in between: its step size is set to zero.
 """
 from ...profiler import device_scope
 
@@ -107,33 +110,21 @@ class PagedAccess:
         import jax
         import jax.numpy as jnp
 
-        from ...ops import attention as attn_ops
         from ...ops import paged_attention as paged_ops
         from .pool import TRASH_BLOCK
         kf, vf, conv, ssm = state
-        BS, C = self.BS, self.MB * self.BS
-        base = li * jnp.int32(self.NB)
-        # the WRITE position is clamped as a whole (programs.py)
-        wpos = jnp.minimum(pos, jnp.int32(C - 1))
-        bidx = jnp.take_along_axis(
-            self.tables, (wpos // jnp.int32(BS))[:, None], axis=1)[:, 0]
-        row = (jnp.arange(BS, dtype=jnp.int32)[None, :]
-               == (wpos % jnp.int32(BS))[:, None])[:, None, :, None]
-        fb = base + bidx
-        with device_scope("kv_write"):
-            kf = kf.at[fb].set(jnp.where(
-                row, k.astype(kf.dtype)[:, :, None], kf[fb]))
-            vf = vf.at[fb].set(jnp.where(
-                row, v.astype(vf.dtype)[:, :, None], vf[fb]))
         # what attention may read of a slot: its positions so far, never
         # more than the blocks its row holds (a released slot: nothing)
         held = jnp.sum((self.tables != TRASH_BLOCK).astype(jnp.int32),
                        axis=1)
-        lengths = jnp.minimum(pos + 1, held * jnp.int32(BS))
-        fn = paged_ops.paged_decode_attention if kernel \
-            else attn_ops.cached_paged_attention
-        return (kf, vf, conv, ssm), fn(q, kf, vf, self.tables + base,
-                                       lengths)
+        lengths = jnp.minimum(pos + 1, held * jnp.int32(self.BS))
+        with device_scope("kv_write"):
+            new = (k.astype(kf.dtype), v.astype(vf.dtype))
+            wpos = paged_ops.live_write_pos(pos, lengths)
+        o, (kf, vf) = paged_ops.paged_write_attention(
+            q, new, (kf, vf), self.tables + li * jnp.int32(self.NB), wpos,
+            lengths, kernel)
+        return (kf, vf, conv, ssm), o
 
     def ssm_decode(self, state, mi, pos, u, dt, A, conv_w, conv_b, kernel):
         import jax.numpy as jnp

@@ -38,16 +38,20 @@ is ring entry ``t % W``.
   ``paged_decode(params, toks [S], pos [S], tables [S, MB], k, kr, v,
                  kring, vring, moe_counts[, samp...])
       -> (next [S], pos + 1, k, kr, v, kring, vring, moe_counts')``
-      One token a slot. A full layer: block write, then
-      ``ops.paged_attention`` over the LIVE blocks in place through
-      ``tables + layer*NB`` (key in two parts). A window layer: the new
-      key and value into entry ``pos % W`` of the slot's ring, then
-      attention over the ring's ``W`` entries and the sink: what it
-      reads and keeps is the ring, whatever the position.
+      One token a slot. A full layer: the new entry into all three
+      pools and attention over the LIVE blocks in place through
+      ``tables + layer*NB`` (key in two parts), both by
+      ``ops.paged_attention.paged_write_attention`` (the kernel places
+      the entry itself; the ``jnp`` path writes blocks first). A window
+      layer: the new key and value into entry ``pos % W`` of the slot's
+      ring, then attention over the ring's ``W`` entries and the sink:
+      what it reads and keeps is the ring, whatever the position.
 
-Parked and released slots: write positions are clamped to the row's
-last entry, free rows point at the trash block, the length mask hides
-what they hold (``programs.py``). A slot parked between the chunks of
+Parked and released slots: their entries are nobody's
+(``ops.paged_attention.live_write_pos``: the kernel writes none, the
+``jnp`` path pins them to the row's last entry), free rows point at the
+trash block, the length mask hides what they hold (``programs.py``). A
+slot parked between the chunks of
 its prefill (``pos == C - 1``; a live sequence never feeds a token
 there) keeps its ring through the decode steps in between: its write
 is masked.
@@ -221,35 +225,23 @@ class PagedAccess:
     def full_decode(self, state, li, pos, q, k, v, kernel):
         import jax.numpy as jnp
 
-        from ...ops import attention as attn_ops
         from ...ops import paged_attention as paged_ops
         from .pool import TRASH_BLOCK
         kf, krf, vf, kring, vring = state
-        BS, C, rd = self.BS, self.MB * self.BS, self.cfg.rot_dim
-        base = li * jnp.int32(self.NB)
-        # the WRITE position is clamped as a whole (programs.py)
-        wpos = jnp.minimum(pos, jnp.int32(C - 1))
-        bidx = jnp.take_along_axis(
-            self.tables, (wpos // jnp.int32(BS))[:, None], axis=1)[:, 0]
-        row = jnp.arange(BS, dtype=jnp.int32)[None, :] \
-            == (wpos % jnp.int32(BS))[:, None]                  # [S, BS]
-        fb = base + bidx
-        with device_scope("kv_write"):
-            kf = kf.at[fb].set(jnp.where(
-                row[:, None, :, None], k[:, :, None, rd:], kf[fb]))
-            vf = vf.at[fb].set(jnp.where(
-                row[:, None, :, None], v[:, :, None, :], vf[fb]))
-            krf = krf.at[fb].set(jnp.where(
-                row[:, None, None, :], k[:, :, :rd, None], krf[fb]))
+        rd = self.cfg.rot_dim
         # what attention may read of a slot: its positions so far, never
         # more than the blocks its row holds (a released slot: nothing)
         held = jnp.sum((self.tables != TRASH_BLOCK).astype(jnp.int32),
                        axis=1)
-        lengths = jnp.minimum(pos + 1, held * jnp.int32(BS))
-        fn = paged_ops.paged_decode_attention if kernel \
-            else attn_ops.cached_paged_attention
-        o = fn(q[..., rd:], kf, vf, self.tables + base, lengths,
-               q[..., :rd], krf)
+        lengths = jnp.minimum(pos + 1, held * jnp.int32(self.BS))
+        with device_scope("kv_write"):
+            new = (k[..., rd:].astype(kf.dtype), v.astype(vf.dtype),
+                   k[..., :rd].astype(krf.dtype))
+            wpos = paged_ops.live_write_pos(pos, lengths)
+        o, (kf, vf, krf) = paged_ops.paged_write_attention(
+            q[..., rd:], new, (kf, vf, krf),
+            self.tables + li * jnp.int32(self.NB), wpos, lengths, kernel,
+            q_rot=q[..., :rd])
         return (kf, krf, vf, kring, vring), o
 
     def win_decode(self, state, wi, pos, q, k, v, sink):

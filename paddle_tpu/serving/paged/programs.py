@@ -18,8 +18,9 @@ block table instead of a slot-contiguous region:
       merges the two by their softmax statistics; then writes the
       run's k and v into the ``B / BS + 1`` table entries from
       ``start // BS`` on by whole-block read-modify-write into the
-      carried flat pool (as decode does; one entry more than the run
-      holds because an end-aligned final chunk starts inside a block).
+      carried flat pool (as decode's ``jnp`` path does; one entry more
+      than the run holds because an end-aligned final chunk starts
+      inside a block).
       Only the run's REAL positions ``start .. start + tail_len``
       change: rows below ``start`` in the first block, the bucket's
       padding rows and every block the row's padding entries name
@@ -42,40 +43,39 @@ block table instead of a slot-contiguous region:
 
   ``paged_decode(params, toks [S], pos [S], tables [S, MB], kc, vc
                  [, samp...])``
-      One fused program advancing every slot a token: each slot writes
-      its new K/V row into block ``tables[s, pos//BS]`` at offset
-      ``pos % BS`` (always a privately-owned block: decode positions
-      are >= prompt_len and only full-prompt blocks are ever shared),
-      then attends through ``ops.attention.cached_paged_attention``
-      under the per-slot length mask. The write position is clamped to
-      the row's last entry (``MB*BS - 1``): parked/released slots'
-      positions keep incrementing past the row, and clamping the whole
-      position (not just the block column) pins their stray write to
-      that one entry — which is always private, never a shared prefix
-      block (see the invariant asserted in ``pool.acquire``) — instead
-      of cycling across block MB-1's offsets or gathering out of
-      bounds.
+      One fused program advancing every slot a token: each slot's new
+      K/V row goes to block ``tables[s, pos//BS]`` at offset ``pos %
+      BS`` (always a privately-owned block: decode positions are >=
+      prompt_len and only full-prompt blocks are ever shared), then
+      the slot attends under the per-slot length mask:
+      ``ops.paged_attention.paged_write_attention``, one function for
+      every paged decode program. A parked or released slot's entry is
+      nobody's (``live_write_pos``: -1). The Pallas kernel, which
+      places the entry itself, writes nothing for it; the ``jnp`` block
+      write pins it to the row's last entry (``MB*BS - 1``), which is
+      always private, never a shared prefix block (see the invariant
+      asserted in ``pool.acquire``), or trash.
 
-How the decode program carries the pool (ISSUE 26). The layer loop is
-a ``lax.scan`` over (stacked weights, layer index) with the pool in its
-CARRY, addressed flat as ``[L*NB, nh, BS, hd]`` (a reshape of the
-row-major pool: a bitcast); layer ``l`` reads and writes block ``b`` as
-flat row ``l*NB + b``, so attention gets the flat pool and
+How the decode program carries the pool (ISSUE 26, ISSUE 43). The layer
+loop is a ``lax.scan`` over (stacked weights, layer index) with the
+pool in its CARRY, addressed flat as ``[L*NB, nh, BS, hd]`` (a reshape
+of the row-major pool: a bitcast); layer ``l`` reads and writes block
+``b`` as flat row ``l*NB + b``, so attention gets the flat pool and
 ``tables + l*NB`` (layer ``l``'s trash block is row ``l*NB + trash``).
 The pool as a scanned input and stacked output was sliced apart and
 restacked every step: five pool-sized passes, and a second pool in
-memory, because stacked outputs cannot alias scanned inputs. The write
-is a whole-block read-modify-write (gather the S current blocks, put
-the new row in, scatter the blocks back): a scatter whose window is
+memory, because stacked outputs cannot alias scanned inputs. On a chip
+the kernel takes the carried pool as an aliased operand and writes the
+one tile that holds the new entry from the buffer it attends over. The
+``jnp`` write (the CPU, shapes the kernel refuses) is a whole-block
+read-modify-write (``write_block_rows``: gather the S current blocks,
+put the new row in, scatter the blocks back): a scatter whose window is
 every trailing dimension leaves the pool's layout row-major from
 parameter to result, so the donated buffer is updated in place; the row
 scatter ``at[fb, :, off]`` made XLA relayout the whole pool around the
-loop. Whole blocks are safe because a decode step's write blocks are
-private to their slot (the invariant ``pool.acquire`` asserts); the
-only duplicate targets are parked or released slots meeting in the
-trash block, where whichever wins is garbage behind the length mask.
-``tests/test_chip_compile.py`` holds the compiled program to this
-(aliased pool, temporaries under one pool half, no pool-shaped copy).
+loop. ``tests/test_chip_compile.py`` holds both compiled programs to
+this (aliased pool, small temporaries, no pool-shaped copy, no gathered
+block set in the kernel's program).
 
 Scatter/gather safety: table-row padding and released rows point at
 the reserved trash block, so pad-entry writes land in garbage, and the
@@ -242,29 +242,11 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
         with device_scope("embed"):
             x = params["wemb"][toks] + params["pemb"][
                 jnp.minimum(pos, params["pemb"].shape[0] - 1)]  # [S, h]
-        # clamp the WRITE position as a whole (column AND offset):
-        # parked / released slots' positions keep incrementing past
-        # the row, and clamping only the column would spray their
-        # stray K/V across every offset of block MB-1 as pos % BS
-        # cycles. Clamped, the stray write pins to the row's single
-        # last entry (MB-1, BS-1) — always safe because the last row
-        # block is never shared (only full-PROMPT blocks are indexed
-        # for sharing, and acquire() guarantees at least one fresh
-        # private block after the pinned prefix; pool.acquire asserts
-        # this) and position C-1 is either trash-backed, beyond the
-        # length mask, or legitimately rewritten before exposure.
-        wpos = jnp.minimum(pos, jnp.int32(C - 1))
-        col = wpos // jnp.int32(BS)
-        bidx = jnp.take_along_axis(tables, col[:, None], axis=1)[:, 0]
-        off = wpos % jnp.int32(BS)
-
         # the pool rides the layer loop as CARRIED state, flat: layer
         # l's block b is row l*NB + b (module docstring)
         NB = kc.shape[1]
         kf = kc.reshape((L * NB,) + kc.shape[2:])
         vf = vc.reshape((L * NB,) + vc.shape[2:])
-        row = (jnp.arange(BS, dtype=jnp.int32)[None, :]
-               == off[:, None])[:, None, :, None]      # [S, 1, BS, 1]
         # what attention may read of a slot: its positions so far, and
         # never more than the blocks its table row holds. A released
         # slot's position keeps counting while its row is all trash:
@@ -273,6 +255,10 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
         # full row of trash
         held = jnp.sum((tables != TRASH_BLOCK).astype(jnp.int32), axis=1)
         lengths = jnp.minimum(pos + 1, held * jnp.int32(BS))
+        with device_scope("kv_write"):
+            # where the step's entry goes: a parked or released slot's
+            # nowhere anybody reads
+            wpos = paged_attn_ops.live_write_pos(pos, lengths)
 
         def body(carry, inp):
             x, kf, vf = carry
@@ -283,25 +269,15 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
                 qkv = h_ @ p["qkv_w"] + p["qkv_b"]
                 qkv = qkv.reshape(S, 3, nh, hd).transpose(1, 0, 2, 3)
                 q, k, v = qkv[0], qkv[1], qkv[2]          # [S, nh, hd]
-                # whole-block read-modify-write of each slot's current
-                # (privately-owned) block, so the carried pool is
-                # updated in place. The only duplicate fb are parked /
-                # released slots meeting in the trash block, where any
-                # winner is garbage behind the length mask.
+                # the step's new entry, as the pool holds it; the
+                # kernel places it (ops.paged_attention), the gather
+                # path writes its block first
                 with device_scope("kv_write"):
-                    fb = base + bidx                      # [S]
-                    kf = kf.at[fb].set(jnp.where(
-                        row, k.astype(kf.dtype)[:, :, None], kf[fb]))
-                    vf = vf.at[fb].set(jnp.where(
-                        row, v.astype(vf.dtype)[:, :, None], vf[fb]))
-                ltab = tables + base     # layer l's trash: l*NB + trash
-                if attn_kernel:
-                    o = paged_attn_ops.paged_decode_attention(
-                        q, kf, vf, ltab, lengths)
-                else:
-                    # gathers the slots' blocks under "kv_gather"
-                    o = attn_ops.cached_paged_attention(
-                        q, kf, vf, ltab, lengths)
+                    new = (k.astype(kf.dtype), v.astype(vf.dtype))
+                # layer l's trash: l*NB + trash
+                o, (kf, vf) = paged_attn_ops.paged_write_attention(
+                    q, new, (kf, vf), tables + base, wpos, lengths,
+                    attn_kernel)
                 o = o.reshape(S, hidden)                  # concat heads
                 x = x + (o @ p["out_w"] + p["out_b"])
             return (mlp(x, p), kf, vf), None

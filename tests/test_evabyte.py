@@ -499,6 +499,35 @@ def test_engine_with_the_kernel_in_interpret_mode(interpret):
     assert _served_gap(w, p, r, hf) < TOL
 
 
+def test_engine_kernel_places_entries_like_the_gather_path(monkeypatch):
+    """The decode program whose kernel places the step's new ENTRY
+    (interpret mode, heads of 128) against the ``jnp`` block write and
+    the gather: two slots, five sessions, so slots are released and
+    taken again; a prompt of 70 prefilled window by window (its slot
+    parked in between); every session decodes across a window's end, so
+    entries are written after a compaction took blocks back, and across
+    block boundaries. The same tokens on both, each the reference's
+    best."""
+    m, w, hf = _model(seed=1, hidden_size=256, num_attention_heads=2,
+                      num_key_value_heads=2, num_hidden_layers=2)
+    rng = np.random.default_rng(43)
+    lens, new = (27, 70, 40, 33, 12), (40, 20, 38, 36, 25)
+    prompts = [rng.integers(0, 64, size=n) for n in lens]
+    served = {}
+    for kernel in (True, False):
+        monkeypatch.setattr(pa, "_FORCE_INTERPRET", [kernel])
+        eng = ServingEngine(m, num_slots=2, block_size=8, max_len=128)
+        reqs = _drive(eng, prompts, new)
+        assert eng.pool.reuse_count >= 2
+        assert eng.metrics.entry_cache_report()["compactions"] >= 5
+        served[kernel] = [np.asarray(r.output_ids) for r in reqs]
+        for p, r in zip(prompts, reqs):
+            assert _served_gap(w, p, r, hf) < TOL
+        eng.pool.check_conservation()
+    for a, b in zip(served[True], served[False]):
+        np.testing.assert_array_equal(a, b)
+
+
 # ------------------------------------------------------ correct's teeth
 def test_a_float8_control_fails_where_bfloat16_passes():
     """What the cell's ``correct_limits`` must tell apart, on the

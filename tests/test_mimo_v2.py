@@ -454,6 +454,39 @@ def test_engine_with_kernels_in_interpret_mode(interpret):
     assert _served_gap(w, p, r, hf) < TOL
 
 
+def test_engine_kernel_places_entries_like_the_gather_path(monkeypatch):
+    """Both kernels in the decode program, the attention one placing
+    the step's new entry in all three pools of a full layer (interpret
+    mode, a key of 128 + 8 beside values of 128), against the ``jnp``
+    formulations with the block write: eight slots, eleven requests, so
+    slots are released and taken again while released ones keep
+    stepping; a prompt of 45 prefilled in chunks of 16 (its slot parked
+    in between); outputs of up to 20 tokens over blocks of 8. The same
+    tokens on both, each the reference's best."""
+    from paddle_tpu.ops import moe_experts as moe
+    m, w, hf = _model(seed=1, head_dim=136, swa_head_dim=136,
+                      v_head_dim=128, swa_v_head_dim=128,
+                      partial_rotary_factor=0.06, hidden_size=128,
+                      moe_intermediate_size=128)
+    rng = np.random.default_rng(43)
+    lens = (5, 45, 9, 17, 12, 3, 7, 14, 6, 11, 4)
+    new = (20, 7, 10, 18, 6, 11, 13, 5, 8, 14, 9)
+    prompts = [rng.integers(0, 128, size=n) for n in lens]
+    served = {}
+    for kernel in (True, False):
+        monkeypatch.setattr(pa, "_FORCE_INTERPRET", [kernel])
+        monkeypatch.setattr(moe, "_FORCE_INTERPRET", [kernel])
+        eng = ServingEngine(m, num_slots=8, block_size=8, max_len=96,
+                            buckets=[16], prefill_chunk=16)
+        reqs = _drive(eng, prompts, new)
+        assert eng.pool.reuse_count >= 2
+        served[kernel] = [np.asarray(r.output_ids) for r in reqs]
+        for p, r in zip(prompts, reqs):
+            assert _served_gap(w, p, r, hf) < TOL
+    for a, b in zip(served[True], served[False]):
+        np.testing.assert_array_equal(a, b)
+
+
 # ------------------------------------------------- what the spec counts
 def test_cache_spec_counts_blocks_and_rings(model_w):
     from paddle_tpu.serving.paged import PagedKVPool

@@ -584,6 +584,35 @@ def test_engine_with_kernels_in_interpret_mode(interpret):
     assert _served_gap(w, p, r, hf) < TOL
 
 
+def test_engine_kernel_places_entries_like_the_gather_path(monkeypatch):
+    """All three kernels in the decode program, the attention one
+    placing the step's new entry (interpret mode), against the ``jnp``
+    formulations with the block write: eight slots, eleven requests, so
+    slots are released and taken again while released ones keep
+    stepping; a prompt of 30 prefilled in chunks of 16 (its slot parked
+    in between); outputs of up to 12 tokens over blocks of 8, so streams
+    cross block boundaries. The same tokens on both."""
+    m, w, hf = _model(seed=1, head_dim=128, mamba_head_dim=64,
+                      ssm_state_size=16, mamba_num_heads=4, n_groups=2)
+    rng = np.random.default_rng(43)
+    lens = (5, 30, 9, 17, 12, 3, 7, 14, 6, 11, 4)
+    new = (12, 7, 10, 9, 6, 11, 3, 5, 8, 4, 9)
+    prompts = [rng.integers(0, 96, size=n) for n in lens]
+    served = {}
+    for kernel in (True, False):
+        for mod in (ssm, moe, pa):
+            monkeypatch.setattr(mod, "_FORCE_INTERPRET", [kernel])
+        eng = ServingEngine(m, num_slots=8, block_size=8, max_len=64,
+                            buckets=[16], prefill_chunk=16)
+        reqs = _drive(eng, prompts, new)
+        assert eng.pool.reuse_count >= 2
+        served[kernel] = [np.asarray(r.output_ids) for r in reqs]
+        for p, r in zip(prompts, reqs):
+            assert _served_gap(w, p, r, hf) < TOL
+    for a, b in zip(served[True], served[False]):
+        np.testing.assert_array_equal(a, b)
+
+
 # ------------------------------------- the other models' pools, unchanged
 def test_cache_spec_counts_both_kinds(model_w):
     from paddle_tpu.serving.paged import PagedKVPool
@@ -611,9 +640,11 @@ def _digest(fn, *args):
 
 def test_gpt_spec_pool_donation_and_decode_program_unchanged():
     """The GPT through the generalised spec: a (k, v) spec with ONE
-    layer count, shareable, no per-slot bytes; the pool's arrays, the
-    engine's donation tuple and the decode program's jaxpr are those of
-    the parent commit (digest taken there)."""
+    layer count, shareable, no per-slot bytes; the pool's arrays and
+    the engine's donation tuple are those of PR 35's parent commit, the
+    decode program's jaxpr that of PR 43 (its cache write moved into
+    ``ops.paged_attention.paged_write_attention``; the digest before
+    was ``dadb0403db741ab5``)."""
     from paddle_tpu.serving.paged.cache_spec import kv_pair_spec
     from paddle_tpu.text.models import GPTForCausalLM, TransformerLMConfig
     spec = kv_pair_spec(2, 4, 16, jnp.float32)
@@ -654,5 +685,5 @@ def test_latent_spec_pool_donation_and_decode_program_unchanged():
 
 # digests of str(jax.make_jaxpr(decode program)) at the sizes above,
 # taken on the parent commit (50d367b) with this same test code
-GPT_DECODE_DIGEST = "dadb0403db741ab5"
+GPT_DECODE_DIGEST = "bfc0be992ec17538"
 LATENT_DECODE_DIGEST = "aa31ce9df0109a05"

@@ -225,6 +225,124 @@ def test_kernel_ignores_trash_and_recycled_rows(interpret_kernel):
     assert np.isfinite(np.asarray(poisoned)).all()
 
 
+# ---------------------------------------------- the kernel places the entry
+# (nq, nh, hd, d2, BS): one query head a KV head (the GPT's, EvaByte's),
+# grouped queries (nemotron_h's), a key in two parts (mimo_v2's: blocks
+# of 256, so that the transposed part has two tiles of 128 lanes)
+_WRITE_KINDS = {"one_head_a_group": (4, 4, 32, 0, 32),
+                "grouped": (8, 2, 32, 0, 32),
+                "two_part_key": (8, 2, 32, 16, 256)}
+_MB = 4     # blocks a slot; chunks of G = 2 blocks
+# per case, every slot's (length, write position) from a block's, a
+# chunk's and a row's positions. The position is length - 1 where None
+# (the entry is live)
+_WRITE_CASES = {
+    # the first row of a block the slot has just been given (the second
+    # of a chunk, the first of the next chunk)
+    "block_first_row": lambda BS, T, C: [
+        (BS + 1, None), (T + 1, None), (T + BS + 1, None), (7, None)],
+    "block_last_row": lambda BS, T, C: [
+        (BS, None), (T, None), (C, None), (T + BS, None)],
+    "chunk_edge": lambda BS, T, C: [
+        (T, None), (T + 1, None), (T - 1, None), (T + 2, None)],
+    "length_one": lambda BS, T, C: [
+        (1, None), (C, None), (1, None), (BS + 3, None)],
+    # a parked slot (its position past the row; it attends what it
+    # holds) and a released one (nothing live) beside a full one
+    "parked_and_released": lambda BS, T, C: [
+        (C, None), (T, C + 3), (0, C + 9), (BS + 5, None)],
+}
+
+
+def _write_case(kind, case, dtype, seed=0):
+    import jax.numpy as jnp
+    nq, nh, hd, d2, BS = _WRITE_KINDS[kind]
+    slots = _WRITE_CASES[case](BS, 2 * BS, _MB * BS)
+    lens = np.asarray([n for n, _ in slots], np.int32)
+    wpos = np.asarray([n - 1 if w is None else w for n, w in slots],
+                      np.int32)
+    S = len(slots)
+    q, kc, vc, tables, _ = _paged_case(seed, S, nh, hd, BS, _MB, lens)
+    rs = np.random.RandomState(seed + 1)
+    dt = jnp.dtype(dtype)
+    q = jnp.asarray(rs.randn(S, nq, hd), dt)
+    pools = [jnp.asarray(kc, dt), jnp.asarray(vc, dt)]
+    new = [jnp.asarray(rs.randn(S, nh, hd), dt) for _ in range(2)]
+    q2 = None
+    if d2:
+        q2 = jnp.asarray(rs.randn(S, nq, d2), dt)
+        pools.append(jnp.asarray(rs.randn(S * _MB + 1, nh, d2, BS), dt))
+        new.append(jnp.asarray(rs.randn(S, nh, d2), dt))
+    return (q, q2, tuple(pools), tuple(new), jnp.asarray(tables),
+            jnp.asarray(lens), jnp.asarray(wpos))
+
+
+@pytest.mark.parametrize("case", list(_WRITE_CASES))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", list(_WRITE_KINDS))
+def test_kernel_places_the_new_entry(interpret_kernel, monkeypatch, kind,
+                                     dtype, case):
+    """The kernel with the write against the ``jnp`` block write, then
+    ``cached_paged_attention``: outputs of every slot with something
+    live as the oracle's; every pool EXACTLY what it was with the live
+    slots' entries at their positions and nothing else changed (not a
+    parked or released slot's block, not the trash block, not another
+    row of the tile that was written back); every live entry bit-equal
+    to the oracle's pools."""
+    nq, nh, hd, d2, BS = _WRITE_KINDS[kind]
+    itemsize = 4 if dtype == "float32" else 2
+    monkeypatch.setattr(pa, "_CHUNK_VMEM_BYTES",
+                        2 * 2 * nh * BS * (2 * hd + d2) * itemsize)
+    assert pa.blocks_per_chunk(nh, hd, BS, _MB, dtype, d2) == 2
+    q, q2, pools, new, tables, lens, wpos = _write_case(kind, case, dtype)
+    live = pa.live_write_pos(wpos, lens)
+    got, got_pools = pa.paged_write_attention(
+        q, new, pools, tables, live, lens, True, q_rot=q2)
+    want, want_pools = pa.paged_write_attention(
+        q, new, pools, tables, live, lens, False, q_rot=q2)
+    lens, live, tables = (np.asarray(a) for a in (lens, live, tables))
+    np.testing.assert_array_equal(live >= 0, np.asarray(wpos) == lens - 1)
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    some = lens > 0
+    np.testing.assert_allclose(np.asarray(got, np.float32)[some],
+                               np.asarray(want, np.float32)[some],
+                               rtol=tol, atol=tol)
+    assert not np.asarray(got, np.float32)[~some].any()   # rows of zeros
+    for i, (pool, entry) in enumerate(zip(pools, new)):
+        expect = np.array(pool.astype("float32"))
+        for s in np.nonzero(live >= 0)[0]:
+            blk, off = tables[s, live[s] // BS], live[s] % BS
+            row = np.asarray(entry.astype("float32"))[s]
+            if i == 2:
+                expect[blk, :, :, off] = row
+            else:
+                expect[blk, :, off] = row
+        have = np.asarray(got_pools[i].astype("float32"))
+        np.testing.assert_array_equal(have, expect)
+        # the oracle's pool at every live position of every slot
+        oracle = np.asarray(want_pools[i].astype("float32"))
+        for s in np.nonzero(some)[0]:
+            for b in range(-(-lens[s] // BS)):
+                n = min(BS, lens[s] - b * BS)
+                rows = np.s_[..., :n] if i == 2 else np.s_[:, :n]
+                np.testing.assert_array_equal(
+                    have[tables[s, b]][rows], oracle[tables[s, b]][rows])
+
+
+def test_read_only_call_traces_no_write(interpret_kernel):
+    """Without a new entry the call is what it was: one result, no
+    aliased pool, no third scalar, no write-back semaphore."""
+    import jax
+    q, kc, vc, tables, lens = _paged_case(0, 2, 4, 32, 8, 2)
+    text = str(jax.make_jaxpr(pa.paged_decode_attention)(
+        q, kc, vc, tables, lens))
+    assert "input_output_aliases=()" in text
+    wrote = str(jax.make_jaxpr(
+        lambda *a: pa.paged_decode_attention(
+            *a, new=(q, q), write_pos=lens - 1))(q, kc, vc, tables, lens))
+    assert "input_output_aliases=((4, 1), (5, 2))" in wrote
+
+
 def test_guard_resolution(monkeypatch):
     """kernel_viable is the only gate: the CPU without forced interpret
     refuses (tier-1 runs the XLA gather); f64 refuses even forced; on a
@@ -365,7 +483,8 @@ def test_released_slot_costs_the_kernel_nothing(interpret_kernel):
     all trash. The decode program hands attention no more than the
     blocks a row holds, so the kernel is asked for nothing of it (length
     0) and not for a capacity of trash; live slots' lengths are their
-    positions, as before."""
+    positions, as before. Its new entry is nobody's either: the write
+    position it is handed is -1."""
     import jax.numpy as jnp
     from paddle_tpu.serving.paged.programs import build_paged_fns
     m = _tiny_model()
@@ -374,9 +493,10 @@ def test_released_slot_costs_the_kernel_nothing(interpret_kernel):
     seen = {}
     real = pa.paged_decode_attention
 
-    def spy(q, kf, vf, tables, lengths):
+    def spy(q, kf, vf, tables, lengths, **placed):
         seen["lengths"] = lengths
-        return real(q, kf, vf, tables, lengths)
+        seen["write_pos"] = placed["write_pos"]
+        return real(q, kf, vf, tables, lengths, **placed)
 
     pa.paged_decode_attention = spy
     try:
@@ -398,6 +518,9 @@ def test_released_slot_costs_the_kernel_nothing(interpret_kernel):
         pa.paged_decode_attention = real
     np.testing.assert_array_equal(np.asarray(seen["lengths"]),
                                   [10, 0, 32])
+    # and its entry goes nowhere; the live slots' to their positions
+    np.testing.assert_array_equal(np.asarray(seen["write_pos"]),
+                                  [9, -1, 31])
 
 
 def test_roofline_paged_pallas_layout():

@@ -163,6 +163,39 @@ def test_paged_sync_mode_matches_pipelined():
         np.testing.assert_array_equal(a, b)
 
 
+def test_engine_kernel_places_entries_like_the_gather_path():
+    """The decode program whose attention kernel places the step's new
+    entry (interpret mode) against the one that writes blocks in
+    ``jnp`` and gathers: three slots, six requests, so slots are
+    released and taken again while released ones keep stepping; a
+    prompt of 21 prefilled in chunks of 8, its slot PARKED through the
+    decode steps in between; outputs of up to 14 tokens over blocks of
+    4, so every stream crosses block boundaries and the kernel's chunk.
+    Every stream is ``generate()``'s on both."""
+    from paddle_tpu.ops import paged_attention as pa
+    m = _model()
+    rs = np.random.RandomState(43)
+    lens, new = (5, 21, 9, 3, 12, 7), (14, 6, 11, 9, 5, 13)
+    prompts = [rs.randint(0, 97, (n,)).astype(np.int64) for n in lens]
+    want = [_ref(m, p, k) for p, k in zip(prompts, new)]
+    for kernel in (True, False):
+        pa._FORCE_INTERPRET[0] = kernel
+        try:
+            eng = ServingEngine(m, num_slots=3, bucket_min=8, block_size=4,
+                                prefill_chunk=8)
+            assert eng.paged_attn == kernel
+            reqs = [eng.add_request(p, max_new_tokens=k)
+                    for p, k in zip(prompts, new)]
+            eng.run()
+        finally:
+            pa._FORCE_INTERPRET[0] = False
+        assert eng.pool.reuse_count >= 2
+        assert eng.metrics.snapshot()["scheduler"]["prefill_chunks"] >= 3
+        for r, w in zip(reqs, want):
+            np.testing.assert_array_equal(r.output_ids, w)
+        eng.pool.check_conservation()
+
+
 def test_plan_prefix_respects_tail_and_capacity():
     """plan_prefix: always leaves >= 1 tail token, stays block-aligned,
     and shrinks the used prefix until the bucket-padded tail fits the
