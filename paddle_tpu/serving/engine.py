@@ -516,11 +516,13 @@ class ServingEngine:
             raise ValueError(
                 f"prefill_chunk {self.chunk_len} exceeds the per-slot "
                 f"capacity {cache_len}")
-        # the GPT's (k, v) pair, one entry a position: what kv_wire,
-        # the speculative verify programs and the analytic decode model
-        # are written for
+        # the GPT's (k, v) pair, one entry a position, nothing carried
+        # beside it: what kv_wire, the speculative verify programs and
+        # the analytic decode model are written for (a looped model
+        # keeps a pair a PASS of every layer, and its counters)
         self._kv_pair = [a.name for a in self.cache_spec.arrays] \
-            == ["k", "v"] and self._window is None
+            == ["k", "v"] and self._window is None \
+            and not self.cache_spec.state
         self.pool = self._new_pool()
         # the decode-attention path, resolved ONCE at build time
         # from what is observable and nothing else: a (k, v) pool
@@ -818,6 +820,11 @@ class ServingEngine:
         if moe is not None:
             self.metrics.set_moe_counters(
                 lambda: np.asarray(self._state[0]), **moe())
+        loop = getattr(model, "loop_counter_layout", None)
+        if loop is not None:
+            self.metrics.set_loop_counters(
+                lambda: tuple(np.asarray(a) for a in self._state),
+                **loop())
         if self._perf_on and self._kv_pair:
             # price the per-program roofline (the CPU has no peaks:
             # device_peak/device_hbm=false and None fractions in the

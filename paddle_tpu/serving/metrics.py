@@ -304,6 +304,7 @@ class ServingMetrics:
         }
         self.kv_donation = {"enabled": False, "effective": False}
         self._moe = None   # set_moe_counters
+        self._loop = None  # set_loop_counters
         self._g_cache_entries = None   # enable_entry_cache
         self._g_cache_live_bytes = None    # enable_ring_cache
         self._t_first_work = None
@@ -570,6 +571,55 @@ class ServingMetrics:
                 "expert_tokens": arr[:, :m["count"]].tolist(),
                 "experts_hit": arr[:, m["count"]].tolist(),
                 "layer_steps": arr[:, m["count"] + 1].tolist()}
+
+    def set_loop_counters(self, read_fn, passes, cache_passes):
+        """Counters of a LOOPED model (one stack of layers run
+        ``passes`` times) that the decode program keeps ON THE DEVICE
+        (``CacheSpec.state``): ``read_fn()`` fetches ``(counts [passes
+        + 1]`` int: decoded tokens by the pass they were read from, then
+        the passes run for them; ``mass [passes]`` float: the exit
+        distribution's summed mass a pass``)`` and is only called when
+        somebody looks, never by the step loop. The gauge
+        ``serving_cache_passes`` says how many cache layers a weight
+        layer has."""
+        r = self.registry
+        run = r.counter(
+            "serving_loop_passes_total",
+            "passes of the layer stack run, summed over decoded tokens")
+        exits = r.counter(
+            "serving_loop_exit_pass",
+            "decoded tokens by the pass they were read from",
+            labelnames=("pass",))
+        mass = r.counter(
+            "serving_loop_gate_mass",
+            "the exit distribution's mass a pass, summed over decoded "
+            "tokens (what a threshold under 1 would let leave)",
+            labelnames=("pass",))
+        r.gauge("serving_cache_passes",
+                "cache layers a weight layer has (a looped model keeps "
+                "keys and values a pass)").set(float(cache_passes))
+        self._loop = {"read": read_fn, "passes": int(passes),
+                      "cache_passes": int(cache_passes)}
+
+        def loop_collect():
+            rep = self.loop_report()
+            run.set_to(float(rep["passes_run"]))
+            for i in range(passes):
+                exits.labels(i).set_to(float(rep["exit_pass"][i]))
+                mass.labels(i).set_to(float(rep["gate_mass"][i]))
+        r.add_collect_hook(loop_collect)
+
+    def loop_report(self):
+        """The loop's counters (None for a model that runs its layers
+        once)."""
+        if self._loop is None:
+            return None
+        counts, mass = self._loop["read"]()
+        n = self._loop["passes"]
+        return {"passes": n, "cache_passes": self._loop["cache_passes"],
+                "exit_pass": [int(c) for c in counts[:n]],
+                "passes_run": int(counts[n]),
+                "gate_mass": [float(m) for m in mass]}
 
     def set_prefix_pool(self, stats_fn):
         """Attach the paged pool's ``stats()`` as the pull source for
@@ -1030,6 +1080,8 @@ class ServingMetrics:
             "tenants": self.tenant_report(),
             # only a model with expert layers adds its section
             **({"moe": self.moe_report()} if self._moe else {}),
+            # only a looped model
+            **({"loop": self.loop_report()} if self._loop else {}),
             # only a model whose cache entries are not positions
             **({"cache_entries": self.entry_cache_report()}
                if self._g_cache_entries is not None else {}),

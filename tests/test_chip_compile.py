@@ -14,6 +14,7 @@ imports every test file. Keep all such tests in THIS file so they land
 on one worker (``--dist loadfile``).
 """
 import re
+import sys
 from unittest import mock
 
 import jax
@@ -1011,3 +1012,137 @@ def test_mixed_prefill_program_updates_the_pool_in_place(one_chip):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(nbytes)
     assert mem.temp_size_in_bytes < 1 << 30, mem.temp_size_in_bytes
+
+
+# ------- ouro: one stack of layers run 4 times, a cache entry for every pass
+def _looped_programs(one_chip):
+    """Both programs of the ``ouro_2p6b`` cell at its configuration's
+    widths and depth (48 layers x 4 passes, nothing cut) and its sizes
+    (2 slots x 2,560 positions, blocks of 64), as
+    ``tools/aot_compile_arch.py`` builds them."""
+    import json
+    import os
+    from paddle_tpu.serving.paged.looped_programs import \
+        build_paged_looped_fns
+    from paddle_tpu.text import ouro
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "ouro_2p6b.json")) as f:
+        config = json.load(f)
+    sys.path.insert(0, root)
+    from benchmarks.planes import serve_arch
+    sz = config["sizing"]
+    cfg = ouro.OuroConfig.from_hf(serve_arch.model_of(config),
+                                  dtype="bfloat16")
+    S, BS = sz["num_slots"], sz["block_size"]
+    MB = sz["max_len"] // BS
+    NB = S * MB + 1
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        prefill, decode = build_paged_looped_fns(cfg, S, BS, NB, MB)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dt),
+                                    sharding=one_chip)
+    params = {}
+    for path, (shape, _, dt) in ouro.param_shapes(cfg).items():
+        node = params
+        for part in path[:-1]:
+            node = node.setdefault(part, {})
+        node[path[-1]] = sds(shape, dt)
+    spec = ouro.looped_cache_spec(cfg)
+    pool = [sds(spec.shape(a, NB, BS), a.dtype) for a in spec.arrays]
+    state = [sds(shape, dt) for _, shape, dt in spec.state]
+    toks, pos = sds((S,), jnp.int32), sds((S,), jnp.int32)
+    nbytes = [int(np.prod(p.shape)) * p.dtype.itemsize for p in pool]
+    return (prefill, decode, params, pool, state, toks, pos, sz, NB, MB,
+            nbytes, sds)
+
+
+def _weight_stack_moves(compiled):
+    """Instructions of the optimized program that copy a leaf of the
+    48-layer weight stack whole, or one layer's matrix of it."""
+    mats = ["2048,6144", "2048,2048", "2048,5632", "5632,2048"]
+    found = _pool_shaped(compiled, [f"48,{m}" for m in mats]
+                         + [f"1,{m}" for m in mats] + mats)
+    return [(name, op) for name, op in found
+            if op == "copy" or "copy" in name]
+
+
+def test_looped_decode_program_reads_one_stack_and_keeps_the_pool(
+        one_chip):
+    """The decode program of the cell: the pool of 192 cache layers
+    (over 48 weight layers) aliased onto the results and carried through
+    the layer loop AND the pass loop in place; ONE call site of the
+    kernel (the layer body is traced once, whatever the passes) with the
+    write inside; temporaries in MBs; no copy of the pool, of a leaf of
+    the weight stack or of one layer's matrix."""
+    (_, decode, params, pool, state, toks, pos, sz, NB, MB, nbytes,
+     sds) = _looped_programs(one_chip)
+    assert pool[0].shape == (192, 81, 16, 64, 128)
+    assert params["layers"]["wqkv"].shape == (48, 2048, 6144)
+    n = len(pool)
+    compiled = jax.jit(
+        decode, donate_argnums=(2,) + tuple(range(4, 4 + n))).lower(
+        params, toks, pos, sds((sz["num_slots"], MB), jnp.int32), *pool,
+        *state).compile()
+    mem = compiled.memory_analysis()
+    assert sum(nbytes) == 8_153_726_976
+    assert mem.alias_size_in_bytes >= sum(nbytes)
+    # AOT, PR 44: 0.77 MB
+    assert mem.temp_size_in_bytes < 4 << 20, mem.temp_size_in_bytes
+    # weights + pool as the device lays them out: 13.49 GB of 16
+    assert mem.argument_size_in_bytes < 13.6e9
+    text = compiled.as_text()
+    assert "paged_decode_attn" in text
+    assert text.count("tpu_custom_call") == 1
+    # the kernel places the new entry: no set of 2 gathered blocks, and
+    # the flat pool is the only thing with the pool's size
+    assert not _pool_shaped(compiled, ["2,16,64,128"], dtype=r"\w+")
+    _assert_pool_stays_put(compiled, (192, NB, 16, 64, 128))
+    assert not _weight_stack_moves(compiled)
+
+
+def test_looped_prefill_program_updates_the_pool_in_place(one_chip):
+    """The one prefill bucket of the cell (512 positions through all
+    four passes): the pool aliased, temporaries in MBs (a slot's 40
+    blocks of one entry as a view, the blocked scores), no copy of the
+    pool or of the weight stack."""
+    (prefill, _, params, pool, _, toks, pos, sz, NB, MB, nbytes,
+     sds) = _looped_programs(one_chip)
+    n = len(pool)
+    scalar = sds((), jnp.int32)
+    (bucket,) = sz["buckets"]
+    assert bucket == sz["prefill_chunk"] == 512
+    compiled = jax.jit(
+        prefill, donate_argnums=tuple(range(8, 9 + n))).lower(
+        params, sds((1, bucket), jnp.int32), scalar, scalar, scalar,
+        scalar, sds((MB,), jnp.int32), toks, pos, *pool).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(nbytes)
+    # AOT, PR 44: 11.5 MB
+    assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
+    whole = _pool_shaped(compiled, [f"192,{NB},16,64,128",
+                                    f"{192 * NB},16,64,128"])
+    assert whole and not [(n_, op) for n_, op in whole
+                          if op == "copy" or "copy" in n_]
+    assert not _weight_stack_moves(compiled)
+
+
+def test_the_aot_tool_compiles_the_looped_cell_unedited(topo, capsys):
+    """``benchmarks/tools/aot_compile_arch.py ouro_2p6b``: the tool as
+    it stands finds the architecture's four files by name, takes the
+    pool's 192 layers from the cache spec and the weights' 48 from the
+    weights file, and reports both programs."""
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from benchmarks import harness
+    from benchmarks.tools import aot_compile_arch
+    config = harness.load_json(harness.HERE, "configs", "ouro_2p6b.json")
+    aot_compile_arch.programs(config,
+                              SingleDeviceSharding(topo.devices[0]))
+    out = capsys.readouterr().out
+    assert "2,667,974,657 parameters" in out
+    assert "cache 1572864 B a token" in out and "81 blocks of 64" in out
+    assert "paged_decode:" in out and "paged_prefill[512]:" in out
+    assert "kernels 1" in out
