@@ -41,7 +41,8 @@ from ..profiler import device_scope
 from ..ops import mla_attention as mla_ops
 from ..ops import moe_experts as moe_ops
 from .stacked_lm import (  # noqa: F401 - parts of this block
-    StackedCausalLM, count_routing, greedy_or_sampled, lm_head, rms_norm)
+    StackedCausalLM, count_routing, greedy_or_sampled, lm_head,
+    project_heads, rms_norm)
 
 
 class DeepseekV3Config:
@@ -153,7 +154,7 @@ def attention(cfg, p, x, positions, access, state, layer, start, mode):
     cdt = jnp.dtype(cfg.cache_dtype)
     with device_scope("mla/q_absorb"):
         xn = rms_norm(x, p["norm1"], cfg.rms_norm_eps)
-        q = jnp.dot(xn, p["wq"]).reshape(lead + (nh, dn + dr))
+        q = project_heads(xn, p["wq"], nh)
         q_nope = q[..., :dn]
         q_pe = rope_interleaved(q[..., dn:], positions[..., None],
                                 cfg.rope_theta)

@@ -46,7 +46,8 @@ import jax.numpy as jnp
 from ..profiler import device_scope
 from ..ops import eva as eva_ops
 from ..ops import moe_experts as moe_ops
-from .stacked_lm import StackedCausalLM, greedy_or_sampled, rms_norm
+from .stacked_lm import (StackedCausalLM, greedy_or_sampled,
+                         project_heads, rms_norm)
 
 
 class EvaByteConfig:
@@ -169,12 +170,11 @@ def attention(cfg, p, x, positions, access, state, layer, start, mode,
     cdt = jnp.dtype(cfg.cache_dtype)
     with device_scope("eva/qkv"):
         h = norm(cfg, x, p["norm1"])
-        # three matmuls, not one over a fused matrix: XLA brings each
-        # layer's [h, h] matrix into VMEM at the HBM bandwidth and
-        # multiplies it there; a fused [h, 3h] one it copied to another
-        # layout first (a decode step 16.1 ms for 14.4, my chip runs,
-        # PR 37)
-        q, k, v = (jnp.dot(h, p[n]).reshape(lead + (H, d))
+        # three matmuls, each reading its layer's [h, h] matrix inside
+        # the dot like wo below. As jnp.dot(h, w).reshape(heads) XLA
+        # staged and relaid each in VMEM (0.86 of a 13.49 ms step, ledger,
+        # PR 44); a fused [h, 3h] one it copied (16.1 ms for 14.4, PR 37)
+        q, k, v = (project_heads(h, p[n], H)
                    for n in ("wq", "wk", "wv"))
         q = eva_ops.rope_half(q, positions[..., None],
                               cfg.rope_theta).astype(cdt)

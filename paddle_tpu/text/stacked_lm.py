@@ -22,6 +22,19 @@ def rms_norm(x, w, eps):
     return (y * w.astype(jnp.float32)).astype(x.dtype)
 
 
+def project_heads(x, w, heads):
+    """``x @ w`` (``w`` ``[in, heads * d]``) split into heads: ``[...,
+    heads, d]`` in x's dtype. A 2-D matmul with an f32 result, rounded
+    at once to x's dtype (where a bf16 dot rounds its own) and split
+    into heads AFTER that. With the split folded into the dot XLA wants
+    the contracted axis minor-most: it staged a stacked layer's weight
+    in VMEM and relaid it there, every layer of every decode step; in
+    this form it reads the weight from HBM inside the matmul, as it does
+    for an output projection (PERF.md section 6, PR 45)."""
+    y = jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
+    return y.reshape(x.shape[:-1] + (heads, -1))
+
+
 def lm_head(cfg, params, x):
     """Final norm + head over x ``[..., h]``: logits in f32."""
     with device_scope("lm_head"):

@@ -378,15 +378,16 @@ def _paged_decode_program(one_chip, sampling, attn_kernel, slots=4):
         *args).compile(), pool
 
 
-def _pool_shaped(compiled, shapes, dtype="bf16"):
+def _pool_shaped(compiled, shapes, dtype="bf16", layout=False):
     """[(instruction name, opcode)] of the optimized program's
     instructions whose result has one of ``shapes`` (comma-joined
-    dims)."""
+    dims); with ``layout`` [(name, layout and memory space, opcode)]."""
     import re
     inst = re.compile(
-        rf"%([\w.\-]+) = {dtype}\[(?:{'|'.join(shapes)})\]\S* ([\w\-]+)\(")
-    return [m.groups() for m in map(inst.search,
-                                    compiled.as_text().splitlines()) if m]
+        rf"%([\w.\-]+) = {dtype}\[(?:{'|'.join(shapes)})\](\S*) ([\w\-]+)\(")
+    found = [m.groups() for m in map(inst.search,
+                                     compiled.as_text().splitlines()) if m]
+    return found if layout else [(name, op) for name, _, op in found]
 
 
 def _assert_pool_stays_put(compiled, pool):
@@ -894,6 +895,40 @@ def test_eva_compact_and_prefill_programs_update_the_pool_in_place(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= nbytes
     assert mem.temp_size_in_bytes < 1 << 30, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("model,shape", [("evabyte", "1,4096,4096"),
+                                         ("latent", "1,1024,3072")])
+def test_decode_projection_reads_the_stacked_weight_in_the_matmul(
+        one_chip, model, shape):
+    """A projection whose result is split into heads (EvaByte's ``wq``,
+    ``wk``, ``wv``; the latent model's ``wq``) reads its layer's slice of
+    the stacked weight from HBM inside the matmul's own fusion
+    (``stacked_lm.project_heads``). With the split folded into the dot
+    XLA sliced the layer's matrix into VMEM (``S(1)``) and relaid it
+    there (a ``copy`` to ``{1,2,0}``) every layer of every step: 0.86 of
+    a 13.49 ms step in the EvaByte cell (ledger, PR 44). No instruction
+    of the slice's shape may be a ``copy``, lie in another layout or
+    live in ``S(1)``; the one exception is the latent helper's DENSE
+    stack, ONE layer deep and so of the slice's own shape, which XLA
+    may prefetch whole (``copy-start`` / ``copy-done``, asynchronous,
+    row-major: not a relayout)."""
+    if model == "evabyte":
+        (_, decode, _), params, pool, toks, pos, tables, _, _, _ = \
+            _eva_programs(one_chip, 8)
+        compiled = jax.jit(decode, donate_argnums=(2, 4, 5)).lower(
+            params, toks, pos, tables, *pool).compile()
+        prefetch = ()
+    else:
+        compiled, _ = _latent_decode_program(one_chip, False)
+        prefetch = ("copy-start", "copy-done", "parameter",
+                    "get-tuple-element")
+    found = _pool_shaped(compiled, [shape], layout=True)
+    assert found   # a layer's slice is in the program under this shape
+    bad = [(name, layout, op) for name, layout, op in found
+           if op == "copy" or not layout.startswith("{2,1,0")
+           or ("S(1)" in layout and op not in prefetch)]
+    assert not bad, bad
 
 
 # -------------- mimo_v2: blocks in the full layers, rings in the window ones

@@ -225,6 +225,36 @@ def test_engine_never_hands_the_latent_model_the_gpt_kernel(model_w):
     assert eng.paged_attn is False and eng.decode_layout == "paged_xla"
 
 
+# ------------------------------------------- where a projection rounds
+def test_query_projection_rounds_to_bf16_straight_after_the_dot():
+    """``wq`` of a decode layer in bfloat16: the dot asks for float32
+    (the form XLA streams a stacked weight in: ``stacked_lm.
+    project_heads``) and its result is rounded to bfloat16 before the
+    rotary, the absorbed einsum or anything else reads it, so the
+    queries hold what ``jnp.dot(xn, wq)`` gave."""
+    from jaxpr_check import assert_same_bf16_rounding, rounded_projections
+    cfg = ds.DeepseekV3Config.from_hf(HF, initializer_range=0.2,
+                                      dtype="bfloat16")
+    w = ds.DeepseekV3ForCausalLM(cfg, seed=5).export_decode_params()
+    p = {n: a[0] for n, a in w["dense"].items()}
+    assert p["wq"].dtype == jnp.bfloat16
+    x = jnp.asarray(np.random.default_rng(5).normal(
+        size=(6, cfg.hidden_size)), jnp.bfloat16)
+    pos = jnp.arange(40, 46, dtype=jnp.int32)
+
+    class Access:
+        def decode(self, state, layer, positions, c, k_pe, q_lat, q_pe,
+                   scale):
+            return state, q_lat.astype(jnp.float32)
+
+    got, = rounded_projections(
+        lambda p, x: ds.attention(cfg, p, x, pos, Access(), (), 0, 0,
+                                  "decode")[0],
+        (p, x), [p["wq"]])
+    assert_same_bf16_rounding(
+        got, ds.rms_norm(x, p["norm1"], cfg.rms_norm_eps), p["wq"])
+
+
 # --------------------------------------------------- hand calculations
 def test_rope_rotates_interleaved_pairs():
     x = jnp.asarray([[1.0, 2.0, 3.0, 4.0]], jnp.float32)

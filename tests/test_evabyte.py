@@ -528,6 +528,35 @@ def test_engine_kernel_places_entries_like_the_gather_path(monkeypatch):
         np.testing.assert_array_equal(a, b)
 
 
+# ------------------------------------------- where a projection rounds
+def test_projections_round_to_bf16_straight_after_the_dot():
+    """``wq``, ``wk``, ``wv`` of a decode layer in bfloat16: each dot
+    asks for float32 (the form XLA streams a stacked weight in), and
+    what it gives is rounded to bfloat16 before the rotary or anything
+    else reads it, so q, k and v hold what ``jnp.dot(h, w)`` gave."""
+    from jaxpr_check import assert_same_bf16_rounding, rounded_projections
+    cfg = eb.EvaByteConfig.from_hf(HF, dtype="bfloat16")
+    w = W.make(5, HF, "bfloat16")
+    p = {n: a[1] for n, a in w["layers"].items()}
+    x = jnp.asarray(np.random.default_rng(5).normal(
+        size=(6, cfg.hidden_size)), jnp.float32)
+    pos = jnp.arange(40, 46, dtype=jnp.int32)
+
+    class Access:
+        def decode(self, state, layer, positions, q, k, v, kernel):
+            return state, q.astype(jnp.float32)
+
+    names = ("wq", "wk", "wv")
+    got = rounded_projections(
+        lambda p, x: eb.attention(cfg, p, x, pos, Access(), (), 0, 0,
+                                  "decode")[0],
+        (p, x), [p[n] for n in names])
+    h = eb.norm(cfg, x, p["norm1"])
+    assert h.dtype == jnp.bfloat16
+    for y, n in zip(got, names):
+        assert_same_bf16_rounding(y, h, p[n])
+
+
 # ------------------------------------------------------ correct's teeth
 def test_a_float8_control_fails_where_bfloat16_passes():
     """What the cell's ``correct_limits`` must tell apart, on the
