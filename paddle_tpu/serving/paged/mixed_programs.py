@@ -43,9 +43,9 @@ is ring entry ``t % W``.
       ``tables + layer*NB`` (key in two parts), both by
       ``ops.paged_attention.paged_write_attention`` (the kernel places
       the entry itself; the ``jnp`` path writes blocks first). A window
-      layer: the new key and value into entry ``pos % W`` of the slot's
-      ring, then attention over the ring's ``W`` entries and the sink:
-      what it reads and keeps is the ring, whatever the position.
+      layer: ``ops.slot_ring_decode`` in ONE call over the rings as they
+      are carried (entry ``pos % W`` placed in VMEM, the ``W`` entries
+      and the sink attended, the changed tiles copied back); ``jnp``: CPU.
 
 Parked and released slots: their entries are nobody's
 (``ops.paged_attention.live_write_pos``: the kernel writes none, the
@@ -84,11 +84,11 @@ class PagedAccess:
     the layer loop and put back after it."""
 
     def __init__(self, cfg, num_slots, num_blocks, block_size,
-                 blocks_per_slot, bt_row=None, tables=None):
+                 blocks_per_slot, bt_row=None, tables=None, kernel=False):
         self.cfg, self.S = cfg, int(num_slots)
         self.NB, self.BS = int(num_blocks), int(block_size)
         self.MB = int(blocks_per_slot)
-        self.bt_row, self.tables = bt_row, tables
+        self.bt_row, self.tables, self.kernel = bt_row, tables, kernel
 
     # ---------------------------------------------------------- prefill
     def full_prefill(self, state, li, start, q, k, v, positions, length):
@@ -222,7 +222,7 @@ class PagedAccess:
         return (kf, krf, vf, kring, vring), o
 
     # ----------------------------------------------------------- decode
-    def full_decode(self, state, li, pos, q, k, v, kernel):
+    def full_decode(self, state, li, pos, q, k, v):
         import jax.numpy as jnp
 
         from ...ops import paged_attention as paged_ops
@@ -240,24 +240,42 @@ class PagedAccess:
             wpos = paged_ops.live_write_pos(pos, lengths)
         o, (kf, vf, krf) = paged_ops.paged_write_attention(
             q[..., rd:], new, (kf, vf, krf),
-            self.tables + li * jnp.int32(self.NB), wpos, lengths, kernel,
+            self.tables + li * jnp.int32(self.NB), wpos, lengths, self.kernel,
             q_rot=q[..., :rd])
         return (kf, krf, vf, kring, vring), o
 
     def win_decode(self, state, wi, pos, q, k, v, sink):
+        """q ``[S, nq, hd]``, k ``[S, nkv, hd]``, v ``[S, nkv, dv]`` ->
+        o ``[S, nq, dv]`` f32. With ``kernel`` (the constructor's:
+        ``decode_kernels``) ``ops.slot_ring_decode`` in ONE call over
+        the rings as they are carried; otherwise the ``jnp`` formulation
+        below, the CPU's path and the parity oracle."""
         import jax
         import jax.numpy as jnp
 
         from ...ops import attention as attn_ops
+        from ...ops import slot_ring_decode as ring_ops
         from ...text.mimo_v2 import ring_positions
         kf, krf, vf, kring, vring = state
         W = self.cfg.window
+        # a slot parked mid-prefill keeps its ring as the last chunk
+        # left it; a released one's is nobody's
+        park = jnp.int32(self.MB * self.BS - 1)
+        if self.kernel:
+            # where the entry goes is the write's arithmetic, staged as
+            # a full layer stages ``live_write_pos``
+            with device_scope("kv_write"):
+                entry = jnp.where(pos < park, pos % jnp.int32(W),
+                                  jnp.int32(-1))
+            with device_scope("window"):
+                o, kring, vring = ring_ops.ring_decode_attention(
+                    q, k, v, kring, vring, wi, ring_positions(pos, W),
+                    entry, sink)
+            return (kf, krf, vf, kring, vring), o
         S, nq, hd = q.shape
         nkv = k.shape[1]
         kr, vr = kring[wi], vring[wi]       # [S, nkv, hd, W], [., W, dv]
-        # a slot parked mid-prefill keeps its ring as the last chunk
-        # left it; a released one's is nobody's
-        active = pos < jnp.int32(self.MB * self.BS - 1)
+        active = pos < park
         hot = jnp.logical_and(
             jnp.arange(W, dtype=jnp.int32)[None, :]
             == (pos % jnp.int32(W))[:, None], active[:, None])  # [S, W]
@@ -284,15 +302,17 @@ class PagedAccess:
 
 
 def decode_kernels(cfg, num_slots, block_size):
-    """Whether the decode program runs its two Pallas kernels: yes on
+    """Whether the decode program runs its three Pallas kernels: yes on
     any backend that has Mosaic, and then a shape they cannot take is
     refused here, by name; no on the CPU (the ``jnp`` formulations)."""
     import jax
 
     from ...ops import moe_experts as moe_ops
     from ...ops import paged_attention as paged_ops
+    from ...ops import slot_ring_decode as ring_ops
     if jax.default_backend() == "cpu" and not (
-            moe_ops._FORCE_INTERPRET[0] or paged_ops._FORCE_INTERPRET[0]):
+            moe_ops._FORCE_INTERPRET[0] or paged_ops._FORCE_INTERPRET[0]
+            or ring_ops._FORCE_INTERPRET[0]):
         return False
     if cfg.nope_dim != cfg.v_head_dim:
         raise ValueError(
@@ -308,6 +328,14 @@ def decode_kernels(cfg, num_slots, block_size):
             f"({cfg.kv_heads['full']}, {cfg.v_head_dim}, {cfg.rot_dim}, "
             f"{block_size}, {cfg.cache_dtype}): "
             f"ops.paged_attention.kernel_viable")
+    if cfg.count("win") and not ring_ops.kernel_viable(
+            cfg.kv_heads["win"], cfg.head_dim, cfg.v_head_dim, cfg.window,
+            cfg.cache_dtype):
+        raise ValueError(
+            f"ring_decode_attn cannot take (kv heads, key width, value "
+            f"width, window, cache dtype) = ({cfg.kv_heads['win']}, "
+            f"{cfg.head_dim}, {cfg.v_head_dim}, {cfg.window}, "
+            f"{cfg.cache_dtype}): ops.slot_ring_decode.kernel_viable")
     if cfg.count("moe") and not moe_ops.kernel_viable(
             num_slots, cfg.hidden_size, cfg.moe_intermediate_size,
             cfg.dtype):
@@ -377,7 +405,8 @@ def build_paged_mixed_fns(cfg, num_slots, block_size, num_blocks,
 
     def _decode_core(params, toks, pos, tables, k, kr, v, kring, vring,
                      counts, samp):
-        access = PagedAccess(cfg, S, NB, BS, MB, tables=tables)
+        access = PagedAccess(cfg, S, NB, BS, MB, tables=tables,
+                             kernel=kernels)
         with device_scope("embed"):
             x = params["wemb"][toks]                         # [S, h]
         x, (kf, krf, vf, kring, vring), counts = block.run_layers(

@@ -41,7 +41,7 @@ differ in how a layer reaches its cache):
   ``ring=``).
 
   ``full_prefill(state, li, start, q, k, v, positions, length)``,
-  ``full_decode(state, li, pos, q, k, v, kernel)``,
+  ``full_decode(state, li, pos, q, k, v)``,
   ``win_prefill(state, wi, start, q, k, v, positions, length, sink)``,
   ``win_decode(state, wi, pos, q, k, v, sink)``  each ``-> state, o``.
 
@@ -201,7 +201,7 @@ class MimoV2Config:
 
 # ------------------------------------------------------------ the block
 def attention(cfg, kind, p, x, positions, access, state, i, start, mode,
-              kernel, length):
+              length):
     """One attention layer of ``kind`` with its norm and residual.
     "prefill": x ``[b, T, h]``, positions ``[b, T]``; "decode": x ``[S,
     h]``, positions ``[S]``."""
@@ -229,8 +229,8 @@ def attention(cfg, kind, p, x, positions, access, state, i, start, mode,
         v = v.astype(cdt)
     with device_scope("attn/paged"):
         if kind == "full":
-            state, o = access.full_decode(state, i, positions, q, k, v,
-                                          kernel) if mode == "decode" \
+            state, o = access.full_decode(state, i, positions, q, k, v) \
+                if mode == "decode" \
                 else access.full_prefill(state, i, start, q, k, v,
                                          positions, length)
         else:
@@ -309,7 +309,7 @@ def run_layers(cfg, params, x, positions, access, state, start=0,
         kind, ffn = LAYERS[letter]
         x, state = attention(
             cfg, kind, take_layer(params[kind], at[kind]), x, positions,
-            access, state, at[kind], start, mode, kernel, length)
+            access, state, at[kind], start, mode, length)
         p = take_layer(params[ffn], at[ffn])
         if ffn == "dense":
             x = dense_mlp(cfg, p, x)

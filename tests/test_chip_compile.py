@@ -991,13 +991,55 @@ def test_two_part_key_paged_decode_compiles(one_chip, mosaic_backend):
                                      nkv, 128, BS, rot=64)
 
 
+# the cell's rings: 48 slots x 8 KV heads, a window of 128; keys of 192
+# transposed, values of 128
+RING_SHAPES = [(4, 48, 8, 192, 128), (4, 48, 8, 128, 128)]
+
+
+def test_ring_decode_kernel_compiles(one_chip, mosaic_backend):
+    """The window layers' kernel alone at the cell's shape: 48 slots,
+    8 KV heads x 8 query heads, keys of 192, values of 128, a window of
+    128, 4 layers' rings in bf16, donated: aliased onto the results, no
+    temporary of a ring's size (the queries laid out by group and the
+    new keys a slot a lane are all there is)."""
+    from paddle_tpu.ops import slot_ring_decode
+    S, nq, nkv, hd, dv = 48, 64, 8, 192, 128
+    monkey = mock.patch.object(slot_ring_decode.jax, "default_backend",
+                               lambda: "tpu")
+    with monkey:
+        assert slot_ring_decode.kernel_viable(nkv, hd, dv, 128,
+                                              jnp.bfloat16)
+    assert slot_ring_decode.slots_per_step(
+        S, slot_ring_decode.slot_ring_bytes(
+            nkv, hd, dv, 128, jnp.bfloat16)) == 4
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    rings = tuple(sds(shape) for shape in RING_SHAPES)
+    compiled = jax.jit(slot_ring_decode.ring_decode_attention,
+                       donate_argnums=(3, 4)).lower(
+        sds((S, nq, hd)), sds((S, nkv, hd)), sds((S, nkv, dv)), *rings,
+        sds((), jnp.int32), sds((S, 128), jnp.int32), sds((S,), jnp.int32),
+        sds((nq,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert any(n.startswith("ring_decode_attn")
+               for n in _instruction_names(text))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        int(np.prod(r.shape)) * 2 for r in rings)
+    assert mem.temp_size_in_bytes < 4 << 20, mem.temp_size_in_bytes
+
+
 def test_mixed_decode_program_keeps_rings_and_no_copy_of_the_pool(
         one_chip):
     """The decode program of the cell: blocks and rings aliased onto the
-    results and carried through the layer loop in place, both kernels in
-    it; NOTHING of a window layer grows with ``max_len`` (no array with
-    the window layers' 8 KV heads has a block or position axis), and no
-    copy, slice or update of a pool-shaped array is left."""
+    results and carried through the layer loop in place, all three
+    kernels in it; NOTHING of a window layer grows with ``max_len`` (no
+    array with the window layers' 8 KV heads has a block or position
+    axis), no copy, slice or update of a pool-shaped array is left, and
+    none of a RING-shaped one either: the window layers' kernel takes
+    the rings whole and places the entry itself."""
     (_, decode, params, pool, state, toks, pos, sz, NB, MB, nbytes,
      sds) = _mixed_programs(one_chip)
     n = len(pool)
@@ -1010,7 +1052,8 @@ def test_mixed_decode_program_keeps_rings_and_no_copy_of_the_pool(
     # AOT, PR 43: 2.24 MB (15.3 with the jnp block write in front)
     assert mem.temp_size_in_bytes < 4 << 20, mem.temp_size_in_bytes
     text = compiled.as_text()
-    for kernel in ("paged_decode_attn", "moe_experts_swiglu_decode"):
+    for kernel in ("paged_decode_attn", "moe_experts_swiglu_decode",
+                   "ring_decode_attn"):
         assert kernel in text
     # the kernel places the new entry: no set of 48 gathered blocks
     assert not _pool_shaped(compiled, ["48,4,256,128", "48,4,64,256"],
@@ -1025,9 +1068,20 @@ def test_mixed_decode_program_keeps_rings_and_no_copy_of_the_pool(
         | {f"{lead},4,64,256" for lead in (f"2,{NB}", f"{2 * NB}")}
     assert set(grows) <= full, set(grows) - full
     # the rings are there, at their size, whatever max_len is
-    assert "bf16[4,48,8,192,128]" in text and "bf16[4,48,8,128,128]" in text
+    for shape in RING_SHAPES:
+        assert f"bf16[{','.join(map(str, shape))}]" in text
     moving = ("copy", "dynamic-slice", "dynamic-update-slice")
     bad = [(name, op) for name, op in _pool_shaped(compiled, sorted(full))
+           if op in moving or any(w in name for w in moving)]
+    assert not bad, bad
+    # nor of a ring, all layers' or one layer's: nothing selects an
+    # entry into a ring outside the kernel or moves a ring around it
+    rings = [f"{lead}48,8,{rows},128" for lead in ("4,", "1,", "")
+             for rows in (192, 128)]
+    found = _pool_shaped(compiled, rings)
+    assert found   # the rings are in the program under these shapes
+    moving += ("select",)
+    bad = [(name, op) for name, op in found
            if op in moving or any(w in name for w in moving)]
     assert not bad, bad
 

@@ -352,8 +352,10 @@ def test_expert_shares_add_up_to_the_uncut_layer(shares, mode):
 @pytest.fixture
 def interpret(monkeypatch):
     from paddle_tpu.ops import moe_experts as moe
+    from paddle_tpu.ops import slot_ring_decode as ring
     monkeypatch.setattr(pa, "_FORCE_INTERPRET", [True])
     monkeypatch.setattr(moe, "_FORCE_INTERPRET", [True])
+    monkeypatch.setattr(ring, "_FORCE_INTERPRET", [True])
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
@@ -440,8 +442,9 @@ def test_one_part_calls_keep_their_jaxpr(interpret, shape):
 
 
 def test_engine_with_kernels_in_interpret_mode(interpret):
-    """The decode program with BOTH kernels in it (interpret mode, a
-    key of 128 + 8 beside values of 128) serves the reference's tokens."""
+    """The decode program with ALL THREE kernels in it (interpret mode,
+    a key of 128 + 8 beside values of 128) serves the reference's
+    tokens."""
     m, w, hf = _model(seed=1, head_dim=136, swa_head_dim=136,
                       v_head_dim=128, swa_v_head_dim=128,
                       partial_rotary_factor=0.06, hidden_size=128,
@@ -455,15 +458,17 @@ def test_engine_with_kernels_in_interpret_mode(interpret):
 
 
 def test_engine_kernel_places_entries_like_the_gather_path(monkeypatch):
-    """Both kernels in the decode program, the attention one placing
-    the step's new entry in all three pools of a full layer (interpret
-    mode, a key of 128 + 8 beside values of 128), against the ``jnp``
-    formulations with the block write: eight slots, eleven requests, so
+    """All three kernels in the decode program, the full layers' one
+    placing the step's new entry in all three pools, the window layers'
+    one in the slot's rings (interpret mode, a key of 128 + 8 beside
+    values of 128), against the ``jnp`` formulations with the block
+    write and the ring select: eight slots, eleven requests, so
     slots are released and taken again while released ones keep
     stepping; a prompt of 45 prefilled in chunks of 16 (its slot parked
     in between); outputs of up to 20 tokens over blocks of 8. The same
     tokens on both, each the reference's best."""
     from paddle_tpu.ops import moe_experts as moe
+    from paddle_tpu.ops import slot_ring_decode as ring
     m, w, hf = _model(seed=1, head_dim=136, swa_head_dim=136,
                       v_head_dim=128, swa_v_head_dim=128,
                       partial_rotary_factor=0.06, hidden_size=128,
@@ -476,10 +481,51 @@ def test_engine_kernel_places_entries_like_the_gather_path(monkeypatch):
     for kernel in (True, False):
         monkeypatch.setattr(pa, "_FORCE_INTERPRET", [kernel])
         monkeypatch.setattr(moe, "_FORCE_INTERPRET", [kernel])
+        monkeypatch.setattr(ring, "_FORCE_INTERPRET", [kernel])
         eng = ServingEngine(m, num_slots=8, block_size=8, max_len=96,
                             buckets=[16], prefill_chunk=16)
         reqs = _drive(eng, prompts, new)
         assert eng.pool.reuse_count >= 2
+        served[kernel] = [np.asarray(r.output_ids) for r in reqs]
+        for p, r in zip(prompts, reqs):
+            assert _served_gap(w, p, r, hf) < TOL
+    for a, b in zip(served[True], served[False]):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_ring_kernel_serves_the_jnp_path_tokens_at_depth_12(monkeypatch):
+    """Through the engine with the kernels forced (interpret mode; the
+    window layers' ``ring_decode_attn`` among them) at the cell's depth,
+    12 steps of results unread, against the ``jnp`` path: eight slots,
+    thirteen requests, so slots are released and prefilled again while
+    older steps that still name them are queued, sequences cross the
+    window of 8 several times, and a prompt of 37 is prefilled in
+    chunks of 16 with its slot parked (ring untouched) in between. The
+    same tokens, each the reference's best."""
+    from paddle_tpu.ops import moe_experts as moe
+    from paddle_tpu.ops import slot_ring_decode as ring
+    from paddle_tpu.serving.paged import mixed_programs as mp
+    m, w, hf = _model(seed=1, head_dim=136, swa_head_dim=136,
+                      v_head_dim=128, swa_v_head_dim=128,
+                      partial_rotary_factor=0.06, hidden_size=128,
+                      moe_intermediate_size=128)
+    rng = np.random.default_rng(46)
+    lens = (5, 37, 9, 30, 12, 3, 7, 14, 6, 11, 4, 21, 8)
+    new = (16, 19, 14, 22, 17, 25, 13, 15, 18, 24, 19, 9, 16)
+    prompts = [rng.integers(0, 128, size=n) for n in lens]
+    served = {}
+    for kernel in (True, False):
+        for op in (pa, moe, ring):
+            monkeypatch.setattr(op, "_FORCE_INTERPRET", [kernel])
+        assert mp.decode_kernels(m.cfg, 8, 8) == kernel
+        eng = ServingEngine(m, num_slots=8, block_size=8, max_len=96,
+                            buckets=[16], prefill_chunk=16, async_depth=12)
+        reqs = [eng.add_request(p, max_new_tokens=k)
+                for p, k in zip(prompts, new)]
+        deepest = 0
+        while eng.step():
+            deepest = max(deepest, len(eng._pending_steps))
+        assert deepest == 12 and eng.pool.reuse_count >= 3
         served[kernel] = [np.asarray(r.output_ids) for r in reqs]
         for p, r in zip(prompts, reqs):
             assert _served_gap(w, p, r, hf) < TOL
