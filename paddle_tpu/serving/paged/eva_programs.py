@@ -1,8 +1,8 @@
 """The engine's paged programs for a model whose cache ENTRIES are not
-positions (``text.evabyte``: ``CacheSpec.window``): the same signatures,
-slot bookkeeping and sampling as ``programs.py`` has for the GPT, with
-the model's block IMPORTED, not written out again, and a third program
-that the other models have no use for.
+positions (``text.evabyte``: ``CacheSpec.window``): signatures, slot
+bookkeeping and sampling are ``shell.py``'s, as every model's are, the
+model's block is IMPORTED, not written out again, and there is a third
+program that the other models have no use for.
 
 A slot's table row addresses its entries: ``S = W / C`` summaries for
 every window that is over, then the raw keys and values of the current
@@ -138,22 +138,14 @@ class PagedAccess:
 
 def decode_kernel(cfg, block_size):
     """Whether the decode program's attention is the Pallas paged
-    kernel: yes on any backend that has Mosaic, and then a shape it
-    cannot take is refused here, by name; no on the CPU (the gather)."""
-    import jax
-
+    kernel (``shell.resolve_decode_kernels``)."""
     from ...ops import paged_attention as paged_ops
-    if jax.default_backend() == "cpu" \
-            and not paged_ops._FORCE_INTERPRET[0]:
-        return False
-    if not paged_ops.kernel_viable(cfg.num_heads, cfg.head_dim,
-                                   block_size, cfg.cache_dtype):
-        raise ValueError(
-            f"paged_decode_attn cannot take (heads, head dim, "
-            f"block_size, cache dtype) = ({cfg.num_heads}, "
-            f"{cfg.head_dim}, {block_size}, {cfg.cache_dtype}): "
-            f"ops.paged_attention.kernel_viable")
-    return True
+    from .shell import resolve_decode_kernels
+    given = (cfg.num_heads, cfg.head_dim, block_size, cfg.cache_dtype)
+    return resolve_decode_kernels([
+        (paged_ops, "paged_decode_attn",
+         "heads, head dim, block_size, cache dtype", given,
+         lambda: paged_ops.kernel_viable(*given))])
 
 
 def build_paged_eva_fns(cfg, num_slots, block_size, num_blocks,
@@ -166,7 +158,7 @@ def build_paged_eva_fns(cfg, num_slots, block_size, num_blocks,
 
     from ...ops import eva as eva_ops
     from ...text import evabyte as block
-    from ..sched.sampling import build_sampling_head
+    from .shell import build_paged_programs, flat
 
     NB, BS, MB = int(num_blocks), int(block_size), int(blocks_per_slot)
     W, S = cfg.window_size, cfg.summaries_per_window
@@ -177,15 +169,10 @@ def build_paged_eva_fns(cfg, num_slots, block_size, num_blocks,
             f"compacted window ends on a block boundary")
     if kernel is None:
         kernel = decode_kernel(cfg, block_size)
-    head = build_sampling_head(cfg.vocab_size) if sampling else None
     L, H, d = cfg.num_layers, cfg.num_heads, cfg.head_dim
-    park = jnp.int32(cfg.max_seq_len - 1)
 
-    def flat(a):
-        return a.reshape((L * NB,) + a.shape[2:])
-
-    def _prefill_core(params, tokens, tail_len, start, slot, final,
-                      bt_row, toks, pos, k, v, samp):
+    def prefill_body(params, tokens, tail_len, start, slot, bt_row, cache):
+        k, v = cache
         B = tokens.shape[1]
         if B > W:
             raise ValueError(f"a prefill run of {B} positions is wider "
@@ -194,41 +181,22 @@ def build_paged_eva_fns(cfg, num_slots, block_size, num_blocks,
         access = PagedAccess(cfg, NB, BS, MB, bt_row=bt_row)
         x = block.embed(cfg, params, tokens)                 # [1, B, h]
         positions = (start + jnp.arange(B, dtype=jnp.int32))[None]
-        x, (kf, vf) = block.run_layers(
+        x, cache = block.run_layers(
             cfg, params, x, positions, access, (flat(k), flat(v)), start,
             "prefill", length=tail_len)
         # ONE row through the head, as a [1, h] matmul
         last = block.lm_head(cfg, params, jax.lax.dynamic_slice_in_dim(
             x[0], tail_len - 1, 1, axis=0))[0]
-        with device_scope("sample"):
-            if samp is None:
-                first = jnp.argmax(last, -1).astype(jnp.int32)
-            else:
-                seed, temp, topk, topp = samp
-                first = head(last[None], seed[None],
-                             (start + tail_len - 1)[None], temp[None],
-                             topk[None], topp[None])[0]
-            toks = jnp.where(final > 0, toks.at[slot].set(first), toks)
-            pos = pos.at[slot].set(
-                jnp.where(final > 0, start + tail_len, park))
-        return first[None], toks, pos, kf.reshape(k.shape), \
-            vf.reshape(v.shape)
+        return last, cache
 
-    def _decode_core(params, toks, pos, tables, k, v, samp):
+    def decode_body(params, toks, pos, tables, cache, state):
+        k, v = cache
         access = PagedAccess(cfg, NB, BS, MB, tables=tables)
         x = block.embed(cfg, params, toks)                   # [S, h]
-        x, (kf, vf) = block.run_layers(
+        x, cache = block.run_layers(
             cfg, params, x, pos, access, (flat(k), flat(v)),
             mode="decode", kernel=kernel)
-        logits = block.lm_head(cfg, params, x)
-        with device_scope("sample"):
-            if samp is None:
-                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            else:
-                seeds, temps, topks, topps = samp
-                nxt = head(logits, seeds, pos, temps, topks, topps)
-        return nxt, pos + jnp.int32(1), kf.reshape(k.shape), \
-            vf.reshape(v.shape)
+        return block.lm_head(cfg, params, x), cache, state
 
     def paged_compact(params, window, bt_row, k, v):
         nw, ns = W // BS, S // BS
@@ -254,25 +222,10 @@ def build_paged_eva_fns(cfg, num_slots, block_size, num_blocks,
              jnp.arange(L, dtype=jnp.int32)))
         return kf.reshape(k.shape), vf.reshape(v.shape)
 
-    if sampling:
-        def paged_prefill(params, tokens, tail_len, start, slot, final,
-                          bt_row, toks, pos, k, v, seed, temp, topk,
-                          topp):
-            return _prefill_core(params, tokens, tail_len, start, slot,
-                                 final, bt_row, toks, pos, k, v,
-                                 (seed, temp, topk, topp))
-
-        def paged_decode(params, toks, pos, tables, k, v, seeds, temps,
-                         topks, topps):
-            return _decode_core(params, toks, pos, tables, k, v,
-                                (seeds, temps, topks, topps))
-    else:
-        def paged_prefill(params, tokens, tail_len, start, slot, final,
-                          bt_row, toks, pos, k, v):
-            return _prefill_core(params, tokens, tail_len, start, slot,
-                                 final, bt_row, toks, pos, k, v, None)
-
-        def paged_decode(params, toks, pos, tables, k, v):
-            return _decode_core(params, toks, pos, tables, k, v, None)
-
-    return paged_prefill, paged_decode, paged_compact
+    # a slot parked between the chunks of its prefill sits at the
+    # model's last POSITION: its entries are fewer than its positions.
+    # An array made here, outside the trace, as it has been since PR 37:
+    # the prefill program's jaxpr holds it as a constant, not a literal
+    return build_paged_programs(
+        prefill_body, decode_body, cfg.vocab_size, sampling,
+        park=jnp.int32(cfg.max_seq_len - 1)) + (paged_compact,)

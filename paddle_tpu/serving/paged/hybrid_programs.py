@@ -2,11 +2,11 @@
 keeps TWO kinds of state side by side (``text.nemotron_h``): keys and
 values a token owns, in the paged pool behind the block tables, and a
 convolution window and a recurrent state a SLOT owns, in per-slot arrays
-(``cache_spec``: per-slot leaves). The same signatures, slot bookkeeping
-and sampling as ``programs.py`` has for the GPT, with the model's block
-IMPORTED, not written out again. What is here is only how a layer
-reaches its cache (``PagedAccess``) and what the engine's calling
-convention asks of a program.
+(``cache_spec``: per-slot leaves). Signatures, slot bookkeeping and
+sampling are ``shell.py``'s, as every model's are, and the model's block
+is IMPORTED, not written out again. What is here is how a layer reaches
+its cache (``PagedAccess``), the kernels it needs and the two bodies the
+shell wraps.
 
   ``paged_prefill(params, tokens [1, B], tail_len, start, slot, final,
                   bt_row [MB], toks [S], pos [S], k, v, conv, ssm
@@ -141,41 +141,27 @@ class PagedAccess:
 
 
 def decode_kernels(cfg, num_slots, block_size):
-    """Whether the decode program runs its three Pallas kernels: yes on
-    any backend that has Mosaic, and then a shape they cannot take is
-    refused here, by name; no on the CPU (the ``jnp`` formulations)."""
-    import jax
-
+    """Whether the decode program runs its three Pallas kernels
+    (``shell.resolve_decode_kernels``)."""
     from ...ops import moe_experts as moe_ops
     from ...ops import paged_attention as paged_ops
     from ...ops import ssm as ssm_ops
-    if jax.default_backend() == "cpu" and not (
-            moe_ops._FORCE_INTERPRET[0] or paged_ops._FORCE_INTERPRET[0]
-            or ssm_ops._FORCE_INTERPRET[0]):
-        return False
-    if cfg.count("*") and not paged_ops.kernel_viable(
-            cfg.num_kv_heads, cfg.head_dim, block_size, cfg.cache_dtype):
-        raise ValueError(
-            f"paged_decode_attn cannot take (kv heads, head dim, "
-            f"block_size, cache dtype) = ({cfg.num_kv_heads}, "
-            f"{cfg.head_dim}, {block_size}, {cfg.cache_dtype}): "
-            f"ops.paged_attention.kernel_viable")
-    if cfg.count("M") and not ssm_ops.kernel_viable(
-            cfg.mamba_heads, cfg.mamba_head_dim, cfg.state_size,
-            cfg.n_groups):
-        raise ValueError(
-            f"ssm_decode_step cannot take (heads, head dim, state size, "
-            f"groups) = ({cfg.mamba_heads}, {cfg.mamba_head_dim}, "
-            f"{cfg.state_size}, {cfg.n_groups}): ops.ssm.kernel_viable")
-    if cfg.count("E") and not moe_ops.kernel_viable(
-            num_slots, cfg.hidden_size, cfg.moe_intermediate_size,
-            cfg.dtype, gated=False):
-        raise ValueError(
-            f"moe_experts_relu2_decode cannot take (slots, hidden, "
-            f"expert width, dtype) = ({num_slots}, {cfg.hidden_size}, "
-            f"{cfg.moe_intermediate_size}, {cfg.dtype}): "
-            f"ops.moe_experts.kernel_viable")
-    return True
+    from .shell import resolve_decode_kernels
+    attn = (cfg.num_kv_heads, cfg.head_dim, block_size, cfg.cache_dtype)
+    ssm = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.state_size,
+           cfg.n_groups)
+    moe = (num_slots, cfg.hidden_size, cfg.moe_intermediate_size, cfg.dtype)
+    return resolve_decode_kernels([
+        (paged_ops, "paged_decode_attn",
+         "kv heads, head dim, block_size, cache dtype", attn,
+         lambda: not cfg.count("*") or paged_ops.kernel_viable(*attn)),
+        (ssm_ops, "ssm_decode_step",
+         "heads, head dim, state size, groups", ssm,
+         lambda: not cfg.count("M") or ssm_ops.kernel_viable(*ssm)),
+        (moe_ops, "moe_experts_relu2_decode",
+         "slots, hidden, expert width, dtype", moe,
+         lambda: not cfg.count("E")
+         or moe_ops.kernel_viable(*moe, gated=False))])
 
 
 def build_paged_hybrid_fns(cfg, num_slots, block_size, num_blocks,
@@ -186,20 +172,15 @@ def build_paged_hybrid_fns(cfg, num_slots, block_size, num_blocks,
     import jax.numpy as jnp
 
     from ...text import nemotron_h as block
-    from ..sched.sampling import build_sampling_head
+    from .shell import build_paged_programs, flat
 
     if kernels is None:
         kernels = decode_kernels(cfg, num_slots, block_size)
-    head = build_sampling_head(cfg.vocab_size) if sampling else None
     S = int(num_slots)
     NB, BS, MB = int(num_blocks), int(block_size), int(blocks_per_slot)
-    C = MB * BS
 
-    def flat(a):
-        return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
-
-    def _prefill_core(params, tokens, tail_len, start, slot, final,
-                      bt_row, toks, pos, k, v, conv, ssm, samp):
+    def prefill_body(params, tokens, tail_len, start, slot, bt_row, cache):
+        k, v, conv, ssm = cache
         B = tokens.shape[1]
         access = PagedAccess(cfg, S, NB, BS, MB, bt_row=bt_row)
         with device_scope("embed"):
@@ -219,61 +200,19 @@ def build_paged_hybrid_fns(cfg, num_slots, block_size, num_blocks,
         # product is elementwise and XLA upcasts the whole head to f32
         last = block.lm_head(cfg, params, jax.lax.dynamic_slice_in_dim(
             x[0], tail_len - 1, 1, axis=0))[0]
-        with device_scope("sample"):
-            if samp is None:
-                first = jnp.argmax(last, -1).astype(jnp.int32)
-            else:
-                seed, temp, topk, topp = samp
-                first = head(last[None], seed[None],
-                             (start + tail_len - 1)[None], temp[None],
-                             topk[None], topp[None])[0]
-            toks = jnp.where(final > 0, toks.at[slot].set(first), toks)
-            pos = pos.at[slot].set(
-                jnp.where(final > 0, start + tail_len, jnp.int32(C - 1)))
-        return first[None], toks, pos, kf.reshape(k.shape), \
-            vf.reshape(v.shape), conv, ssm
+        return last, (kf, vf, conv, ssm)
 
-    def _decode_core(params, toks, pos, tables, k, v, conv, ssm, counts,
-                     samp):
+    def decode_body(params, toks, pos, tables, cache, state):
+        k, v, conv, ssm = cache
         access = PagedAccess(cfg, S, NB, BS, MB, tables=tables)
         with device_scope("embed"):
             x = params["wemb"][toks]                         # [S, h]
-        x, (kf, vf, conv, sf), counts = block.run_layers(
+        x, cache, counts = block.run_layers(
             cfg, params, x, pos, access,
             (flat(k), flat(v), conv, flat(ssm)), mode="decode",
-            kernel=kernels, counts=counts)
-        logits = block.lm_head(cfg, params, x)
-        with device_scope("sample"):
-            if samp is None:
-                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            else:
-                seeds, temps, topks, topps = samp
-                nxt = head(logits, seeds, pos, temps, topks, topps)
-        return nxt, pos + jnp.int32(1), kf.reshape(k.shape), \
-            vf.reshape(v.shape), conv, sf.reshape(ssm.shape), counts
+            kernel=kernels, counts=state[0])
+        return block.lm_head(cfg, params, x), cache, (counts,)
 
-    if sampling:
-        def paged_prefill(params, tokens, tail_len, start, slot, final,
-                          bt_row, toks, pos, k, v, conv, ssm, seed, temp,
-                          topk, topp):
-            return _prefill_core(params, tokens, tail_len, start, slot,
-                                 final, bt_row, toks, pos, k, v, conv, ssm,
-                                 (seed, temp, topk, topp))
-
-        def paged_decode(params, toks, pos, tables, k, v, conv, ssm,
-                         counts, seeds, temps, topks, topps):
-            return _decode_core(params, toks, pos, tables, k, v, conv,
-                                ssm, counts, (seeds, temps, topks, topps))
-    else:
-        def paged_prefill(params, tokens, tail_len, start, slot, final,
-                          bt_row, toks, pos, k, v, conv, ssm):
-            return _prefill_core(params, tokens, tail_len, start, slot,
-                                 final, bt_row, toks, pos, k, v, conv, ssm,
-                                 None)
-
-        def paged_decode(params, toks, pos, tables, k, v, conv, ssm,
-                         counts):
-            return _decode_core(params, toks, pos, tables, k, v, conv,
-                                ssm, counts, None)
-
-    return paged_prefill, paged_decode
+    return build_paged_programs(
+        prefill_body, decode_body, cfg.vocab_size, sampling,
+        park=MB * BS - 1, num_state=len(block.hybrid_cache_spec(cfg).state))

@@ -1,9 +1,9 @@
 """The engine's paged prefill and decode programs for a model whose
-cache is a LATENT a token (``text.deepseek_v3``): the same signatures,
-slot bookkeeping and sampling as ``programs.py`` has for the GPT, with
-the model's block IMPORTED, not written out again. What is here is only
-how a layer reaches the paged pool (``PagedAccess``) and what the
-engine's calling convention asks of a program.
+cache is a LATENT a token (``text.deepseek_v3``): signatures, slot
+bookkeeping and sampling are ``shell.py``'s, as every model's are, and
+the model's block is IMPORTED, not written out again. What is here is
+how a layer reaches the paged pool (``PagedAccess``), the kernels it
+needs and the two bodies the shell wraps.
 
   ``paged_prefill(params, tokens [1, B], tail_len, start, slot, final,
                   bt_row [MB], toks [S], pos [S], c, k_pe[, samp...])
@@ -99,117 +99,59 @@ class PagedAccess:
 
 
 def decode_kernels(cfg, num_slots, block_size):
-    """Whether the decode program runs its two Pallas kernels: yes on
-    any backend that has Mosaic, and then a shape they cannot take is
-    refused here, by name; no on the CPU (the ``jnp`` formulations)."""
-    import jax
-
+    """Whether the decode program runs its two Pallas kernels
+    (``shell.resolve_decode_kernels``)."""
     from ...ops import mla_attention as mla_ops
     from ...ops import moe_experts as moe_ops
-    if jax.default_backend() == "cpu" and not (
-            mla_ops._FORCE_INTERPRET[0] or moe_ops._FORCE_INTERPRET[0]):
-        return False
-    if not mla_ops.kernel_viable(block_size, cfg.kv_lora_rank,
-                                 cfg.qk_rope_head_dim, cfg.cache_dtype):
-        raise ValueError(
-            f"mla_paged_decode_attn cannot take (block_size, rank, rope "
-            f"dim, cache dtype) = ({block_size}, {cfg.kv_lora_rank}, "
-            f"{cfg.qk_rope_head_dim}, {cfg.cache_dtype}): "
-            f"ops.mla_attention.kernel_viable")
-    if cfg.num_moe_layers and not moe_ops.kernel_viable(
-            num_slots, cfg.hidden_size, cfg.moe_intermediate_size,
-            cfg.dtype):
-        raise ValueError(
-            f"moe_experts_swiglu_decode cannot take (slots, hidden, "
-            f"expert width, dtype) = ({num_slots}, {cfg.hidden_size}, "
-            f"{cfg.moe_intermediate_size}, {cfg.dtype}): "
-            f"ops.moe_experts.kernel_viable")
-    return True
+    from .shell import resolve_decode_kernels
+    attn = (block_size, cfg.kv_lora_rank, cfg.qk_rope_head_dim,
+            cfg.cache_dtype)
+    moe = (num_slots, cfg.hidden_size, cfg.moe_intermediate_size, cfg.dtype)
+    return resolve_decode_kernels([
+        (mla_ops, "mla_paged_decode_attn",
+         "block_size, rank, rope dim, cache dtype", attn,
+         lambda: mla_ops.kernel_viable(*attn)),
+        (moe_ops, "moe_experts_swiglu_decode",
+         "slots, hidden, expert width, dtype", moe,
+         lambda: not cfg.num_moe_layers or moe_ops.kernel_viable(*moe))])
 
 
 def build_paged_latent_fns(cfg, num_slots, block_size, num_blocks,
                            blocks_per_slot, sampling=False, kernels=None):
     """(paged_prefill, paged_decode) for a ``DeepseekV3Config``. Pure
     and shape-stable; ``kernels=None`` asks ``decode_kernels``."""
-    import jax
     import jax.numpy as jnp
 
     from ...text import deepseek_v3 as block
-    from ..sched.sampling import build_sampling_head
+    from .shell import build_paged_programs, flat
 
     if kernels is None:
         kernels = decode_kernels(cfg, num_slots, block_size)
-    head = build_sampling_head(cfg.vocab_size) if sampling else None
-    L = cfg.num_layers
     NB, BS, MB = int(num_blocks), int(block_size), int(blocks_per_slot)
-    C = MB * BS
 
-    def flat(a):
-        return a.reshape((L * NB,) + a.shape[2:])
-
-    def _prefill_core(params, tokens, tail_len, start, slot, final,
-                      bt_row, toks, pos, c, k_pe, samp):
+    def prefill_body(params, tokens, tail_len, start, slot, bt_row, cache):
+        c, k_pe = cache
         B = tokens.shape[1]
         access = PagedAccess(NB, BS, MB, bt_row=bt_row)
         with device_scope("embed"):
             x = params["wemb"][tokens]                       # [1, B, h]
         positions = (start + jnp.arange(B, dtype=jnp.int32))[None]
-        x, (cf, pf), _ = block.run_layers(
+        x, cache, _ = block.run_layers(
             cfg, params, x, positions, access, (flat(c), flat(k_pe)),
             start, "prefill")
-        last = block.lm_head(cfg, params,
-                             jnp.take(x[0], tail_len - 1, axis=0))
-        with device_scope("sample"):
-            if samp is None:
-                first = jnp.argmax(last, -1).astype(jnp.int32)
-            else:
-                seed, temp, topk, topp = samp
-                first = head(last[None], seed[None],
-                             (start + tail_len - 1)[None], temp[None],
-                             topk[None], topp[None])[0]
-            toks = jnp.where(final > 0, toks.at[slot].set(first), toks)
-            pos = pos.at[slot].set(
-                jnp.where(final > 0, start + tail_len, jnp.int32(C - 1)))
-        return first[None], toks, pos, cf.reshape(c.shape), \
-            pf.reshape(k_pe.shape)
+        return block.lm_head(
+            cfg, params, jnp.take(x[0], tail_len - 1, axis=0)), cache
 
-    def _decode_core(params, toks, pos, tables, c, k_pe, counts, samp):
+    def decode_body(params, toks, pos, tables, cache, state):
+        c, k_pe = cache
         access = PagedAccess(NB, BS, MB, tables=tables, kernel=kernels)
         with device_scope("embed"):
             x = params["wemb"][toks]                         # [S, h]
-        x, (cf, pf), counts = block.run_layers(
+        x, cache, counts = block.run_layers(
             cfg, params, x, pos, access, (flat(c), flat(k_pe)),
-            mode="decode", kernel=kernels, counts=counts)
-        logits = block.lm_head(cfg, params, x)
-        with device_scope("sample"):
-            if samp is None:
-                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            else:
-                seeds, temps, topks, topps = samp
-                nxt = head(logits, seeds, pos, temps, topks, topps)
-        return nxt, pos + jnp.int32(1), cf.reshape(c.shape), \
-            pf.reshape(k_pe.shape), counts
+            mode="decode", kernel=kernels, counts=state[0])
+        return block.lm_head(cfg, params, x), cache, (counts,)
 
-    if sampling:
-        def paged_prefill(params, tokens, tail_len, start, slot, final,
-                          bt_row, toks, pos, c, k_pe, seed, temp, topk,
-                          topp):
-            return _prefill_core(params, tokens, tail_len, start, slot,
-                                 final, bt_row, toks, pos, c, k_pe,
-                                 (seed, temp, topk, topp))
-
-        def paged_decode(params, toks, pos, tables, c, k_pe, counts,
-                         seeds, temps, topks, topps):
-            return _decode_core(params, toks, pos, tables, c, k_pe,
-                                counts, (seeds, temps, topks, topps))
-    else:
-        def paged_prefill(params, tokens, tail_len, start, slot, final,
-                          bt_row, toks, pos, c, k_pe):
-            return _prefill_core(params, tokens, tail_len, start, slot,
-                                 final, bt_row, toks, pos, c, k_pe, None)
-
-        def paged_decode(params, toks, pos, tables, c, k_pe, counts):
-            return _decode_core(params, toks, pos, tables, c, k_pe,
-                                counts, None)
-
-    return paged_prefill, paged_decode
+    return build_paged_programs(
+        prefill_body, decode_body, cfg.vocab_size, sampling,
+        park=MB * BS - 1, num_state=len(block.latent_cache_spec(cfg).state))

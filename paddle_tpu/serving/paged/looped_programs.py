@@ -3,9 +3,9 @@
 weights, every pass of every layer with keys and values of its own. The
 pool's arrays are ``k, v [R * L, NB, nkv, BS, hd]``: pass ``r`` of layer
 ``l`` is entry ``r * L + l``, reached through the ONE block table a
-slot as ``tables + (r * L + l) * NB``. The same signatures, slot
-bookkeeping and sampling as ``programs.py`` has for the GPT, with the
-model's block IMPORTED, not written out again, and keys and values
+slot as ``tables + (r * L + l) * NB``. Signatures, slot
+bookkeeping and sampling are ``shell.py``'s, as every model's are, the
+model's block is IMPORTED, not written out again, and keys and values are
 reached through ``hybrid_programs.PagedAccess`` (``attn_prefill`` /
 ``attn_decode``, whose index is the entry; of its state ``(k, v, conv,
 ssm)`` this model has the first two).
@@ -40,23 +40,15 @@ from ...profiler import device_scope
 
 
 def decode_kernel(cfg, block_size):
-    """Whether the decode program runs the paged attention kernel: yes
-    on any backend that has Mosaic, and then a shape it cannot take is
-    refused here, by name; no on the CPU (the ``jnp`` formulation)."""
-    import jax
-
+    """Whether the decode program runs the paged attention kernel
+    (``shell.resolve_decode_kernels``)."""
     from ...ops import paged_attention as paged_ops
-    if jax.default_backend() == "cpu" \
-            and not paged_ops._FORCE_INTERPRET[0]:
-        return False
-    if not paged_ops.kernel_viable(cfg.num_kv_heads, cfg.head_dim,
-                                   block_size, cfg.cache_dtype):
-        raise ValueError(
-            f"paged_decode_attn cannot take (kv heads, head dim, "
-            f"block_size, cache dtype) = ({cfg.num_kv_heads}, "
-            f"{cfg.head_dim}, {block_size}, {cfg.cache_dtype}): "
-            f"ops.paged_attention.kernel_viable")
-    return True
+    from .shell import resolve_decode_kernels
+    given = (cfg.num_kv_heads, cfg.head_dim, block_size, cfg.cache_dtype)
+    return resolve_decode_kernels([
+        (paged_ops, "paged_decode_attn",
+         "kv heads, head dim, block_size, cache dtype", given,
+         lambda: paged_ops.kernel_viable(*given))])
 
 
 def build_paged_looped_fns(cfg, num_slots, block_size, num_blocks,
@@ -67,23 +59,19 @@ def build_paged_looped_fns(cfg, num_slots, block_size, num_blocks,
     import jax.numpy as jnp
 
     from ...text import ouro as block
-    from ..sched.sampling import build_sampling_head
     from .hybrid_programs import PagedAccess
     from .pool import TRASH_BLOCK
+    from .shell import build_paged_programs, flat
 
     if kernel is None:
         kernel = decode_kernel(cfg, block_size)
-    head = build_sampling_head(cfg.vocab_size) if sampling else None
     S = int(num_slots)
     NB, BS, MB = int(num_blocks), int(block_size), int(blocks_per_slot)
     C = MB * BS
     R = cfg.num_passes
 
-    def flat(a):
-        return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
-
-    def _prefill_core(params, tokens, tail_len, start, slot, final,
-                      bt_row, toks, pos, k, v, samp):
+    def prefill_body(params, tokens, tail_len, start, slot, bt_row, cache):
+        k, v = cache
         B = tokens.shape[1]
         access = PagedAccess(cfg, S, NB, BS, MB, bt_row=bt_row)
         with device_scope("embed"):
@@ -98,21 +86,11 @@ def build_paged_looped_fns(cfg, num_slots, block_size, num_blocks,
         last = block.head(params, block.read_exit(
             cfg, row, jax.lax.dynamic_slice_in_dim(
                 p[:, 0], tail_len - 1, 1, 1))[0])[0]
-        with device_scope("sample"):
-            if samp is None:
-                first = jnp.argmax(last, -1).astype(jnp.int32)
-            else:
-                seed, temp, topk, topp = samp
-                first = head(last[None], seed[None],
-                             (start + tail_len - 1)[None], temp[None],
-                             topk[None], topp[None])[0]
-            toks = jnp.where(final > 0, toks.at[slot].set(first), toks)
-            pos = pos.at[slot].set(
-                jnp.where(final > 0, start + tail_len, jnp.int32(C - 1)))
-        return first[None], toks, pos, kf.reshape(k.shape), \
-            vf.reshape(v.shape)
+        return last, (kf, vf)
 
-    def _decode_core(params, toks, pos, tables, k, v, counts, mass, samp):
+    def decode_body(params, toks, pos, tables, cache, state):
+        k, v = cache
+        counts, mass = state
         access = PagedAccess(cfg, S, NB, BS, MB, tables=tables)
         with device_scope("embed"):
             x = params["wemb"][toks]                         # [S, h]
@@ -132,35 +110,8 @@ def build_paged_looped_fns(cfg, num_slots, block_size, num_blocks,
             counts = counts + jnp.concatenate(
                 [exits, jnp.int32(R) * jnp.sum(live, dtype=jnp.int32)[None]])
             mass = mass + jnp.sum(jnp.where(live[None, :], p, 0.0), axis=1)
-        logits = block.head(params, h)
-        with device_scope("sample"):
-            if samp is None:
-                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            else:
-                seeds, temps, topks, topps = samp
-                nxt = head(logits, seeds, pos, temps, topks, topps)
-        return nxt, pos + jnp.int32(1), kf.reshape(k.shape), \
-            vf.reshape(v.shape), counts, mass
+        return block.head(params, h), (kf, vf), (counts, mass)
 
-    if sampling:
-        def paged_prefill(params, tokens, tail_len, start, slot, final,
-                          bt_row, toks, pos, k, v, seed, temp, topk, topp):
-            return _prefill_core(params, tokens, tail_len, start, slot,
-                                 final, bt_row, toks, pos, k, v,
-                                 (seed, temp, topk, topp))
-
-        def paged_decode(params, toks, pos, tables, k, v, counts, mass,
-                         seeds, temps, topks, topps):
-            return _decode_core(params, toks, pos, tables, k, v, counts,
-                                mass, (seeds, temps, topks, topps))
-    else:
-        def paged_prefill(params, tokens, tail_len, start, slot, final,
-                          bt_row, toks, pos, k, v):
-            return _prefill_core(params, tokens, tail_len, start, slot,
-                                 final, bt_row, toks, pos, k, v, None)
-
-        def paged_decode(params, toks, pos, tables, k, v, counts, mass):
-            return _decode_core(params, toks, pos, tables, k, v, counts,
-                                mass, None)
-
-    return paged_prefill, paged_decode
+    return build_paged_programs(
+        prefill_body, decode_body, cfg.vocab_size, sampling, park=C - 1,
+        num_state=len(block.looped_cache_spec(cfg).state))

@@ -3,11 +3,11 @@ FULL and WINDOW attention layers live side by side (``text.mimo_v2``):
 a full layer keeps every position in the paged pool behind the block
 tables, a window layer keeps a RING of ``W`` entries a slot in per-slot
 arrays (``cache_spec``: per-slot leaves, ``ring=W``) and NOTHING that
-grows with the position. The same signatures, slot bookkeeping and
-sampling as ``programs.py`` has for the GPT, with the model's block
-IMPORTED, not written out again. What is here is only how a layer
-reaches its cache (``PagedAccess``) and what the engine's calling
-convention asks of a program.
+grows with the position. Signatures, slot bookkeeping and sampling are
+``shell.py``'s, as every model's are, and the model's block is IMPORTED,
+not written out again. What is here is how a layer reaches its cache
+(``PagedAccess``), the kernels it needs and the two bodies the shell
+wraps.
 
 The pool's arrays, in the order the programs take them: ``k [Lf, NB,
 nkv, BS, dn]`` (a key's un-rotated lanes, as wide as a value), ``kr [Lf,
@@ -302,49 +302,37 @@ class PagedAccess:
 
 
 def decode_kernels(cfg, num_slots, block_size):
-    """Whether the decode program runs its three Pallas kernels: yes on
-    any backend that has Mosaic, and then a shape they cannot take is
-    refused here, by name; no on the CPU (the ``jnp`` formulations)."""
-    import jax
-
+    """Whether the decode program runs its three Pallas kernels
+    (``shell.resolve_decode_kernels``)."""
     from ...ops import moe_experts as moe_ops
     from ...ops import paged_attention as paged_ops
     from ...ops import slot_ring_decode as ring_ops
-    if jax.default_backend() == "cpu" and not (
-            moe_ops._FORCE_INTERPRET[0] or paged_ops._FORCE_INTERPRET[0]
-            or ring_ops._FORCE_INTERPRET[0]):
-        return False
-    if cfg.nope_dim != cfg.v_head_dim:
-        raise ValueError(
-            f"paged_decode_attn takes a key whose un-rotated lanes are "
-            f"as wide as its value: got {cfg.nope_dim} beside "
-            f"{cfg.v_head_dim}")
-    if cfg.count("full") and not paged_ops.kernel_viable(
+    from .shell import resolve_decode_kernels
+
+    def full_viable():
+        if cfg.nope_dim != cfg.v_head_dim:
+            raise ValueError(
+                f"paged_decode_attn takes a key whose un-rotated lanes "
+                f"are as wide as its value: got {cfg.nope_dim} beside "
+                f"{cfg.v_head_dim}")
+        return not cfg.count("full") or paged_ops.kernel_viable(
             cfg.kv_heads["full"], cfg.v_head_dim, block_size,
-            cfg.cache_dtype, cfg.rot_dim):
-        raise ValueError(
-            f"paged_decode_attn cannot take (kv heads, key lanes, "
-            f"rotated lanes, block_size, cache dtype) = "
-            f"({cfg.kv_heads['full']}, {cfg.v_head_dim}, {cfg.rot_dim}, "
-            f"{block_size}, {cfg.cache_dtype}): "
-            f"ops.paged_attention.kernel_viable")
-    if cfg.count("win") and not ring_ops.kernel_viable(
-            cfg.kv_heads["win"], cfg.head_dim, cfg.v_head_dim, cfg.window,
-            cfg.cache_dtype):
-        raise ValueError(
-            f"ring_decode_attn cannot take (kv heads, key width, value "
-            f"width, window, cache dtype) = ({cfg.kv_heads['win']}, "
-            f"{cfg.head_dim}, {cfg.v_head_dim}, {cfg.window}, "
-            f"{cfg.cache_dtype}): ops.slot_ring_decode.kernel_viable")
-    if cfg.count("moe") and not moe_ops.kernel_viable(
-            num_slots, cfg.hidden_size, cfg.moe_intermediate_size,
-            cfg.dtype):
-        raise ValueError(
-            f"moe_experts_swiglu_decode cannot take (slots, hidden, "
-            f"expert width, dtype) = ({num_slots}, {cfg.hidden_size}, "
-            f"{cfg.moe_intermediate_size}, {cfg.dtype}): "
-            f"ops.moe_experts.kernel_viable")
-    return True
+            cfg.cache_dtype, cfg.rot_dim)
+
+    win = (cfg.kv_heads["win"], cfg.head_dim, cfg.v_head_dim, cfg.window,
+           cfg.cache_dtype)
+    moe = (num_slots, cfg.hidden_size, cfg.moe_intermediate_size, cfg.dtype)
+    return resolve_decode_kernels([
+        (paged_ops, "paged_decode_attn",
+         "kv heads, key lanes, rotated lanes, block_size, cache dtype",
+         (cfg.kv_heads["full"], cfg.v_head_dim, cfg.rot_dim, block_size,
+          cfg.cache_dtype), full_viable),
+        (ring_ops, "ring_decode_attn",
+         "kv heads, key width, value width, window, cache dtype", win,
+         lambda: not cfg.count("win") or ring_ops.kernel_viable(*win)),
+        (moe_ops, "moe_experts_swiglu_decode",
+         "slots, hidden, expert width, dtype", moe,
+         lambda: not cfg.count("moe") or moe_ops.kernel_viable(*moe))])
 
 
 def build_paged_mixed_fns(cfg, num_slots, block_size, num_blocks,
@@ -355,20 +343,15 @@ def build_paged_mixed_fns(cfg, num_slots, block_size, num_blocks,
     import jax.numpy as jnp
 
     from ...text import mimo_v2 as block
-    from ..sched.sampling import build_sampling_head
+    from .shell import build_paged_programs, flat
 
     if kernels is None:
         kernels = decode_kernels(cfg, num_slots, block_size)
-    head = build_sampling_head(cfg.vocab_size) if sampling else None
     S = int(num_slots)
     NB, BS, MB = int(num_blocks), int(block_size), int(blocks_per_slot)
-    C = MB * BS
 
-    def flat(a):
-        return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
-
-    def _prefill_core(params, tokens, tail_len, start, slot, final,
-                      bt_row, toks, pos, k, kr, v, kring, vring, samp):
+    def prefill_body(params, tokens, tail_len, start, slot, bt_row, cache):
+        k, kr, v, kring, vring = cache
         B = tokens.shape[1]
         access = PagedAccess(cfg, S, NB, BS, MB, bt_row=bt_row)
         with device_scope("embed"):
@@ -389,64 +372,20 @@ def build_paged_mixed_fns(cfg, num_slots, block_size, num_blocks,
         # product is elementwise and XLA upcasts the whole head to f32
         last = block.lm_head(cfg, params, jax.lax.dynamic_slice_in_dim(
             x[0], tail_len - 1, 1, axis=0))[0]
-        with device_scope("sample"):
-            if samp is None:
-                first = jnp.argmax(last, -1).astype(jnp.int32)
-            else:
-                seed, temp, topk, topp = samp
-                first = head(last[None], seed[None],
-                             (start + tail_len - 1)[None], temp[None],
-                             topk[None], topp[None])[0]
-            toks = jnp.where(final > 0, toks.at[slot].set(first), toks)
-            pos = pos.at[slot].set(
-                jnp.where(final > 0, start + tail_len, jnp.int32(C - 1)))
-        return first[None], toks, pos, kf.reshape(k.shape), \
-            krf.reshape(kr.shape), vf.reshape(v.shape), kring, vring
+        return last, (kf, krf, vf, kring, vring)
 
-    def _decode_core(params, toks, pos, tables, k, kr, v, kring, vring,
-                     counts, samp):
+    def decode_body(params, toks, pos, tables, cache, state):
+        k, kr, v, kring, vring = cache
         access = PagedAccess(cfg, S, NB, BS, MB, tables=tables,
                              kernel=kernels)
         with device_scope("embed"):
             x = params["wemb"][toks]                         # [S, h]
-        x, (kf, krf, vf, kring, vring), counts = block.run_layers(
+        x, cache, counts = block.run_layers(
             cfg, params, x, pos, access,
             (flat(k), flat(kr), flat(v), kring, vring), mode="decode",
-            kernel=kernels, counts=counts)
-        logits = block.lm_head(cfg, params, x)
-        with device_scope("sample"):
-            if samp is None:
-                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            else:
-                seeds, temps, topks, topps = samp
-                nxt = head(logits, seeds, pos, temps, topks, topps)
-        return nxt, pos + jnp.int32(1), kf.reshape(k.shape), \
-            krf.reshape(kr.shape), vf.reshape(v.shape), kring, vring, \
-            counts
+            kernel=kernels, counts=state[0])
+        return block.lm_head(cfg, params, x), cache, (counts,)
 
-    if sampling:
-        def paged_prefill(params, tokens, tail_len, start, slot, final,
-                          bt_row, toks, pos, k, kr, v, kring, vring, seed,
-                          temp, topk, topp):
-            return _prefill_core(params, tokens, tail_len, start, slot,
-                                 final, bt_row, toks, pos, k, kr, v,
-                                 kring, vring, (seed, temp, topk, topp))
-
-        def paged_decode(params, toks, pos, tables, k, kr, v, kring,
-                         vring, counts, seeds, temps, topks, topps):
-            return _decode_core(params, toks, pos, tables, k, kr, v,
-                                kring, vring, counts,
-                                (seeds, temps, topks, topps))
-    else:
-        def paged_prefill(params, tokens, tail_len, start, slot, final,
-                          bt_row, toks, pos, k, kr, v, kring, vring):
-            return _prefill_core(params, tokens, tail_len, start, slot,
-                                 final, bt_row, toks, pos, k, kr, v,
-                                 kring, vring, None)
-
-        def paged_decode(params, toks, pos, tables, k, kr, v, kring,
-                         vring, counts):
-            return _decode_core(params, toks, pos, tables, k, kr, v,
-                                kring, vring, counts, None)
-
-    return paged_prefill, paged_decode
+    return build_paged_programs(
+        prefill_body, decode_body, cfg.vocab_size, sampling,
+        park=MB * BS - 1, num_state=len(block.mixed_cache_spec(cfg).state))

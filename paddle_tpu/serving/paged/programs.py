@@ -1,4 +1,4 @@
-"""AOT-compilable prefill/decode programs over the paged cache.
+"""The GPT's AOT-compilable prefill/decode programs over the paged cache.
 
 Same decode math as ``GPTForCausalLM.generate()`` (its ``forward_t``,
 from ``_decode_forward_builder``, is the parity oracle the tests hold
@@ -82,9 +82,10 @@ the reserved trash block, so pad-entry writes land in garbage, and the
 length mask keeps garbage reads at exactly-zero softmax weight — the
 same recycled-slot invariant as a contiguous cache, at block granularity.
 
-``sampling=True`` threads per-slot sampling parameters (seeds / temps
-/ top-k / top-p — serving.sched.sampling) through both programs; the
-greedy path is the default and keeps the original signatures.
+The signatures, the sampling tail (``sampling=True`` threads per-slot
+seeds / temps / top-k / top-p through both programs; greedy is the
+default) and the slots' bookkeeping are ``shell.py``'s, as every
+model's are; what is here is the GPT's two bodies, embed to head.
 
 ``attn_kernel`` is the decode program's attention: True = the Pallas
 paged kernel (ops.paged_attention) that reads each slot's LIVE K/V
@@ -116,18 +117,16 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
     from ...ops import attention as attn_ops
     from ...ops import paged_attention as paged_attn_ops
     from ...text.models import _decode_forward_builder
-    from ..sched.sampling import build_sampling_head
     from .pool import TRASH_BLOCK
+    from .shell import build_paged_programs, flat
 
     nh = cfg.num_heads
     hd = cfg.hidden_size // nh
     hidden = cfg.hidden_size
     ln, _ = _decode_forward_builder(nh, hd, hidden)
-    head = build_sampling_head(cfg.vocab_size) if sampling else None
     L = cfg.num_layers
     BS = int(block_size)
     MB = int(blocks_per_slot)
-    C = MB * BS   # positions a slot's table row addresses
 
     def mlp(x, p):
         with device_scope("mlp"):
@@ -135,9 +134,9 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
             m = jax.nn.gelu(h2 @ p["fc1_w"] + p["fc1_b"], approximate=True)
             return x + (m @ p["fc2_w"] + p["fc2_b"])
 
-    def _prefill_core(params, tokens, tail_len, start, slot, final,
-                      bt_row, toks, pos, kc, vc, samp):
+    def prefill_body(params, tokens, tail_len, start, slot, bt_row, cache):
         # tokens [1, B] right-padded tail; start = cached prefix length
+        kc, vc = cache
         B = tokens.shape[1]
         NB = kc.shape[1]
         with device_scope("embed"):
@@ -168,11 +167,6 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
                 new.transpose(1, 0, 2), (off, jnp.int32(0), jnp.int32(0)))
             return buf.reshape(nW, BS, nh, hd).transpose(0, 2, 1, 3)
 
-        # the pool rides the layer loop as CARRIED state, flat, as in
-        # the decode program: layer l's block b is row l*NB + b
-        kf = kc.reshape((L * NB,) + kc.shape[2:])
-        vf = vc.reshape((L * NB,) + vc.shape[2:])
-
         def body(carry, inp):
             x, kf, vf = carry
             p, layer = inp
@@ -196,9 +190,14 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
                 x = x + (o @ p["out_w"] + p["out_b"])
             return (mlp(x, p), kf, vf), None
 
+        # the pool rides the layer loop as CARRIED state, flat, as in
+        # the decode program: layer l's block b is row l*NB + b
         (x, kf, vf), _ = lax.scan(
-            body, (x, kf, vf),
+            body, (x, flat(kc), flat(vc)),
             (params["stacked"], jnp.arange(L, dtype=jnp.int32)))
+        # back in the pool's shape in front of the head, where this
+        # program has always had it (the shell's own reshape is then
+        # nothing, and the pinned jaxpr stays as it is)
         kc, vc = kf.reshape(kc.shape), vf.reshape(vc.shape)
         with device_scope("lm_head"):
             # ONE row through the head, as a [1, h] matmul (as a vector
@@ -206,38 +205,10 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
             row = lax.dynamic_slice_in_dim(x, tail_len - 1, 1, axis=0)
             last = (ln(row, params["lnf_w"], params["lnf_b"])
                     @ params["head"])[0]                       # [vocab]
-        with device_scope("sample"):
-            if samp is None:
-                first = jnp.argmax(last, -1).astype(jnp.int32)
-            else:
-                seed, temp, topk, topp = samp
-                first = head(last[None], seed[None],
-                             (start + tail_len - 1)[None], temp[None],
-                             topk[None], topp[None])[0]
-            toks = jnp.where(final > 0, toks.at[slot].set(first), toks)
-            # final: the next decode writes this slot at prompt_len;
-            # interior chunk: park at the row's last addressable
-            # position
-            pos = pos.at[slot].set(
-                jnp.where(final > 0, start + tail_len,
-                          jnp.int32(C - 1)))
-        return first[None], toks, pos, kc, vc
+        return last, (kc, vc)
 
-    if sampling:
-        def paged_prefill(params, tokens, tail_len, start, slot,
-                          final, bt_row, toks, pos, kc, vc, seed,
-                          temp, topk, topp):
-            return _prefill_core(params, tokens, tail_len, start,
-                                 slot, final, bt_row, toks, pos, kc,
-                                 vc, (seed, temp, topk, topp))
-    else:
-        def paged_prefill(params, tokens, tail_len, start, slot,
-                          final, bt_row, toks, pos, kc, vc):
-            return _prefill_core(params, tokens, tail_len, start,
-                                 slot, final, bt_row, toks, pos, kc,
-                                 vc, None)
-
-    def _decode_core(params, toks, pos, tables, kc, vc, samp):
+    def decode_body(params, toks, pos, tables, cache, state):
+        kc, vc = cache
         S = toks.shape[0]
         with device_scope("embed"):
             x = params["wemb"][toks] + params["pemb"][
@@ -245,8 +216,7 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
         # the pool rides the layer loop as CARRIED state, flat: layer
         # l's block b is row l*NB + b (module docstring)
         NB = kc.shape[1]
-        kf = kc.reshape((L * NB,) + kc.shape[2:])
-        vf = vc.reshape((L * NB,) + vc.shape[2:])
+        kf, vf = flat(kc), flat(vc)
         # what attention may read of a slot: its positions so far, and
         # never more than the blocks its table row holds. A released
         # slot's position keeps counting while its row is all trash:
@@ -285,26 +255,14 @@ def build_paged_fns(cfg, num_slots, block_size, num_blocks,
         (x, kf, vf), _ = lax.scan(
             body, (x, kf, vf),
             (params["stacked"], jnp.arange(L, dtype=jnp.int32)))
+        # in front of the head, as in the prefill program
         kc, vc = kf.reshape(kc.shape), vf.reshape(vc.shape)
         with device_scope("lm_head"):
             logits = ln(x, params["lnf_w"], params["lnf_b"]) \
                 @ params["head"]                          # [S, vocab]
-        with device_scope("sample"):
-            if samp is None:
-                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            else:
-                seeds, temps, topks, topps = samp
-                nxt = head(logits, seeds, pos, temps, topks, topps)
-        return nxt, pos + jnp.int32(1), kc, vc
+        return logits, (kc, vc), state
 
-    if sampling:
-        def paged_decode(params, toks, pos, tables, kc, vc, seeds,
-                         temps, topks, topps):
-            return _decode_core(params, toks, pos, tables, kc, vc,
-                                (seeds, temps, topks, topps))
-    else:
-        def paged_decode(params, toks, pos, tables, kc, vc):
-            return _decode_core(params, toks, pos, tables, kc, vc,
-                                None)
-
-    return paged_prefill, paged_decode
+    # an interior chunk parks the slot at the row's last addressable
+    # position
+    return build_paged_programs(prefill_body, decode_body, cfg.vocab_size,
+                                sampling, park=MB * BS - 1)
