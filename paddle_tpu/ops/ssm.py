@@ -43,8 +43,10 @@ from .pallas_compat import trace_32bit as _trace_32bit
 _FORCE_INTERPRET = [False]
 _HI = jax.lax.Precision.HIGHEST
 # a grid step holds one slot's whole state, in and out, each
-# double-buffered (2 MB at 64 heads of 64 x 128): what the kernel may
-# take of VMEM, and the largest state a slot may have for it
+# double-buffered (2 MB at nemotron_h's 64 heads of 64 x 128, two heads
+# a row; 4 MB at falcon_h1's 32 heads of 128 x 256, one head a row:
+# exactly the limit, 16 MB of the 48 with the buffers): what the kernel
+# may take of VMEM, and the largest state a slot may have for it
 _VMEM_BYTES = 48 << 20
 _SLOT_STATE_BYTES = 4 << 20
 

@@ -1,12 +1,19 @@
 """The engine's paged prefill and decode programs for a model that
-keeps TWO kinds of state side by side (``text.nemotron_h``): keys and
-values a token owns, in the paged pool behind the block tables, and a
-convolution window and a recurrent state a SLOT owns, in per-slot arrays
-(``cache_spec``: per-slot leaves). Signatures, slot bookkeeping and
-sampling are ``shell.py``'s, as every model's are, and the model's block
-is IMPORTED, not written out again. What is here is how a layer reaches
-its cache (``PagedAccess``), the kernels it needs and the two bodies the
-shell wraps.
+keeps TWO kinds of state side by side: keys and values a token owns, in
+the paged pool behind the block tables, and a convolution window and a
+recurrent state a SLOT owns, in per-slot arrays (``cache_spec``:
+per-slot leaves). Two families run through this one file:
+``text.nemotron_h`` (ONE mixer a layer, chosen by a pattern: a layer
+owns one kind of cache or none) and ``text.falcon_h1`` (a state-space
+mixer AND an attention mixer in EVERY layer: a layer owns both kinds,
+attention layer ``l`` and state-space layer ``l`` are the same layer).
+Signatures, slot bookkeeping and sampling are ``shell.py``'s, as every
+model's are, and the model's block is TAKEN FROM THE CONFIGURATION
+(``text.stacked_lm.block_of``: the module of its class; ``embed``,
+``run_layers``, ``lm_head``, ``split_channels``, ``hybrid_cache_spec``),
+not written out again. What is here is how a layer reaches its cache
+(``PagedAccess``), the kernels it needs and the two bodies the shell
+wraps.
 
   ``paged_prefill(params, tokens [1, B], tail_len, start, slot, final,
                   bt_row [MB], toks [S], pos [S], k, v, conv, ssm
@@ -25,8 +32,8 @@ shell wraps.
       ``start`` is only ever a chunk boundary of the request's own.
 
   ``paged_decode(params, toks [S], pos [S], tables [S, MB], k, v, conv,
-                 ssm, moe_counts[, samp...])
-      -> (next [S], pos + 1, k, v, conv, ssm, moe_counts')``
+                 ssm[, moe_counts][, samp...])
+      -> (next [S], pos + 1, k, v, conv, ssm[, moe_counts'])``
       One token a slot. Keys and values ride the layer loop flat
       (``[La*NB, nkv, BS, hd]``); the new row is placed and attention
       reads the LIVE blocks in place through ``tables + layer*NB``
@@ -34,7 +41,9 @@ shell wraps.
       the row itself, the ``jnp`` path writes the slot's current block
       back whole in front of the gather). The recurrent
       state rides it flat too (``[Lm*S, ...]``) and is updated in place
-      by ``ops.ssm``'s kernel, a layer's rows a call.
+      by ``ops.ssm``'s kernel, a layer's rows a call. ``moe_counts`` is
+      carried where the model's cache spec names it (a model with
+      expert layers).
 
 Parked and released slots: their entries are nobody's
 (``ops.paged_attention.live_write_pos``: the kernel writes none, the
@@ -130,7 +139,8 @@ class PagedAccess:
         import jax.numpy as jnp
 
         from ...ops import ssm as ssm_ops
-        from ...text.nemotron_h import split_channels
+        from ...text.stacked_lm import block_of
+        split_channels = block_of(self.cfg).split_channels
         kf, vf, conv, ssm = state
         active = pos < jnp.int32(self.MB * self.BS - 1)
         conv, ssm, xs, y = ssm_ops.ssm_decode_step(
@@ -141,38 +151,47 @@ class PagedAccess:
 
 
 def decode_kernels(cfg, num_slots, block_size):
-    """Whether the decode program runs its three Pallas kernels
-    (``shell.resolve_decode_kernels``)."""
+    """Whether the decode program runs its Pallas kernels, one a kind
+    of layer the model has (``cfg.count``: attention ``*``, state-space
+    ``M``, experts ``E``) (``shell.resolve_decode_kernels``)."""
     from ...ops import moe_experts as moe_ops
     from ...ops import paged_attention as paged_ops
     from ...ops import ssm as ssm_ops
     from .shell import resolve_decode_kernels
-    attn = (cfg.num_kv_heads, cfg.head_dim, block_size, cfg.cache_dtype)
-    ssm = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.state_size,
-           cfg.n_groups)
-    moe = (num_slots, cfg.hidden_size, cfg.moe_intermediate_size, cfg.dtype)
-    return resolve_decode_kernels([
-        (paged_ops, "paged_decode_attn",
-         "kv heads, head dim, block_size, cache dtype", attn,
-         lambda: not cfg.count("*") or paged_ops.kernel_viable(*attn)),
-        (ssm_ops, "ssm_decode_step",
-         "heads, head dim, state size, groups", ssm,
-         lambda: not cfg.count("M") or ssm_ops.kernel_viable(*ssm)),
-        (moe_ops, "moe_experts_relu2_decode",
-         "slots, hidden, expert width, dtype", moe,
-         lambda: not cfg.count("E")
-         or moe_ops.kernel_viable(*moe, gated=False))])
+    checks = []
+    if cfg.count("*"):
+        attn = (cfg.num_kv_heads, cfg.head_dim, block_size,
+                cfg.cache_dtype)
+        checks.append((paged_ops, "paged_decode_attn",
+                       "kv heads, head dim, block_size, cache dtype", attn,
+                       lambda: paged_ops.kernel_viable(*attn)))
+    if cfg.count("M"):
+        ssm = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.state_size,
+               cfg.n_groups)
+        checks.append((ssm_ops, "ssm_decode_step",
+                       "heads, head dim, state size, groups", ssm,
+                       lambda: ssm_ops.kernel_viable(*ssm)))
+    if cfg.count("E"):
+        moe = (num_slots, cfg.hidden_size, cfg.moe_intermediate_size,
+               cfg.dtype)
+        checks.append((moe_ops, "moe_experts_relu2_decode",
+                       "slots, hidden, expert width, dtype", moe,
+                       lambda: moe_ops.kernel_viable(*moe, gated=False)))
+    return resolve_decode_kernels(checks)
 
 
 def build_paged_hybrid_fns(cfg, num_slots, block_size, num_blocks,
                            blocks_per_slot, sampling=False, kernels=None):
-    """(paged_prefill, paged_decode) for a ``NemotronHConfig``. Pure and
-    shape-stable; ``kernels=None`` asks ``decode_kernels``."""
+    """(paged_prefill, paged_decode) for a ``NemotronHConfig`` or a
+    ``FalconH1Config``. Pure and shape-stable; ``kernels=None`` asks
+    ``decode_kernels``."""
     import jax
     import jax.numpy as jnp
 
-    from ...text import nemotron_h as block
+    from ...text.stacked_lm import block_of
     from .shell import build_paged_programs, flat
+
+    block = block_of(cfg)
 
     if kernels is None:
         kernels = decode_kernels(cfg, num_slots, block_size)
@@ -184,7 +203,7 @@ def build_paged_hybrid_fns(cfg, num_slots, block_size, num_blocks,
         B = tokens.shape[1]
         access = PagedAccess(cfg, S, NB, BS, MB, bt_row=bt_row)
         with device_scope("embed"):
-            x = params["wemb"][tokens]                       # [1, B, h]
+            x = block.embed(cfg, params, tokens)             # [1, B, h]
         positions = (start + jnp.arange(B, dtype=jnp.int32))[None]
         mine = (jax.lax.dynamic_index_in_dim(conv, slot, 1, False),
                 jax.lax.dynamic_index_in_dim(ssm, slot, 1, False))
@@ -206,12 +225,14 @@ def build_paged_hybrid_fns(cfg, num_slots, block_size, num_blocks,
         k, v, conv, ssm = cache
         access = PagedAccess(cfg, S, NB, BS, MB, tables=tables)
         with device_scope("embed"):
-            x = params["wemb"][toks]                         # [S, h]
+            x = block.embed(cfg, params, toks)               # [S, h]
+        # the expert-routing counters, where the model keeps them
         x, cache, counts = block.run_layers(
             cfg, params, x, pos, access,
             (flat(k), flat(v), conv, flat(ssm)), mode="decode",
-            kernel=kernels, counts=state[0])
-        return block.lm_head(cfg, params, x), cache, (counts,)
+            kernel=kernels, counts=state[0] if state else None)
+        return block.lm_head(cfg, params, x), cache, \
+            ((counts,) if state else ())
 
     return build_paged_programs(
         prefill_body, decode_body, cfg.vocab_size, sampling,

@@ -53,6 +53,12 @@ router's (published) width and ``first_held_expert`` where the share
 starts; the layer returns its own experts' part of the sum plus the
 shared expert's.
 
+Shared with ``text.falcon_h1`` (a state-space mixer AND attention in
+EVERY layer): the state-space mixer between its two projections
+(``split_projection``, ``ssm_core``), the access objects, and the model
+class's serving and eager paths (``HybridCausalLM``), which call the
+block of the configuration's own module (``stacked_lm.block_of``).
+
 Not brought by this module: training, sharding over a mesh, expert
 groups (``n_group > 1``), projection biases, a sliding window,
 speculative decoding, a disaggregated role, KV hand-off.
@@ -65,8 +71,8 @@ from ..ops import attention as attn_ops
 from ..ops import moe_experts as moe_ops
 from ..ops import ssm as ssm_ops
 from .stacked_lm import (  # noqa: F401 - parts of this block
-    StackedCausalLM, count_routing, greedy_or_sampled, layer_plan, lm_head,
-    rms_norm, take_layer as _take)
+    StackedCausalLM, block_of, count_routing, greedy_or_sampled,
+    layer_plan, lm_head, rms_norm, take_layer as _take)
 
 KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
 
@@ -195,30 +201,36 @@ def split_channels(cfg, act):
                                               cfg.state_size)))
 
 
-def mamba_mixer(cfg, p, x, positions, access, state, mi, start, mode,
-                length, kernel):
-    """One state-space layer with its norm and residual. "prefill": x
-    ``[b, T, h]``, the first ``length`` rows are the run; "decode": x
-    ``[S, h]``."""
-    d, H = cfg.d_inner, cfg.mamba_heads
+def split_projection(cfg, p, zxd):
+    """A state-space in-projection's output ``[..., d + conv_dim + H]``
+    as (gate z, convolution inputs u, step sizes dt in float32 after
+    the softplus, A ``[H]``)."""
+    d = cfg.d_inner
     f32 = jnp.float32
-    with device_scope("ssm/in_proj"):
-        xn = rms_norm(x, p["norm"], cfg.rms_norm_eps)
-        zxd = jnp.dot(xn, p["in_proj"])
-        z = zxd[..., :d]
-        u = zxd[..., d:d + cfg.conv_dim]
-        dt = jax.nn.softplus(zxd[..., d + cfg.conv_dim:].astype(f32)
-                             + p["dt_bias"].astype(f32))
-        A = -jnp.exp(p["A_log"].astype(f32))
+    z = zxd[..., :d]
+    u = zxd[..., d:d + cfg.conv_dim]
+    dt = jax.nn.softplus(zxd[..., d + cfg.conv_dim:].astype(f32)
+                         + p["dt_bias"].astype(f32))
+    A = -jnp.exp(p["A_log"].astype(f32))
+    return z, u, dt, A
+
+
+def ssm_core(cfg, p, z, u, dt, A, positions, access, state, mi, start,
+             mode, length, kernel):
+    """A state-space mixer between its two projections: convolution,
+    recurrence, ``D`` skip and the gated group norm. "prefill": u ``[b,
+    T, conv_dim]``, the first ``length`` rows are the run; "decode": u
+    ``[S, conv_dim]``. Returns (y ``[..., d]`` f32, state)."""
+    f32 = jnp.float32
     if mode == "decode":
         with device_scope("ssm/scan"):
             state, xs, y = access.ssm_decode(
                 state, mi, positions, u, dt, A, p["conv_w"], p["conv_b"],
                 kernel)
     else:
-        T = x.shape[1]
+        T = u.shape[1]
         with device_scope("ssm/conv"):
-            window, S0 = access.ssm_init(state, mi, start, x.shape[0])
+            window, S0 = access.ssm_init(state, mi, start, u.shape[0])
             act, window = jax.vmap(
                 lambda uu, ww: ssm_ops.conv_prefill(
                     uu, ww, p["conv_w"], p["conv_b"], length))(u, window)
@@ -233,9 +245,22 @@ def mamba_mixer(cfg, p, x, positions, access, state, mi, start, mode,
             state = access.ssm_commit(state, mi, window, S)
     with device_scope("ssm/out"):
         y = y + p["D"].astype(f32)[:, None] * xs.astype(f32)
-        y = y.reshape(x.shape[:-1] + (d,))
-        y = group_rms_norm(y * jax.nn.silu(z.astype(f32)), p["gnorm"],
-                           cfg.n_groups, cfg.rms_norm_eps)
+        y = y.reshape(u.shape[:-1] + (cfg.d_inner,))
+        return group_rms_norm(y * jax.nn.silu(z.astype(f32)), p["gnorm"],
+                              cfg.n_groups, cfg.rms_norm_eps), state
+
+
+def mamba_mixer(cfg, p, x, positions, access, state, mi, start, mode,
+                length, kernel):
+    """One state-space layer with its norm and residual. "prefill": x
+    ``[b, T, h]``, the first ``length`` rows are the run; "decode": x
+    ``[S, h]``."""
+    with device_scope("ssm/in_proj"):
+        xn = rms_norm(x, p["norm"], cfg.rms_norm_eps)
+        z, u, dt, A = split_projection(cfg, p, jnp.dot(xn, p["in_proj"]))
+    y, state = ssm_core(cfg, p, z, u, dt, A, positions, access, state, mi,
+                        start, mode, length, kernel)
+    with device_scope("ssm/out"):
         return x + jnp.dot(y.astype(x.dtype), p["out_proj"]), state
 
 
@@ -309,6 +334,11 @@ def expert_mixer(cfg, p, experts, x, ei, mode, kernel, counts):
     if counts is not None and mode == "decode":
         counts = count_routing(counts, ei, tokens)
     return x + y.astype(x.dtype).reshape(lead + (x.shape[-1],)), counts
+
+
+def embed(cfg, params, ids):
+    """The residual stream's first state: the tokens' rows."""
+    return params["wemb"][ids]
 
 
 def run_layers(cfg, params, x, positions, access, state, start=0,
@@ -509,25 +539,17 @@ def param_shapes(cfg):
     return out
 
 
-class NemotronHForCausalLM(StackedCausalLM):
-    """Causal LM of the family, for serving. Parameters are held
-    STACKED by kind of layer, in ``cfg.dtype``, exactly as the compiled
-    programs take them (``stacked_lm.StackedCausalLM``)."""
-
-    def __init__(self, cfg, weights=None, seed=0):
-        super().__init__(cfg, param_shapes(cfg), weights, seed)
+class HybridCausalLM(StackedCausalLM):
+    """A causal LM whose layers keep keys and values a token owns AND a
+    convolution window and a recurrent state a slot owns, for serving:
+    what ``NemotronHForCausalLM`` and ``text.falcon_h1
+    .FalconH1ForCausalLM`` share. The block (``embed``, ``run_layers``,
+    ``lm_head``, ``hybrid_cache_spec``) is the one in the module of the
+    configuration's class (``stacked_lm.block_of``)."""
 
     # -------------------------------------------------- what serving takes
     def cache_spec(self):
-        return hybrid_cache_spec(self.cfg)
-
-    def moe_counter_layout(self):
-        """Which layers and experts the rows and columns of
-        ``moe_counts`` stand for (``ServingMetrics.set_moe_counters``)."""
-        cfg = self.cfg
-        return {"layers": [i for i, c in enumerate(cfg.pattern)
-                           if c == "E"],
-                "first": cfg.held[0], "count": cfg.held[1]}
+        return block_of(self.cfg).hybrid_cache_spec(self.cfg)
 
     def build_paged_serving_fns(self, num_slots, block_size, num_blocks,
                                 blocks_per_slot, sampling=False):
@@ -552,12 +574,13 @@ class NemotronHForCausalLM(StackedCausalLM):
         return Tensor(fn(self.export_decode_params(), ids))
 
     def _forward_fn(self, params, ids):
-        cfg = self.cfg
+        cfg, block = self.cfg, block_of(self.cfg)
         b, t = ids.shape
         pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-        x, _, _ = run_layers(cfg, params, params["wemb"][ids], pos,
-                             SeqAccess(cfg), (), 0, "prefill")
-        return lm_head(cfg, params, x)
+        x, _, _ = block.run_layers(cfg, params,
+                                   block.embed(cfg, params, ids), pos,
+                                   SeqAccess(cfg), (), 0, "prefill")
+        return block.lm_head(cfg, params, x)
 
     def generate(self, input_ids, max_new_tokens=32, temperature=0.0,
                  top_k=0, seed=0):
@@ -566,7 +589,7 @@ class NemotronHForCausalLM(StackedCausalLM):
         ``temperature <= 0`` or ``top_k == 1``, else temperature
         sampling over the ``top_k`` logits (0 = all)."""
         from ..core.tensor import Tensor
-        cfg = self.cfg
+        cfg, block = self.cfg, block_of(self.cfg)
         ids = self._ids(input_ids)
         b, s0 = ids.shape
         n_new = int(max_new_tokens)
@@ -593,20 +616,20 @@ class NemotronHForCausalLM(StackedCausalLM):
                                jnp.dtype(cfg.state_dtype)))
             pos = jnp.broadcast_to(jnp.arange(s0, dtype=jnp.int32),
                                    (b, s0))
-            x, state, _ = run_layers(cfg, params, params["wemb"][ids],
-                                     pos, access, state, jnp.int32(0),
-                                     "prefill")
+            x, state, _ = block.run_layers(
+                cfg, params, block.embed(cfg, params, ids), pos, access,
+                state, jnp.int32(0), "prefill")
             key, sub = jax.random.split(key)
-            first = pick(lm_head(cfg, params, x[:, -1]), sub, temp)
+            first = pick(block.lm_head(cfg, params, x[:, -1]), sub, temp)
 
             def step(carry, _):
                 tok, p, state, key = carry
-                x, state, _ = run_layers(
-                    cfg, params, params["wemb"][tok],
+                x, state, _ = block.run_layers(
+                    cfg, params, block.embed(cfg, params, tok),
                     jnp.broadcast_to(p, (b,)), access, state,
                     mode="decode")
                 key, sub = jax.random.split(key)
-                nxt = pick(lm_head(cfg, params, x), sub, temp)
+                nxt = pick(block.lm_head(cfg, params, x), sub, temp)
                 return (nxt, p + 1, state, key), nxt
 
             _, rest = jax.lax.scan(
@@ -620,3 +643,20 @@ class NemotronHForCausalLM(StackedCausalLM):
                  jax.random.PRNGKey(int(seed)),
                  jnp.float32(max(float(temperature), 1e-6)))
         return Tensor(out.astype(jnp.int64))
+
+
+class NemotronHForCausalLM(HybridCausalLM):
+    """Causal LM of the family, for serving. Parameters are held
+    STACKED by kind of layer, in ``cfg.dtype``, exactly as the compiled
+    programs take them (``stacked_lm.StackedCausalLM``)."""
+
+    def __init__(self, cfg, weights=None, seed=0):
+        super().__init__(cfg, param_shapes(cfg), weights, seed)
+
+    def moe_counter_layout(self):
+        """Which layers and experts the rows and columns of
+        ``moe_counts`` stand for (``ServingMetrics.set_moe_counters``)."""
+        cfg = self.cfg
+        return {"layers": [i for i, c in enumerate(cfg.pattern)
+                           if c == "E"],
+                "first": cfg.held[0], "count": cfg.held[1]}

@@ -1,5 +1,6 @@
 """What the causal LMs that are built FOR SERVING share
-(``text.deepseek_v3``, ``text.nemotron_h``, ``text.mimo_v2``): parameters held stacked per
+(``text.deepseek_v3``, ``text.nemotron_h``, ``text.mimo_v2``, ``text.ouro``,
+``text.falcon_h1``): parameters held stacked per
 group in the serving dtype, exactly as the compiled programs take them,
 a ready tree of arrays adopted without a copy, a small cache of jitted
 eager programs, and the refusal by name of engine options the model has
@@ -7,6 +8,7 @@ no program for.
 """
 import collections
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +35,15 @@ def project_heads(x, w, heads):
     for an output projection (PERF.md section 6, PR 45)."""
     y = jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
     return y.reshape(x.shape[:-1] + (heads, -1))
+
+
+def block_of(cfg):
+    """The module that holds a configuration's block: the one its class
+    is defined in (``text.nemotron_h`` for a ``NemotronHConfig``,
+    ``text.falcon_h1`` for a ``FalconH1Config``). The paged programs
+    and the eager paths that two families share call the block through
+    it."""
+    return sys.modules[type(cfg).__module__]
 
 
 def lm_head(cfg, params, x):

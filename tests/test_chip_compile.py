@@ -714,12 +714,12 @@ def test_grouped_query_paged_decode_compiles(one_chip, dtype,
                                      hd, block)
 
 
-def _hybrid_programs(one_chip, pattern, slots, max_len):
+def _hybrid_family_programs(one_chip, block, cfg, slots, max_len):
+    """Both programs of ``hybrid_programs.py`` for a configuration of
+    either family it serves (``block``: the module of its class), with
+    the shapes they are lowered on."""
     from paddle_tpu.serving.paged.hybrid_programs import \
         build_paged_hybrid_fns
-    from paddle_tpu.text import nemotron_h as nh
-    cfg = nh.NemotronHConfig.from_hf(
-        dict(NEMOTRON, hybrid_override_pattern=pattern), dtype="bfloat16")
     BS = 256
     MB = max_len // BS
     NB = slots * MB + 1
@@ -730,18 +730,25 @@ def _hybrid_programs(one_chip, pattern, slots, max_len):
         return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dt),
                                     sharding=one_chip)
     params = {}
-    for path, (shape, _, dt) in nh.param_shapes(cfg).items():
+    for path, (shape, _, dt) in block.param_shapes(cfg).items():
         node = params
         for part in path[:-1]:
             node = node.setdefault(part, {})
         node[path[-1]] = sds(shape, dt)
-    spec = nh.hybrid_cache_spec(cfg).with_slots(slots)
+    spec = block.hybrid_cache_spec(cfg).with_slots(slots)
     pool = [sds(spec.shape(a, NB, BS), a.dtype) for a in spec.arrays]
     state = [sds(shape, dt) for _, shape, dt in spec.state]
     i32 = jnp.int32
     toks, pos = sds((slots,), i32), sds((slots,), i32)
     nbytes = [int(np.prod(p.shape)) * p.dtype.itemsize for p in pool]
     return prefill, decode, params, pool, state, toks, pos, MB, nbytes
+
+
+def _hybrid_programs(one_chip, pattern, slots, max_len):
+    from paddle_tpu.text import nemotron_h as nh
+    cfg = nh.NemotronHConfig.from_hf(
+        dict(NEMOTRON, hybrid_override_pattern=pattern), dtype="bfloat16")
+    return _hybrid_family_programs(one_chip, nh, cfg, slots, max_len)
 
 
 def test_hybrid_decode_program_updates_both_kinds_of_state_in_place(
@@ -1235,3 +1242,129 @@ def test_the_aot_tool_compiles_the_looped_cell_unedited(topo, capsys):
     assert "cache 1572864 B a token" in out and "81 blocks of 64" in out
     assert "paged_decode:" in out and "paged_prefill[512]:" in out
     assert "kernels 1" in out
+
+
+# ---- falcon_h1: a state-space mixer AND attention in every layer (PR 48)
+# Falcon-H1-34B-Instruct's widths and multipliers, cut in depth only
+FALCON = dict(
+    vocab_size=261120, hidden_size=5120, intermediate_size=21504,
+    num_hidden_layers=2, num_attention_heads=20, num_key_value_heads=4,
+    head_dim=128, mamba_n_heads=32, mamba_d_head=128, mamba_d_ssm=4096,
+    mamba_d_state=256, mamba_n_groups=2, mamba_d_conv=4,
+    mamba_chunk_size=128, rope_theta=1e11, max_position_embeddings=262144,
+    attention_in_multiplier=1, attention_out_multiplier=0.0375,
+    key_multiplier=0.011048543456039804, ssm_in_multiplier=0.25,
+    ssm_out_multiplier=0.08838834764831845,
+    ssm_multipliers=[0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                     0.3535533905932738],
+    mlp_multipliers=[0.1767766952966369, 0.011160714285714284],
+    embedding_multiplier=5.656854249492381, lm_head_multiplier=0.0078125)
+
+
+def test_ssm_decode_step_compiles_at_the_largest_state(one_chip):
+    """The state-space decode kernel where ONE head fills a row of the
+    packed state (32 heads of 128 x 256 in 2 groups: 4 MiB a slot a
+    layer, exactly the kernel's limit; 16 MiB of its VMEM with both
+    double buffers), 40 slots, layer 3 of 6: the packed float32 state
+    aliased in and out."""
+    from paddle_tpu.ops import ssm
+    S, H, P, G, N, Lm = 40, 32, 128, 2, 256, 6
+    assert ssm.kernel_viable(H, P, N, G)
+    assert ssm.packed_shape(H, P, N, G) == (32, 256, 128)
+    f32 = jnp.float32
+
+    def sds(shape, dt=f32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    state = sds((Lm * S,) + ssm.packed_shape(H, P, N, G))
+    compiled = jax.jit(
+        lambda st, xs, dt, A, B, C: ssm.ssm_state_step(
+            st, jnp.int32(3), xs, dt, A, B, C, S),
+        donate_argnums=(0,)).lower(
+        state, sds((S, H, P), jnp.bfloat16), sds((S, H)), sds((H,)),
+        sds((S, G, N), jnp.bfloat16), sds((S, G, N), jnp.bfloat16)
+    ).compile()
+    assert "ssm_decode_step" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= Lm * S * H * P * N * 4
+    assert mem.temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_paged_decode_compiles_at_a_query_group_of_five(one_chip, dtype,
+                                                        mosaic_backend):
+    """20 query heads over 4 KV heads of 128 in blocks of 256: a group
+    of 5 rides in a tile of 8 rows (twice over for a 16-bit pool), 3 of
+    them padding; read only, and placing the step's new entry."""
+    slots, per_slot, nkv, nq, hd, block = 40, 24, 4, 20, 128, 256
+    assert paged_attention.kernel_viable(nkv, hd, block, dtype)
+    assert paged_attention._group_rows(5, jnp.dtype(dtype)) \
+        == ((8, 8) if dtype == jnp.float32 else (16, 8))
+
+    def sds(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    _compile(paged_attention.paged_decode_attention, sds((slots, nq, hd)),
+             sds((961, nkv, block, hd)), sds((961, nkv, block, hd)),
+             sds((slots, per_slot), jnp.int32), sds((slots,), jnp.int32))
+    _compile_paged_decode_with_write(one_chip, dtype, slots, per_slot, nq,
+                                     nkv, hd, block)
+
+
+def _parallel_programs(one_chip, slots, max_len):
+    from paddle_tpu.text import falcon_h1 as fh
+    cfg = fh.FalconH1Config.from_hf(FALCON, dtype="bfloat16")
+    return _hybrid_family_programs(one_chip, fh, cfg, slots, max_len)
+
+
+def test_parallel_decode_program_updates_both_kinds_of_state_in_place(
+        one_chip):
+    """The falcon_h1 decode program (2 layers at the published widths
+    and vocabulary, 16 slots) through ``hybrid_programs.py``: keys,
+    values, convolution windows and recurrent state ride its ONE layer
+    scan in place (all four aliased onto the results, temporaries of a
+    few MB), both kernels in the program under their branch's scope, no
+    expert kernel, no gathered block set in front of the attention
+    kernel."""
+    _, decode, params, pool, _, toks, pos, MB, nbytes = \
+        _parallel_programs(one_chip, 16, 1024)
+    n = len(pool)
+    tables = jax.ShapeDtypeStruct((16, MB), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        decode, donate_argnums=(2,) + tuple(range(4, 4 + n))).lower(
+        params, toks, pos, tables, *pool).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(nbytes)
+    # AOT, PR 48: 0.6 MB
+    assert mem.temp_size_in_bytes < 8 << 20, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    assert "moe_experts" not in text
+    for kernel, scope in (("ssm_decode_step", "branch/ssm/ssm/scan"),
+                          ("paged_decode_attn", "branch/attn/attn/paged")):
+        assert re.search(rf'{scope}[^"]*{kernel}', text), kernel
+    assert not _pool_shaped(compiled, ["16,4,256,128"], dtype=r"\w+")
+    assert "/kv_write/" in text and "branch/mix" in text
+
+
+def test_parallel_prefill_program_copies_no_slot_state(one_chip):
+    """Its prefill program at a bucket of 512: the pool aliased, and no
+    copy of the whole recurrent state (the slot's is cut out before the
+    layer scan and put back after it)."""
+    prefill, _, params, pool, _, toks, pos, MB, nbytes = \
+        _parallel_programs(one_chip, 16, 1024)
+    n = len(pool)
+    i32 = jnp.int32
+    scalar = jax.ShapeDtypeStruct((), i32, sharding=one_chip)
+    compiled = jax.jit(
+        prefill, donate_argnums=tuple(range(8, 9 + n))).lower(
+        params, jax.ShapeDtypeStruct((1, 512), i32, sharding=one_chip),
+        scalar, scalar, scalar, scalar,
+        jax.ShapeDtypeStruct((MB,), i32, sharding=one_chip), toks, pos,
+        *pool).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(nbytes)
+    state = pool[3].shape
+    shapes = "|".join(",".join(str(d) for d in s) for s in (
+        state, (state[0] * state[1],) + state[2:]))
+    moved = re.findall(rf"= f32\[(?:{shapes})\]\S* copy\(",
+                       compiled.as_text())
+    assert not moved, moved

@@ -19,7 +19,7 @@ from paddle_tpu.observability.watchdog import scope_path  # noqa: E402
 from paddle_tpu.serving import ServingEngine  # noqa: E402
 
 
-# ------------------------------------------- the six models, tiny sizes
+# ----------------------------------------- the seven models, tiny sizes
 def _gpt():
     from paddle_tpu.text.models import GPTForCausalLM, TransformerLMConfig
     cfg = TransformerLMConfig(vocab_size=64, hidden_size=64, num_layers=2,
@@ -52,13 +52,20 @@ def _looped():
                               buckets=[16])
 
 
+def _parallel():
+    from tests.test_falcon_h1 import _model
+    return _model()[0], dict(num_slots=3, block_size=8, max_len=64,
+                             buckets=[16])
+
+
 def _eva():
     from tests.test_evabyte import _model
     return _model()[0], dict(num_slots=2, block_size=4, max_len=128)
 
 
 MODELS = {"gpt": _gpt, "latent": _latent, "hybrid": _hybrid,
-          "eva": _eva, "mixed": _mixed, "looped": _looped}
+          "eva": _eva, "mixed": _mixed, "looped": _looped,
+          "parallel": _parallel}
 
 
 def _program(model, program, sampling=False):
@@ -119,6 +126,14 @@ DIGESTS = {
     ("looped", "prefill", True): "e5068df3c10ffa8b",
     ("looped", "decode", False): "debd7a7438ea6318",
     ("looped", "decode", True): "b4a9975f0d4b9f1c",
+    # text.falcon_h1 through hybrid_programs.py (PR 48 brought the
+    # program: these four are that PR's own, and the twelve of "hybrid"
+    # and "looped" above are what shows that making hybrid_programs.py
+    # take the block from the configuration changed neither's program)
+    ("parallel", "prefill", False): "dd0d537655ae68a0",
+    ("parallel", "prefill", True): "31a212ca68f8be36",
+    ("parallel", "decode", False): "fed044f9e82da53d",
+    ("parallel", "decode", True): "e67b858c3ccfbe81",
 }
 
 
@@ -217,6 +232,24 @@ SCOPES = {
         "attn/paged/kv_write": 83, "attn/qkv": 53, "embed": 5,
         "lm_head": 1, "loop/gate": 59, "loop/norm": 9, "mlp": 25,
         "sample": 2},
+    # PR 48's own: each branch under its enclosing scope, the accepted
+    # scope names inside it
+    ("parallel", "prefill"): {
+        "branch/attn/attn/out": 3, "branch/attn/attn/paged": 49,
+        "branch/attn/attn/paged/kv_gather": 14,
+        "branch/attn/attn/paged/kv_write": 14, "branch/attn/attn/qkv": 46,
+        "branch/mix": 2, "branch/ssm/ssm/conv": 77,
+        "branch/ssm/ssm/in_proj": 31, "branch/ssm/ssm/out": 22,
+        "branch/ssm/ssm/scan": 108, "branch/ssm/ssm/scan/state_write": 14,
+        "embed": 6, "lm_head": 11, "mlp": 19, "sample": 19,
+        "state_write": 10},
+    ("parallel", "decode"): {
+        "branch/attn/attn/out": 3, "branch/attn/attn/paged": 33,
+        "branch/attn/attn/paged/kv_gather": 18,
+        "branch/attn/attn/paged/kv_write": 83, "branch/attn/attn/qkv": 46,
+        "branch/mix": 2, "branch/ssm/ssm/in_proj": 31,
+        "branch/ssm/ssm/out": 22, "branch/ssm/ssm/scan": 87, "embed": 6,
+        "lm_head": 11, "mlp": 19, "sample": 2},
 }
 
 
@@ -280,6 +313,7 @@ def _builder_kernels(model):
     cfg = MODELS[model]()[0].cfg
     return {"latent": lambda: latent_programs.decode_kernels(cfg, 3, 8),
             "hybrid": lambda: hybrid_programs.decode_kernels(cfg, 3, 8),
+            "parallel": lambda: hybrid_programs.decode_kernels(cfg, 3, 8),
             "mixed": lambda: mixed_programs.decode_kernels(cfg, 3, 8),
             "eva": lambda: eva_programs.decode_kernel(cfg, 4),
             "looped": lambda: looped_programs.decode_kernel(cfg, 8)}[model]
@@ -288,8 +322,8 @@ def _builder_kernels(model):
 @pytest.mark.parametrize("model", sorted(set(MODELS) - {"gpt"}))
 def test_a_builder_resolves_its_kernels_through_the_shell(monkeypatch,
                                                           model):
-    """The five builders that choose their kernels themselves (the
-    GPT's are the engine's choice): none on the CPU; where there is
+    """The builders that choose their kernels themselves, for every
+    model that runs through one (the GPT's are the engine's choice): none on the CPU; where there is
     Mosaic, a tiny shape is refused in the shell's words."""
     resolve = _builder_kernels(model)
     assert resolve() is False
