@@ -23,9 +23,9 @@ x [dr, BS]`` matmul.
 
 ``mla_paged_decode_attn`` is the absorbed form's middle (scores,
 softmax, ``o_lat``) for every head of a slot over the slot's LIVE
-blocks, read in place; ``mla_decode_attn_jnp`` is the same arithmetic in
-``jnp`` over a gathered view (the CPU path and the interpret-mode
-oracle).
+blocks, read in place, and the placing of the step's new entry in
+them; ``mla_decode_attn_jnp`` is the same arithmetic in ``jnp`` over a
+gathered view (the CPU path and the interpret-mode oracle).
 
 How the table drives the DMA schedule (after ``ops/paged_attention.py``):
 grid ``(S,)``, one step a slot, with ``tables`` and ``lengths``
@@ -59,8 +59,43 @@ chunk's buffer no copy refreshed, a chunk's reach past the slot's
 capacity) get ``-1e30`` before the softmax, so they carry exactly-zero
 weight. The latent buffer, which is the value operand too, is zeroed
 once a call, so what lies behind a zero weight is always finite.
+
+Who writes the step's new entry (``new=``, ``write_pos=``: what the
+decode program calls, ``latent_write_attention``; after
+``ops/paged_attention.py``'s ``place_entry``). The KERNEL does. A
+slot's new entry sits at position ``lengths[s] - 1``, in its last live
+block, which the slot's LAST chunk brings into ``cbuf`` / ``pebuf``
+anyway. After that chunk's copies are waited for, the kernel selects
+the latent into row ``write_pos % (G*BS)`` of ``cbuf`` (an ``iota ==``
+select over the 16-row tile that holds it, via f32: exact for a 16-bit
+pool) and the rotary key into lane ``write_pos % BS`` of its block's
+plane of ``pebuf`` (positions ride the lanes there, so the entry's
+``dr`` values are turned from lanes onto sublanes by a ``diag(entry) x
+onehot`` matmul, one exact term a sum), runs the chunk's arithmetic on
+the buffer as for any other chunk (the attention reads the entry from
+the same bits HBM will hold: no second source), and copies back to HBM
+only what changed: the latent's ``[16, rank]`` tile (8 rows of an f32
+pool) and the 128 lanes around the entry of the rotary key's block,
+``[dr, 128]``: 16 + 16 KB at the cell's widths where the ``jnp`` block
+write read and wrote 288 KB a slot a layer (PR 49). Both pools are the
+call's aliased outputs (``input_output_aliases``), so a donated pool
+carried through a layer loop is updated in place and no gather or
+scatter of a block is left in front of the kernel. The write-back is
+started before the chunk's arithmetic and waited for after the slot's
+last chunk, before its half of the double buffer can be a copy's target
+again and before the grid step ends; the next slot's prefetched first
+chunk never holds the block, because a slot's last block is private
+(``pool.acquire`` asserts it). Only a LIVE entry is written
+(``write_pos[s] == lengths[s] - 1`` inside the slot's row,
+``ops.paged_attention.live_write_pos``): a parked or released slot
+(``write_pos`` -1), whose stray row the ``jnp`` write pins to the last
+entry of its table row or drops in the trash block, writes nothing, and
+a slot with nothing live copies nothing in either direction. Without
+``new`` no ref, copy or select of the write is traced and the call is
+what it was (the prefill-side tests and the oracle's comparisons).
 """
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -194,9 +229,8 @@ def mla_paged_decode_attn_jnp(q_lat, q_pe, c_cache, pe_cache, tables,
 
 
 # --------------------------------------------------------------- kernel
-def _mla_decode_kernel(bt_ref, len_ref, ql_ref, qp_ref, c_hbm, pe_hbm,
-                       o_ref, cbuf, pebuf, acc_ref, m_ref, l_ref, sem,
-                       half_ref, *, block_size, group, scale):
+def _mla_decode_kernel(bt_ref, len_ref, *refs, block_size, group, scale,
+                       write=False):
     """Grid (S,), sequential: one step a slot. The table row of the
     slot names the blocks to copy, ``lengths`` how many of them are
     live; ``cbuf [2, G*BS, rank]`` / ``pebuf [2, G, dr, BS]`` are the
@@ -209,9 +243,24 @@ def _mla_decode_kernel(bt_ref, len_ref, ql_ref, qp_ref, c_hbm, pe_hbm,
     T]`` and ``p @ c`` are two MXU matmuls over the latent, ``G`` small
     ones over the rotary key; the online-softmax state lives in VMEM
     scratch across the chunks and the slot's output row is written
-    once."""
+    once. With ``write`` the step's new entry is placed (module
+    docstring): ``wpos_ref`` (a third prefetched scalar a slot),
+    ``cn_ref [S, rank]`` / ``pn_ref [S, dr]`` resident like ``ql_ref``,
+    the pools the call's aliased OUTPUTS (read and written through the
+    one ref), the write-backs on ``wsem``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    it = iter(refs)
+    wpos_ref = next(it) if write else None
+    ql_ref, qp_ref, c_hbm, pe_hbm = (next(it) for _ in range(4))
+    cn_ref, pn_ref = (next(it), next(it)) if write else (None, None)
+    o_ref = next(it)
+    if write:
+        # the aliased outputs: the same HBM as the inputs
+        c_hbm, pe_hbm = next(it), next(it)
+    cbuf, pebuf, acc_ref, m_ref, l_ref, sem, half_ref = (
+        next(it) for _ in range(7))
+    wsem = next(it) if write else None
     BS, G, MB = block_size, group, bt_ref.shape[1]
     T = G * BS
     si = pl.program_id(0)
@@ -241,6 +290,61 @@ def _mla_decode_kernel(bt_ref, len_ref, ql_ref, qp_ref, c_hbm, pe_hbm,
     def wait_chunk(s, c, half):
         chunk_copies(s, c, half, lambda copy: copy.wait())
 
+    # the step's new entry (``write``): position ``wpos``, which the
+    # slot's LAST chunk holds at buffer row ``wpos - c * T``. Rows of a
+    # latent tile, lanes of a rotary-key tile (whole ones on a chip:
+    # ``kernel_viable``; interpret mode takes smaller blocks)
+    sub = math.gcd(BS, 8 if cbuf.dtype == jnp.float32 else 16)
+    lane = math.gcd(BS, 128)
+
+    def entry_copies(c, half, go):
+        """``go`` (start or wait) the copies back to HBM of the tiles of
+        chunk c's buffers that hold this slot's new entry: the ``sub``
+        rows around it of the latent, the 128 lanes around it of the
+        rotary key's block."""
+        p = wpos_ref[si]
+        blk = bt_ref[si, p // BS]
+        src = pl.ds(pl.multiple_of((p - c * T) // sub * sub, sub), sub)
+        dst = pl.ds(pl.multiple_of(p % BS // sub * sub, sub), sub)
+        go(pltpu.make_async_copy(
+            cbuf.at[half, src, :], c_hbm.at[blk, dst, :], wsem.at[0]))
+        lanes = pl.ds(pl.multiple_of(p % BS // lane * lane, lane), lane)
+        go(pltpu.make_async_copy(
+            pebuf.at[half, (p - c * T) // BS, :, lanes],
+            pe_hbm.at[blk, :, lanes], wsem.at[1]))
+
+    def place_entry(c, half):
+        """Select the new entry into chunk c's buffers (an ``iota ==``
+        select, via f32: exact for a 16-bit pool) and start the tiles'
+        way back."""
+        f32 = jnp.float32
+        r = wpos_ref[si] - c * T
+        at = pl.ds(pl.multiple_of(r // sub * sub, sub), sub)
+        rank = cbuf.shape[2]
+        hot = jax.lax.broadcasted_iota(
+            jnp.int32, (sub, rank), 0) == r % sub
+        row = jnp.broadcast_to(cn_ref[pl.ds(si, 1), :], (sub, rank))
+        cbuf[half, at, :] = jnp.where(
+            hot, row, cbuf[half, at, :].astype(f32)).astype(cbuf.dtype)
+        # positions ride the lanes of the rotary key's block and the
+        # entry's dr values must ride the sublanes: diag(entry) x (ones
+        # on the entry's lane), one exact term a sum, is the column
+        g, dr = r // BS, pebuf.shape[2]
+        eye = jax.lax.broadcasted_iota(jnp.int32, (dr, dr), 0) \
+            == jax.lax.broadcasted_iota(jnp.int32, (dr, dr), 1)
+        here = jax.lax.broadcasted_iota(jnp.int32, (dr, BS), 1) == r % BS
+        diag = jnp.where(eye, jnp.broadcast_to(
+            pn_ref[pl.ds(si, 1), :], (dr, dr)), f32(0))
+        col = jnp.dot(
+            diag.astype(pebuf.dtype),
+            jnp.where(here, f32(1), f32(0)).astype(pebuf.dtype),
+            precision=(None if pebuf.dtype != f32
+                       else jax.lax.Precision.HIGHEST),
+            preferred_element_type=f32)
+        pebuf[half, g] = jnp.where(
+            here, col, pebuf[half, g].astype(f32)).astype(pebuf.dtype)
+        entry_copies(c, half, lambda copy: copy.start())
+
     @pl.when(si == 0)
     def _first():
         # what no copy has written yet must be finite behind its zero
@@ -257,6 +361,10 @@ def _mla_decode_kernel(bt_ref, len_ref, ql_ref, qp_ref, c_hbm, pe_hbm,
     has_next = si + 1 < num_slots
     nxt = jnp.minimum(si + 1, num_slots - 1)
     nt = (((1,), (1,)), ((), ()))                        # a @ b^T
+    # only a LIVE entry inside the row is written: a parked or released
+    # slot's write position is not its last live one (module docstring)
+    placed = write and jnp.logical_and(wpos_ref[si] == len_ref[si] - 1,
+                                       len_ref[si] <= MB * BS)
 
     def chunk(c, carry):
         half = (half0 + c) % 2
@@ -270,6 +378,10 @@ def _mla_decode_kernel(bt_ref, len_ref, ql_ref, qp_ref, c_hbm, pe_hbm,
             start_chunk(nxt, 0, 1 - half)
 
         wait_chunk(si, c, half)
+        if write:
+            @pl.when(jnp.logical_and(c + 1 == chunks, placed))
+            def _():
+                place_entry(c, half)
         lat = cbuf[half]                                 # [T, rank]
         qp = qp_ref[si]
         s = jax.lax.dot_general(ql_ref[si], lat, nt,
@@ -299,6 +411,13 @@ def _mla_decode_kernel(bt_ref, len_ref, ql_ref, qp_ref, c_hbm, pe_hbm,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
         jax.lax.fori_loop(0, chunks, chunk, 0)
+        if write:
+            # the tiles are in HBM before this half is a copy's target
+            # again, and before the grid step ends
+            @pl.when(placed)
+            def _():
+                entry_copies(chunks - 1, (half0 + chunks - 1) % 2,
+                             lambda copy: copy.wait())
         # l >= 1 (the max's own exp term)
         o_ref[si] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
@@ -316,7 +435,7 @@ def _mla_decode_kernel(bt_ref, len_ref, ql_ref, qp_ref, c_hbm, pe_hbm,
 
 
 def _mla_paged_decode_32(q_lat, q_pe, c_cache, pe_cache, tables, lengths,
-                         scale):
+                         scale, new=None, write_pos=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     S, nh, rank = q_lat.shape
@@ -325,55 +444,126 @@ def _mla_paged_decode_32(q_lat, q_pe, c_cache, pe_cache, tables, lengths,
     MB = tables.shape[1]
     dtype = c_cache.dtype
     G = blocks_per_chunk(BS, rank, dr, MB, dtype)
-    tables = tables.astype(jnp.int32)
-    lengths = lengths.astype(jnp.int32)
+    write = new is not None
+    scalars = [tables.astype(jnp.int32), lengths.astype(jnp.int32)]
+    if write:
+        scalars.append(write_pos.astype(jnp.int32))
 
     def whole(shape):
-        # q and o stay in VMEM for the whole call (a block a grid step
-        # would put three small copies' latency into every step)
-        return pl.BlockSpec(shape, lambda si, bt_ref, len_ref:
+        # q, o and the new entry stay in VMEM for the whole call (a
+        # block a grid step would put three small copies' latency into
+        # every step)
+        return pl.BlockSpec(shape, lambda si, *scalar_refs:
                             (0,) * len(shape))
 
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    operands = [q_lat, q_pe, c_cache, pe_cache]
+    in_specs = [whole(q_lat.shape), whole(q_pe.shape), in_hbm, in_hbm]
+    out_shape = [jax.ShapeDtypeStruct((S, nh, rank), jnp.float32)]
+    out_specs = [whole((S, nh, rank))]
+    scratch = [
+        pltpu.VMEM((2, G * BS, rank), dtype),
+        pltpu.VMEM((2, G, dr, BS), dtype),
+        pltpu.VMEM((nh, rank), jnp.float32),
+        pltpu.VMEM((nh, 1), jnp.float32),
+        pltpu.VMEM((nh, 1), jnp.float32),
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.SMEM((1,), jnp.int32),
+    ]
+    aliases = {}
+    if write:
+        # the pools come back, updated in place: operands 2 and 3 after
+        # the prefetched scalars, each aliased onto a result
+        for at in (2, 3):
+            aliases[len(scalars) + at] = len(out_shape)
+            out_shape.append(jax.ShapeDtypeStruct(
+                operands[at].shape, operands[at].dtype))
+            out_specs.append(in_hbm)
+        # the entry in f32 (exact): the kernel reads slot si's row of
+        # it, which a packed 16-bit tile does not give
+        new = [a.astype(jnp.float32) for a in new]
+        operands += new
+        in_specs += [whole(a.shape) for a in new]
+        scratch.append(pltpu.SemaphoreType.DMA((2,)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(S,),
-        in_specs=[
-            whole(q_lat.shape),
-            whole(q_pe.shape),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=whole((S, nh, rank)),
-        scratch_shapes=[
-            pltpu.VMEM((2, G * BS, rank), dtype),
-            pltpu.VMEM((2, G, dr, BS), dtype),
-            pltpu.VMEM((nh, rank), jnp.float32),
-            pltpu.VMEM((nh, 1), jnp.float32),
-            pltpu.VMEM((nh, 1), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SMEM((1,), jnp.int32),
-        ],
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=scratch,
     )
     kernel = functools.partial(_mla_decode_kernel, block_size=BS, group=G,
-                               scale=float(scale))
-    return pl.pallas_call(
+                               scale=float(scale), write=write)
+    o, *pools = pl.pallas_call(
         kernel, name="mla_paged_decode_attn", grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, nh, rank), jnp.float32),
+        out_shape=out_shape, input_output_aliases=aliases,
         # sequential: a slot's last chunk starts the next slot's first
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_FORCE_INTERPRET[0],
-    )(tables, lengths, q_lat, q_pe, c_cache, pe_cache)
+    )(*scalars, *operands)
+    return (o, tuple(pools)) if write else o
 
 
 def mla_paged_decode_attn(q_lat, q_pe, c_cache, pe_cache, tables, lengths,
-                          scale):
+                          scale, new=None, write_pos=None):
     """Absorbed latent attention for all heads of every slot over its
     blocks, read in place. q_lat ``[S, nh, rank]``, q_pe ``[S, nh, dr]``
     in the cache's dtype; c_cache ``[NB, BS, rank]``, pe_cache ``[NB,
     dr, BS]``; tables ``[S, MB]`` physical block ids; positions ``>=
     lengths[s]`` carry exactly zero weight. Returns o_lat ``[S, nh,
     rank]`` f32. Same signature and numbers as
-    ``mla_paged_decode_attn_jnp``."""
+    ``mla_paged_decode_attn_jnp``.
+
+    With ``new`` the kernel places the step's new entry itself before
+    it attends (module docstring): ``new = (c_new [S, rank], pe_new [S,
+    dr])`` in the cache's dtype, ``write_pos [S]`` the position each
+    slot's entry goes to. It is written where that is the slot's last
+    live position (``lengths[s] - 1``: ``lengths`` counts it) inside
+    the slot's row, and nowhere otherwise (-1: nothing). The entry
+    reaches VMEM as two small RESIDENT inputs, widened to f32, held for
+    the whole call as ``q_lat`` and ``q_pe`` are (72 KB at 32 slots),
+    not as copies a slot. Returns ``(o_lat, (c_cache, pe_cache))``, the
+    pools updated in place where the caller donates them."""
     return _trace_32bit(_mla_paged_decode_32)(
-        q_lat, q_pe, c_cache, pe_cache, tables, lengths, scale)
+        q_lat, q_pe, c_cache, pe_cache, tables, lengths, scale, new=new,
+        write_pos=write_pos)
+
+
+def latent_write_attention(q_lat, q_pe, new, pools, tables, write_pos,
+                           lengths, scale, kernel):
+    """A decode step's cache write and attention over the latent pool
+    (the sibling of ``ops.paged_attention.paged_write_attention``): the
+    new entry of each slot (``new = (c [S, rank], k_pe [S, dr])`` in the
+    pools' dtype) goes to position ``write_pos[s]``
+    (``ops.paged_attention.live_write_pos``) of the slot's table row in
+    ``pools = (c_cache, pe_cache)``, then the queries attend over the
+    ``lengths`` live positions. Returns ``(o_lat, pools)``.
+
+    ``kernel`` (the engine's choice, ``kernel_viable``): the Pallas
+    kernel, which places the entry itself and writes nothing for -1.
+    Otherwise the ``jnp`` block write (each slot's block read, given
+    its row and set back whole: in place on a donated pool, ISSUE 26),
+    then ``mla_paged_decode_attn_jnp``, the parity oracle. There every
+    slot writes, and -1 goes to the row's last entry AS A WHOLE (column
+    MB-1 AND offset BS-1): private or trash, and behind the length mask
+    either way."""
+    if kernel:
+        return mla_paged_decode_attn(q_lat, q_pe, *pools, tables, lengths,
+                                     scale, new=new, write_pos=write_pos)
+    cf, pf = pools
+    BS = cf.shape[1]
+    with device_scope("kv_write"):
+        wpos = jnp.where(write_pos < 0,
+                         jnp.int32(tables.shape[1] * BS - 1), write_pos)
+        fb = jnp.take_along_axis(
+            tables, (wpos // jnp.int32(BS))[:, None], axis=1)[:, 0]
+        row = (jnp.arange(BS, dtype=jnp.int32)[None, :]
+               == (wpos % jnp.int32(BS))[:, None])           # [S, BS]
+        cf = cf.at[fb].set(jnp.where(
+            row[:, :, None], new[0][:, None, :], cf[fb]))
+        # the rotary key's positions are its last axis
+        pf = pf.at[fb].set(jnp.where(
+            row[:, None, :], new[1][:, :, None], pf[fb]))
+    return mla_paged_decode_attn_jnp(q_lat, q_pe, cf, pf, tables, lengths,
+                                     scale), (cf, pf)

@@ -18,17 +18,24 @@ needs and the two bodies the shell wraps.
                  moe_counts[, samp...])
       -> (next [S], pos + 1, c, k_pe, moe_counts')``
       One token a slot in the ABSORBED form. The pool rides the layer
-      loops' carry flat (``[L*NB, BS, .]``), each slot's current block is
-      read, given its new row and written back whole (in place on a
-      donated pool: ISSUE 26's write), and attention reads the blocks in
-      place through ``tables + layer*NB``. ``moe_counts`` is the model's
+      loops' carry flat (``[L*NB, BS, .]``) and attention reads the
+      blocks in place through ``tables + layer*NB``. The step's new
+      entry is written by ``ops.mla_attention.latent_write_attention``:
+      on a TPU ``mla_paged_decode_attn`` places it itself, in the chunk
+      it has copied, and writes back the two tiles that changed (both
+      pools aliased in and out: no block is gathered or scattered in
+      front of it); elsewhere each slot's current block is read, given
+      its new row and written back whole (in place on a donated pool:
+      ISSUE 26's write). ``moe_counts`` is the model's
       carried state (``CacheSpec.state``): routing counters that stay on
       the device, returned new each step and never donated, so that a
       reader in another thread holds a live array whenever it looks.
 
-Parked and released slots behave as in ``programs.py``: write positions
-are clamped to the row's last entry, free rows point at the trash block,
-the length mask hides what they hold.
+Parked and released slots behave as in ``programs.py``: their entries
+are nobody's (``ops.paged_attention.live_write_pos``: the kernel writes
+none, the ``jnp`` write pins them to the row's last entry), free rows
+point at the trash block and nothing of them is live (length 0), the
+length mask hides what a parked slot's row holds.
 """
 from ...profiler import device_scope
 
@@ -71,31 +78,23 @@ class PagedAccess:
         return tuple(out), tuple(views)
 
     def decode(self, state, layer, pos, c, k_pe, q_lat, q_pe, scale):
-        import jax
         import jax.numpy as jnp
 
         from ...ops import mla_attention as mla_ops
-        BS, C = self.BS, self.MB * self.BS
-        base = layer * jnp.int32(self.NB)
-        # the WRITE position is clamped as a whole (programs.py)
-        wpos = jnp.minimum(pos, jnp.int32(C - 1))
-        bidx = jnp.take_along_axis(
-            self.tables, (wpos // jnp.int32(BS))[:, None], axis=1)[:, 0]
-        row = (jnp.arange(BS, dtype=jnp.int32)[None, :]
-               == (wpos % jnp.int32(BS))[:, None])           # [S, BS]
-        fb = base + bidx
+        from ...ops.paged_attention import live_write_pos
+        from .pool import TRASH_BLOCK
+        # what attention may read of a slot: its positions so far, never
+        # more than the blocks its row holds (a released slot: nothing)
+        held = jnp.sum((self.tables != TRASH_BLOCK).astype(jnp.int32),
+                       axis=1)
+        lengths = jnp.minimum(pos + 1, held * jnp.int32(self.BS))
         with device_scope("kv_write"):
-            cf, pf = state
-            cf = cf.at[fb].set(jnp.where(
-                row[:, :, None], c.astype(cf.dtype)[:, None, :], cf[fb]))
-            pf = pf.at[fb].set(jnp.where(
-                row[:, None, :], k_pe.astype(pf.dtype)[:, :, None],
-                pf[fb]))
-            state = (cf, pf)
-        fn = mla_ops.mla_paged_decode_attn if self.kernel \
-            else mla_ops.mla_paged_decode_attn_jnp
-        return state, fn(q_lat, q_pe, state[0], state[1],
-                         self.tables + base, pos + 1, scale)
+            new = (c.astype(state[0].dtype), k_pe.astype(state[1].dtype))
+            wpos = live_write_pos(pos, lengths)
+        o_lat, state = mla_ops.latent_write_attention(
+            q_lat, q_pe, new, state, self.tables + layer * jnp.int32(self.NB),
+            wpos, lengths, scale, self.kernel)
+        return state, o_lat
 
 
 def decode_kernels(cfg, num_slots, block_size):

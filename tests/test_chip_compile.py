@@ -547,10 +547,27 @@ def test_mla_paged_decode_attn_compiles(one_chip, nh, r, BS, G):
     NB = 6 * (S * MB + 1)
     assert mla_attention.kernel_viable(BS, r, dr, jnp.bfloat16)
     assert mla_attention.blocks_per_chunk(BS, r, dr, MB, jnp.bfloat16) == G
+    q = (sds((S, nh, r)), sds((S, nh, dr)))
+    pools = (sds((NB, BS, r)), sds((NB, dr, BS)))
+    scalars = (sds((S, MB), jnp.int32), sds((S,), jnp.int32))
     _compile(lambda *a: mla_attention.mla_paged_decode_attn(*a, 192 ** -0.5),
-             sds((S, nh, r)), sds((S, nh, dr)), sds((NB, BS, r)),
-             sds((NB, dr, BS)), sds((S, MB), jnp.int32),
-             sds((S,), jnp.int32))
+             *q, *pools, *scalars)
+
+    # WITH the step's new entry placed in it (what the decode program
+    # calls), pools donated: Mosaic takes the write-back of the latent's
+    # [16, rank] tile and of 128 lanes of the rotary key's block, both
+    # pools are aliased onto the results and the call has no temporary
+    def call(q, pools, new, bt, ln, wpos):
+        return mla_attention.latent_write_attention(
+            *q, new, pools, bt, wpos, ln, 192 ** -0.5, True)
+    compiled = jax.jit(call, donate_argnums=(1,)).lower(
+        q, pools, (sds((S, r)), sds((S, dr))), *scalars,
+        sds((S,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mla_paged_decode_attn" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * NB * BS * (r + dr)
+    assert mem.temp_size_in_bytes < 1 << 20, mem.temp_size_in_bytes
 
 
 def test_moe_experts_swiglu_decode_compiles(one_chip):
@@ -565,6 +582,9 @@ def test_moe_experts_swiglu_decode_compiles(one_chip):
              sds((), jnp.int32))
 
 
+LATENT_SLOTS = 16
+
+
 def _latent_decode_program(one_chip, sampling):
     """(compiled paged_decode, the two pool shapes) of a deepseek_v3
     model, jitted with the engine's donation (pos and the cache spec's
@@ -576,7 +596,7 @@ def _latent_decode_program(one_chip, sampling):
     from paddle_tpu.serving.paged.latent_programs import \
         build_paged_latent_fns
     from paddle_tpu.text import deepseek_v3 as ds
-    S, BS, MB = 16, 256, 64
+    S, BS, MB = LATENT_SLOTS, 256, 64
     NB = S * MB + 1
     cfg = ds.DeepseekV3Config(
         vocab_size=1024, hidden_size=1024, num_hidden_layers=4,
@@ -612,9 +632,10 @@ def test_latent_decode_program_updates_pool_in_place(one_chip, sampling):
     """The deepseek_v3 decode program carries the donated latent pool
     through both of its layer loops in place: both arrays aliased onto
     the results, temporaries far under the pool, both kernels in the
-    program, and no copy / dynamic-slice / dynamic-update-slice of the
+    program, no copy / dynamic-slice / dynamic-update-slice of the
     pool's shape (a rotary-key array with the key dim minor made XLA copy
-    the whole array in front of the kernel, every layer: PERF.md)."""
+    the whole array in front of the kernel, every layer: PERF.md) and no
+    instruction of a block gather's shape (PR 49)."""
     import re
     compiled, pool = _latent_decode_program(one_chip, sampling)
     nbytes = sum(2 * int(np.prod(p)) for p in pool)
@@ -636,6 +657,13 @@ def test_latent_decode_program_updates_pool_in_place(one_chip, sampling):
     bad = [(name, op) for name, op in found
            if op in moving or any(w in name for w in moving)]
     assert not bad, bad
+    # the kernel places the step's entry: no gather of every slot's
+    # current block (``[S, BS, rank]``, ``[S, dr, BS]``) and no scatter
+    # of it back is left in front of it
+    blocks = "|".join(",".join(str(d) for d in (LATENT_SLOTS,) + p[2:])
+                      for p in pool)
+    left = re.findall(rf"%([\w.\-]+) = bf16\[(?:{blocks})\]", text)
+    assert not left, left
 
 
 # ------------------------------------------------ nemotron_h (PR 35)

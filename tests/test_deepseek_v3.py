@@ -27,6 +27,7 @@ sys.path.insert(0, ROOT)
 from benchmarks.reference import deepseek_v3 as ref  # noqa: E402
 from paddle_tpu.ops import mla_attention as mla  # noqa: E402
 from paddle_tpu.ops import moe_experts as moe  # noqa: E402
+from paddle_tpu.ops.paged_attention import live_write_pos  # noqa: E402
 from paddle_tpu.serving import ServingEngine  # noqa: E402
 from paddle_tpu.text import deepseek_v3 as ds  # noqa: E402
 
@@ -398,6 +399,128 @@ def test_mla_decode_kernel_never_reads_a_dead_block(interpret, monkeypatch,
     live = np.asarray(lengths) > 0
     assert np.isfinite(np.asarray(got)).all()
     assert np.abs(np.asarray(got) - np.asarray(want))[live].max() < 2e-6
+
+
+# the step's new entry placed by the kernel. Blocks of 256 (two lane
+# tiles of the transposed rotary key), 5 a slot, chunks of G = 2 blocks:
+# (length, write position) of the slot under test, None = length - 1
+# (a live entry). It sits between two live slots, so that its first
+# chunk is a hand-over and its write-back is waited for before the next
+# slot's chunk lands
+_BSW, _MBW = 256, 5
+_MLA_WRITES = {
+    "block_first_row": (_BSW + 1, None),
+    "block_last_row": (_BSW, None),
+    "mid_tile": (_BSW + 8, None),             # row 7 of a 16-row tile
+    "lane_127": (128, None),
+    "lane_128": (129, None),
+    "chunk_first_block": (2 * _BSW + 5, None),
+    "chunk_last_block": (4 * _BSW - 3, None),
+    "one_live_block_chunk": (4 * _BSW + 10, None),
+    "length_1": (1, None),
+    "capacity": (_MBW * _BSW, None),
+    "no_write": (300, -1),
+    "parked": (2 * _BSW, -1),      # holds 2 blocks, position at the park
+    "released": (0, -1),           # a row of trash
+    "stale_position": (300, 17),   # not the last live position
+    "past_capacity": (_MBW * _BSW + 1, _MBW * _BSW),
+}
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view({2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", list(_MLA_WRITES))
+def test_mla_decode_kernel_places_the_new_entry(interpret, monkeypatch,
+                                                case, dtype, tol):
+    """The kernel with ``new`` / ``write_pos`` against the ``jnp`` block
+    write followed by ``mla_paged_decode_attn_jnp``: ``o_lat`` of every
+    slot with something live as the oracle's; both pools BYTE-equal to
+    what they were with the live entries at their positions and nothing
+    else changed (not another row of the tile written back, not another
+    lane of the rotary key's, not a parked or released slot's block, not
+    the trash block), and byte-equal to the oracle's everywhere but the
+    row's last entry of a slot that writes nothing, where the oracle
+    pins its stray entry."""
+    BS, MB, G = _BSW, _MBW, 2
+    nh, r, dr = 4, 128, 16
+    slots = [(700, None), _MLA_WRITES[case], (40, None)]
+    lens = np.asarray([n for n, _ in slots], np.int32)
+    wpos = np.asarray([n - 1 if w is None else w for n, w in slots],
+                      np.int32)
+    S = len(slots)
+    NB = S * MB + 1                                   # the last: trash
+    monkeypatch.setattr(mla, "_CHUNK_VMEM_BYTES", 2 * G * BS * (r + dr)
+                        * jnp.dtype(dtype).itemsize)
+    assert mla.blocks_per_chunk(BS, r, dr, MB, dtype) == G
+    rng = np.random.default_rng(2)
+    q_lat = jnp.asarray(rng.normal(size=(S, nh, r)), dtype)
+    q_pe = jnp.asarray(rng.normal(size=(S, nh, dr)), dtype)
+    pools = (jnp.asarray(rng.normal(size=(NB, BS, r)), dtype),
+             jnp.asarray(rng.normal(size=(NB, dr, BS)), dtype))
+    new = (jnp.asarray(rng.normal(size=(S, r)), dtype),
+           jnp.asarray(rng.normal(size=(S, dr)), dtype))
+    tables = rng.permutation(NB - 1).reshape(S, MB).astype(np.int32)
+    # blocks a slot does not hold are the trash block
+    tables[np.arange(MB)[None, :] * BS >= lens[:, None]] = NB - 1
+    def run(wpos, kernel):
+        return mla.latent_write_attention(
+            q_lat, q_pe, new, pools, jnp.asarray(tables),
+            jnp.asarray(wpos), jnp.asarray(lens), 0.1, kernel)
+    # the kernel is handed the position as it came and must itself
+    # write only a live one; the oracle what the program forms of it
+    # (-1 where it is not the slot's last live position)
+    got, got_pools = run(wpos, True)
+    wpos = np.asarray(live_write_pos(jnp.asarray(wpos), jnp.asarray(lens)))
+    placed = (wpos >= 0) & (wpos < MB * BS)
+    if case != "past_capacity":      # no program forms it, no oracle
+        want, want_pools = run(wpos, False)
+        some = lens > 0
+        assert np.abs(np.asarray(got) - np.asarray(want))[some].max() < tol
+    assert np.isfinite(np.asarray(got)).all()
+    for i, (pool, entry) in enumerate(zip(pools, new)):
+        expect = np.array(pool)
+        for s in np.nonzero(placed)[0]:
+            blk, off = tables[s, wpos[s] // BS], wpos[s] % BS
+            if i:
+                expect[blk, :, off] = np.asarray(entry)[s]
+            else:
+                expect[blk, off] = np.asarray(entry)[s]
+        np.testing.assert_array_equal(_bits(got_pools[i]), _bits(expect))
+        if case == "past_capacity":
+            continue
+        same = np.ones(pool.shape, bool)
+        for s in np.nonzero(~placed)[0]:
+            # where the oracle pins a stray entry
+            blk, off = tables[s, MB - 1], BS - 1
+            same[(blk, slice(None), off) if i else (blk, off)] = False
+        np.testing.assert_array_equal(_bits(got_pools[i])[same],
+                                      _bits(want_pools[i])[same])
+
+
+def test_mla_read_only_call_traces_no_write(interpret):
+    """Without a new entry the call is what it was: one result, no
+    aliased pool, no third scalar; with one, both pools are aliased
+    onto results 1 and 2."""
+    f32 = jnp.float32
+    q_lat, q_pe, c, pe, tables, lens = (
+        jnp.zeros((2, 4, 128), f32), jnp.zeros((2, 4, 16), f32),
+        jnp.zeros((5, 16, 128), f32), jnp.zeros((5, 16, 16), f32),
+        jnp.zeros((2, 2), jnp.int32), jnp.ones((2,), jnp.int32))
+    text = str(jax.make_jaxpr(
+        lambda *a: mla.mla_paged_decode_attn(*a, 0.1))(
+            q_lat, q_pe, c, pe, tables, lens))
+    assert "input_output_aliases=()" in text
+    wrote = str(jax.make_jaxpr(
+        lambda *a: mla.mla_paged_decode_attn(
+            *a, 0.1, new=(q_lat[:, 0], q_pe[:, 0]), write_pos=lens - 1))(
+                q_lat, q_pe, c, pe, tables, lens))
+    assert "input_output_aliases=((5, 1), (6, 2))" in wrote
 
 
 @pytest.mark.parametrize("layer_m", [0, 1])

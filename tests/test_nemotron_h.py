@@ -684,6 +684,9 @@ def test_latent_spec_pool_donation_and_decode_program_unchanged():
 
 
 # digests of str(jax.make_jaxpr(decode program)) at the sizes above,
-# taken on the parent commit (50d367b) with this same test code
+# taken on the parent commit (50d367b) with this same test code; the
+# latent one is PR 49's (its cache write moved into
+# ``ops.mla_attention.latent_write_attention`` and its length counts
+# held blocks only; the digest before was ``aa31ce9df0109a05``)
 GPT_DECODE_DIGEST = "bfc0be992ec17538"
-LATENT_DECODE_DIGEST = "aa31ce9df0109a05"
+LATENT_DECODE_DIGEST = "31433cb513ed06f1"

@@ -107,8 +107,11 @@ DIGESTS = {
     ("gpt", "decode", True): "f2ac1337f0cf7faf",
     ("latent", "prefill", False): "3f1b5a985bf40e04",
     ("latent", "prefill", True): "8e721e2688d39e17",
-    ("latent", "decode", False): "aa31ce9df0109a05",
-    ("latent", "decode", True): "95e43798a673263a",
+    # PR 49's own (a MEANT change: the decode program forms a length
+    # that counts only held blocks and a live write position, as the
+    # other five do, and writes through ``latent_write_attention``)
+    ("latent", "decode", False): "31433cb513ed06f1",
+    ("latent", "decode", True): "7e62ef3c2c2e9129",
     ("hybrid", "prefill", False): "2211a68f08327650",
     ("hybrid", "prefill", True): "3b4cbbf1bcab0710",
     ("hybrid", "decode", False): "a18b63eca0d7b956",
@@ -182,9 +185,11 @@ SCOPES = {
         "mla/attn/kv_gather": 26, "mla/attn/kv_write": 26, "mla/out": 6,
         "mla/q_absorb": 148, "mlp": 16, "moe/experts": 162,
         "moe/router": 25, "moe/shared": 7, "sample": 19},
+    # PR 49's own: the ``jnp`` block write (the CPU's arm) is staged
+    # whole under ``kv_write``, the write position with it
     ("latent", "decode"): {
-        "embed": 5, "lm_head": 10, "mla/attn": 128,
-        "mla/attn/kv_gather": 26, "mla/attn/kv_write": 64,
+        "embed": 5, "lm_head": 10, "mla/attn": 66,
+        "mla/attn/kv_gather": 26, "mla/attn/kv_write": 158,
         "mla/out": 12, "mla/q_absorb": 154, "mlp": 16,
         "moe/experts": 34, "moe/router": 25, "moe/shared": 7,
         "sample": 2},
