@@ -1,23 +1,32 @@
-"""Fused linear + softmax-cross-entropy Pallas kernel (the LM head).
+"""Linear + softmax-cross-entropy as one op (the LM head and its loss).
 
-The GPT head computes logits = x @ W^T over a ~50k vocab and immediately
-reduces them to one scalar per token. Unfused, the [tokens, vocab]
-logits tensor (1.6 GB f32 at batch 8 x seq 1024) round-trips HBM several
-times (write logits, read for log-softmax, read again for d_logits,
-write d_logits, read twice for dx/dW) — pure bandwidth, no reuse. This
-kernel streams vocab TILES through VMEM with an online logsumexp
-(the flash-attention trick applied to the classifier), so the full
-logits tensor never exists in HBM in either direction. Backward splits
-into two pallas_calls (dx accumulates over the vocab grid dim, dW over
-the token grid dim — each accumulator needs ITS dim innermost), so each
-recomputes the logits tiles: TWO extra x@W matmul passes total. FLOPs
-are cheap here — the unfused path's MXU sits idle on the ~5 HBM passes
-over the logits tensor these kernels delete.
+The GPT head computes logits = x @ W^T over a ~50k vocabulary and
+reduces them at once to one scalar a token. Two ways of doing it live
+here, and the code chooses between them from what it can see.
+
+**One chip, the default: plain ``jnp``, three logits-sized passes.** The
+f32 logits are written once, with their row maximum ``m`` fused into the
+matmul by XLA. A call that will be differentiated takes the softmax's sum
+OUT OF THE dx MATMUL: ``exp(logits - m) @ [W | 1]`` is dx unnormalised
+and, in the columns of ones, the sum (``_exp_fwd``), so the loss has no
+pass of its own over the logits; dW is a third matmul whose producer
+forms the logits' gradient (``_exp_bwd``). A forward-only call
+(evaluation) is the plain composition, ``_reference``: one matmul and the
+log-sum-exp's pass.
+
+**The Pallas kernels: the logits never exist in HBM.** Vocabulary TILES
+stream through VMEM with an online logsumexp (the flash-attention trick
+applied to the classifier). The backward is two pallas_calls (dx
+accumulates over the vocab grid dim, dW over the token grid dim: each
+accumulator needs ITS dim innermost), so each recomputes the logits
+tiles: TWO extra x@W matmul passes. That lost 46 ms a step to the
+composition on one chip (GPT-124M, 2026-08-02 sweep), so there they are
+opt-in; the vocab-sharded path below runs them by default, for the
+per-shard logits they never hold.
 
 Reference analogue: the reference fuses this pair as
 softmax_with_cross_entropy_op on the [T, V] logits its matmul wrote
-(paddle/fluid/operators/softmax_with_cross_entropy_op.cu) — on TPU the
-win is fusing the MATMUL too, which XLA will not do across a reduction.
+(paddle/fluid/operators/softmax_with_cross_entropy_op.cu).
 
 Weight layout is [V, H] (paddle embedding layout), so tied-embedding
 heads pass word_embeddings.weight with no transpose.
@@ -29,7 +38,7 @@ import os as _os
 import jax
 import jax.numpy as jnp
 
-from ..core.dispatch import register_op
+from ..core.dispatch import is_grad_enabled, register_op
 from .pallas_compat import trace_32bit as _trace_32bit
 
 _BLOCK_T = int(_os.environ.get("PADDLE_FUSED_CE_BLOCK_T", "256"))
@@ -71,12 +80,13 @@ def _use_pallas(x, w_vh, tp=False):
     # Default OFF on real hardware since the 2026-08-02 on-chip sweep:
     # the Pallas kernels cost ~46 ms/step on GPT-124M vs the XLA
     # composition (the bwd recomputes the 633-GFLOP head matmul in both
-    # dx and dw kernels at below-XLA MXU efficiency; tools/
-    # gpt_roofline.py shows fused cannot beat unfused on speed even at
-    # equal kernel efficiency — its win is logits-tensor MEMORY, which
-    # matters for big-batch/long-seq configs). PADDLE_FUSED_CE=1 opts
-    # in; the vocab-sharded TP path has its own default-on gate above
-    # (PADDLE_FUSED_CE_TP).
+    # dx and dw kernels at below-XLA MXU efficiency; their win is
+    # logits-tensor MEMORY, which matters for big-batch/long-seq
+    # configs). With the gate off a differentiated call takes the jnp
+    # rule below (_exp_fwd / _exp_bwd: the logits written once in f32,
+    # the softmax's sum out of the dx matmul, no recompute), the CPU
+    # included. PADDLE_FUSED_CE=1 opts in; the vocab-sharded TP path has
+    # its own default-on gate above (PADDLE_FUSED_CE_TP).
     return ok and _os.environ.get("PADDLE_FUSED_CE") == "1"
 
 
@@ -267,58 +277,74 @@ def _reference(x, w_vh, labels, ignore_index):
     return jnp.where(valid, lse - ll, 0.0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _fused_core(x, w_vh, labels, ignore_index):
+# width of the block of ones beside W in the dx matmul: one lane tile,
+# so that the softmax's sum is a column of a matmul the step runs anyway
+_ONES = 128
+
+
+def _exp_fwd(x, w_vh, labels, ignore_index):
+    """The differentiated call's forward, in plain ``jnp``: the softmax's
+    sum is NOT a pass of its own over the f32 logits. With ``m`` the row
+    maximum (XLA fuses it into the logits' matmul), ``e = exp(logits -
+    m)`` needs no sum, and ``e @ [W | 1]`` is dx unnormalised AND, in
+    the columns of ones, ``s = sum(e)``: the MXU reduces inside the dx
+    matmul. XLA forms ``e`` and ``[W | 1]`` as that matmul's producers,
+    so nothing logits-sized is written but the logits. ``s >= 1``
+    always: the maximum's own term is exp(0)."""
+    v, h = w_vh.shape
+    logits = _dot_f32(x, w_vh, ((1,), (1,)))
+    m = jnp.max(logits, axis=-1)
+    e = jnp.exp(logits - m[:, None])
+    w1 = jnp.concatenate([w_vh, jnp.ones((v, _ONES), w_vh.dtype)], axis=1)
+    acc = _dot_f32(e, w1, ((1,), (0,)))            # [T, H + 128] f32
+    u, s = acc[:, :h], acc[:, h]
+    lab = jnp.clip(labels, 0, v - 1).astype(jnp.int32)
+    ll = jnp.take_along_axis(logits, lab[:, None], axis=-1)[:, 0]
+    loss = jnp.where(labels != ignore_index, m + jnp.log(s) - ll, 0.0)
+    return loss, (x, w_vh, labels, (logits, m, s, u))
+
+
+def _exp_bwd(x, w_vh, labels, saved, g, ignore_index):
+    """dx is the forward's matmul scaled by row AFTER it, less the
+    label's row of W; dW is one matmul whose producer forms ``(softmax -
+    onehot) * g`` from the saved logits, ``m`` and ``r = g / s``."""
+    logits, m, s, u = saved
+    v = w_vh.shape[0]
+    lab = jnp.clip(labels, 0, v - 1).astype(jnp.int32)
+    gv = jnp.where(labels != ignore_index, g.astype(jnp.float32), 0.0)
+    r = gv / s
+    dx = u * r[:, None] - gv[:, None] * w_vh[lab].astype(jnp.float32)
+    col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    d = (jnp.exp(logits - m[:, None]) * r[:, None]
+         - jnp.where(col == lab[:, None], gv[:, None], 0.0))
+    dw = _dot_f32(d, x, ((0,), (0,)))
+    return dx.astype(x.dtype), dw.astype(w_vh.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _fused_core(x, w_vh, labels, ignore_index, taped=False):
     if _use_pallas(x, w_vh):
         return _pallas_fwd(x, w_vh, labels, ignore_index)[0]
+    if taped:
+        # the framework's tape runs THIS call for the loss and replays
+        # _fused_fwd for the backward in the same program (core/
+        # dispatch.py: vjp_fn): spelled alike, XLA merges the two
+        return _exp_fwd(x, w_vh, labels, ignore_index)[0]
+    # forward only (evaluation): one matmul, no second one for a sum
     return _reference(x, w_vh, labels, ignore_index)
 
 
-def _fused_fwd(x, w_vh, labels, ignore_index):
+def _fused_fwd(x, w_vh, labels, ignore_index, taped):
     if _use_pallas(x, w_vh):
         loss, lse = _pallas_fwd(x, w_vh, labels, ignore_index)
         return loss, (x, w_vh, labels, lse)
-    return (_reference(x, w_vh, labels, ignore_index),
-            (x, w_vh, labels, None))
+    return _exp_fwd(x, w_vh, labels, ignore_index)
 
 
-def _xla_bwd(x, w_vh, labels, lse, g, ignore_index):
-    """Backward as plain XLA ops from the saved lse: ONE logits
-    recompute at XLA matmul efficiency, d_logits = (softmax-onehot)*g
-    fused into its consumers by XLA, dx/dW as two MXU matmuls. Trades
-    the Pallas bwd's zero-materialization for d_logits round-tripping
-    HBM once in bf16 — but deletes the second logits recompute and runs
-    every matmul at XLA's MXU scheduling, not a hand-rolled kernel's.
-    Selected by PADDLE_FUSED_CE_BWD=xla (perf sweep axis)."""
-    logits = _dot_f32(x, w_vh, ((1,), (1,)))
-    p = jnp.exp(logits - lse[:, None])
-    col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-    onehot = (col == labels.astype(jnp.int32)[:, None]).astype(
-        jnp.float32)
-    valid = (labels != ignore_index).astype(jnp.float32)
-    # d_logits stays f32 through BOTH matmuls (ADVICE r5): casting to
-    # bf16 first would quantize the gradient signal the Pallas backward
-    # keeps at f32 tile precision; only the final outputs narrow.
-    # dot_general accepts the mixed f32/bf16 operands and accumulates
-    # f32 (preferred_element_type in _dot_f32).
-    d = (p - onehot) * (g.astype(jnp.float32) * valid)[:, None]
-    dx = _dot_f32(d, w_vh, ((1,), (0,))).astype(x.dtype)
-    dw = _dot_f32(d, x, ((0,), (0,))).astype(w_vh.dtype)
-    return dx, dw
-
-
-def _fused_bwd(ignore_index, res, g):
-    x, w_vh, labels, lse = res
-    if lse is None:  # reference path: differentiate the composition
-        _, vjp = jax.vjp(
-            lambda x_, w_: _reference(x_, w_, labels, ignore_index),
-            x, w_vh)
-        dx, dw = vjp(g)
-        return dx, dw, None
-    if _os.environ.get("PADDLE_FUSED_CE_BWD") == "xla":
-        dx, dw = _xla_bwd(x, w_vh, labels, lse, g, ignore_index)
-        return dx, dw, None
-    dx, dw = _pallas_bwd(x, w_vh, labels, lse, g, ignore_index)
+def _fused_bwd(ignore_index, taped, res, g):
+    # the kernels save the log-sum-exp, the jnp rule its four arrays
+    bwd = _exp_bwd if isinstance(res[3], tuple) else _pallas_bwd
+    dx, dw = bwd(*res, g, ignore_index)
     return dx, dw, None
 
 
@@ -326,18 +352,21 @@ _fused_core.defvjp(_fused_fwd, _fused_bwd)
 
 
 @register_op("fused_linear_cross_entropy")
-def _fused_op(x, w_vh, labels, *, ignore_index):
+def _fused_op(x, w_vh, labels, *, ignore_index, taped):
     """Per-token loss [T] for logits = x @ w_vh.T, labels [T] int.
     ignore_index rows contribute 0 loss and 0 gradient."""
-    return _fused_core(x, w_vh, labels, ignore_index)
+    return _fused_core(x, w_vh, labels, ignore_index, taped)
 
 
 def fused_linear_cross_entropy(x, weight_vh, labels, ignore_index=-100):
     """Public wrapper over Tensors: x [T, H], weight_vh [V, H] (paddle
     embedding layout — tied heads pass the embedding table directly),
     labels [T]. Returns per-token loss [T] (reduce outside)."""
+    # a call the tape records is one whose backward will be asked for
+    taped = is_grad_enabled() and not (x.stop_gradient
+                                       and weight_vh.stop_gradient)
     return _fused_op(x, weight_vh, labels,
-                     ignore_index=int(ignore_index))
+                     ignore_index=int(ignore_index), taped=taped)
 
 
 # ---- tensor-parallel (vocab-sharded) variant --------------------------------

@@ -343,13 +343,13 @@ class GPTForCausalLM(nn.Layer):
         eager_mp = self.cfg.use_mp and mesh is not None and not in_step
         if labels is not None and self.cfg.tie_embeddings \
                 and ((not self.cfg.use_mp and mesh_trivial) or eager_mp):
-            # fused linear+CE streams vocab tiles through VMEM: the
-            # [tokens, vocab] logits tensor never exists in HBM in
-            # either direction (ops/fused_ce.py; falls back to the
-            # composition below on CPU / unsupported shapes). Also the
-            # one-device head of a TP model's eager phases: unfused,
-            # they keep ~8 GiB of logits-sized intermediates live at
-            # GPT-124M x 8192 tokens.
+            # head and loss as ONE op (ops/fused_ce.py): by default
+            # plain jnp whose f32 [tokens, vocab] logits DO live in HBM,
+            # once, the softmax's sum taken out of the dx matmul (three
+            # logits-sized passes a step); its Pallas kernels, which
+            # never hold the logits, are off on one chip (they lost on
+            # time). Also the one-device head of a TP model's eager
+            # phases.
             from ..ops.fused_ce import fused_linear_cross_entropy
             # head and loss are one kernel here: one scope, both names
             with device_scope("lm_head"), device_scope("loss"):

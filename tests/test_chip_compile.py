@@ -239,12 +239,13 @@ def test_replayed_packed_flash_fwd_merges_with_the_first(one_chip):
         "flash_bwd_dqkv", "flash_fwd"], kernels
 
 
-def _ce_args(sharding_x, sharding_w, sharding_t, vocab=VOCAB):
-    x = jax.ShapeDtypeStruct((TOKENS, HIDDEN), jnp.bfloat16,
+def _ce_args(sharding_x, sharding_w, sharding_t, vocab=VOCAB,
+             tokens=TOKENS):
+    x = jax.ShapeDtypeStruct((tokens, HIDDEN), jnp.bfloat16,
                              sharding=sharding_x)
     w = jax.ShapeDtypeStruct((vocab, HIDDEN), jnp.bfloat16,
                              sharding=sharding_w)
-    lab = jax.ShapeDtypeStruct((TOKENS,), jnp.int32, sharding=sharding_t)
+    lab = jax.ShapeDtypeStruct((tokens,), jnp.int32, sharding=sharding_t)
     return x, w, lab
 
 
@@ -259,6 +260,53 @@ def test_fused_ce_bwd_compiles(one_chip):
     vec = jax.ShapeDtypeStruct((TOKENS,), jnp.float32, sharding=one_chip)
     _compile(lambda x, w, lab, lse, g: fused_ce._pallas_bwd(
         x, w, lab, lse, g, -100), x, w, lab, vec, vec)
+
+
+def _fusions_over(text, shape):
+    """(name, kind) of the entry computation's fusions that take or give
+    an array of ``shape``."""
+    bodies = dict(re.findall(r"^%?([\w.-]+) \([^\n]*\{\n(.*?)^\}", text,
+                             re.M | re.S))
+    entry = re.search(r"^ENTRY [^\n]*\n(.*?)^\}", text, re.M | re.S).group(1)
+    found = []
+    for line in entry.splitlines():
+        m = re.search(r"%([\w.-]+) = (.*?) fusion\(.*kind=(\w+), "
+                      r"calls=%([\w.-]+)", line)
+        if m is None:
+            continue
+        name, gives, kind, body = m.groups()
+        takes = any(" parameter(" in ln and shape in ln
+                    for ln in bodies[body].splitlines())
+        if takes or shape in gives:
+            found.append((name, kind))
+    return found
+
+
+def test_differentiated_head_walks_the_logits_three_times(one_chip):
+    """The training cell's head and loss at its own shape (24 x 1024
+    tokens), as the tape runs them: the forward call AND the replay of
+    its rule for the backward, in one program. XLA must merge the two
+    and keep three dense passes over the f32 logits (the logits with
+    their row maximum; `exp(logits - m) @ [W | 1]` = dx and the softmax's
+    sum; dW), none of them a reduce-only loop fusion: what the ledger's
+    `breakdown.device_ops` shows of the cell. Beside them only the
+    label's lookup, a gather of one element a row."""
+    tokens = 24 * 1024
+    x, w, lab = _ce_args(one_chip, one_chip, one_chip, tokens=tokens)
+
+    def step(x, w, lab):
+        def head(x, w):
+            return fused_ce._fused_core(x, w, lab, -100, True)
+        loss = head(x, w)
+        _, vjp = jax.vjp(head, x, w)
+        g = jnp.full((tokens,), 1.0 / tokens, jnp.float32)
+        return loss.sum(), vjp(g)
+    compiled = jax.jit(step).lower(x, w, lab).compile()
+    over = _fusions_over(compiled.as_text(), f"f32[{tokens},{VOCAB}]")
+    dense = [(n, k) for n, k in over if k != "kCustom"]
+    assert len(dense) == 3 and {k for _, k in dense} == {"kOutput"}, over
+    assert len(over) - len(dense) == 1, over      # the label's gather
+    assert compiled.memory_analysis().temp_size_in_bytes < 5.1e9
 
 
 # the 1.3B serving cell's attention: 16 heads x 128, blocks of 16, 64 a

@@ -85,31 +85,157 @@ def test_pallas_kernel_grads_interpret(interpret_kernels):
                                rtol=2e-4, atol=2e-6)
 
 
-def test_xla_bwd_variant_grads_match(interpret_kernels, monkeypatch):
-    """PADDLE_FUSED_CE_BWD=xla (Pallas fused fwd + XLA-composed bwd
-    from the saved lse) matches jax.grad of the unfused composition —
-    the hybrid the perf sweep measures against the all-Pallas bwd."""
+def _spread_case(rs, t, h, v, dtype):
+    """Rows whose logits spread by 150 and more: every term of the
+    softmax's sum but the maximum's underflows, so s is exactly 1."""
+    import jax.numpy as jnp
+    w = np.zeros((v, h), np.float32)
+    for j in range(v):
+        w[j, j % h] = 1.0 + j // h        # rows j, j + h, ... parallel
+    hot = rs.randint(0, h, (t,))
+    x = np.zeros((t, h), np.float32)
+    x[np.arange(t), hot] = 150.0
+    return jnp.asarray(x, dtype), jnp.asarray(w, dtype)
+
+
+# name: (t, h, v, dtype, what the labels hold, cotangent, inputs)
+_RULE_CASES = {
+    "f32": (128, 128, 1024, "float32", "one_ignored", "mean", "randn"),
+    "bf16": (128, 128, 1024, "bfloat16", "one_ignored", "mean", "randn"),
+    "some_rows_ignored": (96, 32, 256, "float32", "third_ignored",
+                          "mean", "randn"),
+    "every_row_ignored": (64, 32, 256, "float32", "all_ignored", "mean",
+                          "randn"),
+    "label_on_last_vocab_row": (64, 32, 256, "float32", "last_row",
+                                "mean", "randn"),
+    "label_past_the_vocab_is_clipped": (64, 32, 256, "float32",
+                                        "past_end", "mean", "randn"),
+    "logits_spread_over_100": (64, 32, 160, "float32", "plain", "mean",
+                               "spread"),
+    "logits_spread_over_100_bf16": (64, 32, 160, "bfloat16",
+                                    "one_ignored", "rows", "spread"),
+    "t_off_128": (100, 32, 256, "float32", "one_ignored", "mean",
+                  "randn"),
+    "v_off_128": (64, 32, 1000, "float32", "one_ignored", "mean",
+                  "randn"),
+    "h_under_a_lane_tile": (64, 8, 200, "float32", "plain", "mean",
+                            "randn"),
+    "cotangent_differs_by_row": (96, 32, 256, "float32", "third_ignored",
+                                 "rows", "randn"),
+    "bf16_cotangent_differs_by_row": (100, 64, 1000, "bfloat16",
+                                      "third_ignored", "rows", "randn"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RULE_CASES))
+def test_differentiated_rule_matches_the_composition(case):
+    """The hand-written rule of a differentiated call (the softmax's sum
+    taken out of the dx matmul, `_exp_fwd` / `_exp_bwd`) against
+    `_reference` differentiated by jax.vjp: loss, dx and dW. Under bf16
+    inputs the oracle runs in f32 THROUGH the same bf16 operand values
+    (ADVICE r5: the logits' gradient stays f32 through both matmuls,
+    only the results narrow), so the rule is held to one final rounding."""
     import jax
     import jax.numpy as jnp
-    monkeypatch.setenv("PADDLE_FUSED_CE_BWD", "xla")
-    rs = np.random.RandomState(6)
-    t, h, v = 128, 128, 1024
-    x = jnp.asarray(rs.randn(t, h).astype(np.float32) * 0.3)
-    w = jnp.asarray(rs.randn(v, h).astype(np.float32) * 0.3)
+    t, h, v, dtype, labels, cotangent, inputs = _RULE_CASES[case]
+    rs = np.random.RandomState(sorted(_RULE_CASES).index(case))
+    dtype = jnp.dtype(dtype)
+    if inputs == "spread":
+        x, w = _spread_case(rs, t, h, v, dtype)
+    else:
+        x = jnp.asarray(rs.randn(t, h).astype(np.float32) * 0.3, dtype)
+        w = jnp.asarray(rs.randn(v, h).astype(np.float32) * 0.3, dtype)
     lab_np = rs.randint(0, v, (t,))
-    lab_np[3] = -100
+    if labels == "one_ignored":
+        lab_np[3] = -100
+    elif labels == "third_ignored":
+        lab_np[::3] = -100
+    elif labels == "all_ignored":
+        lab_np[:] = -100
+    elif labels == "last_row":
+        lab_np[::2] = v - 1
+    elif labels == "past_end":
+        lab_np[::4] = v + 5
     lab = jnp.asarray(lab_np.astype(np.int32))
+    g = (jnp.full((t,), 1.0 / t, jnp.float32) if cotangent == "mean"
+         else jnp.asarray(rs.rand(t).astype(np.float32)))
 
-    gx_f, gw_f = jax.grad(
-        lambda x_, w_: fused_ce._fused_core(x_, w_, lab, -100).mean(),
-        argnums=(0, 1))(x, w)
-    gx_r, gw_r = jax.grad(
-        lambda x_, w_: fused_ce._reference(x_, w_, lab, -100).mean(),
-        argnums=(0, 1))(x, w)
-    np.testing.assert_allclose(np.asarray(gx_f), np.asarray(gx_r),
-                               rtol=2e-4, atol=2e-6)
-    np.testing.assert_allclose(np.asarray(gw_f), np.asarray(gw_r),
-                               rtol=2e-4, atol=2e-6)
+    loss, vjp = jax.vjp(
+        lambda x_, w_: fused_ce._fused_core(x_, w_, lab, -100), x, w)
+    dx, dw = vjp(g)
+    assert dx.dtype == dtype and dw.dtype == dtype
+    loss_r, vjp_r = jax.vjp(
+        lambda x_, w_: fused_ce._reference(x_, w_, lab, -100),
+        x.astype(jnp.float32), w.astype(jnp.float32))
+    dx_r, dw_r = vjp_r(g)
+
+    assert np.isfinite(np.asarray(loss)).all()
+    if inputs == "spread":   # s == 1: the loss is max - logits[label]
+        logits = np.asarray(x, np.float32) @ np.asarray(w, np.float32).T
+        want = logits.max(-1) - logits[np.arange(t), np.clip(lab_np, 0, v - 1)]
+        want[lab_np == -100] = 0.0
+        np.testing.assert_array_equal(np.asarray(loss), want)
+    if labels == "all_ignored":
+        assert not np.asarray(loss).any()
+        assert not np.asarray(dx).any() and not np.asarray(dw).any()
+    rtol, atol = (2e-4, 2e-6) if dtype == jnp.float32 else (2e-2, 1e-5)
+    np.testing.assert_allclose(np.asarray(loss), np.asarray(loss_r),
+                               rtol=2e-5 if dtype == jnp.float32 else 2e-3,
+                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(dx, np.float32),
+                               np.asarray(dx_r), rtol=rtol, atol=atol)
+    np.testing.assert_allclose(np.asarray(dw, np.float32),
+                               np.asarray(dw_r), rtol=rtol, atol=atol)
+
+
+def test_only_a_differentiated_call_pays_the_second_matmul():
+    """A forward-only call (evaluation) holds the logits' matmul alone;
+    a differentiated one holds three (logits, `e @ [W | 1]` = dx and the
+    softmax's sum, dW) and no pass of its own for the sum; the call the
+    tape records for a backward is spelled like the rule's forward, so
+    that XLA merges the two in the compiled step."""
+    import jax
+    import jax.numpy as jnp
+    x = jnp.ones((16, 8), jnp.float32)
+    w = jnp.ones((40, 8), jnp.float32)
+    lab = jnp.zeros((16,), jnp.int32)
+
+    def dots(fn):
+        return str(jax.make_jaxpr(fn)(x, w)).count("dot_general")
+    assert dots(lambda x_, w_: fused_ce._fused_core(
+        x_, w_, lab, -100)) == 1
+    assert dots(lambda x_, w_: fused_ce._fused_core(
+        x_, w_, lab, -100, True)) == 2
+    grad = jax.grad(lambda x_, w_: fused_ce._fused_core(
+        x_, w_, lab, -100).sum(), argnums=(0, 1))
+    text = str(jax.make_jaxpr(grad)(x, w))
+    assert text.count("dot_general") == 3
+    # no reduction over the vocabulary but the row maximum
+    assert "reduce_max[axes=(1,)" in text
+    assert "reduce_sum[axes=(1,)" not in text
+
+
+def test_wrapper_tells_the_op_whether_the_tape_records(monkeypatch):
+    """No option chooses the spelling: the wrapper reads whether the
+    call will be differentiated from the grad mode and its operands."""
+    seen = []
+    orig = fused_ce._fused_op
+    monkeypatch.setattr(
+        fused_ce, "_fused_op",
+        lambda *a, **k: seen.append(k["taped"]) or orig(*a, **k))
+    rs = np.random.RandomState(0)
+    x = paddle.to_tensor(rs.randn(8, 16).astype(np.float32))
+    w = paddle.to_tensor(rs.randn(32, 16).astype(np.float32))
+    lab = paddle.to_tensor(rs.randint(0, 32, (8,)).astype(np.int64))
+    a = fused_ce.fused_linear_cross_entropy(x, w, lab)
+    w.stop_gradient = False
+    b = fused_ce.fused_linear_cross_entropy(x, w, lab)
+    with paddle.no_grad():
+        fused_ce.fused_linear_cross_entropy(x, w, lab)
+    assert seen == [False, True, False]
+    np.testing.assert_allclose(a.numpy(), b.numpy(), rtol=1e-6)
+    b.sum().backward()
+    assert np.isfinite(w.grad.numpy()).all()
 
 
 def test_fused_head_hardware_optin_policy(monkeypatch):
@@ -482,38 +608,3 @@ def test_tp_pallas_gate_defaults_on(monkeypatch):
     monkeypatch.delenv("PADDLE_FUSED_CE_TP")
     monkeypatch.setenv("PADDLE_FUSED_CE_DISABLE", "1")
     assert not fused_ce._use_pallas(x, w, tp=True)
-
-
-def test_xla_bwd_bf16_keeps_dlogits_f32(interpret_kernels, monkeypatch):
-    """ADVICE r5 (low): PADDLE_FUSED_CE_BWD=xla under bf16 inputs —
-    d_logits must stay f32 through the dx/dW matmuls (only the final
-    outputs narrow to the input dtype), so the variant tracks the f32
-    reference composition within bf16 I/O tolerance instead of
-    double-quantizing the gradient signal."""
-    import jax
-    import jax.numpy as jnp
-    monkeypatch.setenv("PADDLE_FUSED_CE_BWD", "xla")
-    rs = np.random.RandomState(12)
-    t, h, v = 128, 128, 1024
-    x32 = (rs.randn(t, h) * 0.3).astype(np.float32)
-    w32 = (rs.randn(v, h) * 0.3).astype(np.float32)
-    lab_np = rs.randint(0, v, (t,))
-    lab_np[7] = -100
-    lab = jnp.asarray(lab_np.astype(np.int32))
-    x16 = jnp.asarray(x32).astype(jnp.bfloat16)
-    w16 = jnp.asarray(w32).astype(jnp.bfloat16)
-
-    gx, gw = jax.grad(
-        lambda x_, w_: fused_ce._fused_core(x_, w_, lab, -100).mean(),
-        argnums=(0, 1))(x16, w16)
-    assert gx.dtype == jnp.bfloat16 and gw.dtype == jnp.bfloat16
-    # reference: full-f32 grads THROUGH the same bf16 operand values
-    gx_r, gw_r = jax.grad(
-        lambda x_, w_: fused_ce._reference(x_, w_, lab, -100).mean(),
-        argnums=(0, 1))(jnp.asarray(x16, jnp.float32),
-                        jnp.asarray(w16, jnp.float32))
-    # bf16 has ~8 mantissa bits: one final-rounding step of tolerance
-    np.testing.assert_allclose(np.asarray(gx, np.float32),
-                               np.asarray(gx_r), rtol=2e-2, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(gw, np.float32),
-                               np.asarray(gw_r), rtol=2e-2, atol=1e-5)

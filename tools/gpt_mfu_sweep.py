@@ -96,12 +96,6 @@ def main():
         # HBM) — the delta vs O2_pure_bf16 is the fused-CE win
         ("O2_unfused_ce", 8, 1024, {"GPT_AMP_LEVEL": "O2",
                                     "PADDLE_FUSED_CE_DISABLE": "1"}),
-        # hybrid: Pallas fused fwd (no logits in HBM) + XLA-composed
-        # bwd (one recompute at XLA matmul efficiency instead of the
-        # Pallas bwd's two hand-rolled ones)
-        ("O2_ce_bwd_xla", 8, 1024, {"GPT_AMP_LEVEL": "O2",
-                                    "PADDLE_FUSED_CE": "1",
-                                    "PADDLE_FUSED_CE_BWD": "xla"}),
         # bigger token tile: halves the per-token-block W streaming
         ("O2_ce_bt512", 8, 1024, {"GPT_AMP_LEVEL": "O2",
                                   "PADDLE_FUSED_CE": "1",
